@@ -25,9 +25,9 @@ BENCH_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmarks"
 )
 
-# not measurement artifacts: budgets carry their own backend/device_kind/
-# jax_version header, and WEDGE_STATUS is a TPU-claim status record
-SKIP = {"perf_budgets.json", "WEDGE_STATUS.json"}
+# not a measurement artifact: budgets carry their own backend/device_kind/
+# jax_version header
+SKIP = {"perf_budgets.json"}
 
 
 def main(argv=None) -> int:

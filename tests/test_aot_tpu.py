@@ -1,0 +1,249 @@
+"""Compile every Pallas kernel flavor for a *described* TPU v5e.
+
+The chip's compiler is installed in the CPU sandbox and compiles for a
+device that is described, not attached (``jax.experimental.topologies``):
+what Mosaic refuses here it refuses on the chip, at no chip time. One case
+per ``KERNEL_PARITY`` flavor at GPT-2-small widths (12 heads x 64, vocab
+50257, 64+40 tokens, KV block 16, bf16 activations as the trainer runs
+them), plus flash attention as the model calls it under the four-device
+mesh ``chip_smoke.py --chips 4`` runs on.
+
+Nothing executes — this says nothing about results or times; the on-chip
+comparison against the XLA references is the ``kernels`` phase of
+``chip_smoke.py``. A flavor Mosaic still refuses is ``xfail(strict=True)``
+with the compiler's words, so the PR that repairs it must flip it.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+# flavor -> the compiler's words, for flavors Mosaic still refuses: one
+# table, kept where the chip run prints it
+from chip_smoke import KERNELS_REFUSED as REFUSED
+from trlx_tpu.analysis.kernels import KERNEL_PARITY
+
+# GPT-2-small attention widths and the bench task shape (bench.py)
+H, D, VOCAB = 12, 64, 50257
+PROMPT, NEW = 64, 40
+T = PROMPT + NEW
+B = 8  # compile cost does not grow with the batch; widths are what matter
+BLOCK = 16  # EngineConfig.kv_block_size default
+TB = -(-T // BLOCK)
+DT = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    """A compile for a described device is written to the persistent cache
+    but cannot be read back without a chip (the next run warns and
+    recompiles), so the cache stays off around these tests."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _compile(fn, args, sharding):
+    """Lower ``fn`` on shapes placed by ``sharding`` and compile it with
+    the TPU compiler; the program must contain a Mosaic custom call."""
+    sds = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), args
+    )
+    text = jax.jit(fn).lower(*sds).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+def _s(shape, dtype=DT):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _pool_args(q_shape, bias_shape):
+    pool = _s((1 + B * TB, BLOCK, H, D))
+    return _s(q_shape), pool, pool, _s((B, TB), jnp.int32), _s(bias_shape, jnp.float32)
+
+
+def _flash_fwd():
+    from trlx_tpu.ops.flash_attention import flash_attention
+
+    x = _s((B, T, H, D))
+    return (
+        lambda q, k, v, m: flash_attention(q, k, v, m, interpret=False),
+        (x, x, x, _s((B, T), jnp.float32)),
+    )
+
+
+def _flash_bwd():
+    from trlx_tpu.ops.flash_attention import flash_attention_bwd_chunk
+
+    x, row = _s((B, T, H, D)), _s((B, H, T), jnp.float32)
+    return (
+        lambda q, k, v, m, lse, delta, do: flash_attention_bwd_chunk(
+            q, k, v, m, lse, delta, do, interpret=False
+        ),
+        (x, x, x, _s((B, T), jnp.float32), row, row, x),
+    )
+
+
+def _paged_decode():
+    from trlx_tpu.ops.paged_attention import paged_attention_decode
+
+    return (
+        lambda *a: paged_attention_decode(*a, interpret=False),
+        _pool_args((B, H, D), (B, 1, T)),
+    )
+
+
+def _paged_prefill():
+    from trlx_tpu.ops.paged_prefill import paged_prefill_attention
+
+    return (
+        lambda *a: paged_prefill_attention(*a, interpret=False),
+        _pool_args((B, PROMPT, H, D), (B, 1, PROMPT, T)),
+    )
+
+
+def _paged_verify():
+    from trlx_tpu.ops.paged_attention import paged_verify_attention
+
+    G = 4  # draft_gamma of the repo's speculative configs
+    return (
+        lambda *a: paged_verify_attention(*a, interpret=False),
+        _pool_args((B, G + 1, H, D), (B, 1, G + 1, T)),
+    )
+
+
+def _fused_sample():
+    from trlx_tpu.ops.paged_attention import fused_sample
+
+    # the bench task's gen_kwargs: unfiltered sampling at temperature 1
+    logits = _s((B, VOCAB), jnp.float32)
+    return (
+        lambda lg, g: fused_sample(
+            lg, g, temperature=1.0, top_k=0, top_p=1.0, interpret=False
+        ),
+        (logits, logits),
+    )
+
+
+def _fused_loss():
+    from trlx_tpu.models.ppo import PPOConfig
+    from trlx_tpu.ops.fused_loss import fused_ppo_loss
+
+    method = PPOConfig()
+    ops = tuple(_s((128, NEW), jnp.float32) for _ in range(6))
+
+    def fwd_bwd(*o):
+        def loss(lp, v):
+            return fused_ppo_loss(method, lp, v, *o[2:], interpret=False)[0]
+
+        return jax.value_and_grad(loss, argnums=(0, 1))(o[0], o[1])
+
+    return fwd_bwd, ops
+
+
+_BUILDERS = {
+    "flash-fwd": _flash_fwd,
+    "flash-bwd": _flash_bwd,
+    "paged-decode": _paged_decode,
+    "paged-prefill": _paged_prefill,
+    "paged-verify": _paged_verify,
+    "fused-sample": _fused_sample,
+    "fused-loss": _fused_loss,
+}
+
+def test_table_covers_the_registry():
+    assert set(_BUILDERS) == {row[0] for row in KERNEL_PARITY}
+    assert set(REFUSED) <= set(_BUILDERS)
+
+
+@pytest.mark.parametrize(
+    "flavor",
+    [
+        pytest.param(
+            f,
+            marks=pytest.mark.xfail(strict=True, reason=REFUSED[f]) if f in REFUSED else (),
+        )
+        for f in _BUILDERS
+    ],
+)
+def test_kernel_compiles_for_v5e(topo, flavor):
+    """Under the default matmul precision (whatever other test modules set
+    at import) — and, for the flavors whose dots take bf16 operands, under
+    ``highest`` too: Mosaic refuses an fp32 contraction on bf16 ("Bad lhs
+    type"), so those kernels must not ask for one when a caller raises the
+    default."""
+    fn, args = _BUILDERS[flavor]()
+    precisions = ("default", "highest") if flavor.startswith("paged-") else ("default",)
+    for precision in precisions:
+        with jax.default_matmul_precision(precision):
+            _compile(fn, args, SingleDeviceSharding(topo.devices[0]))
+
+
+def test_flash_under_four_device_mesh(topo, monkeypatch):
+    """Flash attention as the model calls it (``Attention`` inside a
+    ``CausalTransformer`` forward + backward) under ``fsdp=2, model=2``:
+    Mosaic kernels cannot be partitioned by GSPMD, so the call must sit in a
+    ``shard_map`` over the batch and head axes."""
+    from trlx_tpu.models.transformer import CausalTransformer, TransformerConfig
+    from trlx_tpu.parallel.mesh import MESH_AXES, set_global_mesh
+    from trlx_tpu.parallel.sharding import param_shardings
+
+    # steer the platform probes the way the chip would answer them
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    cfg = TransformerConfig(
+        vocab_size=512, hidden_size=H * D, num_layers=1, num_heads=H,
+        intermediate_size=4 * H * D, max_position_embeddings=T,
+        dtype=DT,
+    )
+    model = CausalTransformer(cfg)
+    mesh = Mesh(
+        np.asarray(topo.devices).reshape(1, 1, 2, 2, 1, 1), MESH_AXES
+    )
+    ids = jnp.zeros((B, T), jnp.int32)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), ids[:1, :8])
+    )["params"]
+    shardings = param_shardings(params, mesh)
+    params = jax.tree_util.tree_map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        params, shardings,
+    )
+    batch = NamedSharding(mesh, P(("data", "fsdp")))
+    ids_s = jax.ShapeDtypeStruct(ids.shape, ids.dtype, sharding=batch)
+
+    def loss(p, x):
+        out = model.apply({"params": p}, x, attention_mask=jnp.ones_like(x))
+        return out["logits"].astype(jnp.float32).mean()
+
+    set_global_mesh(mesh)
+    try:
+        with jax.default_matmul_precision("default"):
+            text = jax.jit(jax.value_and_grad(loss)).lower(params, ids_s).compile().as_text()
+    finally:
+        set_global_mesh(None)
+    assert "tpu_custom_call" in text
+    # heads are sharded over `model`: each device runs the kernel on 6 of 12
+    assert f"{B // 2},{H // 2}," in text.replace(" ", "")

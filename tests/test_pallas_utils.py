@@ -51,9 +51,6 @@ def test_resolve_interpret_respects_explicit_knob():
     assert pu.resolve_interpret(None) == pu.default_interpret()
 
 
-@pytest.mark.skipif(
-    not pu.has_pallas_tpu(), reason="Mosaic backend unavailable"
-)
 def test_paged_pool_grid_spec_drives_fetches_through_the_table():
     """The factored grid builder must behave exactly like the inline
     PrefetchScalarGridSpec it replaced: a trivial copy kernel assembling

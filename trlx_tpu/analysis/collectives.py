@@ -1,7 +1,7 @@
 """SPMD collective-discipline pass (GL7xx): host collectives must be
 posted by EVERY rank, in the same order, with matching payloads — or the
 pod hangs. ``ClusterDesyncError`` catches one class of divergence at
-runtime, after a chip window is already burning; this pass proves the
+runtime, after the pod's time is already burning; this pass proves the
 classic divergence shapes absent statically.
 
 **The catalog.** A *direct collective site* is a call whose callee name

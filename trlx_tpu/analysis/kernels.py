@@ -2,11 +2,11 @@
 
 Everything that landed with the native-kernel PRs is guarded by
 *convention*: every ``pallas_call`` hides behind the shared
-``ops/pallas_utils.py`` gate (``has_pallas_tpu()`` routes Mosaic-less
-builds to the XLA reference, ``resolve_interpret()`` selects interpret
-mode off-TPU), every ``*_pallas`` metric gauge is stamped from the gate
-(a fallback build must not claim kernel=1 in an A/B artifact — a bug
-that shipped twice), every kernel body obeys the documented lowering
+``ops/pallas_utils.py`` gate (``resolve_interpret()`` selects interpret
+mode off-TPU; a ``has_pallas_tpu()`` probe, which this tree no longer
+carries, counts as a gate too), every ``*_pallas`` metric gauge is
+stamped from the state that selected the compute (an artifact must not
+claim kernel=1 unconditionally — a bug that shipped twice), every kernel body obeys the documented lowering
 landmines, and every kernel flavor has an XLA reference pinned
 bit-identical by a parity test. This pass family turns each convention
 into a whole-program check (docs/STATIC_ANALYSIS.md, "The kernel

@@ -466,7 +466,7 @@ def measure_continuous_batching(
     ``time/rollout``, ``rollout/padded_decode_frac`` and
     ``throughput/slot_utilization``, plus the wall-clock speedup. Runs on
     whatever backend JAX selected (CPU program-level ratios or on-chip
-    numbers — the evidence chain runs it in ``scripts/tpu_evidence.py``).
+    numbers).
     """
     import numpy as np
 
@@ -1476,7 +1476,6 @@ def measure_loss_kernel(
 
     from trlx_tpu.models.ppo import PPOConfig
     from trlx_tpu.ops.fused_loss import fused_ppo_loss, fused_ppo_loss_reference
-    from trlx_tpu.ops.pallas_utils import has_pallas_tpu
     from trlx_tpu.perf import lowered_costs
     from trlx_tpu.utils.stats import whiten
 
@@ -1623,7 +1622,6 @@ def measure_loss_kernel(
             "cost model cannot see"
         ),
         "loss_grad_seconds_per_call": timings,
-        "loss_kernel_pallas": float(has_pallas_tpu()),
     }
     import jax as _jax
 

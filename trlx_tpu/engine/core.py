@@ -622,25 +622,16 @@ class ContinuousEngine(Engine):
             # upper bound on each slot's decode step (segments survived)
             self._steps_bound = [0] * self.B
             self.stats.kv_blocks_total = self.spec.max_blocks - 1
-            # gauges reflect the compute that actually RUNS: on builds
-            # without the Mosaic backend the kernels fall back to their
-            # gather references (ops/pallas_utils.has_pallas_tpu), and
-            # reporting kernel=1 / gather bytes=0 there would stamp wrong
-            # acceptance numbers into an A/B artifact
-            from trlx_tpu.ops.pallas_utils import has_pallas_tpu
-
+            # gauges name the compute the slot-refill programs were built
+            # with; a selected kernel runs (interpreted off-TPU) or raises
             self.stats.decode_kernel_pallas = (
                 getattr(fns, "decode_kernel", "xla") == "pallas"
-                and has_pallas_tpu()
             )
             self.stats.prefill_kernel_pallas = (
                 getattr(fns, "prefill_kernel", "xla") == "pallas"
-                and has_pallas_tpu()
             )
             self.stats.spec_verify_kernel_pallas = bool(
-                self._gamma
-                and getattr(fns, "decode_kernel", "xla") == "pallas"
-                and has_pallas_tpu()
+                self._gamma and self.stats.decode_kernel_pallas
             )
             self._block_bytes = block_bytes(self.state.cache)
             # per-cache-column bytes (all layers, k+v): the unit of the

@@ -353,8 +353,7 @@ def kl_penalty_rewards_np(logprobs, ref_logprobs, response_mask, scores, kl_coef
     already-fetched [B, R] arrays. The reward assembly depends on the
     host-side ``reward_fn`` scores, so computing it here lets the scoring
     forward be dispatched *before* the host scores exist, collapsing the
-    rollout loop to a single device→host sync per batch (the sync dominates
-    wall time on tunneled/remote TPU setups)."""
+    rollout loop to a single device→host sync per batch."""
     import numpy as np
 
     mask = np.asarray(response_mask, np.float32)

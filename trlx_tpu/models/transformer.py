@@ -67,14 +67,9 @@ def _traced_global_mesh():
     """The global mesh, iff one is set AND we are inside a trace (sharding
     constraints / collective layouts only apply under jit; eager passes —
     e.g. ``module.init`` — take the plain paths)."""
+    from jax._src.core import trace_state_clean
+
     from trlx_tpu.parallel.mesh import get_global_mesh
-
-    try:
-        from jax._src.core import trace_state_clean
-    except ImportError:  # pragma: no cover - private API moved
-
-        def trace_state_clean():
-            return False
 
     mesh = get_global_mesh()
     if mesh is not None and not trace_state_clean():
@@ -129,6 +124,59 @@ def _maybe_ring_mesh(T: int):
     ):
         return mesh
     return None
+
+
+def _flash_attention(q, k, v, flash_args: Dict[str, Any]) -> jax.Array:
+    """The fused flash kernel over ``[B, T, H, D]`` / ``[B, S, KV, D]``, on
+    whatever mesh is live.
+
+    GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot be
+    automatically partitioned"), so under a mesh of more than one device the
+    call runs per shard inside a fully manual ``shard_map``, laid out by
+    ``parallel/sharding.py::attention_shard_axes`` (batch and heads where
+    the projections already put them, so no operand moves) and replicated
+    over the remaining axes. Masking semantics are the additive-bias path's
+    (slot-causal + key validity + optional window/ALiBi)."""
+    from trlx_tpu.ops.flash_attention import flash_attention
+
+    window = flash_args.get("window")
+    # q_offset may be a traced scalar (prefill into a cache), so it rides as
+    # an operand; window is static
+    operands = {
+        name: flash_args[name]
+        for name in ("key_mask", "q_positions", "k_positions", "alibi_slopes")
+        if flash_args.get(name) is not None
+    }
+    operands["q_offset"] = jnp.asarray(flash_args.get("q_offset", 0), jnp.int32)
+
+    def call(q, k, v, kw):
+        return flash_attention(q, k, v, causal=True, window=window, **kw)
+
+    mesh = _traced_global_mesh()
+    if mesh is None or mesh.devices.size == 1:
+        return call(q, k, v, operands)
+
+    from jax.sharding import PartitionSpec as P
+
+    from trlx_tpu.parallel.sharding import attention_shard_axes
+
+    batch_axes, head_axis = attention_shard_axes(mesh, k.shape)
+    heads = P(batch_axes, None, head_axis, None)
+    rows = P(batch_axes, None)
+    layouts = {
+        "key_mask": rows,
+        "q_positions": rows,
+        "k_positions": rows,
+        "alibi_slopes": P(head_axis),
+        "q_offset": P(),
+    }
+    return jax.shard_map(
+        call,
+        mesh=mesh,
+        in_specs=(heads, heads, heads, {name: layouts[name] for name in operands}),
+        out_specs=heads,
+        check_vma=False,
+    )(q, k, v, operands)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -688,22 +736,7 @@ class Attention(nn.Module):
                 window=flash_args.get("window"),
             ).reshape(B, T, H * D)
         elif flash_args is not None:
-            # fused flash-attention kernel; masking semantics identical to the
-            # additive-bias path (slot-causal + key validity + optional ALiBi)
-            from trlx_tpu.ops.flash_attention import flash_attention
-
-            out = flash_attention(
-                q,
-                k,
-                v,
-                flash_args["key_mask"],
-                causal=True,
-                q_offset=flash_args.get("q_offset", 0),
-                q_positions=flash_args.get("q_positions"),
-                k_positions=flash_args.get("k_positions"),
-                alibi_slopes=flash_args.get("alibi_slopes"),
-                window=flash_args.get("window"),
-            ).reshape(B, T, H * D)
+            out = _flash_attention(q, k, v, flash_args).reshape(B, T, H * D)
         else:
             if KV < H:  # flash/ring kernels consume unrepeated K/V (GQA-aware)
                 k = jnp.repeat(k, H // KV, axis=2)

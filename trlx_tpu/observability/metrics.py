@@ -12,17 +12,21 @@ same machinery as ``trlx_tpu/perf.py`` — see ``perf.lowered_costs``), joined
 against the device-fenced step time from the span tracer. ``cost_analysis``
 reports *per-device* flops, so MFU divides by the per-device peak directly.
 
-On hardware whose peak is unknown (CPU, exotic kinds), a nominal
-``DEFAULT_PEAK_FLOPS`` (1 TFLOP/s) keeps ``throughput/mfu`` defined as a
-run-over-run *relative* utilization index; set ``TRLX_TPU_PEAK_FLOPS`` (per
-device) to make it absolute.
+Off-TPU (the CPU test meshes) a nominal ``DEFAULT_PEAK_FLOPS`` (1 TFLOP/s)
+keeps ``throughput/mfu`` defined as a run-over-run *relative* index — never
+a device's utilization. On a TPU the peak comes from :data:`TPU_PEAK_FLOPS`
+by ``device_kind`` and a kind the table does not know is an error, not a
+default; ``TRLX_TPU_PEAK_FLOPS`` (per device) overrides either.
 """
 
 import os
 import threading
 from typing import Any, Dict, List, Optional
 
-# bf16 peak per chip — single source of truth (bench.py imports this table)
+# bf16 peak per chip, keyed by a substring of ``device_kind`` — single source
+# of truth (bench.py reads it through device_peak_flops). Sources: Google
+# Cloud TPU documentation, system architecture pages per generation. A v5e
+# reports ``device_kind == "TPU v5 lite"``.
 TPU_PEAK_FLOPS = {
     "v4": 275e12,
     "v5e": 197e12,
@@ -31,28 +35,31 @@ TPU_PEAK_FLOPS = {
     "v6e": 918e12,
 }
 
-# nominal per-device peak when the hardware is unknown (CPU test meshes):
-# keeps throughput/mfu defined as a relative index rather than absent
+# nominal per-device peak off-TPU (CPU test meshes): keeps throughput/mfu
+# defined as a relative index rather than absent
 DEFAULT_PEAK_FLOPS = 1e12
 
 
 def device_peak_flops(device=None) -> float:
-    """Per-device peak FLOP/s: ``TRLX_TPU_PEAK_FLOPS`` env override, else the
-    known TPU table by ``device_kind``, else :data:`DEFAULT_PEAK_FLOPS`."""
+    """Per-device peak FLOP/s: ``TRLX_TPU_PEAK_FLOPS`` env override, else
+    the TPU table by ``device_kind`` (an unknown TPU kind raises), else —
+    off-TPU only — the nominal :data:`DEFAULT_PEAK_FLOPS`."""
     env = os.environ.get("TRLX_TPU_PEAK_FLOPS")
     if env:
         return float(env)
     if device is None:
-        try:
-            import jax
+        import jax
 
-            device = jax.local_devices()[0]
-        except Exception:
-            return DEFAULT_PEAK_FLOPS
+        device = jax.local_devices()[0]
     kind = getattr(device, "device_kind", "").lower()
     for key, val in TPU_PEAK_FLOPS.items():
         if key in kind:
             return val
+    if getattr(device, "platform", None) == "tpu":
+        raise ValueError(
+            f"no peak FLOP/s known for TPU device_kind {kind!r}: add it to "
+            "TPU_PEAK_FLOPS (with its source) or set TRLX_TPU_PEAK_FLOPS"
+        )
     return DEFAULT_PEAK_FLOPS
 
 

@@ -62,15 +62,16 @@ a 2⁻¹¹-scale shift in the value loss, far beyond reduction jitter. All
 pinned by ``tests/test_fused_loss.py``.
 
 Off-TPU the program runs under the Pallas interpreter (the kernel body
-as ordinary XLA ops — what the CPU tier-1 parity suite pins); builds
-without the Mosaic backend fall back to the staged XLA composition with
-identical semantics.
+as ordinary XLA ops — what the CPU tier-1 parity suite pins).
 
-Hardware notes (``/opt/skills/guides/pallas_guide.md``): the GAE
-recurrence is a ``lax.scan`` and the sketches are scatter-adds — both
-trace into the kernel body and run today under the interpreter (the
-pinned tier-1 contract); Mosaic's ability to lower them on-chip is the
-next-TPU-window A/B (``docs/PERFORMANCE.md`` "Fused learner kernels").
+Hardware notes: the GAE recurrence is a reversed ``lax.scan`` and the
+sketches are scatter-adds — both trace into the kernel body and run under
+the interpreter (the pinned tier-1 contract). Mosaic refuses the program
+(``Unimplemented primitive in Pallas TPU lowering for KernelType.TC: rev``;
+past ``rev`` it lowers only fori_loop-shaped scans, with no per-step
+inputs or outputs), so on a TPU ``method.loss_kernel: pallas`` raises at
+compile time — strict xfail in ``tests/test_aot_tpu.py`` until the body is
+rewritten (``docs/PERFORMANCE.md`` "Fused learner kernels").
 ``block_rows`` sets the batch-axis pad granularity (keep it a multiple
 of 8, the f32 sublane, on chip); the response width pads to the
 128-lane multiple.
@@ -94,7 +95,6 @@ from trlx_tpu.observability.dynamics import SKETCH_BINS
 from trlx_tpu.ops.pallas_utils import (
     LANES,
     align_rows,
-    has_pallas_tpu,
     resolve_interpret,
 )
 
@@ -383,8 +383,7 @@ def fused_ppo_loss_reference(
     behavior_logprobs: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, dict]:
     """The staged XLA composition — GAE → whiten → loss — exactly as the
-    trainer's ``loss_kernel: xla`` path runs it (test reference, and the
-    fallback when the Mosaic backend is unavailable)."""
+    trainer's ``loss_kernel: xla`` path runs it (the parity reference)."""
     return _loss_core(
         loss_params_of(method),
         logprobs,
@@ -420,11 +419,6 @@ def fused_ppo_loss(
     ``values`` only (the rest are batch constants in the trainer).
     """
     p = loss_params_of(method)
-    if not has_pallas_tpu():  # pragma: no cover - exotic CPU-only builds
-        return fused_ppo_loss_reference(
-            method, logprobs, values, old_logprobs, old_values, rewards,
-            mask, behavior_logprobs,
-        )
     interpret = resolve_interpret(interpret)
     use_iw = behavior_logprobs is not None and p.iw_correction != "off"
     operands = (logprobs, values, old_logprobs, old_values, rewards, mask)

@@ -49,20 +49,21 @@ bit-identical to plain ``generate``. Two design rules follow:
 
 Off-TPU both kernels run under the Pallas interpreter (the kernel body as
 ordinary XLA ops — ``ops/pallas_utils.py``), which is what the CPU tier-1
-parity suite pins. Builds without the Mosaic backend
-(``pallas_utils.has_pallas_tpu() == False``) fall back to
-:func:`paged_attention_decode_reference` / the XLA sampler with identical
-semantics.
+parity suite pins; on a TPU they compile through Mosaic or raise.
 
-Hardware notes (``/opt/skills/guides/pallas_guide.md``): block fetches are
-``(block_size, KV, D)`` tiles pipelined by the grid (each of a row's blocks
-is fetched exactly once — the grid is per row with all heads in one cell),
-so ``engine.kv_block_size`` should be a multiple of 8 (f32 sublane) and
-``D`` ideally a multiple of 128 on real TPUs; the CPU-scale tests run tiny
-blocks under the interpreter, where Mosaic tiling constraints do not apply.
-The sampling kernel's top-p path sorts in VMEM via the traced
-``jnp.sort`` — backends whose Mosaic build cannot lower sort should keep
-``engine.decode_kernel: xla`` for top-p sampling.
+Hardware notes (TPU v5e; ``tests/test_aot_tpu.py`` compiles these for a
+described chip, ``chip_smoke.py`` runs them against their references):
+block fetches are ``(block_size, KV, D)`` tiles pipelined by the grid (each
+of a row's blocks is fetched exactly once — the grid is per row with all
+heads in one cell). Decode and verify lower and match at GPT-2-small widths
+(block 16, D 64, bf16); the score dots accumulate in f32
+(``preferred_element_type``) and round to the operand dtype — what the
+dense path's einsum does on a TPU — and ``sqrt(depth)`` is taken in f32
+(v5e has no bf16 ``sqrt``). The sampling kernel lowers for temperature-only
+sampling; Mosaic lowers neither ``top_k`` nor ``sort``, so top-k / top-p
+under ``engine.decode_kernel: pallas`` is a compile error on the chip —
+keep ``decode_kernel: xla`` for filtered sampling. The CPU-scale tests run
+tiny blocks under the interpreter, where tiling constraints do not apply.
 
 Registered in ``analysis/kernels.py::KERNEL_PARITY`` as ``paged-decode``
 (and ``fused-sample``/``paged-verify``): graftlint's kernel-discipline
@@ -82,7 +83,7 @@ from trlx_tpu.ops.pallas_utils import (
     LANES,
     align_rows,
     clamp_block_table,
-    has_pallas_tpu,
+    dot_precision,
     pad_bias_to,
     paged_pool_grid_spec,
     resolve_interpret,
@@ -137,15 +138,23 @@ def _paged_decode_kernel(
         # Batch-dim (row) slicing is bit-preserving on the dense lowering —
         # making h a dot *batch* dim is not, which is why the grid is per
         # row with all heads in one cell.
+        # Mosaic wants a 32-bit matmul accumulator and v5e has no bf16
+        # sqrt: accumulate in f32 and round back to the operand dtype (a
+        # no-op for f32, and what the dense bf16 einsum does on a TPU),
+        # and take sqrt(depth) in f32, rounded to the same value the dense
+        # path's sqrt in the operand dtype gives.
         q = q_ref[...]  # (1, H, D)
         k = k_buf_ref[0:seq_len, :, :]
         vv = v_buf_ref[0:seq_len, :, :]
         if group > 1:
             k = jnp.repeat(k, group, axis=1)
             vv = jnp.repeat(vv, group, axis=1)
-        raw = jnp.einsum("thd,shd->hts", q, k)  # (H, 1, S)
-        depth = jnp.asarray(head_dim, raw.dtype)
-        scores = raw / jnp.sqrt(depth)
+        precision = dot_precision(q.dtype)
+        raw = jnp.einsum(
+            "thd,shd->hts", q, k,
+            precision=precision, preferred_element_type=jnp.float32,
+        ).astype(q.dtype)  # (H, 1, S)
+        scores = raw / jnp.sqrt(jnp.float32(head_dim)).astype(raw.dtype)
         # (HB, 1, S) broadcasts over heads exactly like the dense path's
         # [B, HB, T, S] bias against its [B, H, T, S] scores
         bias = jnp.swapaxes(bias_ref[0:1, :, 0:seq_len], 0, 1)
@@ -153,7 +162,10 @@ def _paged_decode_kernel(
         probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(
             raw.dtype
         )
-        out = jnp.einsum("hts,shd->thd", probs, vv)  # (1, H, D)
+        out = jnp.einsum(
+            "hts,shd->thd", probs, vv,
+            precision=precision, preferred_element_type=jnp.float32,
+        )  # (1, H, D)
         o_ref[...] = out.astype(o_ref.dtype)
 
 
@@ -190,10 +202,6 @@ def paged_attention_decode(
     if TB * bs < S:
         raise ValueError(
             f"block table covers {TB * bs} columns < bias width {S}"
-        )
-    if not has_pallas_tpu():  # pragma: no cover - exotic CPU-only builds
-        return paged_attention_decode_reference(
-            q, k_pool, v_pool, block_table, bias
         )
     interpret = resolve_interpret(interpret)
     S_pad = TB * bs
@@ -239,8 +247,8 @@ def paged_attention_decode_reference(
     bias: jax.Array,  # (B, HB, S); HB is 1 or H (per-head ALiBi)
 ) -> jax.Array:
     """Gather-then-dense oracle: the exact computation the gather path's
-    dense einsum attention performs on the gathered view (test reference,
-    and the fallback when the Mosaic backend is unavailable)."""
+    dense einsum attention performs on the gathered view (the parity
+    reference)."""
     B, H, D = q.shape
     NB, bs, KV, _ = k_pool.shape
     S = bias.shape[2]
@@ -333,9 +341,13 @@ def _fused_sample_kernel(
         choice = jnp.argmax(gumbel_ref[...] + filtered, axis=-1)  # (1,)
     else:
         choice = jnp.argmax(filtered, axis=-1)
-    lp = jnp.take_along_axis(
-        jax.nn.log_softmax(row, axis=-1), choice[:, None], axis=-1
-    )[:, 0]
+    # the chosen logprob by one-hot select + sum (exact: one term, rest 0.0);
+    # Mosaic lowers no (1, V)[(1, 1)] gather
+    cols = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
+    lp = jnp.sum(
+        jnp.where(cols == choice[:, None], jax.nn.log_softmax(row, axis=-1), 0.0),
+        axis=-1,
+    )
     tok_ref[...] = jnp.broadcast_to(
         choice[:, None].astype(jnp.int32), tok_ref.shape
     )
@@ -367,24 +379,29 @@ def fused_sample(
         top_p=top_p,
         do_sample=do_sample,
     )
-    row_spec = pl.BlockSpec((1, V), lambda b: (b, 0))
-    in_specs = [row_spec] + ([row_spec] if do_sample else [])
-    args = (logits,) + ((gumbel,) if do_sample else ())
+    # Mosaic wants the lane (vocab) dim a multiple of 128: on chip the row
+    # is padded with -inf logits (never drawn, exp() == 0 in every softmax)
+    # and zero noise; under the interpreter V stays exact. Rows ride a unit
+    # middle dim so each (1, Vp) block equals the operand's trailing dims.
+    Vp = align_rows(V, interpret)
+    pads = ((0, 0), (0, Vp - V))
+    args = [jnp.pad(logits, pads, constant_values=-jnp.inf)]
+    if do_sample:
+        args.append(jnp.pad(gumbel, pads))
+    row_spec = pl.BlockSpec((None, 1, Vp), lambda b: (b, 0, 0))
+    out_spec = pl.BlockSpec((None, 1, LANES), lambda b: (b, 0, 0))
     tok, lp = pl.pallas_call(
         kernel,
         grid=(B,),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, LANES), lambda b: (b, 0)),
-            pl.BlockSpec((1, LANES), lambda b: (b, 0)),
-        ],
+        in_specs=[row_spec] * len(args),
+        out_specs=[out_spec, out_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((B, LANES), jnp.int32),
-            jax.ShapeDtypeStruct((B, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, LANES), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1, LANES), jnp.float32),
         ],
         interpret=interpret,
-    )(*args)
-    return tok[:, 0], lp[:, 0]
+    )(*(a[:, None, :] for a in args))
+    return tok[:, 0, 0], lp[:, 0, 0]
 
 
 def sample_token_fused(

@@ -39,14 +39,14 @@ by the parity suite; truncating the key axis instead changes the dot's
 lowering at some shapes — 1-ulp contraction drift).
 
 Off-TPU the kernel runs under the Pallas interpreter (the body as ordinary
-XLA ops — what the CPU tier-1 parity suite pins); builds without the
-Mosaic backend fall back to :func:`paged_prefill_attention_reference` with
-identical semantics.
+XLA ops — what the CPU tier-1 parity suite pins); on a TPU it compiles
+through Mosaic or raises.
 
-Hardware notes (``/opt/skills/guides/pallas_guide.md``): block fetches are
-``(block_size, KV, D)`` tiles pipelined by the grid; keep
-``engine.kv_block_size`` a multiple of 8 (f32 sublane) and ``D`` a
-multiple of 128 on real TPUs. VMEM holds the assembled row
+Hardware notes (TPU v5e; compiled by ``tests/test_aot_tpu.py``, run against
+the reference by ``chip_smoke.py``): block fetches are
+``(block_size, KV, D)`` tiles pipelined by the grid. Mosaic's matmul takes
+one batch dim, so the dots run on the row with its unit batch dim dropped
+(heads are the batch dim) and accumulate in f32. VMEM holds the assembled row
 (``TB·block_size × KV × D``) plus the ``[T, S]`` f32 score block — bound
 ``T`` with ``engine.prefill_chunk`` for long prompts on chip.
 
@@ -66,7 +66,7 @@ from jax.experimental import pallas as pl
 from trlx_tpu.ops.pallas_utils import (
     align_rows,
     clamp_block_table,
-    has_pallas_tpu,
+    dot_precision,
     pad_bias_to,
     paged_pool_grid_spec,
     resolve_interpret,
@@ -108,32 +108,35 @@ def _paged_prefill_kernel(
         # the dense path on the per-row slice, op for op: GQA repeat;
         # scores = einsum(q, k) / sqrt(depth); scores += bias;
         # probs = softmax(f32(scores)).astype(dtype); out = einsum(probs, v)
-        # The unit batch dim is KEPT on every operand so both dots carry
-        # the dense path's exact dimension numbers ("bthd,bshd->bhts" /
-        # "bhts,bshd->bthd", batch size 1 instead of B): batch-dim slicing
-        # is the established bit-safe decomposition, while DROPPING the
-        # batch dim changes the dot's structure — and for T > 1 matmuls
-        # inside the interpreter's grid machinery that can change which
-        # CPU emitter XLA picks, shifting contraction bits by 1 ulp. A
-        # third lowering landmine for the next kernel author, beside the
-        # two the decode kernel documents.
-        q = q_ref[...]  # (1, T, H, D)
-        k = k_buf_ref[0:seq_len, :, :][None]
-        vv = v_buf_ref[0:seq_len, :, :][None]
+        # The row's unit batch dim is DROPPED, so heads are each dot's one
+        # batch dim: Mosaic's matmul takes at most one ("Up to 1 batch dim
+        # supported"). An earlier version kept it for the interpreter's
+        # sake (on jax 0.4.37 dropping it shifted contraction bits by 1
+        # ulp on the CPU); on the installed toolchain the parity suite is
+        # bit-equal either way, and only this form lowers.
+        q = q_ref[0]  # (T, H, D)
+        k = k_buf_ref[0:seq_len, :, :]
+        vv = v_buf_ref[0:seq_len, :, :]
         if group > 1:
-            k = jnp.repeat(k, group, axis=2)
-            vv = jnp.repeat(vv, group, axis=2)
-        raw = jnp.einsum("bthd,bshd->bhts", q, k)  # (1, H, T, S)
-        depth = jnp.asarray(head_dim, raw.dtype)
-        scores = raw / jnp.sqrt(depth)
-        # (1, HB, T, S) broadcasts over heads exactly like the dense
-        # path's [B, HB, T, S] bias against its [B, H, T, S] scores
-        bias = bias_ref[...][:, :, :, 0:seq_len]
+            k = jnp.repeat(k, group, axis=1)
+            vv = jnp.repeat(vv, group, axis=1)
+        precision = dot_precision(q.dtype)
+        raw = jnp.einsum(
+            "thd,shd->hts", q, k,
+            precision=precision, preferred_element_type=jnp.float32,
+        ).astype(q.dtype)  # (H, T, S)
+        scores = raw / jnp.sqrt(jnp.float32(head_dim)).astype(raw.dtype)
+        # (HB, T, S) broadcasts over heads exactly like the dense path's
+        # [B, HB, T, S] bias against its [B, H, T, S] scores
+        bias = bias_ref[0][:, :, 0:seq_len]
         scores = scores + bias.astype(scores.dtype)
         probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(
             raw.dtype
         )
-        out = jnp.einsum("bhts,bshd->bthd", probs, vv)  # (1, T, H, D)
+        out = jnp.einsum(
+            "hts,shd->thd", probs, vv,
+            precision=precision, preferred_element_type=jnp.float32,
+        )[None]  # (1, T, H, D)
         o_ref[...] = out.astype(o_ref.dtype)
 
 
@@ -176,10 +179,6 @@ def paged_prefill_attention(
     if TB * bs < S:
         raise ValueError(
             f"block table covers {TB * bs} columns < bias width {S}"
-        )
-    if not has_pallas_tpu():  # pragma: no cover - exotic CPU-only builds
-        return paged_prefill_attention_reference(
-            q, k_pool, v_pool, block_table, bias
         )
     interpret = resolve_interpret(interpret)
     S_pad = TB * bs
@@ -225,8 +224,8 @@ def paged_prefill_attention_reference(
     bias: jax.Array,  # (B, HB, T, S); HB is 1 or H (per-head ALiBi)
 ) -> jax.Array:
     """Gather-then-dense oracle: the exact computation the gather refill's
-    dense einsum attention performs on the gathered view (test reference,
-    and the fallback when the Mosaic backend is unavailable)."""
+    dense einsum attention performs on the gathered view (the parity
+    reference)."""
     B, T, H, D = q.shape
     NB, bs, KV, _ = k_pool.shape
     S = bias.shape[3]

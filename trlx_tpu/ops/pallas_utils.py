@@ -1,18 +1,15 @@
 """Shared Pallas TPU plumbing for the repo's kernels.
 
 Every Pallas kernel module (``ops/flash_attention.py``,
-``ops/paged_attention.py``) needs the same three decisions made the same
-way, so they live here once:
+``ops/paged_attention.py``) needs the same decisions made the same way, so
+they live here once:
 
-- **Backend probe**: ``jax.experimental.pallas.tpu`` (Mosaic) is absent on
-  some CPU-only builds; kernels must import it guardedly and degrade to
-  generic Pallas (``pl.ANY`` memory spaces) when it is missing.
 - **Interpret-mode default**: off-TPU, kernels run under the Pallas
   interpreter — the same kernel body executed as traced jax ops, which is
   what makes the CPU tier-1 bit-parity tests meaningful (interpret-mode
-  ops are ordinary XLA ops on the same values).
-- **SMEM spec**: scalar operands live in SMEM on hardware; interpret mode
-  (and pltpu-less builds) take ``pl.ANY``.
+  ops are ordinary XLA ops on the same values). On a TPU every kernel
+  compiles through Mosaic or raises: nothing gives way to a reference.
+- **SMEM spec**: scalar operands live in SMEM.
 
 Masking convention shared by the kernels: masked scores are driven to
 ``NEG_INF`` (or carry the dense path's ``-1e9`` additive bias) so that
@@ -26,22 +23,16 @@ from typing import Optional
 import jax
 from jax.experimental import pallas as pl
 
-try:  # the pallas TPU backend is unavailable on some CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
     "pltpu",
     "NEG_INF",
     "LANES",
-    "has_pallas_tpu",
     "default_interpret",
     "resolve_interpret",
     "smem_spec",
+    "dot_precision",
     "pad_to",
     "align_rows",
     "clamp_block_table",
@@ -56,11 +47,6 @@ NEG_INF = -1e30
 LANES = 8
 
 
-def has_pallas_tpu() -> bool:
-    """True when the Mosaic (pallas TPU) backend is importable."""
-    return _HAS_PLTPU
-
-
 def default_interpret() -> bool:
     """Kernels compile for real only on TPU; every other backend runs the
     Pallas interpreter (bit-parity tests pin the interpret path on CPU)."""
@@ -73,10 +59,19 @@ def resolve_interpret(interpret: Optional[bool]) -> bool:
 
 
 def smem_spec() -> pl.BlockSpec:
-    """Whole-operand scalar spec: SMEM on hardware, ANY elsewhere."""
-    if _HAS_PLTPU:
-        return pl.BlockSpec(memory_space=pltpu.SMEM)
-    return pl.BlockSpec(memory_space=pl.ANY)
+    """Whole-operand scalar spec in SMEM."""
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def dot_precision(dtype):
+    """``precision=`` for an in-kernel dot on ``dtype`` operands: f32 follows
+    ``jax_default_matmul_precision`` like the dense path it mirrors; narrower
+    operands pin DEFAULT — one MXU pass is already exact on them, and Mosaic
+    refuses an fp32 contraction on bf16 ("Bad lhs type") when a caller has
+    raised the default."""
+    import jax.numpy as jnp
+
+    return None if dtype == jnp.float32 else jax.lax.Precision.DEFAULT
 
 
 def pad_to(x: jax.Array, mult: int, axis: int) -> jax.Array:
@@ -155,10 +150,6 @@ def paged_pool_grid_spec(
     (ISSUE 18) — the shape differences between decode (``q: (1, H, D)``)
     and prefill (``q: (1, T, H, D)``) are entirely in the block tuples.
     """
-    if not _HAS_PLTPU:  # pragma: no cover - callers gate on has_pallas_tpu
-        raise RuntimeError(
-            "paged_pool_grid_spec requires the Mosaic (pallas TPU) backend"
-        )
     pool_block = (1, block_size, kv_heads, head_dim)
 
     def pool_map(b, j, tbl):

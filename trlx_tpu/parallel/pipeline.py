@@ -207,7 +207,7 @@ def pipeline_blocks(
             )
         return h, branch_buf, new_stage_cache, aux_sum
 
-    stages = jax.vmap(stage_fn, in_axes=(0, 0, 0, 0, 0, 0, 0, 0, 0))
+    stages = jax.vmap(stage_fn, in_axes=(0, 0, 0, 0, 0, 0, 0, 0, 0), spmd_axis_name="pipe")
     stage_iota = jnp.arange(S)
 
     def tick(carry: _TickCarry, inputs):

@@ -265,9 +265,7 @@ def ring_flash_attention(
     """Exact attention with K/V rotating over the ``axis`` mesh ring.
 
     T must be divisible by ``mesh.shape[axis]``. Falls back to a single flash
-    call when the axis has size 1. Differentiable (custom ring VJP). Must be
-    called under ``jit`` when the ring is active: partially-manual shard_map
-    (``axis_names={axis}``) is unsupported in eager mode.
+    call when the axis has size 1. Differentiable (custom ring VJP).
 
     ``placement="auto"`` uses zigzag half-chunk placement whenever it pays
     (causal, T divisible by 2n) and contiguous otherwise.
@@ -317,32 +315,21 @@ def ring_flash_attention(
         axis, n, causal, alibi, zigzag, sm_scale, block_q, block_k, interpret,
         window,
     )
-    shard = P(None, axis, None, None)
-    in_specs = (shard, shard, shard, P(None, axis), P(None, axis), P(None, axis), P())
-    if hasattr(jax, "shard_map"):
-        f = jax.shard_map(
-            ring,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=shard,
-            axis_names={axis},
-            check_vma=False,
-        )
-    else:
-        # pre-0.5 jax: the public API lives in jax.experimental and spells
-        # partial-manual mode as the complement (`auto` = the axes that
-        # STAY automatic) instead of `axis_names`; `check_rep` is the old
-        # name of `check_vma`
-        from jax.experimental.shard_map import shard_map
+    # fully manual: GSPMD cannot partition the Mosaic kernels inside the
+    # ring, so every mesh axis is spelled out — sequence over the ring axis,
+    # batch and heads as attention_shard_axes lays them, the rest replicated
+    from trlx_tpu.parallel.sharding import attention_shard_axes
 
-        f = shard_map(
-            ring,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=shard,
-            check_rep=False,
-            auto=frozenset(mesh.axis_names) - {axis},
-        )
+    batch_axes, head_axis = attention_shard_axes(mesh, k.shape)
+    shard = P(batch_axes, axis, head_axis, None)
+    rows = P(batch_axes, axis)
+    f = jax.shard_map(
+        ring,
+        mesh=mesh,
+        in_specs=(shard, shard, shard, rows, rows, rows, P(head_axis)),
+        out_specs=shard,
+        check_vma=False,
+    )
     out = f(q, k, v, key_mask, qpos, kpos, slopes)
     if zigzag:
         out = jnp.take(out, inverse, axis=1)

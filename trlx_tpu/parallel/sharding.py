@@ -304,6 +304,20 @@ def fit_spec(mesh: Mesh, shape: Tuple[int, ...], spec: Tuple[Any, ...]) -> P:
     return P(*out)
 
 
+def attention_shard_axes(mesh: Mesh, kv_shape: Tuple[int, ...]):
+    """``(batch_axes, head_axis)`` for running an attention kernel per shard
+    inside a fully manual ``shard_map`` (GSPMD cannot partition a Mosaic
+    kernel): batch over ``data`` x ``fsdp`` and heads over ``model`` — the
+    layouts the q/k/v projections already produce — each kept only as far
+    as it divides. Fitted on the ``[B, S, KV, D]`` key tensor: attention is
+    independent per (row, head) and GQA groups are contiguous, so query
+    heads may shard exactly when the (smaller) kv-head count does."""
+    batch_axes, _, head_axis, _ = fit_spec(
+        mesh, kv_shape, (("data", "fsdp"), None, "model", None)
+    )
+    return batch_axes, head_axis
+
+
 def spec_to_jsonable(spec: P) -> list:
     """A PartitionSpec as JSON-safe nested lists (``None`` | axis name |
     list of names per dim) — the checkpoint topology manifest's per-leaf

