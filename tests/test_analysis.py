@@ -2520,7 +2520,7 @@ def test_tree_jit_surface_is_covered(tree_findings):
     ctx = AnalysisContext(TREE)
     g = ctx.callgraph
     root_names = {r.fn.qualname for r in g.jit_roots}
-    assert any("step_fn" in n for n in root_names)
+    assert any("_build_train_step.<locals>.train_step" in n for n in root_names)
     assert any("_get_score_fn" in n for n in root_names)
     assert any("decode_segment" in n for n in root_names)
     traced_mods = {f.module.modname for f in g.traced_functions()}

@@ -247,3 +247,35 @@ def test_flash_under_four_device_mesh(topo, monkeypatch):
     assert "tpu_custom_call" in text
     # heads are sharded over `model`: each device runs the kernel on 6 of 12
     assert f"{B // 2},{H // 2}," in text.replace(" ", "")
+
+
+def test_flash_kernels_keep_their_names_in_the_compiled_program(topo):
+    """A device trace's ``XLA Ops`` event is the instruction's text, and the
+    benchmark's ``flash_fwd_device_ms`` / ``flash_bwd_device_ms`` match on
+    its name. The TPU compiler names a custom call after the innermost
+    named scope: ``pallas_call(name=...)`` must win over the enclosing flax
+    scope (``%attn.N`` before the kernels had names), in the forward and in
+    the transposed backward."""
+    import json
+    import os
+    import re
+
+    from trlx_tpu.ops import flash_attention as fa
+
+    def loss(q, k, v, m):
+        with jax.named_scope("attn"):
+            return fa.flash_attention(q, k, v, m, interpret=False).astype(jnp.float32).sum()
+
+    x = _s((B, T, H, D))
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), (x, x, x, _s((B, T), jnp.float32)),
+                    SingleDeviceSharding(topo.devices[0]))
+    calls = [l.strip().removeprefix("ROOT ") for l in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in l]
+    assert len(calls) == 2
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for metric, kernel in (("flash_fwd_device_ms", fa.FWD_KERNEL_NAME),
+                           ("flash_bwd_device_ms", fa.BWD_KERNEL_NAME)):
+        with open(os.path.join(root, "chipbench", "layer_metrics", f"{metric}.json")) as f:
+            pattern = json.load(f)["pattern"]
+        hits = [c for c in calls if re.search(pattern, c)]
+        assert len(hits) == 1 and hits[0].startswith(f"%{kernel}"), (metric, calls)

@@ -1,6 +1,7 @@
 """Observability-layer coverage (CPU-only, fast tier).
 
-- spans: nesting in the Chrome/Perfetto export, device fencing, JSONL export;
+- spans: nesting in the Chrome/Perfetto export, device fencing, the
+  ``trlx/`` twin on the profiler's clock;
 - metrics: registry semantics, MFU math against a hand-computed fixture;
 - watchdogs: recompile detection on a shape-changing second call, memory
   gauge CPU fallback;
@@ -79,14 +80,66 @@ class TestTracer:
             with tracer.span("inner"):
                 pass
         trace_path = tracer.export_chrome_trace(str(tmp_path / "trace.json"))
-        jsonl_path = tracer.export_jsonl(str(tmp_path / "spans.jsonl"))
         trace = json.load(open(trace_path))
         assert {e["name"] for e in trace["traceEvents"]} == {"outer", "inner"}
         assert all(e["ph"] == "X" for e in trace["traceEvents"])
-        spans = [json.loads(l) for l in open(jsonl_path)]
-        assert {s["name"] for s in spans} == {"outer", "inner"}
-        outer = next(s for s in spans if s["name"] == "outer")
+        outer = next(e for e in trace["traceEvents"] if e["name"] == "outer")
         assert outer["args"] == {"step": 3}
+
+    def test_span_lands_on_the_profilers_host_plane(self, tmp_path):
+        """While a ``jax.profiler`` session is open every span, worker
+        threads' too, is a ``trlx/<name>`` event of the written xplane's
+        host plane, closed after the fence, with the span's args as stats."""
+        import glob
+        import threading
+
+        tracer = Tracer()
+        tracer.next_cycle()
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            with tracer.span("collect/experience"):
+                with tracer.span("generate", eval_mode=False) as sp:
+                    sp.fence(jax.jit(lambda a: a @ a)(jnp.ones((64, 64))))
+
+            def work():
+                with tracer.span("rollout/overlap"):
+                    pass
+
+            worker = threading.Thread(target=work)
+            worker.start()
+            worker.join(timeout=30)
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+        data = jax.profiler.ProfileData.from_file(path)
+        found = {}
+        for plane in data.planes:
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("trlx/"):
+                        assert plane.name.startswith("/host:")
+                        found[e.name] = (e, line.name)
+        assert set(found) == {"trlx/collect/experience", "trlx/generate", "trlx/rollout/overlap"}
+        outer, inner = found["trlx/collect/experience"][0], found["trlx/generate"][0]
+        assert outer.start_ns <= inner.start_ns
+        assert inner.start_ns + inner.duration_ns <= outer.start_ns + outer.duration_ns
+        # the annotation outlives the fenced span it mirrors
+        assert inner.duration_ns * 1e-9 >= sp.duration
+        assert dict(inner.stats) == {"cycle": 1, "eval_mode": 0}
+        # the tracer's own buffer is untouched by the mirror
+        assert {e["name"] for e in tracer.events()} == {
+            "collect/experience", "generate", "rollout/overlap"}
+
+    def test_span_without_a_profiler_session_is_inert(self, tmp_path):
+        tracer = Tracer()
+        with tracer.span("collect/experience", step=1) as sp:
+            pass
+        assert sp.duration >= 0 and [e["name"] for e in tracer.events()] == ["collect/experience"]
+        assert list(tmp_path.iterdir()) == []
+        with pytest.raises(RuntimeError):  # no session was ever open
+            jax.profiler.stop_trace()
 
     def test_event_buffer_is_bounded(self):
         tracer = Tracer(max_events=5)
@@ -625,8 +678,6 @@ def test_ppo_smoke_emits_throughput_and_trace(tmp_path):
         if r["ts"] <= g["ts"] and g["ts"] + g["dur"] <= r["ts"] + r["dur"] + 1e-3
     ]
     assert nested, "no generate span nested inside a rollout span"
-    # span stream export landed too
-    assert (tmp_path / "logs" / "spans.jsonl").exists()
     # distributed-telemetry gauges ride the stream even single-process
     # (skew degenerates to 0.0 over one rank) with the drop gauge beside
     assert "cluster/step_skew_s" in keys

@@ -6,7 +6,8 @@ programs, but nothing observed the *running* system. This subsystem closes
 that gap:
 
 - :mod:`tracing` — nestable, rank-aware spans with device fencing
-  (``block_until_ready`` at span exit) and JSONL + Chrome/Perfetto export;
+  (``block_until_ready`` at span exit), a Chrome/Perfetto export, and a
+  ``trlx/<name>`` twin of every span on the ``jax.profiler`` clock;
 - :mod:`metrics` — counters/gauges/histograms feeding the existing
   ``Tracker`` stream, plus tokens/sec / samples/sec / **MFU** derived by
   joining fenced step times against XLA ``cost_analysis`` flops of the
@@ -87,8 +88,8 @@ class Observability:
 
     Each trainer owns its own instance (no cross-trainer event bleed in a
     process that builds several). ``export()`` writes the span stream next
-    to the tracker's stats (``trace.json`` + ``spans.jsonl``), process 0
-    only — the same single-writer gating as the trackers.
+    to the tracker's stats (``trace.json``), process 0 only — the same
+    single-writer gating as the trackers.
     """
 
     def __init__(self, config: Any = None, trace_dir: Optional[str] = None):
@@ -164,7 +165,7 @@ class Observability:
             )
 
     def export(self, directory: Optional[str] = None) -> Dict[str, str]:
-        """Write ``trace.json`` (Chrome/Perfetto) and ``spans.jsonl``.
+        """Write ``trace.json`` (Chrome/Perfetto).
 
         Multihost: non-zero ranks write ``trace_rank<k>.json`` into the
         shared trace dir (and return {}); process 0 merges every rank's
@@ -201,12 +202,7 @@ class Observability:
             trace_path = self.tracer.export_chrome_trace(
                 os.path.join(directory, "trace.json")
             )
-        return {
-            "trace": trace_path,
-            "spans": self.tracer.export_jsonl(
-                os.path.join(directory, "spans.jsonl")
-            ),
-        }
+        return {"trace": trace_path}
 
     def dump_flight_record(
         self, reason: str, directory: Optional[str] = None

@@ -7,11 +7,17 @@ therefore carries an optional **fence**: a pytree of device arrays that is
 device-true execution time, not dispatch latency.
 
 Spans nest (a thread-local stack), are rank-aware (every event records
-``jax.process_index()`` as its Chrome-trace ``pid``), and export two ways:
+``jax.process_index()`` as its Chrome-trace ``pid``), and land in two places:
 
-- ``export_jsonl(path)`` — one JSON object per span, grep/pandas friendly;
-- ``export_chrome_trace(path)`` — Chrome/Perfetto ``trace.json`` (complete
-  ``"ph": "X"`` events; containment on one ``tid`` renders as nesting).
+- the tracer's own bounded buffer, exported by ``export_chrome_trace(path)``
+  as a Chrome/Perfetto ``trace.json`` (complete ``"ph": "X"`` events;
+  containment on one ``tid`` renders as nesting);
+- the profiler's clock: every span also opens a
+  ``jax.profiler.TraceAnnotation("trlx/<name>")`` for its whole life, fence
+  included, so that while a ``jax.profiler`` session is open (the
+  ``TRLX_TPU_PROFILE`` window, a benchmark's traced run) the span sits on the
+  trace's ``/host:CPU`` plane beside the device's ``XLA Ops``, from worker
+  threads too. With no session open the annotation is inert.
 
 Usage::
 
@@ -32,6 +38,9 @@ from typing import Any, Callable, Dict, Iterator, List, Optional, Union
 
 FenceLike = Union[None, Any, Callable[[], Any]]
 
+# prefix of every program span on the profiler's host plane
+PROFILER_PREFIX = "trlx/"
+
 
 def _process_index() -> int:
     # lazy: importing/initializing jax at module import would race the
@@ -48,6 +57,14 @@ def _block(tree: Any) -> None:
     import jax
 
     jax.block_until_ready(tree)
+
+
+def _profiler_annotation(name: str, args: Dict[str, Any]):
+    """The span's twin on the profiler's clock; the span's args ride along
+    as the event's stats. Costs a no-op C++ call while no session is open."""
+    import jax
+
+    return jax.profiler.TraceAnnotation(PROFILER_PREFIX + name, **args)
 
 
 class Span:
@@ -99,6 +116,10 @@ class Tracer:
         self.dropped = 0  # guarded-by: _lock
         self._events: List[Dict[str, Any]] = []  # guarded-by: _lock
         self._local = threading.local()
+        # the collect-plus-learn cycle the trainer is in (set by the main
+        # thread at the start of each collection): stamped into the args of
+        # every span opened meanwhile, on any thread
+        self.cycle: Optional[int] = None
         self._epoch = time.perf_counter()
         self._last_duration: Dict[str, float] = {}  # guarded-by: _lock
         # event listeners (the crash flight recorder): called for EVERY
@@ -118,6 +139,11 @@ class Tracer:
         return getattr(
             self._local, "tid", None
         ) or threading.get_ident() % 2**31
+
+    def next_cycle(self) -> None:
+        """Begin the next collect-plus-learn cycle (the trainer calls this
+        at the start of every collection)."""
+        self.cycle = (self.cycle or 0) + 1
 
     def alias_current_thread(self, alias: str) -> None:
         """Record this thread's events under a stable pseudo-tid derived
@@ -140,23 +166,26 @@ class Tracer:
         bare call that stashes (or discards) the context manager without
         entering it leaks the open span and is a finding."""
         stack = self._stack()
-        sp = Span(name, depth=len(stack), args=args)
-        if fence is not None:
-            sp.fence(fence)
-        stack.append(sp)
-        try:
-            yield sp
-        finally:
-            # remove *this* span (not blindly the top): an exception that
-            # unwinds past a manually-entered inner span must not corrupt
-            # the depth bookkeeping of outer spans
-            if sp in stack:
-                stack.remove(sp)
-            dur = sp.close()
-            with self._lock:  # worker + main thread both close spans
-                self._last_duration[name] = dur
-            if self.enabled:
-                self._record(sp)
+        if self.cycle is not None:
+            args.setdefault("cycle", self.cycle)
+        with _profiler_annotation(name, args):  # closes after the fence
+            sp = Span(name, depth=len(stack), args=args)
+            if fence is not None:
+                sp.fence(fence)
+            stack.append(sp)
+            try:
+                yield sp
+            finally:
+                # remove *this* span (not blindly the top): an exception that
+                # unwinds past a manually-entered inner span must not corrupt
+                # the depth bookkeeping of outer spans
+                if sp in stack:
+                    stack.remove(sp)
+                dur = sp.close()
+                with self._lock:  # worker + main thread both close spans
+                    self._last_duration[name] = dur
+                if self.enabled:
+                    self._record(sp)
 
     def instant(self, name: str, **args: Any) -> None:
         """A zero-duration marker event (Chrome-trace ``"ph": "i"``)."""
@@ -281,24 +310,6 @@ class Tracer:
         os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
         with open(path, "w") as f:
             json.dump(self.to_chrome_trace(), f)
-        return path
-
-    def export_jsonl(self, path: str) -> str:
-        os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
-        with open(path, "w") as f:
-            for e in self.events():
-                if e.get("ph") == "M":  # metadata (thread names): trace-only
-                    continue
-                record = {
-                    "name": e["name"],
-                    "start_s": e["ts"] / 1e6,
-                    "dur_s": e.get("dur", 0.0) / 1e6,
-                    "pid": e["pid"],
-                    "tid": e["tid"],
-                }
-                if "args" in e:
-                    record["args"] = e["args"]
-                f.write(json.dumps(record) + "\n")
         return path
 
 

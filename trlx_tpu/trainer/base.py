@@ -19,7 +19,8 @@ at the host boundary (reward/metric fns), and per-rank scatter is
 import json
 import os
 from abc import abstractmethod
-from time import time
+from contextlib import ExitStack
+from time import perf_counter, time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import flax.struct
@@ -319,6 +320,9 @@ class TPUBaseTrainer(BaseRLTrainer):
         self.draft_module = self.draft_params = self.draft_tcfg = None
         self.last_spec_stats: Dict[str, float] = {}
         self.last_generate_time = 0.0
+        # where the host gap before the next train step began (perf_counter):
+        # the end of the last step's fence, or of the collection before it
+        self._host_gap_t0: Optional[float] = None
         if config.model.draft_model_path and self.is_seq2seq:
             logger.warning(
                 "model.draft_model_path is ignored for seq2seq models: "
@@ -617,7 +621,8 @@ class TPUBaseTrainer(BaseRLTrainer):
             # per-trainer loss key varies; callers only consume stats
             return (jnp.zeros(()), stats), grads
 
-        def step_fn(state: TrainState, batch: Dict[str, jax.Array], loss_scale):
+        # named for the device trace: the program is `jit_train_step` there
+        def train_step(state: TrainState, batch: Dict[str, jax.Array], loss_scale):
             rng, step_rng = jax.random.split(state.rng)
             if accum == 1:
                 (loss, stats), grads = grads_of(
@@ -663,9 +668,9 @@ class TPUBaseTrainer(BaseRLTrainer):
         if state_shardings is not None:
             # stats stay unspecified (None): XLA picks, as before
             return jax.jit(
-                step_fn, donate_argnums=(0,), out_shardings=(state_shardings, None)
+                train_step, donate_argnums=(0,), out_shardings=(state_shardings, None)
             )
-        return jax.jit(step_fn, donate_argnums=(0,))
+        return jax.jit(train_step, donate_argnums=(0,))
 
     def _drop_batch_memo(self) -> None:
         """Release the memoized sharded batch (one batch of HBM) once its
@@ -706,27 +711,34 @@ class TPUBaseTrainer(BaseRLTrainer):
             self._prompt_chunks_drawn += 1
             yield chunk
 
-    def _batch_token_count(self, batch: Any) -> int:
-        """Real (unpadded) tokens this batch feeds the step — from the batch
-        masks, so padding doesn't inflate ``throughput/tokens_per_sec``."""
+    def _batch_token_counts(self, batch: Any) -> Tuple[int, int]:
+        """``(real, fed)`` tokens of a host batch: the unpadded tokens its
+        masks count (so padding doesn't inflate
+        ``throughput/tokens_per_sec``) and the rows × width slots the step is
+        fed; ``learn/pad_frac`` is one minus their ratio."""
         items = batch._asdict() if hasattr(batch, "_asdict") else batch
         if not isinstance(items, dict):
-            return 0
+            return 0, 0
         if "attention_mask" in items:
-            return int(np.asarray(items["attention_mask"]).sum())
-        masks = [
-            v for k, v in items.items() if k.endswith("mask") and hasattr(v, "sum")
-        ]
+            masks = [items["attention_mask"]]
+        else:
+            masks = [
+                v for k, v in items.items() if k.endswith("mask") and hasattr(v, "sum")
+            ]
         if masks:
-            return int(sum(np.asarray(m).sum() for m in masks))
+            return (
+                int(sum(np.asarray(m).sum() for m in masks)),
+                int(sum(np.asarray(m).size for m in masks)),
+            )
         for v in items.values():
             if hasattr(v, "shape") and len(v.shape) >= 2:
-                return int(v.shape[0] * v.shape[1])
-        return 0
+                fed = int(v.shape[0] * v.shape[1])
+                return fed, fed
+        return 0, 0
 
     def _export_observability(self) -> None:
-        """Best-effort span export (``trace.json`` + ``spans.jsonl``) next to
-        the tracker's stats — never allowed to fail a training run."""
+        """Best-effort span export (``trace.json``) next to the tracker's
+        stats — never allowed to fail a training run."""
         try:
             paths = self.obs.export()
             if paths:
@@ -766,9 +778,10 @@ class TPUBaseTrainer(BaseRLTrainer):
             arrays = self._last_batch_sharded
         else:
             items = batch._asdict() if hasattr(batch, "_asdict") else batch
-            arrays = shard_batch(
-                {k: v for k, v in items.items() if hasattr(v, "ndim")}, self.mesh
-            )
+            with self.obs.span("learn/loader", stage="shard"):
+                arrays = shard_batch(
+                    {k: v for k, v in items.items() if hasattr(v, "ndim")}, self.mesh
+                )
             self._last_batch_host = batch
             self._last_batch_sharded = arrays
         self.state, stats = self._train_step_fn(self.state, arrays, self._loss_scale())
@@ -902,7 +915,7 @@ class TPUBaseTrainer(BaseRLTrainer):
                         method=type(module).decode,
                     )
 
-                def fn(params, input_ids, attention_mask, rng):
+                def rollout_generate(params, input_ids, attention_mask, rng):
                     return generate_seq2seq(
                         encode_fn,
                         decode_fn,
@@ -936,7 +949,7 @@ class TPUBaseTrainer(BaseRLTrainer):
                 def draft_apply(p, ids, **kw):
                     return draft_module.apply({"params": p}, ids, **kw)
 
-                def fn(params, input_ids, attention_mask, rng):
+                def rollout_generate(params, input_ids, attention_mask, rng):
                     # first arg is the target params, or the engine's
                     # (target, draft) tuple — the tuple form keeps draft
                     # params a traced operand instead of a closure, which
@@ -967,7 +980,7 @@ class TPUBaseTrainer(BaseRLTrainer):
                 tcfg = self.tcfg
                 adjust = self._compose_logit_mask(algo_adjust)
 
-                def fn(params, input_ids, attention_mask, rng):
+                def rollout_generate(params, input_ids, attention_mask, rng):
                     return generate(
                         apply_fn,
                         params,
@@ -979,7 +992,9 @@ class TPUBaseTrainer(BaseRLTrainer):
                         adjust_logits=adjust,
                     )
 
-            self._generate_fns[key] = jax.jit(fn)
+            # the def's name is the program's (module `jit_rollout_generate`
+            # in a device trace): trace reductions match on it
+            self._generate_fns[key] = jax.jit(rollout_generate)
         return self._generate_fns[key]
 
     def _resolve_gen_config(
@@ -1199,36 +1214,38 @@ class TPUBaseTrainer(BaseRLTrainer):
             attention_mask = (input_ids != self.tokenizer.pad_token_id).astype(np.int32)
         if rng is None:
             self._rollout_rng, rng = jax.random.split(self._rollout_rng)
-        # the serial dense path behind the unified Engine interface
-        # (trlx_tpu/engine/core.py) — the wrapped jitted program is
-        # unchanged: it stays the bit-equivalence reference for the
-        # continuous-batching and paged backends. The params-override path
-        # (async actor threads) gets a PER-THREAD engine wrapper: engines
-        # carry mutable `params`, and an actor generating concurrently with
-        # the learner's eval on one shared wrapper would clobber each
-        # other's params mid-call (the compiled program underneath is still
-        # shared via _get_generate_fn's cache — wrappers are thin).
-        if params is not None:
-            import threading as _threading
-
-            engine = self._get_serial_engine(
-                gen_config, extra_kwargs, tag=_threading.get_ident()
-            )
-            engine.params = params
-        else:
-            engine = self._get_serial_engine(gen_config, extra_kwargs)
-        batch = shard_batch(
-            {"input_ids": input_ids, "attention_mask": np.asarray(attention_mask, np.int32)},
-            self.mesh,
-        )
-        # cleared up front so stats only ever reflect the *current* rollout
-        # path — a draft-less or seq2seq generate must not keep reporting a
-        # stale acceptance rate from an earlier speculative call
-        self.last_spec_stats = {}
-        self._note_dense_kv_gauge(input_ids.shape, gen_config)
         # fenced span: duration is device-true decode time, not dispatch
-        # latency (nests under make_experience's "rollout" span)
+        # latency (nests under make_experience's "rollout" span). It opens
+        # before the host-side set-up of the call (engine lookup, placing the
+        # prompts): the device waits through that too
         with self.obs.span("generate", eval_mode=bool(eval_mode)) as sp:
+            # the serial dense path behind the unified Engine interface
+            # (trlx_tpu/engine/core.py) — the wrapped jitted program is
+            # unchanged: it stays the bit-equivalence reference for the
+            # continuous-batching and paged backends. The params-override path
+            # (async actor threads) gets a PER-THREAD engine wrapper: engines
+            # carry mutable `params`, and an actor generating concurrently with
+            # the learner's eval on one shared wrapper would clobber each
+            # other's params mid-call (the compiled program underneath is still
+            # shared via _get_generate_fn's cache — wrappers are thin).
+            if params is not None:
+                import threading as _threading
+
+                engine = self._get_serial_engine(
+                    gen_config, extra_kwargs, tag=_threading.get_ident()
+                )
+                engine.params = params
+            else:
+                engine = self._get_serial_engine(gen_config, extra_kwargs)
+            batch = shard_batch(
+                {"input_ids": input_ids, "attention_mask": np.asarray(attention_mask, np.int32)},
+                self.mesh,
+            )
+            # cleared up front so stats only ever reflect the *current* rollout
+            # path — a draft-less or seq2seq generate must not keep reporting a
+            # stale acceptance rate from an earlier speculative call
+            self.last_spec_stats = {}
+            self._note_dense_kv_gauge(input_ids.shape, gen_config)
             out = engine.generate(batch["input_ids"], batch["attention_mask"], rng)
             if type(out) is tuple:  # speculative sampler: (output, stats) —
                 # GenerationOutput itself is a NamedTuple, hence the exact check
@@ -1447,8 +1464,10 @@ class TPUBaseTrainer(BaseRLTrainer):
         self.maybe_resume()
         self._maybe_start_serving()
         try:
-            with self.resilience.preemption:
-                return self._learn_loop()
+            # step_host holds the open `learn/step_host` span between two
+            # train steps; every way out of the loop closes it
+            with self.resilience.preemption, ExitStack() as step_host:
+                return self._learn_loop(step_host)
         except BaseException as e:
             # crash-safe shutdown: without this, an exception loses every
             # buffered tracker record and the whole Perfetto trace — and
@@ -1773,7 +1792,19 @@ class TPUBaseTrainer(BaseRLTrainer):
             checkpoint_dir=path,
         )
 
-    def _learn_loop(self) -> Dict[str, Any]:  # noqa: C901
+    def _spanned(self, iterable, name: str, **args):
+        """``iterable`` with every ``next()`` inside a span (the learn
+        loop's collation, or its wait on the prefetch thread)."""
+        it = iter(iterable)
+        while True:
+            with self.obs.span(name, **args):
+                try:
+                    item = next(it)
+                except StopIteration:
+                    return
+            yield item
+
+    def _learn_loop(self, step_host: ExitStack) -> Dict[str, Any]:  # noqa: C901
         # Emergency resume: the checkpoint froze the run between two
         # updates. Fast-forward the loop to that exact boundary — skipped
         # slots run no device work, no eval, no callbacks (all of that
@@ -1795,6 +1826,8 @@ class TPUBaseTrainer(BaseRLTrainer):
             self.tracker.log(results, step=self.iter_count)
             self._report_sweep(results)
         clock = Clock()
+        if self._host_gap_t0 is None:  # no collection came before the loop
+            self._host_gap_t0 = perf_counter()
 
         tbar = logging.tqdm(
             initial=self.iter_count,
@@ -1827,7 +1860,9 @@ class TPUBaseTrainer(BaseRLTrainer):
                         self.train_dataloader.advance_epoch()
                     continue
             epoch_ran = False
-            for batch in self._maybe_prefetch(self.train_dataloader):
+            for batch in self._spanned(
+                self._maybe_prefetch(self.train_dataloader), "learn/loader", stage="collate"
+            ):
                 batch_ran = False
                 for _ in range(self.n_updates_per_batch):
                     if done < skip_target:
@@ -1836,14 +1871,20 @@ class TPUBaseTrainer(BaseRLTrainer):
                     batch_ran = epoch_ran = True
                     self._check_faults_and_preemption()
                     profile.on_step_start(self.iter_count)
+                    step_host.close()  # the host gap ends where the step begins
                     with profile.step_annotation("train", self.iter_count):
                         with self.obs.span("train_step") as sp:
+                            step_gap = sp.t0 - self._host_gap_t0
                             device_stats = self.train_step(batch)
                             # fence on the new state AND the stat outputs:
                             # the donated-state update can still be in
                             # flight after the stats land, and without any
                             # fence the timer reads async dispatch latency
                             sp.fence((self.state, device_stats))
+                    self._host_gap_t0 = sp.t1
+                    # everything the host does until the next step's span
+                    # opens, or until the post-epoch collection
+                    step_host.enter_context(self.obs.span("learn/step_host"))
                     host_stats = to_host(device_stats)
                     stats = filter_non_scalars(host_stats)
                     # collapse the on-device distribution sketches into
@@ -1867,13 +1908,21 @@ class TPUBaseTrainer(BaseRLTrainer):
                     step_time = sp.duration
                     stats["time/step"] = step_time
                     stats["time/train_step"] = step_time
+                    # host time between the previous fence (or the end of
+                    # the collection) and this step's span: with
+                    # time/train_step it tiles the learn phase
+                    stats["time/step_gap"] = step_gap
+                    real_tokens, fed_tokens = self._batch_token_counts(batch)
+                    stats["learn/pad_frac"] = (
+                        1.0 - real_tokens / fed_tokens if fed_tokens else 0.0
+                    )
                     batch_size = next(
                         v.shape[0] for v in batch.values() if hasattr(v, "shape")
                     ) if isinstance(batch, dict) else self.config.train.batch_size
                     stats.update(
                         self.obs.throughput.step_stats(
                             step_time,
-                            tokens=self._batch_token_count(batch),
+                            tokens=real_tokens,
                             samples=batch_size,
                             flops_per_device=self._ensure_train_step_flops(
                                 self._last_batch_sharded
@@ -2003,7 +2052,9 @@ class TPUBaseTrainer(BaseRLTrainer):
                     self.post_backward_callback()  # their callback pre-checkpoint
             if epoch_ran:
                 self._drop_batch_memo()  # free the batch's HBM before rollouts
-                self.post_epoch_callback()
+                step_host.close()
+                with self.obs.span("learn/post_epoch"):
+                    self.post_epoch_callback()
         profile.stop()
         tbar.close()
         wait_for_saves()  # async saves must land before exit

@@ -117,17 +117,14 @@ class GRPOTrainer(PPOTrainer):
         response_mask: np.ndarray,
         elements: list,
         agg: Dict[str, Any],
-        score_out=None,  # pre-dispatched scoring outputs (serial path)
     ) -> None:
-        """Score + store one group-contiguous batch — the shared tail of the
-        serial chunk loop and the continuous-batching group flush, composed
-        from the produce/finalize halves the async actor/learner split also
-        uses (produce runs on the actor, finalize on the learner)."""
+        """Score + store one group-contiguous batch — the continuous-batching
+        group flush, composed from the produce/finalize halves the serial
+        chunk loop and the async actor/learner split also use (produce runs
+        on the actor, finalize on the learner)."""
         chunk = self._grpo_chunk_produce(
-            prompt_ids, prompt_mask, response_tokens, response_mask,
-            score_out=score_out,
+            prompt_ids, prompt_mask, response_tokens, response_mask
         )
-        agg["score_time_sum"] += chunk["score_s"]
         self._grpo_chunk_finalize(chunk, elements, agg)
 
     def _grpo_chunk_produce(
@@ -157,13 +154,14 @@ class GRPOTrainer(PPOTrainer):
         samples, prompts, outputs = self.decode(
             prompt_ids, response_tokens, append_eos_token=True
         )
-        score_time = perf_counter()
-        scores = np.asarray(
-            self.reward_fn(samples=samples, prompts=prompts, outputs=outputs),
-            dtype=np.float32,
-        )
-        score_s = perf_counter() - score_time
+        with self.obs.span("reward") as reward_sp:
+            scores = np.asarray(
+                self.reward_fn(samples=samples, prompts=prompts, outputs=outputs),
+                dtype=np.float32,
+            )
+        wait_t0 = perf_counter()
         host = to_host(score_out)
+        score_wait_s = perf_counter() - wait_t0
         return {
             "prompt_ids": prompt_ids,
             "prompt_mask": prompt_mask,
@@ -171,7 +169,8 @@ class GRPOTrainer(PPOTrainer):
             "response_mask": response_mask,
             "scores": scores,
             "host": host,
-            "score_s": score_s,
+            "score_s": reward_sp.duration,  # reward_fn's host time
+            "score_wait_s": score_wait_s,  # blocked on the scoring outputs
         }
 
     def _grpo_chunk_finalize(
@@ -179,56 +178,59 @@ class GRPOTrainer(PPOTrainer):
     ) -> None:
         """Learner-side ordered tail: reward clipping, running moments,
         group-relative advantages, KL logging, element construction."""
-        method: GRPOConfig = self.config.method
-        G = method.group_size
-        prompt_ids = chunk["prompt_ids"]
-        prompt_mask = chunk["prompt_mask"]
-        response_tokens = chunk["response_tokens"]
-        response_mask = chunk["response_mask"]
-        scores = chunk["scores"]
-        host = chunk["host"]
-        B = prompt_ids.shape[0]
+        with self.obs.span("collect/finalize"):
+            agg["score_time_sum"] += chunk["score_s"]
+            agg["blocked_s"] += chunk["score_s"] + chunk["score_wait_s"]
+            method: GRPOConfig = self.config.method
+            G = method.group_size
+            prompt_ids = chunk["prompt_ids"]
+            prompt_mask = chunk["prompt_mask"]
+            response_tokens = chunk["response_tokens"]
+            response_mask = chunk["response_mask"]
+            scores = chunk["scores"]
+            host = chunk["host"]
+            B = prompt_ids.shape[0]
 
-        clip = method.cliprange_reward
-        if clip:
-            scores = np.clip(scores, -clip, clip)
-        self.running_moments.update(scores)  # logging only: the group
-        # normalization below IS the reward scaling in GRPO
-        agg["all_scores"].append(scores)
-        advantages = group_advantages_np(
-            scores, G, method.scale_advantage, baseline=method.baseline
-        )
-
-        # reference KL for logging (the loss recomputes it on device);
-        # to_host already landed numpy arrays — no further conversion
-        lp, rlp = host["logprobs"], host["ref_logprobs"]
-        delta = (rlp - lp) * response_mask
-        n_tok = max(response_mask.sum(), 1)
-        mean_kl = float(((np.exp(delta) - delta - 1.0) * response_mask).sum() / n_tok)
-        agg["kl_sum"] += mean_kl
-        agg["kl_batches"] += 1
-
-        behavior = chunk.get("behavior_logprobs")
-        if method.iw_correction == "off":
-            behavior = None
-        for i in range(B):
-            n_i = int(response_mask[i].sum())
-            if n_i == 0:
-                continue
-            elements.append(
-                GRPORLElement(
-                    query_tensor=prompt_ids[i][prompt_mask[i] > 0],
-                    response_tensor=response_tokens[i, :n_i],
-                    logprobs=lp[i, :n_i],
-                    ref_logprobs=rlp[i, :n_i],
-                    advantage=float(advantages[i]),
-                    behavior_logprobs=(
-                        np.asarray(behavior[i, :n_i], np.float32)
-                        if behavior is not None
-                        else None
-                    ),
-                )
+            clip = method.cliprange_reward
+            if clip:
+                scores = np.clip(scores, -clip, clip)
+            self.running_moments.update(scores)  # logging only: the group
+            # normalization below IS the reward scaling in GRPO
+            agg["all_scores"].append(scores)
+            advantages = group_advantages_np(
+                scores, G, method.scale_advantage, baseline=method.baseline
             )
+
+            # reference KL for logging (the loss recomputes it on device);
+            # to_host already landed numpy arrays — no further conversion
+            lp, rlp = host["logprobs"], host["ref_logprobs"]
+            delta = (rlp - lp) * response_mask
+            n_tok = max(response_mask.sum(), 1)
+            mean_kl = float(((np.exp(delta) - delta - 1.0) * response_mask).sum() / n_tok)
+            agg["kl_sum"] += mean_kl
+            agg["kl_batches"] += 1
+
+            behavior = chunk.get("behavior_logprobs")
+            if method.iw_correction == "off":
+                behavior = None
+            for i in range(B):
+                n_i = int(response_mask[i].sum())
+                if n_i == 0:
+                    continue
+                elements.append(
+                    GRPORLElement(
+                        query_tensor=prompt_ids[i][prompt_mask[i] > 0],
+                        response_tensor=response_tokens[i, :n_i],
+                        logprobs=lp[i, :n_i],
+                        ref_logprobs=rlp[i, :n_i],
+                        advantage=float(advantages[i]),
+                        behavior_logprobs=(
+                            np.asarray(behavior[i, :n_i], np.float32)
+                            if behavior is not None
+                            else None
+                        ),
+                    )
+                )
 
     def _grpo_collect_serial(
         self, num_rollouts: int, elements: list, agg: Dict[str, Any]
@@ -238,48 +240,49 @@ class GRPOTrainer(PPOTrainer):
         method: GRPOConfig = self.config.method
         G = method.group_size
         while len(elements) < num_rollouts:
-            batch = next(self.prompt_iterator)
-            prompt_ids = np.repeat(np.asarray(batch["input_ids"], np.int32), G, axis=0)
-            prompt_mask = np.repeat(
-                np.asarray(batch["attention_mask"], np.int32), G, axis=0
-            )
+            prompt_ids, prompt_mask = self._next_prompt_chunk(repeat=G)
 
             gen_time = perf_counter()
             gen_out = self.generate(prompt_ids, prompt_mask)
-            # dispatch the scoring forward on the generation's device arrays
-            # FIRST: it needs nothing from the host, so it runs while the
-            # generation outputs land and reward_fn scores them
-            B, P = prompt_ids.shape
-            N = int(gen_out.response_tokens.shape[1])
-            score_out = self._dispatch_score(
-                (B, P, N),
-                gen_out.sequences,
-                prompt_mask,
-                gen_out.response_tokens,
-                gen_out.response_mask,
-            )
-            host_gen = to_host(
-                {
-                    "response_tokens": gen_out.response_tokens,
-                    "response_mask": gen_out.response_mask,
-                }
-            )
-            response_tokens = host_gen["response_tokens"]
-            response_mask = host_gen["response_mask"]
-            agg["gen_time_sum"] += perf_counter() - gen_time
+            agg["generate_s"] += self.last_generate_time
+            # the scoring forward, dispatch to host landing; reward_fn and
+            # the token copy run inside it, while the device scores
+            with self.obs.span("score") as score_sp:
+                # dispatch on the generation's device arrays FIRST: it needs
+                # nothing from the host, so it runs while the generation
+                # outputs land and reward_fn scores them
+                B, P = prompt_ids.shape
+                N = int(gen_out.response_tokens.shape[1])
+                score_out = self._dispatch_score(
+                    (B, P, N),
+                    gen_out.sequences,
+                    prompt_mask,
+                    gen_out.response_tokens,
+                    gen_out.response_mask,
+                )
+                host_gen = to_host(
+                    {
+                        "response_tokens": gen_out.response_tokens,
+                        "response_mask": gen_out.response_mask,
+                    }
+                )
+                response_tokens = host_gen["response_tokens"]
+                response_mask = host_gen["response_mask"]
+                agg["gen_time_sum"] += perf_counter() - gen_time
+                chunk = self._grpo_chunk_produce(
+                    prompt_ids, prompt_mask, response_tokens, response_mask,
+                    score_out=score_out,
+                )
+            agg["score_span_s"] += score_sp.duration
             # slot accounting (docs/PERFORMANCE.md): this chunk's decode ran
             # max(n_i) steps over B slots — same mask-derived gauges as
             # PPO's chunked paths, so a serial-vs-CB A/B compares them
             n_per_row = response_mask.sum(axis=1)
-            agg["slot_steps"] += int(response_mask.shape[0]) * (
-                int(n_per_row.max()) if n_per_row.size else 0
-            )
+            decode_steps = int(n_per_row.max()) if n_per_row.size else 0
+            agg["decode_steps"] += decode_steps
+            agg["slot_steps"] += int(response_mask.shape[0]) * decode_steps
             agg["live_slot_steps"] += int(n_per_row.sum())
-
-            self._grpo_score_batch(
-                prompt_ids, prompt_mask, response_tokens, response_mask,
-                elements, agg, score_out=score_out,
-            )
+            self._grpo_chunk_finalize(chunk, elements, agg)
 
     def _grpo_collect_continuous(
         self, num_rollouts: int, elements: list, agg: Dict[str, Any]
@@ -357,6 +360,7 @@ class GRPOTrainer(PPOTrainer):
                 flush(groups_per_batch)
 
         agg["gen_time_sum"] += engine.stats.decode_s + engine.stats.refill_s
+        agg["generate_s"] += engine.stats.decode_s  # as PPO's engine path reports it
         agg["engine_stats"] = engine.stats
 
     def _store_element_cls(self) -> type:
@@ -420,7 +424,6 @@ class GRPOTrainer(PPOTrainer):
         collector.begin_collection()
         while len(elements) < num_rollouts:
             chunk = collector.next_chunk()
-            agg["score_time_sum"] += chunk.payload["score_s"]
             self._grpo_chunk_finalize(chunk.payload, elements, agg)
             mask = chunk.payload["response_mask"]
             n_per_row = mask.sum(axis=1)
@@ -445,50 +448,70 @@ class GRPOTrainer(PPOTrainer):
             "kl_sum": 0.0, "kl_batches": 0, "all_scores": [],
             "gen_time_sum": 0.0, "score_time_sum": 0.0,
             "slot_steps": 0, "live_slot_steps": 0,
+            # fenced generate spans, score spans, decode steps, and what the
+            # producing thread spent in reward_fn or waiting for scoring outputs
+            "generate_s": 0.0, "score_span_s": 0.0, "decode_steps": 0,
+            "blocked_s": 0.0,
         }
-        exp_time = perf_counter()
+        self.obs.tracer.next_cycle()
+        with self.obs.span("collect/experience"):
+            exp_time = perf_counter()
 
-        if bool(self.config.async_rl.enabled):
-            self._collect_async_grpo(num_rollouts, elements, agg)
-        elif bool(getattr(self.config.train, "continuous_batching", False)):
-            self._grpo_collect_continuous(num_rollouts, elements, agg)
-        else:
-            self._grpo_collect_serial(num_rollouts, elements, agg)
+            if bool(self.config.async_rl.enabled):
+                self._collect_async_grpo(num_rollouts, elements, agg)
+            elif bool(getattr(self.config.train, "continuous_batching", False)):
+                self._grpo_collect_continuous(num_rollouts, elements, agg)
+            else:
+                self._grpo_collect_serial(num_rollouts, elements, agg)
 
-        self.mean_kl = agg["kl_sum"] / max(agg["kl_batches"], 1)
-        stats["policy/sqrt_ref_kl"] = float(np.sqrt(max(self.mean_kl, 0.0)))
-        stats["time/exp_generate"] = agg["gen_time_sum"]
-        stats.update(self.last_spec_stats)
-        stats["time/exp_score"] = agg["score_time_sum"]
-        all_scores = agg["all_scores"]
-        pooled = np.concatenate(all_scores) if all_scores else np.zeros((0,), np.float32)
-        stats["exp_scores/mean"] = float(pooled.mean()) if pooled.size else 0.0
-        stats["exp_scores/std"] = float(pooled.std()) if pooled.size else 0.0
-        if "async_stats" in agg:
-            stats.update(agg["async_stats"])
-        engine_stats = agg.get("engine_stats")
-        if engine_stats is not None:
-            engine_metrics = engine_stats.metrics()
-            stats.update(engine_metrics)
-            # EngineStats snapshot into the crash flight recorder (same as
-            # the PPO continuous path)
-            self.obs.flightrec.record("engine_stats", engine_metrics)
-        elif agg["slot_steps"]:
-            # mask-derived slot gauges on the serial path (the CB branch
-            # reports the engine's exact counters above)
-            stats["throughput/slot_utilization"] = (
-                agg["live_slot_steps"] / agg["slot_steps"]
-            )
-            stats["rollout/padded_decode_frac"] = (
-                1.0 - agg["live_slot_steps"] / agg["slot_steps"]
-            )
-        stats["time/exp"] = perf_counter() - exp_time
-        self.make_experience_stats = stats
-        self.tracker.log(stats, step=iter_count)
+            with self.obs.span("collect/finalize", stage="collection"):
+                self.mean_kl = agg["kl_sum"] / max(agg["kl_batches"], 1)
+                stats["policy/sqrt_ref_kl"] = float(np.sqrt(max(self.mean_kl, 0.0)))
+                stats["time/exp_generate"] = agg["gen_time_sum"]
+                stats.update(self.last_spec_stats)
+                stats["time/exp_score"] = agg["score_time_sum"]
+                all_scores = agg["all_scores"]
+                pooled = np.concatenate(all_scores) if all_scores else np.zeros((0,), np.float32)
+                stats["exp_scores/mean"] = float(pooled.mean()) if pooled.size else 0.0
+                stats["exp_scores/std"] = float(pooled.std()) if pooled.size else 0.0
+                if "async_stats" in agg:
+                    stats.update(agg["async_stats"])
+                engine_stats = agg.get("engine_stats")
+                if engine_stats is not None:
+                    engine_metrics = engine_stats.metrics()
+                    stats.update(engine_metrics)
+                    # EngineStats snapshot into the crash flight recorder (same as
+                    # the PPO continuous path)
+                    self.obs.flightrec.record("engine_stats", engine_metrics)
+                elif agg["slot_steps"]:
+                    # mask-derived slot gauges on the serial path (the CB branch
+                    # reports the engine's exact counters above)
+                    stats["throughput/slot_utilization"] = (
+                        agg["live_slot_steps"] / agg["slot_steps"]
+                    )
+                    stats["rollout/padded_decode_frac"] = (
+                        1.0 - agg["live_slot_steps"] / agg["slot_steps"]
+                    )
+                self._host_gap_t0 = perf_counter()  # the first step's gap starts here
+                total = self._host_gap_t0 - exp_time
+                stats["time/exp"] = total
+                # the same collection keys as PPO publishes (trainer/ppo.py), each a
+                # sum over the collection's chunks
+                stats["time/generate"] = agg["generate_s"]
+                stats["time/score"] = agg["score_span_s"]
+                stats["time/reward"] = agg["score_time_sum"]
+                stats["time/collect_host"] = max(
+                    0.0, total - agg["generate_s"] - agg["blocked_s"]
+                )
+                stats["rollout/decode_steps"] = float(agg["decode_steps"])
+                if agg["decode_steps"]:
+                    stats["time/decode_step"] = agg["generate_s"] / agg["decode_steps"]
+                self.make_experience_stats = stats
+                self.tracker.log(stats, step=iter_count)
 
-        self.store.push(elements[:num_rollouts] if num_rollouts else elements)
-        if self.log_rollouts:
-            self.store.export_history(location=self.rollout_logging_dir)
+                self.store.push(elements[:num_rollouts] if num_rollouts else elements)
+                if self.log_rollouts:
+                    self.store.export_history(location=self.rollout_logging_dir)
 
     def loss_fn(
         self, params: Any, batch: Dict[str, jax.Array], rng: jax.Array

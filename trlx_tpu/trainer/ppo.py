@@ -52,6 +52,16 @@ from trlx_tpu.utils.stats import RunningMoments, logprobs_of_labels
 logger = logging.get_logger(__name__)
 
 
+def _add_times(stats: Dict[str, float], chunk_stats: Dict[str, float]) -> None:
+    """Fold one chunk's stats into the collection's: ``time/*`` keys add up
+    (a share of the collection's wall time is only right for a sum), any
+    other key keeps the newest chunk's value."""
+    for key, value in chunk_stats.items():
+        if key.startswith("time/"):
+            value = stats.get(key, 0.0) + value
+        stats[key] = value
+
+
 @register_trainer
 class PPOTrainer(TPUBaseTrainer):
     model_head = "value"
@@ -423,10 +433,20 @@ class PPOTrainer(TPUBaseTrainer):
     def _rollout_chunk_device(self, stats: Dict[str, float]) -> Dict[str, Any]:
         """Main-thread device side of one chunk: prompt fetch, generation,
         and the scoring-forward dispatch with async device→host copies."""
-        batch = next(self.prompt_iterator)
-        prompt_ids = np.asarray(batch["input_ids"], np.int32)
-        prompt_mask = np.asarray(batch["attention_mask"], np.int32)
+        prompt_ids, prompt_mask = self._next_prompt_chunk()
         return self._chunk_device(prompt_ids, prompt_mask, stats)
+
+    def _next_prompt_chunk(self, repeat: int = 1) -> Tuple[np.ndarray, np.ndarray]:
+        """The next prompt batch as host ``(ids, mask)``, every row repeated
+        ``repeat`` times (GRPO's group-contiguous fan-out)."""
+        with self.obs.span("collect/prompts"):
+            batch = next(self.prompt_iterator)
+            prompt_ids = np.asarray(batch["input_ids"], np.int32)
+            prompt_mask = np.asarray(batch["attention_mask"], np.int32)
+            if repeat > 1:
+                prompt_ids = np.repeat(prompt_ids, repeat, axis=0)
+                prompt_mask = np.repeat(prompt_mask, repeat, axis=0)
+        return prompt_ids, prompt_mask
 
     def _chunk_device(
         self,
@@ -444,8 +464,14 @@ class PPOTrainer(TPUBaseTrainer):
         # generate() opens its own fenced "generate" span, nested under the
         # caller's "rollout" span in the Chrome/Perfetto export
         gen_out = self.generate(prompt_ids, prompt_mask, params=params, rng=rng)
-        stats["time/exp_generate"] = perf_counter() - gen_time
-        stats["time/generate"] = self.last_generate_time
+        # sums over the collection's chunks (one `stats` a collection)
+        _add_times(
+            stats,
+            {
+                "time/exp_generate": perf_counter() - gen_time,
+                "time/generate": self.last_generate_time,
+            },
+        )
         stats.update(self.last_spec_stats)
 
         # dispatch the scoring forward immediately on the generation's
@@ -502,7 +528,9 @@ class PPOTrainer(TPUBaseTrainer):
                 )
             stats["time/reward"] = reward_sp.duration
             stats["time/exp_score"] = reward_sp.duration
+            wait_t0 = perf_counter()
             host = to_host(dev["score_out"])  # usually landed already (async copy)
+            score_wait = perf_counter() - wait_t0
         stats["time/score"] = score_sp.duration
         return {
             "prompt_ids": dev["prompt_ids"],
@@ -513,6 +541,9 @@ class PPOTrainer(TPUBaseTrainer):
             "host": host,
             "stats": stats,
             "host_s": perf_counter() - host_t0,
+            # what this stage spent inside reward_fn and waiting for the
+            # scoring outputs: not host work of the collection itself
+            "blocked_s": reward_sp.duration + score_wait,
         }
 
     def _rollout_chunk_finalize(
@@ -526,125 +557,126 @@ class PPOTrainer(TPUBaseTrainer):
         the main thread in submission order in BOTH modes, so reward scaling
         (running moments) and the store contents are bit-identical between
         depth 0 and depth ≥ 1."""
-        stats.update(chunk["stats"])
-        acc["host_s"] += chunk["host_s"]
-        scores = chunk["scores"]
-        response_mask = chunk["response_mask"]
-        response_tokens = chunk["response_tokens"]
-        host = chunk["host"]
+        with self.obs.span("collect/finalize"):
+            _add_times(stats, chunk["stats"])
+            acc["host_s"] += chunk["host_s"]
+            scores = chunk["scores"]
+            response_mask = chunk["response_mask"]
+            response_tokens = chunk["response_tokens"]
+            host = chunk["host"]
 
-        # reward scaling/clipping (reference :350-366). Non-finite scores
-        # (a flaky reward endpoint, an overflowed RM) are zeroed BEFORE the
-        # running moments fold them in — RunningMoments state is cumulative,
-        # so one NaN would poison every subsequently scaled reward.
-        scores = np.asarray(scores, np.float32)
-        nonfinite = ~np.isfinite(scores)
-        if nonfinite.any():
-            stats["health/nonfinite_scores"] = stats.get(
-                "health/nonfinite_scores", 0.0
-            ) + float(nonfinite.sum())
-            scores = np.where(nonfinite, 0.0, scores)
-        scores_mean, scores_std = self.running_moments.update(scores)
-        stats["exp_scores/mean"] = float(scores_mean)
-        stats["exp_scores/std"] = float(scores_std)
-        stats["exp_scores/running_mean"] = float(self.running_moments.mean)
-        stats["exp_scores/running_std"] = float(self.running_moments.std)
-        if self.config.method.scale_reward == "running":
-            scores /= max(self.running_moments.std, 1e-8)
-        elif self.config.method.scale_reward == "ref":
-            scores /= max(self.ref_std or 1.0, 1e-8)
-        clip = self.config.method.cliprange_reward
-        if clip:
-            scores = np.clip(scores, -clip, clip)
+            # reward scaling/clipping (reference :350-366). Non-finite scores
+            # (a flaky reward endpoint, an overflowed RM) are zeroed BEFORE the
+            # running moments fold them in — RunningMoments state is cumulative,
+            # so one NaN would poison every subsequently scaled reward.
+            scores = np.asarray(scores, np.float32)
+            nonfinite = ~np.isfinite(scores)
+            if nonfinite.any():
+                stats["health/nonfinite_scores"] = stats.get(
+                    "health/nonfinite_scores", 0.0
+                ) + float(nonfinite.sum())
+                scores = np.where(nonfinite, 0.0, scores)
+            scores_mean, scores_std = self.running_moments.update(scores)
+            stats["exp_scores/mean"] = float(scores_mean)
+            stats["exp_scores/std"] = float(scores_std)
+            stats["exp_scores/running_mean"] = float(self.running_moments.mean)
+            stats["exp_scores/running_std"] = float(self.running_moments.std)
+            if self.config.method.scale_reward == "running":
+                scores /= max(self.running_moments.std, 1e-8)
+            elif self.config.method.scale_reward == "ref":
+                scores /= max(self.ref_std or 1.0, 1e-8)
+            clip = self.config.method.cliprange_reward
+            if clip:
+                scores = np.clip(scores, -clip, clip)
 
-        # KL-penalty reward assembly on host (numpy twin of the device
-        # math; [B, N] arrays — microseconds)
-        rewards, (mean_kl, mean_kl_per_seq) = kl_penalty_rewards_np(
-            host["logprobs"], host["ref_logprobs"], response_mask,
-            scores, self.kl_ctl.value,
-        )
-        # a non-finite chunk KL (one overflowed logprob) must reach neither
-        # the adaptive controller's accumulator nor the tracker stream —
-        # max(nan, 0.0) is nan, so the old sqrt guard passed NaN through
-        if np.isfinite(mean_kl):
-            acc["kl_sum"] += mean_kl
-            acc["kl_batches"] += 1
-            stats["policy/sqrt_kl"] = float(np.sqrt(max(mean_kl, 0.0)))
-        else:
-            stats["health/nonfinite_kl_chunks"] = stats.get(
-                "health/nonfinite_kl_chunks", 0.0
-            ) + 1.0
-            stats["policy/sqrt_kl"] = 0.0
-        acc["gen_tokens"] += int(response_mask.sum())
-        acc["chunks"] += 1
-
-        # rollout-side dynamics sketches (observability/dynamics.py): the
-        # per-token KL vs the frozen reference only exists host-side here
-        # (the train step sees new-vs-old only), and all four collection
-        # paths (serial / pipelined / continuous / async) funnel through
-        # this finalize — one uniform feed point for the health canary
-        fmask = np.asarray(response_mask, np.float32)
-        ref_lr = (
-            np.asarray(host["logprobs"]) - np.asarray(host["ref_logprobs"])
-        ) * fmask
-        ref_k3 = (np.exp(ref_lr) - 1.0) - ref_lr
-        lo, hi = SKETCH_RANGES["ref_kl"]
-        acc["ref_kl_hist"] = acc.get("ref_kl_hist", 0.0) + sketch_np(
-            ref_k3, fmask, lo=lo, hi=hi
-        )
-        # generation-length + repeated-adjacent-token canary (host twin of
-        # the engine-harvest counters; engine's exact numbers win via
-        # setdefault in make_experience on the continuous path)
-        toks = np.asarray(response_tokens)
-        pair_mask = fmask[:, 1:] * fmask[:, :-1]
-        acc["rep_pairs"] = acc.get("rep_pairs", 0.0) + float(
-            ((toks[:, 1:] == toks[:, :-1]) * pair_mask).sum()
-        )
-        acc["rep_total"] = acc.get("rep_total", 0.0) + float(pair_mask.sum())
-        acc.setdefault("gen_lens", []).extend(
-            fmask.sum(axis=1).astype(np.int64).tolist()
-        )
-
-        # slot accounting (docs/PERFORMANCE.md): a chunk's decode ran
-        # max(n_i) steps over B slots (per-sample eos early-exit ends the
-        # while_loop at the longest row) — rows past their own eos burned
-        # padded slot-steps. The continuous-batching path replaces these
-        # numbers with the engine's exact counters.
-        n_per_row = response_mask.sum(axis=1)
-        acc["slot_steps"] += int(response_mask.shape[0]) * (
-            int(n_per_row.max()) if n_per_row.size else 0
-        )
-        acc["live_slot_steps"] += int(n_per_row.sum())
-
-        prompt_ids, prompt_mask = chunk["prompt_ids"], chunk["prompt_mask"]
-        # async chunks ship the sampler's exact behavior logprobs; they ride
-        # into elements only when the IW correction will consume them — the
-        # default-off path keeps the store's field set (and bytes) identical
-        # to the serial reference
-        behavior = chunk.get("behavior_logprobs")
-        if self.config.method.iw_correction == "off":
-            behavior = None
-        for i in range(prompt_ids.shape[0]):
-            n_i = int(response_mask[i].sum())
-            if n_i == 0:
-                continue
-            query = prompt_ids[i][prompt_mask[i] > 0]
-            elements.append(
-                PPORLElement(
-                    query_tensor=query,
-                    # host[...] landed via to_host: already numpy, slices
-                    # need no re-asarray
-                    response_tensor=response_tokens[i, :n_i],
-                    logprobs=host["logprobs"][i, :n_i],
-                    values=host["values"][i, :n_i],
-                    rewards=rewards[i, :n_i],
-                    behavior_logprobs=(
-                        np.asarray(behavior[i, :n_i], np.float32)
-                        if behavior is not None
-                        else None
-                    ),
-                )
+            # KL-penalty reward assembly on host (numpy twin of the device
+            # math; [B, N] arrays — microseconds)
+            rewards, (mean_kl, mean_kl_per_seq) = kl_penalty_rewards_np(
+                host["logprobs"], host["ref_logprobs"], response_mask,
+                scores, self.kl_ctl.value,
             )
+            # a non-finite chunk KL (one overflowed logprob) must reach neither
+            # the adaptive controller's accumulator nor the tracker stream —
+            # max(nan, 0.0) is nan, so the old sqrt guard passed NaN through
+            if np.isfinite(mean_kl):
+                acc["kl_sum"] += mean_kl
+                acc["kl_batches"] += 1
+                stats["policy/sqrt_kl"] = float(np.sqrt(max(mean_kl, 0.0)))
+            else:
+                stats["health/nonfinite_kl_chunks"] = stats.get(
+                    "health/nonfinite_kl_chunks", 0.0
+                ) + 1.0
+                stats["policy/sqrt_kl"] = 0.0
+            acc["gen_tokens"] += int(response_mask.sum())
+            acc["chunks"] += 1
+
+            # rollout-side dynamics sketches (observability/dynamics.py): the
+            # per-token KL vs the frozen reference only exists host-side here
+            # (the train step sees new-vs-old only), and all four collection
+            # paths (serial / pipelined / continuous / async) funnel through
+            # this finalize — one uniform feed point for the health canary
+            fmask = np.asarray(response_mask, np.float32)
+            ref_lr = (
+                np.asarray(host["logprobs"]) - np.asarray(host["ref_logprobs"])
+            ) * fmask
+            ref_k3 = (np.exp(ref_lr) - 1.0) - ref_lr
+            lo, hi = SKETCH_RANGES["ref_kl"]
+            acc["ref_kl_hist"] = acc.get("ref_kl_hist", 0.0) + sketch_np(
+                ref_k3, fmask, lo=lo, hi=hi
+            )
+            # generation-length + repeated-adjacent-token canary (host twin of
+            # the engine-harvest counters; engine's exact numbers win via
+            # setdefault in make_experience on the continuous path)
+            toks = np.asarray(response_tokens)
+            pair_mask = fmask[:, 1:] * fmask[:, :-1]
+            acc["rep_pairs"] = acc.get("rep_pairs", 0.0) + float(
+                ((toks[:, 1:] == toks[:, :-1]) * pair_mask).sum()
+            )
+            acc["rep_total"] = acc.get("rep_total", 0.0) + float(pair_mask.sum())
+            acc.setdefault("gen_lens", []).extend(
+                fmask.sum(axis=1).astype(np.int64).tolist()
+            )
+
+            # slot accounting (docs/PERFORMANCE.md): a chunk's decode ran
+            # max(n_i) steps over B slots (per-sample eos early-exit ends the
+            # while_loop at the longest row) — rows past their own eos burned
+            # padded slot-steps. The continuous-batching path replaces these
+            # numbers with the engine's exact counters.
+            n_per_row = response_mask.sum(axis=1)
+            decode_steps = int(n_per_row.max()) if n_per_row.size else 0
+            acc["decode_steps"] += decode_steps
+            acc["slot_steps"] += int(response_mask.shape[0]) * decode_steps
+            acc["live_slot_steps"] += int(n_per_row.sum())
+
+            prompt_ids, prompt_mask = chunk["prompt_ids"], chunk["prompt_mask"]
+            # async chunks ship the sampler's exact behavior logprobs; they ride
+            # into elements only when the IW correction will consume them — the
+            # default-off path keeps the store's field set (and bytes) identical
+            # to the serial reference
+            behavior = chunk.get("behavior_logprobs")
+            if self.config.method.iw_correction == "off":
+                behavior = None
+            for i in range(prompt_ids.shape[0]):
+                n_i = int(response_mask[i].sum())
+                if n_i == 0:
+                    continue
+                query = prompt_ids[i][prompt_mask[i] > 0]
+                elements.append(
+                    PPORLElement(
+                        query_tensor=query,
+                        # host[...] landed via to_host: already numpy, slices
+                        # need no re-asarray
+                        response_tensor=response_tokens[i, :n_i],
+                        logprobs=host["logprobs"][i, :n_i],
+                        values=host["values"][i, :n_i],
+                        rewards=rewards[i, :n_i],
+                        behavior_logprobs=(
+                            np.asarray(behavior[i, :n_i], np.float32)
+                            if behavior is not None
+                            else None
+                        ),
+                    )
+                )
 
     def _collect_serial(
         self, num_rollouts: int, elements: list, stats: Dict[str, float],
@@ -659,6 +691,7 @@ class PPOTrainer(TPUBaseTrainer):
             with self.obs.span("rollout"):
                 dev = self._rollout_chunk_device(stats)
                 chunk = self._rollout_chunk_host(dev)
+            acc["blocked_s"] += chunk["blocked_s"]
             self._rollout_chunk_finalize(chunk, elements, stats, acc)
         stats["throughput/rollout_overlap_frac"] = 0.0
 
@@ -701,7 +734,7 @@ class PPOTrainer(TPUBaseTrainer):
                 # host side shows up as "rollout/overlap" on the worker tid
                 with self.obs.span("rollout", pipelined=True) as rollout_sp:
                     dev = self._rollout_chunk_device(stats)
-                stats["time/rollout_device"] = rollout_sp.duration
+                _add_times(stats, {"time/rollout_device": rollout_sp.duration})
                 rows_in_flight.append(int(dev["prompt_ids"].shape[0]))
 
                 def work(dev=dev):
@@ -713,6 +746,9 @@ class PPOTrainer(TPUBaseTrainer):
 
                 pipe.submit(work)
             pipe_stats = pipe.stats
+        # reward and the wait for the scoring outputs ran on the worker: the
+        # main thread was blocked on them only while it waited on the pipe
+        acc["blocked_s"] += pipe_stats.wait_s
         stats["throughput/rollout_overlap_frac"] = pipe_stats.overlap_frac(
             perf_counter() - t0
         )
@@ -1219,74 +1255,94 @@ class PPOTrainer(TPUBaseTrainer):
             "kl_sum": 0.0, "kl_batches": 0, "host_s": 0.0,
             "gen_tokens": 0, "chunks": 0,
             "slot_steps": 0, "live_slot_steps": 0,
+            "decode_steps": 0, "blocked_s": 0.0,
         }
-        exp_time = perf_counter()
+        self.obs.tracer.next_cycle()
+        with self.obs.span("collect/experience"):
+            exp_time = perf_counter()
 
-        if bool(self.config.async_rl.enabled):
-            # the actor/learner split (docs/ASYNC_RL.md): actors generate —
-            # continuously, across collections — and this thread only drains
-            # and finalizes. rollout_pipeline_depth is moot here (host work
-            # already runs on actor threads/processes); continuous_batching
-            # selects the actors' engine path.
-            self._collect_async(num_rollouts, elements, stats, acc)
-        elif continuous:
-            self._collect_continuous(num_rollouts, depth, elements, stats, acc)
-        elif depth > 0:
-            self._collect_pipelined(num_rollouts, depth, elements, stats, acc)
-        else:
-            self._collect_serial(num_rollouts, elements, stats, acc)
+            if bool(self.config.async_rl.enabled):
+                # the actor/learner split (docs/ASYNC_RL.md): actors generate —
+                # continuously, across collections — and this thread only drains
+                # and finalizes. rollout_pipeline_depth is moot here (host work
+                # already runs on actor threads/processes); continuous_batching
+                # selects the actors' engine path.
+                self._collect_async(num_rollouts, elements, stats, acc)
+            elif continuous:
+                self._collect_continuous(num_rollouts, depth, elements, stats, acc)
+            elif depth > 0:
+                self._collect_pipelined(num_rollouts, depth, elements, stats, acc)
+            else:
+                self._collect_serial(num_rollouts, elements, stats, acc)
 
-        self.mean_kl = acc["kl_sum"] / max(acc["kl_batches"], 1)
-        stats["kl_ctl_value"] = self.kl_ctl.value
-        stats["time/rollout_host"] = acc["host_s"]
-        total = perf_counter() - exp_time
-        stats["time/exp"] = total
-        # whole-collection aggregates with identical definitions in BOTH
-        # modes (wall per chunk; generated tokens ÷ collection wall time) —
-        # the benchmark suite's A/B report then measures real speedup, never
-        # a per-mode metric redefinition
-        stats["time/rollout"] = total / max(acc["chunks"], 1)
-        if total > 0 and acc["gen_tokens"]:
-            stats["throughput/rollout_tokens_per_sec"] = acc["gen_tokens"] / total
-        # slot accounting, uniform across modes (continuous batching already
-        # set these from the engine's exact counters; the chunked paths
-        # derive them from response masks — see docs/PERFORMANCE.md)
-        if acc["slot_steps"]:
-            stats.setdefault(
-                "throughput/slot_utilization",
-                acc["live_slot_steps"] / acc["slot_steps"],
-            )
-            stats.setdefault(
-                "rollout/padded_decode_frac",
-                1.0 - acc["live_slot_steps"] / acc["slot_steps"],
-            )
-        # rollout-side dynamics summaries + health canary (accumulated per
-        # chunk in _rollout_chunk_finalize; setdefault keeps the engine's
-        # exact counters when continuous batching already merged them)
-        ref_hist = acc.get("ref_kl_hist")
-        if ref_hist is not None:
-            stats.update(
-                self.obs.dynamics.summarize({"dist/ref_kl_hist": ref_hist})
-            )
-        gen_lens = acc.get("gen_lens")
-        if gen_lens:
-            stats.setdefault(
-                "rollout/gen_len_p50", float(np.percentile(gen_lens, 50))
-            )
-            stats.setdefault(
-                "rollout/gen_len_p95", float(np.percentile(gen_lens, 95))
-            )
-        if acc.get("rep_total"):
-            stats.setdefault(
-                "rollout/repetition_frac", acc["rep_pairs"] / acc["rep_total"]
-            )
-        self.obs.health.observe_rollout(stats)
-        self.make_experience_stats = stats
-        self.tracker.log(stats, step=iter_count)
+            with self.obs.span("collect/finalize", stage="collection"):
+                self.mean_kl = acc["kl_sum"] / max(acc["kl_batches"], 1)
+                stats["kl_ctl_value"] = self.kl_ctl.value
+                stats["time/rollout_host"] = acc["host_s"]
+                self._host_gap_t0 = perf_counter()  # the first step's gap starts here
+                total = self._host_gap_t0 - exp_time
+                stats["time/exp"] = total
+                # the collection's self time: wall time the main thread spent in
+                # none of generate, reward_fn, or the wait for the scoring outputs
+                # (defined on the chunked paths; actors and the slot-refill engine
+                # generate off this thread's clock)
+                stats["time/collect_host"] = max(
+                    0.0, total - stats.get("time/generate", 0.0) - acc["blocked_s"]
+                )
+                # decode steps the sampler's loops ran (the longest row of each
+                # chunk, from the response masks) and the fenced generate time per
+                # step, prefill included
+                stats["rollout/decode_steps"] = float(acc["decode_steps"])
+                if acc["decode_steps"]:
+                    stats["time/decode_step"] = (
+                        stats.get("time/generate", 0.0) / acc["decode_steps"]
+                    )
+                # whole-collection aggregates with identical definitions in BOTH
+                # modes (wall per chunk; generated tokens ÷ collection wall time) —
+                # the benchmark suite's A/B report then measures real speedup, never
+                # a per-mode metric redefinition
+                stats["time/rollout"] = total / max(acc["chunks"], 1)
+                if total > 0 and acc["gen_tokens"]:
+                    stats["throughput/rollout_tokens_per_sec"] = acc["gen_tokens"] / total
+                # slot accounting, uniform across modes (continuous batching already
+                # set these from the engine's exact counters; the chunked paths
+                # derive them from response masks — see docs/PERFORMANCE.md)
+                if acc["slot_steps"]:
+                    stats.setdefault(
+                        "throughput/slot_utilization",
+                        acc["live_slot_steps"] / acc["slot_steps"],
+                    )
+                    stats.setdefault(
+                        "rollout/padded_decode_frac",
+                        1.0 - acc["live_slot_steps"] / acc["slot_steps"],
+                    )
+                # rollout-side dynamics summaries + health canary (accumulated per
+                # chunk in _rollout_chunk_finalize; setdefault keeps the engine's
+                # exact counters when continuous batching already merged them)
+                ref_hist = acc.get("ref_kl_hist")
+                if ref_hist is not None:
+                    stats.update(
+                        self.obs.dynamics.summarize({"dist/ref_kl_hist": ref_hist})
+                    )
+                gen_lens = acc.get("gen_lens")
+                if gen_lens:
+                    stats.setdefault(
+                        "rollout/gen_len_p50", float(np.percentile(gen_lens, 50))
+                    )
+                    stats.setdefault(
+                        "rollout/gen_len_p95", float(np.percentile(gen_lens, 95))
+                    )
+                if acc.get("rep_total"):
+                    stats.setdefault(
+                        "rollout/repetition_frac", acc["rep_pairs"] / acc["rep_total"]
+                    )
+                self.obs.health.observe_rollout(stats)
+                self.make_experience_stats = stats
+                self.tracker.log(stats, step=iter_count)
 
-        self.store.push(elements[:num_rollouts] if num_rollouts else elements)
-        if self.log_rollouts:
-            self.store.export_history(location=self.rollout_logging_dir)
+                self.store.push(elements[:num_rollouts] if num_rollouts else elements)
+                if self.log_rollouts:
+                    self.store.export_history(location=self.rollout_logging_dir)
 
     # ------------------------------------------------------------------
     # optimization
@@ -1473,6 +1529,7 @@ class PPOTrainer(TPUBaseTrainer):
         # fresh rollouts with the updated policy (reference ``:222-231``)
         self.store.clear_history()
         self.make_experience(self.config.method.num_rollouts, self.iter_count)
-        self.train_dataloader = self.store.create_loader(
-            self.config.train.batch_size, shuffle=True, seed=self.config.train.seed
-        )
+        with self.obs.span("learn/loader", stage="create"):
+            self.train_dataloader = self.store.create_loader(
+                self.config.train.batch_size, shuffle=True, seed=self.config.train.seed
+            )
