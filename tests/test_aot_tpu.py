@@ -279,3 +279,52 @@ def test_flash_kernels_keep_their_names_in_the_compiled_program(topo):
             pattern = json.load(f)["pattern"]
         hits = [c for c in calls if re.search(pattern, c)]
         assert len(hits) == 1 and hits[0].startswith(f"%{kernel}"), (metric, calls)
+
+
+def test_dense_decode_step_builds_no_repeated_kv(topo, monkeypatch):
+    """Cached single-token decoding runs the dense einsum branch of
+    ``Attention``. At the attention shapes of ``mistral7b_grpo_decode``
+    (64 rows, 640 cache slots, 32 query / 8 KV heads of 128, bf16; one layer
+    of the ``mistral`` preset) the optimised program must hold no array as
+    large as K or V repeated to every query head (``B*S*H*D`` elements), in
+    the decode loop's body or outside it, whatever the compiler calls its
+    shape. A count from shapes: it says nothing about time."""
+    import re
+
+    from trlx_tpu.models.transformer import CausalTransformer, TransformerConfig, make_kv_cache
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rows, prompt, slots = 64, 128, 640
+    cfg = TransformerConfig.mistral("7b", num_layers=1, dtype=DT, param_dtype=DT)
+    model = CausalTransformer(cfg)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    place = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
+    )
+    params = place(jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    )["params"])
+    cache = place(jax.eval_shape(lambda: make_kv_cache(cfg, rows, slots)))
+
+    def decode(params, token, slot_mask, cache):
+        def step(i, carry):
+            token, cache = carry
+            out = model.apply(
+                {"params": params}, token, attention_mask=slot_mask,
+                positions=jnp.full((rows, 1), prompt + i), cache=cache, cache_index=prompt + i,
+            )
+            return jnp.argmax(out["logits"][:, -1], axis=-1).astype(jnp.int32)[:, None], out["cache"]
+
+        return jax.lax.fori_loop(0, 4, step, (token, cache))
+
+    text = jax.jit(decode).lower(
+        params, place(_s((rows, 1), jnp.int32)), place(_s((rows, slots), jnp.int32)), cache
+    ).compile().as_text()
+    assert " while(" in text
+    sizes = {
+        shape: int(np.prod([int(d) for d in shape.split(",")]))
+        for shape in re.findall(r"\b[a-z]+\d*\[(\d+(?:,\d+)*)\]", text)
+    }
+    assert max(sizes.values()) >= rows * slots * 8 * 128  # the cache itself is there
+    repeated = rows * slots * cfg.num_heads * cfg.dims_per_head
+    assert not {s: n for s, n in sizes.items() if n >= repeated}

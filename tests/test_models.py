@@ -20,7 +20,8 @@ from trlx_tpu.models.heads import (
     CausalLMWithValueHead,
     sync_target_q_params,
 )
-from trlx_tpu.models.transformer import CausalTransformer, TransformerConfig
+from trlx_tpu.models import transformer
+from trlx_tpu.models.transformer import Attention, CausalTransformer, TransformerConfig, alibi_slopes
 from trlx_tpu.models import hf_interop
 from trlx_tpu.ops.sampling import GenerationConfig, generate
 
@@ -333,3 +334,86 @@ def test_pad_rows_left_truncation_keeps_tail():
     assert out.tolist() == [[3, 4, 5]]  # keeps tokens adjacent to response
     out, _ = pad_rows([[1, 2, 3, 4, 5]], 0, "right", 1, fixed_length=3)
     assert out.tolist() == [[1, 2, 3]]
+
+
+def _repeated_kv_attention(q, k, v, attention_bias, dtype):
+    """The plain oracle: K and V repeated to every query head, then the two
+    classic einsums."""
+    reps = q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, reps, axis=2), jnp.repeat(v, reps, axis=2)
+    scores = jnp.einsum("bthd,bshd->bhts", q, k) / jnp.sqrt(jnp.asarray(q.shape[-1], dtype))
+    probs = jax.nn.softmax((scores + attention_bias).astype(jnp.float32), axis=-1).astype(dtype)
+    return jnp.einsum("bhts,bshd->bthd", probs, v)
+
+
+def _einsum_branch_case(case, H):
+    """``(T, S, cache_index, bias [B, 1 | H, T, S])`` of one way into the
+    einsum branch, B = 2, row 1 left-padded by one slot."""
+    B, S = 2, 8
+    T, ci = {"full": (S, None), "window": (S, None), "alibi": (S, None),
+             "decode": (1, jnp.asarray(5)), "verify": (3, jnp.asarray([2, 5]))}[case]
+    first = jnp.zeros((B,), jnp.int32) if ci is None else jnp.broadcast_to(ci, (B,))
+    q_slots = first[:, None] + jnp.arange(T)[None, :]  # [B, T]
+    k_slots = jnp.arange(S)[None, None, :]
+    key_mask = jnp.ones((B, S), bool).at[1, 0].set(False)
+    visible = (k_slots <= q_slots[:, :, None]) & key_mask[:, None, :]
+    if case == "window":
+        visible &= q_slots[:, :, None] - k_slots < 3
+    bias = jnp.where(visible, 0.0, -1e9)[:, None]  # [B, 1, T, S]
+    if case == "alibi":
+        dist = (k_slots - q_slots[:, :, None]).astype(jnp.float32)  # [B, T, S]
+        slopes = jnp.asarray(alibi_slopes(H), jnp.float32)
+        bias = bias + jnp.where(visible[:, None], slopes[None, :, None, None] * dist[:, None], 0.0)
+    return T, S, ci, bias
+
+
+@pytest.mark.parametrize("case", ["full", "decode", "verify", "window", "alibi"])
+@pytest.mark.parametrize("kv_heads", [8, 4, 2, 1])
+def test_einsum_attention_consumes_unrepeated_kv(kv_heads, case, monkeypatch):
+    """The dense einsum branch contracts over a grouped head axis: for every
+    head layout (MHA, GQA 2 and 4, MQA) and every way into the branch (full
+    pass, cached single-token step at a scalar ``cache_index``, speculative
+    verify span at a ``[B]`` one, sliding window, per-head ALiBi bias) the
+    output, the written cache and the gradients (``dk``, ``dv`` group-summed)
+    match the repeated-K/V oracle within float32 rounding."""
+    B, H, D = 2, 8, 4
+    T, S, ci, bias = _einsum_branch_case(case, H)
+    ks = jax.random.split(jax.random.PRNGKey(kv_heads), 6)
+    close = partial(np.testing.assert_allclose, atol=2e-5, rtol=2e-5)
+
+    # the two contractions themselves: gradients with respect to q, k, v
+    q = jax.random.normal(ks[0], (B, T, H, D), jnp.float32)
+    k = jax.random.normal(ks[1], (B, S, kv_heads, D), jnp.float32)
+    v = jax.random.normal(ks[2], (B, S, kv_heads, D), jnp.float32)
+
+    def out_and_grads(attention):
+        attend = lambda q, k, v: attention(q, k, v, bias, jnp.float32)
+        return attend(q, k, v), jax.grad(lambda *a: jnp.sum(attend(*a) ** 2), argnums=(0, 1, 2))(q, k, v)
+
+    jax.tree_util.tree_map(
+        close, out_and_grads(transformer.grouped_einsum_attention), out_and_grads(_repeated_kv_attention)
+    )
+
+    # the Attention module around them: projections, cache write, o_proj
+    cfg = TransformerConfig(
+        vocab_size=11, hidden_size=H * D, num_layers=1, num_heads=H, intermediate_size=16,
+        num_kv_heads=kv_heads, position_scheme="alibi" if case == "alibi" else "learned",
+        dtype=jnp.float32, attention_impl="xla",
+    )
+    x = jax.random.normal(ks[3], (B, T, H * D), jnp.float32)
+    positions = jnp.zeros((B, T), jnp.int32)  # read by rotary only
+    cache = None if ci is None else {
+        "k": jax.random.normal(ks[4], (B, S, kv_heads, D), jnp.float32),
+        "v": jax.random.normal(ks[5], (B, S, kv_heads, D), jnp.float32),
+    }
+    attn = Attention(cfg)
+    params = attn.init(jax.random.PRNGKey(0), x, bias, positions, cache, ci)
+
+    def run(params, x):
+        out, new_cache = attn.apply(params, x, bias, positions, cache, ci)
+        return jnp.sum(out ** 2), (out, new_cache)
+
+    got = jax.value_and_grad(run, argnums=(0, 1), has_aux=True)(params, x)
+    monkeypatch.setattr(transformer, "grouped_einsum_attention", _repeated_kv_attention)
+    want = jax.value_and_grad(run, argnums=(0, 1), has_aux=True)(params, x)
+    jax.tree_util.tree_map(close, got, want)

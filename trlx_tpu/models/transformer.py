@@ -585,6 +585,26 @@ def _dense(cfg, features, use_bias, kernel_axes, name=None):
     )
 
 
+def grouped_einsum_attention(q, k, v, attention_bias, dtype) -> jax.Array:
+    """Dense attention ``[B, T, H, D]`` of ``q [B, T, H, D]`` over unrepeated
+    ``k``, ``v [B, S, KV, D]`` under an additive ``attention_bias``
+    ``[B, 1 | H, T, S]``, softmax in float32.
+
+    Query heads ``j*G .. j*G+G-1`` (``G = H // KV``) share KV head ``j``, so
+    the head axis is viewed as ``[KV, G]`` and both contractions run against
+    K and V as the cache holds them: nothing of ``B*S*H*D`` elements is ever
+    built. MHA is ``G = 1``."""
+    B, T, H, D = q.shape
+    S, KV = k.shape[1], k.shape[2]
+    G = H // KV
+    scores = jnp.einsum("btkgd,bskd->bkgts", q.reshape(B, T, KV, G, D), k).reshape(B, H, T, S)
+    scores = scores / jnp.sqrt(jnp.asarray(D, dtype))
+    scores = scores + attention_bias.astype(scores.dtype)
+    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(dtype)
+    out = jnp.einsum("bkgts,bskd->btkgd", probs.reshape(B, KV, G, T, S), v)
+    return out.reshape(B, T, H, D)
+
+
 class Attention(nn.Module):
     """Multi-head / grouped-query attention with RoPE/ALiBi and an explicit
     KV cache ({"k","v"} arrays [B, S, kvH, D] written at ``cache_index``)."""
@@ -738,14 +758,7 @@ class Attention(nn.Module):
         elif flash_args is not None:
             out = _flash_attention(q, k, v, flash_args).reshape(B, T, H * D)
         else:
-            if KV < H:  # flash/ring kernels consume unrepeated K/V (GQA-aware)
-                k = jnp.repeat(k, H // KV, axis=2)
-                v = jnp.repeat(v, H // KV, axis=2)
-            depth = jnp.asarray(D, cfg.dtype)
-            scores = jnp.einsum("bthd,bshd->bhts", q, k) / jnp.sqrt(depth)
-            scores = scores + attention_bias.astype(scores.dtype)
-            probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(cfg.dtype)
-            out = jnp.einsum("bhts,bshd->bthd", probs, v).reshape(B, T, H * D)
+            out = grouped_einsum_attention(q, k, v, attention_bias, cfg.dtype).reshape(B, T, H * D)
         out = _dense(cfg, cfg.hidden_size, cfg.attn_bias, ("joined_kv", "embed"), "o_proj")(out)
         return out, new_cache
 
