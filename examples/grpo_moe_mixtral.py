@@ -13,13 +13,14 @@ anywhere (CPU mesh included); point ``MODEL_PATH`` at a local Mixtral
 checkpoint directory to RLHF the real 8x7B (import is exact —
 ``tests/test_hf_export.py::test_roundtrip_exact_logits[mixtral]``).
 
-Capacity note: HF import pins ``moe_capacity_factor = num_experts`` so
-imported checkpoints reproduce HF logits exactly (drop-free routing), but
-that makes the dispatch/combine slot tensors multi-GB per layer at 8x7B
-scale. For *training* this script overrides it to ``MOE_CAPACITY``
-(default 2.0): overflow tokens are dropped — standard MoE training
-behavior; the Switch load-balance loss keeps drops rare. Set
-``MOE_CAPACITY=8`` to recover the drop-free parity setting.
+Capacity note: HF import routes without a capacity bound
+(``moe_capacity_factor = 0``, dropless grouped matmuls), so imported
+checkpoints reproduce HF logits exactly; that path runs the experts on one
+device. For expert-parallel *training* this script overrides it to
+``MOE_CAPACITY`` (default 2.0): the one-hot dispatch with a static capacity,
+whose overflow tokens are dropped — standard MoE training behavior; the
+Switch load-balance loss keeps drops rare (``moe/dropped_frac`` in the step
+stats says how rare).
 """
 
 import os
@@ -43,9 +44,8 @@ def main(hparams=None):
 
     extra = dict(router_aux_coef=0.01, router_z_coef=0.001)
     if os.environ.get("MODEL_PATH"):
-        # Override the drop-free import default (capacity = num_experts,
-        # needed only for exact-logit parity) with a training-throughput
-        # capacity; see the module docstring for the trade-off.
+        # Override the dropless import default (one device) with a capacity
+        # the `expert` mesh axis can dispatch; see the module docstring.
         extra["moe_capacity_factor"] = float(os.environ.get("MOE_CAPACITY", 2.0))
 
     config = default_grpo_config().evolve(

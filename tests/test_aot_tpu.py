@@ -328,3 +328,47 @@ def test_dense_decode_step_builds_no_repeated_kv(topo, monkeypatch):
     assert max(sizes.values()) >= rows * slots * 8 * 128  # the cache itself is there
     repeated = rows * slots * cfg.num_heads * cfg.dims_per_head
     assert not {s: n for s, n in sizes.items() if n >= repeated}
+
+
+@pytest.mark.parametrize("tokens,grad", [((64, 1), False), ((16, 640), True)])
+def test_dropless_experts_are_grouped_kernels_at_olmoe_widths(topo, tokens, grad):
+    """One OLMoE expert layer (64 experts of 1024 on hidden 2048, top-8,
+    bf16) as ``olmoe7b_grpo_decode`` runs it: a decode step of 64 rows, and
+    a train step's 16 x 640 tokens forward and backward. The TPU compiler
+    must keep ``jax.lax.ragged_dot`` as grouped Mosaic kernels under the
+    instruction name ``moe_gmm_device_ms`` reads, and must not expand them to
+    one dense matmul per expert: the program's FLOPs are those of the B*T*k
+    assignments, not of ``E`` times them. Counts from the compiler; it says
+    nothing about time."""
+    import json
+    import os
+    import re
+
+    from trlx_tpu.models.transformer import MoEMLP, TransformerConfig
+
+    cfg = TransformerConfig.olmoe("1b-7b", dtype=DT, param_dtype=DT)
+    layer = MoEMLP(cfg)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    place = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
+    )
+    x = place(_s(tokens + (cfg.hidden_size,)))
+    params = place(jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, cfg.hidden_size), DT)))["params"])
+
+    def fwd(p, x):
+        y, aux = layer.apply({"params": p}, x)
+        return jnp.sum(y.astype(jnp.float32)) + aux[0]
+
+    compiled = jax.jit(jax.grad(fwd, argnums=(0, 1)) if grad else fwd).lower(params, x).compile()
+    calls = [l.strip().removeprefix("ROOT ") for l in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in l]
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "layer_metrics", "moe_gmm_device_ms.json")) as f:
+        pattern = json.load(f)["pattern"]
+    grouped = [c for c in calls if re.search(pattern, c)]
+    # gate, up, down; the backward adds one for the rows and one for the kernel of each
+    assert len(grouped) == (9 if grad else 3), [c[:60] for c in calls]
+    assignments = tokens[0] * tokens[1] * cfg.num_experts_per_tok
+    matmul = 2 * assignments * cfg.hidden_size * cfg.intermediate_size
+    flops = compiled.cost_analysis()["flops"]
+    assert 3 * matmul * (3 if grad else 1) <= flops < 1.5 * 3 * matmul * (3 if grad else 1)

@@ -15,7 +15,7 @@ from trlx_tpu.models.builder import build_causal_lm
 from tests.test_models import _tiny_hf
 
 
-@pytest.mark.parametrize("family", ["gpt2", "llama", "gpt_neox", "gptj", "opt", "bloom", "mistral", "mixtral"])
+@pytest.mark.parametrize("family", ["gpt2", "llama", "gpt_neox", "gptj", "opt", "bloom", "mistral", "mixtral", "olmoe"])
 def test_roundtrip_exact_logits(family, tmp_path):
     """import tiny torch model → export → reload in transformers → exact parity."""
     import torch

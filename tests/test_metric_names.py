@@ -117,6 +117,10 @@ def test_scanner_sees_the_codebase():
     assert "health/triage_dumps" in keys
     assert "health/nonfinite_scores" in keys
     assert "health/nonfinite_kl_chunks" in keys
+    # mixture-of-experts routing counters (docs/OBSERVABILITY.md "Per-step
+    # keys"): literal sites in trainer/base.py::with_router_aux
+    assert "moe/dropped_frac" in keys
+    assert "moe/load_max_over_mean" in keys
 
 
 def test_engine_keys_registered_and_namespaced():

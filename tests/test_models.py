@@ -86,6 +86,14 @@ def _tiny_hf(family: str):
                 tie_word_embeddings=False,
             )
         )
+    elif family == "olmoe":
+        hf = tf.OlmoeForCausalLM(
+            tf.OlmoeConfig(
+                vocab_size=97, hidden_size=32, num_hidden_layers=2, num_attention_heads=4,
+                num_key_value_heads=4, intermediate_size=24, max_position_embeddings=64,
+                num_experts=8, num_experts_per_tok=3, tie_word_embeddings=False,
+            )
+        )
     else:
         raise ValueError(family)
     hf.eval()
@@ -93,7 +101,7 @@ def _tiny_hf(family: str):
     return hf, params, _f32(cfg)
 
 
-@pytest.mark.parametrize("family", ["gpt2", "llama", "gpt_neox", "gptj", "opt", "bloom", "mistral", "mixtral"])
+@pytest.mark.parametrize("family", ["gpt2", "llama", "gpt_neox", "gptj", "opt", "bloom", "mistral", "mixtral", "olmoe"])
 def test_hf_logit_parity(family):
     """The flax decoder reproduces the torch reference logits exactly."""
     import torch

@@ -411,3 +411,114 @@ def test_moe_through_pipeline_parity():
         )
     finally:
         set_global_mesh(None)
+
+
+# ---------------------------------------------------------------------------
+# dropless routing (moe_capacity_factor = 0)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("what", ["forward", "gradients"])
+def test_dropless_equals_ample_capacity(what):
+    """With capacity for every assignment (factor = E) the one-hot dispatch
+    drops nothing, so the two dispatches are the same function: logits and
+    router statistics, and the gradients of a loss that uses both."""
+    import dataclasses
+
+    ample = _cfg(moe_capacity_factor=4.0)
+    dropless = dataclasses.replace(ample, moe_capacity_factor=0.0)
+    rs = np.random.RandomState(0)
+    ids = jnp.asarray(rs.randint(0, 259, (3, 16)), jnp.int32)
+    mask = jnp.ones((3, 16), jnp.int32).at[1, :6].set(0)
+    params = CausalTransformer(ample).init(jax.random.PRNGKey(0), ids)["params"]
+
+    def run(cfg, p):
+        return CausalTransformer(cfg).apply({"params": p}, ids, attention_mask=mask)
+
+    def loss(cfg, p):
+        out = run(cfg, p)
+        return jnp.mean(out["logits"] ** 2) + jnp.sum(out["router_aux_loss"])
+
+    if what == "forward":
+        a, d = run(ample, params), run(dropless, params)
+        for key in ("logits", "router_aux_loss", "router_load"):
+            np.testing.assert_allclose(np.asarray(d[key]), np.asarray(a[key]), rtol=1e-5, atol=1e-6)
+        assert float(d["router_load"][0]) == 0.0
+        return
+    ga = jax.grad(lambda p: loss(ample, p))(params)
+    gd = jax.grad(lambda p: loss(dropless, p))(params)
+    for (path, a), d in zip(jax.tree_util.tree_flatten_with_path(ga)[0], jax.tree_util.tree_leaves(gd)):
+        scale = float(jnp.abs(a).max())
+        np.testing.assert_allclose(np.asarray(d), np.asarray(a), rtol=1e-4, atol=1e-5 * scale,
+                                   err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("method", ["grpo", "ppo"])
+def test_olmoe_rl_step_through_train(method, tmp_path):
+    """One collection and two optimizer steps on ``builtin:olmoe-test``
+    through ``trlx_tpu.train()``: the normal trainer, sampler, cache and
+    learn loop, with the dropless counters in the step stats."""
+    import trlx_tpu
+    from trlx_tpu.data import default_configs
+
+    base = {"grpo": default_configs.default_grpo_config,
+            "ppo": default_configs.default_ppo_config}[method]()
+    method_kw = dict(num_rollouts=16, chunk_size=16, ppo_epochs=1,
+                     gen_kwargs=dict(max_new_tokens=8, top_k=0, top_p=1.0, do_sample=True))
+    if method == "grpo":
+        method_kw["group_size"] = 4
+    config = base.evolve(
+        train=dict(seq_length=24, batch_size=8, total_steps=2, epochs=100, eval_interval=100,
+                   checkpoint_interval=1000, checkpoint_dir=str(tmp_path / "ckpt"), tracker=None),
+        model=dict(model_path="builtin:olmoe-test"),
+        tokenizer=dict(tokenizer_path="builtin:bytes"),
+        method=method_kw,
+    )
+    seen = []
+
+    def hook(trainer):
+        class Tracker:
+            def log(self, stats, step=None):
+                seen.append(dict(stats))
+
+            def finish(self):
+                pass
+
+        trainer.tracker = Tracker()
+
+    trainer = trlx_tpu.train(
+        reward_fn=lambda samples, prompts, outputs, **kw: [float(len(set(o))) for o in outputs],
+        prompts=["abcdefgh" * 2] * 16, eval_prompts=["abcdefgh" * 2] * 2,
+        config=config, init_trainer_hook=hook,
+    )
+    assert trainer.iter_count == 2
+    assert trainer.tcfg.qk_norm and trainer.tcfg.moe_capacity_factor == 0
+    steps = [s for s in seen if "moe/dropped_frac" in s]
+    assert len(steps) == 2
+    for s in steps:
+        assert float(s["moe/dropped_frac"]) == 0.0
+        assert 1.0 <= float(s["moe/load_max_over_mean"]) <= trainer.tcfg.num_experts
+        assert np.isfinite(float(s["losses/total_loss"]))
+
+
+def test_olmoe_cell_rehearsal():
+    """The benchmark cell ``olmoe7b_grpo_decode`` end to end on the CPU at
+    the configuration's toy widths: the same harness, trainer and checks
+    against ``chipbench/reference/olmoe.py`` as on the chip. Its numbers mean
+    nothing; ``correct`` and ``programs_compiled`` do."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", XLA_FLAGS="--xla_force_host_platform_device_count=1")
+    proc = subprocess.run(
+        [sys.executable, "-m", "chipbench.run", "--workload", "olmoe7b_grpo_decode", "--seed",
+         "3000000019", "--seconds", "2", "--trace", "0", "--rehearse"],
+        cwd=root, env=env, capture_output=True, text=True, timeout=600,
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    line = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert line["correct"] and line["rehearsal"] and line["failed"] == 0
+    assert line["device"]["platform"] == "cpu"

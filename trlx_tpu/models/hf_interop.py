@@ -159,6 +159,45 @@ def convert_mixtral(sd: Dict[str, np.ndarray], cfg: TransformerConfig) -> Dict[s
     return {"backbone": backbone}
 
 
+_OLMOE_EXPERT_KEYS = (("w_gate", "gate_proj"), ("w_up", "up_proj"), ("w_down", "down_proj"))
+
+
+def convert_olmoe(sd: Dict[str, np.ndarray], cfg: TransformerConfig) -> Dict[str, Any]:
+    """OLMoE: the llama key layout plus ``self_attn.{q,k}_norm`` (RMSNorm
+    over the whole projected width) and a sparse ``mlp`` whose router is
+    ``mlp.gate`` and whose experts are ``mlp.experts.<e>.{gate,up,down}_proj``."""
+    p = "model."
+    backbone: Dict[str, Any] = {
+        "wte": {"embedding": sd[p + "embed_tokens.weight"]},
+        "ln_f": {"scale": sd[p + "norm.weight"]},
+        "lm_head": {"kernel": _t(sd["lm_head.weight"])},
+    }
+    for i in range(cfg.num_layers):
+        lp = f"{p}layers.{i}."
+        ep = lp + "mlp.experts."
+        attn = {
+            name: _proj(_t(sd[f"{lp}self_attn.{name}.weight"]))
+            for name in ("q_proj", "k_proj", "v_proj", "o_proj")
+        }
+        attn["q_norm"] = {"scale": sd[lp + "self_attn.q_norm.weight"]}
+        attn["k_norm"] = {"scale": sd[lp + "self_attn.k_norm.weight"]}
+        backbone[f"h_{i}"] = {
+            "ln_attn": {"scale": sd[lp + "input_layernorm.weight"]},
+            "ln_mlp": {"scale": sd[lp + "post_attention_layernorm.weight"]},
+            "attn": attn,
+            "mlp": {
+                "router": {"kernel": _t(sd[lp + "mlp.gate.weight"])},
+                **{
+                    ours: np.stack(
+                        [_t(sd[f"{ep}{e}.{theirs}.weight"]) for e in range(cfg.num_experts)]
+                    )
+                    for ours, theirs in _OLMOE_EXPERT_KEYS
+                },
+            },
+        }
+    return {"backbone": backbone}
+
+
 def convert_gptneox(sd: Dict[str, np.ndarray], cfg: TransformerConfig) -> Dict[str, Any]:
     p = "gpt_neox."
     D = cfg.dims_per_head
@@ -287,6 +326,7 @@ CONVERTERS: Dict[str, Callable] = {
     "bloom": convert_bloom,
     "mistral": convert_llama,  # identical key layout (llama + sliding window)
     "mixtral": convert_mixtral,
+    "olmoe": convert_olmoe,
 }
 
 
@@ -352,12 +392,35 @@ def config_from_hf(hf_config) -> TransformerConfig:
             router_aux_coef=getattr(hf_config, "router_aux_loss_coef", 0.01),
             moe_group_size=512,
             sliding_window=getattr(hf_config, "sliding_window", None),
-            # HF Mixtral routes with no capacity bound (dense gather); a
-            # capacity factor of E makes the einsum dispatch drop-free by
-            # construction (even if every token picked the same expert), so
-            # imported checkpoints reproduce HF logits exactly. Lower it for
-            # training throughput at the cost of overflow-token drops.
-            moe_capacity_factor=float(hf_config.num_local_experts),
+            # HF Mixtral routes with no capacity bound: dropless here too, so
+            # imported checkpoints reproduce HF logits. Set a capacity factor
+            # above 0 for expert-parallel dispatch (overflow tokens drop).
+            moe_capacity_factor=0.0,
+        )
+    if mt == "olmoe":
+        return TransformerConfig(
+            model_type=mt,
+            vocab_size=hf_config.vocab_size,
+            hidden_size=hf_config.hidden_size,
+            num_layers=hf_config.num_hidden_layers,
+            num_heads=hf_config.num_attention_heads,
+            num_kv_heads=getattr(hf_config, "num_key_value_heads", None),
+            intermediate_size=hf_config.intermediate_size,
+            max_position_embeddings=hf_config.max_position_embeddings,
+            position_scheme="rotary",
+            rope_theta=getattr(hf_config, "rope_theta", 10000.0),
+            norm="rmsnorm",
+            layer_norm_epsilon=hf_config.rms_norm_eps,
+            activation="silu",
+            attn_bias=False,
+            mlp_bias=False,
+            tie_word_embeddings=bool(getattr(hf_config, "tie_word_embeddings", False)),
+            qk_norm=True,
+            num_experts=hf_config.num_experts,
+            num_experts_per_tok=hf_config.num_experts_per_tok,
+            moe_renormalize=bool(getattr(hf_config, "norm_topk_prob", False)),
+            router_aux_coef=getattr(hf_config, "router_aux_loss_coef", 0.01),
+            moe_capacity_factor=0.0,  # dropless, as published
         )
     if mt == "gpt_neox":
         head_dim = hf_config.hidden_size // hf_config.num_attention_heads
@@ -859,6 +922,35 @@ def export_t5(backbone: Dict[str, Any], cfg) -> Dict[str, np.ndarray]:
     return sd
 
 
+def export_olmoe(backbone: Dict[str, Any], cfg) -> Dict[str, np.ndarray]:
+    """Inverse of :func:`convert_olmoe`."""
+    p = "model."
+    sd: Dict[str, np.ndarray] = {
+        p + "embed_tokens.weight": np.asarray(backbone["wte"]["embedding"]),
+        p + "norm.weight": np.asarray(backbone["ln_f"]["scale"]),
+        "lm_head.weight": (
+            np.asarray(backbone["wte"]["embedding"])
+            if cfg.tie_word_embeddings
+            else _t(np.asarray(backbone["lm_head"]["kernel"]))
+        ),
+    }
+    for i in range(cfg.num_layers):
+        lp = f"{p}layers.{i}."
+        h = backbone[f"h_{i}"]
+        sd[lp + "input_layernorm.weight"] = np.asarray(h["ln_attn"]["scale"])
+        sd[lp + "post_attention_layernorm.weight"] = np.asarray(h["ln_mlp"]["scale"])
+        for name in ("q_proj", "k_proj", "v_proj", "o_proj"):
+            _put_linear(sd, f"{lp}self_attn.{name}", h["attn"][name])
+        sd[lp + "self_attn.q_norm.weight"] = np.asarray(h["attn"]["q_norm"]["scale"])
+        sd[lp + "self_attn.k_norm.weight"] = np.asarray(h["attn"]["k_norm"]["scale"])
+        mlp = h["mlp"]
+        sd[lp + "mlp.gate.weight"] = _t(np.asarray(mlp["router"]["kernel"]))
+        for e in range(cfg.num_experts):
+            for ours, theirs in _OLMOE_EXPERT_KEYS:
+                sd[f"{lp}mlp.experts.{e}.{theirs}.weight"] = _t(np.asarray(mlp[ours][e]))
+    return sd
+
+
 EXPORTERS: Dict[str, Callable] = {
     "gpt2": export_gpt2,
     "llama": export_llama,
@@ -869,6 +961,7 @@ EXPORTERS: Dict[str, Callable] = {
     "t5": export_t5,
     "mistral": export_llama,  # identical key layout
     "mixtral": export_mixtral,
+    "olmoe": export_olmoe,
 }
 
 
@@ -964,6 +1057,23 @@ def hf_config_from_transformer(cfg):
             num_experts_per_tok=cfg.num_experts_per_tok,
             router_aux_loss_coef=cfg.router_aux_coef,
             sliding_window=cfg.sliding_window,
+            tie_word_embeddings=cfg.tie_word_embeddings,
+        )
+    if mt == "olmoe":
+        return tf.OlmoeConfig(
+            vocab_size=cfg.vocab_size,
+            hidden_size=cfg.hidden_size,
+            num_hidden_layers=cfg.num_layers,
+            num_attention_heads=cfg.num_heads,
+            num_key_value_heads=cfg.kv_heads,
+            intermediate_size=cfg.intermediate_size,
+            max_position_embeddings=cfg.max_position_embeddings,
+            rms_norm_eps=cfg.layer_norm_epsilon,
+            rope_theta=cfg.rope_theta,
+            num_experts=cfg.num_experts,
+            num_experts_per_tok=cfg.num_experts_per_tok,
+            norm_topk_prob=cfg.moe_renormalize,
+            router_aux_loss_coef=cfg.router_aux_coef,
             tie_word_embeddings=cfg.tie_word_embeddings,
         )
     if mt == "gpt_neox":

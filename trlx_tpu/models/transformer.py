@@ -212,6 +212,9 @@ class TransformerConfig:
     activation: str = "gelu_new"  # gelu_new | gelu | silu | relu
     parallel_residual: bool = False  # gptj/neox style
     shared_ln: bool = False  # gptj: one LN feeds both attn and mlp
+    # olmoe: RMSNorm with a learned scale over the whole projected width of
+    # q and of k, before the split into heads and before rotary
+    qk_norm: bool = False
     attn_bias: bool = True
     mlp_bias: bool = True
     qkv_bias: Optional[bool] = None  # overrides attn_bias for q/k/v if set
@@ -219,6 +222,13 @@ class TransformerConfig:
     final_norm: bool = True
     embedding_layernorm: bool = False  # bloom has a LN after word embeddings
     lm_head_bias: bool = False  # gptj has a bias on the lm head
+    # std of the token embedding's random init (every other matrix: 0.02).
+    # At 0.02 the attention output of a shared prefix outweighs the token's
+    # own embedding in the residual stream, so a randomly initialised MoE
+    # router sends all rows of a prompt group to the same few experts; at 1
+    # (torch's nn.Embedding default) routing follows the token, as in a
+    # trained MoE. Only matters for models run from random weights.
+    embed_init_std: float = 0.02
 
     # numerics / compilation
     param_dtype: Any = jnp.float32
@@ -256,7 +266,10 @@ class TransformerConfig:
     # (parallel/mesh.py) so XLA inserts the token all_to_alls.
     num_experts: int = 0
     num_experts_per_tok: int = 2
-    moe_capacity_factor: float = 1.25  # slots per expert = ceil(k*G*cf/E)
+    # slots per expert = ceil(k*G*cf/E); 0 = no capacity bound (dropless:
+    # every token is computed by all k of its experts, grouped matmuls over
+    # the assignments sorted by expert; one chip only, no `expert` axis)
+    moe_capacity_factor: float = 1.25
     moe_group_size: int = 0  # dispatch group tokens (0 = whole sequence);
     # bounds the [.., E, C] slot tensors to O(T·G) instead of O(T²)
     moe_renormalize: bool = True  # mixtral renormalizes the top-k gate probs
@@ -356,6 +369,30 @@ class TransformerConfig:
             mlp_bias=False,
             tie_word_embeddings=False,
             num_experts_per_tok=2,
+        )
+
+    @staticmethod
+    def olmoe(size: str = "1b-7b", **overrides) -> "TransformerConfig":
+        dims = {
+            "test": dict(vocab_size=259, hidden_size=64, num_layers=2, num_heads=4, intermediate_size=48, max_position_embeddings=128, num_experts=8, num_experts_per_tok=2),
+            "1b-7b": dict(vocab_size=50304, hidden_size=2048, num_layers=16, num_heads=16, num_kv_heads=16, intermediate_size=1024, max_position_embeddings=4096, num_experts=64, num_experts_per_tok=8),
+        }[size]
+        return _make_preset(
+            dims,
+            overrides,
+            model_type="olmoe",
+            position_scheme="rotary",
+            rope_theta=10000.0,
+            norm="rmsnorm",
+            layer_norm_epsilon=1e-5,
+            activation="silu",
+            attn_bias=False,
+            mlp_bias=False,
+            tie_word_embeddings=False,
+            qk_norm=True,
+            moe_capacity_factor=0.0,  # dropless, as published
+            moe_renormalize=False,  # norm_topk_prob: false
+            router_aux_coef=0.01,
         )
 
     @staticmethod
@@ -527,6 +564,16 @@ def Norm(config: TransformerConfig, name: str):
     )
 
 
+def _qk_norm(config: TransformerConfig, name: str):
+    return nn.RMSNorm(
+        epsilon=config.layer_norm_epsilon,
+        dtype=config.dtype,
+        param_dtype=config.param_dtype,
+        scale_init=param_with_axes(nn.initializers.ones, ("joined_kv",)),
+        name=name,
+    )
+
+
 class LoRADense(nn.Module):
     """Dense with an additive low-rank branch: ``y = xW (+b) + (alpha/r)·xAB``.
 
@@ -626,9 +673,14 @@ class Attention(nn.Module):
         H, KV, D = cfg.num_heads, cfg.kv_heads, cfg.dims_per_head
         qkv_bias = cfg.attn_bias if cfg.qkv_bias is None else cfg.qkv_bias
 
-        q = _dense(cfg, H * D, qkv_bias, ("embed", "joined_kv"), "q_proj")(x).reshape(B, T, H, D)
-        k = _dense(cfg, KV * D, qkv_bias, ("embed", "joined_kv"), "k_proj")(x).reshape(B, T, KV, D)
+        q = _dense(cfg, H * D, qkv_bias, ("embed", "joined_kv"), "q_proj")(x)
+        k = _dense(cfg, KV * D, qkv_bias, ("embed", "joined_kv"), "k_proj")(x)
         v = _dense(cfg, KV * D, qkv_bias, ("embed", "joined_kv"), "v_proj")(x).reshape(B, T, KV, D)
+        if cfg.qk_norm:
+            # over the whole projected width (all heads together), float32
+            # statistics; every cache and kernel path below sees normed q, k
+            q, k = _qk_norm(cfg, "q_norm")(q), _qk_norm(cfg, "k_norm")(k)
+        q, k = q.reshape(B, T, H, D), k.reshape(B, T, KV, D)
 
         if cfg.position_scheme == "rotary":
             rdim = cfg.rotary_dim or D
@@ -806,27 +858,36 @@ def _maybe_expert_mesh():
 
 
 class MoEMLP(nn.Module):
-    """Mixture-of-experts MLP: top-k router + GShard-style einsum dispatch.
+    """Mixture-of-experts MLP: top-k router, then one of two dispatches.
 
     TPU-first design (the reference has no MoE at all — SURVEY.md §2.3 lists
-    EP as n/a; this is a beyond-parity capability for the mixtral family):
+    EP as n/a; this is a beyond-parity capability for the mixtral and olmoe
+    families). The router runs in fp32; expert weights carry a leading ``E``
+    dim. Returns ``(y, aux)`` where ``aux`` is the layer's additive
+    statistics (``_ZERO_AUX``), summed over layers / microbatches / pipeline
+    stages and normalized by ``router_aux_summary`` / ``router_load_summary``.
+
+    ``moe_capacity_factor > 0`` — GShard-style einsum dispatch:
 
     - each sequence is a dispatch group: tokens route to their top-k experts
       with a *static* per-group capacity ``C = ceil(k·T·cf/E)`` (first
       choices claim slots before second choices; overflow tokens fall back to
-      the residual path). Static shapes keep the whole thing one XLA program
-      — no sorting, no dynamic gather.
-    - expert weights carry a leading ``E`` dim sharded over the mesh's
-      ``expert`` axis; the dispatch/combine einsums change token layout from
-      batch-sharded to expert-sharded and back, which GSPMD lowers to
-      all_to_all over the ``expert`` axis (the EP analogue of Megatron TP's
-      allreduce). Per-expert matmul dims still shard over ``fsdp``/``model``.
-    - the router runs in fp32; returns ``(y, aux)`` where ``aux`` is
-      ``[load_balance, router_z]`` — the Switch-style balance loss
-      (≡ 1.0 at a perfectly uniform router) and the ST-MoE z-loss.
+      the residual path). No sorting, no dynamic gather.
+    - the ``E`` dim shards over the mesh's ``expert`` axis; the
+      dispatch/combine einsums change token layout from batch-sharded to
+      expert-sharded and back, which GSPMD lowers to all_to_all over the
+      ``expert`` axis (the EP analogue of Megatron TP's allreduce).
+      Per-expert matmul dims still shard over ``fsdp``/``model``.
+    - at decode (T = 1) the capacity is ``max(1, ceil(k·cf/E)) ≥ 1`` and
+      top-k indices are distinct, so decode never drops tokens.
 
-    At decode (T = 1) the capacity is ``max(1, ceil(k·cf/E)) ≥ 1`` and top-k
-    indices are distinct, so decode never drops tokens.
+    ``moe_capacity_factor == 0`` — dropless token-choice routing: the
+    ``B·T·k`` (token, expert) assignments are sorted by expert and the three
+    expert matmuls run as grouped matmuls over the sorted rows
+    (``jax.lax.ragged_dot``; on TPU the compiler makes it a Mosaic kernel
+    whose FLOPs are those of the assignments, not of ``E`` times them).
+    Shapes are static (``B·T·k`` rows whatever the routing); nothing couples
+    two tokens, so a row's output does not depend on its neighbours.
     """
 
     config: TransformerConfig
@@ -839,8 +900,6 @@ class MoEMLP(nn.Module):
         E, K = cfg.num_experts, cfg.num_experts_per_tok
         B, T, d = x.shape
         f = cfg.intermediate_size
-        act = get_activation(cfg.activation)
-        gated = cfg.activation == "silu"
 
         logits = nn.Dense(
             E,
@@ -856,7 +915,106 @@ class MoEMLP(nn.Module):
             gate_vals = gate_vals / jnp.maximum(
                 jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9
             )
+        # padding tokens route nowhere: they reach no expert and leave the
+        # layer with zero output (the Block residual carries them)
+        w = (
+            jnp.ones((B, T), jnp.float32)
+            if token_mask is None
+            else token_mask.reshape(B, T).astype(jnp.float32)
+        )
 
+        def expert_kernel(name, shape, axes):
+            return self.param(
+                name,
+                param_with_axes(nn.initializers.normal(0.02), axes),
+                shape,
+                cfg.param_dtype,
+            ).astype(cfg.dtype)
+
+        kernels = {}
+        if cfg.activation == "silu":  # gated (llama-style) experts
+            kernels["w_gate"] = expert_kernel("w_gate", (E, d, f), ("expert", "embed", "ffn"))
+        kernels["w_up"] = expert_kernel("w_up", (E, d, f), ("expert", "embed", "ffn"))
+        kernels["w_down"] = expert_kernel("w_down", (E, f, d), ("expert", "ffn", "embed"))
+
+        dispatch = self._dropless if cfg.moe_capacity_factor == 0 else self._capacity
+        y, counts, dropped = dispatch(x, w, gate_vals, idx, kernels)
+
+        # Switch load-balance loss over the assignments asked for: E·Σ f_e·p_e
+        # (1.0 when both routing fractions and router probs are uniform).
+        # Means are over REAL tokens only — padding must not train the router.
+        # Everything is returned as token-weighted sufficient statistics so
+        # that accumulation over layers / microbatches / pipeline stages
+        # stays correctly weighted under uneven padding.
+        n_real = jnp.sum(w)
+        denom = jnp.maximum(n_real, 1.0)
+        me = jnp.sum(probs * w[..., None], axis=(0, 1)) / denom
+        ce = counts / (denom * K)
+        aux_lb = E * jnp.sum(me * ce)
+        lse = jax.scipy.special.logsumexp(logits, axis=-1)  # [B, T]
+        z_sum = jnp.sum((lse**2) * w)
+        busiest = E * jnp.max(ce)  # the busiest expert's tokens over the mean
+        aux = jnp.stack(
+            [aux_lb * n_real, z_sum, n_real, dropped, n_real * K, busiest * n_real]
+        )
+        return y.astype(cfg.dtype), aux
+
+    def _experts(self, kernels, matmul, xin):
+        """gate/up/down on ``xin`` with ``matmul(rows, kernel)``."""
+        act = get_activation(self.config.activation)
+        h = act(matmul(xin, kernels["w_gate"])) if "w_gate" in kernels else None
+        up = matmul(xin, kernels["w_up"])
+        h = act(up) if h is None else h * up
+        return matmul(h, kernels["w_down"])
+
+    def _dropless(self, x, w, gate_vals, idx, kernels):
+        """Every real token through all ``K`` of its experts. Returns
+        ``(y [B, T, d], assignments per expert [E], dropped = 0)``."""
+        cfg = self.config
+        E, K = cfg.num_experts, cfg.num_experts_per_tok
+        B, T, d = x.shape
+        N = B * T
+        if _maybe_expert_mesh() is not None:
+            raise ValueError(
+                "moe_capacity_factor=0 (dropless routing) runs the experts on "
+                "one device; this mesh has an `expert` axis above 1 — set "
+                "moe_capacity_factor > 0 for expert-parallel dispatch"
+            )
+        real = w.reshape(N) > 0
+        # assignment r = token r // K, choice r % K; padding sorts past the
+        # last expert, outside every group, and is never computed
+        expert = jnp.where(real[:, None], idx.reshape(N, K), E).reshape(N * K)
+        counts = jnp.zeros((E + 1,), jnp.int32).at[expert].add(1)[:E]
+        order = jnp.argsort(expert)  # stable: sorted row -> assignment
+        # a permutation of the K-fold repeated rows: its transpose scatters to
+        # unique rows, where x[order // K] would scatter-add with duplicates
+        xin = jnp.repeat(x.reshape(N, d), K, axis=0).at[order].get(unique_indices=True)
+
+        def grouped(lhs, kernel):
+            # bf16 operands have one precision; saying so keeps the kernel
+            # compiling under a global jax_default_matmul_precision=highest,
+            # which Mosaic refuses for bf16 ("Bad lhs type")
+            precision = jax.lax.Precision.DEFAULT if lhs.dtype == jnp.bfloat16 else None
+            return jax.lax.ragged_dot(lhs, kernel, counts, precision=precision)
+
+        out = self._experts(kernels, grouped, xin)  # [N·K, d], sorted by expert
+        # rows past the last group (padding) hold whatever the kernel left
+        out = jnp.where((jnp.arange(N * K) < jnp.sum(counts))[:, None], out, 0)
+        unsort = jnp.zeros_like(order).at[order].set(jnp.arange(N * K), unique_indices=True)
+        out = out.at[unsort].get(unique_indices=True).reshape(N, K, d)
+        gates = gate_vals.reshape(N, K) * real[:, None]
+        y = jnp.einsum(
+            "nkd,nk->nd", out, gates.astype(out.dtype),
+            preferred_element_type=jnp.float32,
+        )
+        return y.reshape(B, T, d), counts.astype(jnp.float32), jnp.zeros((), jnp.float32)
+
+    def _capacity(self, x, w, gate_vals, idx, kernels):
+        """GShard one-hot dispatch with a static capacity. Returns ``(y,
+        assignments asked for per expert [E], assignments dropped)``."""
+        cfg = self.config
+        E, K = cfg.num_experts, cfg.num_experts_per_tok
+        B, T, d = x.shape
         # dispatch groups: capacity (and the [.., E, C] dispatch tensors)
         # scale with the group size G, not with T — whole-sequence groups
         # would make the slot tensors O(T²) per row at long context. G is
@@ -868,17 +1026,11 @@ class MoEMLP(nn.Module):
                 G -= 1
         N = B * (T // G)
         xg = x.reshape(N, G, d)
-        w = (
-            jnp.ones((N, G), jnp.float32)
-            if token_mask is None
-            else token_mask.reshape(N, G).astype(jnp.float32)
-        )
 
         C = max(1, int(np.ceil(K * G * cfg.moe_capacity_factor / E)))
         onehot = jax.nn.one_hot(idx, E, dtype=jnp.int32).reshape(N, G, K, E)
-        # padding tokens route nowhere: they claim no capacity slots and
-        # leave the layer with zero output (the Block residual carries them)
-        onehot = onehot * w[..., None, None].astype(jnp.int32)
+        # padding tokens claim no capacity slots
+        onehot = onehot * w.reshape(N, G)[..., None, None].astype(jnp.int32)
         # slot assignment with choice-priority: every token's first choice
         # outranks any second choice (GShard top-2 semantics)
         perm = onehot.transpose(0, 2, 1, 3).reshape(N, K * G, E)
@@ -905,59 +1057,19 @@ class MoEMLP(nn.Module):
 
         xin = jnp.einsum("ngd,ngec->encd", xg, dispatch.astype(x.dtype))
         xin = expert_sharded(xin)  # ← GSPMD inserts the dispatch all_to_all
-        if gated:
-            w_gate = self.param(
-                "w_gate",
-                param_with_axes(nn.initializers.normal(0.02), ("expert", "embed", "ffn")),
-                (E, d, f),
-                cfg.param_dtype,
-            )
-            w_up = self.param(
-                "w_up",
-                param_with_axes(nn.initializers.normal(0.02), ("expert", "embed", "ffn")),
-                (E, d, f),
-                cfg.param_dtype,
-            )
-            h = act(jnp.einsum("encd,edf->encf", xin, w_gate.astype(cfg.dtype)))
-            h = h * jnp.einsum("encd,edf->encf", xin, w_up.astype(cfg.dtype))
-        else:
-            w_up = self.param(
-                "w_up",
-                param_with_axes(nn.initializers.normal(0.02), ("expert", "embed", "ffn")),
-                (E, d, f),
-                cfg.param_dtype,
-            )
-            h = act(jnp.einsum("encd,edf->encf", xin, w_up.astype(cfg.dtype)))
-        w_down = self.param(
-            "w_down",
-            param_with_axes(nn.initializers.normal(0.02), ("expert", "ffn", "embed")),
-            (E, f, d),
-            cfg.param_dtype,
+        out = self._experts(
+            kernels, lambda a, kernel: jnp.einsum("enca,eab->encb", a, kernel), xin
         )
-        out = jnp.einsum("encf,efd->encd", h, w_down.astype(cfg.dtype))
         out = expert_sharded(out)
         y = jnp.einsum("encd,ngec->ngd", out, combine.astype(out.dtype))
-        y = y.reshape(B, T, d)
-
-        # Switch load-balance loss over pre-capacity assignments: E·Σ f_e·p_e
-        # (1.0 when both routing fractions and router probs are uniform).
-        # Means are over REAL tokens only — padding must not train the router.
-        # Returned as token-weighted sufficient statistics [lb·w, Σw·lse², w]
-        # so accumulation over layers / microbatches / pipeline stages stays
-        # correctly weighted under uneven padding; ``router_aux_summary``
-        # normalizes to [lb, z] at the forward's end.
-        n_real = jnp.sum(w)
-        denom = jnp.maximum(n_real, 1.0)
-        wf = w.reshape(B, T)
-        me = jnp.sum(probs * wf[..., None], axis=(0, 1)) / denom
-        ce = jnp.sum(onehot.astype(jnp.float32), axis=(0, 1, 2)) / (denom * K)
-        aux_lb = E * jnp.sum(me * ce)
-        lse = jax.scipy.special.logsumexp(logits, axis=-1)  # [B, T]
-        z_sum = jnp.sum((lse**2) * wf)
-        return y.astype(cfg.dtype), jnp.stack([aux_lb * n_real, z_sum, n_real])
+        counts = jnp.sum(onehot.astype(jnp.float32), axis=(0, 1, 2))
+        dropped = jnp.sum((perm - kept).astype(jnp.float32))
+        return y.reshape(B, T, d), counts, dropped
 
 
-_ZERO_AUX = (3,)  # Block aux statistics: [lb·tokens, Σ tokens·lse², tokens]
+# Block aux statistics, all additive: [lb·tokens, Σ tokens·lse², tokens,
+# assignments dropped, assignments asked for, (busiest expert / mean)·tokens]
+_ZERO_AUX = (6,)
 
 
 def router_aux_summary(aux: jax.Array) -> jax.Array:
@@ -967,6 +1079,16 @@ def router_aux_summary(aux: jax.Array) -> jax.Array:
     is a product of per-group means and therefore has per-group semantics,
     like every microbatched MoE implementation)."""
     return aux[:2] / jnp.maximum(aux[2], 1.0)
+
+
+def router_load_summary(aux: jax.Array) -> jax.Array:
+    """Accumulated per-layer aux statistics → ``[dropped_frac,
+    load_max_over_mean]``: the share of (token, expert) assignments asked for
+    and not computed (0 under dropless routing, always), and the busiest
+    expert's assignments over the mean, token-weighted over the layers."""
+    return jnp.stack(
+        [aux[3] / jnp.maximum(aux[4], 1.0), aux[5] / jnp.maximum(aux[2], 1.0)]
+    )
 
 
 def _cache_is_paged(cache) -> bool:
@@ -1088,7 +1210,7 @@ class CausalTransformer(nn.Module):
             cfg.hidden_size,
             dtype=cfg.dtype,
             param_dtype=cfg.param_dtype,
-            embedding_init=param_with_axes(nn.initializers.normal(0.02), ("vocab", "embed")),
+            embedding_init=param_with_axes(nn.initializers.normal(cfg.embed_init_std), ("vocab", "embed")),
             name="wte",
         )
         if cfg.position_scheme == "learned":
@@ -1312,6 +1434,7 @@ class CausalTransformer(nn.Module):
             # token-weighted [load_balance, router_z] over all layers —
             # trainers add router_aux_coef/router_z_coef · these to the loss
             out["router_aux_loss"] = router_aux_summary(aux)
+            out["router_load"] = router_load_summary(aux)
         return out
 
     def _pipelined_blocks(
@@ -1492,6 +1615,7 @@ BUILTIN_SPECS = {
     "llama": TransformerConfig.llama,
     "mistral": TransformerConfig.mistral,
     "mixtral": TransformerConfig.mixtral,
+    "olmoe": TransformerConfig.olmoe,
     "gptj": TransformerConfig.gptj,
     "gptneox": TransformerConfig.gptneox,
     "pythia": TransformerConfig.gptneox,

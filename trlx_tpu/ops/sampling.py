@@ -170,7 +170,8 @@ def sample_token_from_logits(
 
 _NON_CARRY_KEYS = (
     "cache", "logits", "branch_input", "pre_norm_hidden", "encoder_hidden",
-    "router_aux_loss",  # scalar vector, not [B, ...] — and unused in decode
+    "router_aux_loss",  # scalar vectors, not [B, ...] — and unused in decode
+    "router_load",
 )
 
 

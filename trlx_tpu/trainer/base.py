@@ -484,6 +484,10 @@ class TPUBaseTrainer(BaseRLTrainer):
         stats = dict(stats)
         stats["losses/router_load_balance"] = aux[0]
         stats["losses/router_z"] = aux[1]
+        load = out.get("router_load")
+        if load is not None:
+            stats["moe/dropped_frac"] = load[0]
+            stats["moe/load_max_over_mean"] = load[1]
         # keep the logged total in sync with what is actually optimized.
         # Contract: every method.loss must report its headline total under
         # one of these canonical keys (PPO/ILQL/GRPO/DPO flatten to
@@ -1169,12 +1173,14 @@ class TPUBaseTrainer(BaseRLTrainer):
         return PagedSpec(block_size=bs, max_blocks=max_blocks)
 
     def _prefix_cache_enabled(self) -> bool:
-        """engine.prefix_cache, gated off (with a one-time warning) for MoE
-        policies: expert capacity couples a row's tokens, so a suffix-only
-        prefill is not bit-identical to the full prefill there."""
+        """engine.prefix_cache, gated off (with a one-time warning) for
+        capacity-routed MoE policies: expert capacity couples a row's tokens,
+        so a suffix-only prefill is not bit-identical to the full prefill
+        there."""
         if not self.config.engine.prefix_cache:
             return False
-        if getattr(self.tcfg, "num_experts", 0):
+        if getattr(self.tcfg, "num_experts", 0) and self.tcfg.moe_capacity_factor > 0:
+            # dropless routing (moe_capacity_factor 0) couples no two tokens
             if not getattr(self, "_warned_moe_prefix", False):
                 self._warned_moe_prefix = True
                 logger.warning(
