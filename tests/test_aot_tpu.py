@@ -330,45 +330,84 @@ def test_dense_decode_step_builds_no_repeated_kv(topo, monkeypatch):
     assert not {s: n for s, n in sizes.items() if n >= repeated}
 
 
-@pytest.mark.parametrize("tokens,grad", [((64, 1), False), ((16, 640), True)])
-def test_dropless_experts_are_grouped_kernels_at_olmoe_widths(topo, tokens, grad):
+@pytest.mark.parametrize(
+    "tokens,grad,mesh_shape",
+    [((64, 1), False, None), ((2, 640), True, None), ((16, 640), True, None), ((64, 1), False, (2, 2))],
+    ids=["decode_step", "short_train_step", "train_step", "fsdp2_model2"],
+)
+def test_dropless_experts_are_grouped_kernels_at_olmoe_widths(topo, monkeypatch, tokens, grad, mesh_shape):
     """One OLMoE expert layer (64 experts of 1024 on hidden 2048, top-8,
     bf16) as ``olmoe7b_grpo_decode`` runs it: a decode step of 64 rows, and
-    a train step's 16 x 640 tokens forward and backward. The TPU compiler
-    must keep ``jax.lax.ragged_dot`` as grouped Mosaic kernels under the
-    instruction name ``moe_gmm_device_ms`` reads, and must not expand them to
-    one dense matmul per expert: the program's FLOPs are those of the B*T*k
-    assignments, not of ``E`` times them. Counts from the compiler; it says
-    nothing about time."""
-    import json
-    import os
+    a train step's 16 x 640 tokens forward and backward. On one chip the
+    decode step's three matmuls (512 rows in 64 groups) are the megablox
+    kernel (``ops/grouped_matmul.py``), and so is a train step short enough
+    for groups under 256 rows (2 x 640 tokens), with its backward: the
+    compiler names the forward's calls ``%gmm.N`` and, compiled alone, the
+    backward's after jax's transformation stack
+    (``%transpose_jvp_jit_gmm___.N`` for the rows, ``...tgmm...`` for the
+    kernels), so only ``gmm`` / ``tgmm`` inside the name is held. The cell's
+    train step has long groups and keeps ``jax.lax.ragged_dot``, which the
+    compiler makes its own Mosaic kernels ``%ragged-dot-none.N``; so does
+    every mesh of several devices (``fsdp=2, model=2`` here), where GSPMD can
+    place ``ragged_dot`` and a Pallas call would need a ``shard_map``. Either
+    way the experts must not expand to one dense matmul per expert: the
+    program's FLOPs are those of the B*T*k assignments, not of ``E`` times
+    them. Counts from the compiler; it says nothing about time."""
     import re
 
     from trlx_tpu.models.transformer import MoEMLP, TransformerConfig
+    from trlx_tpu.ops.grouped_matmul import SHORT_GROUP
+    from trlx_tpu.parallel.mesh import MESH_AXES, set_global_mesh
+    from trlx_tpu.parallel.sharding import param_shardings
 
+    # steer the platform probe the way the chip would answer it
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = TransformerConfig.olmoe("1b-7b", dtype=DT, param_dtype=DT)
     layer = MoEMLP(cfg)
-    one_chip = SingleDeviceSharding(topo.devices[0])
-    place = lambda tree: jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
+    x = _s(tokens + (cfg.hidden_size,))
+    params = jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, cfg.hidden_size), DT)))["params"]
+    place = lambda tree, shardings: jax.tree_util.tree_map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s), tree, shardings
     )
-    x = place(_s(tokens + (cfg.hidden_size,)))
-    params = place(jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, cfg.hidden_size), DT)))["params"])
+    if mesh_shape is None:
+        mesh = None
+        one_chip = SingleDeviceSharding(topo.devices[0])
+        params = place(params, jax.tree_util.tree_map(lambda _: one_chip, params))
+        x = place(x, one_chip)
+    else:
+        mesh = Mesh(np.asarray(topo.devices).reshape((1, 1) + mesh_shape + (1, 1)), MESH_AXES)
+        # the layer's own parameter rules, under the name a Block gives it
+        params = place({"mlp": params}, param_shardings({"mlp": params}, mesh))["mlp"]
+        x = place(x, NamedSharding(mesh, P(("data", "fsdp"))))
 
     def fwd(p, x):
         y, aux = layer.apply({"params": p}, x)
         return jnp.sum(y.astype(jnp.float32)) + aux[0]
 
-    compiled = jax.jit(jax.grad(fwd, argnums=(0, 1)) if grad else fwd).lower(params, x).compile()
+    assignments = tokens[0] * tokens[1] * cfg.num_experts_per_tok
+    short = mesh is None and assignments < SHORT_GROUP * cfg.num_experts
+    set_global_mesh(mesh)
+    try:
+        # test modules that share this process raise the default, which Mosaic
+        # refuses for bf16: ragged_dot has to pin its own precision; jax's
+        # megablox kernels take the ambient one
+        with jax.default_matmul_precision("default" if short else "highest"):
+            compiled = jax.jit(jax.grad(fwd, argnums=(0, 1)) if grad else fwd).lower(params, x).compile()
+    finally:
+        set_global_mesh(None)
     calls = [l.strip().removeprefix("ROOT ") for l in compiled.as_text().splitlines()
              if 'custom_call_target="tpu_custom_call"' in l]
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "chipbench", "layer_metrics", "moe_gmm_device_ms.json")) as f:
-        pattern = json.load(f)["pattern"]
-    grouped = [c for c in calls if re.search(pattern, c)]
+    named = lambda pattern: [c for c in calls if re.search(pattern, c.split(" = ")[0])]
     # gate, up, down; the backward adds one for the rows and one for the kernel of each
-    assert len(grouped) == (9 if grad else 3), [c[:60] for c in calls]
-    assignments = tokens[0] * tokens[1] * cfg.num_experts_per_tok
+    if short:
+        assert len(named(r"(?<!t)gmm")) == (6 if grad else 3), [c[:60] for c in calls]
+        assert len(named(r"tgmm")) == (3 if grad else 0), [c[:60] for c in calls]
+        assert not named(r"ragged-dot")
+    else:
+        assert len(named(r"^%ragged-dot-(?!metadata)")) == (9 if grad else 3), [c[:60] for c in calls]
+        assert not named(r"gmm")
+    # under the mesh too these are a device's FLOPs: the partitioner gathers
+    # rows and kernels and every device computes the whole grouped matmul
     matmul = 2 * assignments * cfg.hidden_size * cfg.intermediate_size
     flops = compiled.cost_analysis()["flops"]
     assert 3 * matmul * (3 if grad else 1) <= flops < 1.5 * 3 * matmul * (3 if grad else 1)

@@ -883,9 +883,13 @@ class MoEMLP(nn.Module):
 
     ``moe_capacity_factor == 0`` — dropless token-choice routing: the
     ``B·T·k`` (token, expert) assignments are sorted by expert and the three
-    expert matmuls run as grouped matmuls over the sorted rows
-    (``jax.lax.ragged_dot``; on TPU the compiler makes it a Mosaic kernel
-    whose FLOPs are those of the assignments, not of ``E`` times them).
+    expert matmuls run as grouped matmuls over the sorted rows, whose FLOPs
+    are those of the assignments, not of ``E`` times them
+    (``ops/grouped_matmul.py``: ``jax.lax.ragged_dot``, which the TPU
+    compiler makes a Mosaic kernel with 512-row tiles and GSPMD can place
+    under a mesh; where groups average under 256 rows on one TPU device, a
+    decode step's groups of about 8 rows, the megablox Pallas kernel with a
+    128-row tile instead).
     Shapes are static (``B·T·k`` rows whatever the routing); nothing couples
     two tokens, so a row's output does not depend on its neighbours.
     """
@@ -970,6 +974,8 @@ class MoEMLP(nn.Module):
     def _dropless(self, x, w, gate_vals, idx, kernels):
         """Every real token through all ``K`` of its experts. Returns
         ``(y [B, T, d], assignments per expert [E], dropped = 0)``."""
+        from trlx_tpu.ops.grouped_matmul import grouped_matmul
+
         cfg = self.config
         E, K = cfg.num_experts, cfg.num_experts_per_tok
         B, T, d = x.shape
@@ -990,14 +996,9 @@ class MoEMLP(nn.Module):
         # unique rows, where x[order // K] would scatter-add with duplicates
         xin = jnp.repeat(x.reshape(N, d), K, axis=0).at[order].get(unique_indices=True)
 
-        def grouped(lhs, kernel):
-            # bf16 operands have one precision; saying so keeps the kernel
-            # compiling under a global jax_default_matmul_precision=highest,
-            # which Mosaic refuses for bf16 ("Bad lhs type")
-            precision = jax.lax.Precision.DEFAULT if lhs.dtype == jnp.bfloat16 else None
-            return jax.lax.ragged_dot(lhs, kernel, counts, precision=precision)
-
-        out = self._experts(kernels, grouped, xin)  # [N·K, d], sorted by expert
+        out = self._experts(  # [N·K, d], sorted by expert
+            kernels, lambda lhs, kernel: grouped_matmul(lhs, kernel, counts), xin
+        )
         # rows past the last group (padding) hold whatever the kernel left
         out = jnp.where((jnp.arange(N * K) < jnp.sum(counts))[:, None], out, 0)
         unsort = jnp.zeros_like(order).at[order].set(jnp.arange(N * K), unique_indices=True)
