@@ -8,12 +8,16 @@ steps), recorded through a stand-in tracker, then:
   key a sum over the collection's chunks;
 - step records carry ``time/step_gap`` and ``learn/pad_frac``;
 - the spans cover the cycle, carry ``cycle=<n>``, and tile the learn phase;
+- the first collection's store is, byte for byte, the one the two separate
+  collectors built before they became one (PR 29), at either pipeline depth;
 - the three programs have the module names the benchmark's trace metrics
   match on, and ``score_fn`` names exactly one of them;
 - every per-layer metric file this vocabulary feeds reads a key or a name
   the program really emits.
 """
 
+import dataclasses
+import hashlib
 import inspect
 import json
 import os
@@ -99,11 +103,11 @@ def _toy_run(flavor, tmp_path):
     trainer = trlx.train(reward_fn=reward_fn, prompts=prompts, config=config,
                          init_trainer_hook=hook)
     return {"trainer": trainer, "records": recorder.records, "generated": generated,
-            "events": trainer.obs.tracer.events()}
+            "events": trainer.obs.tracer.events(), "method": method}
 
 
-@pytest.fixture(scope="module", params=[("ppo", 2), ("ppo", 0), ("grpo", 0)],
-                ids=["ppo-pipelined", "ppo-serial", "grpo"])
+@pytest.fixture(scope="module", params=[("ppo", 2), ("ppo", 0), ("grpo", 0), ("grpo", 2)],
+                ids=["ppo-pipelined", "ppo-serial", "grpo", "grpo-pipelined"])
 def run(request, tmp_path_factory):
     return _toy_run(request.param, tmp_path_factory.mktemp("cycle"))
 
@@ -132,6 +136,34 @@ def test_collection_keys_are_sums_over_chunks(run):
     assert rec["rollout/decode_steps"] == steps > 0
     assert rec["time/decode_step"] == pytest.approx(rec["time/generate"] / steps)
     assert 0.0 < rec["time/collect_host"] < rec["time/exp"] - rec["time/generate"]
+
+
+# sha256 over every element's fields, in order, of the first collection's
+# store, recorded at the parent of PR 29 (commit 362c28d), where PPO and GRPO
+# each had a collector of their own; the pipelined runs must give the same
+STORE_DIGESTS = {
+    "ppo": "5cd08ee277f2a218b4e32fb88d4f073511714c64327c7839d767a405825b1879",
+    "grpo": "21fd5cfe4e3c9743b509d6fe3c51fe34ed3c57e270b1e8c24ea5fb060cd47091",
+}
+
+
+def test_store_is_the_one_the_separate_collectors_built(run):
+    # the run ended before the post-epoch refill: the store is the first
+    # collection's
+    digest = hashlib.sha256()
+    history = run["trainer"].store.history
+    assert len(history) == 16
+    for element in history:
+        for field in dataclasses.fields(element):
+            value = getattr(element, field.name)
+            digest.update(field.name.encode())
+            if value is None:
+                digest.update(b"None")
+                continue
+            array = np.ascontiguousarray(value)
+            digest.update(f"{array.dtype}{array.shape}".encode())
+            digest.update(array.tobytes())
+    assert digest.hexdigest() == STORE_DIGESTS[run["method"]]
 
 
 def test_step_records_carry_gap_and_padding(run):

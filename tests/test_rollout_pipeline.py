@@ -15,6 +15,7 @@ Three contracts, per the pipeline's design:
 Plus unit tests of the :class:`RolloutPipeline` state machine itself.
 """
 
+import dataclasses
 import threading
 import time
 
@@ -125,20 +126,23 @@ class TestRolloutPipeline:
 
 
 # ---------------------------------------------------------------------------
-# PPO make_experience: pipelined vs serial
+# PPO and GRPO make_experience: pipelined vs serial
 # ---------------------------------------------------------------------------
 
 PROMPTS = ["hello world", "the quick brown fox", "lorem ipsum", "foo bar"] * 4
 
 
-def _ppo_trainer(tmp_path, depth, reward_fn, tag):
+def _trainer(method, tmp_path, depth, reward_fn, tag):
     import trlx_tpu.pipeline.offline_pipeline  # noqa: F401 (registration)
+    import trlx_tpu.trainer.grpo  # noqa: F401 (registration)
     import trlx_tpu.trainer.ppo  # noqa: F401 (registration)
-    from trlx_tpu.data.default_configs import default_ppo_config
+    from trlx_tpu.data.default_configs import default_grpo_config, default_ppo_config
     from trlx_tpu.pipeline import get_pipeline
     from trlx_tpu.trainer import get_trainer
 
-    cfg = default_ppo_config().evolve(
+    default = default_grpo_config if method == "grpo" else default_ppo_config
+    extra = dict(group_size=2) if method == "grpo" else {}
+    cfg = default().evolve(
         train=dict(
             seq_length=48,
             batch_size=8,
@@ -149,11 +153,13 @@ def _ppo_trainer(tmp_path, depth, reward_fn, tag):
             rollout_pipeline_depth=depth,
         ),
         model=dict(model_path="builtin:gpt2-test", num_layers_unfrozen=1),
+        tokenizer=dict(tokenizer_path="builtin:bytes"),
         method=dict(
             num_rollouts=16,
             chunk_size=4,
             ppo_epochs=1,
             gen_kwargs=dict(max_new_tokens=8, top_k=0, top_p=1.0, do_sample=True),
+            **extra,
         ),
     )
     trainer = get_trainer(cfg.train.trainer)(
@@ -177,19 +183,22 @@ def _slow_letter_reward(samples, prompts, outputs, **kwargs):
 def _assert_stores_identical(store_a, store_b):
     assert len(store_a) == len(store_b)
     for a, b in zip(store_a.history, store_b.history):
-        for field in ("query_tensor", "response_tensor", "logprobs", "values", "rewards"):
+        # PPORLElement or GRPORLElement: every field, behavior_logprobs (None
+        # off the async path) included
+        for field in dataclasses.fields(a):
             np.testing.assert_array_equal(
-                np.asarray(getattr(a, field)), np.asarray(getattr(b, field)),
-                err_msg=field,
+                np.asarray(getattr(a, field.name)), np.asarray(getattr(b, field.name)),
+                err_msg=field.name,
             )
 
 
 class TestPipelinedExperience:
-    def test_bit_identical_and_faster_than_serial(self, tmp_path):
+    @pytest.mark.parametrize("method", ["ppo", "grpo"])
+    def test_bit_identical_and_faster_than_serial(self, tmp_path, method):
         """Acceptance: depth 2 + a 60ms/chunk reward → same store, same
         exp_scores/*, overlap_frac > 0, lower wall-time than depth 0."""
-        serial = _ppo_trainer(tmp_path, 0, _slow_letter_reward, "serial")
-        piped = _ppo_trainer(tmp_path, 2, _slow_letter_reward, "piped")
+        serial = _trainer(method, tmp_path, 0, _slow_letter_reward, "serial")
+        piped = _trainer(method, tmp_path, 2, _slow_letter_reward, "piped")
 
         # first call covers compile; stores must already match bit-for-bit
         serial.make_experience(16)
@@ -208,15 +217,15 @@ class TestPipelinedExperience:
         dt_piped = time.perf_counter() - t0
 
         _assert_stores_identical(serial.store, piped.store)
-        for key in (
-            "exp_scores/mean",
-            "exp_scores/std",
-            "exp_scores/running_mean",
-            "exp_scores/running_std",
-        ):
+        keys = ["exp_scores/mean", "exp_scores/std"]
+        if method == "ppo":  # GRPO keeps the running moments but publishes none
+            keys += ["exp_scores/running_mean", "exp_scores/running_std"]
+        for key in keys:
             assert (
                 serial.make_experience_stats[key] == piped.make_experience_stats[key]
             ), key
+        assert serial.running_moments.mean == piped.running_moments.mean
+        assert serial.running_moments.std == piped.running_moments.std
 
         assert serial.make_experience_stats["throughput/rollout_overlap_frac"] == 0.0
         assert piped.make_experience_stats["throughput/rollout_overlap_frac"] > 0.0
@@ -247,17 +256,38 @@ class TestPipelinedExperience:
                 raise RuntimeError("reward backend down")
             return [0.0] * len(outputs)
 
-        trainer = _ppo_trainer(tmp_path, 2, exploding_reward, "err")
+        trainer = _trainer("ppo", tmp_path, 2, exploding_reward, "err")
         with pytest.raises(RuntimeError, match="reward backend down"):
             trainer.make_experience(16)
         assert _pipeline_threads() == []  # drained and joined, not leaked
 
-    def test_depth_zero_is_the_reference_path(self, tmp_path):
+    @pytest.mark.parametrize("method", ["ppo", "grpo"])
+    def test_depth_zero_is_the_reference_path(self, tmp_path, method):
         """The serial path never constructs a pipeline (no worker thread)."""
-        trainer = _ppo_trainer(tmp_path, 0, _slow_letter_reward, "ref")
+        trainer = _trainer(method, tmp_path, 0, _slow_letter_reward, "ref")
         trainer.make_experience(8)
         assert len(trainer.store) == 8
         assert _pipeline_threads() == []
+
+    @pytest.mark.parametrize("method", ["ppo", "grpo"])
+    def test_nonfinite_scores_are_zeroed_before_the_moments(self, tmp_path, method):
+        """One NaN and one inf from a reward endpoint are zeroed, counted,
+        and never reach the cumulative running moments."""
+
+        def flaky_reward(samples, prompts, outputs, **kwargs):
+            scores = [float(len(o)) for o in outputs]
+            scores[0], scores[-1] = float("nan"), float("inf")
+            return scores
+
+        trainer = _trainer(method, tmp_path, 0, flaky_reward, "nan")
+        trainer.make_experience(8)
+        assert len(trainer.store) == 8
+        assert trainer.make_experience_stats["health/nonfinite_scores"] == 4.0
+        moments = trainer.running_moments
+        assert np.isfinite([moments.mean, moments.std, moments.var]).all()
+        fields = ("advantage",) if method == "grpo" else ("rewards",)
+        for element in trainer.store.history:
+            assert all(np.isfinite(getattr(element, f)).all() for f in fields)
 
 
 # ---------------------------------------------------------------------------

@@ -437,15 +437,21 @@ class TestPriorityScheduling:
                 ids[None], mask[None], _keys(70 + i), metas=[f"a{i}"],
                 klass="actor",
             )
-        engine.step()
+        done = engine.step()
         assert engine.live == 1, "actor traffic took the reserved slot"
+        assert engine.pending == 1
         engine.enqueue_prompts(
             ids[None], mask[None], _keys(72), metas=["vip"],
             klass="interactive",
         )
-        engine.step()
-        assert engine.live == 2  # interactive admitted instantly
-        got = {c.meta for c in _drain_engine(engine)}
+        done += engine.step()
+        # interactive admitted instantly, into the reserved slot, while the
+        # second actor request still waits. `live` would not say so: a step
+        # ends with a harvest, and a0 (5 tokens under this seed, segments of
+        # 3) finishes inside this very step and leaves its slot free
+        assert "vip" in {meta for _, meta, _ in engine.progress_snapshot()}
+        assert engine.pending == 1
+        got = {c.meta for c in done + _drain_engine(engine)}
         assert got == {"a0", "a1", "vip"}
 
 

@@ -2,10 +2,11 @@
 continuous batching"): draft-model decode segments for the paged Engine.
 
 The pinned contract: with ``engine.speculative = k`` on, every sequence
-harvested from the continuous-batching Engine — tokens, logprobs, values,
-mask — is BIT-IDENTICAL to a solo ``ops/speculative.py`` run of that row
-under its per-row RNG chain, regardless of block size, prefix hits,
-refills, chunked prefill, or segment size. The mechanism is structural:
+harvested from the continuous-batching Engine is the solo
+``ops/speculative.py`` run of that row under its per-row RNG chain — tokens
+and mask bit for bit, logprobs and values to a few float32 ulp (see
+``FLOAT_ULPS``) — regardless of block size, prefix hits, refills, chunked
+prefill, or segment size. The mechanism is structural:
 the segment's round body IS ``ops/speculative.py::spec_round_step`` (one
 function, not mirrored code), so these tests pin the paged plumbing around
 it — the gather/scatter commit discipline, the refill prefills, and the
@@ -137,14 +138,27 @@ def _harvest_all(m, fns, ids, mask, keys, params=None, prefill_chunk=0):
     return got, eng
 
 
+# The engine and the solo reference share the round body but not the program
+# around it: the engine's forward runs B=2 rows over a paged (gathered) cache,
+# the solo run one row over a dense one, and XLA tiles and orders the two
+# matmul reductions differently. The float outputs therefore agree to the last
+# bit or two of float32 (seen: 1 ulp on a logprob near 6, 2e-8 on a value near
+# 0.01), not bitwise. What sampling decides stays exact: tokens and masks.
+FLOAT_ULPS = 4 * float(np.finfo(np.float32).eps)
+
+
 def _assert_parity(got, refs, ctx):
     assert sorted(got) == list(range(len(refs)))
     for i, ref in enumerate(refs):
         for f in FIELDS:
-            np.testing.assert_array_equal(
-                np.asarray(got[i][f]), ref[f],
-                err_msg=f"{ctx}: request {i} field {f}",
-            )
+            err_msg = f"{ctx}: request {i} field {f}"
+            if f in ("tokens", "mask"):
+                np.testing.assert_array_equal(np.asarray(got[i][f]), ref[f], err_msg=err_msg)
+            else:
+                np.testing.assert_allclose(
+                    np.asarray(got[i][f]), ref[f], rtol=FLOAT_ULPS, atol=FLOAT_ULPS,
+                    err_msg=err_msg,
+                )
 
 
 class TestBitParity:

@@ -21,6 +21,7 @@ shape bucket.
 """
 
 import os
+from collections import deque
 from contextlib import ExitStack
 from time import perf_counter
 from typing import Any, Dict, Optional, Tuple
@@ -145,9 +146,16 @@ class PPOTrainer(TPUBaseTrainer):
     # rollout collection
     # ------------------------------------------------------------------
 
+    # hook 1 of 3 (stage map below): rollout rows one prompt becomes,
+    # group-contiguous
+    _rollout_fanout = 1
+
     def add_prompt_pipeline(self, pipeline: BasePipeline) -> None:
+        # one loader row fans out into _rollout_fanout rollout rows
         loader = pipeline.create_loader(
-            self.config.method.chunk_size, shuffle=True, seed=self.config.train.seed
+            max(self.config.method.chunk_size // self._rollout_fanout, 1),
+            shuffle=True,
+            seed=self.config.train.seed,
         )
         # prompt collation prefetches on a background thread when the rollout
         # pipeline is on, so chunk dispatch never stalls on next(...); the
@@ -380,8 +388,15 @@ class PPOTrainer(TPUBaseTrainer):
     #              Pure w.r.t. its inputs (no trainer state mutation);
     #   finalize — main thread, strictly in submission order: running-
     #              moments update (the one sequential dependency — reward
-    #              scaling must fold chunks in order), KL-penalty assembly,
-    #              PPORLElement construction.
+    #              scaling must fold chunks in order), then the algorithm's
+    #              reward or advantage assembly and element construction.
+    #
+    # Four drivers run the stages (_collect_serial, _collect_pipelined,
+    # _collect_continuous, _collect_async) and make_experience sums them up;
+    # a subclass changes none of that. What an algorithm owns is three hooks:
+    # _rollout_fanout (rows per prompt), _chunk_element_fn (scores → stored
+    # elements, inside finalize) and _collection_summary (the reward and KL
+    # keys of the collection's record). GRPO is exactly those three.
     #
     # Within one make_experience call the params never change, so running
     # chunk k+1's generation while chunk k's host work drains is *exactly*
@@ -430,23 +445,23 @@ class PPOTrainer(TPUBaseTrainer):
                 leaf.copy_to_host_async()
         return score_out
 
-    def _rollout_chunk_device(self, stats: Dict[str, float]) -> Dict[str, Any]:
-        """Main-thread device side of one chunk: prompt fetch, generation,
-        and the scoring-forward dispatch with async device→host copies."""
-        prompt_ids, prompt_mask = self._next_prompt_chunk()
-        return self._chunk_device(prompt_ids, prompt_mask, stats)
+    def _fan_out(self, prompt_ids, prompt_mask) -> Tuple[np.ndarray, np.ndarray]:
+        """Every prompt row repeated ``_rollout_fanout`` times, group-
+        contiguous (rows ``g*F .. g*F+F-1`` are group ``g``)."""
+        fanout = self._rollout_fanout
+        if fanout > 1:
+            prompt_ids = np.repeat(prompt_ids, fanout, axis=0)
+            prompt_mask = np.repeat(prompt_mask, fanout, axis=0)
+        return prompt_ids, prompt_mask
 
-    def _next_prompt_chunk(self, repeat: int = 1) -> Tuple[np.ndarray, np.ndarray]:
-        """The next prompt batch as host ``(ids, mask)``, every row repeated
-        ``repeat`` times (GRPO's group-contiguous fan-out)."""
+    def _next_prompt_chunk(self) -> Tuple[np.ndarray, np.ndarray]:
+        """The next prompt batch as host ``(ids, mask)``, fanned out."""
         with self.obs.span("collect/prompts"):
             batch = next(self.prompt_iterator)
-            prompt_ids = np.asarray(batch["input_ids"], np.int32)
-            prompt_mask = np.asarray(batch["attention_mask"], np.int32)
-            if repeat > 1:
-                prompt_ids = np.repeat(prompt_ids, repeat, axis=0)
-                prompt_mask = np.repeat(prompt_mask, repeat, axis=0)
-        return prompt_ids, prompt_mask
+            return self._fan_out(
+                np.asarray(batch["input_ids"], np.int32),
+                np.asarray(batch["attention_mask"], np.int32),
+            )
 
     def _chunk_device(
         self,
@@ -546,6 +561,18 @@ class PPOTrainer(TPUBaseTrainer):
             "blocked_s": reward_sp.duration + score_wait,
         }
 
+    def _host_stage_job(self, dev: Dict[str, Any]):
+        """The host stage of ``dev`` as a job for the pipeline worker."""
+
+        def work() -> Dict[str, Any]:
+            # fenced: the span closes only once the scoring outputs are
+            # device-complete, so its duration is host-true
+            with self.obs.span("rollout/overlap") as sp:
+                sp.fence(dev["score_out"])
+                return self._rollout_chunk_host(dev)
+
+        return work
+
     def _rollout_chunk_finalize(
         self,
         chunk: Dict[str, Any],
@@ -554,59 +581,29 @@ class PPOTrainer(TPUBaseTrainer):
         acc: Dict[str, float],
     ) -> None:
         """Ordered tail of one chunk — the sequential dependencies. Runs on
-        the main thread in submission order in BOTH modes, so reward scaling
+        the main thread in submission order in EVERY mode, so reward scaling
         (running moments) and the store contents are bit-identical between
-        depth 0 and depth ≥ 1."""
+        depth 0 and depth ≥ 1. What turns scores into stored elements is the
+        algorithm's (:meth:`_chunk_element_fn`); the rest is shared."""
         with self.obs.span("collect/finalize"):
             _add_times(stats, chunk["stats"])
             acc["host_s"] += chunk["host_s"]
-            scores = chunk["scores"]
             response_mask = chunk["response_mask"]
             response_tokens = chunk["response_tokens"]
             host = chunk["host"]
 
-            # reward scaling/clipping (reference :350-366). Non-finite scores
-            # (a flaky reward endpoint, an overflowed RM) are zeroed BEFORE the
-            # running moments fold them in — RunningMoments state is cumulative,
-            # so one NaN would poison every subsequently scaled reward.
-            scores = np.asarray(scores, np.float32)
+            # Non-finite scores (a flaky reward endpoint, an overflowed RM)
+            # are zeroed BEFORE the running moments fold them in —
+            # RunningMoments state is cumulative, so one NaN would poison
+            # every subsequently scaled reward.
+            scores = np.asarray(chunk["scores"], np.float32)
             nonfinite = ~np.isfinite(scores)
             if nonfinite.any():
                 stats["health/nonfinite_scores"] = stats.get(
                     "health/nonfinite_scores", 0.0
                 ) + float(nonfinite.sum())
                 scores = np.where(nonfinite, 0.0, scores)
-            scores_mean, scores_std = self.running_moments.update(scores)
-            stats["exp_scores/mean"] = float(scores_mean)
-            stats["exp_scores/std"] = float(scores_std)
-            stats["exp_scores/running_mean"] = float(self.running_moments.mean)
-            stats["exp_scores/running_std"] = float(self.running_moments.std)
-            if self.config.method.scale_reward == "running":
-                scores /= max(self.running_moments.std, 1e-8)
-            elif self.config.method.scale_reward == "ref":
-                scores /= max(self.ref_std or 1.0, 1e-8)
-            clip = self.config.method.cliprange_reward
-            if clip:
-                scores = np.clip(scores, -clip, clip)
-
-            # KL-penalty reward assembly on host (numpy twin of the device
-            # math; [B, N] arrays — microseconds)
-            rewards, (mean_kl, mean_kl_per_seq) = kl_penalty_rewards_np(
-                host["logprobs"], host["ref_logprobs"], response_mask,
-                scores, self.kl_ctl.value,
-            )
-            # a non-finite chunk KL (one overflowed logprob) must reach neither
-            # the adaptive controller's accumulator nor the tracker stream —
-            # max(nan, 0.0) is nan, so the old sqrt guard passed NaN through
-            if np.isfinite(mean_kl):
-                acc["kl_sum"] += mean_kl
-                acc["kl_batches"] += 1
-                stats["policy/sqrt_kl"] = float(np.sqrt(max(mean_kl, 0.0)))
-            else:
-                stats["health/nonfinite_kl_chunks"] = stats.get(
-                    "health/nonfinite_kl_chunks", 0.0
-                ) + 1.0
-                stats["policy/sqrt_kl"] = 0.0
+            element_of = self._chunk_element_fn(chunk, scores, stats, acc)
             acc["gen_tokens"] += int(response_mask.sum())
             acc["chunks"] += 1
 
@@ -660,16 +657,12 @@ class PPOTrainer(TPUBaseTrainer):
                 n_i = int(response_mask[i].sum())
                 if n_i == 0:
                     continue
-                query = prompt_ids[i][prompt_mask[i] > 0]
                 elements.append(
-                    PPORLElement(
-                        query_tensor=query,
-                        # host[...] landed via to_host: already numpy, slices
-                        # need no re-asarray
+                    element_of(
+                        i,
+                        n_i,
+                        query_tensor=prompt_ids[i][prompt_mask[i] > 0],
                         response_tensor=response_tokens[i, :n_i],
-                        logprobs=host["logprobs"][i, :n_i],
-                        values=host["values"][i, :n_i],
-                        rewards=rewards[i, :n_i],
                         behavior_logprobs=(
                             np.asarray(behavior[i, :n_i], np.float32)
                             if behavior is not None
@@ -677,6 +670,64 @@ class PPOTrainer(TPUBaseTrainer):
                         ),
                     )
                 )
+
+    def _chunk_element_fn(
+        self,
+        chunk: Dict[str, Any],
+        scores: np.ndarray,  # [B] float32, finite
+        stats: Dict[str, float],
+        acc: Dict[str, float],
+    ):
+        """Hook 2 of 3: fold one chunk's scores into the algorithm's state
+        (running moments, ``acc["kl_sum"]`` / ``acc["kl_batches"]``) and
+        return ``element(i, n_i, **common)`` building row ``i``'s stored
+        element from its first ``n_i`` response positions. PPO: reward
+        scaling (reference :350-366), clipping, KL-penalty rewards."""
+        host, response_mask = chunk["host"], chunk["response_mask"]
+        method: PPOConfig = self.config.method
+        scores_mean, scores_std = self.running_moments.update(scores)
+        stats["exp_scores/mean"] = float(scores_mean)
+        stats["exp_scores/std"] = float(scores_std)
+        stats["exp_scores/running_mean"] = float(self.running_moments.mean)
+        stats["exp_scores/running_std"] = float(self.running_moments.std)
+        if method.scale_reward == "running":
+            scores /= max(self.running_moments.std, 1e-8)
+        elif method.scale_reward == "ref":
+            scores /= max(self.ref_std or 1.0, 1e-8)
+        clip = method.cliprange_reward
+        if clip:
+            scores = np.clip(scores, -clip, clip)
+
+        # KL-penalty reward assembly on host (numpy twin of the device
+        # math; [B, N] arrays — microseconds)
+        rewards, (mean_kl, _) = kl_penalty_rewards_np(
+            host["logprobs"], host["ref_logprobs"], response_mask,
+            scores, self.kl_ctl.value,
+        )
+        # a non-finite chunk KL (one overflowed logprob) must reach neither
+        # the adaptive controller's accumulator nor the tracker stream —
+        # max(nan, 0.0) is nan, so the old sqrt guard passed NaN through
+        if np.isfinite(mean_kl):
+            acc["kl_sum"] += mean_kl
+            acc["kl_batches"] += 1
+            stats["policy/sqrt_kl"] = float(np.sqrt(max(mean_kl, 0.0)))
+        else:
+            stats["health/nonfinite_kl_chunks"] = stats.get(
+                "health/nonfinite_kl_chunks", 0.0
+            ) + 1.0
+            stats["policy/sqrt_kl"] = 0.0
+
+        def element(i: int, n_i: int, **common) -> PPORLElement:
+            # host[...] landed via to_host: already numpy, slices need no
+            # re-asarray
+            return PPORLElement(
+                logprobs=host["logprobs"][i, :n_i],
+                values=host["values"][i, :n_i],
+                rewards=rewards[i, :n_i],
+                **common,
+            )
+
+        return element
 
     def _collect_serial(
         self, num_rollouts: int, elements: list, stats: Dict[str, float],
@@ -689,7 +740,7 @@ class PPOTrainer(TPUBaseTrainer):
             # the span feeds the trace; the time/rollout *stat* is computed
             # uniformly for both modes in make_experience (wall ÷ chunks)
             with self.obs.span("rollout"):
-                dev = self._rollout_chunk_device(stats)
+                dev = self._chunk_device(*self._next_prompt_chunk(), stats)
                 chunk = self._rollout_chunk_host(dev)
             acc["blocked_s"] += chunk["blocked_s"]
             self._rollout_chunk_finalize(chunk, elements, stats, acc)
@@ -704,8 +755,6 @@ class PPOTrainer(TPUBaseTrainer):
         while up to ``depth`` chunks of host work drain on the pipeline
         worker. Finalization happens on this thread in submission order —
         see the stage map above for why the result is bit-identical."""
-        from collections import deque
-
         from trlx_tpu.pipeline.rollout_pipeline import RolloutPipeline
 
         # upper-bound row count of each in-flight chunk, submission order
@@ -733,18 +782,10 @@ class PPOTrainer(TPUBaseTrainer):
                 # the "rollout" span covers the device side only here; the
                 # host side shows up as "rollout/overlap" on the worker tid
                 with self.obs.span("rollout", pipelined=True) as rollout_sp:
-                    dev = self._rollout_chunk_device(stats)
+                    dev = self._chunk_device(*self._next_prompt_chunk(), stats)
                 _add_times(stats, {"time/rollout_device": rollout_sp.duration})
                 rows_in_flight.append(int(dev["prompt_ids"].shape[0]))
-
-                def work(dev=dev):
-                    # fenced: the span closes only once the scoring outputs
-                    # are device-complete, so its duration is host-true
-                    with self.obs.span("rollout/overlap") as sp:
-                        sp.fence(dev["score_out"])
-                        return self._rollout_chunk_host(dev)
-
-                pipe.submit(work)
+                pipe.submit(self._host_stage_job(dev))
             pipe_stats = pipe.stats
         # reward and the wait for the scoring outputs ran on the worker: the
         # main thread was blocked on them only while it waited on the pipe
@@ -761,7 +802,7 @@ class PPOTrainer(TPUBaseTrainer):
         """Device side of one harvested group: assemble the score batch from
         individually completed sequences and dispatch the scoring forward
         with async device→host copies — the same ``dev`` contract as
-        :meth:`_rollout_chunk_device`, so the host/finalize stages are
+        :meth:`_chunk_device`, so the host/finalize stages are
         shared verbatim with the chunked paths."""
         prompt_ids = np.stack([c.prompt_ids for c in group]).astype(np.int32)
         prompt_mask = np.stack([c.prompt_mask for c in group]).astype(np.int32)
@@ -868,29 +909,36 @@ class PPOTrainer(TPUBaseTrainer):
         sampling is bit-identical to plain ``generate`` under per-row RNG;
         the chunk barrier of the serial path is gone, so the store matches
         the serial-with-per-row-RNG store up to sequence order
-        (tests/test_continuous_batching.py)."""
-        from contextlib import ExitStack
+        (tests/test_continuous_batching.py).
 
+        The harvest is group-aware: rows carry ``(group, member)`` metas, a
+        group is ready when its ``_rollout_fanout`` members have completed,
+        and ready groups flush in completion order with members in member
+        order, so every score batch is group-contiguous. With fan-out 1
+        each completion is its own ready group."""
         from trlx_tpu.pipeline.rollout_pipeline import RolloutPipeline
 
         if num_rollouts <= 0:
             stats["throughput/rollout_overlap_frac"] = 0.0
             return
         gen_config, extra_kwargs = self._resolve_gen_config(eval_mode=False)
-        state = {"engine": None, "supplied": 0, "finalized_rows": 0}
-        harvest_buf: list = []
+        fanout = self._rollout_fanout
+        state = {"engine": None, "supplied": 0, "finalized_rows": 0, "next_group": 0}
+        partial: Dict[int, list] = {}  # group id → completed members
+        ready: deque = deque()  # fully-completed groups, completion order
 
         def fetch_chunk() -> None:
-            batch = next(self.prompt_iterator)
-            ids = np.asarray(batch["input_ids"], np.int32)
-            mask = np.asarray(batch["attention_mask"], np.int32)
-            keys = self._cb_chunk_keys(ids.shape[0])
+            ids, mask = self._next_prompt_chunk()
+            rows = ids.shape[0]
+            keys = self._cb_chunk_keys(rows)
+            metas = [(state["next_group"] + r // fanout, r % fanout) for r in range(rows)]
+            state["next_group"] += rows // fanout
             if state["engine"] is None:
                 state["engine"] = self._cb_make_engine(
-                    gen_config, extra_kwargs, ids.shape[0], ids.shape[1]
+                    gen_config, extra_kwargs, rows, ids.shape[1]
                 )
-            state["engine"].enqueue_prompts(ids, mask, keys)
-            state["supplied"] += ids.shape[0]
+            state["engine"].enqueue_prompts(ids, mask, keys, metas=metas)
+            state["supplied"] += rows
 
         def finalize(chunk: Dict[str, Any]) -> None:
             state["finalized_rows"] += int(chunk["prompt_ids"].shape[0])
@@ -907,18 +955,18 @@ class PPOTrainer(TPUBaseTrainer):
                     )
                 )
 
-            def submit_group(group: list) -> None:
-                dev = self._cb_group_device(group)
+            def flush(n_groups: int) -> None:
+                dev = self._cb_group_device(
+                    [
+                        member
+                        for _ in range(n_groups)
+                        for member in sorted(ready.popleft(), key=lambda c: c.meta[1])
+                    ]
+                )
                 if pipe is None:
                     finalize(self._rollout_chunk_host(dev))
-                    return
-
-                def work(dev=dev):
-                    with self.obs.span("rollout/overlap") as sp:
-                        sp.fence(dev["score_out"])
-                        return self._rollout_chunk_host(dev)
-
-                pipe.submit(work)
+                else:
+                    pipe.submit(self._host_stage_job(dev))
 
             while True:
                 # supply so the queue can (expected-case) cover the target;
@@ -930,20 +978,22 @@ class PPOTrainer(TPUBaseTrainer):
                 ):
                     fetch_chunk()
                 engine = state["engine"]
-                B = engine.B
+                groups_per_batch = max(engine.B // fanout, 1)
                 if not engine.busy:
-                    while harvest_buf:  # flush the (possibly partial) tail
-                        group, harvest_buf = harvest_buf[:B], harvest_buf[B:]
-                        submit_group(group)
+                    if ready:  # the (possibly partial) tail
+                        flush(len(ready))
                     if pipe is not None:
                         pipe.drain()
                     if len(elements) >= num_rollouts:
                         break
                     continue
-                harvest_buf.extend(engine.step())
-                while len(harvest_buf) >= B:
-                    group, harvest_buf = harvest_buf[:B], harvest_buf[B:]
-                    submit_group(group)
+                for c in engine.step():
+                    members = partial.setdefault(c.meta[0], [])
+                    members.append(c)
+                    if len(members) == fanout:
+                        ready.append(partial.pop(c.meta[0]))
+                while len(ready) >= groups_per_batch:
+                    flush(groups_per_batch)
             if pipe is not None:
                 stats["throughput/rollout_overlap_frac"] = pipe.stats.overlap_frac(
                     perf_counter() - t0
@@ -1136,11 +1186,17 @@ class PPOTrainer(TPUBaseTrainer):
         record of the (mixed-version) behavior policy."""
         stats: Dict[str, float] = {}
         if bool(getattr(self.config.train, "continuous_batching", False)):
+            if self._rollout_fanout > 1:
+                raise NotImplementedError(
+                    "async_rl + train.continuous_batching is implemented for a "
+                    "rollout fan-out of 1 (PPO) only: the group-aware harvest "
+                    "keeps the single-program CB loop. Drop one of the two."
+                )
             dev = self._async_produce_cb(spec, params, version, channel, stats)
         else:
             dev = self._chunk_device(
-                spec.prompt_ids, spec.prompt_mask, stats, params=params,
-                rng=spec.rng,
+                *self._fan_out(spec.prompt_ids, spec.prompt_mask), stats,
+                params=params, rng=spec.rng,
             )
         chunk = self._rollout_chunk_host(dev)
         chunk["stats"].update(stats)
@@ -1277,7 +1333,6 @@ class PPOTrainer(TPUBaseTrainer):
 
             with self.obs.span("collect/finalize", stage="collection"):
                 self.mean_kl = acc["kl_sum"] / max(acc["kl_batches"], 1)
-                stats["kl_ctl_value"] = self.kl_ctl.value
                 stats["time/rollout_host"] = acc["host_s"]
                 self._host_gap_t0 = perf_counter()  # the first step's gap starts here
                 total = self._host_gap_t0 - exp_time
@@ -1336,13 +1391,26 @@ class PPOTrainer(TPUBaseTrainer):
                     stats.setdefault(
                         "rollout/repetition_frac", acc["rep_pairs"] / acc["rep_total"]
                     )
-                self.obs.health.observe_rollout(stats)
+                self._collection_summary(stats, acc)
                 self.make_experience_stats = stats
                 self.tracker.log(stats, step=iter_count)
 
                 self.store.push(elements[:num_rollouts] if num_rollouts else elements)
                 if self.log_rollouts:
                     self.store.export_history(location=self.rollout_logging_dir)
+
+    def _collection_summary(
+        self, stats: Dict[str, float], acc: Dict[str, float]
+    ) -> None:
+        """Hook 3 of 3: the reward and KL keys of a collection's record
+        whose meaning is the algorithm's (``self.mean_kl`` is already the
+        collection's). PPO's ``exp_scores/*`` and ``policy/sqrt_kl`` are the
+        newest chunk's, set in :meth:`_chunk_element_fn`; the rollout health
+        detectors read PPO's vocabulary (``policy/sqrt_kl`` against the
+        controller target) and a trip dumps a triage batch through PPO's
+        ``_triage_extra``, so the feed stays here."""
+        stats["kl_ctl_value"] = self.kl_ctl.value
+        self.obs.health.observe_rollout(stats)
 
     # ------------------------------------------------------------------
     # optimization
