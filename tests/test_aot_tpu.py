@@ -411,3 +411,82 @@ def test_dropless_experts_are_grouped_kernels_at_olmoe_widths(topo, monkeypatch,
     matmul = 2 * assignments * cfg.hidden_size * cfg.intermediate_size
     flops = compiled.cost_analysis()["flops"]
     assert 3 * matmul * (3 if grad else 1) <= flops < 1.5 * 3 * matmul * (3 if grad else 1)
+
+
+# ---------------------------------------------------------------------------
+# the PPO learner's ladder of widths: one train-step program per rung
+# ---------------------------------------------------------------------------
+
+# what the compiler gives the parent's one train step of ``gptj6b_ppo_hh``
+# (8 x (896 + 128), arguments + outputs + temporaries - aliased), PR 29's tree
+PARENT_STEP_BYTES_AT_1024 = 9_329_875_968
+
+
+@pytest.fixture(scope="module")
+def gptj_ppo_step(topo):
+    """The abstract PPO trainer of ``gptj-6b-l4`` as ``gptj6b_ppo_hh`` builds
+    it (published widths, depth 4, two layers unfrozen, bf16), on one
+    described v5e, with its train step and the state's shapes."""
+    import dataclasses
+
+    from chipbench import job
+    from trlx_tpu import perf
+    from trlx_tpu.parallel.mesh import make_mesh, set_global_mesh
+    from trlx_tpu.parallel.sharding import param_shardings
+    from trlx_tpu.trainer.base import _optimizer_state_shardings
+
+    cell = job.find_cell("gptj6b_ppo_hh")
+    cfg = job.build_config(job.load_config(cell["config"]), job.load_json("traffic", cell["traffic"]),
+                           0, toy=False, ckpt_dir="/nonexistent")
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        # the trainer makes its mesh of every device JAX has (eight virtual
+        # CPUs under these tests) and places its step counter there: build
+        # it on one of them, then hand it the one described chip
+        import trlx_tpu.trainer.base as base
+
+        mp.setattr(base, "make_mesh", lambda parallel: make_mesh(parallel, devices=jax.devices()[:1]))
+        trainer = perf._build_abstract_trainer(cfg)
+        mesh = trainer.mesh = make_mesh(cfg.parallel, devices=topo.devices[:1])
+        place = lambda tree, sh: jax.tree_util.tree_map(  # noqa: E731
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s), tree, sh)
+        params = place(trainer.state.params, param_shardings(trainer.state.params, mesh))
+        opt = place(trainer.state.opt_state,
+                    _optimizer_state_shardings(mesh, params, trainer.state.opt_state))
+        everywhere = NamedSharding(mesh, P())
+        state = dataclasses.replace(
+            trainer.state, params=params, opt_state=opt,
+            step=jax.ShapeDtypeStruct((), np.int32, sharding=everywhere),
+            rng=jax.ShapeDtypeStruct(trainer.state.rng.shape, trainer.state.rng.dtype,
+                                     sharding=everywhere))
+        set_global_mesh(mesh)
+        try:
+            yield trainer, trainer._build_train_step(), state, mesh
+        finally:
+            set_global_mesh(None)
+
+
+@pytest.mark.parametrize("query_width", [256, 512, 896])
+def test_ppo_train_step_compiles_at_each_ladder_width(gptj_ppo_step, monkeypatch, query_width):
+    """The learner's loader pads a minibatch of ``gptj6b_ppo_hh`` to one of
+    three widths (``length_ladder(896)`` + 128 new tokens): each is a program
+    of its own and must lower and compile for the chip, flash kernels in it,
+    and the widest (the parent's only one) may need no more memory than the
+    parent's. Bytes from the compiler; nothing runs."""
+    from trlx_tpu import perf
+    from trlx_tpu.pipeline.ppo_pipeline import length_ladder
+
+    trainer, step, state, mesh = gptj_ppo_step
+    assert length_ladder(896) == (256, 512, 896) and length_ladder(128) == (128,)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    batch = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=NamedSharding(mesh, P()))
+             for k, v in perf._train_batch_sds("ppotrainer", 8, query_width, 128).items()}
+    with mesh:
+        compiled = step.lower(state, batch, jax.ShapeDtypeStruct((), np.float32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total <= PARENT_STEP_BYTES_AT_1024, (query_width, total)
+    if query_width == 896:
+        assert total > 0.95 * PARENT_STEP_BYTES_AT_1024  # the same program, not a smaller one

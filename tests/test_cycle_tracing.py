@@ -6,7 +6,7 @@ steps), recorded through a stand-in tracker, then:
 
 - collection records carry one vocabulary in both trainers, each ``time/*``
   key a sum over the collection's chunks;
-- step records carry ``time/step_gap`` and ``learn/pad_frac``;
+- step records carry ``time/step_gap``, ``learn/pad_frac`` and ``learn/step_width``;
 - the spans cover the cycle, carry ``cycle=<n>``, and tile the learn phase;
 - the first collection's store is, byte for byte, the one the two separate
   collectors built before they became one (PR 29), at either pipeline depth;
@@ -172,15 +172,18 @@ def test_step_records_carry_gap_and_padding(run):
     assert len(steps) == 2
     assert all(r["time/step_gap"] > 0 for r in steps)
     # the run ended before the post-epoch refill: the store still holds what
-    # the two steps trained on, in batches of known padding
-    loader = trainer.store.create_loader(8, shuffle=True, seed=trainer.config.train.seed)
+    # the two steps trained on, in batches of known padding, on the one rung
+    # this job's budget has (16 prompt + 8 new tokens)
     want = []
-    for batch in loader:
+    for batch in trainer._learner_loader():
         masks = [np.asarray(batch.query_mask), np.asarray(batch.response_mask)]
+        assert [m.shape[1] for m in masks] == [16, 8]
         want.append(1.0 - sum(m.sum() for m in masks) / sum(m.size for m in masks))
     got = [r["learn/pad_frac"] for r in steps]
     assert sorted(got) == pytest.approx(sorted(want))
     assert all(0.0 < g < 1.0 for g in got)
+    assert [r["learn/step_width"] for r in steps] == [24.0, 24.0]
+    assert steps[-1]["learn/step_shapes"] == 1.0 and "recompile/train_step" not in steps[-1]
     # with time/train_step the gap tiles the learn phase: the second step's
     # gap is the host time from the first step's fence to its own span
     first, second = sorted(_spans(run, "train_step"), key=lambda e: e["ts"])
@@ -194,8 +197,8 @@ def test_known_padding_gives_the_known_fraction(run):
         "response_mask": np.array([[1, 1, 0, 0], [1, 0, 0, 0]], np.int32),
         "rewards": np.zeros((2, 4), np.float32),
     }
-    assert run["trainer"]._batch_token_counts(batch) == (11, 16)
-    assert run["trainer"]._batch_token_counts({"attention_mask": np.eye(3)}) == (3, 9)
+    assert run["trainer"]._batch_token_counts(batch) == (11, 16, 8)
+    assert run["trainer"]._batch_token_counts({"attention_mask": np.eye(3)}) == (3, 9, 3)
 
 
 def test_spans_cover_the_cycle(run):
@@ -321,3 +324,110 @@ def test_host_gaps_cuts_idle_at_span_boundaries():
     modules = {"/device:TPU:0": [("jit_train_step(1)", 0.1, 0.9), ("jit_train_step(1)", 3.0, 1.5)]}
     # the second program outlasts its span: a fence that is not inside
     assert host_gaps.train_step_fences(spans, modules) == (1, 2)
+
+
+# ---------------------------------------------------------------------------
+# the learner's pad policy: length-grouped minibatches on a ladder of widths
+# (trainer/ppo.py::_learner_loader; loader tests in tests/test_pipelines.py)
+# ---------------------------------------------------------------------------
+
+# byte prompts of uneven lengths: sorted into four minibatches of eight their
+# longest rows are 11, 25, 39 and 60 tokens
+UNEVEN = [3, 4, 5, 6, 7, 8, 9, 11, 12, 14, 16, 18, 20, 22, 24, 25,
+          26, 28, 30, 32, 34, 36, 38, 39, 40, 44, 48, 52, 56, 58, 59, 60]
+
+
+def _uneven_run(method, tmp_path, monkeypatch, total_steps, **model):
+    """Two cycles of a toy job whose query budget is 64 tokens, with the
+    ladder's smallest rung lowered from 128 to 16 for the toy widths: rungs
+    16, 32 and 64 for the queries, 8 for the responses."""
+    from trlx_tpu.pipeline import ppo_pipeline
+
+    monkeypatch.setattr(ppo_pipeline, "LADDER_BASE", 16)
+    default = default_grpo_config if method == "grpo" else default_ppo_config
+    lengths = UNEVEN[::4] if method == "grpo" else UNEVEN  # 8 prompts x group 4
+    config = default().evolve(
+        train=dict(
+            seq_length=72, batch_size=8, total_steps=total_steps, eval_interval=100,
+            checkpoint_interval=100, epochs=2, save_best=False, tracker=None,
+            checkpoint_dir=str(tmp_path / "ckpts"), logging_dir=str(tmp_path / "logs"),
+        ),
+        model=dict(model_path="builtin:gpt2-test", num_layers_unfrozen=1, **model),
+        tokenizer=dict(tokenizer_path="builtin:bytes"),
+        method=dict(
+            num_rollouts=32, chunk_size=len(lengths), ppo_epochs=1,
+            gen_kwargs=dict(max_new_tokens=8, min_new_tokens=8, top_k=0, top_p=1.0,
+                            do_sample=True),
+            **(dict(group_size=4) if method == "grpo" else {}),
+        ),
+    )
+    recorder = Recorder()
+
+    def hook(trainer):
+        trainer.tracker = recorder
+
+    def reward_fn(samples, prompts, outputs, **kwargs):
+        return [float(len(o)) + 0.1 * i for i, o in enumerate(outputs)]
+
+    rng = np.random.RandomState(0)
+    prompts = ["".join(chr(97 + c) for c in rng.randint(0, 26, size=n)) for n in lengths]
+    trainer = trlx.train(reward_fn=reward_fn, prompts=prompts, config=config,
+                         init_trainer_hook=hook)
+    return trainer, [r for r in recorder.records if "time/train_step" in r]
+
+
+@pytest.mark.parametrize("method", ["ppo", "grpo"])
+def test_uneven_rows_compile_one_train_step_per_ladder_shape(method, tmp_path, monkeypatch):
+    """No outside pin: the trainer's own loader keeps every minibatch on
+    ladder x ladder, so two cycles of uneven rows compile one train step per
+    shape, none of them counted as a recompile."""
+    trainer, steps = _uneven_run(method, tmp_path, monkeypatch, total_steps=8)
+    assert trainer._step_ladders == ((16, 32, 64), (8,))
+    assert len(steps) == 8
+    widths = [r["learn/step_width"] for r in steps]
+    assert sorted(widths[:4]) == sorted(widths[4:]) == [24.0, 40.0, 72.0, 72.0]
+    shapes = len(set(widths))
+    assert shapes == 3
+    assert trainer._train_step_fn._cache_size() == shapes
+    assert steps[-1]["learn/step_shapes"] == float(shapes)
+    assert [r["learn/step_shapes"] for r in steps[4:]] == [3.0] * 4  # the second cycle adds none
+    assert all(r.get("recompile/train_step", 0.0) == 0.0 for r in steps)
+    assert trainer.obs.recompile.excess_compiles("train_step") == 0
+    # grouped rows waste less: the uniform partition would pad every step to 72
+    assert np.mean([r["learn/pad_frac"] for r in steps]) < 0.45
+
+
+def test_loss_and_gradients_do_not_depend_on_the_padded_width(tmp_path, monkeypatch):
+    """PPO with value head and hydra branch, float32: the same eight rows
+    collated at the rung they need (32) and at the job's maximum (64, what
+    the benchmark's pin fed every step) give one loss, one set of stats and
+    one set of gradients."""
+    trainer, _ = _uneven_run("ppo", tmp_path, monkeypatch, total_steps=1,
+                             model_extra_kwargs={"dtype": "float32"})
+    assert trainer.num_layers_unfrozen == 1 and "v_head" in str(
+        jax.tree_util.tree_structure(trainer.state.params))
+    rows = sorted(trainer.store.history, key=lambda e: len(e.query_tensor))[8:16]
+    assert max(len(e.query_tensor) for e in rows) == 25
+
+    @jax.jit
+    def value_and_grad(params, batch):
+        (loss, stats), grads = jax.value_and_grad(trainer.loss_fn, has_aux=True)(
+            params, batch, jax.random.PRNGKey(0))
+        return loss, stats, grads
+
+    results = []
+    for width in ((16, 32, 64), 64):
+        batch = trainer.store.collate(rows, query_length=width, response_length=(8,))
+        assert batch.query_tensors.shape == (8, 32 if width != 64 else 64)
+        arrays = shard_batch({k: v for k, v in batch._asdict().items() if v is not None},
+                             trainer.mesh)
+        results.append(jax.device_get(value_and_grad(trainer.state.params, arrays)))
+    (loss_a, stats_a, grads_a), (loss_b, stats_b, grads_b) = results
+    assert np.isfinite(loss_a) and loss_a == pytest.approx(loss_b, abs=1e-5)
+    assert stats_a.keys() == stats_b.keys()
+    for key in stats_a:
+        np.testing.assert_allclose(stats_a[key], stats_b[key], atol=1e-5, err_msg=key)
+    flat_a, flat_b = (jax.tree_util.tree_leaves(g) for g in (grads_a, grads_b))
+    assert any(np.abs(g).max() > 1e-4 for g in flat_a)
+    for a, b in zip(flat_a, flat_b):
+        np.testing.assert_allclose(a, b, atol=1e-5)

@@ -147,6 +147,28 @@ def test_entropy_collapse_trips_once_window_full():
     assert mon.trip_counts["entropy_collapse"] == 1
 
 
+def test_a_detector_that_flaps_cues_one_dump(trlx_log_records):
+    """An untrained critic's explained variance hovers around 0: the
+    windowed mean crosses the floor again and again. Every crossing is
+    counted and shows on the gauge; only the first cues the flight-record and
+    triage dump (``just_tripped``), whose un-jitted forward stalls the loop."""
+    mon = _monitor()
+    bad = {"values/values_error": 1.2, "returns/std": 1.0}   # EV -0.2
+    good = {"values/values_error": 0.7, "returns/std": 1.0}  # EV +0.3
+    cues, gauge = [], []
+    for step, stats in enumerate([bad, bad, good, good, bad, bad, good, good, bad, bad]):
+        gauge.append(mon.update(stats, step=step)["health/value_ev_collapse"])
+        cues.append(mon.just_tripped)
+    assert gauge == [0.0, 1.0, 0.0, 0.0, 0.0, 1.0, 0.0, 0.0, 0.0, 1.0]
+    assert mon.trip_counts["value_ev_collapse"] == 3
+    assert cues == [None, "value_ev_collapse"] + [None] * 8
+    assert sum("value_ev_collapse tripped" in r.getMessage() for r in trlx_log_records) == 1
+    # another detector's first trip still cues its own dump
+    mon.update({"dist/entropy_p50": 0.01}, step=10)
+    mon.update({"dist/entropy_p50": 0.01}, step=11)
+    assert mon.just_tripped == "entropy_collapse"
+
+
 def test_kl_runaway_vs_controller_target():
     mon = _monitor(kl_target=0.1)
     for _ in range(2):

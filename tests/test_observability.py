@@ -289,6 +289,39 @@ class TestRecompileWatchdog:
         assert reg.counter("recompile/generate") == 1
         assert dog.excess_compiles("generate") == 1
 
+    @pytest.mark.parametrize("tracked_by", ["cache_size", "signature"])
+    def test_first_compile_of_a_planned_shape_is_expected(
+        self, trlx_log_records, tracked_by
+    ):
+        """A pad policy feeds one program a fixed set of shapes (the PPO
+        learner's ladder of widths): the first compile of each shape it names
+        is warm-up; a second compile at a shape already seen, and any compile
+        at a shape it did not plan, still count."""
+        reg = MetricsRegistry()
+        dog = RecompileWatchdog(reg)
+        if tracked_by == "cache_size":
+            fn = jax.jit(lambda x: x * 2)
+            call = lambda x: (fn(x), dog.observe("train_step", fn, planned=x.shape))[1]  # noqa: E731
+        else:
+            fn = lambda x: x  # noqa: E731 — no _cache_size attr
+            call = lambda x: dog.observe("train_step", fn, args=(x,), planned=x.shape)  # noqa: E731
+        for width in (256, 384, 640, 1024, 384, 256):
+            assert call(jnp.ones((width,))) == 0
+        assert reg.counter("recompile/train_step") == 0
+        assert not trlx_log_records
+        # the same planned shape compiles again (a dtype drift): counted
+        assert call(jnp.ones((384,), jnp.int32)) == 1
+        assert reg.counter("recompile/train_step") == 1
+        assert any("retraced" in r.getMessage() for r in trlx_log_records)
+        # a shape outside the plan: counted
+        if tracked_by == "cache_size":
+            fn(jnp.ones((100,)))
+            assert dog.observe("train_step", fn) == 2
+        else:
+            assert dog.observe("train_step", fn, args=(jnp.ones((100,)),)) == 2
+        assert reg.counter("recompile/train_step") == 2
+        assert dog.excess_compiles("train_step") == 2
+
     def test_warning_flood_is_capped(self, trlx_log_records):
         dog = RecompileWatchdog(max_warnings=2)
         fn = lambda x: x  # noqa: E731

@@ -286,3 +286,170 @@ def test_prefetch_loader_cancels_promptly():
     it.close()  # generator close runs the finally: must cancel, not drain
     assert time.time() - t0 < 2.0
     assert len(collated) < 50  # worker stopped early, not 4000 collations
+
+
+# ---------------------------------------------------------------------------
+# the rollout stores' pad policy: length-grouped minibatches on a ladder of
+# widths (PPORolloutStorage.create_loader with ladders; the PPO and GRPO
+# learners pass them, trainer/ppo.py::_learner_loader)
+# ---------------------------------------------------------------------------
+
+# one cycle of the ``ppo_hh`` traffic: 32 query lengths on a geometric grid
+HH_LENGTHS = [int(round(x / 8.0)) * 8 for x in np.geomspace(64, 896, 32)]
+QUERY_LADDER, RESPONSE_LADDER = (256, 512, 896), (128,)
+
+
+def _rollout_store(kind, query_lengths, response_length=128, order_seed=0):
+    """A PPO or GRPO store whose row ``i`` carries ``i`` as its first
+    response token, in an order drawn from ``order_seed``."""
+    from trlx_tpu.data.grpo_types import GRPORLElement
+    from trlx_tpu.pipeline.grpo_pipeline import GRPORolloutStorage
+
+    lengths = list(query_lengths)
+    np.random.RandomState(order_seed).shuffle(lengths)
+    store = (GRPORolloutStorage if kind == "grpo" else PPORolloutStorage)(pad_token_id=0)
+    rows = []
+    for i, q in enumerate(lengths):
+        response = np.full(response_length, 7, np.int32)
+        response[0] = 1000 + i
+        per_token = np.zeros(response_length, np.float32)
+        common = dict(query_tensor=np.full(q, 5, np.int32), response_tensor=response,
+                      logprobs=per_token)
+        if kind == "grpo":
+            rows.append(GRPORLElement(ref_logprobs=per_token, advantage=float(i), **common))
+        else:
+            rows.append(PPORLElement(values=per_token, rewards=per_token, **common))
+    store.push(rows)
+    return store
+
+
+def _ids(batch):
+    return [int(t) - 1000 for t in np.asarray(batch.response_tensors)[:, 0]]
+
+
+def _ladder_loader(store, batch_size=8, seed=0, **kw):
+    return store.create_loader(batch_size, shuffle=True, seed=seed,
+                               query_length=QUERY_LADDER, response_length=RESPONSE_LADDER, **kw)
+
+
+def test_length_ladder_follows_the_budget():
+    from trlx_tpu.pipeline.ppo_pipeline import length_ladder, pad_length
+
+    assert length_ladder(896) == QUERY_LADDER and length_ladder(128) == RESPONSE_LADDER
+    assert length_ladder(512) == (256, 512) and length_ladder(2048) == (256, 512, 1024, 2048)
+    assert length_ladder(100) == (104,)
+    assert length_ladder(0) == () and length_ladder(-8) == ()
+    rows = [[0] * 260, [0] * 7]
+    assert pad_length(rows, QUERY_LADDER) == 512
+    assert pad_length(rows, 64) == 64 and pad_length(rows, None) is None
+    assert pad_length([[0] * 900], QUERY_LADDER) is None  # over the budget: not cut
+
+
+@pytest.mark.parametrize("kind", ["ppo", "grpo"])
+@pytest.mark.parametrize("seed", [0, 3141592653])
+def test_grouped_loader_feeds_every_row_once_on_the_ladder(kind, seed):
+    store = _rollout_store(kind, HH_LENGTHS, order_seed=seed)
+    batches = list(_ladder_loader(store, seed=seed))
+    assert len(batches) == 4
+    assert sorted(i for b in batches for i in _ids(b)) == list(range(32))
+    widths = []
+    for b in batches:
+        assert b.query_tensors.shape[1] in QUERY_LADDER
+        assert b.response_tensors.shape == (8, 128) == b.logprobs.shape
+        # left-padded, nothing cut: every row keeps its own length
+        assert sorted(np.asarray(b.query_mask).sum(axis=1)) == sorted(
+            len(store[i].query_tensor) for i in _ids(b))
+        assert np.asarray(b.query_mask)[:, -1].all()
+        widths.append(b.query_tensors.shape[1])
+    # the four sorted minibatches' longest queries are 120, 232, 456, 896
+    assert sorted(widths) == [256, 256, 512, 896]
+    slots = sum(8 * (w + 128) for w in widths)
+    assert slots == 19456 and slots < 0.6 * 32 * 1024
+
+
+@pytest.mark.parametrize("kind", ["ppo", "grpo"])
+def test_grouped_loader_visits_widths_in_an_order_drawn_from_the_seed(kind):
+    store = _rollout_store(kind, HH_LENGTHS)
+    orders = {tuple(b.query_tensors.shape[1] for b in _ladder_loader(store, seed=s))
+              for s in range(12)}
+    assert len(orders) > 3  # not short to long, and not one fixed order
+    assert all(sorted(o) == [256, 256, 512, 896] for o in orders)
+
+
+@pytest.mark.parametrize("kind", ["ppo", "grpo"])
+def test_rows_of_one_length_give_the_ungrouped_batches(kind):
+    """Cells 1 and 3: every row on one rung, so the batches are the parent's
+    (the uniform partition at the pinned widths), element for element and in
+    order, epoch after epoch."""
+    store = _rollout_store(kind, [128] * 64, response_length=512)
+    from trlx_tpu.pipeline.ppo_pipeline import length_ladder
+
+    assert (length_ladder(128), length_ladder(512)) == ((128,), (256, 512))
+    grouped = store.create_loader(16, shuffle=True, seed=11, query_length=length_ladder(128),
+                                  response_length=length_ladder(512))
+    parent = store.create_loader(16, shuffle=True, seed=11, query_length=128,
+                                 response_length=512)
+    for _ in range(3):
+        got, want = list(grouped), list(parent)
+        assert len(got) == len(want) == 4
+        for g, w in zip(got, want):
+            for a, b in zip(g, w):
+                assert (a is None and b is None) or (
+                    a.shape == b.shape and a.dtype == b.dtype and (a == b).all())
+
+
+@pytest.mark.parametrize("kind", ["ppo", "grpo"])
+def test_grouped_partition_is_a_function_of_seed_and_store(kind):
+    store = _rollout_store(kind, HH_LENGTHS)
+    first, second = _ladder_loader(store, seed=5), _ladder_loader(store, seed=5)
+    epochs = [[_ids(b) for b in first] for _ in range(2)]
+    assert [_ids(b) for b in second] == epochs[0]
+    assert epochs[0] != epochs[1]  # a fresh shuffle every epoch
+    # emergency resume skips an epoch without iterating it
+    resumed = _ladder_loader(store, seed=5)
+    resumed.advance_epoch()
+    assert [_ids(b) for b in resumed] == epochs[1]
+    assert [_ids(b) for b in _ladder_loader(store, seed=6)] != epochs[0]
+
+
+@pytest.mark.parametrize("kind", ["ppo", "grpo"])
+@pytest.mark.parametrize("drop_last", [True, False])
+def test_grouped_loader_keeps_drop_last(kind, drop_last):
+    """The rows that do not fill a batch are the ones the uniform loader
+    leaves over (the tail of the shuffle), not the longest."""
+    store = _rollout_store(kind, HH_LENGTHS + [72, 600, 896])
+    grouped = _ladder_loader(store, seed=2, drop_last=drop_last)
+    uniform = store.create_loader(8, shuffle=True, seed=2, drop_last=drop_last)
+    got, want = [_ids(b) for b in grouped], [_ids(b) for b in uniform]
+    assert len(got) == len(want) == len(grouped) == (4 if drop_last else 5)
+    assert sorted(i for b in got for i in b) == sorted(i for b in want for i in b)
+    if not drop_last:
+        assert got[-1] == want[-1] and len(got[-1]) == 3
+
+
+@pytest.mark.parametrize("kind", ["ppo", "grpo"])
+def test_a_callers_default_lengths_do_not_undo_the_policy(kind):
+    """What ``chipbench/run.py::_pin_learner_pad`` does: ``setdefault`` of both
+    lengths around ``create_loader``. The trainer passes its ladders by name,
+    so the defaults never apply."""
+    store = _rollout_store(kind, HH_LENGTHS)
+    create = store.create_loader
+
+    def pinned(*a, **kw):
+        kw.setdefault("query_length", 896)
+        kw.setdefault("response_length", 128)
+        return create(*a, **kw)
+
+    store.create_loader = pinned
+    widths = sorted(b.query_tensors.shape[1] for b in _ladder_loader(store))
+    assert widths == [256, 256, 512, 896]
+    # a caller that names no length still gets the pin, and the uniform partition
+    assert {b.query_tensors.shape[1] for b in store.create_loader(8, shuffle=True)} == {896}
+
+
+@pytest.mark.parametrize("kind", ["ppo", "grpo"])
+def test_a_row_over_the_budget_is_not_cut(kind):
+    store = _rollout_store(kind, [64] * 7 + [1000])
+    (batch,) = list(_ladder_loader(store))
+    assert batch.query_tensors.shape == (8, 1000)
+    assert int(np.asarray(batch.query_mask).sum(axis=1).max()) == 1000

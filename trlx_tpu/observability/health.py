@@ -32,10 +32,12 @@ collection (:meth:`HealthMonitor.observe_rollout`):
 
 Each detector publishes a ``health/<name>`` 0/1 gauge; ``health/verdict``
 summarizes (0 = ok). The string verdict (``"ok"`` or the first tripped
-detector) feeds the bench headline. A trip transition logs once per
-detector, records a structured ``health`` flight-recorder event, and sets
+detector) feeds the bench headline. A detector's first trip of the run logs,
+records a structured ``health`` flight-recorder event, and sets
 :attr:`just_tripped` for exactly one step so the trainer can dump the flight
 record and the offending batch (``triage/step<N>.npz`` — trainer/base.py).
+A detector that clears and trips again is counted (``trip_counts``, the
+gauges) and cues no second dump.
 
 The ``health_trip@step:N`` fault-plan kind (resilience/faults.py) forces a
 trip via :meth:`force_trip`, exercising the full detector→triage path
@@ -111,7 +113,6 @@ class HealthMonitor:
         self.just_tripped: Optional[str] = None
         self.trip_counts: Dict[str, int] = {name: 0 for name in DETECTORS}
         self._tripped: Dict[str, bool] = {name: False for name in DETECTORS}
-        self._warned: set = set()
         self._forced: Optional[str] = None
         # Per-step windows (optimizer-step cadence).
         self._entropy: Deque[float] = deque(maxlen=self.window)
@@ -200,10 +201,13 @@ class HealthMonitor:
         for name in DETECTORS:
             hit = detections[name]
             if hit and not self._tripped[name]:
-                self.just_tripped = name
                 self.trip_counts[name] += 1
-                if name not in self._warned:
-                    self._warned.add(name)
+                # a signal that hovers at its threshold (an untrained critic's
+                # explained variance around 0) clears and trips again and
+                # again: only a detector's first trip of the run warns and
+                # cues the dump, which stalls the loop for the triage forward
+                if self.trip_counts[name] == 1:
+                    self.just_tripped = name
                     logger.warning(
                         "health: detector %s tripped at step %d "
                         "(see docs/OBSERVABILITY.md 'Training dynamics')",

@@ -12,7 +12,10 @@ Two failure modes are invisible until a pod run dies:
 cache (``_cache_size()`` where the jit wrapper exposes it, an argument
 shape-signature set otherwise) and logs a warning — plus a
 ``recompile/<program>`` counter — whenever a program that already compiled
-once compiles *again*. :class:`DeviceMemoryGauge` reads
+once compiles *again*, unless the caller planned the shape: a pad policy
+that feeds one program a fixed set of shapes (the PPO learner's ladder of
+widths) names the shape of each call, and the first compile of each planned
+shape is as expected as the program's first. :class:`DeviceMemoryGauge` reads
 ``device.memory_stats()`` where the backend provides it (TPU/GPU), falling
 back to host RSS on CPU, and warns when usage crosses a fraction of the
 device limit.
@@ -52,6 +55,9 @@ class RecompileWatchdog:
     subsequent cache growth for the same program name is counted
     (``recompile/<name>``) and logged — one warning per event, with a
     rate-limit so a pathological per-step retrace doesn't flood the log.
+    A call that names a ``planned`` shape (``observe``) is allowed that
+    shape's first compile too; a compile at a planned shape already seen
+    counts like any other.
     """
 
     def __init__(self, metrics=None, max_warnings: int = 10):
@@ -64,11 +70,16 @@ class RecompileWatchdog:
         self._cache_sizes: Dict[tuple, int] = {}  # key -> last seen size
         self._signatures: Dict[tuple, set] = {}  # key -> seen arg signatures
         self._compiles: Dict[tuple, int] = {}  # key -> total compiles seen
+        self._planned: Dict[tuple, set] = {}  # key -> planned shapes seen
         self._warnings = 0
 
-    def observe(self, name: str, fn: Callable, args: Any = None) -> int:
+    def observe(
+        self, name: str, fn: Callable, args: Any = None, planned: Any = None
+    ) -> int:
         """Record one call of ``fn`` under program ``name``; returns the
-        number of *excess* (post-warmup) compiles seen for this fn so far."""
+        number of *excess* (post-warmup) compiles seen for this fn so far.
+        ``planned`` (hashable, or ``None``) names the shape of this call where
+        the caller's pad policy set it out beforehand."""
         key = (name, id(fn))
         size = _cache_size(fn)
         if size is not None:
@@ -82,6 +93,13 @@ class RecompileWatchdog:
             seen.add(sig)
         else:
             return 0
+        if planned is not None:
+            seen = self._planned.setdefault(key, set())
+            # the program's own first compile is free whatever its shape:
+            # only a later first sight of a planned shape needs the allowance
+            if planned not in seen and new > 0 and self._compiles.get(key, 0) > 0:
+                new -= 1
+            seen.add(planned)
         total = self._compiles.get(key, 0) + new
         if new <= 0:
             return max(total - 1, 0)

@@ -48,6 +48,16 @@ class BatchLoader:
 
     Supports shuffling, drop_last, and a collate function; re-iterable
     (fresh order per epoch when shuffled).
+
+    ``group_key`` (the rollout stores' pad policy, ``ppo_pipeline.py``) forms
+    the minibatches of a shuffled epoch from rows of like key: the shuffle is
+    drawn as without it, the rows that fill whole batches are stable-sorted
+    by key and cut into batches, and the batches are visited in the order in
+    which the shuffle first reached one of their rows (a uniform order, and
+    no second draw: the partition is a function of the seed and the dataset,
+    which emergency resume relies on). The rows left over stay the trailing
+    partial batch. Where every key is equal nothing moves: the batches are
+    the ungrouped loader's, element for element and in order.
     """
 
     def __init__(
@@ -58,12 +68,14 @@ class BatchLoader:
         shuffle: bool = False,
         drop_last: bool = False,
         seed: int = 0,
+        group_key: Optional[Callable[[Any], Any]] = None,
     ):
         self.dataset = dataset
         self.batch_size = batch_size
         self.collate_fn = collate_fn
         self.shuffle = shuffle
         self.drop_last = drop_last
+        self.group_key = group_key
         self._rng = random.Random(seed)
 
     def __len__(self) -> int:
@@ -80,12 +92,28 @@ class BatchLoader:
         if self.shuffle:
             self._rng.shuffle(list(range(len(self.dataset))))
 
+    def _grouped(self, order: List[int]) -> List[List[int]]:
+        """``order`` (one epoch's shuffle) as batches of like ``group_key``."""
+        bs = self.batch_size
+        whole = len(order) - len(order) % bs
+        drawn = {idx: pos for pos, idx in enumerate(order)}
+        head = sorted(order[:whole], key=lambda i: self.group_key(self.dataset[i]))
+        batches = [head[s : s + bs] for s in range(0, whole, bs)]
+        batches.sort(key=lambda b: min(drawn[i] for i in b))
+        return batches + ([order[whole:]] if whole < len(order) else [])
+
     def __iter__(self) -> Iterator[Any]:
         order = list(range(len(self.dataset)))
         if self.shuffle:
             self._rng.shuffle(order)
-        for start in range(0, len(order), self.batch_size):
-            idxs = order[start : start + self.batch_size]
+        if self.shuffle and self.group_key is not None:
+            batches = self._grouped(order)
+        else:
+            batches = [
+                order[s : s + self.batch_size]
+                for s in range(0, len(order), self.batch_size)
+            ]
+        for idxs in batches:
             if self.drop_last and len(idxs) < self.batch_size:
                 return
             yield self.collate_fn([self.dataset[i] for i in idxs])

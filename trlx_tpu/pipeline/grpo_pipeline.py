@@ -2,13 +2,13 @@
 (``trlx/pipeline/ppo_pipeline.py:13-80`` analogue) carrying per-sequence
 advantages and reference logprobs instead of values/per-token rewards."""
 
-from typing import List, Optional
+from typing import List
 
 import numpy as np
 
 from trlx_tpu.data.grpo_types import GRPORLBatch, GRPORLElement
 from trlx_tpu.pipeline.offline_pipeline import pad_rows
-from trlx_tpu.pipeline.ppo_pipeline import PPORolloutStorage
+from trlx_tpu.pipeline.ppo_pipeline import PadLength, PPORolloutStorage
 
 
 class GRPORolloutStorage(PPORolloutStorage):
@@ -46,14 +46,11 @@ class GRPORolloutStorage(PPORolloutStorage):
         self,
         elems: List[GRPORLElement],
         pad_multiple: int = 8,
-        query_length: Optional[int] = None,
-        response_length: Optional[int] = None,
+        query_length: PadLength = None,
+        response_length: PadLength = None,
     ) -> GRPORLBatch:
-        queries, query_mask = pad_rows(
-            [e.query_tensor for e in elems], self.pad_token_id, "left", pad_multiple, query_length
-        )
-        responses, response_mask = pad_rows(
-            [e.response_tensor for e in elems], self.pad_token_id, "right", pad_multiple, response_length
+        queries, query_mask, responses, response_mask = self._pad_tokens(
+            elems, pad_multiple, query_length, response_length
         )
         r_len = responses.shape[1]
         logprobs, _ = pad_rows([e.logprobs for e in elems], 0.0, "right", 1, r_len, np.float32)

@@ -405,6 +405,7 @@ class TPUBaseTrainer(BaseRLTrainer):
         )
         self._generate_fns: Dict[Any, Callable] = {}
         self._train_step_fn: Optional[Callable] = None
+        self._step_shapes: set = set()  # batch shapes the train step has run at
         self._last_batch_host: Any = None
         self._last_batch_sharded: Any = None
 
@@ -715,14 +716,15 @@ class TPUBaseTrainer(BaseRLTrainer):
             self._prompt_chunks_drawn += 1
             yield chunk
 
-    def _batch_token_counts(self, batch: Any) -> Tuple[int, int]:
-        """``(real, fed)`` tokens of a host batch: the unpadded tokens its
+    def _batch_token_counts(self, batch: Any) -> Tuple[int, int, int]:
+        """``(real, fed, width)`` of a host batch: the unpadded tokens its
         masks count (so padding doesn't inflate
-        ``throughput/tokens_per_sec``) and the rows × width slots the step is
-        fed; ``learn/pad_frac`` is one minus their ratio."""
+        ``throughput/tokens_per_sec``), the rows × width slots the step is
+        fed (``learn/pad_frac`` is one minus their ratio) and the slots of
+        one row (``learn/step_width``: query plus response width under PPO)."""
         items = batch._asdict() if hasattr(batch, "_asdict") else batch
         if not isinstance(items, dict):
-            return 0, 0
+            return 0, 0, 0
         if "attention_mask" in items:
             masks = [items["attention_mask"]]
         else:
@@ -733,12 +735,13 @@ class TPUBaseTrainer(BaseRLTrainer):
             return (
                 int(sum(np.asarray(m).sum() for m in masks)),
                 int(sum(np.asarray(m).size for m in masks)),
+                int(sum(np.asarray(m).shape[-1] for m in masks)),
             )
         for v in items.values():
             if hasattr(v, "shape") and len(v.shape) >= 2:
                 fed = int(v.shape[0] * v.shape[1])
-                return fed, fed
-        return 0, 0
+                return fed, fed, int(v.shape[1])
+        return 0, 0, 0
 
     def _export_observability(self) -> None:
         """Best-effort span export (``trace.json``) next to the tracker's
@@ -790,9 +793,23 @@ class TPUBaseTrainer(BaseRLTrainer):
             self._last_batch_sharded = arrays
         self.state, stats = self._train_step_fn(self.state, arrays, self._loss_scale())
         # recompile watchdog: a warm train step retracing (shape/dtype
-        # drift) is invisible otherwise — it just gets slow
-        self.obs.recompile.observe("train_step", self._train_step_fn)
+        # drift) is invisible otherwise — it just gets slow. The first
+        # compile of a shape the trainer's pad policy planned is expected
+        shape = tuple(sorted((k, tuple(v.shape)) for k, v in arrays.items()))
+        self.obs.recompile.observe(
+            "train_step",
+            self._train_step_fn,
+            planned=shape if self._planned_step_shape(batch) else None,
+        )
+        self._step_shapes.add(shape)
+        self.obs.metrics.set_gauge("learn/step_shapes", float(len(self._step_shapes)))
         return stats
+
+    def _planned_step_shape(self, batch: Any) -> bool:
+        """Whether ``batch`` has a shape the trainer's pad policy set out
+        before the run (PPO and GRPO: a rung of each ladder of widths), so
+        that the train step's first compile for it is no shape drift."""
+        return False
 
     def _loss_scale(self) -> np.float32:
         """1.0, or NaN when the fault plan poisons this step's loss
@@ -1918,10 +1935,11 @@ class TPUBaseTrainer(BaseRLTrainer):
                     # the collection) and this step's span: with
                     # time/train_step it tiles the learn phase
                     stats["time/step_gap"] = step_gap
-                    real_tokens, fed_tokens = self._batch_token_counts(batch)
+                    real_tokens, fed_tokens, width = self._batch_token_counts(batch)
                     stats["learn/pad_frac"] = (
                         1.0 - real_tokens / fed_tokens if fed_tokens else 0.0
                     )
+                    stats["learn/step_width"] = float(width)
                     batch_size = next(
                         v.shape[0] for v in batch.values() if hasattr(v, "shape")
                     ) if isinstance(batch, dict) else self.config.train.batch_size

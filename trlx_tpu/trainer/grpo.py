@@ -147,7 +147,7 @@ class GRPOTrainer(PPOTrainer):
         """The k3 reference KL and the pooled (clipped) scores of the whole
         collection. The rollout health detectors are not fed: they read
         PPO's keys, and a trip writes a triage batch through PPO's
-        un-jitted ``_triage_extra`` forward."""
+        ``_triage_extra`` forward."""
         stats["policy/sqrt_ref_kl"] = float(np.sqrt(max(self.mean_kl, 0.0)))
         all_scores = acc.get("all_scores")
         pooled = np.concatenate(all_scores) if all_scores else np.zeros((0,), np.float32)

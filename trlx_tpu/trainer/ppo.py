@@ -44,7 +44,7 @@ from trlx_tpu.models.transformer import CausalTransformer
 from trlx_tpu.ops.sampling import GenerationOutput
 from trlx_tpu.parallel import shard_batch
 from trlx_tpu.pipeline import BasePipeline
-from trlx_tpu.pipeline.ppo_pipeline import PPORolloutStorage
+from trlx_tpu.pipeline.ppo_pipeline import PPORolloutStorage, length_ladder
 from trlx_tpu.trainer import register_trainer
 from trlx_tpu.trainer.base import TPUBaseTrainer
 from trlx_tpu.utils import infinite_loader, logging, to_host
@@ -116,6 +116,8 @@ class PPOTrainer(TPUBaseTrainer):
         self.mean_kl = 0.0
         self._score_fns: Dict[Tuple[int, int, int], Any] = {}
         self.make_experience_stats: Dict[str, float] = {}
+        # query and response widths the learner's loader pads to
+        self._step_ladders: Tuple[Tuple[int, ...], Tuple[int, ...]] = ((), ())
 
         # disaggregated async collection (trlx_tpu/async_rl/,
         # docs/ASYNC_RL.md): the collector is built lazily at the first
@@ -1521,10 +1523,36 @@ class PPOTrainer(TPUBaseTrainer):
             )
         return self.with_router_aux((loss, stats), out)
 
-    def prepare_learning(self) -> None:
-        self.train_dataloader = self.store.create_loader(
-            self.config.train.batch_size, shuffle=True, seed=self.config.train.seed
+    def _learner_loader(self):
+        """The store's minibatches under the learner's pad policy
+        (``PPORolloutStorage.create_loader``): rows grouped by length, each
+        minibatch padded to a rung of a ladder of widths that follows from
+        the job's own length budget (``trlx.py::train`` truncates the prompts
+        to the same one) and is fixed for the run. Both ladders are passed by
+        name, so a caller's default for either length cannot replace them."""
+        new = int(self._resolve_gen_config()[0].max_new_tokens)
+        self._step_ladders = (
+            length_ladder(int(self.config.train.seq_length) - new),
+            length_ladder(new),
         )
+        return self.store.create_loader(
+            self.config.train.batch_size,
+            shuffle=True,
+            seed=self.config.train.seed,
+            query_length=self._step_ladders[0],
+            response_length=self._step_ladders[1],
+        )
+
+    def _planned_step_shape(self, batch: Any) -> bool:
+        items = batch._asdict() if hasattr(batch, "_asdict") else batch
+        queries, responses = self._step_ladders
+        return (
+            items["query_tensors"].shape[1] in queries
+            and items["response_tensors"].shape[1] in responses
+        )
+
+    def prepare_learning(self) -> None:
+        self.train_dataloader = self._learner_loader()
         self.n_updates_per_batch = self.config.method.ppo_epochs
         self.total_steps = min(
             self.config.train.total_steps,
@@ -1536,16 +1564,17 @@ class PPOTrainer(TPUBaseTrainer):
     def _triage_extra(self, arrays: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
         """Derived per-token quantities for a triaged batch: GAE advantages/
         returns, plus the new-policy per-token logprob deltas from one
-        un-jitted forward under the current params (best-effort — a sick
-        enough state can fail the forward, and the tokens/masks already
-        dumped are the irreplaceable part)."""
+        forward under the current params (best-effort — a sick enough state
+        can fail the forward, and the tokens/masks already dumped are the
+        irreplaceable part). Both are jitted: op by op the forward of a 1.2 B
+        model held the loop for 12 s on a v5e (PERF.md section 6, PR 30)."""
         extra: Dict[str, np.ndarray] = {}
         values = arrays.get("values")
         rewards = arrays.get("rewards")
         mask = arrays.get("response_mask")
         try:
             if values is not None and rewards is not None and mask is not None:
-                adv, ret = self.config.method.get_advantages_and_returns(
+                adv, ret = jax.jit(self.config.method.get_advantages_and_returns)(
                     jnp.asarray(values),
                     jnp.asarray(rewards),
                     jnp.asarray(mask, jnp.float32),
@@ -1554,28 +1583,28 @@ class PPOTrainer(TPUBaseTrainer):
                 extra["returns"] = np.asarray(ret)
         except Exception:  # pragma: no cover - defensive, crash-path code
             pass
-        needed = (
-            "query_tensors", "response_tensors", "query_mask",
-            "response_mask", "logprobs",
-        )
+        tokens = ("query_tensors", "response_tensors", "query_mask", "response_mask")
+        module = self.module
+
+        @jax.jit
+        def response_logprobs(params, batch):
+            queries, responses = batch["query_tensors"], batch["response_tensors"]
+            Q, R = queries.shape[1], responses.shape[1]
+            out = module.apply(
+                {"params": params},
+                jnp.concatenate([queries, responses], axis=1),
+                attention_mask=jnp.concatenate(
+                    [batch["query_mask"], batch["response_mask"]], axis=1
+                ),
+                logits_span=(Q - 1, Q + R - 1),
+            )
+            return logprobs_of_labels(out["logits"], responses)
+
         try:
-            if not self.is_seq2seq and all(k in arrays for k in needed):
-                queries = jnp.asarray(arrays["query_tensors"])
-                responses = jnp.asarray(arrays["response_tensors"])
-                Q, R = queries.shape[1], responses.shape[1]
-                out = self.module.apply(
-                    {"params": self.state.params},
-                    jnp.concatenate([queries, responses], axis=1),
-                    attention_mask=jnp.concatenate(
-                        [
-                            jnp.asarray(arrays["query_mask"]),
-                            jnp.asarray(arrays["response_mask"]),
-                        ],
-                        axis=1,
-                    ),
-                    logits_span=(Q - 1, Q + R - 1),
+            if not self.is_seq2seq and all(k in arrays for k in tokens + ("logprobs",)):
+                new_logprobs = response_logprobs(
+                    self.state.params, {k: jnp.asarray(arrays[k]) for k in tokens}
                 )
-                new_logprobs = logprobs_of_labels(out["logits"], responses)
                 extra["logprob_deltas"] = np.asarray(new_logprobs) - np.asarray(
                     arrays["logprobs"]
                 )
@@ -1598,6 +1627,4 @@ class PPOTrainer(TPUBaseTrainer):
         self.store.clear_history()
         self.make_experience(self.config.method.num_rollouts, self.iter_count)
         with self.obs.span("learn/loader", stage="create"):
-            self.train_dataloader = self.store.create_loader(
-                self.config.train.batch_size, shuffle=True, seed=self.config.train.seed
-            )
+            self.train_dataloader = self._learner_loader()
