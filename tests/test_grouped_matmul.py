@@ -12,6 +12,17 @@ from trlx_tpu.ops import grouped_matmul as gm
 K, N = 32, 48
 TILES = (8, 16, 16)
 
+
+@pytest.fixture(autouse=True)
+def _no_global_mesh():
+    """The choice of kernel reads the global mesh, and a trainer built by an
+    earlier file in this process leaves its own behind."""
+    from trlx_tpu.parallel.mesh import set_global_mesh
+
+    set_global_mesh(None)
+    yield
+    set_global_mesh(None)
+
 # name -> (rows, group sizes): the row tile is 8
 CASES = {
     "even": (32, [8, 8, 8, 8]),

@@ -70,7 +70,12 @@ from trlx_tpu.engine.allocator import (
     TenantQuotaExceeded,
 )
 from trlx_tpu.engine.prefix_cache import PrefixCache
-from trlx_tpu.ops.paged_kv import block_bytes, kv_bytes, num_table_blocks
+from trlx_tpu.ops.paged_kv import (
+    block_bytes,
+    kv_bytes,
+    num_table_blocks,
+    refuse_recurrent_state,
+)
 
 __all__ = [
     "CompletedSequence",
@@ -610,6 +615,7 @@ class ContinuousEngine(Engine):
             self._TB = num_table_blocks(S, self._bs)
             self.allocator = BlockAllocator(self.spec.max_blocks)
             if prefix_cache:
+                refuse_recurrent_state(self.state.cache, "prefix_cache")
                 self.prefix = PrefixCache(self._bs, prefix_capacity_blocks)
                 self.stats.prefix_enabled = True
             # host mirror of the device block table — authoritative between

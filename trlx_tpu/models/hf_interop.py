@@ -18,6 +18,13 @@ import numpy as np
 from trlx_tpu.models.transformer import TransformerConfig
 
 
+_FALCON_H1_NO_INTEROP = (
+    "model_type 'falcon_h1' has no HF checkpoint conversion yet: the family runs "
+    "from 'builtin:falconh1-<size>' (random weights) only; the converter pair was "
+    "never checked against a checkpoint (ROADMAP.md queue 2, B7)"
+)
+
+
 class UnsupportedHFExport(ValueError):
     """Raised when an architecture has no transformers family mapping —
     the one 'skip HF export, keep the native msgpack' case. Genuine
@@ -491,6 +498,8 @@ def config_from_hf(hf_config) -> TransformerConfig:
             layer_norm_epsilon=hf_config.layer_norm_epsilon,
             tie_word_embeddings=True,
         )
+    if mt == "falcon_h1":
+        raise ValueError(_FALCON_H1_NO_INTEROP)
     raise ValueError(f"Unsupported HF model type for causal import: {mt}")
 
 
@@ -1122,6 +1131,8 @@ def hf_config_from_transformer(cfg):
             n_head=cfg.num_heads,
             layer_norm_epsilon=cfg.layer_norm_epsilon,
         )
+    if mt == "falcon_h1":
+        raise UnsupportedHFExport(_FALCON_H1_NO_INTEROP)
     raise UnsupportedHFExport(
         f"No HF export mapping for model_type={mt!r} "
         "(set TransformerConfig.model_type to an HF family)"

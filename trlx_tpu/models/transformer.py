@@ -276,6 +276,30 @@ class TransformerConfig:
     router_aux_coef: float = 0.01  # load-balance loss weight (Switch-style)
     router_z_coef: float = 0.0  # router logit z-loss weight (ST-MoE)
 
+    # a second sequence mixer beside attention in every block (falcon_h1):
+    # "mamba2" runs Mamba-2 heads and the attention heads on the SAME normed
+    # input and adds both to the residual. Its per-sequence state (the
+    # recurrent state, float32, and the conv's last rows) lives in the layer's
+    # cache dict beside K and V (make_kv_cache). "none" = attention alone.
+    mixer: str = "none"  # none | mamba2
+    mamba_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_state: int = 0  # state size N of each head
+    mamba_groups: int = 1  # B and C are shared by heads / groups heads
+    mamba_conv: int = 4  # causal depthwise conv width over x, B, C
+    mamba_chunk: int = 128  # chunk of the scan (ops/ssd.py)
+    # the family's fixed scalar multipliers (muP forward scalings), all 1 for
+    # every other family: applied only where they differ from 1
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    mlp_multipliers: Tuple[float, ...] = (1.0, 1.0)  # gate pre-activation, down output
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: Tuple[float, ...] = (1.0,) * 5  # in_proj's z, x, B, C, dt segments
+
     def resolved_attention_impl(self) -> str:
         if self.attention_impl == "auto":
             return "pallas" if jax.default_backend() == "tpu" else "xla"
@@ -288,6 +312,15 @@ class TransformerConfig:
     @property
     def dims_per_head(self) -> int:
         return self.head_dim or self.hidden_size // self.num_heads
+
+    @property
+    def mamba_d_ssm(self) -> int:
+        return self.mamba_heads * self.mamba_head_dim
+
+    @property
+    def mamba_conv_channels(self) -> int:
+        """x, B and C, the channels the conv runs over."""
+        return self.mamba_d_ssm + 2 * self.mamba_groups * self.mamba_state
 
     # ---- family presets (sizes per the public model cards) ----
 
@@ -393,6 +426,39 @@ class TransformerConfig:
             moe_capacity_factor=0.0,  # dropless, as published
             moe_renormalize=False,  # norm_topk_prob: false
             router_aux_coef=0.01,
+        )
+
+    @staticmethod
+    def falconh1(size: str = "34b", **overrides) -> "TransformerConfig":
+        """Falcon-H1: Mamba-2 heads beside attention heads in every block.
+        Limits: the plain sampler, the scoring forward and the train step
+        only (``ops/paged_kv.py::refuse_recurrent_state``); no HF checkpoint import."""
+        dims = {
+            # every multiplier differs from 1, so that a test sees each
+            "test": dict(vocab_size=259, hidden_size=64, num_layers=2, num_heads=4, num_kv_heads=2, head_dim=16, intermediate_size=128, max_position_embeddings=128,
+                         mamba_heads=4, mamba_head_dim=16, mamba_groups=2, mamba_state=32, mamba_chunk=8,
+                         embedding_multiplier=2.0, lm_head_multiplier=0.5, attention_in_multiplier=0.75, attention_out_multiplier=0.6, key_multiplier=0.5,
+                         mlp_multipliers=(0.7, 0.4), ssm_in_multiplier=0.8, ssm_out_multiplier=0.7, ssm_multipliers=(0.9, 0.8, 0.7, 1.3, 1.1)),
+            "34b": dict(vocab_size=261120, hidden_size=5120, num_layers=72, num_heads=20, num_kv_heads=4, head_dim=128, intermediate_size=21504, max_position_embeddings=262144,
+                        mamba_heads=32, mamba_head_dim=128, mamba_groups=2, mamba_state=256, mamba_chunk=128,
+                        embedding_multiplier=5.656854249492381, lm_head_multiplier=0.0078125, attention_in_multiplier=1.0, attention_out_multiplier=0.0375, key_multiplier=0.011048543456039804,
+                        mlp_multipliers=(0.1767766952966369, 0.011160714285714284), ssm_in_multiplier=0.25, ssm_out_multiplier=0.08838834764831845,
+                        ssm_multipliers=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5, 0.3535533905932738)),
+        }[size]
+        return _make_preset(
+            dims,
+            overrides,
+            model_type="falcon_h1",
+            mixer="mamba2",
+            mamba_conv=4,
+            position_scheme="rotary",
+            rope_theta=1e11,
+            norm="rmsnorm",
+            layer_norm_epsilon=1e-5,
+            activation="silu",
+            attn_bias=False,
+            mlp_bias=False,
+            tie_word_embeddings=False,
         )
 
     @staticmethod
@@ -676,6 +742,8 @@ class Attention(nn.Module):
         q = _dense(cfg, H * D, qkv_bias, ("embed", "joined_kv"), "q_proj")(x)
         k = _dense(cfg, KV * D, qkv_bias, ("embed", "joined_kv"), "k_proj")(x)
         v = _dense(cfg, KV * D, qkv_bias, ("embed", "joined_kv"), "v_proj")(x).reshape(B, T, KV, D)
+        if cfg.key_multiplier != 1.0:
+            k = k * cfg.key_multiplier
         if cfg.qk_norm:
             # over the whole projected width (all heads together), float32
             # statistics; every cache and kernel path below sees normed q, k
@@ -825,10 +893,135 @@ class MLP(nn.Module):
         if cfg.activation == "silu":  # gated (llama-style) MLP
             gate = _dense(cfg, cfg.intermediate_size, cfg.mlp_bias, ("embed", "ffn"), "gate_proj")(x)
             up = _dense(cfg, cfg.intermediate_size, cfg.mlp_bias, ("embed", "ffn"), "up_proj")(x)
+            gate_mult, down_mult = cfg.mlp_multipliers
+            if gate_mult != 1.0:
+                gate = gate * gate_mult
             h = act(gate) * up
-        else:
-            h = act(_dense(cfg, cfg.intermediate_size, cfg.mlp_bias, ("embed", "ffn"), "up_proj")(x))
+            y = _dense(cfg, cfg.hidden_size, cfg.mlp_bias, ("ffn", "embed"), "down_proj")(h)
+            return y * down_mult if down_mult != 1.0 else y
+        h = act(_dense(cfg, cfg.intermediate_size, cfg.mlp_bias, ("embed", "ffn"), "up_proj")(x))
         return _dense(cfg, cfg.hidden_size, cfg.mlp_bias, ("ffn", "embed"), "down_proj")(h)
+
+
+class Mamba2Mixer(nn.Module):
+    """Mamba-2 heads (``ops/ssd.py``): ``in_proj`` to ``z | x | B | C | dt``,
+    a causal depthwise conv and SiLU over ``x, B, C``, the selective
+    state-space recurrence a head, ``y * silu(z)`` under an RMSNorm over each
+    group's channels, ``out_proj``.
+
+    With a ``cache`` (a layer's dict: ``ssm [B, H, P, N]`` float32, ``conv
+    [B, K-1, C]``) the recurrence starts from the stored state and the new
+    state is returned: one token takes ``ssd_step``, a span (prefill) the
+    chunked scan. Without one (scoring forward, hydra branch, train step) it
+    starts from zero. ``token_mask [B, T]`` marks real tokens: a padded
+    position contributes nothing, so a left-padded row reaches its first
+    real token with a zero state and a zero conv window, and a row that has
+    ended only decays its state."""
+
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, u, cache=None, token_mask=None):
+        from trlx_tpu.ops.ssd import causal_conv, ssd_chunked, ssd_step
+
+        cfg = self.config
+        B, T, _ = u.shape
+        H, P, G, N = cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_groups, cfg.mamba_state
+        d_ssm, gn = cfg.mamba_d_ssm, cfg.mamba_groups * cfg.mamba_state
+        keep = None if token_mask is None else token_mask.reshape(B, T, 1).astype(u.dtype)
+        if keep is not None:
+            u = u * keep
+        if cfg.ssm_in_multiplier != 1.0:
+            u = u * cfg.ssm_in_multiplier
+        segments = (d_ssm, d_ssm, gn, gn, H)  # z, x, B, C, dt
+
+        def in_proj_init(key, shape, dtype):
+            # z, x, B, C columns at 0.5: at the 0.02 of every other matrix the
+            # family's multipliers leave B and C so small that the state's part
+            # of a head's output is 1e-4 of the skip D x, and a model run from
+            # random weights has a dead state. The dt columns keep 0.02, so
+            # that the step sizes stay where dt_bias puts them
+            std = jnp.full((shape[1],), 0.5).at[-H:].set(0.02)
+            return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
+
+        p = nn.Dense(
+            sum(segments),
+            use_bias=False,
+            dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype,
+            kernel_init=param_with_axes(in_proj_init, ("embed", "ssm")),
+            name="in_proj",
+        )(u)
+        if any(m != 1.0 for m in cfg.ssm_multipliers):
+            p = p * jnp.asarray(np.repeat(cfg.ssm_multipliers, segments), cfg.dtype)
+        z, xbc, dt = jnp.split(p, [d_ssm, d_ssm + cfg.mamba_conv_channels], axis=-1)
+
+        def vector(name, init, n):
+            return self.param(name, param_with_axes(init, ("ssm",)), (n,), cfg.param_dtype)
+
+        K = cfg.mamba_conv
+
+        def conv_init(key, shape, dtype):
+            # torch's Conv1d default for a depthwise conv of width K
+            bound = 1.0 / np.sqrt(K)
+            return jax.random.uniform(key, shape, jnp.float32, -bound, bound).astype(dtype)
+
+        conv_w = self.param(
+            "conv_weight", param_with_axes(conv_init, ("conv", "ssm")),
+            (K, cfg.mamba_conv_channels), cfg.param_dtype,
+        )
+        conv_b = vector("conv_bias", nn.initializers.zeros, cfg.mamba_conv_channels)
+        xbc, conv_state = causal_conv(
+            xbc, conv_w, conv_b, None if cache is None else cache["conv"]
+        )
+        xbc = nn.silu(xbc)
+        if keep is not None:
+            xbc = xbc * keep  # the conv's bias is not zero at a pad
+        x, Bm, Cm = jnp.split(xbc, [d_ssm, d_ssm + gn], axis=-1)
+
+        # the Mamba-2 paper's initialisation: A uniform in [1, 16], dt
+        # log-uniform in [0.001, 0.1] through dt_bias (softplus's inverse), D = 1
+        def a_log_init(key, shape, dtype):
+            return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0)).astype(dtype)
+
+        def dt_bias_init(key, shape, dtype):
+            dt0 = jnp.exp(jax.random.uniform(key, shape, jnp.float32, np.log(1e-3), np.log(1e-1)))
+            return (dt0 + jnp.log(-jnp.expm1(-dt0))).astype(dtype)
+
+        A = -jnp.exp(vector("A_log", a_log_init, H).astype(jnp.float32))
+        D = vector("D", nn.initializers.ones, H)
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + vector("dt_bias", dt_bias_init, H).astype(jnp.float32))
+
+        x, Bm, Cm = x.reshape(B, T, H, P), Bm.reshape(B, T, G, N), Cm.reshape(B, T, G, N)
+        state = None if cache is None else cache["ssm"]
+        if cache is not None and T == 1:
+            y, state = ssd_step(state, x[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0], D)
+            y = y[:, None]
+        else:
+            scan = partial(ssd_chunked, chunk=cfg.mamba_chunk)
+            if cache is None:
+                # a backward pass runs the scan again rather than keep its
+                # float32 decay matrices and chunk states: 166 KB a token a
+                # block at the 34B widths, 45% of what the block saves,
+                # against 0.6% of its FLOPs
+                scan = jax.checkpoint(scan)
+            y, state = scan(x, dt, A, Bm, Cm, D, token_mask, state)
+
+        # gated norm, the gate first (mamba_norm_before_gate false): RMSNorm
+        # over each group's channels, float32 statistics
+        y = (y.reshape(B, T, d_ssm) * nn.silu(z)).astype(jnp.float32).reshape(B, T, G, d_ssm // G)
+        y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + cfg.layer_norm_epsilon)
+        y = y.reshape(B, T, d_ssm).astype(cfg.dtype) * vector("norm_scale", nn.initializers.ones, d_ssm).astype(cfg.dtype)
+        out = nn.Dense(
+            cfg.hidden_size,
+            use_bias=False,
+            dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype,
+            kernel_init=param_with_axes(nn.initializers.normal(0.02), ("ssm", "embed")),
+            name="out_proj",
+        )(y)
+        new_cache = None if cache is None else {"ssm": state, "conv": conv_state.astype(cache["conv"].dtype)}
+        return out, new_cache
 
 
 @functools.lru_cache(maxsize=None)
@@ -1107,6 +1300,10 @@ def _cache_is_paged(cache) -> bool:
     return False
 
 
+def _needs_token_mask(cfg: TransformerConfig) -> bool:
+    return cfg.num_experts > 0 or cfg.mixer != "none"
+
+
 def _query_slots(q_offset, B: int, T: int) -> jax.Array:
     """[B, T] slot indices of queries at ``q_offset`` (scalar, or [B] when
     rows sit at different cache depths — speculative decoding)."""
@@ -1136,6 +1333,17 @@ class Block(nn.Module):
             return MLP(cfg, name="mlp")(h), jnp.zeros(_ZERO_AUX, jnp.float32)
 
         h = Norm(cfg, name="ln_attn")(x)
+        if cfg.mixer == "mamba2":
+            # both mixers read the same normed input; their outputs are
+            # summed before the one residual add, then the MLP as usual
+            attn_in = h * cfg.attention_in_multiplier if cfg.attention_in_multiplier != 1.0 else h
+            attn_out, new_cache = Attention(cfg, name="attn")(attn_in, attention_bias, positions, cache, cache_index, flash_args)
+            mix_out, new_state = Mamba2Mixer(cfg, name="mixer")(h, cache, token_mask)
+            if cache is not None:
+                new_cache = {**new_cache, **new_state}
+            x = x + mix_out * cfg.ssm_out_multiplier + attn_out * cfg.attention_out_multiplier
+            mlp_out, aux = run_mlp(Norm(cfg, name="ln_mlp")(x))
+            return x + mlp_out, new_cache, aux
         attn_out, new_cache = Attention(cfg, name="attn")(h, attention_bias, positions, cache, cache_index, flash_args)
         if cfg.parallel_residual:
             mlp_in = h if cfg.shared_ln else Norm(cfg, name="ln_mlp")(x)
@@ -1251,13 +1459,14 @@ class CausalTransformer(nn.Module):
 
     def _logits(self, h):
         cfg = self.config
-        if cfg.tie_word_embeddings:
-            return self.wte.attend(h)
-        return self.lm_head(h)
+        logits = self.wte.attend(h) if cfg.tie_word_embeddings else self.lm_head(h)
+        return logits * cfg.lm_head_multiplier if cfg.lm_head_multiplier != 1.0 else logits
 
     def _embed(self, input_ids, positions):
         cfg = self.config
         x = _activation_sharded(self.wte(input_ids))
+        if cfg.embedding_multiplier != 1.0:
+            x = x * cfg.embedding_multiplier
         if cfg.position_scheme == "learned":
             x = x + self.wpe(positions + cfg.pos_offset)
         if cfg.embedding_layernorm:
@@ -1351,9 +1560,10 @@ class CausalTransformer(nn.Module):
                 positions = jax.vmap(lambda kp, qs: kp[qs])(key_pos, query_slots)
 
         token_mask = None
-        if cfg.num_experts > 0:
+        if _needs_token_mask(cfg):
             # MoE routing must know which query tokens are real: padding
-            # claims no expert capacity and trains no router statistics
+            # claims no expert capacity and trains no router statistics; a
+            # recurrent mixer must feed no padding into its state
             if cache is None:
                 token_mask = attention_mask
             else:
@@ -1462,7 +1672,7 @@ class CausalTransformer(nn.Module):
             # cache_index (speculative decoding), or the scalar/None given
             q_offset = ci_mb if in_decode else 0
             tm = None
-            if cfg.num_experts > 0:
+            if _needs_token_mask(cfg):
                 tm = (
                     _token_validity(mask_mb, q_offset, pos_mb.shape[1])
                     if in_decode
@@ -1570,18 +1780,24 @@ def make_kv_cache(
 
     Layout follows the block layout: a per-layer list of ``{"k", "v"}`` dicts,
     or one stacked dict with a leading layer dim when ``cfg.scan_layers``.
+    A ``mixer: mamba2`` layer also holds ``ssm`` (the recurrent state,
+    float32 whatever ``dtype``: hundreds of steps of ``S = aS + ...`` drift
+    in bf16) and ``conv`` (the conv's last ``K - 1`` input rows).
     """
     dtype = dtype or cfg.dtype
-    shape = (batch_size, max_length, cfg.kv_heads, cfg.dims_per_head)
-    if cfg.scan_layers:
-        return {
-            "k": jnp.zeros((cfg.num_layers,) + shape, dtype),
-            "v": jnp.zeros((cfg.num_layers,) + shape, dtype),
-        }
-    return [
-        {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
-        for _ in range(cfg.num_layers)
-    ]
+    shapes = {
+        "k": ((batch_size, max_length, cfg.kv_heads, cfg.dims_per_head), dtype),
+        "v": ((batch_size, max_length, cfg.kv_heads, cfg.dims_per_head), dtype),
+    }
+    if cfg.mixer == "mamba2":
+        shapes["ssm"] = ((batch_size, cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_state), jnp.float32)
+        shapes["conv"] = ((batch_size, cfg.mamba_conv - 1, cfg.mamba_conv_channels), dtype)
+    stacked = (cfg.num_layers,) if cfg.scan_layers else ()
+
+    def layer():
+        return {name: jnp.zeros(stacked + shape, dt) for name, (shape, dt) in shapes.items()}
+
+    return layer() if cfg.scan_layers else [layer() for _ in range(cfg.num_layers)]
 
 
 def stack_layer_params(backbone: Dict[str, Any], num_layers: int, prefix: str = "h_") -> Dict[str, Any]:
@@ -1617,6 +1833,7 @@ BUILTIN_SPECS = {
     "mistral": TransformerConfig.mistral,
     "mixtral": TransformerConfig.mixtral,
     "olmoe": TransformerConfig.olmoe,
+    "falconh1": TransformerConfig.falconh1,
     "gptj": TransformerConfig.gptj,
     "gptneox": TransformerConfig.gptneox,
     "pythia": TransformerConfig.gptneox,

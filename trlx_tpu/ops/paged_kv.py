@@ -63,6 +63,8 @@ __all__ = [
     "kv_bytes",
     "block_bytes",
     "dense_kv_bytes",
+    "recurrent_state_bytes",
+    "refuse_recurrent_state",
 ]
 
 # Physical block 0 is reserved as the permanent all-zeros block: fresh table
@@ -264,6 +266,44 @@ def kv_bytes(cache: Any) -> int:
             for leaf in jax.tree_util.tree_leaves(cache)
         )
     )
+
+
+# what a `mixer: mamba2` layer keeps a sequence beside K and V
+# (models/transformer.py::make_kv_cache): the state and the conv's last rows
+RECURRENT_LEAVES = ("ssm", "conv")
+
+
+def recurrent_state_bytes(cache: Any) -> int:
+    """Bytes of a cache pytree's recurrent leaves, by leaf name (0 for a
+    KV-only model); ``kv_bytes`` less this is what K and V hold."""
+    return int(
+        sum(
+            int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
+            for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]
+            if getattr(path[-1], "key", None) in RECURRENT_LEAVES
+        )
+    )
+
+
+# rollout paths that keep K and V and nothing else (ROADMAP.md queue 2, B7)
+_KV_ONLY_PATHS = {
+    "slot_refill": "ops/slot_refill.py::SlotState (train.continuous_batching) holds K and V a slot and would refill a slot over another row's recurrent state",
+    "engine": "the engine/ slots (paged cache) hold K and V blocks and no recurrent state",
+    "prefix_cache": "the engine's prefix cache shares K and V blocks; a recurrent state has no snapshot at a block boundary to share",
+    "speculative": "ops/speculative.py rewinds K and V to the accepted length and cannot rewind a recurrent state",
+}
+
+
+def refuse_recurrent_state(cache: Any, path: str) -> None:
+    """Called where each KV-only rollout path builds its state, on the cache
+    pytree (arrays or shapes) it was given: a model whose layers hold
+    recurrent state stops there by name rather than drop it silently."""
+    if recurrent_state_bytes(cache):
+        raise NotImplementedError(
+            f"{path} does not support a model whose cache holds recurrent state "
+            f"(leaves {RECURRENT_LEAVES}: `mixer: mamba2`, the falcon_h1 family): "
+            f"{_KV_ONLY_PATHS[path]}; use the plain sampler (ROADMAP.md queue 2, B7)"
+        )
 
 
 def block_bytes(cache: Any) -> int:

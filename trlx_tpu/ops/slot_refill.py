@@ -70,6 +70,7 @@ from trlx_tpu.ops.paged_kv import (
     detach_block_table,
     gather_view,
     init_paged_kv,
+    refuse_recurrent_state,
     scatter_span,
     scatter_steps,
 )
@@ -287,6 +288,10 @@ def make_slot_refill_fns(
             "(ops/paged_prefill.py) — it requires the paged KV backend "
             "(engine.backend: paged)"
         )
+    refuse_recurrent_state(
+        jax.eval_shape(lambda: init_cache_fn(1, 1)),
+        "slot_refill" if paged is None else "engine",
+    )
     G = int(speculative or 0)
     if G < 0:
         raise ValueError(f"speculative must be >= 0, got {G}")

@@ -54,6 +54,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from trlx_tpu.ops.paged_kv import refuse_recurrent_state
 from trlx_tpu.ops.sampling import (
     _NON_CARRY_KEYS,
     GenerationConfig,
@@ -464,6 +465,7 @@ def generate_speculative(
 
     t_cache = init_target_cache(B, S)
     d_cache = init_draft_cache(B, S)
+    refuse_recurrent_state((t_cache, d_cache), "speculative")
 
     # ---- prefill both caches over the prompt block ----
     slot0 = jnp.concatenate([prompt_mask, jnp.zeros((B, NB - 1), jnp.int32)], axis=1)

@@ -54,6 +54,11 @@ _RULES: Tuple[Tuple[str, P], ...] = (
     (r".*/wpe/embedding$", P(None, None)),
     (r".*/lm_head/kernel$", P("fsdp", "model")),
     (r".*/lm_head/bias$", P("model")),
+    # Mamba-2 mixer: its projections shard over fsdp alone. in_proj's output
+    # is five segments (z | x | B | C | dt) of a head-and-group structure that
+    # a `model` split would cut across; heads are not tensor-parallel yet
+    (r".*/mixer/in_proj/kernel$", P("fsdp", None)),
+    (r".*/mixer/out_proj/kernel$", P(None, "fsdp")),
     # MLP heads (value / Q): column-parallel in, row-parallel out
     (r".*/in_proj/kernel$", P("fsdp", "model")),
     (r".*/in_proj/bias$", P("model")),

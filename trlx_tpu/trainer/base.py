@@ -319,6 +319,7 @@ class TPUBaseTrainer(BaseRLTrainer):
         self.param_mask = mask_fn(params, self.tcfg, config.model.num_layers_unfrozen)
         self.draft_module = self.draft_params = self.draft_tcfg = None
         self.last_spec_stats: Dict[str, float] = {}
+        self.last_cache_stats: Dict[str, float] = {}
         self.last_generate_time = 0.0
         # where the host gap before the next train step began (perf_counter):
         # the end of the last step's fence, or of the collection before it
@@ -1310,21 +1311,36 @@ class TPUBaseTrainer(BaseRLTrainer):
     def _note_dense_kv_gauge(self, prompt_shape, gen_config) -> None:
         """``memory/kv_cache_bytes`` for the serial dense path: the cache
         is allocated inside the jitted program, so the gauge is computed
-        from the static shapes (exact). The continuous-batching engines
-        report their own measured gauge (EngineStats.metrics)."""
+        from the static shapes of that pytree (exact). The same walk gives
+        the collection record its two counters, the two kinds of
+        per-sequence state side by side by leaf name:
+        ``rollout/kv_cache_bytes`` (``k``, ``v``) and
+        ``rollout/ssm_state_bytes`` (``ssm``, ``conv``; 0 for a KV-only
+        model). The continuous-batching engines report their own measured
+        gauge (EngineStats.metrics)."""
         if self.is_seq2seq:
             return  # T5 cross/self caches have their own layout; not gauged
-        from trlx_tpu.ops.paged_kv import dense_kv_bytes
+        from trlx_tpu.ops.paged_kv import kv_bytes, recurrent_state_bytes
 
         B, P = prompt_shape
         S = P + gen_config.max_new_tokens
-        total = dense_kv_bytes(self.tcfg, B, S)
+
+        def cache(tcfg, slots):
+            return jax.eval_shape(lambda: make_kv_cache(tcfg, B, slots))
+
+        policy_cache = cache(self.tcfg, S)
+        state = recurrent_state_bytes(policy_cache)
+        total = kv_bytes(policy_cache) - state
+        self.last_cache_stats = {
+            "rollout/kv_cache_bytes": float(total),
+            "rollout/ssm_state_bytes": float(state),
+        }
         if self.draft_module is not None:
             # speculative decoding: target + draft caches, both S + gamma
             # slots (ops/speculative.py sizes them P + N + G)
             S_spec = S + int(self.config.model.draft_gamma)
-            total = dense_kv_bytes(self.tcfg, B, S_spec) + dense_kv_bytes(
-                self.draft_tcfg, B, S_spec
+            total = kv_bytes(cache(self.tcfg, S_spec)) + kv_bytes(
+                cache(self.draft_tcfg, S_spec)
             )
         self.obs.metrics.set_gauge("memory/kv_cache_bytes", float(total))
 

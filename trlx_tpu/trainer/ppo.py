@@ -490,6 +490,7 @@ class PPOTrainer(TPUBaseTrainer):
             },
         )
         stats.update(self.last_spec_stats)
+        stats.update(self.last_cache_stats)
 
         # dispatch the scoring forward immediately on the generation's
         # device arrays — it needs nothing from the host, so it runs while
