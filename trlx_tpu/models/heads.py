@@ -65,6 +65,7 @@ class CausalLMWithValueHead(nn.Module):
         cache_index=None,
         branch_layer: Optional[int] = None,
         logits_span: Optional[Tuple[int, int]] = None,
+        kv_extents: Optional[Tuple[int, ...]] = None,
     ) -> Dict[str, Any]:
         out = self.backbone(
             input_ids,
@@ -74,6 +75,7 @@ class CausalLMWithValueHead(nn.Module):
             cache_index=cache_index,
             branch_layer=branch_layer,
             logits_span=logits_span,
+            kv_extents=kv_extents,
         )
         out["value"] = self.v_head(out["hidden_states"])[..., 0]
         return out
@@ -137,10 +139,12 @@ class CausalLMWithILQLHeads(nn.Module):
         cache=None,
         cache_index=None,
         logits_span: Optional[Tuple[int, int]] = None,
+        kv_extents: Optional[Tuple[int, ...]] = None,
     ) -> Dict[str, Any]:
         out = self.backbone(
             input_ids, attention_mask=attention_mask, positions=positions,
             cache=cache, cache_index=cache_index, logits_span=logits_span,
+            kv_extents=kv_extents,
         )
         # the vocab-sized Q heads are as expensive as the lm head — restrict
         # them to the same span (V stays full: values are per-state scalars)

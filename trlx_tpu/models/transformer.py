@@ -718,6 +718,41 @@ def grouped_einsum_attention(q, k, v, attention_bias, dtype) -> jax.Array:
     return out.reshape(B, T, H, D)
 
 
+@jax.tree_util.register_static
+@dataclasses.dataclass(frozen=True)
+class StaticExtents:
+    """The sampler's static ``kv_extents`` on its way down to ``Attention``:
+    a pytree with no leaves, so ``nn.remat``, ``nn.scan`` and the pipeline's
+    ``jax.checkpoint`` hand the Python ints through instead of tracing them."""
+
+    slots: Tuple[int, ...]
+
+
+def extent_attention(q, k, v, attention_bias, cache_index, kv_extents, dtype) -> jax.Array:
+    """``grouped_einsum_attention`` of one query token over the shortest
+    static prefix of the cache that holds the slot just written.
+
+    ``kv_extents`` is an ascending tuple of slot counts ending at the cache's
+    own (``ops/sampling.py::kv_extents``); a step writing slot
+    ``cache_index`` sees slots ``[0, cache_index]``, so it takes the first
+    extent of at least ``cache_index + 1``. Every slot above the one written
+    is masked by the bias and adds ``exp(-1e9) = 0.0`` to the softmax, so
+    leaving it unread changes no term, only how many bytes of K and V the
+    step moves. Each branch slices its own operands: q, the whole caches and
+    the bias go into the conditional as they are, and nothing is copied."""
+
+    def over(extent):
+        def attend(q, k, v, bias):
+            return grouped_einsum_attention(
+                q, k[:, :extent], v[:, :extent], bias[..., :extent], dtype
+            )
+
+        return attend
+
+    branch = sum((cache_index + 1 > e).astype(jnp.int32) for e in kv_extents[:-1])
+    return jax.lax.switch(branch, [over(e) for e in kv_extents], q, k, v, attention_bias)
+
+
 class Attention(nn.Module):
     """Multi-head / grouped-query attention with RoPE/ALiBi and an explicit
     KV cache ({"k","v"} arrays [B, S, kvH, D] written at ``cache_index``)."""
@@ -733,6 +768,7 @@ class Attention(nn.Module):
         cache: Optional[Dict[str, jax.Array]] = None,
         cache_index: Optional[jax.Array] = None,
         flash_args: Optional[Dict[str, Any]] = None,  # pallas path (see below)
+        kv_extents: Optional[StaticExtents] = None,  # see extent_attention
     ):
         cfg = self.config
         B, T, _ = x.shape
@@ -877,6 +913,9 @@ class Attention(nn.Module):
             ).reshape(B, T, H * D)
         elif flash_args is not None:
             out = _flash_attention(q, k, v, flash_args).reshape(B, T, H * D)
+        elif kv_extents is not None and T == 1 and cache is not None and ci.ndim == 0:
+            # the sampler's single-token step, all rows at one slot
+            out = extent_attention(q, k, v, attention_bias, ci, kv_extents.slots, cfg.dtype).reshape(B, T, H * D)
         else:
             out = grouped_einsum_attention(q, k, v, attention_bias, cfg.dtype).reshape(B, T, H * D)
         out = _dense(cfg, cfg.hidden_size, cfg.attn_bias, ("joined_kv", "embed"), "o_proj")(out)
@@ -1324,7 +1363,7 @@ class Block(nn.Module):
     config: TransformerConfig
 
     @nn.compact
-    def __call__(self, x, attention_bias, positions, cache=None, cache_index=None, flash_args=None, token_mask=None):
+    def __call__(self, x, attention_bias, positions, cache=None, cache_index=None, flash_args=None, token_mask=None, kv_extents=None):
         cfg = self.config
 
         def run_mlp(h):
@@ -1337,14 +1376,14 @@ class Block(nn.Module):
             # both mixers read the same normed input; their outputs are
             # summed before the one residual add, then the MLP as usual
             attn_in = h * cfg.attention_in_multiplier if cfg.attention_in_multiplier != 1.0 else h
-            attn_out, new_cache = Attention(cfg, name="attn")(attn_in, attention_bias, positions, cache, cache_index, flash_args)
+            attn_out, new_cache = Attention(cfg, name="attn")(attn_in, attention_bias, positions, cache, cache_index, flash_args, kv_extents)
             mix_out, new_state = Mamba2Mixer(cfg, name="mixer")(h, cache, token_mask)
             if cache is not None:
                 new_cache = {**new_cache, **new_state}
             x = x + mix_out * cfg.ssm_out_multiplier + attn_out * cfg.attention_out_multiplier
             mlp_out, aux = run_mlp(Norm(cfg, name="ln_mlp")(x))
             return x + mlp_out, new_cache, aux
-        attn_out, new_cache = Attention(cfg, name="attn")(h, attention_bias, positions, cache, cache_index, flash_args)
+        attn_out, new_cache = Attention(cfg, name="attn")(h, attention_bias, positions, cache, cache_index, flash_args, kv_extents)
         if cfg.parallel_residual:
             mlp_in = h if cfg.shared_ln else Norm(cfg, name="ln_mlp")(x)
             mlp_out, aux = run_mlp(mlp_in)
@@ -1389,10 +1428,10 @@ class _ScanBlockBody(nn.Module):
     config: TransformerConfig
 
     @nn.compact
-    def __call__(self, carry, cache_layer, layer_idx, attention_bias, positions, cache_index, flash_args, branch_at, token_mask):
+    def __call__(self, carry, cache_layer, layer_idx, attention_bias, positions, cache_index, flash_args, branch_at, token_mask, kv_extents):
         x, branch_input, aux_sum = carry
         x_new, new_cache, aux = _block_cls(self.config)(self.config, name="block")(
-            x, attention_bias, positions, cache_layer, cache_index, flash_args, token_mask
+            x, attention_bias, positions, cache_layer, cache_index, flash_args, token_mask, kv_extents
         )
         if branch_input is not None:  # static: only hydra passes pay for it
             branch_input = jnp.where(layer_idx == branch_at, x, branch_input)
@@ -1443,7 +1482,7 @@ class CausalTransformer(nn.Module):
                 _ScanBlockBody,
                 variable_axes={"params": 0},
                 split_rngs={"params": True},
-                in_axes=(0, 0, nn.broadcast, nn.broadcast, nn.broadcast, nn.broadcast, nn.broadcast, nn.broadcast),
+                in_axes=(0, 0) + (nn.broadcast,) * 7,
                 out_axes=0,
                 length=cfg.num_layers,
             )
@@ -1541,9 +1580,13 @@ class CausalTransformer(nn.Module):
         # projection restricted to these positions — the vocab matmul is the
         # single biggest op in PPO scoring/training forwards and only the
         # response span is consumed there
+        kv_extents: Optional[Tuple[int, ...]] = None,  # static, ascending, ending at
+        # the cache's slots: a single-token step attends over the first that
+        # holds its slot (extent_attention); one extent or None reads them all
     ) -> Dict[str, Any]:
         cfg = self.config
         B, T = input_ids.shape
+        extents = StaticExtents(tuple(kv_extents)) if kv_extents and len(kv_extents) > 1 else None
         if attention_mask is None:
             attention_mask = jnp.ones((B, T), jnp.int32)
         if cache is None:
@@ -1588,7 +1631,7 @@ class CausalTransformer(nn.Module):
         if pipe_mesh is not None:
             x, branch_input, new_cache, aux = self._pipelined_blocks(
                 pipe_mesh, x, attention_mask, positions, use_flash,
-                cache, cache_index, branch_layer,
+                cache, cache_index, branch_layer, extents,
             )
             return self._epilogue(x, branch_input, new_cache, logits_span, aux)
         bias, flash_args = self._attn_inputs(
@@ -1613,6 +1656,7 @@ class CausalTransformer(nn.Module):
                 flash_args,
                 jnp.asarray(branch_at),
                 token_mask,
+                extents,
             )
             if branch_layer is not None:
                 branch_input = branch_buf
@@ -1622,7 +1666,7 @@ class CausalTransformer(nn.Module):
                 if branch_layer is not None and i == len(self.blocks) - branch_layer:
                     branch_input = x
                 layer_cache = cache[i] if cache is not None else None
-                x, updated, aux_i = block(x, bias, positions, layer_cache, cache_index, flash_args, token_mask)
+                x, updated, aux_i = block(x, bias, positions, layer_cache, cache_index, flash_args, token_mask, extents)
                 aux = aux + aux_i
                 if cache is not None:
                     new_cache.append(updated)
@@ -1649,7 +1693,7 @@ class CausalTransformer(nn.Module):
         return out
 
     def _pipelined_blocks(
-        self, mesh, x, attention_mask, positions, use_flash, cache, cache_index, branch_layer
+        self, mesh, x, attention_mask, positions, use_flash, cache, cache_index, branch_layer, kv_extents=None
     ):
         """Run the stacked blocks through the GPipe schedule over the mesh's
         ``pipe`` axis (``parallel/pipeline.py``) — the reference's Megatron
@@ -1683,7 +1727,7 @@ class CausalTransformer(nn.Module):
         def apply_block(layer_params, h, attn_inputs, cache_layer, cidx):
             bias_mb, flash_mb, pos_mb, tm = attn_inputs
             return body_block.apply(
-                {"params": layer_params}, h, bias_mb, pos_mb, cache_layer, cidx, flash_mb, tm
+                {"params": layer_params}, h, bias_mb, pos_mb, cache_layer, cidx, flash_mb, tm, kv_extents
             )
 
         if cfg.remat in ("full", "minimal"):

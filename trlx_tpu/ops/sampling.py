@@ -11,6 +11,7 @@ ILQL's ``logπ + β(minQ − V)`` advantage reshaping plugs in here (reference:
 """
 
 import dataclasses
+from bisect import bisect_left
 from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -186,6 +187,51 @@ def last_step_info(out: Dict[str, Any]) -> Dict[str, Any]:
     return info
 
 
+# The decode loop's cache extents: their step in slots, how many of them a
+# layer may have (each is one more attention body a layer to trace, compile
+# and load; the step doubles until they fit), and the share of the cache
+# reads they must spare to be worth a conditional at all. On one v5e, 128
+# prompt + 512 new tokens, 64 rows, medians of six seeds (PERF.md section 6,
+# PR 32): a step of 128 slots (four extents) gave the dense and the MoE cell
+# +4.6% and +2.2% samples/s, 64 (eight) +6.9% and +3.0%, at warm set-ups of
+# 40.7 | 42.2 | 43.5 s and 45.3 | 44.1 | 45.5 s (none | 128 | 64; spread 6 to
+# 12%). Constants with their measurement, not settings.
+KV_BUCKET = 64
+MAX_KV_EXTENTS = 8
+MIN_KV_SAVING = 0.1
+
+
+def kv_extents(prompt_width: int, max_new_tokens: int) -> Tuple[int, ...]:
+    """The static cache extents ``generate``'s decode steps attend over: the
+    multiples of the bucket strictly between the prompt's width and the
+    cache's ``S = P + N`` slots, then ``S``. A step writing slot ``t``
+    attends over the first extent of at least ``t + 1``
+    (``models/transformer.py::extent_attention``), so a cache that starts a
+    fifth full is not read whole at every step. ``(128, 512)`` gives ``(192,
+    256, ..., 576, 640)``; ``(128, 1024)`` takes a bucket of 128 to stay at
+    eight; a decode that would spare under a tenth of its reads, ``(896,
+    128)``, gives ``(1024,)`` and the program that reads every slot."""
+    P, N, S = prompt_width, max_new_tokens, prompt_width + max_new_tokens
+
+    def every(bucket):
+        return (*range((P // bucket + 1) * bucket, S, bucket), S)
+
+    bucket = KV_BUCKET
+    while len(every(bucket)) > MAX_KV_EXTENTS:
+        bucket *= 2
+    extents = every(bucket)
+    if kv_slots_read(extents, P, N) > (1 - MIN_KV_SAVING) * N * S:
+        return (S,)
+    return extents
+
+
+def kv_slots_read(extents: Tuple[int, ...], prompt_width: int, steps: int) -> int:
+    """Cache slots a row's attention reads over the first ``steps`` decode
+    steps under ``extents`` (step ``i`` writes slot ``P + i``): host
+    arithmetic for ``rollout/kv_read_frac``, against ``steps * extents[-1]``."""
+    return sum(extents[bisect_left(extents, prompt_width + i + 1)] for i in range(steps))
+
+
 class GenerationOutput(NamedTuple):
     sequences: jax.Array  # [B, P + N] prompt (left-padded) ‖ response
     response_tokens: jax.Array  # [B, N] pad-filled after eos
@@ -210,7 +256,10 @@ def generate(
     ``apply_fn(params, input_ids, attention_mask, positions, cache,
     cache_index)`` must return a dict with at least ``logits`` and ``cache``
     (the model wrappers' ``__call__``). ``adjust_logits(step_outputs, logits)``
-    may reshape the last-token logits before sampling (ILQL).
+    may reshape the last-token logits before sampling (ILQL). Where the
+    decode loop crosses a ``KV_BUCKET`` boundary the single-token step is
+    also handed ``kv_extents=`` (:func:`kv_extents`), which the wrappers
+    pass down to ``Attention``.
 
     Fully jittable; wrap in ``jax.jit``/``pjit`` with static ``config``.
     """
@@ -218,6 +267,11 @@ def generate(
     N = config.max_new_tokens
     S = P + N
     input_ids = input_ids.astype(jnp.int32)
+
+    # single-token steps only; the key is absent where one extent covers the
+    # loop, and the step is then the program it was before there were extents
+    extents = kv_extents(P, N)
+    step_kwargs = {"kv_extents": extents} if len(extents) > 1 else {}
 
     cache = init_cache_fn(B, S)
     # slot mask over the full cache: prompt mask then zeros (filled as we go)
@@ -288,6 +342,7 @@ def generate(
             positions=(prompt_len + carry.step)[:, None],
             cache=carry.cache,
             cache_index=slot,
+            **step_kwargs,
         )
         return Carry(
             tokens=tokens,

@@ -38,6 +38,7 @@ from trlx_tpu.ops.sampling import (
     GenerationOutput,
     generate,
     generate_seq2seq,
+    kv_extents,
 )
 from trlx_tpu.parallel import make_mesh, set_global_mesh, shard_batch, shard_params
 from trlx_tpu.pipeline import BasePipeline
@@ -320,6 +321,10 @@ class TPUBaseTrainer(BaseRLTrainer):
         self.draft_module = self.draft_params = self.draft_tcfg = None
         self.last_spec_stats: Dict[str, float] = {}
         self.last_cache_stats: Dict[str, float] = {}
+        # the serial dense sampler's static cache extents for the newest
+        # generate() call (ops/sampling.py::kv_extents); None on the paths
+        # that read every slot (seq2seq, speculative)
+        self.last_kv_extents: Optional[Tuple[int, ...]] = None
         self.last_generate_time = 0.0
         # where the host gap before the next train step began (perf_counter):
         # the end of the last step's fence, or of the collection before it
@@ -1318,12 +1323,15 @@ class TPUBaseTrainer(BaseRLTrainer):
         ``rollout/ssm_state_bytes`` (``ssm``, ``conv``; 0 for a KV-only
         model). The continuous-batching engines report their own measured
         gauge (EngineStats.metrics)."""
+        self.last_kv_extents = None
         if self.is_seq2seq:
             return  # T5 cross/self caches have their own layout; not gauged
         from trlx_tpu.ops.paged_kv import kv_bytes, recurrent_state_bytes
 
         B, P = prompt_shape
         S = P + gen_config.max_new_tokens
+        if self.draft_module is None:
+            self.last_kv_extents = kv_extents(P, gen_config.max_new_tokens)
 
         def cache(tcfg, slots):
             return jax.eval_shape(lambda: make_kv_cache(tcfg, B, slots))

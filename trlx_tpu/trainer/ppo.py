@@ -41,7 +41,7 @@ from trlx_tpu.observability.dynamics import (
     sketch_np,
 )
 from trlx_tpu.models.transformer import CausalTransformer
-from trlx_tpu.ops.sampling import GenerationOutput
+from trlx_tpu.ops.sampling import GenerationOutput, kv_slots_read
 from trlx_tpu.parallel import shard_batch
 from trlx_tpu.pipeline import BasePipeline
 from trlx_tpu.pipeline.ppo_pipeline import PPORolloutStorage, length_ladder
@@ -510,6 +510,7 @@ class PPOTrainer(TPUBaseTrainer):
             "prompt_mask": prompt_mask,
             "gen_out": gen_out,
             "score_out": score_out,
+            "kv_extents": self.last_kv_extents,
         }
 
     def _rollout_chunk_host(self, dev: Dict[str, Any]) -> Dict[str, Any]:
@@ -557,6 +558,7 @@ class PPOTrainer(TPUBaseTrainer):
             "response_mask": response_mask,
             "scores": scores,
             "host": host,
+            "kv_extents": dev.get("kv_extents"),
             "stats": stats,
             "host_s": perf_counter() - host_t0,
             # what this stage spent inside reward_fn and waiting for the
@@ -647,6 +649,13 @@ class PPOTrainer(TPUBaseTrainer):
             acc["decode_steps"] += decode_steps
             acc["slot_steps"] += int(response_mask.shape[0]) * decode_steps
             acc["live_slot_steps"] += int(n_per_row.sum())
+            # cache slots a row's attention read over those steps: the dense
+            # sampler's steps stop at a static extent of the cache
+            # (ops/sampling.py::kv_extents); every other sampler reads it whole
+            P, N = chunk["prompt_ids"].shape[1], response_mask.shape[1]
+            extents = chunk.get("kv_extents") or (P + N,)
+            acc["kv_slots_read"] += kv_slots_read(extents, P, decode_steps)
+            acc["kv_slots"] += decode_steps * (P + N)
 
             prompt_ids, prompt_mask = chunk["prompt_ids"], chunk["prompt_mask"]
             # async chunks ship the sampler's exact behavior logprobs; they ride
@@ -1315,6 +1324,7 @@ class PPOTrainer(TPUBaseTrainer):
             "gen_tokens": 0, "chunks": 0,
             "slot_steps": 0, "live_slot_steps": 0,
             "decode_steps": 0, "blocked_s": 0.0,
+            "kv_slots_read": 0, "kv_slots": 0,
         }
         self.obs.tracer.next_cycle()
         with self.obs.span("collect/experience"):
@@ -1374,6 +1384,10 @@ class PPOTrainer(TPUBaseTrainer):
                         "rollout/padded_decode_frac",
                         1.0 - acc["live_slot_steps"] / acc["slot_steps"],
                     )
+                # the share of the cache's slots the decode steps' attention read
+                stats["rollout/kv_read_frac"] = (
+                    acc["kv_slots_read"] / acc["kv_slots"] if acc["kv_slots"] else 1.0
+                )
                 # rollout-side dynamics summaries + health canary (accumulated per
                 # chunk in _rollout_chunk_finalize; setdefault keeps the engine's
                 # exact counters when continuous batching already merged them)
