@@ -281,6 +281,30 @@ def test_flash_kernels_keep_their_names_in_the_compiled_program(topo):
         assert len(hits) == 1 and hits[0].startswith(f"%{kernel}"), (metric, calls)
 
 
+@pytest.mark.parametrize("window", [4096, None], ids=["window_4096", "global"])
+def test_flash_forward_and_backward_compile_at_8192_slots(topo, window):
+    """``smallthinker21b_grpo_ctx8k``'s learner: one row of 8192 slots, 28
+    query heads over 4 key/value heads of 128. The fused backward keeps
+    whole-sequence q, do, dq, lse and delta in VMEM across its k-block steps,
+    32 MiB double-buffered at this length: Mosaic's default 16 MiB scope
+    refuses it ("Ran out of memory in memory space vmem"), so past that the
+    kernel asks for its own limit (``_bwd_vmem_params``); at the lengths every
+    other cell runs it asks for nothing and is the program it was."""
+    from trlx_tpu.ops import flash_attention as fa
+
+    assert fa._bwd_vmem_params(1024, 128, 2, False) == {} == fa._bwd_vmem_params(1024, 256, 2, False)
+    assert fa._bwd_vmem_params(8192, 128, 2, True) == {}  # the interpreter has no VMEM
+    assert fa._bwd_vmem_params(8192, 128, 2, False)["compiler_params"].vmem_limit_bytes == 40 * 2**20
+
+    def loss(q, k, v, m):
+        return fa.flash_attention(q, k, v, m, window=window, interpret=False).astype(jnp.float32).sum()
+
+    q, kv = _s((1, 8192, 28, 128)), _s((1, 8192, 4, 128))
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), (q, kv, kv, _s((1, 8192), jnp.float32)),
+                    SingleDeviceSharding(topo.devices[0]))
+    assert text.count('custom_call_target="tpu_custom_call"') == 2  # the forward and the fused backward
+
+
 def test_dense_decode_step_builds_no_repeated_kv(topo, monkeypatch):
     """Cached single-token decoding runs the dense einsum branch of
     ``Attention``. At the attention shapes of ``mistral7b_grpo_decode``

@@ -30,6 +30,11 @@ from trlx_tpu.ops.sampling import GenerationConfig, generate, kv_extents, kv_slo
 
 FAMILIES = ["mistral", "gptj", "olmoe", "falconh1"]
 P, N = 6, 10  # with a bucket of 4: extents 8, 12, 16
+# The Mistral toy's window of 8 is shorter than these rows of 16, and a window
+# layer's cache is then a ring of 8 slots with one extent, its own
+# (tests/test_smallthinker.py and test_mistral_ring_* below hold those). Here
+# the window holds the row, so that the extents are what is tested.
+WINDOW_OVER_THE_ROW = {"mistral": dict(sliding_window=64)}
 
 
 @pytest.fixture
@@ -39,7 +44,8 @@ def bucket4(monkeypatch):
 
 def _model(family, **kw):
     cfg = config_from_spec(f"builtin:{family}-test", dtype=jnp.float32,
-                           param_dtype=jnp.float32, attention_impl="xla", **kw)
+                           param_dtype=jnp.float32, attention_impl="xla",
+                           **{**WINDOW_OVER_THE_ROW.get(family, {}), **kw})
     model = CausalTransformer(cfg)
     ids = jax.random.randint(jax.random.PRNGKey(1), (2, P), 3, 50)
     params = model.init(jax.random.PRNGKey(0), ids)["params"]
@@ -186,7 +192,8 @@ def test_pipelined_blocks_take_the_branch_too(monkeypatch):
 
 
 def _step_jaxpr(family, kv_extents=None, tokens=1, cache_index=None, paged=False, **kw):
-    cfg = config_from_spec(f"builtin:{family}-test", attention_impl="xla", **kw)
+    cfg = config_from_spec(f"builtin:{family}-test", attention_impl="xla",
+                           **{**WINDOW_OVER_THE_ROW.get(family, {}), **kw})
     model = CausalTransformer(cfg)
     ids = jnp.zeros((2, 12), jnp.int32)
     params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), ids)["params"])
@@ -311,3 +318,32 @@ def test_collection_record_carries_kv_read_frac(method, tmp_path, bucket4):
     assert total > 0
     assert rec["rollout/kv_read_frac"] == pytest.approx(read / total)
     assert rec["rollout/kv_read_frac"] < 1.0
+
+
+# ---------------------------------------------------------------------------
+# a uniform window shorter than the row: every layer's cache is a ring
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("scan_layers", [False, True], ids=["layers", "scan"])
+def test_mistral_ring_decode_matches_the_windowed_full_forward(scan_layers, bucket4):
+    """The Mistral toy at its own window of 8 on rows of 16: ``make_kv_cache``
+    gives every layer 8 slots, the prefill of 6 does not wrap, the ten steps
+    do, and each step's logits are the windowed full forward's; the extents
+    (8, 12, 16) cut to the ring leave one, so the step has no conditional."""
+    cfg, model, params, ids = _model("mistral", scan_layers=scan_layers, sliding_window=8)
+    cache = make_kv_cache(cfg, 2, P + N)
+    assert {leaf.shape[-3] for leaf in jax.tree_util.tree_leaves(cache)} == {8}
+    tokens = jnp.concatenate([ids, 7 + jnp.arange(N)[None].repeat(2, 0)], axis=1)
+    want = model.apply({"params": params}, tokens, attention_mask=jnp.ones((2, P + N), jnp.int32))["logits"]
+    mask = jnp.concatenate([jnp.ones((2, P), jnp.int32), jnp.zeros((2, N), jnp.int32)], 1)
+    out = model.apply({"params": params}, ids, attention_mask=mask, cache=cache,
+                      cache_index=jnp.asarray(0, jnp.int32))
+    step = lambda c, m, t, s: model.apply({"params": params}, t, attention_mask=m, cache=c,
+                                          cache_index=s, kv_extents=kv_extents(P, N))
+    assert "cond[" not in str(jax.make_jaxpr(step)(out["cache"], mask, tokens[:, P:P + 1], jnp.asarray(P, jnp.int32)))
+    for i in range(N):
+        mask = mask.at[:, P + i].set(1)
+        out = jax.jit(step)(out["cache"], mask, tokens[:, P + i : P + i + 1], jnp.asarray(P + i, jnp.int32))
+        np.testing.assert_allclose(np.asarray(out["logits"][:, 0]), np.asarray(want[:, P + i]),
+                                   rtol=2e-4, atol=2e-4, err_msg=f"slot {P + i}")

@@ -7,8 +7,9 @@ multiplier other than 1), float32 on both sides, so the mathematics has to
 agree: the chunked scan against the token-by-token recurrence, the state
 through the sampler's cache, left padding with lengths off the chunk grid, the
 hydra branch replay, and the scan's gradient. Also here: the KV-only rollout
-paths refuse the family by name, and the Mistral, GPT-J and OLMoE toy presets
-trace to the programs they had before the family was added.
+paths refuse the family by name, and the Mistral, GPT-J, OLMoE and Falcon-H1
+toy presets trace to the programs they had before the per-layer attention
+layouts (PR 33) were added.
 """
 
 import hashlib
@@ -352,7 +353,9 @@ def test_collection_counters_read_the_cache_by_leaf_name(family, state_bytes):
         parallel=dict(param_dtype="float32", compute_dtype="float32"))
     trainer = PPOTrainer(cfg, reward_fn=lambda samples, **kw: [0.0] * len(samples))
     trainer._note_dense_kv_gauge((3, 8), GenerationConfig(max_new_tokens=4))
-    kv = 2 * 2 * 3 * 12 * trainer.tcfg.kv_heads * trainer.tcfg.dims_per_head * 4  # k, v; 2 blocks; 12 slots
+    # k, v; 2 blocks; 12 slots, or the 8 of the Mistral toy's window: a window layer's ring
+    slots = min(12, trainer.tcfg.sliding_window or 12)
+    kv = 2 * 2 * 3 * slots * trainer.tcfg.kv_heads * trainer.tcfg.dims_per_head * 4
     assert trainer.last_cache_stats == {
         "rollout/kv_cache_bytes": float(kv), "rollout/ssm_state_bytes": float(state_bytes)}
 
@@ -370,19 +373,23 @@ def test_hf_interop_says_there_is_no_converter():
 # dense and MoE presets trace to the programs they had before this family
 # ---------------------------------------------------------------------------
 
-RECORDED = os.path.join(os.path.dirname(__file__), "fixtures", "programs_before_falconh1.json")
+RECORDED = os.path.join(os.path.dirname(__file__), "fixtures", "programs_before_smallthinker.json")
+RECORDED_FAMILIES = ("mistral", "gptj", "olmoe", "falconh1")
 
 
 def program_fingerprints(family):
     """sha256 of the toy preset's parameter tree, cache tree, and the jaxpr
-    text of one forward and one decode step (float32, xla attention)."""
+    text of one forward and one decode step (float32, xla attention). Eight
+    slots: no more than the Mistral toy's window, so that no layer's cache is
+    shorter than the row (a window layer's is a ring of ``min(S, window)``
+    slots since the per-layer layouts)."""
     cfg = config_from_spec(f"builtin:{family}-test", attention_impl="xla")
     model = CausalTransformer(cfg)
-    ids = jnp.zeros((2, 12), jnp.int32)
-    mask = jnp.ones((2, 12), jnp.int32)
+    ids = jnp.zeros((2, 6), jnp.int32)
+    mask = jnp.ones((2, 6), jnp.int32)
     params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), ids)["params"])
-    cache = jax.eval_shape(lambda: make_kv_cache(cfg, 2, 16))
-    slots = jnp.ones((2, 16), jnp.int32)
+    cache = jax.eval_shape(lambda: make_kv_cache(cfg, 2, 8))
+    slots = jnp.ones((2, 8), jnp.int32)
     texts = {
         "params": str(jax.tree_util.tree_map(lambda x: (x.shape, str(x.dtype)), params)),
         "cache": str(jax.tree_util.tree_map(lambda x: (x.shape, str(x.dtype)), cache)),
@@ -390,17 +397,18 @@ def program_fingerprints(family):
             lambda p: model.apply({"params": p}, ids, attention_mask=mask, branch_layer=1))(params)),
         "decode": str(jax.make_jaxpr(
             lambda p, c: model.apply({"params": p}, ids[:, :1], attention_mask=slots, cache=c,
-                                     cache_index=jnp.asarray(12, jnp.int32)))(params, cache)),
+                                     cache_index=jnp.asarray(6, jnp.int32)))(params, cache)),
     }
     clean = lambda text: re.sub(r"0x[0-9a-f]+", "0x", text)
     return {k: hashlib.sha256(clean(v).encode()).hexdigest() for k, v in texts.items()}
 
 
-@pytest.mark.parametrize("family", ["mistral", "gptj", "olmoe"])
+@pytest.mark.parametrize("family", RECORDED_FAMILIES)
 def test_kv_only_presets_trace_to_the_programs_recorded_before_the_family(family):
-    """Recorded on the parent commit by this function (``python
-    tests/test_falconh1.py`` there writes the file): parameter tree, cache
-    tree and both jaxprs byte for byte."""
+    """Recorded on the commit before the per-layer attention layouts (PR 33's
+    parent) by this function (``python tests/test_falconh1.py`` there writes
+    the file): parameter tree, cache tree and both jaxprs byte for byte, of
+    the three KV-only presets and of this family's own."""
     # other test files of the same worker set jax_default_matmul_precision at
     # import, and a precision is printed on every dot of a jaxpr
     with jax.default_matmul_precision(None), open(RECORDED) as f:
@@ -408,4 +416,4 @@ def test_kv_only_presets_trace_to_the_programs_recorded_before_the_family(family
 
 
 if __name__ == "__main__":  # the recorder
-    print(json.dumps({f: program_fingerprints(f) for f in ("mistral", "gptj", "olmoe")}, indent=1))
+    print(json.dumps({f: program_fingerprints(f) for f in RECORDED_FAMILIES}, indent=1))

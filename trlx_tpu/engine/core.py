@@ -75,6 +75,7 @@ from trlx_tpu.ops.paged_kv import (
     kv_bytes,
     num_table_blocks,
     refuse_recurrent_state,
+    refuse_ring_cache,
 )
 
 __all__ = [
@@ -616,6 +617,7 @@ class ContinuousEngine(Engine):
             self.allocator = BlockAllocator(self.spec.max_blocks)
             if prefix_cache:
                 refuse_recurrent_state(self.state.cache, "prefix_cache")
+                refuse_ring_cache(self.state.cache.pool, self._bs, "prefix_cache")
                 self.prefix = PrefixCache(self._bs, prefix_capacity_blocks)
                 self.stats.prefix_enabled = True
             # host mirror of the device block table — authoritative between

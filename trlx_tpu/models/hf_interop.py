@@ -23,6 +23,12 @@ _FALCON_H1_NO_INTEROP = (
     "from 'builtin:falconh1-<size>' (random weights) only; the converter pair was "
     "never checked against a checkpoint (ROADMAP.md queue 2, B7)"
 )
+_SMALLTHINKER_NO_INTEROP = (
+    "model_type 'smallthinker' has no HF checkpoint conversion yet: no checkpoint can "
+    "be fetched where this was built, so the family runs from "
+    "'builtin:smallthinker-<size>' (random weights) only and no converter pair was "
+    "ever checked against one (ROADMAP.md queue 2, B3)"
+)
 
 
 class UnsupportedHFExport(ValueError):
@@ -395,6 +401,7 @@ def config_from_hf(hf_config) -> TransformerConfig:
             mlp_bias=False,
             tie_word_embeddings=bool(getattr(hf_config, "tie_word_embeddings", False)),
             num_experts=hf_config.num_local_experts,
+            moe_gated=True,
             num_experts_per_tok=hf_config.num_experts_per_tok,
             router_aux_coef=getattr(hf_config, "router_aux_loss_coef", 0.01),
             moe_group_size=512,
@@ -424,6 +431,7 @@ def config_from_hf(hf_config) -> TransformerConfig:
             tie_word_embeddings=bool(getattr(hf_config, "tie_word_embeddings", False)),
             qk_norm=True,
             num_experts=hf_config.num_experts,
+            moe_gated=True,
             num_experts_per_tok=hf_config.num_experts_per_tok,
             moe_renormalize=bool(getattr(hf_config, "norm_topk_prob", False)),
             router_aux_coef=getattr(hf_config, "router_aux_loss_coef", 0.01),
@@ -500,6 +508,8 @@ def config_from_hf(hf_config) -> TransformerConfig:
         )
     if mt == "falcon_h1":
         raise ValueError(_FALCON_H1_NO_INTEROP)
+    if mt == "smallthinker":
+        raise ValueError(_SMALLTHINKER_NO_INTEROP)
     raise ValueError(f"Unsupported HF model type for causal import: {mt}")
 
 
@@ -1133,6 +1143,8 @@ def hf_config_from_transformer(cfg):
         )
     if mt == "falcon_h1":
         raise UnsupportedHFExport(_FALCON_H1_NO_INTEROP)
+    if mt == "smallthinker":
+        raise UnsupportedHFExport(_SMALLTHINKER_NO_INTEROP)
     raise UnsupportedHFExport(
         f"No HF export mapping for model_type={mt!r} "
         "(set TransformerConfig.model_type to an HF family)"

@@ -21,7 +21,7 @@ TPU-first design decisions:
 import dataclasses
 import functools
 from functools import partial
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -179,6 +179,24 @@ def _flash_attention(q, k, v, flash_args: Dict[str, Any]) -> jax.Array:
     )(q, k, v, operands)
 
 
+# The stand-in scale of q_proj and k_proj for `builtin:smallthinker-*`, run
+# from random weights (TransformerConfig.qk_init_std): chosen once on the CPU
+# at the published widths so that the window and the two rotary readings each
+# move the float32 logits by several times the chip tolerance
+# (chipbench/configs/smallthinker-21b-a3b-l4e16.json, `assumed`, has the
+# measured numbers). A constant with its measurement, not a setting.
+QK_INIT_STD_SMALLTHINKER = 0.04
+
+
+class LayerLayout(NamedTuple):
+    """One layer's attention layout (``TransformerConfig.layer_layout``): the
+    one definition the bias, the flash arguments, the sampler's cache and the
+    hydra branch all read."""
+
+    window: Optional[int]  # a query sees its last `window` slots; None = full causal
+    rotary: bool  # False: this layer applies no rotary embedding (NoPE)
+
+
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     """Architecture description of a causal decoder-only transformer."""
@@ -206,6 +224,15 @@ class TransformerConfig:
     # are temporally ordered with padding only on the left, so the window is
     # enforced on slot distance in every path (xla bias, flash kernel, ring).
     sliding_window: Optional[int] = None
+    # per-layer attention layout (smallthinker): two published lists of one
+    # 0/1 entry a layer, read independently. `sliding_window_layout[l] = 0`
+    # leaves layer l full causal whatever `sliding_window` says;
+    # `rope_layout[l] = 0` leaves it without rotary embedding (NoPE). None =
+    # every layer as the two scalars above say: a uniform layout is the same
+    # definition with all ones, not a second path (`layer_layout`). Entries
+    # past `num_layers` (a published list on a cut depth) are not read.
+    sliding_window_layout: Optional[Tuple[int, ...]] = None
+    rope_layout: Optional[Tuple[int, ...]] = None
 
     norm: str = "layernorm"  # layernorm | rmsnorm
     layer_norm_epsilon: float = 1e-5
@@ -229,6 +256,12 @@ class TransformerConfig:
     # (torch's nn.Embedding default) routing follows the token, as in a
     # trained MoE. Only matters for models run from random weights.
     embed_init_std: float = 0.02
+    # std of q_proj's and k_proj's random init. At 0.02 and hidden 2560 the
+    # scores q.k/sqrt(d) have a standard deviation of 1: the softmax is close
+    # to flat, and leaving a window out moves the logits by less than a bf16
+    # check can tell from rounding. Only matters for models run from random
+    # weights
+    qk_init_std: float = 0.02
 
     # numerics / compilation
     param_dtype: Any = jnp.float32
@@ -259,13 +292,29 @@ class TransformerConfig:
     # pipe axis > 1 (0 = auto: one per stage). See parallel/pipeline.py.
     pipe_microbatches: int = 0
 
-    # mixture-of-experts MLP (mixtral family; beyond the reference, which has
-    # no MoE — SURVEY.md §2.3 lists EP as n/a). 0 = dense MLP. Experts are
-    # GShard-style einsum dispatch with a per-sequence token group and a
-    # static capacity; expert weights shard over the mesh's `expert` axis
-    # (parallel/mesh.py) so XLA inserts the token all_to_alls.
+    # mixture-of-experts MLP (mixtral, olmoe and smallthinker families; beyond
+    # the reference, which has no MoE — SURVEY.md §2.3 lists EP as n/a). 0 =
+    # dense MLP. `num_experts` is the ROUTER's width. With a capacity
+    # (mixtral) experts are GShard-style einsum dispatch with a per-sequence
+    # token group and a static capacity, all of them held, and their weights
+    # shard over the mesh's `expert` axis (parallel/mesh.py) so XLA inserts
+    # the token all_to_alls.
     num_experts: int = 0
     num_experts_per_tok: int = 2
+    # gated experts, `act(gate) * up` (SwiGLU with silu, ReGLU with relu):
+    # said by the preset, never inferred from the activation's name
+    moe_gated: bool = False
+    # one chip's share of a deployment's experts (dropless routing only): the
+    # layer holds experts [moe_first_expert, moe_first_expert +
+    # moe_experts_held) of the router's `num_experts`, routes over all of
+    # them, renormalises over all the chosen, and computes the part of the
+    # result its own give. 0 = all held
+    moe_experts_held: int = 0
+    moe_first_expert: int = 0
+    # what the router reads: "mlp_input" (the normed input of the experts,
+    # behind attention) or "block_input" (the block's raw input, before the
+    # input norm and before attention: smallthinker)
+    moe_router_input: str = "mlp_input"
     # slots per expert = ceil(k*G*cf/E); 0 = no capacity bound (dropless:
     # every token is computed by all k of its experts, grouped matmuls over
     # the assignments sorted by expert; one chip only, no `expert` axis)
@@ -305,9 +354,38 @@ class TransformerConfig:
             return "pallas" if jax.default_backend() == "tpu" else "xla"
         return self.attention_impl
 
+    def __post_init__(self):
+        for name in ("sliding_window_layout", "rope_layout"):
+            value = getattr(self, name)
+            if value is not None:  # a list from a JSON override
+                value = tuple(int(x) for x in value)
+                if len(value) < self.num_layers:
+                    raise ValueError(f"{name} has {len(value)} entries for {self.num_layers} layers")
+                object.__setattr__(self, name, value)
+
     @property
     def kv_heads(self) -> int:
         return self.num_kv_heads or self.num_heads
+
+    def layer_layout(self, layer: int) -> LayerLayout:
+        windowed = self.sliding_window_layout is None or bool(self.sliding_window_layout[layer])
+        roped = self.rope_layout is None or bool(self.rope_layout[layer])
+        return LayerLayout(
+            window=self.sliding_window if windowed and self.sliding_window else None,
+            rotary=self.position_scheme == "rotary" and roped,
+        )
+
+    @property
+    def layer_layouts(self) -> Tuple[LayerLayout, ...]:
+        return tuple(self.layer_layout(i) for i in range(self.num_layers))
+
+    @property
+    def mixed_layout(self) -> bool:
+        return len(set(self.layer_layouts)) > 1
+
+    @property
+    def experts_held(self) -> int:
+        return self.moe_experts_held or self.num_experts
 
     @property
     def dims_per_head(self) -> int:
@@ -402,6 +480,7 @@ class TransformerConfig:
             mlp_bias=False,
             tie_word_embeddings=False,
             num_experts_per_tok=2,
+            moe_gated=True,
         )
 
     @staticmethod
@@ -423,9 +502,52 @@ class TransformerConfig:
             mlp_bias=False,
             tie_word_embeddings=False,
             qk_norm=True,
+            moe_gated=True,
             moe_capacity_factor=0.0,  # dropless, as published
             moe_renormalize=False,  # norm_topk_prob: false
             router_aux_coef=0.01,
+        )
+
+    @staticmethod
+    def smallthinker(size: str = "21b-a3b", **overrides) -> "TransformerConfig":
+        """SmallThinker-21BA3B-Instruct (PowerInfer): layers 1, 2, 3 of every
+        four attend through a window of 4096 with rotary embedding, layers 0,
+        4, 8, ... attend globally with no positional encoding at all; the
+        router reads the block's raw input; 64 ReGLU experts of 768, top 6
+        renormalised. Limits: the plain sampler, the scoring forward and the
+        train step run a row longer than the window (window layers then keep
+        a ring of ``sliding_window`` slots); slot refill, the paged Engine,
+        the prefix cache and speculation only while no layer's cache is
+        shorter than the row (``ops/paged_kv.py::refuse_ring_cache``); no
+        ``scan_layers``, no HF checkpoint import. ``qk_init_std`` is this
+        preset's stand-in scale for q_proj and k_proj
+        (chipbench/configs/smallthinker-21b-a3b-l4e16.json, `assumed`)."""
+        period = (0, 1, 1, 1)
+        dims = {
+            "test": dict(vocab_size=259, hidden_size=64, num_layers=4, num_heads=4, num_kv_heads=2, head_dim=16, intermediate_size=32, max_position_embeddings=128,
+                         num_experts=8, num_experts_per_tok=3, sliding_window=8, sliding_window_layout=period, rope_layout=period),
+            "21b-a3b": dict(vocab_size=151936, hidden_size=2560, num_layers=52, num_heads=28, num_kv_heads=4, head_dim=128, intermediate_size=768, max_position_embeddings=16384,
+                            num_experts=64, num_experts_per_tok=6, sliding_window=4096, sliding_window_layout=period * 13, rope_layout=period * 13),
+        }[size]
+        return _make_preset(
+            dims,
+            overrides,
+            model_type="smallthinker",
+            position_scheme="rotary",
+            rope_theta=1.5e6,
+            norm="rmsnorm",
+            layer_norm_epsilon=1e-6,
+            activation="relu",
+            attn_bias=False,
+            mlp_bias=False,
+            tie_word_embeddings=False,
+            moe_gated=True,
+            moe_router_input="block_input",
+            moe_capacity_factor=0.0,  # no capacity bound
+            moe_renormalize=True,  # norm_topk_prob: true
+            router_aux_coef=0.0,  # the config publishes no balance loss
+            embed_init_std=1.0,
+            qk_init_std=QK_INIT_STD_SMALLTHINKER,
         )
 
     @staticmethod
@@ -672,8 +794,8 @@ class LoRADense(nn.Module):
         return y
 
 
-def _dense(cfg, features, use_bias, kernel_axes, name=None):
-    kernel_init = param_with_axes(nn.initializers.normal(0.02), kernel_axes)
+def _dense(cfg, features, use_bias, kernel_axes, name=None, std=0.02):
+    kernel_init = param_with_axes(nn.initializers.normal(std), kernel_axes)
     bias_init = param_with_axes(nn.initializers.zeros, (kernel_axes[-1],))
     if getattr(cfg, "lora_r", 0) and name in getattr(cfg, "lora_targets", ()):
         return LoRADense(
@@ -721,11 +843,15 @@ def grouped_einsum_attention(q, k, v, attention_bias, dtype) -> jax.Array:
 @jax.tree_util.register_static
 @dataclasses.dataclass(frozen=True)
 class StaticExtents:
-    """The sampler's static ``kv_extents`` on its way down to ``Attention``:
-    a pytree with no leaves, so ``nn.remat``, ``nn.scan`` and the pipeline's
-    ``jax.checkpoint`` hand the Python ints through instead of tracing them."""
+    """What ``Attention`` is told, statically, of the cache it is handed: the
+    sampler's ``kv_extents`` cut to this layer's cache, and whether that cache
+    is a ring (a window layer's, shorter than the row: slot ``t`` lives at
+    ``t mod slots[-1]``). A pytree with no leaves, so ``nn.remat``,
+    ``nn.scan`` and the pipeline's ``jax.checkpoint`` hand the Python values
+    through instead of tracing them."""
 
     slots: Tuple[int, ...]
+    ring: bool = False
 
 
 def extent_attention(q, k, v, attention_bias, cache_index, kv_extents, dtype) -> jax.Array:
@@ -758,6 +884,7 @@ class Attention(nn.Module):
     KV cache ({"k","v"} arrays [B, S, kvH, D] written at ``cache_index``)."""
 
     config: TransformerConfig
+    rotary: Optional[bool] = None  # the layer's layout says; None = position_scheme does
 
     @nn.compact
     def __call__(
@@ -775,8 +902,8 @@ class Attention(nn.Module):
         H, KV, D = cfg.num_heads, cfg.kv_heads, cfg.dims_per_head
         qkv_bias = cfg.attn_bias if cfg.qkv_bias is None else cfg.qkv_bias
 
-        q = _dense(cfg, H * D, qkv_bias, ("embed", "joined_kv"), "q_proj")(x)
-        k = _dense(cfg, KV * D, qkv_bias, ("embed", "joined_kv"), "k_proj")(x)
+        q = _dense(cfg, H * D, qkv_bias, ("embed", "joined_kv"), "q_proj", cfg.qk_init_std)(x)
+        k = _dense(cfg, KV * D, qkv_bias, ("embed", "joined_kv"), "k_proj", cfg.qk_init_std)(x)
         v = _dense(cfg, KV * D, qkv_bias, ("embed", "joined_kv"), "v_proj")(x).reshape(B, T, KV, D)
         if cfg.key_multiplier != 1.0:
             k = k * cfg.key_multiplier
@@ -786,7 +913,7 @@ class Attention(nn.Module):
             q, k = _qk_norm(cfg, "q_norm")(q), _qk_norm(cfg, "k_norm")(k)
         q, k = q.reshape(B, T, H, D), k.reshape(B, T, KV, D)
 
-        if cfg.position_scheme == "rotary":
+        if (cfg.position_scheme == "rotary") if self.rotary is None else self.rotary:
             rdim = cfg.rotary_dim or D
             sin, cos = rotary_sin_cos(positions, rdim, cfg.rope_theta)
             neox = cfg.norm == "rmsnorm" or not cfg.shared_ln  # llama/neox vs gptj
@@ -876,7 +1003,23 @@ class Attention(nn.Module):
             return out, new_cache
 
         new_cache = None
-        if cache is not None:
+        ring = kv_extents is not None and kv_extents.ring
+        if ring:
+            # a window layer's cache of C < S slots, slot t at t mod C
+            # (CausalTransformer._ring_plan built the bias / flash_args to match)
+            ci = jnp.asarray(cache_index)
+            C = cache["k"].shape[1]
+            if T == 1:
+                write = lambda c, x: jax.lax.dynamic_update_slice(c, x.astype(c.dtype), (0, ci % C, 0, 0))
+            elif T <= C:  # a prefill from slot 0 that does not wrap
+                write = lambda c, x: jax.lax.dynamic_update_slice(c, x.astype(c.dtype), (0, 0, 0, 0))
+            else:  # a prefill from slot 0: its last C positions stay
+                write = lambda c, x: jnp.roll(x[:, T - C :].astype(c.dtype), (T - C) % C, axis=1)
+            new_cache = {"k": write(cache["k"], k), "v": write(cache["v"], v)}
+            if T == 1:
+                k, v = new_cache["k"], new_cache["v"]
+            # a prefill attends over its own k, v: nothing older is in the ring
+        elif cache is not None:
             # decode: write this step's k/v into the cache at cache_index —
             # a scalar (all rows aligned) or a [B] vector (speculative
             # decoding: rows rewind to different accepted lengths)
@@ -913,7 +1056,7 @@ class Attention(nn.Module):
             ).reshape(B, T, H * D)
         elif flash_args is not None:
             out = _flash_attention(q, k, v, flash_args).reshape(B, T, H * D)
-        elif kv_extents is not None and T == 1 and cache is not None and ci.ndim == 0:
+        elif kv_extents is not None and len(kv_extents.slots) > 1 and T == 1 and cache is not None and ci.ndim == 0:
             # the sampler's single-token step, all rows at one slot
             out = extent_attention(q, k, v, attention_bias, ci, kv_extents.slots, cfg.dtype).reshape(B, T, H * D)
         else:
@@ -1093,11 +1236,18 @@ class MoEMLP(nn.Module):
     """Mixture-of-experts MLP: top-k router, then one of two dispatches.
 
     TPU-first design (the reference has no MoE at all — SURVEY.md §2.3 lists
-    EP as n/a; this is a beyond-parity capability for the mixtral and olmoe
-    families). The router runs in fp32; expert weights carry a leading ``E``
-    dim. Returns ``(y, aux)`` where ``aux`` is the layer's additive
-    statistics (``_ZERO_AUX``), summed over layers / microbatches / pipeline
-    stages and normalized by ``router_aux_summary`` / ``router_load_summary``.
+    EP as n/a; this is a beyond-parity capability for the mixtral, olmoe and
+    smallthinker families). The router runs in fp32 over ``num_experts``
+    logits, on ``router_input`` where the ``Block`` hands one in (a family
+    whose router reads the block's input) and on ``x`` otherwise. Experts are
+    gated (``act(gate) * up``) where ``moe_gated`` says so, whatever the
+    activation. Expert weights carry a leading dim of the experts HELD:
+    ``num_experts``, or under dropless routing a contiguous slice
+    ``[moe_first_expert, moe_first_expert + moe_experts_held)`` of them, one
+    chip's share of a deployment (below). Returns ``(y, aux)`` where ``aux``
+    is the layer's additive statistics (``aux_size``), summed over layers /
+    microbatches / pipeline stages and normalized by ``router_aux_summary`` /
+    ``router_load_summary``.
 
     ``moe_capacity_factor > 0`` — GShard-style einsum dispatch:
 
@@ -1124,18 +1274,33 @@ class MoEMLP(nn.Module):
     128-row tile instead).
     Shapes are static (``B·T·k`` rows whatever the routing); nothing couples
     two tokens, so a row's output does not depend on its neighbours.
+
+    **Held experts** (``moe_experts_held`` below ``num_experts``): the router
+    and the top-k run over all ``num_experts``, the gates are renormalised
+    over all ``k`` chosen, and an assignment to an expert that lives on
+    another chip sorts past the last held group exactly as a padding row
+    does, is never computed and adds nothing: the layer returns the part of
+    the result its own experts give, and that partial result goes on to the
+    next layer. Nothing stands in for the absent chips or their traffic.
     """
 
     config: TransformerConfig
 
     @nn.compact
     def __call__(
-        self, x: jax.Array, token_mask: Optional[jax.Array] = None
+        self, x: jax.Array, token_mask: Optional[jax.Array] = None,
+        router_input: Optional[jax.Array] = None,
     ) -> Tuple[jax.Array, jax.Array]:
         cfg = self.config
         E, K = cfg.num_experts, cfg.num_experts_per_tok
+        held = cfg.experts_held
         B, T, d = x.shape
         f = cfg.intermediate_size
+        if held < E and cfg.moe_capacity_factor != 0:
+            raise NotImplementedError(
+                "moe_experts_held below num_experts runs under dropless routing "
+                "(moe_capacity_factor=0) only: the capacity dispatch holds every expert"
+            )
 
         logits = nn.Dense(
             E,
@@ -1144,7 +1309,7 @@ class MoEMLP(nn.Module):
             param_dtype=cfg.param_dtype,
             kernel_init=param_with_axes(nn.initializers.normal(0.02), ("embed", "expert_sel")),
             name="router",
-        )(x.astype(jnp.float32))  # [B, T, E]
+        )((x if router_input is None else router_input).astype(jnp.float32))  # [B, T, E]
         probs = jax.nn.softmax(logits, axis=-1)
         gate_vals, idx = jax.lax.top_k(probs, K)  # [B, T, K]
         if cfg.moe_renormalize:
@@ -1168,10 +1333,10 @@ class MoEMLP(nn.Module):
             ).astype(cfg.dtype)
 
         kernels = {}
-        if cfg.activation == "silu":  # gated (llama-style) experts
-            kernels["w_gate"] = expert_kernel("w_gate", (E, d, f), ("expert", "embed", "ffn"))
-        kernels["w_up"] = expert_kernel("w_up", (E, d, f), ("expert", "embed", "ffn"))
-        kernels["w_down"] = expert_kernel("w_down", (E, f, d), ("expert", "ffn", "embed"))
+        if cfg.moe_gated:
+            kernels["w_gate"] = expert_kernel("w_gate", (held, d, f), ("expert", "embed", "ffn"))
+        kernels["w_up"] = expert_kernel("w_up", (held, d, f), ("expert", "embed", "ffn"))
+        kernels["w_down"] = expert_kernel("w_down", (held, f, d), ("expert", "ffn", "embed"))
 
         dispatch = self._dropless if cfg.moe_capacity_factor == 0 else self._capacity
         y, counts, dropped = dispatch(x, w, gate_vals, idx, kernels)
@@ -1190,9 +1355,13 @@ class MoEMLP(nn.Module):
         lse = jax.scipy.special.logsumexp(logits, axis=-1)  # [B, T]
         z_sum = jnp.sum((lse**2) * w)
         busiest = E * jnp.max(ce)  # the busiest expert's tokens over the mean
-        aux = jnp.stack(
-            [aux_lb * n_real, z_sum, n_real, dropped, n_real * K, busiest * n_real]
-        )
+        stats = [aux_lb * n_real, z_sum, n_real, dropped, n_real * K, busiest * n_real]
+        if held < E:
+            # the real assignments that fell on a held expert, and the
+            # busiest held expert over the mean of the held
+            here = counts[cfg.moe_first_expert : cfg.moe_first_expert + held]
+            stats += [jnp.sum(here), held * jnp.max(here) / jnp.maximum(jnp.sum(here), 1.0) * n_real]
+        aux = jnp.stack(stats)
         return y.astype(cfg.dtype), aux
 
     def _experts(self, kernels, matmul, xin):
@@ -1204,8 +1373,25 @@ class MoEMLP(nn.Module):
         return matmul(h, kernels["w_down"])
 
     def _dropless(self, x, w, gate_vals, idx, kernels):
-        """Every real token through all ``K`` of its experts. Returns
-        ``(y [B, T, d], assignments per expert [E], dropped = 0)``."""
+        """``_dropless_rows`` on all the tokens at once or, past
+        ``MOE_MAX_TOKENS`` of them, on pieces of at most ``MOE_PIECE_TOKENS``
+        one after another: the sorted ``[tokens·K, d]`` row buffers are what a
+        long prefill cannot hold (``moe_token_pieces``)."""
+        B, T, d = x.shape
+        pieces = moe_token_pieces(B * T)
+        if pieces == 1:
+            return self._dropless_rows(x, w, gate_vals, idx, kernels)
+        split = lambda a: a.reshape(pieces, 1, B * T // pieces, *a.shape[2:])
+        y, counts, dropped = jax.lax.map(
+            lambda piece: self._dropless_rows(*piece, kernels),
+            tuple(split(a) for a in (x, w, gate_vals, idx)),
+        )
+        return y.reshape(B, T, d), counts.sum(0), dropped.sum(0)
+
+    def _dropless_rows(self, x, w, gate_vals, idx, kernels):
+        """Every real token through all of its ``K`` experts that are held
+        here. Returns ``(y [B, T, d], assignments asked for per router expert
+        [E], dropped = 0)``."""
         from trlx_tpu.ops.grouped_matmul import grouped_matmul
 
         cfg = self.config
@@ -1223,16 +1409,23 @@ class MoEMLP(nn.Module):
         # last expert, outside every group, and is never computed
         expert = jnp.where(real[:, None], idx.reshape(N, K), E).reshape(N * K)
         counts = jnp.zeros((E + 1,), jnp.int32).at[expert].add(1)[:E]
+        group_sizes = counts
+        held, first = cfg.experts_held, cfg.moe_first_expert
+        if held < E:
+            # an assignment to an expert of another chip joins the padding
+            # past the last held group; the groups are the held experts'
+            expert = jnp.where((expert >= first) & (expert < first + held), expert - first, held)
+            group_sizes = counts[first : first + held]
         order = jnp.argsort(expert)  # stable: sorted row -> assignment
         # a permutation of the K-fold repeated rows: its transpose scatters to
         # unique rows, where x[order // K] would scatter-add with duplicates
         xin = jnp.repeat(x.reshape(N, d), K, axis=0).at[order].get(unique_indices=True)
 
         out = self._experts(  # [N·K, d], sorted by expert
-            kernels, lambda lhs, kernel: grouped_matmul(lhs, kernel, counts), xin
+            kernels, lambda lhs, kernel: grouped_matmul(lhs, kernel, group_sizes), xin
         )
         # rows past the last group (padding) hold whatever the kernel left
-        out = jnp.where((jnp.arange(N * K) < jnp.sum(counts))[:, None], out, 0)
+        out = jnp.where((jnp.arange(N * K) < jnp.sum(group_sizes))[:, None], out, 0)
         unsort = jnp.zeros_like(order).at[order].set(jnp.arange(N * K), unique_indices=True)
         out = out.at[unsort].get(unique_indices=True).reshape(N, K, d)
         gates = gate_vals.reshape(N, K) * real[:, None]
@@ -1300,9 +1493,37 @@ class MoEMLP(nn.Module):
         return y.reshape(B, T, d), counts, dropped
 
 
-# Block aux statistics, all additive: [lb·tokens, Σ tokens·lse², tokens,
-# assignments dropped, assignments asked for, (busiest expert / mean)·tokens]
-_ZERO_AUX = (6,)
+# A dropless layer sorts its (token, expert) assignments into [tokens·K, d] row
+# buffers (the rows in, the rows out, the gathered copy): 15 KB a token a
+# buffer at hidden 2560 and six experts a token, 3 GB a buffer for the 98,304
+# tokens of a 16-row prefill at 6144, which one v5e cannot hold beside the
+# weights (compiled for a described v5e, PR 33: 12.8 GiB for that generate
+# program). Tokens do not interact, so past MOE_MAX_TOKENS the layer runs
+# pieces of at most MOE_PIECE_TOKENS one after another. The largest forward of
+# the cells that came before (64 rows x 640 = 40,960 tokens) is under the
+# first number and keeps its program. Constants with their arithmetic, not
+# settings.
+MOE_MAX_TOKENS = 65536
+MOE_PIECE_TOKENS = 16384
+
+
+def moe_token_pieces(tokens: int) -> int:
+    """How many equal pieces a dropless layer cuts ``tokens`` into: 1 up to
+    ``MOE_MAX_TOKENS``, else the fewest that divide ``tokens`` into pieces of
+    at most ``MOE_PIECE_TOKENS`` (1 again where nothing divides it)."""
+    if tokens <= MOE_MAX_TOKENS:
+        return 1
+    fewest = -(-tokens // MOE_PIECE_TOKENS)
+    return next((n for n in range(fewest, 64 * fewest) if tokens % n == 0), 1)
+
+
+def aux_size(cfg: TransformerConfig) -> int:
+    """Length of a Block's aux statistics, all additive: [lb·tokens, Σ
+    tokens·lse², tokens, assignments dropped, assignments asked for, (busiest
+    expert / mean)·tokens] and, where the layer holds a share of its experts,
+    [assignments that fell on a held expert, (busiest held expert / mean of
+    the held)·tokens]."""
+    return 8 if 0 < cfg.experts_held < cfg.num_experts else 6
 
 
 def router_aux_summary(aux: jax.Array) -> jax.Array:
@@ -1318,10 +1539,15 @@ def router_load_summary(aux: jax.Array) -> jax.Array:
     """Accumulated per-layer aux statistics → ``[dropped_frac,
     load_max_over_mean]``: the share of (token, expert) assignments asked for
     and not computed (0 under dropless routing, always), and the busiest
-    expert's assignments over the mean, token-weighted over the layers."""
-    return jnp.stack(
-        [aux[3] / jnp.maximum(aux[4], 1.0), aux[5] / jnp.maximum(aux[2], 1.0)]
-    )
+    expert's assignments over the mean, token-weighted over the layers.
+    Where the layers hold a share of their experts, also ``[held_frac,
+    held_load_max_over_mean]``: the share of the assignments asked for that
+    fell on a held expert (every one of them computed), and the busiest held
+    expert over the mean of the held."""
+    load = [aux[3] / jnp.maximum(aux[4], 1.0), aux[5] / jnp.maximum(aux[2], 1.0)]
+    if aux.shape[0] > 6:  # layers that hold a share: [held_frac, held_load_max_over_mean]
+        load += [aux[6] / jnp.maximum(aux[4], 1.0), aux[7] / jnp.maximum(aux[2], 1.0)]
+    return jnp.stack(load)
 
 
 def _cache_is_paged(cache) -> bool:
@@ -1361,29 +1587,33 @@ def _token_validity(slot_mask: jax.Array, q_offset, T: int) -> jax.Array:
 
 class Block(nn.Module):
     config: TransformerConfig
+    layer: int = 0  # which entry of the config's per-layer layout this block reads
 
     @nn.compact
     def __call__(self, x, attention_bias, positions, cache=None, cache_index=None, flash_args=None, token_mask=None, kv_extents=None):
         cfg = self.config
+        rotary = cfg.layer_layout(self.layer).rotary
+        # a router that reads the block's raw input, before the input norm
+        router_input = x if cfg.num_experts > 0 and cfg.moe_router_input == "block_input" else None
 
         def run_mlp(h):
             if cfg.num_experts > 0:
-                return MoEMLP(cfg, name="mlp")(h, token_mask)
-            return MLP(cfg, name="mlp")(h), jnp.zeros(_ZERO_AUX, jnp.float32)
+                return MoEMLP(cfg, name="mlp")(h, token_mask, router_input)
+            return MLP(cfg, name="mlp")(h), jnp.zeros((aux_size(cfg),), jnp.float32)
 
         h = Norm(cfg, name="ln_attn")(x)
         if cfg.mixer == "mamba2":
             # both mixers read the same normed input; their outputs are
             # summed before the one residual add, then the MLP as usual
             attn_in = h * cfg.attention_in_multiplier if cfg.attention_in_multiplier != 1.0 else h
-            attn_out, new_cache = Attention(cfg, name="attn")(attn_in, attention_bias, positions, cache, cache_index, flash_args, kv_extents)
+            attn_out, new_cache = Attention(cfg, rotary, name="attn")(attn_in, attention_bias, positions, cache, cache_index, flash_args, kv_extents)
             mix_out, new_state = Mamba2Mixer(cfg, name="mixer")(h, cache, token_mask)
             if cache is not None:
                 new_cache = {**new_cache, **new_state}
             x = x + mix_out * cfg.ssm_out_multiplier + attn_out * cfg.attention_out_multiplier
             mlp_out, aux = run_mlp(Norm(cfg, name="ln_mlp")(x))
             return x + mlp_out, new_cache, aux
-        attn_out, new_cache = Attention(cfg, name="attn")(h, attention_bias, positions, cache, cache_index, flash_args, kv_extents)
+        attn_out, new_cache = Attention(cfg, rotary, name="attn")(h, attention_bias, positions, cache, cache_index, flash_args, kv_extents)
         if cfg.parallel_residual:
             mlp_in = h if cfg.shared_ln else Norm(cfg, name="ln_mlp")(x)
             mlp_out, aux = run_mlp(mlp_in)
@@ -1472,6 +1702,13 @@ class CausalTransformer(nn.Module):
             )
         if cfg.embedding_layernorm:
             self.emb_ln = Norm(cfg, name="emb_ln")
+        if cfg.scan_layers and cfg.mixed_layout:
+            raise NotImplementedError(
+                "scan_layers (and the pipeline schedule, which needs it) runs ONE Block "
+                f"body over stacked parameters; model_type {cfg.model_type!r} has layers of "
+                f"more than one attention layout ({sorted(set(cfg.layer_layouts), key=str)}): run it "
+                "with scan_layers=False (ROADMAP.md queue 2, B3: a scan over whole periods)"
+            )
         if cfg.scan_layers:
             # roll all blocks into one lax.scan over stacked params — one
             # traced/compiled block instead of L, O(1) compile time and
@@ -1490,7 +1727,7 @@ class CausalTransformer(nn.Module):
             self.blocks = []
         else:
             block = _block_cls(cfg)
-            self.blocks = [block(cfg, name=f"h_{i}") for i in range(cfg.num_layers)]
+            self.blocks = [block(cfg, i, name=f"h_{i}") for i in range(cfg.num_layers)]
         if cfg.final_norm:
             self.ln_f = Norm(cfg, name="ln_f")
         if not cfg.tie_word_embeddings:
@@ -1512,7 +1749,7 @@ class CausalTransformer(nn.Module):
             x = self.emb_ln(x)
         return x
 
-    def _attention_bias(self, key_mask, query_slots, query_positions):
+    def _attention_bias(self, key_mask, query_slots, query_positions, window):
         """Additive [B, 1, T, S] bias over key *slots*: slot-causal + padding
         (+ ALiBi on true token positions).
 
@@ -1527,10 +1764,10 @@ class CausalTransformer(nn.Module):
         S = key_mask.shape[1]
         key_slots = jnp.arange(S)[None, None, :]  # [1, 1, S]
         visible = (key_slots <= query_slots[:, :, None]) & (key_mask[:, None, :] > 0)
-        if cfg.sliding_window:
+        if window:
             # slot distance ≡ position distance (padding is left-only)
             visible = visible & (
-                query_slots[:, :, None] - key_slots < cfg.sliding_window
+                query_slots[:, :, None] - key_slots < window
             )
         bias = jnp.where(visible[:, None, :, :], 0.0, -1e9)
         if cfg.position_scheme == "alibi":
@@ -1542,26 +1779,86 @@ class CausalTransformer(nn.Module):
         return bias
 
     def _attn_inputs(
-        self, key_mask, positions, q_offset, use_flash
+        self, key_mask, positions, q_offset, use_flash, window
     ) -> Tuple[Optional[jax.Array], Optional[Dict[str, Any]]]:
-        """``(bias, flash_args)`` for one forward — the single definition of
-        the masking semantics, shared by the unpipelined path, the hydra
+        """``(bias, flash_args)`` for one forward of the layers whose layout
+        has this ``window`` (``LayerLayout.window``) — the single definition
+        of the masking semantics, shared by the unpipelined path, the hydra
         branch replay, and each pipeline stage. Queries occupy slots
         ``[q_offset, q_offset + T)`` (0 for full passes)."""
         if use_flash:
-            return None, self._flash_args(key_mask, positions, q_offset=q_offset)
+            return None, self._flash_args(key_mask, positions, window, q_offset=q_offset)
         B, T = positions.shape
         query_slots = _query_slots(q_offset, B, T)
-        return self._attention_bias(key_mask, query_slots, positions), None
+        return self._attention_bias(key_mask, query_slots, positions, window), None
 
-    def _flash_args(self, key_mask, query_positions, q_offset=0) -> Dict[str, Any]:
+    def _layer_plans(self, layers, cache, key_mask, positions, cache_index, use_flash, extents):
+        """``(bias, flash_args, cache view)`` for each of ``layers``, built
+        once per kind of layer: layers of one window and one cache length
+        share theirs, so a uniform stack builds ONE, as it always has.
+
+        A layer whose cache is shorter than the row's ``S`` slots is a window
+        layer's ring (``make_kv_cache``). A single-token step reads the ring
+        under a bias over its ``C`` slots: ring position ``j`` holds the
+        newest slot ``<= cache_index`` congruent to ``j``, valid where that
+        slot is (``slot distance = position distance``, so every slot in the
+        ring is inside the window and a padded one stays masked). A span from
+        slot 0 (the sampler's prefill) attends over its own keys and leaves
+        its last ``C`` positions in the ring."""
+        cfg = self.config
+        S = key_mask.shape[1]
+        dense = cache is not None and not _cache_is_paged(cache)
+        q_offset = cache_index if cache is not None and cache_index is not None else 0
+        plans: Dict[Any, Any] = {}
+        out = []
+        for i in layers:
+            window = cfg.layer_layout(i).window
+            slots = S
+            if dense:
+                slots = (cache["k"].shape[2] if isinstance(cache, dict) else cache[i]["k"].shape[1])
+            if (window, slots) not in plans:
+                if slots == S:
+                    plans[window, slots] = self._attn_inputs(key_mask, positions, q_offset, use_flash, window) + (extents,)
+                else:
+                    plans[window, slots] = self._ring_plan(key_mask, positions, cache_index, use_flash, window, slots, extents)
+            out.append(plans[window, slots])
+        return out
+
+    def _ring_plan(self, key_mask, positions, cache_index, use_flash, window, slots, extents):
+        cfg = self.config
+        B, T = positions.shape
+        ci = jnp.asarray(cache_index)
+        if ci.ndim or cfg.position_scheme == "alibi":
+            raise NotImplementedError(
+                "a window layer's ring cache (fewer slots than the row: make_kv_cache) "
+                "is written by the plain sampler only: one scalar cache_index for all "
+                "rows, no ALiBi (ROADMAP.md queue 2, B3)"
+            )
+        if T > 1:
+            # (a traced index cannot be looked at: the caller's word for it)
+            if not isinstance(ci, jax.core.Tracer) and int(ci) != 0:
+                raise NotImplementedError(
+                    "a span of tokens into a ring cache must start at slot 0 (the "
+                    "sampler's prefill): chunked prefill over a ring is not built"
+                )
+            view = StaticExtents((slots,), ring=True)
+            return self._attn_inputs(key_mask[:, :T], positions, 0, use_flash, window) + (view,)
+        j = jnp.arange(slots)
+        slot = ci - jnp.mod(ci - j, slots)  # the newest slot <= ci at ring position j
+        ring_mask = jnp.where(slot >= 0, jnp.take(key_mask, jnp.maximum(slot, 0), axis=1), 0)
+        bias = jnp.where(ring_mask > 0, 0.0, -1e9)[:, None, None, :]
+        from trlx_tpu.ops.sampling import layer_extents
+
+        return bias, None, StaticExtents(layer_extents(extents.slots if extents else (), slots), ring=True)
+
+    def _flash_args(self, key_mask, query_positions, window, q_offset=0) -> Dict[str, Any]:
         """Inputs for the pallas flash-attention path: same masking semantics
         as ``_attention_bias`` but resolved inside the kernel (no [B,1,T,S]
         bias tensor is ever materialised)."""
         cfg = self.config
         args: Dict[str, Any] = {"key_mask": key_mask, "q_offset": q_offset}
-        if cfg.sliding_window:
-            args["window"] = cfg.sliding_window
+        if window:
+            args["window"] = window
         if cfg.position_scheme == "alibi":
             args["alibi_slopes"] = jnp.asarray(alibi_slopes(cfg.num_heads), jnp.float32)
             args["q_positions"] = query_positions
@@ -1634,16 +1931,15 @@ class CausalTransformer(nn.Module):
                 cache, cache_index, branch_layer, extents,
             )
             return self._epilogue(x, branch_input, new_cache, logits_span, aux)
-        bias, flash_args = self._attn_inputs(
-            attention_mask,
-            positions,
-            cache_index if cache is not None and cache_index is not None else 0,
-            use_flash,
+        plans = self._layer_plans(
+            range(1 if cfg.scan_layers else cfg.num_layers),
+            cache, attention_mask, positions, cache_index, use_flash, extents,
         )
 
         branch_input = None
-        aux = jnp.zeros(_ZERO_AUX, jnp.float32)
+        aux = jnp.zeros((aux_size(cfg),), jnp.float32)
         if cfg.scan_layers:
+            bias, flash_args, extents = plans[0]
             branch_at = cfg.num_layers - branch_layer if branch_layer is not None else -1
             branch_buf0 = jnp.zeros_like(x) if branch_layer is not None else None
             (x, branch_buf, aux), new_cache = self.scan_blocks(
@@ -1666,7 +1962,8 @@ class CausalTransformer(nn.Module):
                 if branch_layer is not None and i == len(self.blocks) - branch_layer:
                     branch_input = x
                 layer_cache = cache[i] if cache is not None else None
-                x, updated, aux_i = block(x, bias, positions, layer_cache, cache_index, flash_args, token_mask, extents)
+                bias, flash_args, view = plans[i]
+                x, updated, aux_i = block(x, bias, positions, layer_cache, cache_index, flash_args, token_mask, view)
                 aux = aux + aux_i
                 if cache is not None:
                     new_cache.append(updated)
@@ -1710,6 +2007,7 @@ class CausalTransformer(nn.Module):
         branch_at = cfg.num_layers - branch_layer if branch_layer is not None else -1
         body_block = Block(cfg, parent=None)
         in_decode = cache is not None and cache_index is not None
+        window = cfg.layer_layout(0).window  # one layout: scan_layers refuses a mixed one
 
         def make_attn_inputs(mask_mb, pos_mb, ci_mb):
             # ci_mb: this stage's microbatch slice of a [B]-vector
@@ -1722,7 +2020,7 @@ class CausalTransformer(nn.Module):
                     if in_decode
                     else mask_mb
                 )
-            return self._attn_inputs(mask_mb, pos_mb, q_offset, use_flash) + (pos_mb, tm)
+            return self._attn_inputs(mask_mb, pos_mb, q_offset, use_flash, window) + (pos_mb, tm)
 
         def apply_block(layer_params, h, attn_inputs, cache_layer, cidx):
             bias_mb, flash_mb, pos_mb, tm = attn_inputs
@@ -1746,7 +2044,7 @@ class CausalTransformer(nn.Module):
             cache_index=cache_index,
             branch_at=branch_at,
             mesh=mesh,
-            aux_init=jnp.zeros(_ZERO_AUX, jnp.float32),
+            aux_init=jnp.zeros((aux_size(cfg),), jnp.float32),
         )
 
     def forward_branch(
@@ -1769,14 +2067,14 @@ class CausalTransformer(nn.Module):
             attention_mask = jnp.ones((B, T), jnp.int32)
         if positions is None:
             positions = jnp.maximum(jnp.cumsum(attention_mask, axis=1) - 1, 0)
-        bias, flash_args = self._attn_inputs(
-            attention_mask,
-            positions,
-            0,
-            cfg.resolved_attention_impl() == "pallas" and T > 1,
+        top = range(cfg.num_layers - branch_layer, cfg.num_layers)
+        plans = self._layer_plans(
+            top[:1] if cfg.scan_layers else top, None, attention_mask, positions, None,
+            cfg.resolved_attention_impl() == "pallas" and T > 1, None,
         )
         x = hidden_states
         if cfg.scan_layers:
+            bias, flash_args, _ = plans[0]
             # scan over the top `branch_layer` rows of the stacked params —
             # the bound tree holds either a pre-sliced branch snapshot
             # (builder.hydra_ref_params) or the full stack
@@ -1798,7 +2096,7 @@ class CausalTransformer(nn.Module):
                 body = jax.checkpoint(body, policy=_remat_policy(cfg))
             x, _ = jax.lax.scan(body, x, sliced)
         else:
-            for block in self.blocks[len(self.blocks) - branch_layer :]:
+            for block, (bias, flash_args, _) in zip(self.blocks[len(self.blocks) - branch_layer :], plans):
                 x, _, _ = block(x, bias, positions, flash_args=flash_args, token_mask=attention_mask)
         h = self.ln_f(x) if cfg.final_norm else x
         logits = self._logits(h if logits_span is None else h[:, logits_span[0] : logits_span[1]])
@@ -1824,24 +2122,31 @@ def make_kv_cache(
 
     Layout follows the block layout: a per-layer list of ``{"k", "v"}`` dicts,
     or one stacked dict with a leading layer dim when ``cfg.scan_layers``.
-    A ``mixer: mamba2`` layer also holds ``ssm`` (the recurrent state,
-    float32 whatever ``dtype``: hundreds of steps of ``S = aS + ...`` drift
-    in bf16) and ``conv`` (the conv's last ``K - 1`` input rows).
+    A layer's ``k`` and ``v`` are as long as its layout needs
+    (``cfg.layer_layout``): ``max_length`` slots for a full-causal layer,
+    ``min(max_length, window)`` for a window layer, which the plain sampler
+    then writes as a ring (slot ``t`` at ``t mod window``: ``CausalTransformer.
+    _ring_plan``). A ``mixer: mamba2`` layer also holds ``ssm`` (the recurrent
+    state, float32 whatever ``dtype``: hundreds of steps of ``S = aS + ...``
+    drift in bf16) and ``conv`` (the conv's last ``K - 1`` input rows).
     """
     dtype = dtype or cfg.dtype
-    shapes = {
-        "k": ((batch_size, max_length, cfg.kv_heads, cfg.dims_per_head), dtype),
-        "v": ((batch_size, max_length, cfg.kv_heads, cfg.dims_per_head), dtype),
-    }
-    if cfg.mixer == "mamba2":
-        shapes["ssm"] = ((batch_size, cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_state), jnp.float32)
-        shapes["conv"] = ((batch_size, cfg.mamba_conv - 1, cfg.mamba_conv_channels), dtype)
     stacked = (cfg.num_layers,) if cfg.scan_layers else ()
 
-    def layer():
+    def layer(layout: LayerLayout):
+        slots = min(max_length, layout.window) if layout.window else max_length
+        shapes = {
+            "k": ((batch_size, slots, cfg.kv_heads, cfg.dims_per_head), dtype),
+            "v": ((batch_size, slots, cfg.kv_heads, cfg.dims_per_head), dtype),
+        }
+        if cfg.mixer == "mamba2":
+            shapes["ssm"] = ((batch_size, cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_state), jnp.float32)
+            shapes["conv"] = ((batch_size, cfg.mamba_conv - 1, cfg.mamba_conv_channels), dtype)
         return {name: jnp.zeros(stacked + shape, dt) for name, (shape, dt) in shapes.items()}
 
-    return layer() if cfg.scan_layers else [layer() for _ in range(cfg.num_layers)]
+    if cfg.scan_layers:  # one layout for the stack: CausalTransformer refuses a mixed one
+        return layer(cfg.layer_layout(0))
+    return [layer(layout) for layout in cfg.layer_layouts]
 
 
 def stack_layer_params(backbone: Dict[str, Any], num_layers: int, prefix: str = "h_") -> Dict[str, Any]:
@@ -1877,6 +2182,7 @@ BUILTIN_SPECS = {
     "mistral": TransformerConfig.mistral,
     "mixtral": TransformerConfig.mixtral,
     "olmoe": TransformerConfig.olmoe,
+    "smallthinker": TransformerConfig.smallthinker,
     "falconh1": TransformerConfig.falconh1,
     "gptj": TransformerConfig.gptj,
     "gptneox": TransformerConfig.gptneox,

@@ -413,11 +413,35 @@ def _bwd_fused_call(
         ],
         interpret=interpret,
         name=BWD_KERNEL_NAME,
+        **_bwd_vmem_params(T, D, q.dtype.itemsize, interpret),
     )(qoff, koff, q, k, v, kmask, qpos, kpos, slopes, lse, delta, do)
     if group > 1:
         dk = dk.reshape(B, KV, group, S, D).sum(axis=2)
         dv = dv.reshape(B, KV, group, S, D).sum(axis=2)
     return dq.astype(q.dtype), dk, dv
+
+
+# Mosaic's default scoped VMEM, and what the backward kernel leaves of it for
+# its k, v blocks and its (block_q, block_k) intermediates
+_SCOPED_VMEM_BYTES = 16 * 2**20
+_BWD_WORKING_BYTES = 4 * 2**20
+
+
+def _bwd_vmem_params(T: int, D: int, itemsize: int, interpret: bool) -> dict:
+    """``pallas_call`` keywords for the fused backward: nothing (the program
+    every sequence up to a few thousand slots has always had) unless the
+    whole-sequence operands it keeps in VMEM across the k-block steps, each
+    double-buffered, outgrow the default scope: q and do (``T x D``), dq
+    (float32) and lse and delta (float32, ``LANES`` padded to a 128-lane
+    tile). At 8192 slots and head size 128 that is 32 MiB, and the kernel
+    asks for that and its working set of a v5e's 128 MiB."""
+    resident = 2 * T * (2 * D * itemsize + D * 4 + 2 * 128 * 4)
+    if interpret or resident + _BWD_WORKING_BYTES <= _SCOPED_VMEM_BYTES:
+        return {}
+    from jax.experimental.pallas import tpu as pltpu
+
+    limit = resident + 2 * _BWD_WORKING_BYTES
+    return {"compiler_params": pltpu.CompilerParams(vmem_limit_bytes=limit)}
 
 
 def _flash_bwd_rule(
@@ -520,6 +544,24 @@ def flash_attention_bwd_chunk(
         dk[:, :, :S, :].transpose(0, 2, 1, 3),
         dv[:, :, :S, :].transpose(0, 2, 1, 3),
     )
+
+
+def block_pairs_visited(
+    width: int, window: Optional[int] = None, block_q: int = 128, block_k: int = 128
+) -> Tuple[int, int]:
+    """``(visited, causal)``: the (query block, key block) pairs the forward
+    kernel visits in a full pass over ``width`` slots under ``window``, and
+    the pairs plain causal attention visits. Host arithmetic that mirrors
+    ``_fwd_kernel``'s ``lo`` and ``hi`` bounds at offset 0, for
+    ``learn/attn_visited_frac``."""
+    n_q, n_k = -(-width // block_q), -(-width // block_k)
+    visited = causal = 0
+    for iq in range(n_q):
+        hi = min(((iq + 1) * block_q + block_k - 1) // block_k, n_k)
+        lo = min(max((iq * block_q - (window - 1)) // block_k, 0), hi) if window else 0
+        visited += hi - lo
+        causal += hi
+    return visited, causal
 
 
 def flash_attention(

@@ -65,6 +65,7 @@ __all__ = [
     "dense_kv_bytes",
     "recurrent_state_bytes",
     "refuse_recurrent_state",
+    "refuse_ring_cache",
 ]
 
 # Physical block 0 is reserved as the permanent all-zeros block: fresh table
@@ -304,6 +305,35 @@ def refuse_recurrent_state(cache: Any, path: str) -> None:
             f"(leaves {RECURRENT_LEAVES}: `mixer: mamba2`, the falcon_h1 family): "
             f"{_KV_ONLY_PATHS[path]}; use the plain sampler (ROADMAP.md queue 2, B7)"
         )
+
+
+# rollout paths that write a layer's cache at any slot of the row, a row at a
+# time (ROADMAP.md queue 2, B3)
+_WHOLE_ROW_PATHS = {
+    "slot_refill": "ops/slot_refill.py refills one slot's row at its own depth, a [B] vector of cache indices",
+    "engine": "the engine/ block tables map every slot of a row to a block and the allocator frees none before the row ends",
+    "prefix_cache": "the engine's prefix cache shares a prompt's blocks from slot 0, which a ring has overwritten",
+    "speculative": "ops/speculative.py verifies and rewinds rows to their own accepted lengths, a [B] vector of cache indices",
+}
+
+
+def refuse_ring_cache(cache: Any, slots: int, path: str) -> None:
+    """Called where each of those paths builds its state, on the cache pytree
+    (arrays or shapes) its ``init_cache_fn`` gives for a row of ``slots``: a
+    window layer keeps ``min(slots, window)`` slots
+    (``models/transformer.py::make_kv_cache``), and where that is fewer than
+    the row's, the cache is a ring only the plain sampler writes. A model of
+    mixed layouts runs through these paths while no layer's cache is shorter
+    than the row (each layer's bias carries its own window); past that they
+    stop here by name rather than write a ring as if it were the row."""
+    for path_, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
+        if getattr(path_[-1], "key", None) == "k" and leaf.shape[-3] < slots:
+            raise NotImplementedError(
+                f"{path} does not support a layer whose cache is shorter than the row "
+                f"(a window layer's ring of {leaf.shape[-3]} slots for a row of {slots}: "
+                f"sliding_window below the row's length): {_WHOLE_ROW_PATHS[path]}; use the "
+                "plain sampler, or rows no longer than the window (ROADMAP.md queue 2, B3)"
+            )
 
 
 def block_bytes(cache: Any) -> int:

@@ -225,11 +225,21 @@ def kv_extents(prompt_width: int, max_new_tokens: int) -> Tuple[int, ...]:
     return extents
 
 
+def layer_extents(extents: Tuple[int, ...], cache_slots: int) -> Tuple[int, ...]:
+    """A layer's own extents: the row's, cut to the slots its cache has. A
+    window layer's ring of ``C`` slots (``models/transformer.py::
+    make_kv_cache``) is read through ``extents`` while the row is shorter
+    than ``C`` and whole, ``C`` slots, from then on."""
+    return (*(e for e in extents if e < cache_slots), cache_slots)
+
+
 def kv_slots_read(extents: Tuple[int, ...], prompt_width: int, steps: int) -> int:
-    """Cache slots a row's attention reads over the first ``steps`` decode
-    steps under ``extents`` (step ``i`` writes slot ``P + i``): host
-    arithmetic for ``rollout/kv_read_frac``, against ``steps * extents[-1]``."""
-    return sum(extents[bisect_left(extents, prompt_width + i + 1)] for i in range(steps))
+    """Cache slots a row's attention reads in one layer over the first
+    ``steps`` decode steps under ``extents`` (step ``i`` writes slot ``P +
+    i``; a step past the last extent, in a ring, reads the last): host
+    arithmetic for ``rollout/kv_read_frac``, against ``steps * S``."""
+    last = len(extents) - 1
+    return sum(extents[min(bisect_left(extents, prompt_width + i + 1), last)] for i in range(steps))
 
 
 class GenerationOutput(NamedTuple):

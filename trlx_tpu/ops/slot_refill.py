@@ -71,6 +71,7 @@ from trlx_tpu.ops.paged_kv import (
     gather_view,
     init_paged_kv,
     refuse_recurrent_state,
+    refuse_ring_cache,
     scatter_span,
     scatter_steps,
 )
@@ -325,6 +326,10 @@ def make_slot_refill_fns(
     # key-width lowering note) — G = 0 reduces to the plain S = P + N
     S = P + N + G
     NB = N + G + 1  # spec token buffers: block writes never clip
+    refuse_ring_cache(
+        jax.eval_shape(lambda: init_cache_fn(1, S)), S,
+        "slot_refill" if paged is None else "engine",
+    )
 
     def empty_state() -> SlotState:
         # step_out structure comes from an abstract prefill — shapes only

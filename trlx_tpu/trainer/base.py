@@ -325,6 +325,9 @@ class TPUBaseTrainer(BaseRLTrainer):
         # generate() call (ops/sampling.py::kv_extents); None on the paths
         # that read every slot (seq2seq, speculative)
         self.last_kv_extents: Optional[Tuple[int, ...]] = None
+        # beside them, each layer's cache slots and whether it has a window
+        # (a window layer's cache is a ring of min(S, window) slots)
+        self.last_kv_layers: Optional[Tuple[Tuple[int, bool], ...]] = None
         self.last_generate_time = 0.0
         # where the host gap before the next train step began (perf_counter):
         # the end of the last step's fence, or of the collection before it
@@ -495,6 +498,9 @@ class TPUBaseTrainer(BaseRLTrainer):
         if load is not None:
             stats["moe/dropped_frac"] = load[0]
             stats["moe/load_max_over_mean"] = load[1]
+            if load.shape[0] > 2:  # layers that hold a share of their experts
+                stats["moe/held_frac"] = load[2]
+                stats["moe/held_load_max_over_mean"] = load[3]
         # keep the logged total in sync with what is actually optimized.
         # Contract: every method.loss must report its headline total under
         # one of these canonical keys (PPO/ILQL/GRPO/DPO flatten to
@@ -721,6 +727,19 @@ class TPUBaseTrainer(BaseRLTrainer):
         for chunk in iterator:
             self._prompt_chunks_drawn += 1
             yield chunk
+
+    def _attn_visited_frac(self, width: int) -> float:
+        """(query block, key block) pairs the flash forward visits in a step
+        of this width over the pairs causal attention has, summed over the
+        layers: static arithmetic from each layer's layout
+        (``TransformerConfig.layer_layouts``); 1 where no window binds."""
+        from trlx_tpu.ops.flash_attention import block_pairs_visited
+
+        layouts = getattr(self.tcfg, "layer_layouts", None)
+        if not layouts or not width:
+            return 1.0
+        pairs = [block_pairs_visited(width, layout.window) for layout in layouts]
+        return sum(v for v, _ in pairs) / max(sum(c for _, c in pairs), 1)
 
     def _batch_token_counts(self, batch: Any) -> Tuple[int, int, int]:
         """``(real, fed, width)`` of a host batch: the unpadded tokens its
@@ -1321,9 +1340,12 @@ class TPUBaseTrainer(BaseRLTrainer):
         per-sequence state side by side by leaf name:
         ``rollout/kv_cache_bytes`` (``k``, ``v``) and
         ``rollout/ssm_state_bytes`` (``ssm``, ``conv``; 0 for a KV-only
-        model). The continuous-batching engines report their own measured
-        gauge (EngineStats.metrics)."""
-        self.last_kv_extents = None
+        model); where the stack mixes attention layouts, K and V are also
+        split into ``rollout/kv_cache_window_bytes`` (the window layers'
+        rings) and ``rollout/kv_cache_global_bytes``. The
+        continuous-batching engines report their own measured gauge
+        (EngineStats.metrics)."""
+        self.last_kv_extents = self.last_kv_layers = None
         if self.is_seq2seq:
             return  # T5 cross/self caches have their own layout; not gauged
         from trlx_tpu.ops.paged_kv import kv_bytes, recurrent_state_bytes
@@ -1343,6 +1365,19 @@ class TPUBaseTrainer(BaseRLTrainer):
             "rollout/kv_cache_bytes": float(total),
             "rollout/ssm_state_bytes": float(state),
         }
+        if not self.tcfg.scan_layers:
+            layouts = self.tcfg.layer_layouts
+            self.last_kv_layers = tuple(
+                (int(layer["k"].shape[1]), layout.window is not None)
+                for layer, layout in zip(policy_cache, layouts)
+            )
+            if self.tcfg.mixed_layout:
+                window = sum(
+                    kv_bytes({"k": layer["k"], "v": layer["v"]})
+                    for layer, layout in zip(policy_cache, layouts) if layout.window is not None
+                )
+                self.last_cache_stats["rollout/kv_cache_window_bytes"] = float(window)
+                self.last_cache_stats["rollout/kv_cache_global_bytes"] = float(total - window)
         if self.draft_module is not None:
             # speculative decoding: target + draft caches, both S + gamma
             # slots (ops/speculative.py sizes them P + N + G)
@@ -1964,6 +1999,7 @@ class TPUBaseTrainer(BaseRLTrainer):
                         1.0 - real_tokens / fed_tokens if fed_tokens else 0.0
                     )
                     stats["learn/step_width"] = float(width)
+                    stats["learn/attn_visited_frac"] = self._attn_visited_frac(width)
                     batch_size = next(
                         v.shape[0] for v in batch.values() if hasattr(v, "shape")
                     ) if isinstance(batch, dict) else self.config.train.batch_size

@@ -41,7 +41,7 @@ from trlx_tpu.observability.dynamics import (
     sketch_np,
 )
 from trlx_tpu.models.transformer import CausalTransformer
-from trlx_tpu.ops.sampling import GenerationOutput, kv_slots_read
+from trlx_tpu.ops.sampling import GenerationOutput, kv_slots_read, layer_extents
 from trlx_tpu.parallel import shard_batch
 from trlx_tpu.pipeline import BasePipeline
 from trlx_tpu.pipeline.ppo_pipeline import PPORolloutStorage, length_ladder
@@ -61,6 +61,33 @@ def _add_times(stats: Dict[str, float], chunk_stats: Dict[str, float]) -> None:
         if key.startswith("time/"):
             value = stats.get(key, 0.0) + value
         stats[key] = value
+
+
+# The scoring forward holds float32 logits over the whole response span of
+# every row of its chunk, for the policy and for the reference: 16 rows x 2048
+# positions x 37,984 ids are 5 GB each, and the rows' 131,072 tokens another
+# 4 GB a buffer in a dropless expert layer; neither fits one v5e beside the
+# weights (compiled for a described v5e, PR 33: the program was refused). Rows
+# do not interact, so a chunk of more than SCORE_MAX_TOKENS slots is scored in
+# groups of rows of at most SCORE_GROUP_TOKENS slots, one after another inside
+# the one program. The largest chunk of the cells that came before (64 rows x
+# 640 = 40,960 slots) is under the first number and keeps its program.
+# Constants with their arithmetic, not settings.
+SCORE_MAX_TOKENS = 65536
+SCORE_GROUP_TOKENS = 16384
+
+
+def score_row_groups(rows: int, width: int) -> int:
+    """How many equal groups of rows a scoring forward of ``rows x width``
+    slots runs in: 1 up to ``SCORE_MAX_TOKENS`` slots, else the fewest that
+    divide ``rows`` into groups of at most ``SCORE_GROUP_TOKENS`` slots (a
+    row wider than that is a group of its own)."""
+    if rows * width <= SCORE_MAX_TOKENS:
+        return 1
+    for groups in range(1, rows + 1):
+        if rows % groups == 0 and (rows // groups) * width <= max(SCORE_GROUP_TOKENS, width):
+            return groups
+    return rows
 
 
 @register_trainer
@@ -339,8 +366,8 @@ class PPOTrainer(TPUBaseTrainer):
         has_value = self.model_head == "value"
         wrap_ref = (lambda p: {"backbone": p}) if self.model_head else (lambda p: p)
 
-        def score_fn(params, ref_params, sequences, prompt_mask, response_tokens,
-                     response_mask):
+        def score_rows(params, ref_params, sequences, prompt_mask, response_tokens,
+                       response_mask):
             full_mask = jnp.concatenate([prompt_mask, response_mask], axis=1)
             # logits at t predict token t+1: response token i lives at column
             # P+i, so its logprob/value come from position P-1+i; the vocab
@@ -375,6 +402,16 @@ class PPOTrainer(TPUBaseTrainer):
             if has_value:
                 result["values"] = out["value"][:, P - 1 : P + N - 1]
             return result
+
+        groups = score_row_groups(B, P + N)
+
+        def score_fn(params, ref_params, *rows):
+            if groups == 1:
+                return score_rows(params, ref_params, *rows)
+            # a chunk too long to score at once: groups of its rows, one after another
+            split = lambda a: a.reshape(groups, B // groups, *a.shape[1:])
+            out = jax.lax.map(lambda g: score_rows(params, ref_params, *g), tuple(map(split, rows)))
+            return jax.tree_util.tree_map(lambda a: a.reshape(B, *a.shape[2:]), out)
 
         fn = jax.jit(score_fn)
         self._score_fns[batch_shape] = fn
@@ -511,6 +548,7 @@ class PPOTrainer(TPUBaseTrainer):
             "gen_out": gen_out,
             "score_out": score_out,
             "kv_extents": self.last_kv_extents,
+            "kv_layers": self.last_kv_layers,
         }
 
     def _rollout_chunk_host(self, dev: Dict[str, Any]) -> Dict[str, Any]:
@@ -559,6 +597,7 @@ class PPOTrainer(TPUBaseTrainer):
             "scores": scores,
             "host": host,
             "kv_extents": dev.get("kv_extents"),
+            "kv_layers": dev.get("kv_layers"),
             "stats": stats,
             "host_s": perf_counter() - host_t0,
             # what this stage spent inside reward_fn and waiting for the
@@ -649,13 +688,21 @@ class PPOTrainer(TPUBaseTrainer):
             acc["decode_steps"] += decode_steps
             acc["slot_steps"] += int(response_mask.shape[0]) * decode_steps
             acc["live_slot_steps"] += int(n_per_row.sum())
-            # cache slots a row's attention read over those steps: the dense
-            # sampler's steps stop at a static extent of the cache
-            # (ops/sampling.py::kv_extents); every other sampler reads it whole
+            # cache slots a row's attention read over those steps, a layer:
+            # the dense sampler's steps stop at a static extent of the cache
+            # (ops/sampling.py::kv_extents), and a window layer's cache is a
+            # ring of at most its window; every other sampler reads it whole.
+            # Both over steps x S x layers, so a uniform stack reads what one
+            # of its layers does
             P, N = chunk["prompt_ids"].shape[1], response_mask.shape[1]
             extents = chunk.get("kv_extents") or (P + N,)
-            acc["kv_slots_read"] += kv_slots_read(extents, P, decode_steps)
-            acc["kv_slots"] += decode_steps * (P + N)
+            for slots, windowed in chunk.get("kv_layers") or ((P + N, False),):
+                read = kv_slots_read(layer_extents(extents, slots), P, decode_steps)
+                acc["kv_slots_read"] += read
+                acc["kv_slots"] += decode_steps * (P + N)
+                if windowed:
+                    acc["kv_window_slots_read"] += read
+                    acc["kv_window_slots"] += decode_steps * (P + N)
 
             prompt_ids, prompt_mask = chunk["prompt_ids"], chunk["prompt_mask"]
             # async chunks ship the sampler's exact behavior logprobs; they ride
@@ -1325,6 +1372,7 @@ class PPOTrainer(TPUBaseTrainer):
             "slot_steps": 0, "live_slot_steps": 0,
             "decode_steps": 0, "blocked_s": 0.0,
             "kv_slots_read": 0, "kv_slots": 0,
+            "kv_window_slots_read": 0, "kv_window_slots": 0,
         }
         self.obs.tracer.next_cycle()
         with self.obs.span("collect/experience"):
@@ -1388,6 +1436,10 @@ class PPOTrainer(TPUBaseTrainer):
                 stats["rollout/kv_read_frac"] = (
                     acc["kv_slots_read"] / acc["kv_slots"] if acc["kv_slots"] else 1.0
                 )
+                if acc["kv_window_slots"]:  # the same, of the window layers alone
+                    stats["rollout/kv_window_read_frac"] = (
+                        acc["kv_window_slots_read"] / acc["kv_window_slots"]
+                    )
                 # rollout-side dynamics summaries + health canary (accumulated per
                 # chunk in _rollout_chunk_finalize; setdefault keeps the engine's
                 # exact counters when continuous batching already merged them)
