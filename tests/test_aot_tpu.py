@@ -256,10 +256,6 @@ def test_flash_kernels_keep_their_names_in_the_compiled_program(topo):
     named scope: ``pallas_call(name=...)`` must win over the enclosing flax
     scope (``%attn.N`` before the kernels had names), in the forward and in
     the transposed backward."""
-    import json
-    import os
-    import re
-
     from trlx_tpu.ops import flash_attention as fa
 
     def loss(q, k, v, m):
@@ -269,9 +265,20 @@ def test_flash_kernels_keep_their_names_in_the_compiled_program(topo):
     x = _s((B, T, H, D))
     text = _compile(jax.grad(loss, argnums=(0, 1, 2)), (x, x, x, _s((B, T), jnp.float32)),
                     SingleDeviceSharding(topo.devices[0]))
+    _assert_the_benchmark_finds_both_kernels(text)
+
+
+def _assert_the_benchmark_finds_both_kernels(text):
+    """Two Mosaic calls, and each of ``flash_fwd_device_ms`` /
+    ``flash_bwd_device_ms``'s patterns matches exactly its own."""
+    import json
+    import re
+
+    from trlx_tpu.ops import flash_attention as fa
+
     calls = [l.strip().removeprefix("ROOT ") for l in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in l]
-    assert len(calls) == 2
+    assert len(calls) == 2  # the forward and the fused backward
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     for metric, kernel in (("flash_fwd_device_ms", fa.FWD_KERNEL_NAME),
                            ("flash_bwd_device_ms", fa.BWD_KERNEL_NAME)):
@@ -281,28 +288,51 @@ def test_flash_kernels_keep_their_names_in_the_compiled_program(topo):
         assert len(hits) == 1 and hits[0].startswith(f"%{kernel}"), (metric, calls)
 
 
-@pytest.mark.parametrize("window", [4096, None], ids=["window_4096", "global"])
-def test_flash_forward_and_backward_compile_at_8192_slots(topo, window):
-    """``smallthinker21b_grpo_ctx8k``'s learner: one row of 8192 slots, 28
-    query heads over 4 key/value heads of 128. The fused backward keeps
-    whole-sequence q, do, dq, lse and delta in VMEM across its k-block steps,
-    32 MiB double-buffered at this length: Mosaic's default 16 MiB scope
-    refuses it ("Ran out of memory in memory space vmem"), so past that the
-    kernel asks for its own limit (``_bwd_vmem_params``); at the lengths every
-    other cell runs it asks for nothing and is the program it was."""
+@pytest.mark.parametrize(
+    "shape,window",
+    [((1, 8192, 28, 4, 128), 4096), ((1, 8192, 28, 4, 128), None), ((8, 1024, 16, 16, 256), None), ((16, 640, 32, 8, 128), None),
+     ((4, 1152, 32, 8, 128), None)],
+    ids=["window_4096", "global", "gptj_1024x256", "mistral_640x128", "not_a_multiple_of_512"],
+)
+def test_flash_forward_and_backward_compile_at_the_chosen_tile(topo, shape, window):
+    """The learners' attention shapes at the tile the kernel chooses for
+    them (``choose_blocks``; no tile is passed, as the model passes none):
+    ``smallthinker21b_grpo_ctx8k``'s one row of 8192 slots, 28 query heads
+    over 4 key/value heads of 128, under its window and without (512 x 512);
+    GPT-J's eight rows of 1024 at head size 256 (512 x 512); sixteen rows of
+    640 at 32 / 8 heads of 128 (one 640 x 640 tile); a long row that 512 does
+    not divide (1152 slots: 384 x 384, no slot of padding). Both kernels must lower
+    for the chip, keep the names the benchmark's ``flash_fwd_device_ms`` /
+    ``flash_bwd_device_ms`` match on, and fit the VMEM they ask for: the
+    fused backward keeps whole-sequence q, do, dq, lse and delta in VMEM
+    across its k-block steps (32 MiB double-buffered at 8192: Mosaic's
+    default 16 MiB scope refuses it, "Ran out of memory in memory space
+    vmem") and a tile's working set grows with the tile, so past the scope
+    each kernel asks for its own limit (``_vmem_params``); at 128 x 128 on a
+    row of 1024 slots it asks for nothing and is the program it was."""
     from trlx_tpu.ops import flash_attention as fa
 
-    assert fa._bwd_vmem_params(1024, 128, 2, False) == {} == fa._bwd_vmem_params(1024, 256, 2, False)
-    assert fa._bwd_vmem_params(8192, 128, 2, True) == {}  # the interpreter has no VMEM
-    assert fa._bwd_vmem_params(8192, 128, 2, False)["compiler_params"].vmem_limit_bytes == 40 * 2**20
+    B, T, H, KV, D = shape
+    block_q, block_k = fa.choose_blocks(T, T)
+    assert (block_q, block_k) == {640: (640, 640), 1152: (384, 384)}.get(T, (512, 512))
+    assert fa._bwd_vmem_params(1024, 128, 2, 128, 128, False) == {} == fa._bwd_vmem_params(1024, 256, 2, 128, 128, False)
+    assert fa._fwd_vmem_params(1024, 256, 2, 128, 128, False) == {}
+    assert fa._bwd_vmem_params(8192, 128, 2, 512, 512, True) == {}  # the interpreter has no VMEM
+    limit = fa._bwd_vmem_params(T, D, 2, block_q, block_k, False)
+    if T == 8192:
+        # 32 MiB resident and twice a 512 x 512 tile's 9 MiB
+        assert limit["compiler_params"].vmem_limit_bytes == 50 * 2**20
+    else:
+        assert limit == {} or limit["compiler_params"].vmem_limit_bytes < 64 * 2**20
 
     def loss(q, k, v, m):
-        return fa.flash_attention(q, k, v, m, window=window, interpret=False).astype(jnp.float32).sum()
+        with jax.named_scope("attn"):  # the model's flax scope
+            return fa.flash_attention(q, k, v, m, window=window, interpret=False).astype(jnp.float32).sum()
 
-    q, kv = _s((1, 8192, 28, 128)), _s((1, 8192, 4, 128))
-    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), (q, kv, kv, _s((1, 8192), jnp.float32)),
+    q, kv = _s((B, T, H, D)), _s((B, T, KV, D))
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), (q, kv, kv, _s((B, T), jnp.float32)),
                     SingleDeviceSharding(topo.devices[0]))
-    assert text.count('custom_call_target="tpu_custom_call"') == 2  # the forward and the fused backward
+    _assert_the_benchmark_finds_both_kernels(text)
 
 
 def test_dense_decode_step_builds_no_repeated_kv(topo, monkeypatch):

@@ -6,6 +6,8 @@ tests: forward, logsumexp, gradients, ALiBi, offsets (ring contract),
 left-padded masks.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -261,3 +263,292 @@ def test_gqa_unrepeated_kv_matches_repeated(kv_heads):
             np.asarray(a), np.asarray(b), atol=5e-5, rtol=5e-5,
             err_msg=f"GQA grad mismatch for {name}",
         )
+
+
+# ---------------------------------------------------------------------------
+# bf16 inputs: float32 tiles inside the kernel, bf16 results
+# ---------------------------------------------------------------------------
+
+BF16_REL_L2 = 1e-2
+BF16_MAX_ABS = 4e-2
+BF16_T = 32
+
+
+def _bf16_case(D, group, window, variant, seed=0):
+    """Inputs of one bf16 case and the keywords both implementations take.
+    ``offsets``: the queries are the second half of the row's slots, the keys
+    the whole row (the ring-attention chunk contract)."""
+    T = S = BF16_T
+    B, KV = 2, 1
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    kw = {}
+    if variant == "offsets":
+        T = S // 2
+        kw.update(q_offset=S - T, k_offset=0)
+    q = jax.random.normal(ks[0], (B, T, KV * group, D), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (B, S, KV, D), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (B, S, KV, D), jnp.bfloat16)
+    mask = np.ones((B, S), np.float32)
+    if variant in ("left_pad", "alibi"):
+        mask[:, :3] = 0.0
+        mask[0, :5] = 0.0
+    mask = jnp.asarray(mask)
+    if variant == "alibi":
+        pos = jnp.maximum(jnp.cumsum(mask, axis=1) - 1, 0).astype(jnp.int32)
+        kw.update(
+            q_positions=pos, k_positions=pos,
+            alibi_slopes=jnp.asarray(alibi_slopes(KV * group), jnp.float32),
+        )
+    if window is not None:
+        kw["window"] = window
+    return q, k, v, mask, kw
+
+
+def _close_bf16(got, want, what):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    scale = max(float(np.abs(want).max()), 1e-6)
+    rel = float(np.linalg.norm(got - want) / max(np.linalg.norm(want), 1e-6))
+    assert rel < BF16_REL_L2, f"{what}: relative L2 {rel}"
+    assert float(np.abs(got - want).max()) < BF16_MAX_ABS * scale, f"{what}: max abs"
+
+
+@pytest.mark.parametrize(
+    "window,variant",
+    [(None, "plain"), (40, "plain"), (9, "plain"), (None, "left_pad"), (9, "left_pad"), (None, "alibi"), (9, "offsets")],
+    ids=["plain", "window_inside", "window_binding", "left_pad", "left_pad_window", "alibi", "offsets_window"],
+)
+@pytest.mark.parametrize("D,group", [(128, 1), (128, 4), (128, 7), (256, 1), (256, 4)])
+def test_bf16_inputs_match_reference(D, group, window, variant):
+    """bf16 q, k, v, do: forward, ``lse`` and all three gradients against
+    ``attention_reference`` on the SAME bf16 inputs (the reference computes
+    in float32 throughout). The kernel converts each tile to float32 and
+    contracts float32 operands, which the interpreter here does exactly (so
+    ``lse``, which never leaves float32, keeps a float32 tolerance) and a
+    v5e's MXU in one bf16 pass; its results are rounded to bf16 on the way
+    out, 2^-9 relative a value. An output or a gradient is held to 1e-2 in
+    relative L2 and to 4e-2 of the largest entry elementwise, which leaves
+    the chip's pass its room (measured here: at most 4.5e-3 and 1.1e-2)."""
+    q, k, v, mask, kw = _bf16_case(D, group, window, variant)
+    reps = group
+
+    def ref_fn(q, k, v):
+        return attention_reference(
+            q, jnp.repeat(k, reps, axis=2), jnp.repeat(v, reps, axis=2), mask, causal=True, **kw
+        )
+
+    out, lse = flash_attention(
+        q, k, v, mask, causal=True, interpret=True, return_lse=True, block_q=16, block_k=16, **kw
+    )
+    assert out.dtype == jnp.bfloat16 and lse.dtype == jnp.float32
+    ref, ref_lse = ref_fn(q, k, v)
+    valid = np.asarray(ref_lse) > -1e29
+    _close_bf16(np.asarray(out, np.float32).transpose(0, 2, 1, 3)[valid], np.asarray(ref).transpose(0, 2, 1, 3)[valid], "out")
+    np.testing.assert_allclose(np.asarray(lse)[valid], np.asarray(ref_lse)[valid], atol=1e-4, rtol=1e-4)
+
+    do = jax.random.normal(jax.random.PRNGKey(7), out.shape, jnp.bfloat16)
+    # cotangent only on rows that see a key: a fully padded row's output is
+    # not part of any loss
+    do = do * jnp.asarray(valid.transpose(0, 2, 1)[..., None], jnp.bfloat16)
+
+    def loss_flash(q, k, v):
+        o = flash_attention(q, k, v, mask, causal=True, interpret=True, block_q=16, block_k=16, **kw)
+        return jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32))
+
+    def loss_ref(q, k, v):
+        return jnp.sum(ref_fn(q, k, v)[0] * do.astype(jnp.float32))
+
+    g_flash = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    for gf, gr, name in zip(g_flash, g_ref, "qkv"):
+        assert gf.dtype == jnp.bfloat16
+        _close_bf16(gf, gr, f"d{name}")
+
+
+def _kernel_eqns(fn, *args):
+    """Every equation of the Pallas kernels ``fn`` traces, loops included."""
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from walk(sub)
+
+    calls = [e for e in walk(jax.make_jaxpr(fn)(*args).jaxpr) if e.primitive.name == "pallas_call"]
+    assert calls
+    return [e for call in calls for e in walk(call.params["jaxpr"])]
+
+
+def _float_converts(eqns):
+    return [
+        (e.invars[0].aval.dtype, e.params["new_dtype"], e.invars[0].aval.shape)
+        for e in eqns
+        if e.primitive.name == "convert_element_type"
+        and jnp.issubdtype(e.invars[0].aval.dtype, jnp.floating)
+        and jnp.issubdtype(e.params["new_dtype"], jnp.floating)
+        and e.invars[0].aval.dtype != e.params["new_dtype"]  # not a weak-type change
+    ]
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "float32"])
+def test_kernel_arithmetic_is_float32_by_jaxpr(dtype):
+    """ONE arithmetic whatever the inputs' dtype, read off the kernels' jaxprs
+    (forward and fused backward, block 16, head size 32): every contraction
+    takes float32 operands into a float32 result with no precision asked for,
+    q is scaled once a tile and the scores never, and the only float
+    conversions are the bf16 operands' up to float32 (operand shaped, none
+    for float32 inputs) and the results' down on the way out."""
+    B, T, H, D, blk = 1, 32, 2, 32, 16
+    q = jnp.ones((B, T, H, D), dtype)
+    mask = jnp.ones((B, T), jnp.float32)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, mask, interpret=True, block_q=blk, block_k=blk, window=20)
+        return out.astype(jnp.float32).sum()
+
+    # other test files of the same worker raise jax_default_matmul_precision
+    # at import, and a dot that asks for none follows the default
+    with jax.default_matmul_precision(None):
+        eqns = _kernel_eqns(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+    dots = [e for e in eqns if e.primitive.name == "dot_general"]
+    # two in each of the forward's bodies (edge, interior, edge), five in the backward's
+    assert len(dots) == 3 * 2 + 3 * 5
+    for e in dots:
+        assert [v.aval.dtype for v in e.invars] == [jnp.float32, jnp.float32]
+        assert e.outvars[0].aval.dtype == jnp.float32
+        assert e.params["precision"] is None
+    scaled_scores = [
+        e for e in eqns
+        if e.primitive.name == "mul" and e.outvars[0].aval.shape == (blk, blk)
+        and any(getattr(v, "val", None) is not None and np.ndim(v.val) == 0 and abs(float(v.val) - D ** -0.5) < 1e-6 for v in e.invars)
+    ]
+    assert scaled_scores == []
+    converts = _float_converts(eqns)
+    if dtype == jnp.float32:
+        assert converts == []
+    else:
+        up = [c for c in converts if c[1] == jnp.float32]
+        down = [c for c in converts if c[1] == jnp.bfloat16]
+        assert len(up) + len(down) == len(converts) and up and down
+        assert all(src == jnp.bfloat16 for src, _, _ in up) and all(src == jnp.float32 for src, _, _ in down)
+        assert {shape for _, _, shape in converts} == {(blk, D)}  # operand tiles in, o / dk / dv out
+
+
+@pytest.mark.parametrize(
+    "causal,window,variant",
+    [(True, None, "plain"), (True, 40, "plain"), (True, 9, "left_pad"), (True, None, "alibi"), (True, 9, "offsets"), (False, None, "left_pad")],
+    ids=["causal", "window_inside", "window_binding_left_pad", "alibi", "offsets_window", "not_causal"],
+)
+def test_split_walk_equals_the_single_masked_loop_to_the_bit(monkeypatch, causal, window, variant):
+    """The interior / edge split changes no value. With ``_walk_tiles``
+    replaced by the ONE loop over ``[lo, hi)`` that masks every tile by
+    position (the loop the kernels had before the split), float32 inputs give
+    bit-equal ``out``, ``lse``, ``dq``, ``dk``, ``dv``: the same tiles in the
+    same order, and an interior tile's positional mask is all true. This is
+    the float32 contract ring attention relies on, held exactly and not to
+    2e-5."""
+    from trlx_tpu.ops import flash_attention as fa
+
+    q, k, v, mask, kw = _bf16_case(32, 4, window, variant)
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    do = jax.random.normal(jax.random.PRNGKey(3), q.shape, jnp.float32)
+
+    def run():
+        call = functools.partial(
+            flash_attention, key_mask=mask, causal=causal, interpret=True, block_q=8, block_k=8, **kw
+        )
+        out, lse = call(q, k, v, return_lse=True)  # the ring's entry: no vjp
+        grads = jax.grad(lambda q, k, v: jnp.sum(call(q, k, v) * do), argnums=(0, 1, 2))(q, k, v)
+        return [np.asarray(x) for x in (out, lse, *grads)]
+
+    split = run()
+    calls = []
+
+    def one_masked_loop(tile, bounds, carry, positional):
+        calls.append(positional)
+        return jax.lax.fori_loop(bounds[0], bounds[3], functools.partial(tile, positional=positional), carry)
+
+    monkeypatch.setattr(fa, "_walk_tiles", one_masked_loop)
+    single = run()
+    assert len(calls) == 3  # two forwards and the backward, each traced anew
+    for a, b, name in zip(split, single, ("out", "lse", "dq", "dk", "dv")):
+        assert np.array_equal(a, b), name
+
+
+def _pair_visibility(T, S, qoff, koff, window):
+    """``[T, S]`` bools: which (query slot, key slot) pairs positions allow."""
+    q_slots = np.arange(T)[:, None] + qoff
+    k_slots = np.arange(S)[None, :] + koff
+    visible = k_slots <= q_slots
+    if window:
+        visible &= q_slots - k_slots < window
+    return visible
+
+
+@pytest.mark.parametrize("window", [None, 1, 100, 512, 700, 4096])
+@pytest.mark.parametrize(
+    "T,S",
+    [(128, 128), (200, 200), (384, 384), (640, 640), (768, 768), (896, 896), (1024, 1024), (1100, 1100),
+     (1152, 1152), (1280, 1280), (1408, 1408), (2048, 2048), (128, 640), (384, 640), (896, 1024), (1536, 2048)],
+)
+def test_tile_walk_against_a_brute_force_walk_of_the_mask(T, S, window):
+    """For the tile ``choose_blocks`` returns at each shape (every tile it
+    can return is among them: the row itself up to 896 slots; beyond, the
+    largest of 512, 384, 256 and 128 that divides the row; a divisor for the
+    keys of a prefill), the bounds the kernels walk
+    (``_fwd_tile_bounds`` and ``_bwd_tile_bounds``: the kernels call these
+    very functions) against the mask itself: every tile with a visible pair
+    is visited, every interior tile has no pair that position masks, and the
+    visited set is no wider than the tiles the diagonal and the window's edge
+    touch. ``block_pairs_visited`` is the forward's sum at offset 0."""
+    from trlx_tpu.ops import flash_attention as fa
+
+    block_q, block_k = fa.choose_blocks(T, S)
+    assert block_q % 128 == 0 and block_k % 128 == 0 and block_k <= block_q
+    Tp, Sp = -(-T // block_q) * block_q, -(-S // block_k) * block_k
+    # no row is padded further than its 128 lanes ask
+    assert (Tp, Sp) == (-(-T // 128) * 128, -(-S // 128) * 128)
+    n_q, n_k = Tp // block_q, Sp // block_k
+    for qoff in {0, S - T}:
+        visible = _pair_visibility(Tp, Sp, qoff, 0, window)
+        tiles = visible.reshape(n_q, block_q, n_k, block_k)
+        some, every = tiles.any(axis=(1, 3)), tiles.all(axis=(1, 3))
+        visited = np.zeros((n_q, n_k), bool)
+        interior = np.zeros((n_q, n_k), bool)
+        for iq in range(n_q):
+            lo, lo_in, hi_in, hi = fa._fwd_tile_bounds(
+                qoff + iq * block_q, 0, block_q, block_k, n_k, True, window
+            )
+            assert 0 <= lo <= lo_in <= hi_in <= hi <= n_k
+            visited[iq, lo:hi] = True
+            interior[iq, lo_in:hi_in] = True
+        assert (visited == some).all()  # exactly the tiles with a visible pair
+        assert (interior == every).all()  # exactly the tiles position leaves whole
+        back_visited = np.zeros((n_q, n_k), bool)
+        back_interior = np.zeros((n_q, n_k), bool)
+        for ik in range(n_k):
+            lo, lo_in, hi_in, hi = fa._bwd_tile_bounds(
+                ik * block_k, qoff, block_q, block_k, n_q, True, window
+            )
+            assert 0 <= lo <= lo_in <= hi_in <= hi <= n_q
+            back_visited[lo:hi, ik] = True
+            back_interior[lo_in:hi_in, ik] = True
+        # the backward's range is contiguous in q, so it may take in a masked
+        # tile between two visible ones only if the mask is not convex: it is
+        assert (back_visited == some).all() and (back_interior == every).all()
+        if T == S and qoff == 0:
+            causal = _pair_visibility(Tp, Sp, 0, 0, None).reshape(n_q, block_q, n_k, block_k).any(axis=(1, 3))
+            assert fa.block_pairs_visited(T, window, block_q, block_k) == (some.sum(), causal.sum(), every.sum())
+
+
+def test_explicit_tiles_are_honoured_and_none_asks_the_chooser():
+    from trlx_tpu.ops import flash_attention as fa
+
+    assert fa._resolve_blocks(None, None, 8192, 8192, False) == fa.choose_blocks(8192, 8192) == (512, 512)
+    assert fa._resolve_blocks(128, 256, 8192, 8192, False) == (128, 256)
+    assert fa._resolve_blocks(None, None, 13, 13, True) == (13, 13)  # the interpreter: no wider than the row
+    # what set the thresholds (PERF.md section 6, PR 34): one tile up to 896 slots, 512 beyond
+    assert [fa.choose_blocks(n, n) for n in (128, 384, 640, 896, 1024, 6144)] == [
+        (128, 128), (384, 384), (640, 640), (896, 896), (512, 512), (512, 512)]
+    # a long row that 512 does not divide takes the largest tile that does
+    assert [fa.choose_blocks(n, n) for n in (1100, 1152, 1280, 1408, 1536)] == [
+        (384, 384), (384, 384), (256, 256), (128, 128), (512, 512)]
+    assert fa.choose_blocks(128, 640) == (128, 128) and fa.choose_blocks(896, 1024) == (896, 512)

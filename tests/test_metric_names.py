@@ -121,6 +121,11 @@ def test_scanner_sees_the_codebase():
     # keys"): literal sites in trainer/base.py::with_router_aux
     assert "moe/dropped_frac" in keys
     assert "moe/load_max_over_mean" in keys
+    # the flash kernels' tile walk at a step's width (docs/OBSERVABILITY.md
+    # "Per-step keys"): literal sites in trainer/base.py's learn loop
+    assert "learn/attn_visited_frac" in keys
+    assert "learn/attn_tile" in keys
+    assert "learn/attn_interior_frac" in keys
 
 
 def test_engine_keys_registered_and_namespaced():

@@ -540,4 +540,5 @@ def test_train_runs_grpo_on_the_preset_and_logs_its_counters(tmp_path):
     assert 0.0 < float(step["moe/held_frac"]) < 0.7 and float(step["moe/dropped_frac"]) == 0.0
     assert 1.0 <= float(step["moe/held_load_max_over_mean"]) <= 2.0
     assert step["learn/step_width"] <= 128 and step["learn/attn_visited_frac"] == 1.0  # one 128-slot block
+    assert step["learn/attn_tile"] == 128.0 and step["learn/attn_interior_frac"] == 0.0  # and it holds the diagonal
     assert np.isfinite([v for k, v in step.items() if k.startswith("losses/")]).all()

@@ -22,7 +22,20 @@ Design notes:
 - The forward also emits the per-row logsumexp ``L``; ``(out, L)`` pairs
   combine associatively, which is exactly what the ring-attention accumulator
   needs.
-- f32 accumulation throughout; inputs may be bf16.
+- float32 throughout; inputs may be bf16. Every q, k, v, do tile is
+  converted to float32 in VMEM (q scaled by ``sm_scale`` once), every
+  contraction takes float32 operands at the default precision, and the
+  softmax, ``lse``, ``delta``, ``p``, ``ds``, the output accumulator and the
+  ``dq`` / ``dk`` / ``dv`` partials are float32. Mosaic serves a float32
+  contraction at the default precision in ONE bf16 pass on a v5e, so
+  handing it bf16 operands (and rounding ``p`` and ``ds`` to bf16 for it)
+  buys nothing and costs 5 to 10% at equal tiles (PERF.md section 6, PR 34).
+- Tiles come from the shapes (``choose_blocks``): a row of up to 896 slots
+  is one tile, a longer one walks 512 x 512 tiles; an explicit ``block_q`` /
+  ``block_k`` is honoured (tests, ring attention). The tile loop runs the
+  body without positional masks over the tiles that lie wholly below the
+  diagonal and inside the window (``_fwd_tile_bounds``); only the diagonal's
+  and the window's edge tiles build their ``iota`` compares.
 - Registered in ``analysis/kernels.py::KERNEL_PARITY`` as ``flash-fwd`` /
   ``flash-bwd``: graftlint's kernel-discipline pass (GL1001–GL1004) keeps
   both entries gated through ``pallas_utils``, the kernel bodies pure, and
@@ -55,6 +68,72 @@ BWD_KERNEL_NAME = "flash_attention_bwd"
 
 
 # ---------------------------------------------------------------------------
+# the tile walk
+# ---------------------------------------------------------------------------
+
+
+def _clip(x, lo, hi):
+    return min(max(x, lo), hi)
+
+
+def _fwd_tile_bounds(q0, koff, block_q, block_k, n_k, causal, window, clip=_clip):
+    """``(lo, lo_in, hi_in, hi)``: the key blocks a query block that starts
+    at slot ``q0`` walks are ``[lo, hi)``, and those of ``[lo_in, hi_in)``
+    need no positional mask: wholly at or below the diagonal and wholly
+    inside every query's window. The kernel calls it on traced scalars
+    (``clip=jnp.clip``), ``block_pairs_visited`` on host integers; ``//``
+    floors in both."""
+    if causal:
+        # last k block whose first slot can be visible to any query in this
+        # q block: k_slot <= q_slot  ⇔  koff + s <= q0 + bQ - 1
+        hi = clip((q0 + block_q - koff + block_k - 1) // block_k, 0, n_k)
+        # wholly at or below the diagonal: koff + (ik+1)*bK - 1 <= q0
+        hi_in = clip((q0 - koff + 1) // block_k, 0, hi)
+    else:
+        hi = hi_in = n_k
+    lo = lo_in = 0
+    if window:
+        # first k block any query here can see: k_slot > q_slot - window
+        lo = clip((q0 - (window - 1) - koff) // block_k, 0, hi)
+        # wholly inside the window: q0 + bQ - 1 - (koff + ik*bK) < window
+        lo_in = clip((q0 + block_q - window - koff + block_k - 1) // block_k, lo, hi_in)
+    return lo, lo_in, hi_in, hi
+
+
+def _bwd_tile_bounds(k0, qoff, block_q, block_k, n_q, causal, window, clip=_clip):
+    """``(lo, lo_in, hi_in, hi)`` of the query blocks the backward walks for
+    the key block that starts at slot ``k0``: the transpose of
+    :func:`_fwd_tile_bounds`."""
+    if causal:
+        lo = clip((k0 - qoff) // block_q, 0, n_q)
+        # wholly at or below the diagonal: qoff + iq*bQ >= k0 + bK - 1
+        lo_in = clip((k0 + block_k - 1 - qoff + block_q - 1) // block_q, lo, n_q)
+    else:
+        lo = lo_in = 0
+    hi = hi_in = n_q
+    if window:
+        # last q block that can still see this k block: q_slot < k_slot + W
+        hi = clip((k0 + block_k + window - 2 - qoff) // block_q + 1, lo, n_q)
+        lo_in = clip(lo_in, lo, hi)
+        # every query has the whole k block in its window:
+        # qoff + (iq+1)*bQ - 1 - k0 < window
+        hi_in = clip((k0 + window - qoff) // block_q, lo_in, hi)
+    return lo, lo_in, hi_in, hi
+
+
+def _walk_tiles(tile, bounds, carry, positional: bool):
+    """``tile(i, carry, positional)`` over ``[lo, hi)`` in ascending order,
+    so the sums run in the order they always did: the body with positional
+    masks on the edge ranges, without them on ``[lo_in, hi_in)``."""
+    lo, lo_in, hi_in, hi = bounds
+    edge = functools.partial(tile, positional=positional)
+    interior = functools.partial(tile, positional=False)
+    carry = jax.lax.fori_loop(lo, lo_in, edge, carry)
+    carry = jax.lax.fori_loop(lo_in, hi_in, interior, carry)
+    return jax.lax.fori_loop(hi_in, hi, edge, carry)
+
+
+# ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
@@ -84,28 +163,17 @@ def _fwd_kernel(
     q = q_ref[0, 0].astype(jnp.float32) * sm_scale  # (bQ, D)
     qoff = qoff_ref[0]
     koff = koff_ref[0]
-    q_slots = qoff + iq * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0
-    )
+    q0 = qoff + iq * block_q  # first query slot of this block
+    q_slots = q0 + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
     if alibi:
         q_pos = qpos_ref[0, 0].astype(jnp.float32).reshape(block_q, 1)
         slope = slopes_ref[pl.program_id(1)]
 
-    n_k = seq_k // block_k
-    if causal:
-        # last k block whose first slot can be visible to any query in this
-        # q block: k_slot <= q_slot  ⇔  koff + s <= qoff + (iq+1)*bQ - 1
-        hi = jnp.clip(
-            (qoff + (iq + 1) * block_q - koff + block_k - 1) // block_k, 0, n_k
-        )
-    else:
-        hi = n_k
-    lo = 0
-    if window:
-        # first k block any query here can see: k_slot > q_slot - window
-        lo = jnp.clip((qoff + iq * block_q - (window - 1) - koff) // block_k, 0, hi)
+    bounds = _fwd_tile_bounds(
+        q0, koff, block_q, block_k, seq_k // block_k, causal, window, jnp.clip
+    )
 
-    def body(ik, carry):
+    def tile(ik, carry, positional):
         acc, m, l = carry
         k = k_ref[0, 0, pl.ds(ik * block_k, block_k), :].astype(jnp.float32)
         v = v_ref[0, 0, pl.ds(ik * block_k, block_k), :].astype(jnp.float32)
@@ -114,18 +182,19 @@ def _fwd_kernel(
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # (bQ, bK)
-        k_slots = (
-            koff
-            + ik * block_k
-            + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-        )
         visible = kmask > 0.5
-        if causal:
-            visible = visible & (k_slots <= q_slots)
-        if window:
-            # slots are laid out in temporal order with padding only on the
-            # left, so slot distance ≡ position distance for real pairs
-            visible = visible & (q_slots - k_slots < window)
+        if positional:
+            k_slots = (
+                koff
+                + ik * block_k
+                + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+            )
+            if causal:
+                visible = visible & (k_slots <= q_slots)
+            if window:
+                # slots are laid out in temporal order with padding only on
+                # the left, so slot distance ≡ position distance for real pairs
+                visible = visible & (q_slots - k_slots < window)
         if alibi:
             k_pos = kpos_ref[0, 0, pl.ds(ik * block_k, block_k)].astype(
                 jnp.float32
@@ -149,7 +218,7 @@ def _fwd_kernel(
     acc = jnp.zeros((block_q, d), jnp.float32)
     m = jnp.full((block_q, 1), NEG_INF, jnp.float32)
     l = jnp.zeros((block_q, 1), jnp.float32)
-    acc, m, l = jax.lax.fori_loop(lo, hi, body, (acc, m, l))
+    acc, m, l = _walk_tiles(tile, bounds, (acc, m, l), causal or bool(window))
 
     safe_l = jnp.where(l > 0.0, l, 1.0)
     o_ref[0, 0] = (acc / safe_l).astype(o_ref.dtype)
@@ -203,26 +272,17 @@ def _bwd_fused_kernel(
     kmask = kmask_ref[0, 0].reshape(1, block_k)
     qoff = qoff_ref[0]
     koff = koff_ref[0]
-    k_slots = koff + ik * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1
-    )
+    k0 = koff + ik * block_k  # first key slot of this block
+    k_slots = k0 + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
     if alibi:
         k_pos = kpos_ref[0, 0].astype(jnp.float32).reshape(1, block_k)
         slope = slopes_ref[pl.program_id(1)]
 
-    n_q = seq_q // block_q
-    if causal:
-        lo = jnp.clip((koff + ik * block_k - qoff) // block_q, 0, n_q)
-    else:
-        lo = 0
-    hi = n_q
-    if window:
-        # last q block that can still see this k block: q_slot < k_slot + W
-        hi = jnp.clip(
-            (koff + (ik + 1) * block_k + window - 2 - qoff) // block_q + 1, lo, n_q
-        )
+    bounds = _bwd_tile_bounds(
+        k0, qoff, block_q, block_k, seq_q // block_q, causal, window, jnp.clip
+    )
 
-    def body(iq, carry):
+    def tile(iq, carry, positional):
         dk, dv = carry
         q = q_ref[0, 0, pl.ds(iq * block_q, block_q), :].astype(jnp.float32) * sm_scale
         do = do_ref[0, 0, pl.ds(iq * block_q, block_q), :].astype(jnp.float32)
@@ -232,14 +292,15 @@ def _bwd_fused_kernel(
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
-        q_slots = qoff + iq * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
-        )
         visible = kmask > 0.5
-        if causal:
-            visible = visible & (k_slots <= q_slots)
-        if window:
-            visible = visible & (q_slots - k_slots < window)
+        if positional:
+            q_slots = qoff + iq * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 0
+            )
+            if causal:
+                visible = visible & (k_slots <= q_slots)
+            if window:
+                visible = visible & (q_slots - k_slots < window)
         if alibi:
             q_pos = qpos_ref[0, 0, pl.ds(iq * block_q, block_q)].astype(
                 jnp.float32
@@ -267,7 +328,7 @@ def _bwd_fused_kernel(
 
     d = q_ref.shape[-1]
     zeros = jnp.zeros((block_k, d), jnp.float32)
-    dk, dv = jax.lax.fori_loop(lo, hi, body, (zeros, zeros))
+    dk, dv = _walk_tiles(tile, bounds, (zeros, zeros), causal or bool(window))
     dk_ref[0, 0] = dk.astype(dk_ref.dtype)
     dv_ref[0, 0] = dv.astype(dv_ref.dtype)
 
@@ -348,6 +409,7 @@ def _flash_fwd_impl(
         ],
         interpret=interpret,
         name=FWD_KERNEL_NAME,
+        **_fwd_vmem_params(S, D, q.dtype.itemsize, block_q, block_k, interpret),
     )(qoff, koff, q, k, v, kmask, qpos, kpos, slopes)
     return out, lse
 
@@ -413,7 +475,7 @@ def _bwd_fused_call(
         ],
         interpret=interpret,
         name=BWD_KERNEL_NAME,
-        **_bwd_vmem_params(T, D, q.dtype.itemsize, interpret),
+        **_bwd_vmem_params(T, D, q.dtype.itemsize, block_q, block_k, interpret),
     )(qoff, koff, q, k, v, kmask, qpos, kpos, slopes, lse, delta, do)
     if group > 1:
         dk = dk.reshape(B, KV, group, S, D).sum(axis=2)
@@ -421,27 +483,46 @@ def _bwd_fused_call(
     return dq.astype(q.dtype), dk, dv
 
 
-# Mosaic's default scoped VMEM, and what the backward kernel leaves of it for
-# its k, v blocks and its (block_q, block_k) intermediates
+# Mosaic's default scoped VMEM
 _SCOPED_VMEM_BYTES = 16 * 2**20
-_BWD_WORKING_BYTES = 4 * 2**20
 
 
-def _bwd_vmem_params(T: int, D: int, itemsize: int, interpret: bool) -> dict:
-    """``pallas_call`` keywords for the fused backward: nothing (the program
-    every sequence up to a few thousand slots has always had) unless the
-    whole-sequence operands it keeps in VMEM across the k-block steps, each
-    double-buffered, outgrow the default scope: q and do (``T x D``), dq
-    (float32) and lse and delta (float32, ``LANES`` padded to a 128-lane
-    tile). At 8192 slots and head size 128 that is 32 MiB, and the kernel
-    asks for that and its working set of a v5e's 128 MiB."""
-    resident = 2 * T * (2 * D * itemsize + D * 4 + 2 * 128 * 4)
-    if interpret or resident + _BWD_WORKING_BYTES <= _SCOPED_VMEM_BYTES:
+def _tile_working_bytes(block_q: int, block_k: int, D: int, itemsize: int) -> int:
+    """What one tile pair keeps live beside the whole-sequence operands: six
+    ``(block_q, block_k)`` float32 intermediates (scores, mask, p, dp, ds and
+    one to spare), and the double-buffered tile operands with their float32
+    copies and partials of ``D`` columns on either side."""
+    return 6 * block_q * block_k * 4 + 4 * (block_q + block_k) * D * (itemsize + 4)
+
+
+def _vmem_params(resident: int, working: int, interpret: bool) -> dict:
+    """``pallas_call`` keywords: nothing (the program every sequence up to a
+    few thousand slots has always had) unless the operands a kernel keeps in
+    VMEM across its grid steps and its tile's working set outgrow the default
+    scope; then the kernel asks for both of a v5e's 128 MiB."""
+    if interpret or resident + working <= _SCOPED_VMEM_BYTES:
         return {}
     from jax.experimental.pallas import tpu as pltpu
 
-    limit = resident + 2 * _BWD_WORKING_BYTES
+    limit = resident + 2 * working
     return {"compiler_params": pltpu.CompilerParams(vmem_limit_bytes=limit)}
+
+
+def _fwd_vmem_params(S: int, D: int, itemsize: int, block_q: int, block_k: int, interpret: bool) -> dict:
+    """The forward keeps a head's whole K and V (``S x D``) and the key mask
+    and positions (float32 and int32 rows, padded to eight sublanes), each
+    double-buffered: 8.5 MiB at 8192 slots and head size 128."""
+    resident = 2 * S * (2 * D * itemsize + 2 * 8 * 4)
+    return _vmem_params(resident, _tile_working_bytes(block_q, block_k, D, itemsize), interpret)
+
+
+def _bwd_vmem_params(T: int, D: int, itemsize: int, block_q: int, block_k: int, interpret: bool) -> dict:
+    """The fused backward keeps whole-sequence operands in VMEM across the
+    k-block steps, each double-buffered: q and do (``T x D``), dq (float32)
+    and lse and delta (float32, ``LANES`` padded to a 128-lane tile): 32 MiB
+    at 8192 slots and head size 128."""
+    resident = 2 * T * (2 * D * itemsize + D * 4 + 2 * 128 * 4)
+    return _vmem_params(resident, _tile_working_bytes(block_q, block_k, D, itemsize), interpret)
 
 
 def _flash_bwd_rule(
@@ -482,8 +563,8 @@ def flash_attention_bwd_chunk(
     q_positions: Optional[jax.Array] = None,  # (B, T) for alibi
     k_positions: Optional[jax.Array] = None,  # (B, S) for alibi
     alibi_slopes: Optional[jax.Array] = None,  # (H,)
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: Optional[int] = None,  # None: choose_blocks
+    block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
     window: Optional[int] = None,  # sliding-window width (None = unbounded)
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -501,9 +582,7 @@ def flash_attention_bwd_chunk(
     if sm_scale is None:
         sm_scale = 1.0 / (D ** 0.5)
     alibi = alibi_slopes is not None
-    if interpret:
-        block_q = min(block_q, max(T, 8))
-        block_k = min(block_k, max(S, 8))
+    block_q, block_k = _resolve_blocks(block_q, block_k, T, S, interpret)
 
     qt = _pad_to(q.transpose(0, 2, 1, 3), block_q, 2)
     kt = _pad_to(k.transpose(0, 2, 1, 3), block_k, 2)
@@ -546,22 +625,88 @@ def flash_attention_bwd_chunk(
     )
 
 
+# A row of at most this many slots is ONE tile; a longer row walks tiles of
+# up to ``_LONG_ROW_TILE`` slots. Set from bare-kernel timings on a TPU v5e
+# (bf16 inputs, forward | backward against the 128 x 128 tiles the kernels
+# always had; PERF.md section 6, PR 34): a whole-row tile gains 1.47x | 1.75x
+# at 384 slots, 1.86x | 2.02x at 640 and 1.99x | 1.96x at 896, where a loop
+# iteration costs more than the masked half of the square; at 1024 slots
+# 512 x 512 (2.44x | 2.24x; head size 256: 1.80x | 2.04x) beats one tile by a
+# sixth, and at 8192 it gives 4.60x | 3.13x (under a window of 4096 4.03x |
+# 2.86x), where 256 x 256 gave little more than half of that and
+# 1024 x 1024 the backward 8% more and the forward 3 to 11% less. A long row
+# that 512 does not divide: 384 x 384 at 1152 slots 1.95x | 1.84x, 256 x 256
+# at 1280 2.03x | 1.54x.
+_ONE_TILE_SLOTS = 896
+_LONG_ROW_TILE = 512
+
+
+def _largest_tile(lanes: int, most: int) -> int:
+    """The largest multiple of 128 up to ``most`` that divides ``lanes``."""
+    return max(t for t in range(128, most + 1, 128) if lanes % t == 0)
+
+
+def _row_tile(slots: int) -> int:
+    """One tile for a short row; for a long one the largest tile up to
+    ``_LONG_ROW_TILE`` that divides the row as rounded up to 128 slots, so a
+    row is never padded further than the 128 x 128 kernels padded it (1152
+    slots walk tiles of 384, 1280 of 256, not 1536 slots of 512)."""
+    lanes = -(-slots // 128) * 128
+    return lanes if lanes <= _ONE_TILE_SLOTS else _largest_tile(lanes, _LONG_ROW_TILE)
+
+
+def choose_blocks(T: int, S: int) -> Tuple[int, int]:
+    """``(block_q, block_k)`` for ``T`` query slots over ``S`` key slots: a
+    function of the shapes alone, so every caller that leaves the tile to the
+    kernel (the model, the step record's counters) gets the same one. Head
+    size and item size do not change the choice on a v5e: 512 x 512 at head
+    size 256 fits once ``_vmem_params`` has asked for it.
+
+    A key tile never exceeds the query tile: a prefill of ``T`` tokens into
+    a cache of ``S > T`` slots sees ``T`` keys, and a wider tile is mostly
+    masked (128 x 640 over a 640-slot cache runs at 0.88x of 128 x 128). It
+    shrinks to a divisor of the row's own tile, so K and V are padded no
+    further."""
+    block_q, block_k = _row_tile(T), _row_tile(S)
+    if block_k > block_q:
+        block_k = _largest_tile(block_k, block_q)
+    return block_q, block_k
+
+
+def _resolve_blocks(block_q, block_k, T, S, interpret):
+    """An explicit tile is honoured; ``None`` asks :func:`choose_blocks`.
+    The interpreter has no tiling constraints, so there a tile never exceeds
+    the sequence (small blocks keep CPU tests fast); on hardware tiles stay
+    multiples of 128 and T/S are padded up to a tile multiple, since Mosaic
+    rejects sub-128 lane blocks."""
+    chosen_q, chosen_k = choose_blocks(T, S)
+    block_q = chosen_q if block_q is None else block_q
+    block_k = chosen_k if block_k is None else block_k
+    if interpret:
+        block_q = min(block_q, max(T, 8))
+        block_k = min(block_k, max(S, 8))
+    return block_q, block_k
+
+
 def block_pairs_visited(
     width: int, window: Optional[int] = None, block_q: int = 128, block_k: int = 128
-) -> Tuple[int, int]:
-    """``(visited, causal)``: the (query block, key block) pairs the forward
-    kernel visits in a full pass over ``width`` slots under ``window``, and
-    the pairs plain causal attention visits. Host arithmetic that mirrors
-    ``_fwd_kernel``'s ``lo`` and ``hi`` bounds at offset 0, for
-    ``learn/attn_visited_frac``."""
+) -> Tuple[int, int, int]:
+    """``(visited, causal, interior)``: the (query block, key block) pairs the
+    forward kernel visits in a full pass over ``width`` slots under
+    ``window``, the pairs plain causal attention visits, and the visited
+    pairs that run the body without positional masks: ``_fwd_kernel``'s own
+    bounds at offset 0, on the host, for ``learn/attn_visited_frac`` and
+    ``learn/attn_interior_frac``."""
     n_q, n_k = -(-width // block_q), -(-width // block_k)
-    visited = causal = 0
+    visited = causal = interior = 0
     for iq in range(n_q):
-        hi = min(((iq + 1) * block_q + block_k - 1) // block_k, n_k)
-        lo = min(max((iq * block_q - (window - 1)) // block_k, 0), hi) if window else 0
+        lo, lo_in, hi_in, hi = _fwd_tile_bounds(
+            iq * block_q, 0, block_q, block_k, n_k, True, window
+        )
         visited += hi - lo
         causal += hi
-    return visited, causal
+        interior += hi_in - lo_in
+    return visited, causal, interior
 
 
 def flash_attention(
@@ -577,8 +722,8 @@ def flash_attention(
     q_positions: Optional[jax.Array] = None,  # (B, T) for alibi
     k_positions: Optional[jax.Array] = None,  # (B, S) for alibi
     alibi_slopes: Optional[jax.Array] = None,  # (H,)
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: Optional[int] = None,  # None: choose_blocks
+    block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
     return_lse: bool = False,
     window: Optional[int] = None,  # sliding-window width (None = unbounded)
@@ -601,13 +746,7 @@ def flash_attention(
     if sm_scale is None:
         sm_scale = 1.0 / (D ** 0.5)
     alibi = alibi_slopes is not None
-
-    if interpret:
-        # interpreter has no tiling constraints; small blocks keep CPU tests fast
-        block_q = min(block_q, max(T, 8))
-        block_k = min(block_k, max(S, 8))
-    # on hardware, blocks stay tile-aligned (128) and T/S are padded up to a
-    # block multiple below — Mosaic rejects sub-128 lane blocks
+    block_q, block_k = _resolve_blocks(block_q, block_k, T, S, interpret)
 
     # [B, T, H, D] → [B, H, T, D]
     qt = _pad_to(q.transpose(0, 2, 1, 3), block_q, 2)

@@ -728,18 +728,24 @@ class TPUBaseTrainer(BaseRLTrainer):
             self._prompt_chunks_drawn += 1
             yield chunk
 
-    def _attn_visited_frac(self, width: int) -> float:
-        """(query block, key block) pairs the flash forward visits in a step
-        of this width over the pairs causal attention has, summed over the
-        layers: static arithmetic from each layer's layout
-        (``TransformerConfig.layer_layouts``); 1 where no window binds."""
-        from trlx_tpu.ops.flash_attention import block_pairs_visited
+    def _attn_tile_walk(self, width: int) -> Tuple[float, float, float]:
+        """``(visited fraction, key tile, interior fraction)`` of the flash
+        forward in a step of this width: (query block, key block) pairs it
+        visits over the pairs causal attention has, the key tile's slots,
+        and the visited pairs that run the body without positional masks,
+        summed over the layers. Static arithmetic from each layer's layout
+        (``TransformerConfig.layer_layouts``) and the tile the kernel itself
+        chooses at this width (``ops/flash_attention.py::choose_blocks``);
+        the first is 1 where no window binds."""
+        from trlx_tpu.ops.flash_attention import block_pairs_visited, choose_blocks
 
         layouts = getattr(self.tcfg, "layer_layouts", None)
         if not layouts or not width:
-            return 1.0
-        pairs = [block_pairs_visited(width, layout.window) for layout in layouts]
-        return sum(v for v, _ in pairs) / max(sum(c for _, c in pairs), 1)
+            return 1.0, 0.0, 0.0
+        block_q, block_k = choose_blocks(width, width)
+        pairs = [block_pairs_visited(width, layout.window, block_q, block_k) for layout in layouts]
+        visited, causal, interior = (sum(p[i] for p in pairs) for i in range(3))
+        return visited / max(causal, 1), float(block_k), interior / max(visited, 1)
 
     def _batch_token_counts(self, batch: Any) -> Tuple[int, int, int]:
         """``(real, fed, width)`` of a host batch: the unpadded tokens its
@@ -1999,7 +2005,11 @@ class TPUBaseTrainer(BaseRLTrainer):
                         1.0 - real_tokens / fed_tokens if fed_tokens else 0.0
                     )
                     stats["learn/step_width"] = float(width)
-                    stats["learn/attn_visited_frac"] = self._attn_visited_frac(width)
+                    (
+                        stats["learn/attn_visited_frac"],
+                        stats["learn/attn_tile"],
+                        stats["learn/attn_interior_frac"],
+                    ) = self._attn_tile_walk(width)
                     batch_size = next(
                         v.shape[0] for v in batch.values() if hasattr(v, "shape")
                     ) if isinstance(batch, dict) else self.config.train.batch_size
