@@ -19,6 +19,7 @@ if REPO_ROOT not in sys.path:
     sys.path.insert(0, REPO_ROOT)
 
 from trlx_tpu.analysis.conventions import (  # noqa: E402,F401
+    ATTRIBUTION_KEYS,
     CLUSTER_KEYS,
     DIST_KEYS,
     ENGINE_KEYS,
@@ -28,6 +29,8 @@ from trlx_tpu.analysis.conventions import (  # noqa: E402,F401
     OBS_KEYS,
     RESILIENCE_KEYS,
     SERVE_KEYS,
+    SETUP_KEYS,
+    SETUP_SPAN_NAMES,
     _CONVENTION_RE,
     _KEY_RE,
     find_violations as _find_violations,

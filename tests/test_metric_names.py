@@ -5,6 +5,8 @@ convention (legacy allowlist frozen)."""
 import importlib.util
 import os
 
+import pytest
+
 
 def _load_checker():
     spec = importlib.util.spec_from_file_location(
@@ -197,6 +199,31 @@ def test_cluster_flightrec_obs_keys_registered_and_namespaced():
         assert missing == set(), (
             f"{registry_name} entries not seen by the scanner: {missing}"
         )
+
+
+@pytest.mark.parametrize("registry_name", ["ATTRIBUTION_KEYS", "SETUP_KEYS", "SETUP_SPAN_NAMES"])
+def test_attribution_and_setup_names_registered_and_namespaced(registry_name):
+    """The sink's kinds, the record keys made of them and set-up's gauges
+    and spans (docs/OBSERVABILITY.md "What happened beneath a span",
+    "Set-up") are registered, follow the convention, live in the three
+    namespaces, and the literal sites reach the scanner."""
+    checker = _load_checker()
+    registry = getattr(checker, registry_name)
+    assert registry, f"{registry_name} is empty"
+    namespaces = {"setup"} if registry_name.startswith("SETUP") else {"host", "runtime", "time"}
+    for key in registry:
+        assert checker._CONVENTION_RE.match(key), key
+        assert key.split("/")[0] in namespaces, key
+    keys = checker.scanned_keys()
+    if registry_name == "ATTRIBUTION_KEYS":  # the step record's literal writes
+        assert {"time/train_step_dispatch", "time/train_step_wait"} <= set(keys)
+    source = open(os.path.join(checker.SCAN_DIR, "observability", "__init__.py")).read()
+    source += open(os.path.join(checker.SCAN_DIR, "trainer", "base.py")).read()
+    source += open(os.path.join(checker.SCAN_DIR, "trlx.py")).read()
+    source += open(os.path.join(checker.SCAN_DIR, "observability", "tracing.py")).read()
+    unwritten = {k for k in registry
+                 if f'"{k}"' not in source and k not in ("host/slow_cycles", "host/slow_steps")}
+    assert unwritten == set(), f"{registry_name} entries the program never writes: {unwritten}"
 
 
 def test_dist_and_health_keys_registered_and_namespaced():

@@ -8,6 +8,10 @@
 - profiling: ``TRLX_TPU_PROFILE`` spec parsing and window no-ops;
 - distributed telemetry: cluster beats over an injected allgather —
   straggler flagging, desync diagnostics, clock offsets, merged traces;
+- attribution: what the runtime, the collector and the fence did lands
+  beneath the span that was open, once, from worker threads too; the records
+  of a toy run carry it, set-up freezes into ``setup/*`` gauges, and a
+  planted slow step is named once;
 - flight recorder: ring semantics, span/metric taps, dump/reload, and the
   end-to-end NaN-halt dump;
 - end-to-end: a tiny PPO smoke run emits the canonical throughput/time keys
@@ -72,7 +76,6 @@ class TestTracer:
             y = jax.jit(lambda a: a @ a)(x)
             sp.fence(y)
         assert sp.duration > 0
-        assert tracer.last_duration("matmul") == sp.duration
 
     def test_exports_are_loadable(self, tmp_path):
         tracer = Tracer()
@@ -121,15 +124,19 @@ class TestTracer:
                     if e.name.startswith("trlx/"):
                         assert plane.name.startswith("/host:")
                         found[e.name] = (e, line.name)
-        assert set(found) == {"trlx/collect/experience", "trlx/generate", "trlx/rollout/overlap"}
+        # a collection that ran meanwhile is on the same clock (trlx/host/gc)
+        assert set(found) - {"trlx/host/gc"} == {
+            "trlx/collect/experience", "trlx/generate", "trlx/rollout/overlap"}
         outer, inner = found["trlx/collect/experience"][0], found["trlx/generate"][0]
         assert outer.start_ns <= inner.start_ns
         assert inner.start_ns + inner.duration_ns <= outer.start_ns + outer.duration_ns
         # the annotation outlives the fenced span it mirrors
         assert inner.duration_ns * 1e-9 >= sp.duration
         assert dict(inner.stats) == {"cycle": 1, "eval_mode": 0}
-        # the tracer's own buffer is untouched by the mirror
-        assert {e["name"] for e in tracer.events()} == {
+        # the tracer's own buffer is untouched by the mirror (beside the
+        # spans it holds what the sink put beneath them: the jit's compile)
+        assert {e["name"] for e in tracer.events() if "/" not in e["name"]
+                or e["name"].split("/")[0] not in ("runtime", "host")} == {
             "collect/experience", "generate", "rollout/overlap"}
 
     def test_span_without_a_profiler_session_is_inert(self, tmp_path):
@@ -159,6 +166,396 @@ class TestTracer:
         with tracer.span("after") as sp:
             pass
         assert sp.depth == 0  # the stack fully unwound
+
+
+# ---------------------------------------------------------------------------
+# attribution: the runtime, the collector and the fence beneath the spans
+# (counts and containment only; a time is never compared with a time)
+# ---------------------------------------------------------------------------
+
+RECORD_KEYS = {"host/gc_pause_s", "host/gc_gen2", "runtime/retrace_s", "runtime/compile_s",
+               "host/cpu_s", "host/invol_switches", "host/major_faults", "host/proc_cpu_s",
+               "host/proc_invol_switches"}
+COLLECTION_RECORD_KEYS = RECORD_KEYS | {"time/generate_dispatch", "time/generate_wait"}
+STEP_RECORD_KEYS = RECORD_KEYS | {"time/train_step_dispatch", "time/train_step_wait"}
+SETUP_GAUGES = {
+    "setup/import_s", "setup/build_s", "setup/init_model_s", "setup/first_eval_s",
+    "setup/first_cycle_s", "setup/trace_lower_s", "setup/compile_s", "setup/cache_load_s",
+    "setup/compile_load_s", "setup/gc_pause_s", "setup/programs", "setup/cache_hits",
+    "setup/cache_misses", "setup/total_s",
+}
+SETUP_SPANS = {"setup/runtime_init", "setup/build_trainer", "setup/init_model",
+               "setup/tokenizer", "setup/pipelines", "setup/first_eval"}
+ATTRIBUTION_LAYER_METRICS = (
+    "setup_build_s", "setup_first_eval_s", "setup_first_cycle_s", "setup_trace_lower_s",
+    "setup_compile_s", "generate_dispatch_ms", "train_dispatch_ms", "collect_gc_pause_ms",
+    "learn_gc_pause_pct", "collect_retrace_ms", "learn_retrace_pct",
+)
+
+
+def _children(tracer, sp, prefix):
+    """Events under ``prefix`` recorded on the span's thread inside it."""
+    (own,) = [e for e in tracer.events() if e["name"] == sp.name and e["ph"] == "X"]
+    return [e for e in tracer.events()
+            if e["name"].startswith(prefix) and e["tid"] == own["tid"]
+            and own["ts"] <= e["ts"] and e["ts"] + e["dur"] <= own["ts"] + own["dur"] + 1e-3]
+
+
+@pytest.fixture
+def no_automatic_gc():
+    import gc
+
+    gc.collect()
+    gc.disable()  # an explicit collect() still calls the callbacks
+    yield
+    gc.enable()
+
+
+@pytest.mark.parametrize("fenced", [True, False], ids=["fenced", "unfenced"])
+def test_dispatch_and_wait_tile_the_span(fenced):
+    tracer = Tracer()
+    with tracer.span("train_step") as sp:
+        y = jax.jit(lambda a: a @ a)(jnp.ones((64, 64)))
+        if fenced:
+            sp.fence(y)
+    assert sp.dispatch + sp.wait == pytest.approx(sp.duration, abs=1e-12)
+    (event,) = [e for e in tracer.events() if e["name"] == "train_step"]
+    if fenced:
+        assert sp.t0 <= sp.t_fence <= sp.t1 and event["args"]["wait_s"] == sp.wait
+    else:
+        assert sp.wait == 0.0 and sp.t_fence is None and "wait_s" not in event.get("args", {})
+
+
+@pytest.mark.parametrize("where", ["main", "worker"])
+def test_gc_lands_on_the_span_that_was_open(where, no_automatic_gc):
+    import gc
+    import threading
+
+    tracer = Tracer()
+    spans = {}
+
+    def work():
+        with tracer.span("obs/before") as spans["before"]:
+            pass
+        with tracer.span("obs/collecting") as spans["collecting"]:
+            gc.collect()
+        with tracer.span("obs/after") as spans["after"]:
+            pass
+
+    if where == "worker":
+        with tracer.span("obs/main_thread") as spans["main"]:
+            worker = threading.Thread(target=work)
+            worker.start()
+            worker.join(timeout=60)
+            assert not worker.is_alive()
+    else:
+        work()
+    (child,) = _children(tracer, spans["collecting"], "host/gc")
+    assert child["args"] == {"generation": 2}
+    assert set(spans["collecting"].attributed) == {"host/gc"}
+    for name in set(spans) - {"collecting"}:  # no sibling, and not the other thread
+        assert spans[name].attributed is None and _children(tracer, spans[name], "host/gc") == []
+
+
+@pytest.mark.parametrize("threads", [2, 4])
+def test_spans_closing_on_several_threads_drain_the_collections_once(threads):
+    """Collections queue up in the callback and the next span to close
+    records them; spans close on the main thread and the pipeline worker at
+    once, and each collection becomes ONE event whoever drains it, also
+    where the interpreter changes threads between a look at the queue and
+    the pop (a drain that tests first raises IndexError there)."""
+    import threading
+    import time
+
+    class SwitchBeforePop(list):
+        def pop(self, index=-1):
+            time.sleep(0.002)  # the other threads run now
+            return super().pop(index)
+
+    tracer = Tracer()
+    tracer._gc_pending = SwitchBeforePop()
+    rounds, errors = 15, []
+
+    def refill():  # the barrier's action: one thread, the others held
+        tracer._gc_pending.append((0.0, 1e-3, 2, 7))
+
+    barrier = threading.Barrier(threads, action=refill)
+
+    def close_spans():
+        try:
+            for _ in range(rounds):
+                barrier.wait(timeout=60)
+                with tracer.span("obs/closing"):
+                    pass
+        except Exception as e:
+            errors.append(e)
+            barrier.abort()
+
+    workers = [threading.Thread(target=close_spans) for _ in range(threads)]
+    for w in workers:
+        w.start()
+    for w in workers:
+        w.join(timeout=120)
+    assert errors == [] and not any(w.is_alive() for w in workers)
+    planted = [e for e in tracer.events() if e["name"] == "host/gc" and e["tid"] == 7]
+    assert len(planted) == rounds
+    assert len([e for e in tracer.events() if e["name"] == "obs/closing"]) == rounds * threads
+
+
+def test_jit_puts_its_trace_lowering_and_compile_under_the_span():
+    from trlx_tpu.observability import tracing
+
+    tracer = Tracer()
+
+    def obs_fresh_program(a):
+        return a * 3 + 1
+
+    fn = jax.jit(obs_fresh_program)
+    before = tracing.mark()
+    with tracer.span("obs/first_call") as first:
+        fn(jnp.ones((3, 5))).block_until_ready()
+    with tracer.span("obs/second_call") as second:
+        fn(jnp.ones((3, 5))).block_until_ready()
+    mine = [e for e in _children(tracer, first, "runtime/")
+            if e["args"]["fun_name"] == "obs_fresh_program"]
+    assert sorted(e["name"] for e in mine if e["name"] != "runtime/cache_load") == [
+        "runtime/compile", "runtime/lower", "runtime/trace"]
+    assert {"runtime/trace", "runtime/lower", "runtime/compile"} <= set(first.attributed)
+    assert second.attributed is None and _children(tracer, second, "runtime/") == []
+    # the process's totals moved by the same events: one program, by name
+    grew = tracing.since(before, tracing.mark())
+    assert grew["runtime/programs"] >= 1
+    assert tracing.programs()["obs_fresh_program"]["programs"] == 1
+    assert "obs_fresh_program" in tracing.recent_programs(first.t0, first.t1)
+    assert tracing.recent_programs(second.t0, second.t1) == []
+
+
+def test_sources_are_registered_once_however_many_tracers():
+    import gc
+
+    import jax.monitoring  # noqa: F401
+    from jax._src import monitoring
+    from trlx_tpu.observability import tracing
+
+    def registered():
+        return (gc.callbacks.count(tracing._on_gc),
+                monitoring.get_event_time_span_listeners().count(tracing._on_runtime_span),
+                monitoring.get_event_duration_listeners().count(tracing._on_runtime_duration),
+                monitoring.get_event_listeners().count(tracing._on_runtime_event),
+                monitoring.get_scalar_listeners().count(tracing._on_runtime_begin))
+
+    tracers = [Tracer() for _ in range(3)]
+    Observability()
+    assert registered() == (1, 1, 1, 1, 1)
+    tracing.uninstall_sources()
+    try:
+        assert registered() == (0, 0, 0, 0, 0)
+    finally:
+        tracing.install_sources()
+    assert registered() == (1, 1, 1, 1, 1)
+    # every live tracer hears the sink; a dead one is dropped from its list
+    tracing.attribute("runtime/trace", 1.0, 2.0, fun_name="obs_nobody")
+    assert all([e["name"] for e in t.events()] == ["runtime/trace"] for t in tracers)
+    n_live = len(tracing._live_tracers())
+    del tracers[0]
+    gc.collect()
+    assert len(tracing._live_tracers()) == n_live - 1
+
+
+class _Recorder:
+    def __init__(self):
+        self.records = []
+
+    def log(self, stats, step=None):
+        self.records.append(dict(stats))
+
+    def finish(self):
+        pass
+
+
+CYCLES = 10  # of two steps each
+SLOW_STEP = 15  # the train_step call a sleep is planted in (the eighth cycle's first)
+
+
+@pytest.fixture(scope="module", params=["ppo", "grpo"])
+def attributed_run(request, tmp_path_factory):
+    """Ten cycles of two steps at toy widths, one step slowed by a sleep."""
+    import time
+
+    import trlx_tpu.trainer.base as base
+    import trlx_tpu.trlx as trlx
+    from trlx_tpu.data.default_configs import default_grpo_config, default_ppo_config
+
+    tmp_path = tmp_path_factory.mktemp("attributed")
+    grpo = request.param == "grpo"
+    config = (default_grpo_config if grpo else default_ppo_config)().evolve(
+        train=dict(
+            seq_length=24, batch_size=8, total_steps=2 * CYCLES, eval_interval=100,
+            checkpoint_interval=100, epochs=CYCLES, save_best=False, tracker=None,
+            checkpoint_dir=str(tmp_path / "ckpts"), logging_dir=str(tmp_path / "logs"),
+        ),
+        model=dict(model_path="builtin:gpt2-test", num_layers_unfrozen=1),
+        tokenizer=dict(tokenizer_path="builtin:bytes"),
+        method=dict(
+            num_rollouts=16, chunk_size=8, ppo_epochs=1,
+            gen_kwargs=dict(max_new_tokens=8, top_k=0, top_p=1.0, do_sample=True),
+            **(dict(group_size=4) if grpo else {}),
+        ),
+    )
+    recorder = _Recorder()
+
+    def hook(trainer):
+        trainer.tracker = recorder
+        train_step, calls = trainer.train_step, []
+
+        def slowed(batch):
+            calls.append(time.perf_counter())
+            if len(calls) == SLOW_STEP:
+                # thirty of the cycles this machine has run since the compiles
+                # (from a call to the call two steps on is one cycle); seven
+                # cycles of history make the median this one is held against
+                # deaf to a burst of load
+                windows = sorted(calls[i] - calls[i - 2] for i in range(4, SLOW_STEP, 2))
+                time.sleep(min(max(3.0, 30 * windows[len(windows) // 2]), 40.0))
+            return train_step(batch)
+
+        trainer.train_step = slowed
+
+    # a CPU under six test workers is no steady machine: only the planted
+    # sleep (thirty cycles long, on steps of some 10 ms) may stand out
+    ratio, base.SLOW_INTERVAL_RATIO = base.SLOW_INTERVAL_RATIO, 20.0
+    # the MFU gauge lowers the train step once more on a thread of its own
+    # after the first step, which the second step's record would show
+    mfu_env, os.environ["TRLX_TPU_MFU"] = os.environ.get("TRLX_TPU_MFU"), "0"
+    try:
+        trainer = trlx.train(
+            reward_fn=lambda samples, prompts, outputs, **kw: [float(len(o)) for o in outputs],
+            prompts=["ab", "cd", "ef", "gh", "ij", "kl", "mn", "op"], config=config,
+            init_trainer_hook=hook)
+    finally:
+        base.SLOW_INTERVAL_RATIO = ratio
+        if mfu_env is None:
+            del os.environ["TRLX_TPU_MFU"]
+        else:
+            os.environ["TRLX_TPU_MFU"] = mfu_env
+    return {"trainer": trainer, "records": recorder.records,
+            "events": trainer.obs.tracer.events()}
+
+
+def _steps(run):
+    return [r for r in run["records"] if "time/train_step" in r]
+
+
+def test_every_record_carries_its_attribution(attributed_run):
+    collections = [r for r in attributed_run["records"] if "time/exp" in r]
+    steps = _steps(attributed_run)
+    assert len(collections) == CYCLES and len(steps) == 2 * CYCLES
+    for r in collections:
+        assert COLLECTION_RECORD_KEYS <= set(r), sorted(COLLECTION_RECORD_KEYS - set(r))
+        assert r["time/generate_dispatch"] + r["time/generate_wait"] == pytest.approx(
+            r["time/generate"], rel=1e-9)
+    for r in steps:
+        assert STEP_RECORD_KEYS <= set(r), sorted(STEP_RECORD_KEYS - set(r))
+        assert r["time/train_step_dispatch"] + r["time/train_step_wait"] == pytest.approx(
+            r["time/train_step"], rel=1e-9)
+    # one width, one program: only the first step of the shape traces
+    assert steps[0]["runtime/retrace_s"] > 0 and steps[0]["runtime/compile_s"] > 0
+    assert [r["runtime/retrace_s"] for r in steps[1:]] == [0.0] * (2 * CYCLES - 1)
+    assert [r["runtime/compile_s"] for r in steps[1:]] == [0.0] * (2 * CYCLES - 1)
+    # after the first cycle a collection compiles nothing either, and the ONE
+    # thing it retraces is the retrace without a compile the sink found:
+    # eval_shape of the cache's shapes at every generate() call
+    # (base.py::_note_dense_kv_gauge; PERF.md section 6, PR 35)
+    assert collections[0]["runtime/compile_s"] > 0
+    assert [r["runtime/compile_s"] for r in collections[1:]] == [0.0] * (CYCLES - 1)
+    assert all(r["runtime/retrace_s"] > 0 for r in collections)
+    events = attributed_run["events"]
+    later = [c for c in events if c["name"] == "collect/experience"][1:]
+    inside = [e for e in events if e["name"].startswith("runtime/") and any(
+        c["ts"] <= e["ts"] and e["ts"] + e["dur"] <= c["ts"] + c["dur"] + 1e-3 for c in later)]
+    assert {(e["name"], e["args"]["fun_name"]) for e in inside} == {
+        ("runtime/trace", "kv_cache_shapes")}
+
+
+def test_setup_is_under_spans_and_frozen_at_the_second_collection(attributed_run):
+    names = {e["name"] for e in attributed_run["events"]}
+    assert SETUP_SPANS <= names, sorted(SETUP_SPANS - names)
+    steps = _steps(attributed_run)
+    for r in steps[:2]:  # the first cycle is set-up itself
+        assert not SETUP_GAUGES & set(r)
+    frozen = {k: steps[2][k] for k in SETUP_GAUGES}
+    for r in steps[2:]:
+        assert {k: r[k] for k in SETUP_GAUGES} == frozen
+    total = frozen["setup/total_s"]
+    phases = ("setup/import_s", "setup/build_s", "setup/first_eval_s", "setup/first_cycle_s")
+    assert all(0 <= frozen[k] <= total for k in SETUP_GAUGES - {"setup/programs"})
+    assert sum(frozen[k] for k in phases) == pytest.approx(total, rel=1e-9)  # they tile it
+    assert frozen["setup/init_model_s"] <= frozen["setup/build_s"]
+    assert frozen["setup/compile_load_s"] == frozen["setup/compile_s"] + frozen["setup/cache_load_s"]
+    assert frozen["setup/programs"] >= 3  # generate, score, the train step
+    # of them, executables the persistent cache gave or took; the rest
+    # compiled too fast to be kept
+    assert 0 <= frozen["setup/cache_hits"] + frozen["setup/cache_misses"] <= frozen["setup/programs"]
+    # the runtime's work sits beneath the spans that caused it
+    for span, program in (("generate", "rollout_generate"), ("train_step", "train_step")):
+        (first, *_) = [e for e in attributed_run["events"] if e["name"] == span]
+        inside = [e for e in attributed_run["events"] if e["name"].startswith("runtime/")
+                  and e["tid"] == first["tid"] and first["ts"] <= e["ts"]
+                  and e["ts"] + e["dur"] <= first["ts"] + first["dur"] + 1e-3]
+        assert {e["name"] for e in inside if e["args"]["fun_name"] == program} >= {
+            "runtime/trace", "runtime/lower", "runtime/compile"}
+
+
+def test_a_planted_sleep_is_named_once(attributed_run, trlx_log_records):
+    steps = _steps(attributed_run)
+    counts = [r.get("host/slow_steps", 0.0) for r in steps]
+    assert counts == [0.0] * (SLOW_STEP - 1) + [1.0] * (len(steps) - SLOW_STEP + 1)
+    ring = [r["data"] for r in attributed_run["trainer"].obs.flightrec.snapshot()
+            if r["kind"] == "slow_interval"]
+    (step,) = [d for d in ring if d["kind"] == "step"]
+    # asleep in train_step before the fence: dispatch, and off the CPU
+    assert step["verdict"] == "thread not running"
+    assert step["line"].startswith(f"step {SLOW_STEP - 1}: ") and "train_step dispatch +" in step["line"]
+    assert f"{int(step['host/major_faults'])} major faults: thread not running" in step["line"]
+    # the cycle that held it is named too, when the next collection closes it
+    (cycle,) = [d for d in ring if d["kind"] == "cycle"]
+    assert cycle["line"].startswith(f"cycle {(SLOW_STEP + 1) // 2}: ")
+    assert steps[-1]["host/slow_cycles"] == 1.0
+
+
+@pytest.mark.parametrize("name", ATTRIBUTION_LAYER_METRICS)
+def test_attribution_layer_metric_reads_what_the_program_emits(attributed_run, name):
+    """The eleven per-layer metrics of PR 35 are data over reducers the
+    benchmark already had, each over one key the records carry."""
+    from types import SimpleNamespace
+
+    from chipbench import layers
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "chipbench", "layer_metrics", f"{name}.json")) as f:
+        spec = json.load(f)
+    declared = {m["name"]: m for m in layers.job.load_benchmark()["per_layer"]}[name]
+    assert "workloads" not in declared
+    assert {k: spec[k] for k in ("name", "unit", "better", "source", "layer", "moves")} == declared
+    assert spec["reducer"] in ("stat_median", "stat_mean", "stat_share")
+    # cycles as the harness keeps them, from the second on (the window)
+    cycles, now = [], 0.0
+    for r in attributed_run["records"]:
+        if "time/exp" in r:
+            cycles.append({"collection": r, "steps": [], "start": now, "end": now + 1.0})
+            now += 1.0
+        elif "time/train_step" in r:
+            cycles[-1]["steps"].append(r)
+    value = layers.reduce_one(spec, SimpleNamespace(cycles=cycles[1:]), None, None, 1)
+    assert value is not None and value >= 0.0
+    if name == "learn_retrace_pct":
+        assert value == 0.0  # programs_compiled's inside twin
+    if name == "collect_retrace_ms":
+        assert value > 0.0  # kv_cache_shapes: traced at every generate() call, never compiled
+    # a program without the key (the parent of PR 35) reports nothing
+    bare = [{"collection": {"time/exp": 1.0}, "steps": [{"time/train_step": 1.0}],
+             "start": 0.0, "end": 1.0}]
+    assert layers.reduce_one(spec, SimpleNamespace(cycles=bare), None, None, 1) is None
 
 
 # ---------------------------------------------------------------------------

@@ -248,6 +248,67 @@ OBS_KEYS = frozenset({
     "obs/spans_dropped",
 })
 
+# What the host and the runtime did in a record's interval, and the
+# intervals that ran long (observability/tracing.py's sink,
+# trainer/base.py::attributed_between, docs/OBSERVABILITY.md "What happened
+# beneath a span"). The record keys are built by one function from the
+# sink's marks and the counters through an f-string, so the registry is
+# their canonical list; the runtime/* and host/gc entries without a record
+# are the sink's kinds (span-event names and keys of tracing.mark()).
+ATTRIBUTION_KEYS = frozenset({
+    "time/generate_dispatch",
+    "time/generate_wait",
+    "time/train_step_dispatch",
+    "time/train_step_wait",
+    "host/gc_pause_s",
+    "host/gc_gen2",
+    "host/cpu_s",
+    "host/invol_switches",
+    "host/major_faults",
+    "host/proc_cpu_s",
+    "host/proc_invol_switches",
+    "host/slow_cycles",
+    "host/slow_steps",
+    "host/gc",
+    "runtime/retrace_s",
+    "runtime/compile_s",
+    "runtime/trace",
+    "runtime/lower",
+    "runtime/compile",
+    "runtime/cache_load",
+    "runtime/programs",
+    "runtime/cache_hits",
+    "runtime/cache_misses",
+})
+
+# Set-up's account, frozen as gauges when the second collection begins
+# (observability/__init__.py::Observability.freeze_setup, set in one loop),
+# and the spans it is read from.
+SETUP_KEYS = frozenset({
+    "setup/import_s",
+    "setup/build_s",
+    "setup/init_model_s",
+    "setup/first_eval_s",
+    "setup/first_cycle_s",
+    "setup/trace_lower_s",
+    "setup/compile_s",
+    "setup/cache_load_s",
+    "setup/compile_load_s",
+    "setup/gc_pause_s",
+    "setup/programs",
+    "setup/cache_hits",
+    "setup/cache_misses",
+    "setup/total_s",
+})
+SETUP_SPAN_NAMES = frozenset({
+    "setup/runtime_init",
+    "setup/build_trainer",
+    "setup/init_model",
+    "setup/tokenizer",
+    "setup/pipelines",
+    "setup/first_eval",
+})
+
 # Canonical training-dynamics sketch keys (observability/dynamics.py,
 # docs/OBSERVABILITY.md "Training dynamics"). The ``*_hist`` keys carry the
 # on-device fixed-bin histogram counts through the stats fetch; the host

@@ -6,8 +6,11 @@ programs, but nothing observed the *running* system. This subsystem closes
 that gap:
 
 - :mod:`tracing` — nestable, rank-aware spans with device fencing
-  (``block_until_ready`` at span exit), a Chrome/Perfetto export, and a
-  ``trlx/<name>`` twin of every span on the ``jax.profiler`` clock;
+  (``block_until_ready`` at span exit), a Chrome/Perfetto export, a
+  ``trlx/<name>`` twin of every span on the ``jax.profiler`` clock, and the
+  sink that puts the runtime's traces, lowerings, compiles and cache loads,
+  the interpreter's garbage collections and the thread's CPU seconds
+  beneath the span that was open;
 - :mod:`metrics` — counters/gauges/histograms feeding the existing
   ``Tracker`` stream, plus tokens/sec / samples/sec / **MFU** derived by
   joining fenced step times against XLA ``cost_analysis`` flops of the
@@ -35,6 +38,7 @@ that gap:
 """
 
 import os
+from dataclasses import dataclass
 from typing import Any, Dict, Optional
 
 from trlx_tpu.observability.distributed import (
@@ -53,7 +57,8 @@ from trlx_tpu.observability.metrics import (
     train_step_flops,
 )
 from trlx_tpu.observability.profiling import ProfileWindow, parse_profile_spec
-from trlx_tpu.observability.tracing import Span, Tracer, get_tracer, span
+from trlx_tpu.observability import tracing
+from trlx_tpu.observability.tracing import Span, Tracer
 from trlx_tpu.observability.watchdogs import DeviceMemoryGauge, RecompileWatchdog
 from trlx_tpu.utils import logging
 
@@ -75,12 +80,32 @@ __all__ = [
     "ThroughputMeter",
     "Tracer",
     "device_peak_flops",
-    "get_tracer",
     "mfu",
     "parse_profile_spec",
-    "span",
     "train_step_flops",
 ]
+
+
+@dataclass(slots=True)
+class SetupAccount:
+    """What set-up was made of (docs/OBSERVABILITY.md "Set-up"):
+    ``trlx.train()`` opens it, the trainer notes each phase as it ends and
+    :meth:`Observability.freeze_setup` turns it into the ``setup/*`` gauges.
+    Slots: a misspelt phase is an AttributeError, not a gauge that reads 0."""
+
+    t_train: Optional[float] = None  # perf_counter where trlx.train() began
+    import_s: float = 0.0  # process start to there
+    # the sink's totals and its table by program once the runtime is up
+    mark: Optional[Dict[str, float]] = None
+    programs: Optional[Dict[str, Dict[str, float]]] = None
+    init_model_s: float = 0.0  # span setup/init_model
+    first_eval_s: Optional[float] = None  # span setup/first_eval, and
+    first_eval_end: Optional[float] = None  # where it closed (perf_counter)
+    first_collect_s: Optional[float] = None  # the first collection's time/exp
+
+    def begin(self, t_train: float, import_s: float, mark: Dict[str, float],
+              programs: Dict[str, Dict[str, float]]) -> None:
+        self.t_train, self.import_s, self.mark, self.programs = t_train, import_s, mark, programs
 
 
 class Observability:
@@ -127,6 +152,7 @@ class Observability:
             kl_target=getattr(method, "target", None),
         )
         self._warned_dropped = False
+        self.setup = SetupAccount()
         # wall-clock construction time: the merge's staleness floor — peer
         # trace files older than this run are a previous incarnation's
         # (same logging dir across a preempt/relaunch) and must not be
@@ -146,6 +172,58 @@ class Observability:
 
     def span(self, name: str, fence: Any = None, **args: Any):
         return self.tracer.span(name, fence=fence, **args)
+
+    def freeze_setup(self) -> None:
+        """The second collection begins: set-up is over. Freeze what it was
+        made of as ``setup/*`` gauges, which every later step record
+        snapshots, and log the table of programs once. The phases tile the
+        time from ``trlx.train()`` to now: build (``train()`` to the first
+        collection, and the eval pipeline and ``prepare_learning`` after
+        it), the first evaluation, the first cycle (its collection, and its
+        steps up to this collection); what the runtime and the collector
+        took of them cuts across. A trainer that ``trlx.train()`` did not
+        build, or that resumed past its first evaluation, has no account."""
+        s = self.setup
+        if s.t_train is None or s.first_eval_end is None:
+            return
+        now = tracing.mark()
+        d = tracing.since(s.mark, now)
+        in_train = now["t"] - s.t_train
+        first_cycle = (s.first_collect_s or 0.0) + now["t"] - s.first_eval_end
+        gauges = {
+            "setup/import_s": s.import_s,
+            # the residual: what train() spent in neither of the two below
+            "setup/build_s": in_train - first_cycle - s.first_eval_s,
+            "setup/init_model_s": s.init_model_s,
+            "setup/first_eval_s": s.first_eval_s,
+            "setup/first_cycle_s": first_cycle,
+            "setup/trace_lower_s": d.get("runtime/trace", 0.0) + d.get("runtime/lower", 0.0),
+            "setup/compile_s": d.get("runtime/compile", 0.0),
+            "setup/cache_load_s": d.get("runtime/cache_load", 0.0),
+            "setup/gc_pause_s": d.get("host/gc", 0.0),
+            # backend compile events; of them, executables the persistent
+            # cache gave and executables compiled and written to it (the rest
+            # compiled too fast to be kept, and compile again at every start)
+            "setup/programs": d.get("runtime/programs", 0.0),
+            "setup/cache_hits": d.get("runtime/cache_hits", 0.0),
+            "setup/cache_misses": d.get("runtime/cache_misses", 0.0),
+            "setup/total_s": s.import_s + in_train,
+        }
+        gauges["setup/compile_load_s"] = gauges["setup/compile_s"] + gauges["setup/cache_load_s"]
+        for name, value in gauges.items():
+            self.metrics.set_gauge(name, value)
+        logger.info(
+            "set-up %.1f s: import %.1f, build %.1f (init_model %.1f), first eval %.1f, "
+            "first cycle %.1f; of these the runtime took trace+lower %.1f, compile %.1f, "
+            "cache load %.1f (%d programs: %d from the cache, %d written to it) and the "
+            "collector %.1f\n%s",
+            gauges["setup/total_s"], gauges["setup/import_s"], gauges["setup/build_s"],
+            gauges["setup/init_model_s"], gauges["setup/first_eval_s"],
+            gauges["setup/first_cycle_s"], gauges["setup/trace_lower_s"],
+            gauges["setup/compile_s"], gauges["setup/cache_load_s"], gauges["setup/programs"],
+            gauges["setup/cache_hits"], gauges["setup/cache_misses"],
+            gauges["setup/gc_pause_s"], tracing.programs_table(s.programs),
+        )
 
     def note_dropped_spans(self) -> None:
         """Surface the tracer's silent drop counter as the

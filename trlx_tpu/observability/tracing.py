@@ -21,25 +21,79 @@ Spans nest (a thread-local stack), are rank-aware (every event records
 
 Usage::
 
-    from trlx_tpu.observability import span
-
-    with span("rollout"):
-        with span("generate") as sp:
+    tracer = Tracer()                 # a trainer's is ``trainer.obs.tracer``
+    with tracer.span("rollout"):
+        with tracer.span("generate") as sp:
             out = generate(...)
             sp.fence(out.sequences)   # block on device work at exit
+
+**What happened beneath a span.** The span tree stops at the program's own
+Python. What the JAX runtime, the interpreter and the operating system did
+meanwhile reaches it through one sink, :func:`attribute`: seconds of a
+``kind`` over an interval, added to the process's cumulative totals
+(:func:`mark` reads them; the difference of two marks describes the interval
+between them) and handed to every live :meth:`Tracer.attribute`, which adds
+them to the innermost span open on the thread they happened on and records
+them as a retrospective child event. The sources are registered once a
+process (:func:`install_sources`):
+
+- the runtime, through ``jax.monitoring``: ``runtime/trace``,
+  ``runtime/lower``, ``runtime/compile`` (the true compile: less the load)
+  and ``runtime/cache_load``, outermost events only, each with its
+  program's ``fun_name``; the counts ``runtime/programs`` (backend compile
+  events), ``runtime/cache_hits`` (executables loaded from the persistent
+  cache) and ``runtime/cache_misses`` (compiled and written to it): set-up's
+  account reads all three (``Observability.freeze_setup``);
+- the interpreter, through ``gc.callbacks``: ``host/gc`` with the generation;
+- the fence: a fenced span knows ``dispatch`` (open to fence) from ``wait``;
+- the operating system, in :func:`mark` (the trainer takes one where a
+  collection and a train step end): the calling thread's CPU seconds,
+  involuntary context switches and major faults (every record carries the
+  three, and the slow-interval line prints them), and the whole process's
+  CPU seconds and switches.
+  Not at every cycle-level span's two ends: a system call costs 10 us on the
+  chip's host, and the records' intervals end where those spans do.
 """
 
+import gc
 import json
 import os
 import threading
 import time
+import weakref
+from collections import deque
 from contextlib import contextmanager
-from typing import Any, Callable, Dict, Iterator, List, Optional, Union
+from typing import Any, Callable, Deque, Dict, Iterator, List, Optional, Tuple, Union
+
+try:  # POSIX only; the thread's counters need Linux's RUSAGE_THREAD
+    import resource
+except ImportError:  # pragma: no cover - not a platform this runs on
+    resource = None
 
 FenceLike = Union[None, Any, Callable[[], Any]]
 
 # prefix of every program span on the profiler's host plane
 PROFILER_PREFIX = "trlx/"
+
+# a garbage collection becomes an event of its own from here up (and every
+# generation-2 one does): the young generations run hundreds of times a
+# second for tens of microseconds and would fill the buffers; their seconds
+# still count in the totals and on the span
+GC_EVENT_MIN_S = 1e-3
+
+# jax.monitoring stamps time.time(); spans live on perf_counter
+_WALL_TO_PERF = time.perf_counter() - time.time()
+
+_RUNTIME_KINDS = {
+    "/jax/core/compile/jaxpr_trace_duration": "runtime/trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "runtime/lower",
+    "/jax/core/compile/backend_compile_duration": "runtime/compile",
+}
+_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_COUNTS = {
+    "/jax/compilation_cache/cache_hits": "runtime/cache_hits",
+    "/jax/compilation_cache/cache_misses": "runtime/cache_misses",
+}
 
 
 def _process_index() -> int:
@@ -59,26 +113,268 @@ def _block(tree: Any) -> None:
     jax.block_until_ready(tree)
 
 
-def _profiler_annotation(name: str, args: Dict[str, Any]):
-    """The span's twin on the profiler's clock; the span's args ride along
-    as the event's stats. Costs a no-op C++ call while no session is open."""
-    import jax
+def thread_usage() -> Tuple[float, int, int]:
+    """CPU seconds, involuntary context switches and major faults of the
+    calling thread so far. ONE system call: on the chip's host one costs 7
+    to 10 us (PERF.md section 6, PR 35), so the CPU seconds are the same
+    call's user plus system time and not ``time.thread_time()`` beside it."""
+    if resource is None or not hasattr(resource, "RUSAGE_THREAD"):
+        return time.thread_time(), 0, 0
+    ru = resource.getrusage(resource.RUSAGE_THREAD)
+    return ru.ru_utime + ru.ru_stime, ru.ru_nivcsw, ru.ru_majflt
 
-    return jax.profiler.TraceAnnotation(PROFILER_PREFIX + name, **args)
+
+def process_usage() -> Tuple[float, int]:
+    """CPU seconds and involuntary context switches of every thread of the
+    process so far: the runtime's own threads launch the programs, and one
+    of them descheduled looks, from the thread at the fence, like a wait."""
+    if resource is None:
+        return time.process_time(), 0
+    ru = resource.getrusage(resource.RUSAGE_SELF)
+    return ru.ru_utime + ru.ru_stime, ru.ru_nivcsw
+
+
+# ---------------------------------------------------------------------------
+# the sink and its sources (process-wide: the runtime, the collector and the
+# scheduler act on the process, whichever trainer's span is open)
+# ---------------------------------------------------------------------------
+
+_LOCK = threading.Lock()
+_TOTALS: Dict[str, float] = {}  # kind -> seconds or count; guarded by _LOCK
+# fun_name -> kind -> seconds (the set-up table); guarded by _LOCK
+_PROGRAMS: Dict[str, Dict[str, float]] = {}
+# (end, kind, fun_name, seconds) of the newest runtime events: which program
+# a slow interval retraced
+_RECENT: Deque[Tuple[float, str, str, float]] = deque(maxlen=64)
+# seconds, generation-2 collections. Written by the collector's callback
+# alone, which the interpreter never runs twice at once, and WITHOUT a lock:
+# a collection can begin at any bytecode of this very thread, one that holds
+# _LOCK included
+_GC = [0.0, 0]
+_gc_open: Optional[Tuple[float, Any]] = None  # (start, profiler annotation)
+_TRACERS: List["weakref.ref[Tracer]"] = []
+_TraceAnnotation: Any = None  # jax.profiler's, looked up once (install_sources)
+_RUNTIME = threading.local()  # .depth of open runtime events, .cache_load seconds pending
+_sources_installed = False
+
+
+def _live_tracers() -> List["Tracer"]:
+    return [t for t in (ref() for ref in _TRACERS) if t is not None]
+
+
+def attribute(kind: str, t0: float, t1: float, seconds: Optional[float] = None,
+              **args: Any) -> None:
+    """THE sink: ``seconds`` of ``kind`` (``t1 - t0`` unless the interval
+    holds other kinds' seconds too) over ``[t0, t1]`` on ``perf_counter``,
+    on the calling thread. Not for the collector's callback
+    (:func:`_on_gc`), which may take no lock."""
+    dt = max(t1 - t0, 0.0) if seconds is None else seconds
+    fun = args.get("fun_name")
+    with _LOCK:
+        _TOTALS[kind] = _TOTALS.get(kind, 0.0) + dt
+        if fun is not None:
+            row = _PROGRAMS.setdefault(fun, {})
+            row[kind] = row.get(kind, 0.0) + dt
+            _RECENT.append((t1, kind, fun, dt))
+    for tracer in _live_tracers():
+        tracer.attribute(kind, t0, t1, seconds=dt, **args)
+
+
+def count(kind: str, n: int = 1) -> None:
+    """One more of a counted kind (``runtime/cache_hits``)."""
+    with _LOCK:
+        _TOTALS[kind] = _TOTALS.get(kind, 0.0) + n
+
+
+def mark() -> Dict[str, float]:
+    """The cumulative totals now, with the calling thread's counters
+    (``host/cpu_s``, ``host/invol_switches``, ``host/major_faults``), the
+    whole process's (``host/proc_cpu_s``, ``host/proc_invol_switches``) and
+    the clock (``t``). ``since(m0, m1)`` of two marks taken on one thread
+    describes the interval between them, on whichever thread the seconds
+    fell."""
+    with _LOCK:
+        m = dict(_TOTALS)
+    m["host/gc"], m["host/gc_gen2"] = _GC
+    m["host/cpu_s"], m["host/invol_switches"], m["host/major_faults"] = thread_usage()
+    m["host/proc_cpu_s"], m["host/proc_invol_switches"] = process_usage()
+    m["t"] = time.perf_counter()
+    return m
+
+
+def since(m0: Dict[str, float], m1: Dict[str, float]) -> Dict[str, float]:
+    """What was added between two marks, key by key."""
+    return {k: v - m0.get(k, 0.0) for k, v in m1.items()}
+
+
+def programs() -> Dict[str, Dict[str, float]]:
+    """``fun_name -> kind -> seconds`` of every program the runtime traced,
+    lowered, compiled or loaded so far."""
+    with _LOCK:
+        return {fun: dict(row) for fun, row in _PROGRAMS.items()}
+
+
+def programs_table(before: Dict[str, Dict[str, float]], rows: int = 12) -> str:
+    """The runtime's seconds by program since ``before`` (an earlier
+    :func:`programs`): the ``rows`` costliest by name, the rest (the eager
+    operations of ``init``, mostly) in one row."""
+    kinds = ("runtime/trace", "runtime/lower", "runtime/compile", "runtime/cache_load")
+    table = []
+    for fun, row in programs().items():
+        old = before.get(fun, {})
+        new = [row.get(k, 0.0) - old.get(k, 0.0) for k in ("programs",) + kinds]
+        if any(new):
+            table.append((fun, new))
+    table.sort(key=lambda r: -sum(r[1][1:]))
+    rest = table[rows:]
+    if rest:
+        table = table[:rows] + [
+            (f"{len(rest)} others", [sum(r[1][i] for r in rest) for i in range(5)])]
+    lines = [f"{'program':<32}{'compiled':>9}{'trace':>9}{'lower':>9}{'compile':>9}{'load':>9}"]
+    for fun, (n, *seconds) in table:
+        lines.append(f"{fun[:31]:<32}{int(n):>9}" + "".join(f"{x:>9.3f}" for x in seconds))
+    return "\n".join(lines)
+
+
+def recent_programs(t0: float, t1: float) -> List[str]:
+    """Names of the programs with a runtime event that ended in ``[t0, t1]``
+    (of the newest 64 events), in order, each once."""
+    with _LOCK:
+        hits = [fun for end, _, fun, _ in _RECENT if t0 <= end <= t1]
+    return list(dict.fromkeys(hits))
+
+
+def _on_runtime_begin(event: str, value: float, **kw: Any) -> None:
+    # jax records an event's start as a scalar under the event's name
+    if event in _RUNTIME_KINDS:
+        _RUNTIME.depth = getattr(_RUNTIME, "depth", 0) + 1
+
+
+def _on_runtime_span(event: str, start: float, end: float, **kw: Any) -> None:
+    kind = _RUNTIME_KINDS.get(event)
+    if kind is None:
+        return
+    _RUNTIME.depth = depth = max(getattr(_RUNTIME, "depth", 1) - 1, 0)
+    if depth:
+        # tracing a program traces the jitted functions it calls (thousands
+        # of events a train step) and may compile an eager operation: only
+        # the outermost event counts, under its program's name
+        _RUNTIME.cache_load = 0.0
+        return
+    t0, t1 = start + _WALL_TO_PERF, end + _WALL_TO_PERF
+    fun = str(kw.get("fun_name", "?"))
+    if fun.startswith("jit(") and fun.endswith(")"):
+        fun = fun[4:-1]  # lowering and compiling say jit(f) where tracing says f
+    load = 0.0
+    if kind == "runtime/compile":
+        count("runtime/programs")
+        with _LOCK:
+            row = _PROGRAMS.setdefault(fun, {})
+            row["programs"] = row.get("programs", 0) + 1
+        load, _RUNTIME.cache_load = getattr(_RUNTIME, "cache_load", 0.0), 0.0
+        if load:  # the executable came from the persistent cache
+            attribute("runtime/cache_load", t1 - load, t1, fun_name=fun)
+    # a compile's own seconds are the true compile: the interval less the load
+    attribute(kind, t0, t1, seconds=max(end - start - load, 0.0), fun_name=fun)
+
+
+def _on_runtime_duration(event: str, duration: float, **kw: Any) -> None:
+    # reported inside the compile event it belongs to, before that ends
+    if event == _CACHE_LOAD_EVENT:
+        _RUNTIME.cache_load = getattr(_RUNTIME, "cache_load", 0.0) + duration
+
+
+def _on_runtime_event(event: str, **kw: Any) -> None:
+    # fired inside the compile event the executable belongs to; like
+    # runtime/programs, only an outermost compile's counts
+    kind = _CACHE_COUNTS.get(event)
+    if kind is not None and getattr(_RUNTIME, "depth", 0) <= 1:
+        count(kind)
+
+
+def _on_gc(phase: str, info: Dict[str, int]) -> None:
+    """``gc.callbacks`` entry. Takes no lock and calls nothing that does: it
+    runs wherever a collection begins, inside the tracer's and the flight
+    recorder's locked sections too. The event is queued and recorded by the
+    tracer's next span (:meth:`Tracer._flush_gc`)."""
+    global _gc_open
+    if phase == "start":
+        annotation = None
+        if info["generation"]:
+            # inert unless a profiler session is open; then host_gaps.py puts
+            # a device idle gap down to the collection (the youngest
+            # generation runs for tens of microseconds: no gap of its own)
+            annotation = _TraceAnnotation(PROFILER_PREFIX + "host/gc",
+                                          generation=info["generation"])
+            annotation.__enter__()
+        _gc_open = (time.perf_counter(), annotation)
+        return
+    if _gc_open is None:  # installed between a collection's start and stop
+        return
+    t1 = time.perf_counter()
+    t0, annotation = _gc_open
+    _gc_open = None
+    if annotation is not None:
+        annotation.__exit__(None, None, None)
+    generation = info["generation"]
+    _GC[0] += t1 - t0
+    _GC[1] += generation == 2
+    for tracer in _live_tracers():
+        tracer._note_gc(t0, t1, generation)
+
+
+def install_sources() -> None:
+    """Register the runtime's and the collector's listeners, once a process
+    (``initialize_runtime`` and every :class:`Tracer` call this)."""
+    global _sources_installed
+    with _LOCK:
+        if _sources_installed:
+            return
+        _sources_installed = True
+    global _TraceAnnotation
+    import jax
+    import jax.monitoring as monitoring
+
+    _TraceAnnotation = jax.profiler.TraceAnnotation
+    monitoring.register_scalar_listener(_on_runtime_begin)
+    monitoring.register_event_time_span_listener(_on_runtime_span)
+    monitoring.register_event_duration_secs_listener(_on_runtime_duration)
+    monitoring.register_event_listener(_on_runtime_event)
+    gc.callbacks.append(_on_gc)
+
+
+def uninstall_sources() -> None:
+    """Take the listeners out again (tests)."""
+    global _sources_installed
+    with _LOCK:
+        if not _sources_installed:
+            return
+        _sources_installed = False
+    import jax.monitoring as monitoring
+
+    monitoring.unregister_scalar_listener(_on_runtime_begin)
+    monitoring.unregister_event_time_span_listener(_on_runtime_span)
+    monitoring.unregister_event_duration_listener(_on_runtime_duration)
+    monitoring.unregister_event_listener(_on_runtime_event)
+    gc.callbacks.remove(_on_gc)
 
 
 class Span:
     """One timed region. ``duration`` is valid after the span closes."""
 
-    __slots__ = ("name", "depth", "args", "t0", "t1", "_fence")
+    __slots__ = ("name", "depth", "args", "t0", "t1", "t_fence", "_fence", "attributed")
 
     def __init__(self, name: str, depth: int, args: Optional[Dict[str, Any]] = None):
         self.name = name
         self.depth = depth
         self.args = args or {}
-        self.t0 = time.perf_counter()
         self.t1: Optional[float] = None
+        self.t_fence: Optional[float] = None  # when the fence began
         self._fence: FenceLike = None
+        # kind -> seconds attributed to this span while it was the innermost
+        # one open on its thread (allocated on first use)
+        self.attributed: Optional[Dict[str, float]] = None
+        self.t0 = time.perf_counter()
 
     def fence(self, tree: FenceLike) -> "Span":
         """Set the device pytree to ``block_until_ready`` at span exit."""
@@ -90,8 +386,27 @@ class Span:
         """Seconds, device-fenced if a fence was set. 0.0 while open."""
         return (self.t1 - self.t0) if self.t1 is not None else 0.0
 
+    @property
+    def wait(self) -> float:
+        """Seconds the host spent in the fence with nothing left to do; 0.0
+        without a fence."""
+        return (self.t1 - self.t_fence) if self.t_fence is not None and self.t1 is not None else 0.0
+
+    @property
+    def dispatch(self) -> float:
+        """Seconds from the span's opening to its fence: the host was still
+        setting up, placing arguments, enqueueing, or in anything
+        ``attributed`` names. ``dispatch + wait == duration``."""
+        return self.duration - self.wait
+
+    def add(self, kind: str, seconds: float) -> None:
+        if self.attributed is None:
+            self.attributed = {}
+        self.attributed[kind] = self.attributed.get(kind, 0.0) + seconds
+
     def close(self) -> float:
         if self._fence is not None:
+            self.t_fence = time.perf_counter()
             _block(self._fence() if callable(self._fence) else self._fence)
         self.t1 = time.perf_counter()
         return self.duration
@@ -121,12 +436,19 @@ class Tracer:
         # every span opened meanwhile, on any thread
         self.cycle: Optional[int] = None
         self._epoch = time.perf_counter()
-        self._last_duration: Dict[str, float] = {}  # guarded-by: _lock
+        # collections waiting to become events: appended by the collector's
+        # callback (list.append, no lock: see _on_gc), drained by _flush_gc
+        self._gc_pending: List[Tuple[float, float, int, int]] = []
+        self._rank: Optional[int] = None
         # event listeners (the crash flight recorder): called for EVERY
         # event, including ones the bounded buffer drops — the recorder's
         # own ring keeps rotating after the tracer cap is hit, which is
         # exactly when a long run crashes
         self._listeners: List[Callable[[Dict[str, Any]], None]] = []  # guarded-by: _lock
+        install_sources()
+        with _LOCK:
+            _TRACERS[:] = [ref for ref in _TRACERS if ref() is not None]
+            _TRACERS.append(weakref.ref(self))
 
     # -- recording ------------------------------------------------------
 
@@ -136,9 +458,18 @@ class Tracer:
         return self._local.stack
 
     def _tid(self) -> int:
-        return getattr(
-            self._local, "tid", None
-        ) or threading.get_ident() % 2**31
+        try:
+            return self._local.tid
+        except AttributeError:  # once a thread: a missing attribute is the slow path
+            tid = self._local.tid = threading.get_ident() % 2**31
+            return tid
+
+    def _pid(self) -> int:
+        # the rank cannot change once the backend is up, which the first
+        # call sees to; looked up once, not at every event
+        if self._rank is None:
+            self._rank = _process_index()
+        return self._rank
 
     def next_cycle(self) -> None:
         """Begin the next collect-plus-learn cycle (the trainer calls this
@@ -168,7 +499,9 @@ class Tracer:
         stack = self._stack()
         if self.cycle is not None:
             args.setdefault("cycle", self.cycle)
-        with _profiler_annotation(name, args):  # closes after the fence
+        # the span's twin on the profiler's clock, its args as the event's
+        # stats; a no-op C++ call while no session is open. Closes after the fence
+        with _TraceAnnotation(PROFILER_PREFIX + name, **args):
             sp = Span(name, depth=len(stack), args=args)
             if fence is not None:
                 sp.fence(fence)
@@ -181,39 +514,58 @@ class Tracer:
                 # the depth bookkeeping of outer spans
                 if sp in stack:
                     stack.remove(sp)
-                dur = sp.close()
-                with self._lock:  # worker + main thread both close spans
-                    self._last_duration[name] = dur
+                sp.close()
                 if self.enabled:
                     self._record(sp)
 
-    def instant(self, name: str, **args: Any) -> None:
-        """A zero-duration marker event (Chrome-trace ``"ph": "i"``)."""
-        if not self.enabled:
-            return
-        event = {
-            "name": name,
-            "ph": "i",
-            "ts": (time.perf_counter() - self._epoch) * 1e6,
-            "pid": _process_index(),
-            "tid": self._tid(),
-            "s": "t",
-        }
-        if args:
-            event["args"] = args
-        self._append(event)
+    def attribute(self, kind: str, t0: float, t1: float, seconds: Optional[float] = None,
+                  **args: Any) -> None:
+        """This tracer's end of the sink (:func:`attribute`): the seconds go
+        to the innermost span open on the calling thread, if there is one,
+        and ``[t0, t1]`` becomes a retrospective child event."""
+        stack = self._stack()
+        if stack:
+            stack[-1].add(kind, max(t1 - t0, 0.0) if seconds is None else seconds)
+        self.add_complete_event(kind, t0, t1, **args)
+
+    def _note_gc(self, t0: float, t1: float, generation: int) -> None:
+        """From the collector's callback: no lock, nothing recorded yet."""
+        stack = getattr(self._local, "stack", None)
+        if stack:
+            stack[-1].add("host/gc", t1 - t0)
+        if (self.enabled and (generation == 2 or t1 - t0 >= GC_EVENT_MIN_S)
+                and len(self._gc_pending) < 1024):  # a tracer nobody opens spans on
+            self._gc_pending.append((t0, t1, generation, self._tid()))
+
+    def _flush_gc(self) -> None:
+        while True:
+            try:  # spans close on two threads at once: the pop is atomic, a test before it is not
+                t0, t1, generation, tid = self._gc_pending.pop(0)
+            except IndexError:
+                return
+            self._append({
+                "name": "host/gc", "ph": "X", "ts": (t0 - self._epoch) * 1e6,
+                "dur": (t1 - t0) * 1e6, "pid": self._pid(), "tid": tid,
+                "args": {"generation": generation},
+            })
 
     def _record(self, sp: Span) -> None:
+        if self._gc_pending:  # the collections inside the span, before the span
+            self._flush_gc()
         event = {
             "name": sp.name,
             "ph": "X",
             "ts": (sp.t0 - self._epoch) * 1e6,
             "dur": (sp.t1 - sp.t0) * 1e6,
-            "pid": _process_index(),
+            "pid": self._pid(),
             "tid": self._tid(),
         }
-        if sp.args:
+        if sp.args or sp.attributed or sp.t_fence is not None:
             event["args"] = dict(sp.args)
+            if sp.t_fence is not None:
+                event["args"]["wait_s"] = sp.wait
+            if sp.attributed:
+                event["args"].update(sp.attributed)
         self._append(event)
 
     def _append(self, event: Dict[str, Any]) -> None:
@@ -257,7 +609,7 @@ class Tracer:
             {
                 "name": "thread_name",
                 "ph": "M",
-                "pid": _process_index(),
+                "pid": self._pid(),
                 "tid": tid,
                 "args": {"name": alias},
             }
@@ -280,7 +632,7 @@ class Tracer:
             "ph": "X",
             "ts": (t0 - self._epoch) * 1e6,
             "dur": max(t1 - t0, 0.0) * 1e6,
-            "pid": _process_index(),
+            "pid": self._pid(),
             "tid": self._track_tid(track) if track else self._tid(),
         }
         if args:
@@ -289,11 +641,8 @@ class Tracer:
 
     # -- reading / export ----------------------------------------------
 
-    def last_duration(self, name: str, default: float = 0.0) -> float:
-        """Duration of the most recently closed span named ``name``."""
-        return self._last_duration.get(name, default)
-
     def events(self) -> List[Dict[str, Any]]:
+        self._flush_gc()
         with self._lock:
             return list(self._events)
 
@@ -311,21 +660,3 @@ class Tracer:
         with open(path, "w") as f:
             json.dump(self.to_chrome_trace(), f)
         return path
-
-
-# ---------------------------------------------------------------------------
-# module-level default tracer (library users without a trainer)
-# ---------------------------------------------------------------------------
-
-_DEFAULT_TRACER = Tracer()
-
-
-def get_tracer() -> Tracer:
-    return _DEFAULT_TRACER
-
-
-@contextmanager
-def span(name: str, fence: FenceLike = None, **args: Any) -> Iterator[Span]:
-    """``with span("rollout"): ...`` on the module-level default tracer."""
-    with _DEFAULT_TRACER.span(name, fence=fence, **args) as sp:
-        yield sp

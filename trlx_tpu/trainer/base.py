@@ -19,6 +19,8 @@ at the host boundary (reward/metric fns), and per-rank scatter is
 import json
 import os
 from abc import abstractmethod
+import statistics
+from collections import deque
 from contextlib import ExitStack
 from time import perf_counter, time
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -61,7 +63,7 @@ from trlx_tpu.utils.checkpoint import (
     save_state,
     wait_for_saves,
 )
-from trlx_tpu.observability import Observability, train_step_flops
+from trlx_tpu.observability import Observability, Span, tracing, train_step_flops
 from trlx_tpu.observability import mfu as obs_mfu
 from trlx_tpu.resilience import UPDATE_OK_KEY, Resilience, TrainingPreempted
 from trlx_tpu.utils.trackers import make_tracker
@@ -83,6 +85,78 @@ class TrainState:
     opt_state: Any
     step: jax.Array  # scalar int32
     rng: jax.Array
+
+
+# An interval (a cycle; a step of one width) that takes more than this many
+# times the median of the newest SLOW_HISTORY of its kind gets one line in the
+# log, with what the host and the runtime did in it. The stalled cycles of
+# PERF.md section 6 ran 1.4 to 2.7 times a normal one (2 to 8 s on 3 to 17)
+# and the long train steps 1.25 to 1.45 times (40 to 70 ms on 155 to 190),
+# while a window's normal cycles differ by under 2%. No verdict before
+# SLOW_MIN_HISTORY intervals: the first of a kind compiles.
+SLOW_INTERVAL_RATIO = 1.25
+SLOW_HISTORY = 64
+SLOW_MIN_HISTORY = 3
+
+# parts of an interval in which the host waits for the device
+_WAIT_PARTS = ("generate wait", "score wait", "train_step wait")
+
+
+def attributed_between(m0: Dict[str, float], m1: Dict[str, float]) -> Dict[str, float]:
+    """What a collection or step record says of the host and the runtime in
+    its interval, from the sink's marks (``tracing.mark``) at its two ends:
+    garbage collections on any thread (they stop every thread), seconds in
+    tracing plus lowering, seconds in the backend's compile (cache loads
+    included), the marking thread's CPU seconds, involuntary context
+    switches and major page faults, and the whole process's CPU seconds and
+    switches (the runtime's own threads launch the programs)."""
+    d = tracing.since(m0, m1)
+    return {
+        "host/gc_pause_s": d.get("host/gc", 0.0),
+        "host/gc_gen2": float(d.get("host/gc_gen2", 0)),
+        "runtime/retrace_s": d.get("runtime/trace", 0.0) + d.get("runtime/lower", 0.0),
+        "runtime/compile_s": d.get("runtime/compile", 0.0) + d.get("runtime/cache_load", 0.0),
+        "host/cpu_s": d["host/cpu_s"],
+        "host/invol_switches": float(d["host/invol_switches"]),
+        "host/major_faults": float(d["host/major_faults"]),
+        "host/proc_cpu_s": d["host/proc_cpu_s"],
+        "host/proc_invol_switches": float(d["host/proc_invol_switches"]),
+    }
+
+
+def slow_interval_line(label: str, seconds: float, median: float, parts: Dict[str, float],
+                       history: List[Tuple[float, Dict[str, float]]],
+                       programs: List[str]) -> Tuple[str, str]:
+    """The log line for an interval that ran long, and its verdict: ``gc``,
+    ``retrace``, ``host dispatch``, ``thread not running`` or ``fence wait``.
+    ``parts`` holds the interval's timed pieces by name beside the
+    ``host/*`` and ``runtime/*`` keys of its record; each piece is set
+    against its median over ``history``."""
+    timed = [k for k in parts if "/" not in k and k != "off cpu"]
+    over = {k: parts[k] - statistics.median(h[1].get(k, 0.0) for h in history) for k in parts}
+    gc_s = parts["host/gc_pause_s"]
+    retrace = parts["runtime/retrace_s"] + parts["runtime/compile_s"]
+    waited = sum(over[k] for k in timed if k in _WAIT_PARTS)
+    busy = sum(over[k] for k in timed if k not in _WAIT_PARTS)
+    causes = {
+        "gc": gc_s,
+        "retrace": retrace,
+        "thread not running": over["off cpu"],
+        "host dispatch": busy - gc_s - retrace - over["off cpu"],
+        "fence wait": waited,
+    }
+    verdict = max(causes, key=causes.get)
+    top = sorted(timed, key=over.get, reverse=True)[:3]
+    line = (
+        f"{label}: {seconds:.2f} s against a median of {median:.2f}: "
+        + ", ".join(f"{k} {over[k]:+.2f}" for k in top)
+        + f", gc {gc_s:.2f}, retrace {retrace:.2f} ({', '.join(programs) or 'none'}), "
+        f"cpu {parts['host/cpu_s']:.1f} s (process {parts['host/proc_cpu_s']:.1f}), "
+        f"{int(parts['host/invol_switches'])} involuntary switches (process "
+        f"{int(parts['host/proc_invol_switches'])}), {int(parts['host/major_faults'])} major "
+        f"faults: {verdict}"
+    )
+    return line, verdict
 
 
 def _optimizer_state_shardings(mesh, params: Any, abstract_opt: Any) -> Any:
@@ -291,119 +365,127 @@ class TPUBaseTrainer(BaseRLTrainer):
         # the serving frontend (trlx_tpu/serve/, docs/SERVING.md); built in
         # learn() when serve.enabled, drained in _shutdown_collectors
         self._serve = None
+        # runtime observability: span tracer, metrics registry, recompile/
+        # memory watchdogs, profiler window (docs/OBSERVABILITY.md). First, so
+        # that what building the model traces and compiles lands under a span
+        self.obs = Observability(config)
         self.mesh = make_mesh(config.parallel)
         set_global_mesh(self.mesh)  # model code reads this for sequence-parallel ops
         # NOTE: the global mesh is process-wide; entry points re-assert it so
         # two trainers in one process don't trace against each other's mesh
-        self.tokenizer = tokenizer_from_config(config.tokenizer)
+        with self.obs.span("setup/tokenizer"):
+            self.tokenizer = tokenizer_from_config(config.tokenizer)
 
-        two_qs = bool(getattr(config.method, "two_qs", True))
-        # seq2seq (T5) vs causal arch selection (reference ``get_arch``,
-        # ``accelerate_ppo_trainer.py:120-134``)
-        self.is_seq2seq = config.model.model_arch_type == "seq2seq"
-        if self.is_seq2seq:
-            from trlx_tpu.models.builder import build_seq2seq_lm, seq2seq_trainable_mask
+        with self.obs.span("setup/init_model") as init_sp:
+            two_qs = bool(getattr(config.method, "two_qs", True))
+            # seq2seq (T5) vs causal arch selection (reference ``get_arch``,
+            # ``accelerate_ppo_trainer.py:120-134``)
+            self.is_seq2seq = config.model.model_arch_type == "seq2seq"
+            if self.is_seq2seq:
+                from trlx_tpu.models.builder import build_seq2seq_lm, seq2seq_trainable_mask
 
-            build, mask_fn = build_seq2seq_lm, seq2seq_trainable_mask
-        else:
-            build, mask_fn = build_causal_lm, trainable_mask
-        self.module, params, self.tcfg = build(
-            config.model,
-            config.parallel,
-            head=self.model_head,
-            two_qs=two_qs,
-            seed=config.train.seed,
-            abstract=abstract_init,
-        )
-        if not abstract_init:
-            params = shard_params(params, self.mesh)
-        self.param_mask = mask_fn(params, self.tcfg, config.model.num_layers_unfrozen)
-        self.draft_module = self.draft_params = self.draft_tcfg = None
-        self.last_spec_stats: Dict[str, float] = {}
-        self.last_cache_stats: Dict[str, float] = {}
-        # the serial dense sampler's static cache extents for the newest
-        # generate() call (ops/sampling.py::kv_extents); None on the paths
-        # that read every slot (seq2seq, speculative)
-        self.last_kv_extents: Optional[Tuple[int, ...]] = None
-        # beside them, each layer's cache slots and whether it has a window
-        # (a window layer's cache is a ring of min(S, window) slots)
-        self.last_kv_layers: Optional[Tuple[Tuple[int, bool], ...]] = None
-        self.last_generate_time = 0.0
-        # where the host gap before the next train step began (perf_counter):
-        # the end of the last step's fence, or of the collection before it
-        self._host_gap_t0: Optional[float] = None
-        if config.model.draft_model_path and self.is_seq2seq:
-            logger.warning(
-                "model.draft_model_path is ignored for seq2seq models: "
-                "speculative decoding is implemented for causal LMs only"
-            )
-        elif config.model.draft_model_path:
-            from trlx_tpu.data.configs import ModelConfig as _MC
-
-            # the draft always runs UNPIPELINED: under a pipe>1 mesh it
-            # computes replicated across stages while the pipelined target
-            # verifies its proposals (per-row cache depths flow through the
-            # microbatch schedule via parallel/pipeline.py's cache_index
-            # slicing)
-            draft_extra = dict(config.model.draft_model_extra_kwargs)
-            draft_extra["ignore_pipe_mesh"] = True
-            self.draft_module, draft_params, self.draft_tcfg = build_causal_lm(
-                _MC(
-                    model_path=config.model.draft_model_path,
-                    model_extra_kwargs=draft_extra,
-                ),
+                build, mask_fn = build_seq2seq_lm, seq2seq_trainable_mask
+            else:
+                build, mask_fn = build_causal_lm, trainable_mask
+            self.module, params, self.tcfg = build(
+                config.model,
                 config.parallel,
-                head=None,
-                seed=config.train.seed + 1,
+                head=self.model_head,
+                two_qs=two_qs,
+                seed=config.train.seed,
                 abstract=abstract_init,
             )
-            if self.draft_tcfg.vocab_size != self.tcfg.vocab_size:
-                raise ValueError(
-                    f"draft vocab {self.draft_tcfg.vocab_size} != policy vocab "
-                    f"{self.tcfg.vocab_size}: speculative decoding needs a "
-                    "same-tokenizer draft"
+            if not abstract_init:
+                params = shard_params(params, self.mesh)
+            self.param_mask = mask_fn(params, self.tcfg, config.model.num_layers_unfrozen)
+            self.draft_module = self.draft_params = self.draft_tcfg = None
+            self.last_spec_stats: Dict[str, float] = {}
+            self.last_cache_stats: Dict[str, float] = {}
+            # the serial dense sampler's static cache extents for the newest
+            # generate() call (ops/sampling.py::kv_extents); None on the paths
+            # that read every slot (seq2seq, speculative)
+            self.last_kv_extents: Optional[Tuple[int, ...]] = None
+            # beside them, each layer's cache slots and whether it has a window
+            # (a window layer's cache is a ring of min(S, window) slots)
+            self.last_kv_layers: Optional[Tuple[Tuple[int, bool], ...]] = None
+            # the newest generate() call's span: duration, dispatch, wait
+            self.last_generate_span: Optional[Span] = None
+            # where the host gap before the next train step began (perf_counter):
+            # the end of the last step's fence, or of the collection before it
+            self._host_gap_t0: Optional[float] = None
+            if config.model.draft_model_path and self.is_seq2seq:
+                logger.warning(
+                    "model.draft_model_path is ignored for seq2seq models: "
+                    "speculative decoding is implemented for causal LMs only"
                 )
-            self.draft_params = (
-                draft_params if abstract_init else shard_params(draft_params, self.mesh)
+            elif config.model.draft_model_path:
+                from trlx_tpu.data.configs import ModelConfig as _MC
+
+                # the draft always runs UNPIPELINED: under a pipe>1 mesh it
+                # computes replicated across stages while the pipelined target
+                # verifies its proposals (per-row cache depths flow through the
+                # microbatch schedule via parallel/pipeline.py's cache_index
+                # slicing)
+                draft_extra = dict(config.model.draft_model_extra_kwargs)
+                draft_extra["ignore_pipe_mesh"] = True
+                self.draft_module, draft_params, self.draft_tcfg = build_causal_lm(
+                    _MC(
+                        model_path=config.model.draft_model_path,
+                        model_extra_kwargs=draft_extra,
+                    ),
+                    config.parallel,
+                    head=None,
+                    seed=config.train.seed + 1,
+                    abstract=abstract_init,
+                )
+                if self.draft_tcfg.vocab_size != self.tcfg.vocab_size:
+                    raise ValueError(
+                        f"draft vocab {self.draft_tcfg.vocab_size} != policy vocab "
+                        f"{self.tcfg.vocab_size}: speculative decoding needs a "
+                        "same-tokenizer draft"
+                    )
+                self.draft_params = (
+                    draft_params if abstract_init else shard_params(draft_params, self.mesh)
+                )
+
+            default_lr = config.optimizer.kwargs.get("lr")
+            self.schedule = get_scheduler(
+                config.scheduler.name, dict(config.scheduler.kwargs), default_lr=default_lr
             )
-
-        default_lr = config.optimizer.kwargs.get("lr")
-        self.schedule = get_scheduler(
-            config.scheduler.name, dict(config.scheduler.kwargs), default_lr=default_lr
-        )
-        self.optimizer = get_optimizer(
-            config.optimizer.name,
-            dict(config.optimizer.kwargs),
-            schedule=self.schedule,
-            mask=self.param_mask,
-        )
-        # Optimizer state gets *explicit* shardings: moment tensors follow
-        # their parameter's sharding (FSDP: ZeRO-sharded optimizer state),
-        # quantized int8 moments shard their block dim, scalars/bookkeeping
-        # replicate. Without out_shardings the compiler may leave the whole
-        # state on one device — and checkpoint restore then commits that
-        # placement, breaking later steps.
-        if abstract_init:
-            opt_state = jax.eval_shape(self.optimizer.init, params)
-        else:
-            opt_shardings = _optimizer_state_shardings(
-                self.mesh, params, jax.eval_shape(self.optimizer.init, params)
+            self.optimizer = get_optimizer(
+                config.optimizer.name,
+                dict(config.optimizer.kwargs),
+                schedule=self.schedule,
+                mask=self.param_mask,
             )
-            opt_state = jax.jit(self.optimizer.init, out_shardings=opt_shardings)(params)
-        from jax.sharding import NamedSharding, PartitionSpec
+            # Optimizer state gets *explicit* shardings: moment tensors follow
+            # their parameter's sharding (FSDP: ZeRO-sharded optimizer state),
+            # quantized int8 moments shard their block dim, scalars/bookkeeping
+            # replicate. Without out_shardings the compiler may leave the whole
+            # state on one device — and checkpoint restore then commits that
+            # placement, breaking later steps.
+            if abstract_init:
+                opt_state = jax.eval_shape(self.optimizer.init, params)
+            else:
+                opt_shardings = _optimizer_state_shardings(
+                    self.mesh, params, jax.eval_shape(self.optimizer.init, params)
+                )
+                opt_state = jax.jit(self.optimizer.init, out_shardings=opt_shardings)(params)
+            from jax.sharding import NamedSharding, PartitionSpec
 
-        from trlx_tpu.parallel.sharding import put_global
+            from trlx_tpu.parallel.sharding import put_global
 
-        replicated = NamedSharding(self.mesh, PartitionSpec())
-        rng = jax.random.PRNGKey(config.train.seed)
-        rollout_rng, state_rng = jax.random.split(rng)
-        self.state = TrainState(
-            params=params,
-            opt_state=opt_state,
-            step=put_global(jnp.zeros((), jnp.int32), replicated),
-            rng=put_global(state_rng, replicated),
-        )
-        self._rollout_rng = rollout_rng
+            replicated = NamedSharding(self.mesh, PartitionSpec())
+            rng = jax.random.PRNGKey(config.train.seed)
+            rollout_rng, state_rng = jax.random.split(rng)
+            self.state = TrainState(
+                params=params,
+                opt_state=opt_state,
+                step=put_global(jnp.zeros((), jnp.int32), replicated),
+                rng=put_global(state_rng, replicated),
+            )
+            self._rollout_rng = rollout_rng
+        self.obs.setup.init_model_s = init_sp.duration
 
         # generation settings (reference: accelerate_base_trainer.py:176-198)
         self.generate_kwargs = dict(config.method.gen_kwargs)
@@ -418,9 +500,6 @@ class TPUBaseTrainer(BaseRLTrainer):
         self._last_batch_host: Any = None
         self._last_batch_sharded: Any = None
 
-        # runtime observability: span tracer, metrics registry, recompile/
-        # memory watchdogs, profiler window (docs/OBSERVABILITY.md)
-        self.obs = Observability(config)
         # resilience: preemption handler, update guard, host-call hardening,
         # fault plan (docs/RESILIENCE.md). Shares the metrics registry so
         # every resilience/* counter rides the tracker stream. reward_fn is
@@ -441,6 +520,13 @@ class TPUBaseTrainer(BaseRLTrainer):
         self._emergency_resume = False
         self._prompt_chunks_drawn = 0
         self._triage_dumps = 0
+        # the sink's totals where the host gap began (tracing.mark): a step
+        # record carries the difference to its own fence
+        self._step_mark: Optional[Dict[str, float]] = None
+        # the newest intervals of each kind (a cycle; a step of one width),
+        # seconds and parts, for the slow-interval line
+        self._intervals: Dict[Any, deque] = {}
+        self._cycle: Optional[Dict[str, Any]] = None  # the cycle being added up
 
     # ------------------------------------------------------------------
     # subclass contract
@@ -1316,7 +1402,7 @@ class TPUBaseTrainer(BaseRLTrainer):
                     ),
                 }
             sp.fence((out.sequences, out.response_tokens))
-        self.last_generate_time = sp.duration
+        self.last_generate_span = sp
         self.obs.recompile.observe("generate", engine._fn)
         return out
 
@@ -1362,7 +1448,11 @@ class TPUBaseTrainer(BaseRLTrainer):
             self.last_kv_extents = kv_extents(P, gen_config.max_new_tokens)
 
         def cache(tcfg, slots):
-            return jax.eval_shape(lambda: make_kv_cache(tcfg, B, slots))
+            def kv_cache_shapes():  # named: eval_shape traces it anew at every
+                # call, which the records show under this name (runtime/retrace_s)
+                return make_kv_cache(tcfg, B, slots)
+
+            return jax.eval_shape(kv_cache_shapes)
 
         policy_cache = cache(self.tcfg, S)
         state = recurrent_state_bytes(policy_cache)
@@ -1910,12 +2000,17 @@ class TPUBaseTrainer(BaseRLTrainer):
                 f"emergency resume: fast-forwarding to update {skip_target}"
             )
         else:
-            results = self.evaluate()
+            with self.obs.span("setup/first_eval") as eval_sp:
+                results = self.evaluate()
+            self.obs.setup.first_eval_s = eval_sp.duration
+            self.obs.setup.first_eval_end = eval_sp.t1
             self.tracker.log(results, step=self.iter_count)
             self._report_sweep(results)
         clock = Clock()
         if self._host_gap_t0 is None:  # no collection came before the loop
             self._host_gap_t0 = perf_counter()
+        if self._step_mark is None:
+            self._step_mark = tracing.mark()
 
         tbar = logging.tqdm(
             initial=self.iter_count,
@@ -1970,6 +2065,13 @@ class TPUBaseTrainer(BaseRLTrainer):
                             # fence the timer reads async dispatch latency
                             sp.fence((self.state, device_stats))
                     self._host_gap_t0 = sp.t1
+                    # what the runtime, the collector and the scheduler did
+                    # from the previous fence (or the end of the collection)
+                    # to this one: the interval time/step_gap and
+                    # time/train_step tile
+                    step_mark = tracing.mark()
+                    attributed = attributed_between(self._step_mark, step_mark)
+                    self._step_mark = step_mark
                     # everything the host does until the next step's span
                     # opens, or until the post-epoch collection
                     step_host.enter_context(self.obs.span("learn/step_host"))
@@ -2000,11 +2102,15 @@ class TPUBaseTrainer(BaseRLTrainer):
                     # the collection) and this step's span: with
                     # time/train_step it tiles the learn phase
                     stats["time/step_gap"] = step_gap
+                    stats["time/train_step_dispatch"] = sp.dispatch
+                    stats["time/train_step_wait"] = sp.wait
+                    stats.update(attributed)
                     real_tokens, fed_tokens, width = self._batch_token_counts(batch)
                     stats["learn/pad_frac"] = (
                         1.0 - real_tokens / fed_tokens if fed_tokens else 0.0
                     )
                     stats["learn/step_width"] = float(width)
+                    self._note_step(stats, attributed, width, sp.t1)
                     (
                         stats["learn/attn_visited_frac"],
                         stats["learn/attn_tile"],
@@ -2155,6 +2261,71 @@ class TPUBaseTrainer(BaseRLTrainer):
         self._export_observability()
         self.tracker.finish()
         return results
+
+    # ------------------------------------------------------------------
+    # slow intervals (docs/OBSERVABILITY.md "A slow interval names its cause")
+    # ------------------------------------------------------------------
+
+    def _note_interval(self, kind: str, key: Any, label: str, seconds: float,
+                       parts: Dict[str, float], t0: float, t1: float) -> None:
+        """One more interval of ``kind`` (``cycle``; ``step``, compared at
+        equal width ``key``). One that ran long is logged once with its
+        attribution, counted, and kept in the flight recorder's ring."""
+        history = self._intervals.setdefault((kind, key), deque(maxlen=SLOW_HISTORY))
+        # wall less wait less CPU: the thread was runnable and not running,
+        # or blocked in a call that is not the fence
+        waited = sum(parts.get(k, 0.0) for k in _WAIT_PARTS)
+        parts = {**parts, "off cpu": max(seconds - waited - parts["host/cpu_s"], 0.0)}
+        median = statistics.median(h[0] for h in history) if history else 0.0
+        if len(history) >= SLOW_MIN_HISTORY and seconds > SLOW_INTERVAL_RATIO * median:
+            retraced = parts["runtime/retrace_s"] + parts["runtime/compile_s"] > 0
+            line, verdict = slow_interval_line(
+                label, seconds, median, parts, list(history),
+                tracing.recent_programs(t0, t1) if retraced else [])
+            logger.warning(line)
+            self.obs.metrics.inc(f"host/slow_{kind}s")
+            self.obs.flightrec.record(
+                "slow_interval", {"kind": kind, "verdict": verdict, "line": line, **parts})
+        history.append((seconds, parts))
+
+    def _note_step(self, stats: Dict[str, float], attributed: Dict[str, float], width: int,
+                   t_fence: float) -> None:
+        """A step record's interval: previous fence to this one."""
+        seconds = stats["time/step_gap"] + stats["time/train_step"]
+        parts = {
+            "train_step wait": stats["time/train_step_wait"],
+            "train_step dispatch": stats["time/train_step_dispatch"],
+            "step gap": stats["time/step_gap"],
+            **attributed,
+        }
+        self._note_interval("step", width, f"step {self.iter_count}", seconds, parts,
+                            t_fence - seconds, t_fence)
+        if self._cycle is not None:
+            self._cycle["seconds"] += seconds
+            for k, v in parts.items():
+                self._cycle["parts"][k] = self._cycle["parts"].get(k, 0.0) + v
+
+    def _open_cycle(self, record: Dict[str, float], attributed: Dict[str, float],
+                    t0: float) -> None:
+        """A collection's record opens its cycle's account; the steps add to
+        it and the next collection closes it."""
+        self._cycle = {
+            "n": self.obs.tracer.cycle, "t0": t0, "seconds": record["time/exp"],
+            "parts": {
+                "generate wait": record.get("time/generate_wait", 0.0),
+                "generate dispatch": record.get("time/generate_dispatch", 0.0),
+                "score wait": record.get("time/score", 0.0),
+                "reward": record.get("time/reward", 0.0),
+                "collect host": record.get("time/collect_host", 0.0),
+                **attributed,
+            },
+        }
+
+    def _close_cycle(self) -> None:
+        cycle, self._cycle = self._cycle, None
+        if cycle is not None and "step gap" in cycle["parts"]:
+            self._note_interval("cycle", None, f"cycle {cycle['n']}", cycle["seconds"],
+                                cycle["parts"], cycle["t0"], cycle["t0"] + cycle["seconds"])
 
     # ------------------------------------------------------------------
     # checkpointing

@@ -46,7 +46,8 @@ from trlx_tpu.parallel import shard_batch
 from trlx_tpu.pipeline import BasePipeline
 from trlx_tpu.pipeline.ppo_pipeline import PPORolloutStorage, length_ladder
 from trlx_tpu.trainer import register_trainer
-from trlx_tpu.trainer.base import TPUBaseTrainer
+from trlx_tpu.observability import tracing
+from trlx_tpu.trainer.base import TPUBaseTrainer, attributed_between
 from trlx_tpu.utils import infinite_loader, logging, to_host
 from trlx_tpu.utils.stats import RunningMoments, logprobs_of_labels
 
@@ -523,7 +524,9 @@ class PPOTrainer(TPUBaseTrainer):
             stats,
             {
                 "time/exp_generate": perf_counter() - gen_time,
-                "time/generate": self.last_generate_time,
+                "time/generate": self.last_generate_span.duration,
+                "time/generate_dispatch": self.last_generate_span.dispatch,
+                "time/generate_wait": self.last_generate_span.wait,
             },
         )
         stats.update(self.last_spec_stats)
@@ -1374,8 +1377,12 @@ class PPOTrainer(TPUBaseTrainer):
             "kv_slots_read": 0, "kv_slots": 0,
             "kv_window_slots_read": 0, "kv_window_slots": 0,
         }
+        self._close_cycle()
+        if self.obs.tracer.cycle == 1:  # the second collection: set-up is over
+            self.obs.freeze_setup()
         self.obs.tracer.next_cycle()
         with self.obs.span("collect/experience"):
+            mark = tracing.mark()
             exp_time = perf_counter()
 
             if bool(self.config.async_rl.enabled):
@@ -1395,9 +1402,19 @@ class PPOTrainer(TPUBaseTrainer):
             with self.obs.span("collect/finalize", stage="collection"):
                 self.mean_kl = acc["kl_sum"] / max(acc["kl_batches"], 1)
                 stats["time/rollout_host"] = acc["host_s"]
-                self._host_gap_t0 = perf_counter()  # the first step's gap starts here
+                # the first step's gap starts here
+                self._step_mark = tracing.mark()
+                self._host_gap_t0 = self._step_mark["t"]
                 total = self._host_gap_t0 - exp_time
                 stats["time/exp"] = total
+                # what the host and the runtime did meanwhile, on any thread
+                attributed = attributed_between(mark, self._step_mark)
+                stats.update(attributed)
+                # the fenced generate spans' two halves, summed like every
+                # time/* key (the chunked paths have them; actors and the
+                # slot-refill engine generate off this thread's clock)
+                stats.setdefault("time/generate_dispatch", 0.0)
+                stats.setdefault("time/generate_wait", 0.0)
                 # the collection's self time: wall time the main thread spent in
                 # none of generate, reward_fn, or the wait for the scoring outputs
                 # (defined on the chunked paths; actors and the slot-refill engine
@@ -1461,6 +1478,9 @@ class PPOTrainer(TPUBaseTrainer):
                         "rollout/repetition_frac", acc["rep_pairs"] / acc["rep_total"]
                     )
                 self._collection_summary(stats, acc)
+                if self.obs.setup.first_collect_s is None:
+                    self.obs.setup.first_collect_s = total
+                self._open_cycle(stats, attributed, exp_time)
                 self.make_experience_stats = stats
                 self.tracker.log(stats, step=iter_count)
 

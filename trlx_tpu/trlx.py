@@ -10,6 +10,7 @@ are preserved exactly:
 """
 
 import os
+import time
 import warnings
 from typing import Callable, Dict, Iterable, List, Optional, Tuple
 
@@ -22,6 +23,22 @@ from trlx_tpu.data.default_configs import (
 from trlx_tpu.utils import set_seed
 
 _runtime_initialized = False
+_T_IMPORTED = time.perf_counter()
+
+
+def process_age_s() -> float:
+    """Seconds since the process started: its start time from
+    ``/proc/self/stat`` against the boot clock where both exist, else since
+    this module was imported."""
+    try:
+        with open("/proc/self/stat") as f:
+            start_ticks = int(f.read().rsplit(")", 1)[1].split()[19])  # field 22
+        age = time.clock_gettime(time.CLOCK_BOOTTIME) - start_ticks / os.sysconf("SC_CLK_TCK")
+        if age >= 0:
+            return age
+    except (OSError, ValueError, IndexError, AttributeError):
+        pass
+    return time.perf_counter() - _T_IMPORTED
 
 
 def compile_cache_dir() -> Optional[str]:
@@ -91,6 +108,11 @@ def initialize_runtime() -> None:
     _runtime_initialized = True
 
     import jax
+
+    from trlx_tpu.observability import tracing
+
+    # before the first program is traced: set-up's account starts here
+    tracing.install_sources()
 
     cache_dir = compile_cache_dir()
     if cache_dir is not None:
@@ -163,7 +185,14 @@ def train(  # noqa: C901
     # Import for registration side effects (trainers/pipelines register here).
     import importlib
 
+    # set-up's account (docs/OBSERVABILITY.md "Set-up"): the trainer's tracer
+    # does not exist yet, so the first two spans are recorded once it does
+    t_train, import_s = time.perf_counter(), process_age_s()
     initialize_runtime()
+    t_runtime = time.perf_counter()
+    from trlx_tpu.observability import tracing
+
+    setup_mark, setup_programs = tracing.mark(), tracing.programs()
 
     for module in (
         "trlx_tpu.pipeline.offline_pipeline",
@@ -205,6 +234,10 @@ def train(  # noqa: C901
         stop_sequences=stop_sequences or [],
         **config.train.trainer_kwargs,
     )
+    trainer.obs.setup.begin(t_train, import_s, setup_mark, setup_programs)
+    tracer = trainer.obs.tracer
+    tracer.add_complete_event("setup/runtime_init", t_train, t_runtime)
+    tracer.add_complete_event("setup/build_trainer", t_runtime, time.perf_counter())
     if init_trainer_hook is not None:
         init_trainer_hook(trainer)
 
@@ -217,10 +250,11 @@ def train(  # noqa: C901
         if eval_prompts is None:
             eval_prompts = prompts[:batch_size]
 
-        pipeline = get_pipeline(config.train.pipeline)(
-            prompts, max_prompt_length, trainer.tokenizer
-        )
-        trainer.add_prompt_pipeline(pipeline)
+        with trainer.obs.span("setup/pipelines", which="prompts"):
+            pipeline = get_pipeline(config.train.pipeline)(
+                prompts, max_prompt_length, trainer.tokenizer
+            )
+            trainer.add_prompt_pipeline(pipeline)
         # restore BEFORE collecting rollouts: PPO behavior logprobs must come
         # from the restored policy, not the freshly initialized one
         if hasattr(trainer, "maybe_resume"):
@@ -240,10 +274,11 @@ def train(  # noqa: C901
     else:
         raise ValueError("Either `samples` or `reward_fn` should be given for training")
 
-    eval_pipeline = get_pipeline(config.train.pipeline)(
-        eval_prompts, max_prompt_length, trainer.tokenizer
-    )
-    trainer.add_eval_pipeline(eval_pipeline)
+    with trainer.obs.span("setup/pipelines", which="eval"):
+        eval_pipeline = get_pipeline(config.train.pipeline)(
+            eval_prompts, max_prompt_length, trainer.tokenizer
+        )
+        trainer.add_eval_pipeline(eval_pipeline)
 
     trainer.learn()
     return trainer
