@@ -140,9 +140,15 @@ def test_collection_keys_are_sums_over_chunks(run):
 
 # sha256 over every element's fields, in order, of the first collection's
 # store, recorded at the parent of PR 29 (commit 362c28d), where PPO and GRPO
-# each had a collector of their own; the pipelined runs must give the same
+# each had a collector of their own; the pipelined runs must give the same.
+# PPO's was pinned anew at PR 36 (5cd08ee2... until then): the parameters are
+# built by one program since, in which XLA folds an initializer's
+# `sqrt(2) * erf_inv(u) * std` into one multiplication, so a float32 weight
+# may differ from the eager walk's by one unit in the last place
+# (tests/test_setup_programs.py), and the value head's outputs with it;
+# GRPO's tokens, logprobs and rewards did not move
 STORE_DIGESTS = {
-    "ppo": "5cd08ee277f2a218b4e32fb88d4f073511714c64327c7839d767a405825b1879",
+    "ppo": "e98931770ccfe926fd4badd5903c4a36994a874f32dbf9c5f7e8e4c8bc7acf42",
     "grpo": "21fd5cfe4e3c9743b509d6fe3c51fe34ed3c57e270b1e8c24ea5fb060cd47091",
 }
 

@@ -12,6 +12,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from trlx_tpu.data.configs import ModelConfig, ParallelConfig
 from trlx_tpu.models.heads import CausalLMWithILQLHeads, CausalLMWithValueHead
@@ -20,6 +21,7 @@ from trlx_tpu.models.transformer import (
     TransformerConfig,
     config_from_spec,
 )
+from trlx_tpu.parallel.sharding import param_shardings, shard_params
 
 DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16, "float16": jnp.float16}
 
@@ -75,8 +77,6 @@ def merge_trees(base: Dict[str, Any], override: Dict[str, Any]) -> Dict[str, Any
 def merge_lora_params(params: Dict[str, Any], cfg) -> Dict[str, Any]:
     """Fold trained adapters into their kernels (``W += (alpha/r)·AB``) and
     drop the lora leaves — for HF-format export of a LoRA-tuned model."""
-    import numpy as np
-
     if not getattr(cfg, "lora_r", 0):
         return params  # nothing to fold
     scale = cfg.lora_alpha / cfg.lora_r
@@ -131,6 +131,30 @@ def _import_hf_backbone(params, head, backbone_numpy, param_dtype):
     return params
 
 
+def _build_params(make_params, seed: int, mesh, abstract: bool, load_backbone=None):
+    """``make_params(seed)`` (initializers, target-Q sync; the dummy forward
+    that ``module.init`` traces is dead code, pruned before lowering) as ONE
+    jitted program whose outputs are born under ``param_shardings`` when a
+    ``mesh`` is given, instead of an eager walk that compiles a program an
+    operation at every start. The seed is an argument, so one compiled
+    program serves every seed (a constant would be a new program, and a new
+    compile, a seed). ``abstract`` stops at the shapes (the trace they cost
+    is the one ``jit`` then finds in its cache). ``load_backbone(params)``
+    overlays pretrained host arrays, which are then placed leaf by leaf."""
+    # an int64 scalar becomes the int32 that ``PRNGKey(<Python int>)`` makes
+    seed = np.int64(seed)
+    shapes = jax.eval_shape(make_params, seed)
+    if abstract:
+        return shapes
+    out_shardings = param_shardings(shapes, mesh) if mesh is not None else None
+    params = jax.jit(make_params, out_shardings=out_shardings)(seed)
+    if load_backbone is not None:
+        params = load_backbone(params)
+        if mesh is not None:
+            params = shard_params(params, mesh)
+    return params
+
+
 def resolve_transformer_config(
     model_config: ModelConfig, parallel: Optional[ParallelConfig] = None
 ) -> Tuple[TransformerConfig, Optional[str]]:
@@ -159,9 +183,11 @@ def build_causal_lm(
     two_qs: bool = True,
     seed: int = 0,
     abstract: bool = False,
+    mesh: Optional[Any] = None,
 ) -> Tuple[Any, Dict[str, Any], TransformerConfig]:
     """Build module + params. Pretrained weights (HF torch) replace the
-    backbone subtree; heads stay freshly initialized.
+    backbone subtree; heads stay freshly initialized. With a ``mesh`` the
+    params come back placed per ``parallel.sharding.param_shardings``.
 
     ``abstract=True`` returns a ``ShapeDtypeStruct`` pytree instead of real
     arrays (and skips any pretrained-weight load): enough to lower/compile
@@ -176,11 +202,8 @@ def build_causal_lm(
     else:
         module = CausalTransformer(tcfg)
 
-    rng = jax.random.PRNGKey(seed)
-    dummy = jnp.zeros((1, 8), jnp.int32)
-
-    def make_params():
-        p = module.init(rng, dummy)["params"]
+    def make_params(seed):
+        p = module.init(jax.random.PRNGKey(seed), jnp.zeros((1, 8), jnp.int32))["params"]
         if head == "ilql":
             # target-Q heads start as exact copies of the Q heads (reference
             # deepcopies them at init, modeling_ilql.py:154) — training toward
@@ -190,12 +213,7 @@ def build_causal_lm(
             p = sync_target_q_params(p, alpha=1.0)
         return p
 
-    if abstract:
-        return module, jax.eval_shape(make_params), tcfg
-
-    params = make_params()
-
-    if hf_path is not None:
+    def load_backbone(params):
         from trlx_tpu.models.hf_interop import load_pretrained
 
         hf_params, _ = load_pretrained(hf_path)
@@ -204,7 +222,11 @@ def build_causal_lm(
             from trlx_tpu.models.transformer import stack_layer_params
 
             backbone = stack_layer_params(backbone, tcfg.num_layers)
-        params = _import_hf_backbone(params, head, backbone, tcfg.param_dtype)
+        return _import_hf_backbone(params, head, backbone, tcfg.param_dtype)
+
+    params = _build_params(
+        make_params, seed, mesh, abstract, load_backbone if hf_path is not None else None
+    )
     return module, params, tcfg
 
 
@@ -260,8 +282,6 @@ def _mask_heads(subtree):
 def _scan_layer_vector(tcfg, num_layers_unfrozen: int):
     """Per-layer 0/1 trainability over the stacked layer dim, or None when
     every layer trains (``num_layers_unfrozen == -1``)."""
-    import numpy as np
-
     if num_layers_unfrozen < 0:
         return None
     vec = np.zeros(tcfg.num_layers, np.float32)
@@ -382,6 +402,7 @@ def build_seq2seq_lm(
     two_qs: bool = True,
     seed: int = 0,
     abstract: bool = False,
+    mesh: Optional[Any] = None,
 ):
     """Build seq2seq module + params (pretrained backbone import, fresh heads).
 
@@ -399,28 +420,27 @@ def build_seq2seq_lm(
     else:
         module = T5Transformer(scfg)
 
-    rng = jax.random.PRNGKey(seed)
-    enc = jnp.zeros((1, 8), jnp.int32)
-    dec = jnp.zeros((1, 4), jnp.int32)
-
-    def make_params():
-        p = module.init(rng, enc, decoder_input_ids=dec)["params"]
+    def make_params(seed):
+        p = module.init(
+            jax.random.PRNGKey(seed),
+            jnp.zeros((1, 8), jnp.int32),
+            decoder_input_ids=jnp.zeros((1, 4), jnp.int32),
+        )["params"]
         if head == "ilql":
             from trlx_tpu.models.heads import sync_target_q_params
 
             p = sync_target_q_params(p, alpha=1.0)
         return p
 
-    if abstract:
-        return module, jax.eval_shape(make_params), scfg
-
-    params = make_params()
-
-    if hf_path is not None:
+    def load_backbone(params):
         from trlx_tpu.models.hf_interop import load_pretrained_seq2seq
 
         hf_params, _ = load_pretrained_seq2seq(hf_path)
-        params = _import_hf_backbone(params, head, hf_params["backbone"], scfg.param_dtype)
+        return _import_hf_backbone(params, head, hf_params["backbone"], scfg.param_dtype)
+
+    params = _build_params(
+        make_params, seed, mesh, abstract, load_backbone if hf_path is not None else None
+    )
     return module, params, scfg
 
 

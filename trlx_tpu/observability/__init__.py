@@ -202,8 +202,9 @@ class Observability:
             "setup/cache_load_s": d.get("runtime/cache_load", 0.0),
             "setup/gc_pause_s": d.get("host/gc", 0.0),
             # backend compile events; of them, executables the persistent
-            # cache gave and executables compiled and written to it (the rest
-            # compiled too fast to be kept, and compile again at every start)
+            # cache gave and executables compiled and written to it. The rest
+            # were compiled and not kept (no cache directory, or a floor on
+            # the compile time: trlx.initialize_runtime() sets it to 0)
             "setup/programs": d.get("runtime/programs", 0.0),
             "setup/cache_hits": d.get("runtime/cache_hits", 0.0),
             "setup/cache_misses": d.get("runtime/cache_misses", 0.0),
@@ -215,13 +216,16 @@ class Observability:
         logger.info(
             "set-up %.1f s: import %.1f, build %.1f (init_model %.1f), first eval %.1f, "
             "first cycle %.1f; of these the runtime took trace+lower %.1f, compile %.1f, "
-            "cache load %.1f (%d programs: %d from the cache, %d written to it) and the "
-            "collector %.1f\n%s",
+            "cache load %.1f (%d programs: %d from the cache, %d written to it, %d "
+            "compiled in all) and the collector %.1f\n%s",
             gauges["setup/total_s"], gauges["setup/import_s"], gauges["setup/build_s"],
             gauges["setup/init_model_s"], gauges["setup/first_eval_s"],
             gauges["setup/first_cycle_s"], gauges["setup/trace_lower_s"],
             gauges["setup/compile_s"], gauges["setup/cache_load_s"], gauges["setup/programs"],
             gauges["setup/cache_hits"], gauges["setup/cache_misses"],
+            # what a start that found its programs in the cache still compiled:
+            # 0 when set-up runs nothing the cache does not keep
+            gauges["setup/programs"] - gauges["setup/cache_hits"],
             gauges["setup/gc_pause_s"], tracing.programs_table(s.programs),
         )
 

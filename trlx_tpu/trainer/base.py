@@ -42,7 +42,7 @@ from trlx_tpu.ops.sampling import (
     generate_seq2seq,
     kv_extents,
 )
-from trlx_tpu.parallel import make_mesh, set_global_mesh, shard_batch, shard_params
+from trlx_tpu.parallel import make_mesh, set_global_mesh, shard_batch
 from trlx_tpu.pipeline import BasePipeline
 from trlx_tpu.trainer import BaseRLTrainer
 from trlx_tpu.utils import (
@@ -394,9 +394,8 @@ class TPUBaseTrainer(BaseRLTrainer):
                 two_qs=two_qs,
                 seed=config.train.seed,
                 abstract=abstract_init,
+                mesh=self.mesh,
             )
-            if not abstract_init:
-                params = shard_params(params, self.mesh)
             self.param_mask = mask_fn(params, self.tcfg, config.model.num_layers_unfrozen)
             self.draft_module = self.draft_params = self.draft_tcfg = None
             self.last_spec_stats: Dict[str, float] = {}
@@ -408,6 +407,8 @@ class TPUBaseTrainer(BaseRLTrainer):
             # beside them, each layer's cache slots and whether it has a window
             # (a window layer's cache is a ring of min(S, window) slots)
             self.last_kv_layers: Optional[Tuple[Tuple[int, bool], ...]] = None
+            # the cache pytree's shapes by (config, rows, slots), for the gauges
+            self._kv_cache_shapes: Dict[Tuple[Any, int, int], Any] = {}
             # the newest generate() call's span: duration, dispatch, wait
             self.last_generate_span: Optional[Span] = None
             # where the host gap before the next train step began (perf_counter):
@@ -428,7 +429,7 @@ class TPUBaseTrainer(BaseRLTrainer):
                 # slicing)
                 draft_extra = dict(config.model.draft_model_extra_kwargs)
                 draft_extra["ignore_pipe_mesh"] = True
-                self.draft_module, draft_params, self.draft_tcfg = build_causal_lm(
+                self.draft_module, self.draft_params, self.draft_tcfg = build_causal_lm(
                     _MC(
                         model_path=config.model.draft_model_path,
                         model_extra_kwargs=draft_extra,
@@ -437,6 +438,7 @@ class TPUBaseTrainer(BaseRLTrainer):
                     head=None,
                     seed=config.train.seed + 1,
                     abstract=abstract_init,
+                    mesh=self.mesh,
                 )
                 if self.draft_tcfg.vocab_size != self.tcfg.vocab_size:
                     raise ValueError(
@@ -444,9 +446,6 @@ class TPUBaseTrainer(BaseRLTrainer):
                         f"{self.tcfg.vocab_size}: speculative decoding needs a "
                         "same-tokenizer draft"
                     )
-                self.draft_params = (
-                    draft_params if abstract_init else shard_params(draft_params, self.mesh)
-                )
 
             default_lr = config.optimizer.kwargs.get("lr")
             self.schedule = get_scheduler(
@@ -464,27 +463,36 @@ class TPUBaseTrainer(BaseRLTrainer):
             # replicate. Without out_shardings the compiler may leave the whole
             # state on one device — and checkpoint restore then commits that
             # placement, breaking later steps.
-            if abstract_init:
-                opt_state = jax.eval_shape(self.optimizer.init, params)
-            else:
-                opt_shardings = _optimizer_state_shardings(
-                    self.mesh, params, jax.eval_shape(self.optimizer.init, params)
-                )
-                opt_state = jax.jit(self.optimizer.init, out_shardings=opt_shardings)(params)
             from jax.sharding import NamedSharding, PartitionSpec
 
-            from trlx_tpu.parallel.sharding import put_global
-
             replicated = NamedSharding(self.mesh, PartitionSpec())
-            rng = jax.random.PRNGKey(config.train.seed)
-            rollout_rng, state_rng = jax.random.split(rng)
+
+            def init_counters(seed):
+                rollout_rng, state_rng = jax.random.split(jax.random.PRNGKey(seed))
+                return jnp.zeros((), jnp.int32), state_rng, rollout_rng
+
+            def init_state(p, seed):
+                return (self.optimizer.init(p), *init_counters(seed))
+
+            # one program for everything of the state but the params: the
+            # moments, the step counter and both rng streams. The seed is an
+            # argument (models/builder.py::_build_params says why)
+            seed = np.int64(config.train.seed)
+            opt_state = jax.eval_shape(self.optimizer.init, params)
+            if abstract_init:
+                counters = jax.jit(init_counters, out_shardings=replicated)(seed)
+            else:
+                opt_state, *counters = jax.jit(
+                    init_state,
+                    out_shardings=(
+                        _optimizer_state_shardings(self.mesh, params, opt_state),
+                        replicated, replicated, replicated,
+                    ),
+                )(params, seed)
+            step, state_rng, self._rollout_rng = counters
             self.state = TrainState(
-                params=params,
-                opt_state=opt_state,
-                step=put_global(jnp.zeros((), jnp.int32), replicated),
-                rng=put_global(state_rng, replicated),
+                params=params, opt_state=opt_state, step=step, rng=state_rng
             )
-            self._rollout_rng = rollout_rng
         self.obs.setup.init_model_s = init_sp.duration
 
         # generation settings (reference: accelerate_base_trainer.py:176-198)
@@ -1448,11 +1456,16 @@ class TPUBaseTrainer(BaseRLTrainer):
             self.last_kv_extents = kv_extents(P, gen_config.max_new_tokens)
 
         def cache(tcfg, slots):
-            def kv_cache_shapes():  # named: eval_shape traces it anew at every
-                # call, which the records show under this name (runtime/retrace_s)
-                return make_kv_cache(tcfg, B, slots)
+            # one trace a shape: a walk at every call would be a retrace on
+            # every collection record (runtime/retrace_s), the device waiting
+            key = (tcfg, B, slots)
+            if key not in self._kv_cache_shapes:
 
-            return jax.eval_shape(kv_cache_shapes)
+                def kv_cache_shapes():  # named for the records
+                    return make_kv_cache(tcfg, B, slots)
+
+                self._kv_cache_shapes[key] = jax.eval_shape(kv_cache_shapes)
+            return self._kv_cache_shapes[key]
 
         policy_cache = cache(self.tcfg, S)
         state = recurrent_state_bytes(policy_cache)

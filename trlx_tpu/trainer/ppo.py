@@ -164,13 +164,18 @@ class PPOTrainer(TPUBaseTrainer):
         """Frozen-reference snapshot of (a branch of) the current params.
 
         Real runs take buffer-owning copies (the train step donates its
-        input state, so the snapshot must not alias it); under
-        ``abstract_init`` only shapes are produced — the branch extractor's
-        slicing traces fine under ``eval_shape`` and an abstract trainer
-        never executes."""
+        input state, so the snapshot must not alias it), all in one program;
+        a copy keeps its operand's sharding, so the branch lies as the params
+        it was taken from do. Under ``abstract_init`` only shapes are
+        produced — the branch extractor's slicing traces fine under
+        ``eval_shape`` and an abstract trainer never executes."""
         if self.abstract_init:
             return jax.eval_shape(extract, self.state.params)
-        return jax.tree_util.tree_map(jnp.copy, extract(self.state.params))
+
+        def ref_snapshot(params):
+            return jax.tree_util.tree_map(jnp.copy, extract(params))
+
+        return jax.jit(ref_snapshot)(self.state.params)
 
     # ------------------------------------------------------------------
     # rollout collection

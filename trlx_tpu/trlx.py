@@ -117,6 +117,11 @@ def initialize_runtime() -> None:
     cache_dir = compile_cache_dir()
     if cache_dir is not None:
         jax.config.update("jax_compilation_cache_dir", cache_dir)
+    # keep every program, however quickly it compiled: the few operations
+    # set-up still runs one by one (a split of the rollout rng, a host value
+    # placed) compile in a tenth of a second each, under the default floor of
+    # one second, and would compile again at every start
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
 
     platform = os.environ.get("TRLX_TPU_PLATFORM")
     if platform:
