@@ -137,6 +137,24 @@ def store_failures(trainer, asked: int) -> int:
     return max(asked - len(elems), 0) + bad
 
 
+BOOLEAN_CHECKS = ("losses_finite", "leaf_changed", "no_recompile", "no_compile_in_window",
+                  "rollouts_delivered")
+
+
+def compared(values: Dict[str, Any], tolerances: Dict[str, float]) -> Dict[str, Dict[str, Any]]:
+    """Each number ``verdict`` compares beside its limit, under short plain
+    names: the result line's last key and the run's last lines on standard
+    error. A yes/no check reads 1 or 0 and has to be 1."""
+    out = {}
+    for name, tol in tolerances.items():
+        v = values.get(name)
+        finite = isinstance(v, (int, float)) and bool(np.isfinite(v))
+        out[name] = {"value": float(v) if finite else None, "at_most": tol}  # no NaN in the line
+    for name in BOOLEAN_CHECKS:
+        out[name] = {"value": int(bool(values.get(name, False))), "at_least": 1}
+    return out
+
+
 def verdict(values: Dict[str, Any], tolerances: Dict[str, float]) -> bool:
     """Print one line per failed check (which, the value, the tolerance) and
     return whether all passed."""
@@ -146,8 +164,7 @@ def verdict(values: Dict[str, Any], tolerances: Dict[str, float]) -> bool:
         if value is None or not np.isfinite(value) or value > tol:
             print(json.dumps({"check_failed": name, "value": value, "tolerance": tol}), flush=True)
             ok = False
-    for name in ("losses_finite", "leaf_changed", "no_recompile", "no_compile_in_window",
-                 "rollouts_delivered"):
+    for name in BOOLEAN_CHECKS:
         if not values.get(name, False):
             print(json.dumps({"check_failed": name, "value": values.get(name),
                               "detail": values.get(name + "_detail")}), flush=True)

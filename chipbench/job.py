@@ -131,14 +131,36 @@ def prompt_lengths(spec: Dict[str, Any], n: int) -> List[int]:
     raise ValueError(f"unknown prompt length kind {kind!r}")
 
 
+LETTERS = "abcdefghijklmnopqrstuvwxyz"
+ALPHABET_BASE = 0x4E00  # a block of 20,992 assigned code points, no surrogates
+
+
+def prompt_alphabet(traffic: Dict[str, Any]) -> str:
+    """The characters a prompt is drawn from, one token each. Without the
+    traffic file's ``prompt_alphabet`` the 26 letters under ``builtin:bytes``.
+    With ``prompt_alphabet: n`` (26 < n <= 20000) n distinct characters under
+    ``builtin:chars:<those characters>`` (ids 0 to n-1, then bos, eos, pad),
+    for a mix whose work follows WHICH tokens a row holds: routing over held
+    experts on 26 distinct vectors swings with the seed's weights, on a
+    transcript's thousands of distinct tokens it does not (PERF.md, PR 39)."""
+    n = traffic.get("prompt_alphabet")
+    if n is None:
+        return LETTERS
+    n = int(n)
+    if not 26 < n <= 20000:
+        raise ValueError(f"traffic/{traffic['name']}.json: prompt_alphabet {n} not in 27..20000")
+    return "".join(chr(ALPHABET_BASE + i) for i in range(n))
+
+
 def make_prompts(traffic: Dict[str, Any], seed: int) -> List[str]:
-    """Byte strings of exact token length (``builtin:bytes``: one ASCII
-    letter is one token), one cycle's worth, order and letters from the seed."""
+    """Strings of exact token length (one character of the alphabet is one
+    token), one cycle's worth, order and characters from the seed."""
     n = int(traffic["prompts_per_cycle"])
     rng = np.random.RandomState(config_seed(seed))
     lengths = prompt_lengths(traffic["prompt_length"], n)
     rng.shuffle(lengths)
-    return ["".join(chr(97 + c) for c in rng.randint(0, 26, size=L)) for L in lengths]
+    alphabet = prompt_alphabet(traffic)
+    return ["".join(alphabet[c] for c in rng.randint(0, len(alphabet), size=L)) for L in lengths]
 
 
 def eval_prompt(traffic: Dict[str, Any], seed: int) -> List[str]:
@@ -211,6 +233,8 @@ def build_config(config_file: Dict[str, Any], traffic: Dict[str, Any], seed: int
         ),
         "method": dict(gen_kwargs=gen),
     }
+    if traffic.get("prompt_alphabet") is not None:
+        derived["tokenizer"] = dict(tokenizer_path="builtin:chars:" + prompt_alphabet(traffic))
     cfg = base.evolve(**derived)
     for job in (t_job, c_job):
         if job:

@@ -18,7 +18,17 @@ Reducers (``"reducer"`` in the file):
 - ``counter``: programs compiled in the window (jax's compile events plus
   the program's ``recompile/*`` counters).
 - ``required_flops_share``: FLOPs the optimizer steps of a cycle require,
-  from shapes (``flops.py``), over their fenced time and the chip's peak, %.
+  from the model as it is (``flops.py``: every layer's own tree, the mask,
+  the family's ``costs/<family>.py`` where it brought one), over their
+  fenced time and the chip's peak, %. A tree the count cannot read leaves
+  the metric out with the reason printed.
+- ``trace_op_roofline``: the floor of the operations matching ``pattern``
+  over their summed device time in the traced cycle, %. The floor is the
+  REQUIRED work of that cycle, which the function ``costs`` names
+  (``flops.py``, or the family's file) returns as phases of operations and
+  bytes: a phase's floor is ``max(flops / peak FLOP/s, bytes / peak
+  bytes/s)``, the cycle's their sum. No matching operation, no phase or an
+  unknown chip: nothing.
 - ``trace_op_sum``: summed device time of operations matching ``pattern`` in
   the traced stretch (one cycle), times ``scale``.
 - ``trace_module_share``: device time of programs (``XLA Modules``) matching
@@ -52,6 +62,36 @@ def _cycle_seconds(h) -> float:
     return sum(c["end"] - c["start"] for c in h.cycles)
 
 
+def _model(h) -> "flops.Model":
+    if getattr(h, "flops_model", None) is None:
+        h.flops_model = flops.Model(h.trainer, h.config_file.get("family"))
+    return h.flops_model
+
+
+def _required_share(spec: Dict[str, Any], h, tr, peak_row, chips: int) -> Optional[float]:
+    """The two readers of ``flops.py``: a share of the chip's peak over the
+    steps' fenced time, and a share of a kernel's roofline over its device
+    time in the traced cycle (``h.cycles[0]``: the profiler runs over the
+    first whole cycle of the window)."""
+    model = _model(h)
+    if spec["reducer"] == "required_flops_share":
+        need = sum(flops.learn_flops_of_cycle(h.trainer, c, model=model) for c in h.cycles)
+        spent = sum(s["time/train_step"] for c in h.cycles for s in c["steps"])
+        if peak_row is None:
+            return None
+        return 100.0 * need / spent / (peak_row["bf16_flops_per_s"] * chips)
+    phases = flops.kernel_costs(spec["costs"], model)(model, h.cycles[0])
+    if peak_row is None or tr is None or not tr["ops"] or not phases:
+        return None
+    spent = trace.op_seconds(tr["ops"], spec["pattern"])
+    if spent <= 0.0:
+        return None
+    floor = flops.floor_seconds(phases, peak_row, chips)
+    print(json.dumps({"roofline": spec["name"], "device_s": spent, "floor_s": floor,
+                      "phases": phases}), flush=True)
+    return 100.0 * floor / spent
+
+
 def reduce_one(spec: Dict[str, Any], h, tr: Optional[Dict[str, Any]], peak_row, chips: int
                ) -> Optional[float]:
     kind = spec["reducer"]
@@ -72,12 +112,12 @@ def reduce_one(spec: Dict[str, Any], h, tr: Optional[Dict[str, Any]], peak_row, 
     if kind == "counter":
         return float(h.check_values.get("compiles_in_window", 0)
                      + sum(h.check_values.get("no_recompile_detail", {}).values()))
-    if kind == "required_flops_share":
-        if peak_row is None:
+    if kind == "required_flops_share" or kind == "trace_op_roofline":
+        try:  # counted on the CPU walk too, where there is no peak to divide by
+            return _required_share(spec, h, tr, peak_row, chips)
+        except flops.Uncountable as e:
+            print(json.dumps({"metric_left_out": spec["name"], "reason": str(e)}), flush=True)
             return None
-        need = sum(flops.learn_flops_of_cycle(h.trainer, c) for c in h.cycles)
-        spent = sum(s["time/train_step"] for c in h.cycles for s in c["steps"])
-        return 100.0 * need / spent / (peak_row["bf16_flops_per_s"] * chips)
     if tr is None or not tr["ops"]:
         return None
     window = tr["window"][1] - tr["window"][0]

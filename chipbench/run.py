@@ -83,6 +83,9 @@ class Harness:
         self.trainer = trainer
         trainer.tracker = self
         self.shape = job.cycle_shape(trainer.config, self.traffic)
+        if trainer.tokenizer.vocab_size > trainer.tcfg.vocab_size:
+            raise ValueError(f"the traffic's tokenizer has {trainer.tokenizer.vocab_size} ids, "
+                             f"the model's vocabulary {trainer.tcfg.vocab_size}")
         self._pin_learner_pad(trainer)
         if self.traffic.get("eos_rate"):
             from chipbench import shaping
@@ -399,8 +402,10 @@ def main(argv=None) -> int:
     peak = h.peak
     correct = finish_checks(h)
     say(length_report=length_report(h))
+    held = [float(s["moe/held_frac"]) for c in h.cycles for s in c["steps"] if "moe/held_frac" in s]
     say(cycles=[round(c["end"] - c["start"], 4) for c in h.cycles],
         window_s=round(sum(c["end"] - c["start"] for c in h.cycles), 4),
+        **({"moe_held_frac_mean": float(np.mean(held))} if held else {}),
         peak_bytes=peak, peak_bytes_after_checks=peak_bytes(devices))
 
     device = {"platform": devices[0].platform, "kind": devices[0].device_kind,
@@ -422,6 +427,13 @@ def main(argv=None) -> int:
         line["control"] = {"checks_only": args.checks_only, "fault": args.fault,
                            "check_values": {k: v for k, v in h.check_values.items()
                                             if isinstance(v, (int, float, bool))}}
+    # each number compared beside its limit: last in the line, and the last
+    # lines of standard error
+    line["compared"] = checks.compared(h.check_values,
+                                       checks.load_tolerances(config_file["name"]))
+    for name, row in line["compared"].items():
+        print(f"compared {name}: {json.dumps(row)}", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(line), flush=True)
     return 0
 

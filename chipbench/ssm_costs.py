@@ -1,8 +1,9 @@
 """Operations and bytes of the Mamba-2 recurrence, from shapes.
 
-Kept with the benchmark for a later ``benchmark`` issue to read (a roofline
-share for a kernel that does the step or the scan): no metric of
-``BENCHMARK.json`` reads them yet. ``rows`` sequences, ``heads`` heads of
+``flops.py`` reads the scan's and the conv's operations into every count of
+a step's required work (``learn_mfu_pct``, since PR 39); the bytes and the
+decode step's costs wait for a kernel of ``ops/ssd.py`` and its roofline
+share (``layers.py::trace_op_roofline``). ``rows`` sequences, ``heads`` heads of
 ``head_dim`` channels, ``state`` the state size, ``groups`` groups of B and
 C. Required work only: what the recurrence needs, nothing recomputed.
 
@@ -52,6 +53,15 @@ def scan_costs(rows: int, tokens: int, heads: int, head_dim: int, state: int, gr
         "bytes": act_bytes * vectors + 2.0 * STATE_BYTES * boundary,
         "state_bytes": float(STATE_BYTES * rows * heads * head_dim * state),
     }
+
+
+def conv_costs(rows: int, tokens: int, channels: int, width: int, act_bytes: int = 2
+               ) -> Dict[str, float]:
+    """The causal depthwise conv over x, B and C, forward: a multiply and an
+    add a tap, ``width`` taps a channel a token; each value read and written
+    once."""
+    elements = float(rows) * tokens * channels
+    return {"flops": 2.0 * width * elements, "bytes": 2.0 * act_bytes * elements}
 
 
 if __name__ == "__main__":
