@@ -335,6 +335,32 @@ def test_flash_forward_and_backward_compile_at_the_chosen_tile(topo, shape, wind
     _assert_the_benchmark_finds_both_kernels(text)
 
 
+@pytest.mark.parametrize("rows,width", [(8, 640), (64, 128)], ids=["train_8x640", "prefill_64x128"])
+def test_flash_compiles_with_unlike_head_sizes_at_latent_attention_widths(topo, rows, width):
+    """Latent attention's expanded form: 128 heads whose q and k are 192 wide
+    and whose v is 128, a train step's minibatch and the sampler's prefill of
+    ``pangu718b_ppo_decode``. Nothing is padded to the larger size: the
+    kernels take a 192-lane q/k block beside a 128-lane v/o block, keep their
+    names, and fit the VMEM they ask for."""
+    from trlx_tpu.ops import flash_attention as fa
+
+    H, D, Dv = 128, 192, 128
+    limit = fa._bwd_vmem_params(width, D, 2, *fa.choose_blocks(width, width), False, Dv=Dv)
+    assert limit == {} or limit["compiler_params"].vmem_limit_bytes < 64 * 2**20
+
+    def loss(q, k, v, m):
+        with jax.named_scope("attn"):
+            out = fa.flash_attention(q, k, v, m, interpret=False)
+            assert out.shape == (rows, width, H, Dv)
+            return out.astype(jnp.float32).sum()
+
+    qk = _s((rows, width, H, D))
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), (qk, qk, _s((rows, width, H, Dv)), _s((rows, width), jnp.float32)),
+                    SingleDeviceSharding(topo.devices[0]))
+    _assert_the_benchmark_finds_both_kernels(text)
+    assert f"bf16[{rows},{H},{width},{Dv}]" in text.replace(" ", "")
+
+
 def test_dense_decode_step_builds_no_repeated_kv(topo, monkeypatch):
     """Cached single-token decoding runs the dense einsum branch of
     ``Attention``. At the attention shapes of ``mistral7b_grpo_decode``

@@ -408,7 +408,13 @@ def test_kv_only_presets_trace_to_the_programs_recorded_before_the_family(family
     """Recorded on the commit before the per-layer attention layouts (PR 33's
     parent) by this function (``python tests/test_falconh1.py`` there writes
     the file): parameter tree, cache tree and both jaxprs byte for byte, of
-    the three KV-only presets and of this family's own."""
+    the three KV-only presets and of this family's own. ``olmoe``'s two jaxprs
+    were recorded again in PR 40: ``MoEMLP._dropless_rows`` passes the sorted
+    rows through a select that lets the gradient of the rows in a group
+    alone through (``ragged_dot``'s backward left the others' unwritten on the
+    chip), one ``select_n`` and one ``stop_gradient`` a layer, which the
+    compiler folds out of the forward; trees and every other family's
+    programs are the first recording's."""
     # other test files of the same worker set jax_default_matmul_precision at
     # import, and a precision is printed on every dot of a jaxpr
     with jax.default_matmul_precision(None), open(RECORDED) as f:

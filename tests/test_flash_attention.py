@@ -552,3 +552,64 @@ def test_explicit_tiles_are_honoured_and_none_asks_the_chooser():
     assert [fa.choose_blocks(n, n) for n in (1100, 1152, 1280, 1408, 1536)] == [
         (384, 384), (384, 384), (256, 256), (128, 128), (512, 512)]
     assert fa.choose_blocks(128, 640) == (128, 128) and fa.choose_blocks(896, 1024) == (896, 512)
+
+
+# ---------------------------------------------------------------------------
+# unlike head sizes: q and k of one size, v (and the output) of another
+# ---------------------------------------------------------------------------
+
+
+def _mk_unlike(D, Dv, B=2, T=24, S=24, H=2, KV=2, left_pad=0, seed=3, dtype=jnp.float32):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = _rand(ks[0], B, T, H, D).astype(dtype)
+    k = _rand(ks[1], B, S, KV, D).astype(dtype)
+    v = _rand(ks[2], B, S, KV, Dv).astype(dtype)
+    mask = np.ones((B, S), np.float32)
+    if left_pad:
+        mask[:, :left_pad] = 0.0
+        mask[0, : left_pad + 2] = 0.0
+    return q, k, v, jnp.asarray(mask)
+
+
+@pytest.mark.parametrize("left_pad", [0, 3])
+@pytest.mark.parametrize("D,Dv", [(24, 16), (16, 24), (192, 128)], ids=["24_16", "16_24", "192_128"])
+def test_unlike_head_sizes_forward_matches_reference(D, Dv, left_pad):
+    q, k, v, mask = _mk_unlike(D, Dv, left_pad=left_pad)
+    out, lse = flash_attention(q, k, v, mask, causal=True, interpret=True, block_q=8, block_k=8,
+                               return_lse=True)
+    ref, ref_lse = attention_reference(q, k, v, mask, causal=True)
+    assert out.shape == (2, 24, 2, Dv)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5, rtol=3e-5)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse), atol=3e-5, rtol=3e-5)
+
+
+@pytest.mark.parametrize("window", [None, 5])
+@pytest.mark.parametrize("D,Dv,KV", [(24, 16, 2), (24, 16, 1), (192, 128, 2)], ids=["24_16", "24_16_gqa", "192_128"])
+def test_unlike_head_sizes_gradients_match_reference(D, Dv, KV, window):
+    q, k, v, mask = _mk_unlike(D, Dv, KV=KV, left_pad=3, seed=5)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v)))
+
+    if KV == 1:
+        repeat = lambda a: jnp.repeat(a, 2, axis=2)
+        ref_fn = lambda q, k, v: attention_reference(q, repeat(k), repeat(v), mask, causal=True, window=window)[0]
+    else:
+        ref_fn = lambda q, k, v: attention_reference(q, k, v, mask, causal=True, window=window)[0]
+    flash_fn = lambda q, k, v: flash_attention(q, k, v, mask, causal=True, interpret=True,
+                                               block_q=8, block_k=8, window=window)
+    g_flash = jax.grad(loss(flash_fn), argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(loss(ref_fn), argnums=(0, 1, 2))(q, k, v)
+    for gf, gr, name in zip(g_flash, g_ref, "qkv"):
+        assert gf.shape == gr.shape
+        np.testing.assert_allclose(np.asarray(gf), np.asarray(gr), atol=1e-4, rtol=1e-4,
+                                   err_msg=f"grad mismatch for {name}")
+
+
+def test_unlike_head_sizes_leave_the_vmem_request_of_like_ones_alone():
+    from trlx_tpu.ops import flash_attention as fa
+
+    for D in (128, 256):
+        assert fa._fwd_vmem_params(8192, D, 2, 512, 512, False) == fa._fwd_vmem_params(8192, D, 2, 512, 512, False, Dv=D)
+        assert fa._bwd_vmem_params(8192, D, 2, 512, 512, False) == fa._bwd_vmem_params(8192, D, 2, 512, 512, False, Dv=D)
+    assert fa._fwd_vmem_params(640, 192, 2, 640, 640, False, Dv=128) == {}  # a 640-slot row at 192 | 128 fits the default scope

@@ -183,3 +183,42 @@ def test_moe_layer_is_the_same_through_either_kernel(monkeypatch):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-6)
     np.testing.assert_allclose(np.asarray(aux1), np.asarray(aux0), rtol=1e-6)
     assert float(aux0[4]) == int(mask.sum()) * cfg.num_experts_per_tok
+
+
+def test_a_kernel_that_leaves_the_padding_rows_gradient_unwritten_reaches_no_token(monkeypatch):
+    """On the chip ``ragged_dot``'s backward leaves the gradient of the rows
+    past the last group unwritten (NaN over memory that held NaN: PERF.md, PR
+    40). Stand in for that with a ``ragged_dot`` whose backward poisons those
+    rows: the layer's gradients, with padding tokens and with experts held
+    elsewhere, are the sound kernel's."""
+    import dataclasses
+
+    from trlx_tpu.models.transformer import MoEMLP, TransformerConfig
+
+    real = jax.lax.ragged_dot
+
+    def leaves_the_tail(a, b, group_sizes, precision=None):
+        @jax.custom_vjp
+        def f(a, b):
+            return real(a, b, group_sizes)
+
+        def bwd(res, g):
+            da, db = jax.vjp(lambda a, b: real(a, b, group_sizes), *res)[1](g)
+            return jnp.where((jnp.arange(a.shape[0]) < jnp.sum(group_sizes))[:, None], da, jnp.nan), db
+
+        f.defvjp(lambda a, b: (f(a, b), (a, b)), bwd)
+        return f(a, b)
+
+    cfg = TransformerConfig.olmoe("test", param_dtype=jnp.float32, dtype=jnp.float32)
+    for cfg in (cfg, dataclasses.replace(cfg, moe_experts_held=2, moe_first_expert=1)):
+        layer = MoEMLP(cfg)
+        x = jnp.asarray(np.random.RandomState(7).randn(2, 12, cfg.hidden_size), jnp.float32)
+        mask = jnp.ones((2, 12), jnp.int32).at[0, :5].set(0)
+        params = jax.tree_util.tree_map(lambda a: a * 10, layer.init(jax.random.PRNGKey(0), x)["params"])
+        loss = lambda p, x: jnp.sum(layer.apply({"params": p}, x, mask)[0] ** 2)
+        want = jax.grad(loss, argnums=(0, 1))(params, x)
+        with monkeypatch.context() as m:
+            m.setattr(jax.lax, "ragged_dot", leaves_the_tail)
+            got = jax.grad(loss, argnums=(0, 1))(params, x)
+        for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6)

@@ -178,7 +178,7 @@ def test_nothing_dropped_when_every_token_picks_the_same_experts(capacity_factor
     x, params = _collapsed_router_layer()
     cfg = dataclasses.replace(CFG, moe_capacity_factor=capacity_factor)
     y, aux = MoEMLP(cfg).apply({"params": params}, x)
-    dropped_frac, load = np.asarray(router_load_summary(aux))
+    dropped_frac, load = np.asarray(router_load_summary(aux, cfg))
     assert load == pytest.approx(E / K)  # K experts share everything
     if dropped:  # the counter counts: capacity 1.25 keeps K*G*1.25/E slots an expert
         assert dropped_frac > 0.5

@@ -29,6 +29,7 @@ BUILDS = {
     "dropless_moe": ("builtin:olmoe-test", "causal", None, "bfloat16"),
     "falconh1_hybrid": ("builtin:falconh1-test", "causal", "value", "bfloat16"),
     "smallthinker_mixed_layout": ("builtin:smallthinker-test", "causal", None, "bfloat16"),
+    "pangu_latent_attention": ("builtin:pangu-test", "causal", "value", "bfloat16"),
     "value_head_f32": ("builtin:gpt2-test", "causal", "value", "float32"),
     "ilql_heads_f32": ("builtin:gpt2-test", "causal", "ilql", "float32"),
     "seq2seq_value_f32": ("builtin:t5-test", "seq2seq", "value", "float32"),

@@ -175,7 +175,7 @@ def test_cache_tree_holds_a_ring_for_each_window_layer():
     assert shapes == [(B, T, kv, d)] + [(B, WINDOW, kv, d)] * 3
     short = [layer["k"].shape[1] for layer in jax.eval_shape(lambda: make_kv_cache(CFG, B, 5))]
     assert short == [5, 5, 5, 5]  # a row inside the window: every layer holds the row
-    assert CFG.layer_layouts == (LayerLayout(None, False),) + (LayerLayout(WINDOW, True),) * 3
+    assert CFG.layer_layouts == (LayerLayout(None, False, "moe"),) + (LayerLayout(WINDOW, True, "moe"),) * 3
     uniform = TransformerConfig.mistral("test")
     assert uniform.layer_layouts == (LayerLayout(8, True),) * 2 and not uniform.mixed_layout
 
@@ -437,7 +437,7 @@ def test_preset_is_the_families_block_and_the_cut_is_the_configuration_files():
     assert (big.norm, big.activation, big.moe_gated, big.moe_router_input, big.position_scheme) == (
         "rmsnorm", "relu", True, "block_input", "rotary")
     assert (big.attn_bias, big.mlp_bias, big.qk_norm, big.moe_capacity_factor) == (False, False, False, 0.0)
-    assert big.layer_layouts[:5] == (LayerLayout(None, False),) + (LayerLayout(4096, True),) * 3 + (LayerLayout(None, False),)
+    assert big.layer_layouts[:5] == (LayerLayout(None, False, "moe"),) + (LayerLayout(4096, True, "moe"),) * 3 + (LayerLayout(None, False, "moe"),)
     file = job.load_config("smallthinker-21b-a3b-l4e16")
     cut = config_from_spec(file["job"]["model"]["model_path"], **file["job"]["model"]["model_extra_kwargs"])
     assert (cut.num_layers, cut.experts_held, cut.moe_first_expert, cut.num_experts, cut.vocab_size) == (

@@ -74,6 +74,7 @@ from trlx_tpu.ops.paged_kv import (
     block_bytes,
     kv_bytes,
     num_table_blocks,
+    refuse_latent_cache,
     refuse_recurrent_state,
     refuse_ring_cache,
 )
@@ -617,6 +618,7 @@ class ContinuousEngine(Engine):
             self.allocator = BlockAllocator(self.spec.max_blocks)
             if prefix_cache:
                 refuse_recurrent_state(self.state.cache, "prefix_cache")
+                refuse_latent_cache(self.state.cache, "prefix_cache")
                 refuse_ring_cache(self.state.cache.pool, self._bs, "prefix_cache")
                 self.prefix = PrefixCache(self._bs, prefix_capacity_blocks)
                 self.stats.prefix_enabled = True

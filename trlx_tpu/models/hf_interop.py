@@ -29,6 +29,12 @@ _SMALLTHINKER_NO_INTEROP = (
     "'builtin:smallthinker-<size>' (random weights) only and no converter pair was "
     "ever checked against one (ROADMAP.md queue 2, B3)"
 )
+_PANGU_ULTRA_MOE_NO_INTEROP = (
+    "model_type 'pangu_ultra_moe' has no HF checkpoint conversion yet: no checkpoint can "
+    "be fetched where this was built, so the family runs from 'builtin:pangu-<size>' "
+    "(random weights) only and no converter pair was ever checked against one "
+    "(ROADMAP.md queue 2, B4)"
+)
 
 
 class UnsupportedHFExport(ValueError):
@@ -510,6 +516,8 @@ def config_from_hf(hf_config) -> TransformerConfig:
         raise ValueError(_FALCON_H1_NO_INTEROP)
     if mt == "smallthinker":
         raise ValueError(_SMALLTHINKER_NO_INTEROP)
+    if mt == "pangu_ultra_moe":
+        raise ValueError(_PANGU_ULTRA_MOE_NO_INTEROP)
     raise ValueError(f"Unsupported HF model type for causal import: {mt}")
 
 
@@ -1145,6 +1153,8 @@ def hf_config_from_transformer(cfg):
         raise UnsupportedHFExport(_FALCON_H1_NO_INTEROP)
     if mt == "smallthinker":
         raise UnsupportedHFExport(_SMALLTHINKER_NO_INTEROP)
+    if mt == "pangu_ultra_moe":
+        raise UnsupportedHFExport(_PANGU_ULTRA_MOE_NO_INTEROP)
     raise UnsupportedHFExport(
         f"No HF export mapping for model_type={mt!r} "
         "(set TransformerConfig.model_type to an HF family)"

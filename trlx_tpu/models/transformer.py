@@ -189,12 +189,13 @@ QK_INIT_STD_SMALLTHINKER = 0.04
 
 
 class LayerLayout(NamedTuple):
-    """One layer's attention layout (``TransformerConfig.layer_layout``): the
-    one definition the bias, the flash arguments, the sampler's cache and the
-    hydra branch all read."""
+    """One layer's kind (``TransformerConfig.layer_layout``): its attention
+    layout, which the bias, the flash arguments, the sampler's cache and the
+    hydra branch all read, and its feed-forward kind, which ``Block`` reads."""
 
     window: Optional[int]  # a query sees its last `window` slots; None = full causal
     rotary: bool  # False: this layer applies no rotary embedding (NoPE)
+    ffn: str = "dense"  # dense (MLP of `intermediate_size`) | moe (MoEMLP of `expert_width`)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -322,8 +323,41 @@ class TransformerConfig:
     moe_group_size: int = 0  # dispatch group tokens (0 = whole sequence);
     # bounds the [.., E, C] slot tensors to O(T·G) instead of O(T²)
     moe_renormalize: bool = True  # mixtral renormalizes the top-k gate probs
+    # the experts' own width where the stack also has dense layers of
+    # `intermediate_size` (0 = `intermediate_size`: every family whose layers
+    # are all sparse publishes one width)
+    moe_intermediate_size: int = 0
+    # the first `first_k_dense` layers are dense MLPs of `intermediate_size`,
+    # the rest sparse (`layer_layout(i).ffn`); 0 with experts = every layer sparse
+    first_k_dense: int = 0
+    # experts every token passes beside the routed ones: ONE gated MLP of
+    # `num_shared_experts * expert_width`, 2-D `kernel` leaves under
+    # `mlp/shared_expert`, added to the routed result unweighted. Every chip
+    # of a deployment computes it alike, so it counts once when held shares
+    # are added up
+    num_shared_experts: int = 0
+    # how the router scores: "softmax" over the experts, or "sigmoid" of each
+    # logit on its own; top-k runs on the scores either way
+    moe_scoring: str = "softmax"
+    routed_scaling_factor: float = 1.0  # multiplies the (renormalised) gates
     router_aux_coef: float = 0.01  # load-balance loss weight (Switch-style)
     router_z_coef: float = 0.0  # router logit z-loss weight (ST-MoE)
+
+    # latent attention (pangu_ultra_moe; `kv_lora_rank` > 0 selects
+    # `LatentAttention`): queries through a normed latent of `q_lora_rank`,
+    # keys and values through a normed latent of `kv_lora_rank` plus ONE
+    # roped key of `qk_rope_head_dim` shared by all heads. A head's q and k
+    # are `qk_nope_head_dim + qk_rope_head_dim` wide (`dims_per_head`), its v
+    # `v_head_dim`. The sampler's cache holds the latent and the roped key
+    # (`make_kv_cache`), never per-head K and V.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: Optional[int] = None  # None = `dims_per_head`
+    # a norm on each sublayer's OUTPUT as well as on its input, before the
+    # residual add: x + N2(Attn(N1(x))), then a + N4(FFN(N3(a)))
+    sandwich_norm: bool = False
 
     # a second sequence mixer beside attention in every block (falcon_h1):
     # "mamba2" runs Mamba-2 heads and the attention heads on the SAME normed
@@ -362,6 +396,10 @@ class TransformerConfig:
                 if len(value) < self.num_layers:
                     raise ValueError(f"{name} has {len(value)} entries for {self.num_layers} layers")
                 object.__setattr__(self, name, value)
+        if self.sandwich_norm and (self.parallel_residual or self.mixer != "none"):
+            raise ValueError("sandwich_norm is built for the sequential residual path only")
+        if self.kv_lora_rank and (self.qk_norm or self.position_scheme != "rotary" or self.mixer != "none"):
+            raise ValueError("latent attention (kv_lora_rank > 0) takes rotary positions, no qk_norm, no second mixer")
 
     @property
     def kv_heads(self) -> int:
@@ -373,6 +411,7 @@ class TransformerConfig:
         return LayerLayout(
             window=self.sliding_window if windowed and self.sliding_window else None,
             rotary=self.position_scheme == "rotary" and roped,
+            ffn="moe" if self.num_experts > 0 and layer >= self.first_k_dense else "dense",
         )
 
     @property
@@ -388,8 +427,23 @@ class TransformerConfig:
         return self.moe_experts_held or self.num_experts
 
     @property
+    def expert_width(self) -> int:
+        return self.moe_intermediate_size or self.intermediate_size
+
+    @property
+    def latent_attention(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
     def dims_per_head(self) -> int:
+        """A head's q and k size (a latent head's no-rope and rope parts together)."""
+        if self.latent_attention:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
         return self.head_dim or self.hidden_size // self.num_heads
+
+    @property
+    def v_dims_per_head(self) -> int:
+        return self.v_head_dim or self.dims_per_head
 
     @property
     def mamba_d_ssm(self) -> int:
@@ -548,6 +602,50 @@ class TransformerConfig:
             router_aux_coef=0.0,  # the config publishes no balance loss
             embed_init_std=1.0,
             qk_init_std=QK_INIT_STD_SMALLTHINKER,
+        )
+
+    @staticmethod
+    def pangu(size: str = "ultra-moe-718b", **overrides) -> "TransformerConfig":
+        """openPangu-Ultra-MoE-718B (``model_type`` ``pangu_ultra_moe``):
+        latent attention (``LatentAttention``), ``first_k_dense`` leading dense
+        SwiGLU layers, then layers of 256 routed SwiGLU experts (sigmoid
+        scores, top 8 renormalised, times 2.5) beside one shared expert, a
+        norm after each sublayer as well as before (``sandwich_norm``). The
+        next-token-prediction module is not built. Limits: the plain sampler,
+        the scoring forward, the hydra branch and the train step only
+        (``ops/paged_kv.py::refuse_latent_cache``); no ``scan_layers`` (two
+        kinds of layer), no ring attention over ``sequence``, no HF checkpoint
+        import. ``builtin:pangu-ultra-moe-718b`` | ``builtin:pangu-test``."""
+        dims = {
+            # unlike sizes everywhere a test can tell them apart: q/k 24 = 16 + 8, v 16, two latents
+            "test": dict(vocab_size=259, hidden_size=64, num_layers=3, num_heads=4, intermediate_size=128, max_position_embeddings=128,
+                         q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+                         moe_intermediate_size=32, num_experts=8, num_experts_per_tok=2, first_k_dense=1),
+            "ultra-moe-718b": dict(vocab_size=153600, hidden_size=7680, num_layers=61, num_heads=128, num_kv_heads=128, intermediate_size=18432, max_position_embeddings=131072,
+                                   q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+                                   moe_intermediate_size=2048, num_experts=256, num_experts_per_tok=8, first_k_dense=3),
+        }[size]
+        return _make_preset(
+            dims,
+            overrides,
+            model_type="pangu_ultra_moe",
+            position_scheme="rotary",
+            rope_theta=25.6e6,
+            norm="rmsnorm",
+            layer_norm_epsilon=1e-5,
+            activation="silu",
+            attn_bias=False,
+            mlp_bias=False,
+            tie_word_embeddings=False,
+            sandwich_norm=True,
+            num_shared_experts=1,
+            moe_gated=True,
+            moe_scoring="sigmoid",
+            routed_scaling_factor=2.5,
+            moe_capacity_factor=0.0,  # dropless
+            moe_renormalize=True,  # norm_topk_prob: true
+            router_aux_coef=0.0,  # the config publishes no balance loss
+            embed_init_std=1.0,
         )
 
     @staticmethod
@@ -837,7 +935,7 @@ def grouped_einsum_attention(q, k, v, attention_bias, dtype) -> jax.Array:
     scores = scores + attention_bias.astype(scores.dtype)
     probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(dtype)
     out = jnp.einsum("bkgts,bskd->btkgd", probs.reshape(B, KV, G, T, S), v)
-    return out.reshape(B, T, H, D)
+    return out.reshape(B, T, H, v.shape[-1])
 
 
 @jax.tree_util.register_static
@@ -875,8 +973,46 @@ def extent_attention(q, k, v, attention_bias, cache_index, kv_extents, dtype) ->
 
         return attend
 
+    return _switch_on_extent(cache_index, kv_extents, over, q, k, v, attention_bias)
+
+
+def _switch_on_extent(cache_index, kv_extents, over, *operands):
+    """``over(extent)(*operands)`` for the first extent of at least
+    ``cache_index + 1`` slots."""
     branch = sum((cache_index + 1 > e).astype(jnp.int32) for e in kv_extents[:-1])
-    return jax.lax.switch(branch, [over(e) for e in kv_extents], q, k, v, attention_bias)
+    return jax.lax.switch(branch, [over(e) for e in kv_extents], *operands)
+
+
+def absorbed_latent_attention(q_c, q_r, ckv, k_rope, attention_bias, cache_index, kv_extents, scale, dtype) -> jax.Array:
+    """One query token over the latent cache itself, ``[B, H, r]``: scores
+    ``q_c . c + q_r . k_r`` of ``q_c [B, H, r]`` (the no-rope query folded
+    through the key half of ``kv_b_proj``) and ``q_r [B, H, dr]`` over ``ckv
+    [B, S, r]`` and the ONE roped key ``k_rope [B, S, dr]`` every head
+    shares, softmax in float32, then ``sum p c``: the caller folds the value
+    half of ``kv_b_proj`` into the result. Nothing of ``B*S*H`` per-head keys
+    or values is built. Over the shortest of ``kv_extents`` that holds the
+    slot just written, as ``extent_attention``; ``None`` reads every slot."""
+
+    def over(extent):
+        def attend(q_c, q_r, ckv, k_rope, bias):
+            c, kr, b = ckv, k_rope, bias[:, :, 0, :]
+            if extent is not None:
+                c, kr, b = c[:, :extent], kr[:, :extent], b[..., :extent]
+            # float32 out of the products: a bf16 score of magnitude 8 to 16
+            # is 2^-4 to 2^-3 apart, which a sharp softmax turns into percents
+            # of a key's weight; the flash kernel of the expanded form keeps
+            # its scores in float32 too
+            f32 = dict(preferred_element_type=jnp.float32)
+            scores = jnp.einsum("bhr,bsr->bhs", q_c, c, **f32) + jnp.einsum("bhd,bsd->bhs", q_r, kr, **f32)
+            probs = jax.nn.softmax(scores * scale + b.astype(jnp.float32), axis=-1).astype(dtype)
+            return jnp.einsum("bhs,bsr->bhr", probs, c)
+
+        return attend
+
+    with jax.named_scope("trlx/attn_latent_absorbed"):
+        if kv_extents is None or len(kv_extents) < 2:
+            return over(None)(q_c, q_r, ckv, k_rope, attention_bias)
+        return _switch_on_extent(cache_index, kv_extents, over, q_c, q_r, ckv, k_rope, attention_bias)
 
 
 class Attention(nn.Module):
@@ -1065,23 +1201,189 @@ class Attention(nn.Module):
         return out, new_cache
 
 
+class _Projection(nn.Module):
+    """The parameters of a bias-free projection, handed back as arrays where
+    a ``Dense`` (or ``LoRADense``, if ``name`` is a LoRA target) would apply
+    them: the same leaves under the same names and initialisers (``kernel``,
+    and ``lora_a`` / ``lora_b`` beside it), for a projection that is used in
+    two forms or inside a loop over row pieces. ``project`` applies them."""
+
+    config: TransformerConfig
+    shape: Tuple[int, int]
+    axes: Tuple[str, ...]
+    std: float = 0.02
+
+    @nn.compact
+    def __call__(self) -> Dict[str, jax.Array]:
+        cfg = self.config
+        init = param_with_axes(nn.initializers.normal(self.std), self.axes)
+        p = {"kernel": self.param("kernel", init, self.shape, cfg.param_dtype)}
+        if cfg.lora_r and self.name in cfg.lora_targets:
+            p["lora_a"] = self.param("lora_a", nn.initializers.he_uniform(), (self.shape[0], cfg.lora_r), cfg.param_dtype)
+            p["lora_b"] = self.param("lora_b", nn.initializers.zeros, (cfg.lora_r, self.shape[1]), cfg.param_dtype)
+        return {k: v.astype(cfg.dtype) for k, v in p.items()}
+
+
+def project(p: Dict[str, jax.Array], x: jax.Array, cfg: TransformerConfig) -> jax.Array:
+    """``x W``, plus ``(alpha / r) x A B`` where ``p`` carries an adapter
+    (``LoRADense``'s arithmetic)."""
+    y = x @ p["kernel"]
+    if "lora_a" in p:
+        y = y + (x @ p["lora_a"]) @ p["lora_b"] * (cfg.lora_alpha / cfg.lora_r)
+    return y
+
+
+# An expanded pass builds q and k of heads x 192 (256 lanes on a TPU) and v
+# and o of heads x 128 a token, and the flash kernel's lane-padded logsumexp:
+# 260 KB a token at 128 heads, 10.6 GB for the 40,960 tokens of a 64-row
+# scoring forward at width 640 (compiled for a described v5e, PR 40: 15.0 GiB
+# of temporaries beside 8.0 GiB of arguments). Rows do not interact, so past
+# LATENT_MAX_TOKENS the expanded form runs equal pieces of whole rows, of at
+# most that many tokens, one after another (``latent_row_pieces``). A train
+# step's minibatch (8 x 640) and the sampler's prefill (64 x 128) are under it
+# and run whole. A constant with its arithmetic, not a setting.
+LATENT_MAX_TOKENS = 8192
+
+
+def latent_row_pieces(rows: int, width: int) -> int:
+    """How many equal pieces of whole rows an expanded latent-attention pass
+    runs in: 1 up to ``LATENT_MAX_TOKENS`` tokens, else the fewest that divide
+    ``rows`` into pieces of at most that many (one row a piece at worst)."""
+    if rows * width <= LATENT_MAX_TOKENS:
+        return 1
+    fewest = -(-rows * width // LATENT_MAX_TOKENS)
+    return next((n for n in range(fewest, rows) if rows % n == 0), rows)
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention with an explicit latent cache.
+
+    ``cq = RMS(x Wqa)``; ``q = cq Wqb`` gives each head ``[q_n | q_r]``;
+    ``[ckv | k_r] = x Wkva``, ``c = RMS(ckv)``, ONE ``k_r`` for all heads;
+    rotary embedding on ``q_r`` and ``k_r`` only (split-half pairs);
+    ``[k_n | v]`` a head ``= c Wkvb``; scores ``(q_n . k_n + q_r . k_r) /
+    sqrt(dn + dr)``, causal softmax in float32, output ``concat(sum p v) Wo``.
+
+    Two forms of that one function. **Expanded** (a pass without a cache:
+    scoring, hydra branch, train step; and the sampler's prefill): per-head
+    K ``[B, T, H, dn + dr]`` and V ``[B, T, H, dv]`` are built from ``c`` and
+    go through the flash kernel or the einsum path like any other head,
+    with unlike q/k and v sizes, in pieces of whole rows where the pass is
+    long (``latent_row_pieces``). **Absorbed** (a single-token step on a
+    cache): the key half of ``Wkvb`` is folded into the query (``q_c = q_n
+    Wkvb_k^T``, ``[B, H, r]``) and its value half into the output (``o =
+    (sum p c) Wkvb_v``), so the step attends over the latent itself
+    (``absorbed_latent_attention``) and never builds K or V of the row.
+
+    The cache is ``{"ckv": [B, S, r], "k_rope": [B, S, dr]}`` written at
+    ``cache_index`` (one scalar for all rows: the plain sampler). A span
+    (prefill) must start at slot 0: it attends over its own keys, expanded,
+    and leaves its latents in the cache. ``kv_b_proj`` takes no LoRA adapter:
+    the absorbed form folds its matrix, not its output."""
+
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, attention_bias, positions, cache=None, cache_index=None, flash_args=None, kv_extents=None):
+        cfg = self.config
+        B, T, _ = x.shape
+        H, dn, dr, dv, r = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_dims_per_head, cfg.kv_lora_rank
+        if "kv_b_proj" in cfg.lora_targets and cfg.lora_r:
+            raise ValueError(
+                "kv_b_proj takes no LoRA adapter: a decode step folds its matrix into the "
+                "query and the output (LatentAttention, absorbed form); adapt q_a_proj, "
+                "q_b_proj, kv_a_proj, o_proj"
+            )
+        if cache is not None and "block_table" in cache:
+            raise NotImplementedError("the paged Engine holds K and V blocks; a latent layer has none (ops/paged_kv.py::refuse_latent_cache)")
+
+        def latent_norm(name):
+            return nn.RMSNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                              scale_init=param_with_axes(nn.initializers.ones, ("latent",)), name=name)
+
+        cq = latent_norm("q_a_norm")(_dense(cfg, cfg.q_lora_rank, False, ("embed", "latent"), "q_a_proj")(x))
+        kv_a = _dense(cfg, r + dr, False, ("embed", "latent"), "kv_a_proj")(x)
+        c = latent_norm("kv_a_norm")(kv_a[..., :r])
+        q_b = _Projection(cfg, (cfg.q_lora_rank, H * (dn + dr)), ("latent", "joined_kv"), cfg.qk_init_std, name="q_b_proj")()
+        w_kvb = _Projection(cfg, (r, H * (dn + dv)), ("latent", "joined_kv"), name="kv_b_proj")()["kernel"].reshape(r, H, dn + dv)
+        o = _Projection(cfg, (H * dv, cfg.hidden_size), ("joined_kv", "embed"), name="o_proj")()
+
+        sin, cos = rotary_sin_cos(positions, dr, cfg.rope_theta)
+        k_r = apply_rotary(kv_a[..., None, r:], sin, cos, dr, True)[:, :, 0]  # [B, T, dr]: one head
+
+        def queries(cq, sin, cos):
+            q = project(q_b, cq, cfg).reshape(*cq.shape[:2], H, dn + dr)
+            return q[..., :dn], apply_rotary(q[..., dn:], sin, cos, dr, True)
+
+        new_cache = None
+        if cache is not None:
+            ci = jnp.asarray(cache_index)
+            if ci.ndim:
+                raise NotImplementedError("a latent cache is written at one scalar cache_index for all rows (the plain sampler)")
+            new_cache = {
+                "ckv": jax.lax.dynamic_update_slice(cache["ckv"], c.astype(cache["ckv"].dtype), (0, ci, 0)),
+                "k_rope": jax.lax.dynamic_update_slice(cache["k_rope"], k_r.astype(cache["k_rope"].dtype), (0, ci, 0)),
+            }
+        if cache is not None and T == 1:
+            q_n, q_r = queries(cq, sin, cos)
+            q_c = jnp.einsum("bhn,rhn->bhr", q_n[:, 0], w_kvb[..., :dn])
+            o_c = absorbed_latent_attention(
+                q_c, q_r[:, 0], new_cache["ckv"], new_cache["k_rope"], attention_bias, ci,
+                kv_extents.slots if kv_extents is not None else None, 1.0 / np.sqrt(dn + dr), cfg.dtype,
+            )
+            out = jnp.einsum("bhr,rhv->bhv", o_c, w_kvb[..., dn:]).reshape(B, 1, H * dv)
+            return project(o, out, cfg), new_cache
+
+        use_flash = flash_args is not None
+        if use_flash and _maybe_ring_mesh(T) is not None:
+            raise NotImplementedError(
+                "ring attention over the mesh's `sequence` axis rotates per-head K and V chunks "
+                "(parallel/ring_attention.py); latent attention is not built for it: use sequence=1"
+            )
+
+        def expanded(cq, c, k_r, sin, cos, visible):
+            """Whole rows ``[b, T, ...]``; ``visible`` is their key mask
+            (flash) or their additive bias (einsum path)."""
+            b = cq.shape[0]
+            with jax.named_scope("trlx/attn_latent_expand"):
+                q_n, q_r = queries(cq, sin, cos)
+                kv = jnp.einsum("btr,rhd->bthd", c, w_kvb)
+                k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(k_r[:, :, None, :], (b, T, H, dr))], axis=-1)
+                q, v = jnp.concatenate([q_n, q_r], axis=-1), kv[..., dn:]
+            if use_flash:
+                out = _flash_attention(q, k, v, {**flash_args, "key_mask": visible})
+            else:
+                out = grouped_einsum_attention(q, k, v, visible, cfg.dtype)
+            return project(o, out.reshape(b, T, H * dv), cfg)
+
+        operands = (cq, c, k_r, sin, cos, flash_args["key_mask"] if use_flash else attention_bias)
+        pieces = latent_row_pieces(B, T)
+        if pieces == 1:
+            return expanded(*operands), new_cache
+        split = lambda a: a.reshape(pieces, B // pieces, *a.shape[1:])
+        out = jax.lax.map(lambda piece: expanded(*piece), tuple(split(a) for a in operands))
+        return out.reshape(B, T, cfg.hidden_size), new_cache
+
+
 class MLP(nn.Module):
     config: TransformerConfig
+    width: Optional[int] = None  # None = `intermediate_size`
 
     @nn.compact
     def __call__(self, x):
         cfg = self.config
+        width = self.width or cfg.intermediate_size
         act = get_activation(cfg.activation)
         if cfg.activation == "silu":  # gated (llama-style) MLP
-            gate = _dense(cfg, cfg.intermediate_size, cfg.mlp_bias, ("embed", "ffn"), "gate_proj")(x)
-            up = _dense(cfg, cfg.intermediate_size, cfg.mlp_bias, ("embed", "ffn"), "up_proj")(x)
+            gate = _dense(cfg, width, cfg.mlp_bias, ("embed", "ffn"), "gate_proj")(x)
+            up = _dense(cfg, width, cfg.mlp_bias, ("embed", "ffn"), "up_proj")(x)
             gate_mult, down_mult = cfg.mlp_multipliers
             if gate_mult != 1.0:
                 gate = gate * gate_mult
             h = act(gate) * up
             y = _dense(cfg, cfg.hidden_size, cfg.mlp_bias, ("ffn", "embed"), "down_proj")(h)
             return y * down_mult if down_mult != 1.0 else y
-        h = act(_dense(cfg, cfg.intermediate_size, cfg.mlp_bias, ("embed", "ffn"), "up_proj")(x))
+        h = act(_dense(cfg, width, cfg.mlp_bias, ("embed", "ffn"), "up_proj")(x))
         return _dense(cfg, cfg.hidden_size, cfg.mlp_bias, ("ffn", "embed"), "down_proj")(h)
 
 
@@ -1295,7 +1597,7 @@ class MoEMLP(nn.Module):
         E, K = cfg.num_experts, cfg.num_experts_per_tok
         held = cfg.experts_held
         B, T, d = x.shape
-        f = cfg.intermediate_size
+        f = cfg.expert_width
         if held < E and cfg.moe_capacity_factor != 0:
             raise NotImplementedError(
                 "moe_experts_held below num_experts runs under dropless routing "
@@ -1310,12 +1612,21 @@ class MoEMLP(nn.Module):
             kernel_init=param_with_axes(nn.initializers.normal(0.02), ("embed", "expert_sel")),
             name="router",
         )((x if router_input is None else router_input).astype(jnp.float32))  # [B, T, E]
-        probs = jax.nn.softmax(logits, axis=-1)
-        gate_vals, idx = jax.lax.top_k(probs, K)  # [B, T, K]
+        if cfg.moe_scoring == "sigmoid":
+            # each logit scored on its own; the balance statistic below reads
+            # the scores as a share of their sum
+            scores = jax.nn.sigmoid(logits)
+            probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
+        else:
+            scores = probs = jax.nn.softmax(logits, axis=-1)
+        gate_vals, idx = jax.lax.top_k(scores, K)  # [B, T, K]
+        chosen_scores = gate_vals
         if cfg.moe_renormalize:
             gate_vals = gate_vals / jnp.maximum(
                 jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9
             )
+        if cfg.routed_scaling_factor != 1.0:
+            gate_vals = gate_vals * cfg.routed_scaling_factor
         # padding tokens route nowhere: they reach no expert and leave the
         # layer with zero output (the Block residual carries them)
         w = (
@@ -1340,6 +1651,11 @@ class MoEMLP(nn.Module):
 
         dispatch = self._dropless if cfg.moe_capacity_factor == 0 else self._capacity
         y, counts, dropped = dispatch(x, w, gate_vals, idx, kernels)
+        if cfg.num_shared_experts:
+            # every token, unweighted; a padding token's part is dropped with
+            # the rest of its output
+            shared = MLP(cfg, cfg.num_shared_experts * f, name="shared_expert")(x)
+            y = y + shared.astype(y.dtype) * w[..., None].astype(y.dtype)
 
         # Switch load-balance loss over the assignments asked for: E·Σ f_e·p_e
         # (1.0 when both routing fractions and router probs are uniform).
@@ -1361,6 +1677,9 @@ class MoEMLP(nn.Module):
             # busiest held expert over the mean of the held
             here = counts[cfg.moe_first_expert : cfg.moe_first_expert + held]
             stats += [jnp.sum(here), held * jnp.max(here) / jnp.maximum(jnp.sum(here), 1.0) * n_real]
+        if cfg.num_shared_experts:
+            # rows through the shared expert, and the sum of the chosen raw scores
+            stats += [n_real * cfg.num_shared_experts, jnp.sum(chosen_scores * w[..., None])]
         aux = jnp.stack(stats)
         return y.astype(cfg.dtype), aux
 
@@ -1378,7 +1697,7 @@ class MoEMLP(nn.Module):
         one after another: the sorted ``[tokens·K, d]`` row buffers are what a
         long prefill cannot hold (``moe_token_pieces``)."""
         B, T, d = x.shape
-        pieces = moe_token_pieces(B * T)
+        pieces = moe_token_pieces(B * T, self.config.num_experts_per_tok * d * x.dtype.itemsize)
         if pieces == 1:
             return self._dropless_rows(x, w, gate_vals, idx, kernels)
         split = lambda a: a.reshape(pieces, 1, B * T // pieces, *a.shape[2:])
@@ -1420,12 +1739,20 @@ class MoEMLP(nn.Module):
         # a permutation of the K-fold repeated rows: its transpose scatters to
         # unique rows, where x[order // K] would scatter-add with duplicates
         xin = jnp.repeat(x.reshape(N, d), K, axis=0).at[order].get(unique_indices=True)
+        # rows past the last group (padding, an expert of another chip) hold
+        # whatever the kernels left, and so does their GRADIENT: neither
+        # kernel's backward writes it (on a v5e ``ragged_dot`` left NaN there
+        # over memory that held NaN, PERF.md, PR 40). The select is the value
+        # it was given either way (the compiler folds it away) and passes the
+        # gradient of the rows in a group alone, once, where it would reach
+        # the tokens; the rows in between never mix with a group's
+        in_a_group = (jnp.arange(N * K) < jnp.sum(group_sizes))[:, None]
+        xin = jnp.where(in_a_group, xin, jax.lax.stop_gradient(xin))
 
         out = self._experts(  # [N·K, d], sorted by expert
             kernels, lambda lhs, kernel: grouped_matmul(lhs, kernel, group_sizes), xin
         )
-        # rows past the last group (padding) hold whatever the kernel left
-        out = jnp.where((jnp.arange(N * K) < jnp.sum(group_sizes))[:, None], out, 0)
+        out = jnp.where(in_a_group, out, 0)
         unsort = jnp.zeros_like(order).at[order].set(jnp.arange(N * K), unique_indices=True)
         out = out.at[unsort].get(unique_indices=True).reshape(N, K, d)
         gates = gate_vals.reshape(N, K) * real[:, None]
@@ -1505,15 +1832,30 @@ class MoEMLP(nn.Module):
 # settings.
 MOE_MAX_TOKENS = 65536
 MOE_PIECE_TOKENS = 16384
+# The same buffers at hidden 7680 and eight experts a token are 123 KB a
+# token: 5.0 GB a buffer for those 40,960 tokens, under the first number and
+# far over the chip. So a forward under MOE_MAX_TOKENS whose row buffer would
+# pass MOE_MAX_ROW_BYTES is cut too, into pieces of at most
+# MOE_PIECE_ROW_BYTES a buffer. The largest buffer of the cells that came
+# before (OLMoE: 40,960 x 8 x 2048 x 2 = 1.34 GB) is under the first number
+# and keeps its program.
+MOE_MAX_ROW_BYTES = 2 * 2**30
+MOE_PIECE_ROW_BYTES = 2**29
 
 
-def moe_token_pieces(tokens: int) -> int:
+def moe_token_pieces(tokens: int, token_bytes: int = 0) -> int:
     """How many equal pieces a dropless layer cuts ``tokens`` into: 1 up to
-    ``MOE_MAX_TOKENS``, else the fewest that divide ``tokens`` into pieces of
-    at most ``MOE_PIECE_TOKENS`` (1 again where nothing divides it)."""
+    ``MOE_MAX_TOKENS`` tokens whose sorted row buffer (``token_bytes`` a
+    token: experts a token x hidden x item size) stays under
+    ``MOE_MAX_ROW_BYTES``, else the fewest that divide ``tokens`` into pieces
+    of at most ``MOE_PIECE_TOKENS`` and ``MOE_PIECE_ROW_BYTES`` (1 again
+    where nothing divides it)."""
+    most = MOE_PIECE_TOKENS
     if tokens <= MOE_MAX_TOKENS:
-        return 1
-    fewest = -(-tokens // MOE_PIECE_TOKENS)
+        if tokens * token_bytes <= MOE_MAX_ROW_BYTES:
+            return 1
+        most = max(MOE_PIECE_ROW_BYTES // token_bytes, 1)
+    fewest = -(-tokens // most)
     return next((n for n in range(fewest, 64 * fewest) if tokens % n == 0), 1)
 
 
@@ -1522,8 +1864,13 @@ def aux_size(cfg: TransformerConfig) -> int:
     tokens·lse², tokens, assignments dropped, assignments asked for, (busiest
     expert / mean)·tokens] and, where the layer holds a share of its experts,
     [assignments that fell on a held expert, (busiest held expert / mean of
-    the held)·tokens]."""
-    return 8 if 0 < cfg.experts_held < cfg.num_experts else 6
+    the held)·tokens]; then, where the layers have shared experts, [rows
+    through the shared expert, Σ chosen raw router scores]."""
+    return 6 + 2 * _holds_share(cfg) + 2 * bool(cfg.num_shared_experts)
+
+
+def _holds_share(cfg: TransformerConfig) -> bool:
+    return 0 < cfg.experts_held < cfg.num_experts
 
 
 def router_aux_summary(aux: jax.Array) -> jax.Array:
@@ -1535,7 +1882,7 @@ def router_aux_summary(aux: jax.Array) -> jax.Array:
     return aux[:2] / jnp.maximum(aux[2], 1.0)
 
 
-def router_load_summary(aux: jax.Array) -> jax.Array:
+def router_load_summary(aux: jax.Array, cfg: TransformerConfig) -> jax.Array:
     """Accumulated per-layer aux statistics → ``[dropped_frac,
     load_max_over_mean]``: the share of (token, expert) assignments asked for
     and not computed (0 under dropless routing, always), and the busiest
@@ -1545,9 +1892,19 @@ def router_load_summary(aux: jax.Array) -> jax.Array:
     fell on a held expert (every one of them computed), and the busiest held
     expert over the mean of the held."""
     load = [aux[3] / jnp.maximum(aux[4], 1.0), aux[5] / jnp.maximum(aux[2], 1.0)]
-    if aux.shape[0] > 6:  # layers that hold a share: [held_frac, held_load_max_over_mean]
+    if _holds_share(cfg):  # [held_frac, held_load_max_over_mean]
         load += [aux[6] / jnp.maximum(aux[4], 1.0), aux[7] / jnp.maximum(aux[2], 1.0)]
     return jnp.stack(load)
+
+
+def shared_expert_summary(aux: jax.Array, cfg: TransformerConfig) -> jax.Array:
+    """``[shared_row_frac, chosen_score_mean]`` of layers with shared experts:
+    the share of the expert rows computed here that are the shared expert's
+    (its rows over its rows plus the routed assignments that fell on an
+    expert held here), and the mean raw router score of a chosen expert."""
+    i = 6 + 2 * _holds_share(cfg)
+    routed_here = aux[6] if _holds_share(cfg) else aux[4] - aux[3]
+    return jnp.stack([aux[i] / jnp.maximum(aux[i] + routed_here, 1.0), aux[i + 1] / jnp.maximum(aux[4], 1.0)])
 
 
 def _cache_is_paged(cache) -> bool:
@@ -1563,6 +1920,13 @@ def _cache_is_paged(cache) -> bool:
             for layer in cache
         )
     return False
+
+
+def cache_slots(layer_cache: Dict[str, jax.Array], stacked: bool = False) -> int:
+    """Slots a layer's dense cache holds a row: the length of ``k``, or of a
+    latent layer's ``ckv`` (behind a leading layer dim where ``stacked``)."""
+    leaf = layer_cache["k"] if "k" in layer_cache else layer_cache["ckv"]
+    return leaf.shape[1 + stacked]
 
 
 def _needs_token_mask(cfg: TransformerConfig) -> bool:
@@ -1592,12 +1956,13 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, attention_bias, positions, cache=None, cache_index=None, flash_args=None, token_mask=None, kv_extents=None):
         cfg = self.config
-        rotary = cfg.layer_layout(self.layer).rotary
+        layout = cfg.layer_layout(self.layer)
+        rotary, sparse = layout.rotary, layout.ffn == "moe"
         # a router that reads the block's raw input, before the input norm
-        router_input = x if cfg.num_experts > 0 and cfg.moe_router_input == "block_input" else None
+        router_input = x if sparse and cfg.moe_router_input == "block_input" else None
 
         def run_mlp(h):
-            if cfg.num_experts > 0:
+            if sparse:
                 return MoEMLP(cfg, name="mlp")(h, token_mask, router_input)
             return MLP(cfg, name="mlp")(h), jnp.zeros((aux_size(cfg),), jnp.float32)
 
@@ -1613,7 +1978,12 @@ class Block(nn.Module):
             x = x + mix_out * cfg.ssm_out_multiplier + attn_out * cfg.attention_out_multiplier
             mlp_out, aux = run_mlp(Norm(cfg, name="ln_mlp")(x))
             return x + mlp_out, new_cache, aux
-        attn_out, new_cache = Attention(cfg, rotary, name="attn")(h, attention_bias, positions, cache, cache_index, flash_args, kv_extents)
+        if cfg.latent_attention:
+            attn_out, new_cache = LatentAttention(cfg, name="attn")(h, attention_bias, positions, cache, cache_index, flash_args, kv_extents)
+        else:
+            attn_out, new_cache = Attention(cfg, rotary, name="attn")(h, attention_bias, positions, cache, cache_index, flash_args, kv_extents)
+        if cfg.sandwich_norm:
+            attn_out = Norm(cfg, name="ln_attn_post")(attn_out)
         if cfg.parallel_residual:
             mlp_in = h if cfg.shared_ln else Norm(cfg, name="ln_mlp")(x)
             mlp_out, aux = run_mlp(mlp_in)
@@ -1622,6 +1992,8 @@ class Block(nn.Module):
             x = x + attn_out
             h = Norm(cfg, name="ln_mlp")(x)
             mlp_out, aux = run_mlp(h)
+            if cfg.sandwich_norm:
+                mlp_out = Norm(cfg, name="ln_mlp_post")(mlp_out)
             x = x + mlp_out
         return x, new_cache, aux
 
@@ -1706,7 +2078,7 @@ class CausalTransformer(nn.Module):
             raise NotImplementedError(
                 "scan_layers (and the pipeline schedule, which needs it) runs ONE Block "
                 f"body over stacked parameters; model_type {cfg.model_type!r} has layers of "
-                f"more than one attention layout ({sorted(set(cfg.layer_layouts), key=str)}): run it "
+                f"more than one attention layout or feed-forward kind ({sorted(set(cfg.layer_layouts), key=str)}): run it "
                 "with scan_layers=False (ROADMAP.md queue 2, B3: a scan over whole periods)"
             )
         if cfg.scan_layers:
@@ -1811,11 +2183,13 @@ class CausalTransformer(nn.Module):
         q_offset = cache_index if cache is not None and cache_index is not None else 0
         plans: Dict[Any, Any] = {}
         out = []
+        if dense and cfg.latent_attention and positions.shape[1] > 1:
+            return [self._latent_prefill_plan(key_mask, positions, cache_index, use_flash)] * len(layers)
         for i in layers:
             window = cfg.layer_layout(i).window
             slots = S
             if dense:
-                slots = (cache["k"].shape[2] if isinstance(cache, dict) else cache[i]["k"].shape[1])
+                slots = cache_slots(cache if isinstance(cache, dict) else cache[i], isinstance(cache, dict))
             if (window, slots) not in plans:
                 if slots == S:
                     plans[window, slots] = self._attn_inputs(key_mask, positions, q_offset, use_flash, window) + (extents,)
@@ -1823,6 +2197,19 @@ class CausalTransformer(nn.Module):
                     plans[window, slots] = self._ring_plan(key_mask, positions, cache_index, use_flash, window, slots, extents)
             out.append(plans[window, slots])
         return out
+
+    def _latent_prefill_plan(self, key_mask, positions, cache_index, use_flash):
+        """A span of tokens into a latent cache (the sampler's prefill)
+        attends over its own keys, expanded: per-head K and V of the whole
+        cache are never built, so it must start at slot 0, as a ring's."""
+        ci = jnp.asarray(cache_index)
+        if ci.ndim or (not isinstance(ci, jax.core.Tracer) and int(ci) != 0):
+            raise NotImplementedError(
+                "a span of tokens into a latent cache must start at slot 0, one scalar cache_index "
+                "for all rows (the sampler's prefill): chunked prefill over a latent cache is not built"
+            )
+        T = positions.shape[1]
+        return self._attn_inputs(key_mask[:, :T], positions, 0, use_flash, None) + (None,)
 
     def _ring_plan(self, key_mask, positions, cache_index, use_flash, window, slots, extents):
         cfg = self.config
@@ -1986,7 +2373,9 @@ class CausalTransformer(nn.Module):
             # token-weighted [load_balance, router_z] over all layers —
             # trainers add router_aux_coef/router_z_coef · these to the loss
             out["router_aux_loss"] = router_aux_summary(aux)
-            out["router_load"] = router_load_summary(aux)
+            out["router_load"] = router_load_summary(aux, cfg)
+            if cfg.num_shared_experts:
+                out["router_shared"] = shared_expert_summary(aux, cfg)
         return out
 
     def _pipelined_blocks(
@@ -2128,13 +2517,24 @@ def make_kv_cache(
     then writes as a ring (slot ``t`` at ``t mod window``: ``CausalTransformer.
     _ring_plan``). A ``mixer: mamba2`` layer also holds ``ssm`` (the recurrent
     state, float32 whatever ``dtype``: hundreds of steps of ``S = aS + ...``
-    drift in bf16) and ``conv`` (the conv's last ``K - 1`` input rows).
+    drift in bf16) and ``conv`` (the conv's last ``K - 1`` input rows). A
+    latent-attention layer (``kv_lora_rank`` > 0) holds ``ckv`` ``[B, slots,
+    kv_lora_rank]`` and ``k_rope`` ``[B, slots, qk_rope_head_dim]`` IN PLACE
+    of ``k`` and ``v``: 576 numbers a slot at the published widths where
+    per-head K and V would be 40,960.
     """
     dtype = dtype or cfg.dtype
     stacked = (cfg.num_layers,) if cfg.scan_layers else ()
 
     def layer(layout: LayerLayout):
         slots = min(max_length, layout.window) if layout.window else max_length
+        if cfg.latent_attention:
+            # the normed latent and the one roped key (ops/paged_kv.py::LATENT_LEAVES), the
+            # same slot axis and cache_index as K and V have, and no K or V
+            return {
+                "ckv": jnp.zeros(stacked + (batch_size, slots, cfg.kv_lora_rank), dtype),
+                "k_rope": jnp.zeros(stacked + (batch_size, slots, cfg.qk_rope_head_dim), dtype),
+            }
         shapes = {
             "k": ((batch_size, slots, cfg.kv_heads, cfg.dims_per_head), dtype),
             "v": ((batch_size, slots, cfg.kv_heads, cfg.dims_per_head), dtype),
@@ -2184,6 +2584,7 @@ BUILTIN_SPECS = {
     "olmoe": TransformerConfig.olmoe,
     "smallthinker": TransformerConfig.smallthinker,
     "falconh1": TransformerConfig.falconh1,
+    "pangu": TransformerConfig.pangu,
     "gptj": TransformerConfig.gptj,
     "gptneox": TransformerConfig.gptneox,
     "pythia": TransformerConfig.gptneox,

@@ -36,6 +36,10 @@ Design notes:
   body without positional masks over the tiles that lie wholly below the
   diagonal and inside the window (``_fwd_tile_bounds``); only the diagonal's
   and the window's edge tiles build their ``iota`` compares.
+- A head's q and k may be one size (``D``) and its v another (``Dv``, the
+  last axis of ``v``, ``o`` and ``do``): latent attention's 192 beside 128.
+  Nothing is padded to the larger; with ``Dv = D`` the programs are the
+  ones they were.
 - Registered in ``analysis/kernels.py::KERNEL_PARITY`` as ``flash-fwd`` /
   ``flash-bwd``: graftlint's kernel-discipline pass (GL1001–GL1004) keeps
   both entries gated through ``pallas_utils``, the kernel bodies pure, and
@@ -143,12 +147,12 @@ def _fwd_kernel(
     koff_ref,  # SMEM (1,)
     q_ref,  # (1, 1, bQ, D)
     k_ref,  # (1, 1, Sp, D)
-    v_ref,  # (1, 1, Sp, D)
+    v_ref,  # (1, 1, Sp, Dv)
     kmask_ref,  # (1, 1, Sp)
     qpos_ref,  # (1, 1, bQ)
     kpos_ref,  # (1, 1, Sp)
     slopes_ref,  # SMEM (H,) alibi slopes
-    o_ref,  # (1, 1, bQ, D)
+    o_ref,  # (1, 1, bQ, Dv)
     l_ref,  # (1, 1, bQ, LANES) lane-replicated logsumexp
     *,
     sm_scale: float,
@@ -214,8 +218,7 @@ def _fwd_kernel(
         acc = acc * alpha + pv
         return acc, m_new, l
 
-    d = q_ref.shape[-1]
-    acc = jnp.zeros((block_q, d), jnp.float32)
+    acc = jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32)  # v's own head size
     m = jnp.full((block_q, 1), NEG_INF, jnp.float32)
     l = jnp.zeros((block_q, 1), jnp.float32)
     acc, m, l = _walk_tiles(tile, bounds, (acc, m, l), causal or bool(window))
@@ -236,17 +239,17 @@ def _bwd_fused_kernel(
     koff_ref,
     q_ref,  # (1, 1, Tp, D)  full queries
     k_ref,  # (1, 1, bK, D)
-    v_ref,  # (1, 1, bK, D)
+    v_ref,  # (1, 1, bK, Dv)
     kmask_ref,  # (1, 1, bK)
     qpos_ref,  # (1, 1, Tp)
     kpos_ref,  # (1, 1, bK)
     slopes_ref,
     lse_ref,  # (1, 1, Tp, LANES)
     delta_ref,  # (1, 1, Tp, LANES)
-    do_ref,  # (1, 1, Tp, D)
+    do_ref,  # (1, 1, Tp, Dv)
     dq_ref,  # (1, 1, Tp, D) f32, accumulated across the k-block grid dim
     dk_ref,  # (1, 1, bK, D)
-    dv_ref,  # (1, 1, bK, D)
+    dv_ref,  # (1, 1, bK, Dv)
     *,
     sm_scale: float,
     causal: bool,
@@ -326,9 +329,9 @@ def _bwd_fused_kernel(
         dq_ref[0, 0, pl.ds(iq * block_q, block_q), :] = cur + dq_blk * sm_scale
         return dk + dk_blk, dv + dv_blk
 
-    d = q_ref.shape[-1]
-    zeros = jnp.zeros((block_k, d), jnp.float32)
-    dk, dv = _walk_tiles(tile, bounds, (zeros, zeros), causal or bool(window))
+    dk = jnp.zeros((block_k, k_ref.shape[-1]), jnp.float32)
+    dv = jnp.zeros((block_k, v_ref.shape[-1]), jnp.float32)
+    dk, dv = _walk_tiles(tile, bounds, (dk, dv), causal or bool(window))
     dk_ref[0, 0] = dk.astype(dk_ref.dtype)
     dv_ref[0, 0] = dv.astype(dv_ref.dtype)
 
@@ -370,7 +373,7 @@ def _flash_fwd_impl(
     sm_scale, causal, alibi, block_q, block_k, interpret, window=0,
 ):
     B, H, T, D = q.shape
-    KV, S = k.shape[1], k.shape[2]
+    KV, S, Dv = k.shape[1], k.shape[2], v.shape[3]
     group = H // KV  # grouped-query attention: q-head h reads kv-head h//group
     qoff, koff = offsets
     grid = (B, H, T // block_q)
@@ -393,23 +396,23 @@ def _flash_fwd_impl(
             _smem_spec(),
             pl.BlockSpec((1, 1, block_q, D), lambda b, h, i: (b, h, i, 0)),
             pl.BlockSpec((1, 1, S, D), lambda b, h, i: (b, h // group, 0, 0)),
-            pl.BlockSpec((1, 1, S, D), lambda b, h, i: (b, h // group, 0, 0)),
+            pl.BlockSpec((1, 1, S, Dv), lambda b, h, i: (b, h // group, 0, 0)),
             pl.BlockSpec((1, 1, S), lambda b, h, i: (b, 0, 0)),
             pl.BlockSpec((1, 1, block_q), lambda b, h, i: (b, 0, i)),
             pl.BlockSpec((1, 1, S), lambda b, h, i: (b, 0, 0)),
             _smem_spec(),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, i: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, block_q, Dv), lambda b, h, i: (b, h, i, 0)),
             pl.BlockSpec((1, 1, block_q, LANES), lambda b, h, i: (b, h, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, T, D), q.dtype),
+            jax.ShapeDtypeStruct((B, H, T, Dv), q.dtype),
             jax.ShapeDtypeStruct((B, H, T, LANES), jnp.float32),
         ],
         interpret=interpret,
         name=FWD_KERNEL_NAME,
-        **_fwd_vmem_params(S, D, q.dtype.itemsize, block_q, block_k, interpret),
+        **_fwd_vmem_params(S, D, q.dtype.itemsize, block_q, block_k, interpret, Dv),
     )(qoff, koff, q, k, v, kmask, qpos, kpos, slopes)
     return out, lse
 
@@ -434,7 +437,7 @@ def _bwd_fused_call(
     padded inputs. dq accumulates in f32 across the sequential k-block grid
     (``sm_scale`` applied in-kernel); GQA partials are group-summed here."""
     B, H, T, D = q.shape
-    KV, S = k.shape[1], k.shape[2]
+    KV, S, Dv = k.shape[1], k.shape[2], v.shape[3]
     group = H // KV
     kernel = functools.partial(
         _bwd_fused_kernel,
@@ -454,32 +457,32 @@ def _bwd_fused_call(
             _smem_spec(),
             pl.BlockSpec((1, 1, T, D), lambda b, h, i: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, block_k, D), lambda b, h, i: (b, h // group, i, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, i: (b, h // group, i, 0)),
+            pl.BlockSpec((1, 1, block_k, Dv), lambda b, h, i: (b, h // group, i, 0)),
             pl.BlockSpec((1, 1, block_k), lambda b, h, i: (b, 0, i)),
             pl.BlockSpec((1, 1, T), lambda b, h, i: (b, 0, 0)),
             pl.BlockSpec((1, 1, block_k), lambda b, h, i: (b, 0, i)),
             _smem_spec(),
             pl.BlockSpec((1, 1, T, LANES), lambda b, h, i: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, T, LANES), lambda b, h, i: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, T, D), lambda b, h, i: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, T, Dv), lambda b, h, i: (b, h, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, T, D), lambda b, h, i: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, block_k, D), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, i: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, block_k, Dv), lambda b, h, i: (b, h, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, T, D), jnp.float32),
             jax.ShapeDtypeStruct((B, H, S, D), k.dtype),
-            jax.ShapeDtypeStruct((B, H, S, D), v.dtype),
+            jax.ShapeDtypeStruct((B, H, S, Dv), v.dtype),
         ],
         interpret=interpret,
         name=BWD_KERNEL_NAME,
-        **_bwd_vmem_params(T, D, q.dtype.itemsize, block_q, block_k, interpret),
+        **_bwd_vmem_params(T, D, q.dtype.itemsize, block_q, block_k, interpret, Dv),
     )(qoff, koff, q, k, v, kmask, qpos, kpos, slopes, lse, delta, do)
     if group > 1:
         dk = dk.reshape(B, KV, group, S, D).sum(axis=2)
-        dv = dv.reshape(B, KV, group, S, D).sum(axis=2)
+        dv = dv.reshape(B, KV, group, S, Dv).sum(axis=2)
     return dq.astype(q.dtype), dk, dv
 
 
@@ -508,21 +511,23 @@ def _vmem_params(resident: int, working: int, interpret: bool) -> dict:
     return {"compiler_params": pltpu.CompilerParams(vmem_limit_bytes=limit)}
 
 
-def _fwd_vmem_params(S: int, D: int, itemsize: int, block_q: int, block_k: int, interpret: bool) -> dict:
-    """The forward keeps a head's whole K and V (``S x D``) and the key mask
-    and positions (float32 and int32 rows, padded to eight sublanes), each
-    double-buffered: 8.5 MiB at 8192 slots and head size 128."""
-    resident = 2 * S * (2 * D * itemsize + 2 * 8 * 4)
-    return _vmem_params(resident, _tile_working_bytes(block_q, block_k, D, itemsize), interpret)
+def _fwd_vmem_params(S: int, D: int, itemsize: int, block_q: int, block_k: int, interpret: bool, Dv: Optional[int] = None) -> dict:
+    """The forward keeps a head's whole K (``S x D``) and V (``S x Dv``) and
+    the key mask and positions (float32 and int32 rows, padded to eight
+    sublanes), each double-buffered: 8.5 MiB at 8192 slots and head size 128."""
+    Dv = Dv or D
+    resident = 2 * S * ((D + Dv) * itemsize + 2 * 8 * 4)
+    return _vmem_params(resident, _tile_working_bytes(block_q, block_k, max(D, Dv), itemsize), interpret)
 
 
-def _bwd_vmem_params(T: int, D: int, itemsize: int, block_q: int, block_k: int, interpret: bool) -> dict:
+def _bwd_vmem_params(T: int, D: int, itemsize: int, block_q: int, block_k: int, interpret: bool, Dv: Optional[int] = None) -> dict:
     """The fused backward keeps whole-sequence operands in VMEM across the
-    k-block steps, each double-buffered: q and do (``T x D``), dq (float32)
-    and lse and delta (float32, ``LANES`` padded to a 128-lane tile): 32 MiB
-    at 8192 slots and head size 128."""
-    resident = 2 * T * (2 * D * itemsize + D * 4 + 2 * 128 * 4)
-    return _vmem_params(resident, _tile_working_bytes(block_q, block_k, D, itemsize), interpret)
+    k-block steps, each double-buffered: q (``T x D``) and do (``T x Dv``),
+    dq (float32) and lse and delta (float32, ``LANES`` padded to a 128-lane
+    tile): 32 MiB at 8192 slots and head size 128."""
+    Dv = Dv or D
+    resident = 2 * T * ((D + Dv) * itemsize + D * 4 + 2 * 128 * 4)
+    return _vmem_params(resident, _tile_working_bytes(block_q, block_k, max(D, Dv), itemsize), interpret)
 
 
 def _flash_bwd_rule(
@@ -712,7 +717,7 @@ def block_pairs_visited(
 def flash_attention(
     q: jax.Array,  # (B, T, H, D)
     k: jax.Array,  # (B, S, H, D)
-    v: jax.Array,  # (B, S, H, D)
+    v: jax.Array,  # (B, S, H, Dv): Dv may differ from D; the output is (B, T, H, Dv)
     key_mask: jax.Array,  # (B, S) 1 = valid slot
     *,
     causal: bool = True,

@@ -66,6 +66,8 @@ __all__ = [
     "recurrent_state_bytes",
     "refuse_recurrent_state",
     "refuse_ring_cache",
+    "latent_cache_bytes",
+    "refuse_latent_cache",
 ]
 
 # Physical block 0 is reserved as the permanent all-zeros block: fresh table
@@ -277,11 +279,15 @@ RECURRENT_LEAVES = ("ssm", "conv")
 def recurrent_state_bytes(cache: Any) -> int:
     """Bytes of a cache pytree's recurrent leaves, by leaf name (0 for a
     KV-only model); ``kv_bytes`` less this is what K and V hold."""
+    return _named_leaf_bytes(cache, RECURRENT_LEAVES)
+
+
+def _named_leaf_bytes(cache: Any, names: Tuple[str, ...]) -> int:
     return int(
         sum(
             int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
             for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]
-            if getattr(path[-1], "key", None) in RECURRENT_LEAVES
+            if getattr(path[-1], "key", None) in names
         )
     )
 
@@ -304,6 +310,40 @@ def refuse_recurrent_state(cache: Any, path: str) -> None:
             f"{path} does not support a model whose cache holds recurrent state "
             f"(leaves {RECURRENT_LEAVES}: `mixer: mamba2`, the falcon_h1 family): "
             f"{_KV_ONLY_PATHS[path]}; use the plain sampler (ROADMAP.md queue 2, B7)"
+        )
+
+
+# what a latent-attention layer keeps a slot IN PLACE of K and V
+# (models/transformer.py::make_kv_cache): the normed latent that keys and
+# values are made from, and the one roped key all heads share
+LATENT_LEAVES = ("ckv", "k_rope")
+
+
+def latent_cache_bytes(cache: Any) -> int:
+    """Bytes of a cache pytree's latent leaves, by leaf name (0 for a model
+    whose layers hold K and V)."""
+    return _named_leaf_bytes(cache, LATENT_LEAVES)
+
+
+# what each KV-only path would do with per-head K and V that a latent layer
+# does not have (ROADMAP.md queue 2, B4)
+_PER_HEAD_KV_PATHS = {
+    "slot_refill": "ops/slot_refill.py::SlotState refills a slot at its own depth, a [B] vector of cache indices, and its span prefill attends over the cache's per-head K and V",
+    "engine": "the engine/ block pool and its paged kernels (ops/paged_attention.py, ops/paged_prefill.py) hold and read per-head K and V blocks",
+    "prefix_cache": "the engine's prefix cache shares per-head K and V blocks",
+    "speculative": "ops/speculative.py verifies and rewinds rows at their own accepted lengths, a [B] vector of cache indices, over per-head K and V",
+}
+
+
+def refuse_latent_cache(cache: Any, path: str) -> None:
+    """Called where each KV-only rollout path builds its state, beside
+    ``refuse_recurrent_state``: a model whose layers cache a latent (no ``k``,
+    no ``v``) stops there by name."""
+    if latent_cache_bytes(cache):
+        raise NotImplementedError(
+            f"{path} does not support a model whose cache holds a latent in place of K and V "
+            f"(leaves {LATENT_LEAVES}: latent attention, `kv_lora_rank` > 0, the pangu_ultra_moe "
+            f"family): {_PER_HEAD_KV_PATHS[path]}; use the plain sampler (ROADMAP.md queue 2, B4)"
         )
 
 
@@ -353,6 +393,8 @@ def dense_kv_bytes(cfg: Any, batch_size: int, slots: int) -> int:
     allocates its cache inside the jitted program, so the gauge is computed
     rather than measured (exact: shapes are static)."""
     itemsize = np.dtype(cfg.dtype).itemsize
+    if getattr(cfg, "latent_attention", False):  # the latent and the one roped key
+        return int(cfg.num_layers * batch_size * slots * (cfg.kv_lora_rank + cfg.qk_rope_head_dim) * itemsize)
     return int(
         2 * cfg.num_layers * batch_size * slots * cfg.kv_heads
         * cfg.dims_per_head * itemsize

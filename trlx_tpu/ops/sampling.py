@@ -173,6 +173,7 @@ _NON_CARRY_KEYS = (
     "cache", "logits", "branch_input", "pre_norm_hidden", "encoder_hidden",
     "router_aux_loss",  # scalar vectors, not [B, ...] — and unused in decode
     "router_load",
+    "router_shared",
 )
 
 

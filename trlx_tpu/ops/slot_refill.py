@@ -70,6 +70,7 @@ from trlx_tpu.ops.paged_kv import (
     detach_block_table,
     gather_view,
     init_paged_kv,
+    refuse_latent_cache,
     refuse_recurrent_state,
     refuse_ring_cache,
     scatter_span,
@@ -289,10 +290,11 @@ def make_slot_refill_fns(
             "(ops/paged_prefill.py) — it requires the paged KV backend "
             "(engine.backend: paged)"
         )
-    refuse_recurrent_state(
-        jax.eval_shape(lambda: init_cache_fn(1, 1)),
-        "slot_refill" if paged is None else "engine",
-    )
+    for refuse in (refuse_recurrent_state, refuse_latent_cache):
+        refuse(
+            jax.eval_shape(lambda: init_cache_fn(1, 1)),
+            "slot_refill" if paged is None else "engine",
+        )
     G = int(speculative or 0)
     if G < 0:
         raise ValueError(f"speculative must be >= 0, got {G}")

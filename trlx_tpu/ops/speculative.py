@@ -54,7 +54,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 
-from trlx_tpu.ops.paged_kv import refuse_recurrent_state, refuse_ring_cache
+from trlx_tpu.ops.paged_kv import refuse_latent_cache, refuse_recurrent_state, refuse_ring_cache
 from trlx_tpu.ops.sampling import (
     _NON_CARRY_KEYS,
     GenerationConfig,
@@ -466,6 +466,7 @@ def generate_speculative(
     t_cache = init_target_cache(B, S)
     d_cache = init_draft_cache(B, S)
     refuse_recurrent_state((t_cache, d_cache), "speculative")
+    refuse_latent_cache((t_cache, d_cache), "speculative")
     refuse_ring_cache((t_cache, d_cache), S, "speculative")
 
     # ---- prefill both caches over the prompt block ----

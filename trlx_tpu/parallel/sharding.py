@@ -35,6 +35,11 @@ _RULES: Tuple[Tuple[str, P], ...] = (
     # row-parallel (input features on `model`); bias replicated
     (r".*/(o_proj|down_proj)/kernel$", P("model", "fsdp")),
     (r".*/(o_proj|down_proj)/bias$", P(None)),
+    # latent attention: the down-projections to a latent shard their input
+    # over fsdp and leave the (small, normed) latent whole; the up-projections
+    # from it are column-parallel over the heads
+    (r".*/(q_a_proj|kv_a_proj)/kernel$", P("fsdp", None)),
+    (r".*/(q_b_proj|kv_b_proj)/kernel$", P("fsdp", "model")),
     # mixture-of-experts MLP: expert dim over `expert` (EP), per-expert
     # matmul dims over fsdp/model exactly like the dense column/row split;
     # the router is tiny and replicates

@@ -34,7 +34,7 @@ import optax
 from trlx_tpu.data.configs import TRLConfig
 from trlx_tpu.data.tokenizer import from_config as tokenizer_from_config
 from trlx_tpu.models.builder import build_causal_lm, trainable_mask
-from trlx_tpu.models.transformer import make_kv_cache
+from trlx_tpu.models.transformer import cache_slots, make_kv_cache
 from trlx_tpu.ops.sampling import (
     GenerationConfig,
     GenerationOutput,
@@ -595,6 +595,10 @@ class TPUBaseTrainer(BaseRLTrainer):
             if load.shape[0] > 2:  # layers that hold a share of their experts
                 stats["moe/held_frac"] = load[2]
                 stats["moe/held_load_max_over_mean"] = load[3]
+        shared = out.get("router_shared")
+        if shared is not None:  # layers with a shared expert beside the routed ones
+            stats["moe/shared_row_frac"] = shared[0]
+            stats["moe/chosen_score_mean"] = shared[1]
         # keep the logged total in sync with what is actually optimized.
         # Contract: every method.loss must report its headline total under
         # one of these canonical keys (PPO/ILQL/GRPO/DPO flatten to
@@ -681,10 +685,24 @@ class TPUBaseTrainer(BaseRLTrainer):
         guard_flag = guard_policy != "off"
         guard_select = guard_policy == "skip"
 
+        # Under LoRA the base freezes whole: no gradient is taken with respect
+        # to a leaf the mask freezes, so nothing below the lowest adapted block
+        # runs a backward pass or keeps its activations for one (the
+        # reference's frozen base has requires_grad False), and
+        # gradients/global_norm is the adapters' and the heads'. Without
+        # adapters every leaf is still differentiated and masked in the
+        # optimizer, as it always was (ROADMAP.md queue 1: the same for
+        # num_layers_unfrozen, a perf_opt with cells of its own to move).
+        mask = self.param_mask if getattr(self.tcfg, "lora_r", 0) else None
+
         def scaled_loss(params, batch, rng, loss_scale):
             # loss_scale is 1.0 outside fault injection — an exact identity
             # multiply (IEEE x*1.0 == x bitwise) — and NaN when the plan
             # poisons this step, making loss AND grads non-finite
+            if mask is not None:
+                params = jax.tree_util.tree_map(
+                    lambda p, m: jax.lax.stop_gradient(p) if m is False else p, params, mask
+                )
             loss, stats = self.loss_fn(params, batch, rng)
             return loss * loss_scale, stats
 
@@ -1440,7 +1458,8 @@ class TPUBaseTrainer(BaseRLTrainer):
         per-sequence state side by side by leaf name:
         ``rollout/kv_cache_bytes`` (``k``, ``v``) and
         ``rollout/ssm_state_bytes`` (``ssm``, ``conv``; 0 for a KV-only
-        model); where the stack mixes attention layouts, K and V are also
+        model), and where the layers cache a latent in place of K and V
+        ``rollout/latent_cache_bytes`` (``ckv``, ``k_rope``; K and V then 0); where the stack mixes attention layouts, K and V are also
         split into ``rollout/kv_cache_window_bytes`` (the window layers'
         rings) and ``rollout/kv_cache_global_bytes``. The
         continuous-batching engines report their own measured gauge
@@ -1448,7 +1467,7 @@ class TPUBaseTrainer(BaseRLTrainer):
         self.last_kv_extents = self.last_kv_layers = None
         if self.is_seq2seq:
             return  # T5 cross/self caches have their own layout; not gauged
-        from trlx_tpu.ops.paged_kv import kv_bytes, recurrent_state_bytes
+        from trlx_tpu.ops.paged_kv import kv_bytes, latent_cache_bytes, recurrent_state_bytes
 
         B, P = prompt_shape
         S = P + gen_config.max_new_tokens
@@ -1469,18 +1488,21 @@ class TPUBaseTrainer(BaseRLTrainer):
 
         policy_cache = cache(self.tcfg, S)
         state = recurrent_state_bytes(policy_cache)
+        latent = latent_cache_bytes(policy_cache)
         total = kv_bytes(policy_cache) - state
         self.last_cache_stats = {
-            "rollout/kv_cache_bytes": float(total),
+            "rollout/kv_cache_bytes": float(total - latent),
             "rollout/ssm_state_bytes": float(state),
         }
+        if latent:  # the layers cache a latent in place of K and V
+            self.last_cache_stats["rollout/latent_cache_bytes"] = float(latent)
         if not self.tcfg.scan_layers:
             layouts = self.tcfg.layer_layouts
             self.last_kv_layers = tuple(
-                (int(layer["k"].shape[1]), layout.window is not None)
+                (int(cache_slots(layer)), layout.window is not None)
                 for layer, layout in zip(policy_cache, layouts)
             )
-            if self.tcfg.mixed_layout:
+            if len({layout.window for layout in layouts}) > 1:  # window layers beside global ones
                 window = sum(
                     kv_bytes({"k": layer["k"], "v": layer["v"]})
                     for layer, layout in zip(policy_cache, layouts) if layout.window is not None
