@@ -44,7 +44,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NEW_LAYER_METRICS = (
     "decode_step_ms", "score_share_pct", "collect_host_share_pct", "step_gap_ms",
     "learn_pad_pct", "generate_device_share_pct", "train_device_share_pct",
-    "flash_fwd_device_ms", "flash_bwd_device_ms",
+    "flash_fwd_device_ms", "flash_bwd_device_ms", "learn_grad_param_pct",
 )
 
 

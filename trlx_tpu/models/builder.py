@@ -314,14 +314,24 @@ def trainable_mask(
     params: Dict[str, Any], tcfg: TransformerConfig, num_layers_unfrozen: int
 ) -> Dict[str, Any]:
     """Bool pytree: True for trainable leaves. ``num_layers_unfrozen == -1``
-    trains everything; otherwise only the top-k blocks, final norm, lm head,
-    and any value/Q heads train (reference ``freeze_bottom_causal_layers``,
-    ``trlx/utils/modeling.py:34-44``). Target-Q heads never train.
+    trains everything; otherwise the top-k blocks ``h_<i>`` train and the
+    blocks below them freeze, while EVERY other name of the backbone trains:
+    the token (and position) embedding ``wte`` / ``wpe`` in front of the
+    blocks, the final norm, the lm head; and so does every top-level tree
+    beside the backbone (value head, Q and V heads) except ILQL's target-Q
+    heads, which never train (reference ``freeze_bottom_causal_layers``,
+    ``trlx/utils/modeling.py:34-44``). A policy without a ``backbone`` key (GRPO's
+    bare transformer) has nothing this function freezes: every leaf trains,
+    whatever ``num_layers_unfrozen`` says. Under ``scan_layers`` the stacked
+    ``h_scan`` leaves get a per-layer 0/1 vector where only some layers train.
 
     With LoRA enabled (``tcfg.lora_r > 0``) the base model freezes entirely
     and only adapter leaves in the unfrozen-layer range plus heads train
     (reference: OpenDelta freezes the base and trains layer-ranged
-    modified_modules, ``trlx/utils/modeling.py:389-417``)."""
+    modified_modules, ``trlx/utils/modeling.py:389-417``).
+
+    The train step takes no gradient with respect to a leaf marked ``False``
+    (:func:`is_frozen`, ``trainer/base.py::_build_train_step``)."""
 
     lora = getattr(tcfg, "lora_r", 0) > 0
     mask: Dict[str, Any] = {}
@@ -351,6 +361,23 @@ def trainable_mask(
         else:
             mask[top_key] = _mark(subtree, True)
     return mask
+
+
+def is_frozen(leaf) -> bool:
+    """Whether a mask leaf freezes its whole parameter: the bool ``False``.
+    A per-layer 0/1 vector freezes layers of a stacked leaf, which the train
+    step still differentiates whole (``get_optimizer`` masks its update)."""
+    return isinstance(leaf, (bool, np.bool_)) and not leaf
+
+
+def grad_param_frac(params, mask) -> float:
+    """Parameters the train step differentiates over parameters in ``params``
+    (``learn/grad_param_frac``): 1.0 where ``mask`` is None or freezes nothing."""
+    if mask is None:
+        return 1.0
+    sizes = [int(np.prod(p.shape)) for p in jax.tree_util.tree_leaves(params)]
+    frozen = [is_frozen(m) for m in jax.tree_util.tree_leaves(mask)]
+    return sum(n for n, f in zip(sizes, frozen) if not f) / max(sum(sizes), 1)
 
 
 # ---------------------------------------------------------------------------

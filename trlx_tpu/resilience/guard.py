@@ -9,7 +9,11 @@ on garbage until someone read the curves. The guard closes that hole with
 - device side (``trainer/base.py::_build_train_step``): the step computes
   ``all_finite = isfinite(global_norm(grads))`` — the global norm is already
   computed for ``gradients/global_norm``, and any non-finite loss, grad, or
-  activation NaN propagates into it. Under the ``skip`` policy it also
+  activation NaN propagates into it. The norm is over the trained leaves
+  (the step takes no gradient with respect to a leaf the mask freezes, and
+  the optimizer gives such a leaf a zero update): every update is a
+  function of a trained leaf's gradient, so the check covers all that can
+  reach a weight. Under the ``skip`` policy it also
   selects the *old* params/opt-state via ``jnp.where`` when the check fails
   (NOTE: the select keeps both state versions live, defeating donation's
   in-place update — ≈2× train-step temp memory; ``rollback``/``halt`` are

@@ -33,7 +33,12 @@ import optax
 
 from trlx_tpu.data.configs import TRLConfig
 from trlx_tpu.data.tokenizer import from_config as tokenizer_from_config
-from trlx_tpu.models.builder import build_causal_lm, trainable_mask
+from trlx_tpu.models.builder import (
+    build_causal_lm,
+    grad_param_frac,
+    is_frozen,
+    trainable_mask,
+)
 from trlx_tpu.models.transformer import cache_slots, make_kv_cache
 from trlx_tpu.ops.sampling import (
     GenerationConfig,
@@ -504,6 +509,9 @@ class TPUBaseTrainer(BaseRLTrainer):
         )
         self._generate_fns: Dict[Any, Callable] = {}
         self._train_step_fn: Optional[Callable] = None
+        # parameters the train step differentiates over parameters held
+        # (learn/grad_param_frac; set where the step is built)
+        self._grad_param_frac = 1.0
         self._step_shapes: set = set()  # batch shapes the train step has run at
         self._last_batch_host: Any = None
         self._last_batch_sharded: Any = None
@@ -685,15 +693,22 @@ class TPUBaseTrainer(BaseRLTrainer):
         guard_flag = guard_policy != "off"
         guard_select = guard_policy == "skip"
 
-        # Under LoRA the base freezes whole: no gradient is taken with respect
-        # to a leaf the mask freezes, so nothing below the lowest adapted block
-        # runs a backward pass or keeps its activations for one (the
-        # reference's frozen base has requires_grad False), and
-        # gradients/global_norm is the adapters' and the heads'. Without
-        # adapters every leaf is still differentiated and masked in the
-        # optimizer, as it always was (ROADMAP.md queue 1: the same for
-        # num_layers_unfrozen, a perf_opt with cells of its own to move).
-        mask = self.param_mask if getattr(self.tcfg, "lora_r", 0) else None
+        # No gradient is taken with respect to a leaf the mask freezes (the
+        # bool False: the blocks under num_layers_unfrozen, the whole base under
+        # LoRA, ILQL's target-Q heads, a seq2seq encoder), as the reference's
+        # requires_grad False takes none: the loss sees such a leaf through
+        # stop_gradient, so no weight gradient is computed for it, nothing is
+        # kept for one, and no backward pass runs below the lowest trained leaf
+        # (wte trains in a causal job, so activation gradients still cross its
+        # frozen blocks). gradients/global_norm is then the trained leaves'
+        # norm; the optimizer sends the frozen leaves' zeros to set_to_zero as
+        # it always did. A per-layer 0/1 vector (h_scan under scan_layers) is
+        # differentiated whole and masked in the optimizer. A mask that freezes
+        # nothing leaves the program as it was.
+        mask = self.param_mask
+        if not any(is_frozen(m) for m in jax.tree_util.tree_leaves(mask)):
+            mask = None
+        self._grad_param_frac = grad_param_frac(self.state.params, mask)
 
         def scaled_loss(params, batch, rng, loss_scale):
             # loss_scale is 1.0 outside fault injection — an exact identity
@@ -701,7 +716,7 @@ class TPUBaseTrainer(BaseRLTrainer):
             # poisons this step, making loss AND grads non-finite
             if mask is not None:
                 params = jax.tree_util.tree_map(
-                    lambda p, m: jax.lax.stop_gradient(p) if m is False else p, params, mask
+                    lambda p, m: jax.lax.stop_gradient(p) if is_frozen(m) else p, params, mask
                 )
             loss, stats = self.loss_fn(params, batch, rng)
             return loss * loss_scale, stats
@@ -2145,6 +2160,7 @@ class TPUBaseTrainer(BaseRLTrainer):
                         1.0 - real_tokens / fed_tokens if fed_tokens else 0.0
                     )
                     stats["learn/step_width"] = float(width)
+                    stats["learn/grad_param_frac"] = self._grad_param_frac
                     self._note_step(stats, attributed, width, sp.t1)
                     (
                         stats["learn/attn_visited_frac"],
