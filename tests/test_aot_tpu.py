@@ -268,6 +268,27 @@ def test_flash_kernels_keep_their_names_in_the_compiled_program(topo):
     _assert_the_benchmark_finds_both_kernels(text)
 
 
+def test_selected_flash_kernels_compile_and_keep_the_names(topo):
+    """Under a selection (``flash_attention(..., selection=)``) the forward
+    and the fused backward are kernels of their own with an int8 tile of the
+    selection beside the key mask: at cell 8's shape (one row of 8192 slots,
+    64 heads of 256, a 512 x 512 tile, a head's K and V and a query block's
+    8192 selection columns in VMEM) Mosaic takes both, and they carry the
+    names the benchmark's flash metrics match."""
+    from trlx_tpu.ops import flash_attention as fa
+
+    def loss(q, k, v, m, sel):
+        with jax.named_scope("attn"):
+            return fa.flash_attention(q, k, v, m, selection=sel, interpret=False).astype(jnp.float32).sum()
+
+    x = _s((1, 8192, 64, 256))
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)),
+                    (x, x, x, _s((1, 8192), jnp.float32), _s((1, 8192, 8192), jnp.bool_)),
+                    SingleDeviceSharding(topo.devices[0]))
+    _assert_the_benchmark_finds_both_kernels(text)
+    assert "s8[1,8192,8192]" in text.replace(" ", "")  # the selection reaches the kernels a byte a pair
+
+
 def _assert_the_benchmark_finds_both_kernels(text):
     """Two Mosaic calls, and each of ``flash_fwd_device_ms`` /
     ``flash_bwd_device_ms``'s patterns matches exactly its own."""

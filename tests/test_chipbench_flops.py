@@ -4,6 +4,7 @@ import pytest
 
 from chipbench.tests.test_flops import *  # noqa: F401,F403
 from chipbench.tests.test_pangu_costs import *  # noqa: F401,F403
+from chipbench.tests.test_glm_costs import *  # noqa: F401,F403
 
 
 @pytest.fixture(autouse=True)

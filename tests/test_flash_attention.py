@@ -613,3 +613,72 @@ def test_unlike_head_sizes_leave_the_vmem_request_of_like_ones_alone():
         assert fa._fwd_vmem_params(8192, D, 2, 512, 512, False) == fa._fwd_vmem_params(8192, D, 2, 512, 512, False, Dv=D)
         assert fa._bwd_vmem_params(8192, D, 2, 512, 512, False) == fa._bwd_vmem_params(8192, D, 2, 512, 512, False, Dv=D)
     assert fa._fwd_vmem_params(640, 192, 2, 640, 640, False, Dv=128) == {}  # a 640-slot row at 192 | 128 fits the default scope
+
+
+# ---------------------------------------------------------------------------
+# under a selection: each query's softmax over the keys it keeps
+# ---------------------------------------------------------------------------
+
+
+def _selection(B, T, keep, seed=0):
+    """A random ``keep`` share of every query's keys, one set for all heads;
+    query 3 of row 0 keeps none at all (a row the kernels must leave at 0)."""
+    sel = np.random.RandomState(seed).rand(B, T, T) < keep
+    sel[0, 3] = False
+    return jnp.asarray(sel)
+
+
+@pytest.mark.parametrize("left_pad", [0, 3])
+@pytest.mark.parametrize("blocks", [(16, 16), (32, 16), (None, None)], ids=["16x16", "32x16", "chosen"])
+@pytest.mark.parametrize("D,Dv", [(8, 8), (24, 16)], ids=["8_8", "24_16"])
+def test_selection_forward_matches_reference(D, Dv, blocks, left_pad):
+    T = 40  # not a multiple of the tiles: padded queries and keys keep nothing
+    q, k, _, mask = _mk(T=T, S=T, D=D, left_pad=left_pad)
+    v = _rand(jax.random.PRNGKey(9), 2, T, 2, Dv)
+    sel = _selection(2, T, 0.4)
+    out = flash_attention(q, k, v, mask, selection=sel, block_q=blocks[0], block_k=blocks[1], interpret=True)
+    ref, _ = attention_reference(q, k, v, mask, selection=sel)
+    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+    assert float(jnp.abs(out[0, 3]).max()) == 0.0
+    # a selection that keeps every key is plain causal attention
+    every = flash_attention(q, k, v, mask, selection=jnp.ones((2, T, T), bool), block_q=16, block_k=16, interpret=True)
+    np.testing.assert_allclose(every, flash_attention(q, k, v, mask, block_q=16, block_k=16, interpret=True), atol=2e-6)
+
+
+@pytest.mark.parametrize("left_pad", [0, 3])
+@pytest.mark.parametrize("blocks", [(16, 16), (32, 16)], ids=["16x16", "32x16"])
+def test_selection_gradients_match_reference(blocks, left_pad):
+    T = 48
+    q, k, _, mask = _mk(T=T, S=T, D=24, left_pad=left_pad)
+    v = _rand(jax.random.PRNGKey(9), 2, T, 2, 16)
+    sel = _selection(2, T, 0.3, seed=1)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v)))
+
+    kernel = lambda q, k, v: flash_attention(q, k, v, mask, selection=sel, block_q=blocks[0], block_k=blocks[1], interpret=True)
+    oracle = lambda q, k, v: attention_reference(q, k, v, mask, selection=sel)[0]
+    got = jax.grad(loss(kernel), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(oracle), argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=5e-5, rtol=5e-5)
+
+
+@pytest.mark.parametrize("how", ["window", "alibi", "not_causal", "grouped_heads", "a_cache", "shape"])
+def test_selection_refuses_what_its_kernels_do_not_mask(how):
+    q, k, v, mask = _mk(T=16, S=16, H=2)
+    sel, kw = jnp.ones((2, 16, 16), bool), {}
+    if how == "window":
+        kw["window"] = 4
+    elif how == "alibi":
+        kw.update(alibi_slopes=jnp.ones((2,)), q_positions=jnp.zeros((2, 16), jnp.int32), k_positions=jnp.zeros((2, 16), jnp.int32))
+    elif how == "not_causal":
+        kw["causal"] = False
+    elif how == "grouped_heads":
+        k, v = k[:, :, :1], v[:, :, :1]
+    elif how == "a_cache":
+        q, sel = q[:, :8], sel[:, :8]
+    else:
+        sel = sel[:, :, :8]
+    with pytest.raises(ValueError, match="a selection runs causal MHA over the row's own keys"):
+        flash_attention(q, k, v, mask, selection=sel, interpret=True, **kw)

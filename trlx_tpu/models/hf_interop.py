@@ -35,6 +35,12 @@ _PANGU_ULTRA_MOE_NO_INTEROP = (
     "(random weights) only and no converter pair was ever checked against one "
     "(ROADMAP.md queue 2, B4)"
 )
+_GLM_MOE_DSA_NO_INTEROP = (
+    "model_type 'glm_moe_dsa' has no HF checkpoint conversion yet: no checkpoint can "
+    "be fetched where this was built, so the family runs from 'builtin:glm-<size>' "
+    "(random weights) only and no converter pair was ever checked against one "
+    "(ROADMAP.md queue 2, B8)"
+)
 
 
 class UnsupportedHFExport(ValueError):
@@ -518,6 +524,8 @@ def config_from_hf(hf_config) -> TransformerConfig:
         raise ValueError(_SMALLTHINKER_NO_INTEROP)
     if mt == "pangu_ultra_moe":
         raise ValueError(_PANGU_ULTRA_MOE_NO_INTEROP)
+    if mt == "glm_moe_dsa":
+        raise ValueError(_GLM_MOE_DSA_NO_INTEROP)
     raise ValueError(f"Unsupported HF model type for causal import: {mt}")
 
 
@@ -1155,6 +1163,8 @@ def hf_config_from_transformer(cfg):
         raise UnsupportedHFExport(_SMALLTHINKER_NO_INTEROP)
     if mt == "pangu_ultra_moe":
         raise UnsupportedHFExport(_PANGU_ULTRA_MOE_NO_INTEROP)
+    if mt == "glm_moe_dsa":
+        raise UnsupportedHFExport(_GLM_MOE_DSA_NO_INTEROP)
     raise UnsupportedHFExport(
         f"No HF export mapping for model_type={mt!r} "
         "(set TransformerConfig.model_type to an HF family)"

@@ -144,7 +144,7 @@ def _flash_attention(q, k, v, flash_args: Dict[str, Any]) -> jax.Array:
     # an operand; window is static
     operands = {
         name: flash_args[name]
-        for name in ("key_mask", "q_positions", "k_positions", "alibi_slopes")
+        for name in ("key_mask", "q_positions", "k_positions", "alibi_slopes", "selection")
         if flash_args.get(name) is not None
     }
     operands["q_offset"] = jnp.asarray(flash_args.get("q_offset", 0), jnp.int32)
@@ -169,6 +169,7 @@ def _flash_attention(q, k, v, flash_args: Dict[str, Any]) -> jax.Array:
         "k_positions": rows,
         "alibi_slopes": P(head_axis),
         "q_offset": P(),
+        "selection": P(batch_axes, None, None),
     }
     return jax.shard_map(
         call,
@@ -196,6 +197,10 @@ class LayerLayout(NamedTuple):
     window: Optional[int]  # a query sees its last `window` slots; None = full causal
     rotary: bool  # False: this layer applies no rotary embedding (NoPE)
     ffn: str = "dense"  # dense (MLP of `intermediate_size`) | moe (MoEMLP of `expert_width`)
+    # learned selection of keys (`index_topk` > 0): "full" = the layer has an
+    # indexer of its own and selects; "shared" = it attends over the set the
+    # last "full" layer before it chose; None = no selection
+    indexer: Optional[str] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -359,6 +364,26 @@ class TransformerConfig:
     # residual add: x + N2(Attn(N1(x))), then a + N4(FFN(N3(a)))
     sandwich_norm: bool = False
 
+    # learned sparse attention over the latent cache (glm_moe_dsa; `index_topk`
+    # > 0, latent attention only): a query attends over the `index_topk` valid
+    # causal slots with the largest index score `I[t, s] = sum_j w[t, j]
+    # relu(qI[t, j] . kI[s])`, `index_heads` query heads of `index_head_dim`
+    # from the query latent against ONE normed key a slot (`LatentAttention`).
+    # `indexer_types[l]` says whether layer l has an indexer of its own
+    # ("full") or borrows the last full layer's set ("shared"); None = every
+    # layer full. Entries past `num_layers` are not read. The sampler's cache
+    # holds the index keys of a full layer beside its latent (`make_kv_cache`).
+    index_topk: int = 0
+    index_heads: int = 0
+    index_head_dim: int = 0
+    indexer_types: Optional[Tuple[str, ...]] = None
+    # how the router CHOOSES its experts: "greedy" = the largest scores;
+    # "noaux_tc" = the largest of `score + bias`, a leaf `router_bias` of the
+    # router's width that only a configuration naming it creates, while the
+    # gates come from the scores without it
+    moe_topk_method: str = "greedy"
+    moe_bias_init_std: float = 0.0  # std of that bias's random init (a trained one balances load)
+
     # a second sequence mixer beside attention in every block (falcon_h1):
     # "mamba2" runs Mamba-2 heads and the attention heads on the SAME normed
     # input and adds both to the residual. Its per-sequence state (the
@@ -400,6 +425,23 @@ class TransformerConfig:
             raise ValueError("sandwich_norm is built for the sequential residual path only")
         if self.kv_lora_rank and (self.qk_norm or self.position_scheme != "rotary" or self.mixer != "none"):
             raise ValueError("latent attention (kv_lora_rank > 0) takes rotary positions, no qk_norm, no second mixer")
+        if self.moe_topk_method not in ("greedy", "noaux_tc"):
+            raise ValueError(f"moe_topk_method {self.moe_topk_method!r} is not greedy or noaux_tc")
+        if self.index_topk:
+            if not self.kv_lora_rank or self.sliding_window or self.index_head_dim < self.qk_rope_head_dim or self.index_heads < 1:
+                raise ValueError(
+                    "a learned selection (index_topk > 0) runs over a latent cache (kv_lora_rank > 0), no sliding "
+                    "window, with index_heads >= 1 heads of index_head_dim >= qk_rope_head_dim"
+                )
+            types = self.indexer_types
+            if types is not None:  # a list from a JSON override
+                types = tuple(str(t) for t in types)
+                if len(types) < self.num_layers or set(types[: self.num_layers]) - {"full", "shared"} or types[0] != "full":
+                    raise ValueError(
+                        f"indexer_types needs one of full | shared for each of {self.num_layers} layers, "
+                        f"the first full (a shared layer borrows the last full layer's set): {types}"
+                    )
+                object.__setattr__(self, "indexer_types", types)
 
     @property
     def kv_heads(self) -> int:
@@ -412,6 +454,7 @@ class TransformerConfig:
             window=self.sliding_window if windowed and self.sliding_window else None,
             rotary=self.position_scheme == "rotary" and roped,
             ffn="moe" if self.num_experts > 0 and layer >= self.first_k_dense else "dense",
+            indexer=(self.indexer_types[layer] if self.indexer_types else "full") if self.index_topk else None,
         )
 
     @property
@@ -645,6 +688,57 @@ class TransformerConfig:
             moe_capacity_factor=0.0,  # dropless
             moe_renormalize=True,  # norm_topk_prob: true
             router_aux_coef=0.0,  # the config publishes no balance loss
+            embed_init_std=1.0,
+        )
+
+    @staticmethod
+    def glm(size: str = "5.2", **overrides) -> "TransformerConfig":
+        """GLM-5.2 (``model_type`` ``glm_moe_dsa``): latent attention
+        (``LatentAttention``) under a learned selection of ``index_topk``
+        keys a query, which a ``full`` layer's indexer makes and the
+        ``shared`` layers after it borrow (``indexer_types``);
+        ``first_k_dense`` leading dense SwiGLU layers, then layers of 256
+        routed SwiGLU experts (sigmoid scores, the eight largest of ``score +
+        bias``, renormalised, times 2.5) beside one shared expert. The
+        next-token-prediction module is not built. Limits: the plain sampler,
+        the scoring forward, the hydra branch and the train step only
+        (``ops/paged_kv.py::refuse_latent_cache``); no ``scan_layers`` and no
+        pipeline schedule (neither carries a selection from layer to layer),
+        no ring attention over ``sequence``, no HF checkpoint import.
+        ``builtin:glm-5.2`` | ``builtin:glm-test``."""
+        period = ("full", "shared", "shared", "shared")
+        dims = {
+            # unlike sizes everywhere a test can tell them apart: q/k 20 = 12 + 8, v 16, index heads of 12,
+            # a selection of 8 keys that binds on any row past 8 tokens, a period and a full layer behind it
+            "test": dict(vocab_size=259, hidden_size=64, num_layers=5, num_heads=4, intermediate_size=128, max_position_embeddings=128,
+                         q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=12, qk_rope_head_dim=8, v_head_dim=16,
+                         index_topk=8, index_heads=2, index_head_dim=12, indexer_types=period + ("full",),
+                         moe_intermediate_size=32, num_experts=8, num_experts_per_tok=2, first_k_dense=1, moe_bias_init_std=0.05),
+            "5.2": dict(vocab_size=154880, hidden_size=6144, num_layers=78, num_heads=64, num_kv_heads=64, intermediate_size=12288, max_position_embeddings=1048576,
+                        q_lora_rank=2048, kv_lora_rank=512, qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256,
+                        index_topk=2048, index_heads=32, index_head_dim=128, indexer_types=("full", "full") + period * 19,
+                        moe_intermediate_size=2048, num_experts=256, num_experts_per_tok=8, first_k_dense=3),
+        }[size]
+        return _make_preset(
+            dims,
+            overrides,
+            model_type="glm_moe_dsa",
+            position_scheme="rotary",
+            rope_theta=8e6,
+            norm="rmsnorm",
+            layer_norm_epsilon=1e-5,
+            activation="silu",
+            attn_bias=False,
+            mlp_bias=False,
+            tie_word_embeddings=False,
+            num_shared_experts=1,
+            moe_gated=True,
+            moe_scoring="sigmoid",
+            moe_topk_method="noaux_tc",
+            routed_scaling_factor=2.5,
+            moe_capacity_factor=0.0,  # dropless
+            moe_renormalize=True,  # norm_topk_prob: true
+            router_aux_coef=0.0,  # balance is the selection bias's work, not a loss's
             embed_init_std=1.0,
         )
 
@@ -1255,6 +1349,177 @@ def latent_row_pieces(rows: int, width: int) -> int:
     return next((n for n in range(fewest, rows) if rows % n == 0), rows)
 
 
+# A pass under a learned selection (``index_topk`` below the row's length)
+# builds the selection, and where no flash kernel runs (``attention_impl:
+# xla``, the CPU) attends under it too, in blocks of SPARSE_Q_BLOCK queries
+# against the keys up to the end of their group of SPARSE_KEY_GROUP: float32
+# index scores (and, on the einsum path, attention scores) of one block at a
+# time. Groups keep the causal half out of the products: at 8192 tokens 62.5%
+# of the square is computed where 50% is causal. On the chip the attention
+# itself goes through the flash kernels with the selection as one more mask a
+# tile (``ops/flash_attention.py``, ``selection=``): XLA's softmax over a
+# block's ``f32[64, 128, 8192]`` scores took 23.6 ms where the products took
+# 1.5 (PERF.md section 6, PR 42). Constants with their arithmetic, not
+# settings.
+SPARSE_Q_BLOCK = 128
+SPARSE_KEY_GROUP = 2048
+
+
+def _query_blocks(T: int, fn: Callable[[Any, int, int], jax.Array]) -> List[jax.Array]:
+    """``fn(start, n_queries, n_keys)`` for every block of queries of a row of
+    ``T`` tokens, in order: queries ``[start, start + n_queries)`` against keys
+    ``[0, n_keys)``, ``n_keys`` the end of the block's group. Each result is
+    ``[b, n_queries, ...]``; a group's equal blocks run under one ``lax.map``
+    (``start`` is then traced) and come back joined along axis 1."""
+    outs = []
+    for g0 in range(0, T, SPARSE_KEY_GROUP):
+        g1 = min(g0 + SPARSE_KEY_GROUP, T)
+        tq = min(SPARSE_Q_BLOCK, g1 - g0)
+        n = (g1 - g0) // tq
+        if n == 1:
+            outs.append(fn(g0, tq, g1))
+        else:
+            y = jax.lax.map(lambda i: fn(g0 + i * tq, tq, g1), jnp.arange(n))  # [n, b, tq, ...]
+            outs.append(jnp.moveaxis(y, 0, 1).reshape(y.shape[1], n * tq, *y.shape[3:]))
+        if (g1 - g0) % tq:
+            outs.append(fn(g0 + n * tq, (g1 - g0) % tq, g1))
+    return outs
+
+
+def selected_frac(width: int, topk: int) -> float:
+    """Pairs (query, key) a learned selection of ``topk`` keeps of a row's
+    causal pairs at ``width`` tokens, no padding: query ``t`` keeps ``min(t +
+    1, topk)``. Host arithmetic for ``learn/attn_selected_frac``."""
+    kept = min(width, topk)
+    return (kept * (kept + 1) // 2 + (width - kept) * topk) / max(width * (width + 1) // 2, 1)
+
+
+def largest_k(x: jax.Array, k: int) -> jax.Array:
+    """Boolean mask of the ``k`` largest entries along the last axis of a
+    float32 ``x`` (every entry where there are fewer than ``k``); of entries
+    equal to the ``k``-th value the first by position count, as
+    ``jax.lax.top_k`` orders them. Exact, without a sort: float32 maps onto
+    uint32 in order, and 32 rounds of counting fix the ``k``-th largest key a
+    bit at a time."""
+    bits = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32)
+    key = jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+    count = functools.partial(jnp.sum, axis=-1, dtype=jnp.int32)
+
+    def fix_bit(i, found):
+        trial = found | (jnp.uint32(1 << 31) >> i.astype(jnp.uint32))
+        return jnp.where(count(key >= trial[..., None]) >= k, trial, found)
+
+    kth = jax.lax.fori_loop(0, 32, fix_bit, jnp.zeros(x.shape[:-1], jnp.uint32))[..., None]
+    above, at = key > kth, key == kth
+    room = k - count(above)[..., None]
+    # the cumulative count is needed only where a tie straddles the k-th place
+    return above | jax.lax.cond(
+        jnp.any(count(at)[..., None] > room),
+        lambda: at & (jnp.cumsum(at, axis=-1, dtype=jnp.int32) <= room),
+        lambda: at,
+    )
+
+
+def index_scores(q_i: jax.Array, k_i: jax.Array, w: jax.Array) -> jax.Array:
+    """``I[.., t, s] = sum_j w[.., t, j] relu(q_i[.., t, j] . k_i[.., s])`` in
+    float32: ``q_i [b, T, HI, DI]`` against the ONE key a slot ``k_i [b, S,
+    DI]``, weighted by ``w [b, T, HI]``."""
+    with jax.named_scope("trlx/attn_index_scores"):
+        dots = jnp.einsum("bthd,bsd->bths", q_i, k_i, preferred_element_type=jnp.float32)
+        return jnp.sum(jax.nn.relu(dots) * w.astype(jnp.float32)[..., None], axis=2)
+
+
+def select_keys(q_i, k_i, w, visible, topk: int) -> jax.Array:
+    """The selection of a whole row ``[b, T, T]`` (bool): for each query the
+    ``topk`` visible keys of the largest index score, every visible key where
+    there are fewer. ``visible(start, n_queries, n_keys)`` is the block's
+    causal and padding mask."""
+    T = q_i.shape[1]
+
+    def block(start, tq, tk):
+        rows = lambda a: jax.lax.dynamic_slice_in_dim(a, start, tq, axis=1)
+        scores = index_scores(rows(q_i), k_i[:, :tk], rows(w))
+        with jax.named_scope("trlx/attn_index_select"):
+            seen = visible(start, tq, tk)
+            chosen = largest_k(jnp.where(seen, scores, -jnp.inf), topk) & seen
+            return jnp.pad(chosen, ((0, 0), (0, 0), (0, T - tk)))
+
+    return jnp.concatenate(_query_blocks(T, block), axis=1)
+
+
+def selected_attention(q, k, v, visible, selection, dtype) -> jax.Array:
+    """``grouped_einsum_attention``'s function of MHA ``q``, ``k [b, T, H,
+    D]`` and ``v [b, T, H, Dv]`` with each query's softmax over its selected
+    visible keys only (``selection [b, T, T]``), a block of queries at a time;
+    a block's scores are recomputed in the backward pass, never kept."""
+    b, T, H, D = q.shape
+
+    @functools.partial(jax.checkpoint, static_argnums=(1, 2))
+    def block(start, tq, tk):
+        with jax.named_scope("trlx/attn_sparse"):
+            keep = visible(start, tq, tk) & jax.lax.dynamic_slice(selection, (0, start, 0), (b, tq, tk))
+            rows = jax.lax.dynamic_slice_in_dim(q, start, tq, axis=1)
+            scores = jnp.einsum("bqhd,bkhd->bhqk", rows, k[:, :tk], preferred_element_type=jnp.float32)
+            scores = jnp.where(keep[:, None], scores / np.sqrt(D), -1e9)
+            probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
+            return jnp.einsum("bhqk,bkhd->bqhd", probs, v[:, :tk])
+
+    return jnp.concatenate(_query_blocks(T, block), axis=1)
+
+
+def select_slots(q_i, k_i, w, attention_bias, cache_index, kv_extents, topk: int) -> jax.Array:
+    """One query token's selection ``[B, topk]`` (slot numbers) over the index
+    keys of the cache ``k_i [B, S, DI]``, ``S > topk``: the ``topk`` visible
+    slots of the largest index score. Where fewer are visible the rest are
+    masked slots, which the caller's gathered bias keeps out of the softmax.
+    Over the shortest of ``kv_extents`` (never under ``topk`` slots) that
+    holds the slot just written, as ``extent_attention``."""
+
+    def over(extent):
+        def pick(q_i, k_i, w, bias):
+            n = k_i.shape[1] if extent is None else max(extent, topk)
+            scores = index_scores(q_i[:, None], k_i[:, :n], w[:, None])[:, 0]
+            with jax.named_scope("trlx/attn_index_select"):
+                return jax.lax.top_k(jnp.where(bias[:, 0, 0, :n] > -1.0, scores, -jnp.inf), topk)[1]
+
+        return pick
+
+    if kv_extents is None or len(kv_extents) < 2:
+        return over(None)(q_i, k_i, w, attention_bias)
+    return _switch_on_extent(cache_index, kv_extents, over, q_i, k_i, w, attention_bias)
+
+
+class Indexer(nn.Module):
+    """The projections of a ``full`` layer's indexer: ``index_heads`` queries
+    of ``index_head_dim`` from the layer's normed query latent, ONE key a
+    token from the layer's input through a LayerNorm, rotary embedding on the
+    first ``qk_rope_head_dim`` dims of both, and the heads' weights ``x WIw /
+    sqrt(index_heads * index_head_dim)`` (float32). No bias, no adapter, and
+    no gradient: ``top_k`` has none, and the loss the publication trains these
+    on is not PPO's."""
+
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, cq, x, sin, cos):
+        cfg = self.config
+        HI, DI, dr = cfg.index_heads, cfg.index_head_dim, cfg.qk_rope_head_dim
+        taken = {"wq_b", "wk", "weights_proj"} & set(cfg.lora_targets)
+        if cfg.lora_r and taken:
+            raise ValueError(
+                f"the indexer takes no LoRA adapter ({sorted(taken)}): its selection has no gradient "
+                "(LatentAttention); adapt q_a_proj, q_b_proj, kv_a_proj, o_proj"
+            )
+        cq, x = jax.lax.stop_gradient(cq), jax.lax.stop_gradient(x)
+        q_i = _dense(cfg, HI * DI, False, ("latent", "joined_kv"), "wq_b")(cq).reshape(*cq.shape[:2], HI, DI)
+        k_i = _dense(cfg, DI, False, ("embed", "latent"), "wk")(x)
+        k_i = nn.LayerNorm(epsilon=1e-6, dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="k_norm")(k_i)
+        w = _dense(cfg, HI, False, ("embed", "latent"), "weights_proj")(x).astype(jnp.float32) / np.sqrt(HI * DI)
+        q_i = apply_rotary(q_i, sin, cos, dr, True)
+        k_i = apply_rotary(k_i[:, :, None, :], sin, cos, dr, True)[:, :, 0]
+        return q_i, k_i, w
+
+
 class LatentAttention(nn.Module):
     """Multi-head latent attention with an explicit latent cache.
 
@@ -1275,19 +1540,39 @@ class LatentAttention(nn.Module):
     (sum p c) Wkvb_v``), so the step attends over the latent itself
     (``absorbed_latent_attention``) and never builds K or V of the row.
 
-    The cache is ``{"ckv": [B, S, r], "k_rope": [B, S, dr]}`` written at
-    ``cache_index`` (one scalar for all rows: the plain sampler). A span
-    (prefill) must start at slot 0: it attends over its own keys, expanded,
-    and leaves its latents in the cache. ``kv_b_proj`` takes no LoRA adapter:
-    the absorbed form folds its matrix, not its output."""
+    **Under a learned selection** (``indexer`` ``full`` or ``shared``:
+    ``cfg.index_topk`` > 0) a query's softmax runs over the ``index_topk``
+    visible keys of the largest index score (``index_scores``) only. A
+    ``full`` layer makes the selection from its own ``Indexer``; a ``shared``
+    layer is handed the one in force (``selection``) and holds no indexer.
+    Both forms honour it and hand it on: an expanded pass of more than
+    ``index_topk`` tokens as a mask ``[B, T, T]`` built over blocks of queries
+    (``select_keys``) and applied by the flash kernels as one more mask a tile
+    (``flash_attention(..., selection=)``; by ``selected_attention``'s masked
+    einsums where no kernel runs), a single-token step on more than
+    ``index_topk`` slots as slot numbers ``[B, index_topk]``
+    (``select_slots``), the chosen latents gathered and attended over in
+    absorbed form. A row no longer than
+    ``index_topk`` selects every causal key and runs as without an indexer
+    (``selection`` None). The selection has no gradient.
+
+    The cache is ``{"ckv": [B, S, r], "k_rope": [B, S, dr]}``, with
+    ``"k_index": [B, S, DI]`` on a ``full`` layer, written at ``cache_index``
+    (one scalar for all rows: the plain sampler). A span (prefill) must
+    start at slot 0: it attends over its own keys, expanded, and leaves its
+    latents in the cache. ``kv_b_proj`` takes no LoRA adapter: the absorbed
+    form folds its matrix, not its output."""
 
     config: TransformerConfig
+    indexer: Optional[str] = None  # this layer's LayerLayout.indexer
+    lends: bool = False  # the next layer borrows the selection in force here
 
     @nn.compact
-    def __call__(self, x, attention_bias, positions, cache=None, cache_index=None, flash_args=None, kv_extents=None):
+    def __call__(self, x, attention_bias, positions, cache=None, cache_index=None, flash_args=None, kv_extents=None, selection=None):
         cfg = self.config
         B, T, _ = x.shape
         H, dn, dr, dv, r = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_dims_per_head, cfg.kv_lora_rank
+        topk = cfg.index_topk if self.indexer else 0
         if "kv_b_proj" in cfg.lora_targets and cfg.lora_r:
             raise ValueError(
                 "kv_b_proj takes no LoRA adapter: a decode step folds its matrix into the "
@@ -1310,6 +1595,7 @@ class LatentAttention(nn.Module):
 
         sin, cos = rotary_sin_cos(positions, dr, cfg.rope_theta)
         k_r = apply_rotary(kv_a[..., None, r:], sin, cos, dr, True)[:, :, 0]  # [B, T, dr]: one head
+        index = Indexer(cfg, name="indexer")(cq, x, sin, cos) if self.indexer == "full" else None
 
         def queries(cq, sin, cos):
             q = project(q_b, cq, cfg).reshape(*cq.shape[:2], H, dn + dr)
@@ -1324,15 +1610,31 @@ class LatentAttention(nn.Module):
                 "ckv": jax.lax.dynamic_update_slice(cache["ckv"], c.astype(cache["ckv"].dtype), (0, ci, 0)),
                 "k_rope": jax.lax.dynamic_update_slice(cache["k_rope"], k_r.astype(cache["k_rope"].dtype), (0, ci, 0)),
             }
-        if cache is not None and T == 1:
+            if index is not None:
+                new_cache["k_index"] = jax.lax.dynamic_update_slice(cache["k_index"], index[1].astype(cache["k_index"].dtype), (0, ci, 0))
+        step = cache is not None and T == 1
+        # the selection binds where the keys in reach outnumber it: a step's cache slots, a pass's tokens
+        selects = bool(topk) and (cache["ckv"].shape[1] if step else T) > topk
+        if selects and index is None and selection is None:
+            raise ValueError("a layer whose indexer type is `shared` was handed no selection")
+        if step:
             q_n, q_r = queries(cq, sin, cos)
             q_c = jnp.einsum("bhn,rhn->bhr", q_n[:, 0], w_kvb[..., :dn])
-            o_c = absorbed_latent_attention(
-                q_c, q_r[:, 0], new_cache["ckv"], new_cache["k_rope"], attention_bias, ci,
-                kv_extents.slots if kv_extents is not None else None, 1.0 / np.sqrt(dn + dr), cfg.dtype,
-            )
+            ckv, k_rope, bias = new_cache["ckv"], new_cache["k_rope"], attention_bias
+            extents = kv_extents.slots if kv_extents is not None else None
+            if selects:
+                if index is not None:
+                    selection = select_slots(index[0][:, 0], new_cache["k_index"], index[2][:, 0], bias, ci, extents, topk)
+                with jax.named_scope("trlx/attn_sparse"):
+                    # the chosen slots' latents, and their own places in the bias
+                    ckv, k_rope = (jnp.take_along_axis(a, selection[:, :, None], axis=1) for a in (ckv, k_rope))
+                    bias = jnp.take_along_axis(bias, selection[:, None, None, :], axis=3)
+                extents = None
+            else:
+                selection = None
+            o_c = absorbed_latent_attention(q_c, q_r[:, 0], ckv, k_rope, bias, ci, extents, 1.0 / np.sqrt(dn + dr), cfg.dtype)
             out = jnp.einsum("bhr,rhv->bhv", o_c, w_kvb[..., dn:]).reshape(B, 1, H * dv)
-            return project(o, out, cfg), new_cache
+            return project(o, out, cfg), new_cache, (selection if self.lends else None)
 
         use_flash = flash_args is not None
         if use_flash and _maybe_ring_mesh(T) is not None:
@@ -1341,28 +1643,56 @@ class LatentAttention(nn.Module):
                 "(parallel/ring_attention.py); latent attention is not built for it: use sequence=1"
             )
 
-        def expanded(cq, c, k_r, sin, cos, visible):
+        def expanded(cq, c, k_r, sin, cos, visible, chosen):
             """Whole rows ``[b, T, ...]``; ``visible`` is their key mask
-            (flash) or their additive bias (einsum path)."""
+            (flash) or their additive bias (einsum path); ``chosen`` what
+            decides their selection: the indexer's ``(q_i, k_i, w)``, the
+            selection handed in, or nothing."""
             b = cq.shape[0]
             with jax.named_scope("trlx/attn_latent_expand"):
                 q_n, q_r = queries(cq, sin, cos)
                 kv = jnp.einsum("btr,rhd->bthd", c, w_kvb)
                 k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(k_r[:, :, None, :], (b, T, H, dr))], axis=-1)
                 q, v = jnp.concatenate([q_n, q_r], axis=-1), kv[..., dn:]
-            if use_flash:
+            if selects:
+                def seen(start, tq, tk):  # [b, tq, tk]: causal by slot, and no padded key
+                    if not use_flash:
+                        return jax.lax.dynamic_slice(visible[:, 0], (0, start, 0), (b, tq, tk)) > -1.0
+                    causal = jnp.arange(tk)[None, :] <= start + jnp.arange(tq)[:, None]
+                    return causal[None] & (visible[:, None, :tk] > 0)
+
+                if index is not None:
+                    chosen = select_keys(*chosen, seen, topk)
+                if use_flash:  # the flash kernels under one more mask a tile
+                    out = _flash_attention(q, k, v, {**flash_args, "key_mask": visible, "selection": chosen})
+                else:
+                    out = selected_attention(q, k, v, seen, chosen, cfg.dtype)
+            elif use_flash:
                 out = _flash_attention(q, k, v, {**flash_args, "key_mask": visible})
             else:
                 out = grouped_einsum_attention(q, k, v, visible, cfg.dtype)
-            return project(o, out.reshape(b, T, H * dv), cfg)
+            out = out.reshape(b, T, H * dv)
+            if selects:
+                # a group of rows at a time: on a whole [7168, H dv] piece the TPU compiler
+                # (jax 0.9.0) runs out of scoped VMEM fusing o_proj's product with its adapter's
+                groups = jnp.split(out, list(range(SPARSE_KEY_GROUP, T, SPARSE_KEY_GROUP)), axis=1)
+                out = jnp.concatenate([project(o, rows, cfg) for rows in groups], axis=1)
+            else:
+                out = project(o, out, cfg)
+            return out, (chosen if selects and index is not None and self.lends else None)
 
-        operands = (cq, c, k_r, sin, cos, flash_args["key_mask"] if use_flash else attention_bias)
+        chosen = (index if index is not None else selection) if selects else None
+        operands = (cq, c, k_r, sin, cos, flash_args["key_mask"] if use_flash else attention_bias, chosen)
         pieces = latent_row_pieces(B, T)
         if pieces == 1:
-            return expanded(*operands), new_cache
-        split = lambda a: a.reshape(pieces, B // pieces, *a.shape[1:])
-        out = jax.lax.map(lambda piece: expanded(*piece), tuple(split(a) for a in operands))
-        return out.reshape(B, T, cfg.hidden_size), new_cache
+            out, made = expanded(*operands)
+        else:
+            split = lambda a: a.reshape(pieces, B // pieces, *a.shape[1:])
+            join = lambda a: a.reshape(B, *a.shape[2:])
+            out, made = jax.tree_util.tree_map(join, jax.lax.map(lambda piece: expanded(*piece), jax.tree_util.tree_map(split, operands)))
+        if not (selects and self.lends):
+            return out, new_cache, None
+        return out, new_cache, (made if index is not None else selection)
 
 
 class MLP(nn.Module):
@@ -1619,7 +1949,14 @@ class MoEMLP(nn.Module):
             probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
         else:
             scores = probs = jax.nn.softmax(logits, axis=-1)
-        gate_vals, idx = jax.lax.top_k(scores, K)  # [B, T, K]
+        if cfg.moe_topk_method == "noaux_tc":
+            # the bias decides WHICH experts, never how much: the gates are
+            # the chosen experts' scores without it
+            bias = self.param("router_bias", param_with_axes(nn.initializers.normal(cfg.moe_bias_init_std), ("expert_sel",)), (E,), cfg.param_dtype)
+            _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), K)
+            gate_vals = jnp.take_along_axis(scores, idx, axis=-1)
+        else:
+            gate_vals, idx = jax.lax.top_k(scores, K)  # [B, T, K]
         chosen_scores = gate_vals
         if cfg.moe_renormalize:
             gate_vals = gate_vals / jnp.maximum(
@@ -1954,7 +2291,11 @@ class Block(nn.Module):
     layer: int = 0  # which entry of the config's per-layer layout this block reads
 
     @nn.compact
-    def __call__(self, x, attention_bias, positions, cache=None, cache_index=None, flash_args=None, token_mask=None, kv_extents=None):
+    def __call__(self, x, attention_bias, positions, cache=None, cache_index=None, flash_args=None, token_mask=None, kv_extents=None, selection=None):
+        """``(x, new_cache, aux, selection)``: ``selection`` is the set of
+        keys in force behind this layer (``LatentAttention``), which the next
+        layer attends over if its indexer type is ``shared``; None wherever no
+        selection binds."""
         cfg = self.config
         layout = cfg.layer_layout(self.layer)
         rotary, sparse = layout.rotary, layout.ffn == "moe"
@@ -1977,9 +2318,12 @@ class Block(nn.Module):
                 new_cache = {**new_cache, **new_state}
             x = x + mix_out * cfg.ssm_out_multiplier + attn_out * cfg.attention_out_multiplier
             mlp_out, aux = run_mlp(Norm(cfg, name="ln_mlp")(x))
-            return x + mlp_out, new_cache, aux
+            return x + mlp_out, new_cache, aux, None
         if cfg.latent_attention:
-            attn_out, new_cache = LatentAttention(cfg, name="attn")(h, attention_bias, positions, cache, cache_index, flash_args, kv_extents)
+            lends = self.layer + 1 < cfg.num_layers and cfg.layer_layout(self.layer + 1).indexer == "shared"
+            attn_out, new_cache, selection = LatentAttention(cfg, layout.indexer, lends, name="attn")(
+                h, attention_bias, positions, cache, cache_index, flash_args, kv_extents, selection
+            )
         else:
             attn_out, new_cache = Attention(cfg, rotary, name="attn")(h, attention_bias, positions, cache, cache_index, flash_args, kv_extents)
         if cfg.sandwich_norm:
@@ -1995,7 +2339,7 @@ class Block(nn.Module):
             if cfg.sandwich_norm:
                 mlp_out = Norm(cfg, name="ln_mlp_post")(mlp_out)
             x = x + mlp_out
-        return x, new_cache, aux
+        return x, new_cache, aux, selection
 
 
 def _remat_policy(cfg: TransformerConfig):
@@ -2032,7 +2376,9 @@ class _ScanBlockBody(nn.Module):
     @nn.compact
     def __call__(self, carry, cache_layer, layer_idx, attention_bias, positions, cache_index, flash_args, branch_at, token_mask, kv_extents):
         x, branch_input, aux_sum = carry
-        x_new, new_cache, aux = _block_cls(self.config)(self.config, name="block")(
+        # one body for every layer: a stack whose layers all select for
+        # themselves runs here, one that lends a selection is a mixed layout
+        x_new, new_cache, aux, _ = _block_cls(self.config)(self.config, name="block")(
             x, attention_bias, positions, cache_layer, cache_index, flash_args, token_mask, kv_extents
         )
         if branch_input is not None:  # static: only hydra passes pay for it
@@ -2078,8 +2424,9 @@ class CausalTransformer(nn.Module):
             raise NotImplementedError(
                 "scan_layers (and the pipeline schedule, which needs it) runs ONE Block "
                 f"body over stacked parameters; model_type {cfg.model_type!r} has layers of "
-                f"more than one attention layout or feed-forward kind ({sorted(set(cfg.layer_layouts), key=str)}): run it "
-                "with scan_layers=False (ROADMAP.md queue 2, B3: a scan over whole periods)"
+                f"more than one attention layout, indexer type or feed-forward kind ({sorted(set(cfg.layer_layouts), key=str)}), "
+                "and its carry has no place for a selection of keys that one layer hands the next: run it "
+                "with scan_layers=False (ROADMAP.md queue 2, B3: a scan over whole periods; B8: the selection in its carry)"
             )
         if cfg.scan_layers:
             # roll all blocks into one lax.scan over stacked params — one
@@ -2345,12 +2692,16 @@ class CausalTransformer(nn.Module):
                 branch_input = branch_buf
         else:
             new_cache = [] if cache is not None else None
+            selection = None
             for i, block in enumerate(self.blocks):
                 if branch_layer is not None and i == len(self.blocks) - branch_layer:
-                    branch_input = x
+                    # a branch that starts at a layer which borrows its keys
+                    # is handed them with the hidden states (forward_branch)
+                    borrows = selection is not None and cfg.layer_layout(i).indexer == "shared"
+                    branch_input = (x, selection) if borrows else x
                 layer_cache = cache[i] if cache is not None else None
                 bias, flash_args, view = plans[i]
-                x, updated, aux_i = block(x, bias, positions, layer_cache, cache_index, flash_args, token_mask, view)
+                x, updated, aux_i, selection = block(x, bias, positions, layer_cache, cache_index, flash_args, token_mask, view, selection)
                 aux = aux + aux_i
                 if cache is not None:
                     new_cache.append(updated)
@@ -2415,7 +2766,7 @@ class CausalTransformer(nn.Module):
             bias_mb, flash_mb, pos_mb, tm = attn_inputs
             return body_block.apply(
                 {"params": layer_params}, h, bias_mb, pos_mb, cache_layer, cidx, flash_mb, tm, kv_extents
-            )
+            )[:3]  # one layout a stack (scan_layers): no layer lends its selection
 
         if cfg.remat in ("full", "minimal"):
             apply_block = jax.checkpoint(apply_block, policy=_remat_policy(cfg))
@@ -2451,6 +2802,9 @@ class CausalTransformer(nn.Module):
         second-model-free KL baseline (``modeling_ppo.py:394-427``).
         """
         cfg = self.config
+        selection = None
+        if isinstance(hidden_states, tuple):  # with the keys its first layer borrows (__call__)
+            hidden_states, selection = hidden_states
         B, T, _ = hidden_states.shape
         if attention_mask is None:
             attention_mask = jnp.ones((B, T), jnp.int32)
@@ -2475,7 +2829,7 @@ class CausalTransformer(nn.Module):
             body_block = Block(cfg, parent=None)
 
             def body(h, layer_params):
-                out, _, _ = body_block.apply(
+                out, _, _, _ = body_block.apply(
                     {"params": layer_params}, h, bias, positions,
                     flash_args=flash_args, token_mask=attention_mask,
                 )
@@ -2486,7 +2840,7 @@ class CausalTransformer(nn.Module):
             x, _ = jax.lax.scan(body, x, sliced)
         else:
             for block, (bias, flash_args, _) in zip(self.blocks[len(self.blocks) - branch_layer :], plans):
-                x, _, _ = block(x, bias, positions, flash_args=flash_args, token_mask=attention_mask)
+                x, _, _, selection = block(x, bias, positions, flash_args=flash_args, token_mask=attention_mask, selection=selection)
         h = self.ln_f(x) if cfg.final_norm else x
         logits = self._logits(h if logits_span is None else h[:, logits_span[0] : logits_span[1]])
         return {"logits": logits, "hidden_states": h}
@@ -2521,7 +2875,10 @@ def make_kv_cache(
     latent-attention layer (``kv_lora_rank`` > 0) holds ``ckv`` ``[B, slots,
     kv_lora_rank]`` and ``k_rope`` ``[B, slots, qk_rope_head_dim]`` IN PLACE
     of ``k`` and ``v``: 576 numbers a slot at the published widths where
-    per-head K and V would be 40,960.
+    per-head K and V would be 40,960. Under a learned selection
+    (``index_topk`` > 0) a layer whose indexer type is ``full`` also holds
+    ``k_index`` ``[B, slots, index_head_dim]``, the index keys its decode
+    steps score; a ``shared`` layer holds none.
     """
     dtype = dtype or cfg.dtype
     stacked = (cfg.num_layers,) if cfg.scan_layers else ()
@@ -2531,10 +2888,15 @@ def make_kv_cache(
         if cfg.latent_attention:
             # the normed latent and the one roped key (ops/paged_kv.py::LATENT_LEAVES), the
             # same slot axis and cache_index as K and V have, and no K or V
-            return {
+            latent = {
                 "ckv": jnp.zeros(stacked + (batch_size, slots, cfg.kv_lora_rank), dtype),
                 "k_rope": jnp.zeros(stacked + (batch_size, slots, cfg.qk_rope_head_dim), dtype),
             }
+            if layout.indexer == "full":
+                # the indexer's ONE normed, roped key a slot (ops/paged_kv.py::INDEX_LEAVES),
+                # on the layers that select for themselves only
+                latent["k_index"] = jnp.zeros(stacked + (batch_size, slots, cfg.index_head_dim), dtype)
+            return latent
         shapes = {
             "k": ((batch_size, slots, cfg.kv_heads, cfg.dims_per_head), dtype),
             "v": ((batch_size, slots, cfg.kv_heads, cfg.dims_per_head), dtype),
@@ -2585,6 +2947,7 @@ BUILTIN_SPECS = {
     "smallthinker": TransformerConfig.smallthinker,
     "falconh1": TransformerConfig.falconh1,
     "pangu": TransformerConfig.pangu,
+    "glm": TransformerConfig.glm,
     "gptj": TransformerConfig.gptj,
     "gptneox": TransformerConfig.gptneox,
     "pythia": TransformerConfig.gptneox,

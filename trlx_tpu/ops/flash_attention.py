@@ -51,6 +51,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 from trlx_tpu.ops.pallas_utils import (  # noqa: F401  (NEG_INF/LANES re-export)
@@ -551,6 +552,227 @@ def _flash_bwd_rule(
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
+# ---------------------------------------------------------------------------
+# under a selection: each query's softmax over a set of keys of its own
+# ---------------------------------------------------------------------------
+
+
+def _selected_fwd_kernel(
+    q_ref,  # (1, 1, bQ, D)
+    k_ref,  # (1, 1, Sp, D)
+    v_ref,  # (1, 1, Sp, Dv)
+    kmask_ref,  # (1, 1, Sp)
+    sel_ref,  # (1, bQ, Sp) int8: nonzero = this query keeps this key; one for all heads
+    o_ref,  # (1, 1, bQ, Dv)
+    l_ref,  # (1, 1, bQ, LANES)
+    *,
+    sm_scale: float,
+    block_k: int,
+    seq_k: int,
+    block_q: int,
+):
+    """``_fwd_kernel``'s causal walk (slot offsets 0, no window, no ALiBi)
+    with one more mask a tile: the query block's rows of the selection. Every
+    tile up to the diagonal is visited and masked: a learned selection keeps
+    some key of nearly every tile, so there is none to skip."""
+    iq = pl.program_id(2)
+    q = q_ref[0, 0].astype(jnp.float32) * sm_scale
+    q0 = iq * block_q
+    q_slots = q0 + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+    hi = jnp.clip((q0 + block_q + block_k - 1) // block_k, 0, seq_k // block_k)
+
+    def tile(ik, carry):
+        acc, m, l = carry
+        k = k_ref[0, 0, pl.ds(ik * block_k, block_k), :].astype(jnp.float32)
+        v = v_ref[0, 0, pl.ds(ik * block_k, block_k), :].astype(jnp.float32)
+        kmask = kmask_ref[0, 0, pl.ds(ik * block_k, block_k)].reshape(1, block_k)
+        chosen = sel_ref[0, :, pl.ds(ik * block_k, block_k)].astype(jnp.int32) != 0
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )  # (bQ, bK)
+        k_slots = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+        visible = (kmask > 0.5) & (k_slots <= q_slots) & chosen
+        s = jnp.where(visible, s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new) * visible.astype(jnp.float32)
+        alpha = jnp.exp(m - m_new)
+        l = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+        pv = jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        return acc * alpha + pv, m_new, l
+
+    acc = jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32)
+    m = jnp.full((block_q, 1), NEG_INF, jnp.float32)
+    l = jnp.zeros((block_q, 1), jnp.float32)
+    acc, m, l = jax.lax.fori_loop(0, hi, tile, (acc, m, l))
+
+    safe_l = jnp.where(l > 0.0, l, 1.0)
+    o_ref[0, 0] = (acc / safe_l).astype(o_ref.dtype)
+    logsum = jnp.where(l > 0.0, m + jnp.log(safe_l), NEG_INF)
+    l_ref[0, 0] = jnp.broadcast_to(logsum, (block_q, LANES))
+
+
+def _selected_bwd_kernel(
+    q_ref,  # (1, 1, Tp, D)
+    k_ref,  # (1, 1, bK, D)
+    v_ref,  # (1, 1, bK, Dv)
+    kmask_ref,  # (1, 1, bK)
+    sel_ref,  # (1, Tp, bK) int8: the key block's columns of the selection
+    lse_ref,  # (1, 1, Tp, LANES)
+    delta_ref,  # (1, 1, Tp, LANES)
+    do_ref,  # (1, 1, Tp, Dv)
+    dq_ref,  # (1, 1, Tp, D) f32, accumulated across the k-block grid dim
+    dk_ref,  # (1, 1, bK, D)
+    dv_ref,  # (1, 1, bK, Dv)
+    *,
+    sm_scale: float,
+    block_q: int,
+    seq_q: int,
+    block_k: int,
+):
+    """``_bwd_fused_kernel`` under the same selection: dq, dk and dv in one
+    pass over the query blocks at or below the key block's diagonal."""
+    ik = pl.program_id(2)
+
+    @pl.when(ik == 0)
+    def _init():
+        dq_ref[...] = jnp.zeros_like(dq_ref)
+
+    k = k_ref[0, 0].astype(jnp.float32)
+    v = v_ref[0, 0].astype(jnp.float32)
+    kmask = kmask_ref[0, 0].reshape(1, block_k)
+    k0 = ik * block_k
+    k_slots = k0 + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+    n_q = seq_q // block_q
+    lo = jnp.clip(k0 // block_q, 0, n_q)
+
+    def tile(iq, carry):
+        dk, dv = carry
+        q = q_ref[0, 0, pl.ds(iq * block_q, block_q), :].astype(jnp.float32) * sm_scale
+        do = do_ref[0, 0, pl.ds(iq * block_q, block_q), :].astype(jnp.float32)
+        lse = lse_ref[0, 0, pl.ds(iq * block_q, block_q), 0:1]
+        delta = delta_ref[0, 0, pl.ds(iq * block_q, block_q), 0:1]
+        chosen = sel_ref[0, pl.ds(iq * block_q, block_q), :].astype(jnp.int32) != 0
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        q_slots = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+        visible = (kmask > 0.5) & (k_slots <= q_slots) & chosen
+        p = jnp.exp(jnp.where(visible, s, NEG_INF) - lse) * visible.astype(jnp.float32)
+        dv_blk = jax.lax.dot_general(
+            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        dp = jax.lax.dot_general(
+            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        ds = p * (dp - delta)
+        dk_blk = jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        dq_blk = jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        cur = dq_ref[0, 0, pl.ds(iq * block_q, block_q), :]
+        dq_ref[0, 0, pl.ds(iq * block_q, block_q), :] = cur + dq_blk * sm_scale
+        return dk + dk_blk, dv + dv_blk
+
+    dk = jnp.zeros((block_k, k_ref.shape[-1]), jnp.float32)
+    dv = jnp.zeros((block_k, v_ref.shape[-1]), jnp.float32)
+    dk, dv = jax.lax.fori_loop(lo, n_q, tile, (dk, dv))
+    dk_ref[0, 0] = dk.astype(dk_ref.dtype)
+    dv_ref[0, 0] = dv.astype(dv_ref.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _selected(q, k, v, kmask, sel, sm_scale: float, block_q: int, block_k: int, interpret: bool):
+    """MHA ``q, k (B, H, T, D)``, ``v (B, H, T, Dv)``, ``kmask (B, 1, T)``
+    float, ``sel (B, T, T)`` int8, all padded to the tiles: causal attention
+    with each query's softmax over the keys it keeps."""
+    return _selected_fwd_impl(q, k, v, kmask, sel, sm_scale, block_q, block_k, interpret)[0]
+
+
+def _selected_fwd_impl(q, k, v, kmask, sel, sm_scale, block_q, block_k, interpret):
+    B, H, T, D = q.shape
+    S, Dv = k.shape[2], v.shape[3]
+    kernel = functools.partial(
+        _selected_fwd_kernel, sm_scale=sm_scale, block_k=block_k, seq_k=S, block_q=block_q
+    )
+    itemsize = q.dtype.itemsize
+    resident = 2 * S * ((D + Dv) * itemsize + 8 * 4) + 2 * block_q * S
+    return pl.pallas_call(
+        kernel,
+        grid=(B, H, T // block_q),
+        in_specs=[
+            pl.BlockSpec((1, 1, block_q, D), lambda b, h, i: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, S, D), lambda b, h, i: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, S, Dv), lambda b, h, i: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, S), lambda b, h, i: (b, 0, 0)),
+            pl.BlockSpec((1, block_q, S), lambda b, h, i: (b, i, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, 1, block_q, Dv), lambda b, h, i: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, block_q, LANES), lambda b, h, i: (b, h, i, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((B, H, T, Dv), q.dtype),
+            jax.ShapeDtypeStruct((B, H, T, LANES), jnp.float32),
+        ],
+        interpret=interpret,
+        name=FWD_KERNEL_NAME,
+        **_vmem_params(resident, _tile_working_bytes(block_q, block_k, max(D, Dv), itemsize), interpret),
+    )(q, k, v, kmask, sel)
+
+
+def _selected_fwd_rule(q, k, v, kmask, sel, sm_scale, block_q, block_k, interpret):
+    out, lse = _selected_fwd_impl(q, k, v, kmask, sel, sm_scale, block_q, block_k, interpret)
+    return out, (q, k, v, kmask, sel, out, lse)
+
+
+def _selected_bwd_rule(sm_scale, block_q, block_k, interpret, res, do):
+    q, k, v, kmask, sel, out, lse = res
+    B, H, T, D = q.shape
+    S, Dv = k.shape[2], v.shape[3]
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    delta = jnp.broadcast_to(delta[..., None], (B, H, T, LANES))
+    kernel = functools.partial(
+        _selected_bwd_kernel, sm_scale=sm_scale, block_q=block_q, seq_q=T, block_k=block_k
+    )
+    itemsize = q.dtype.itemsize
+    resident = 2 * T * ((D + Dv) * itemsize + D * 4 + 2 * 128 * 4) + 2 * T * block_k
+    dq, dk, dv = pl.pallas_call(
+        kernel,
+        grid=(B, H, S // block_k),
+        in_specs=[
+            pl.BlockSpec((1, 1, T, D), lambda b, h, i: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, block_k, D), lambda b, h, i: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, block_k, Dv), lambda b, h, i: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, block_k), lambda b, h, i: (b, 0, i)),
+            pl.BlockSpec((1, T, block_k), lambda b, h, i: (b, 0, i)),
+            pl.BlockSpec((1, 1, T, LANES), lambda b, h, i: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, T, LANES), lambda b, h, i: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, T, Dv), lambda b, h, i: (b, h, 0, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, 1, T, D), lambda b, h, i: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, block_k, D), lambda b, h, i: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, block_k, Dv), lambda b, h, i: (b, h, i, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((B, H, T, D), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, S, D), k.dtype),
+            jax.ShapeDtypeStruct((B, H, S, Dv), v.dtype),
+        ],
+        interpret=interpret,
+        name=BWD_KERNEL_NAME,
+        **_vmem_params(resident, _tile_working_bytes(block_q, block_k, max(D, Dv), itemsize), interpret),
+    )(q, k, v, kmask, sel, lse, delta, do)
+    no_gradient = np.zeros(sel.shape, jax.dtypes.float0)  # an integer operand's cotangent
+    return dq.astype(q.dtype), dk, dv, jnp.zeros_like(kmask), no_gradient
+
+
+_selected.defvjp(_selected_fwd_rule, _selected_bwd_rule)
+
 
 def flash_attention_bwd_chunk(
     q: jax.Array,  # (B, T, H, D) local queries
@@ -732,8 +954,14 @@ def flash_attention(
     interpret: Optional[bool] = None,
     return_lse: bool = False,
     window: Optional[int] = None,  # sliding-window width (None = unbounded)
+    selection: Optional[jax.Array] = None,  # (B, T, S) nonzero = this query keeps this key
 ):
     """Flash attention over ``[B, T, H, D]`` tensors (model layout).
+
+    With ``selection`` each query's softmax runs over the keys it keeps (a
+    learned sparse selection, one for all heads), causal and under the key
+    mask as always: MHA over the row's own keys (``S = T``, slot offsets 0),
+    no window, no ALiBi; kernels of their own, under the same names.
 
     Pads T/S up to block multiples internally; padded key slots are invisible
     (mask 0), padded query rows produce zeros and are sliced off. With
@@ -752,6 +980,21 @@ def flash_attention(
         sm_scale = 1.0 / (D ** 0.5)
     alibi = alibi_slopes is not None
     block_q, block_k = _resolve_blocks(block_q, block_k, T, S, interpret)
+    if selection is not None:
+        if not causal or alibi or window or return_lse or H != KV or S != T or selection.shape != (B, T, S):
+            raise ValueError(
+                "a selection runs causal MHA over the row's own keys: no window, no ALiBi, "
+                f"no lse, a (B, T, T) selection (got {selection.shape} for q {q.shape}, k {k.shape})"
+            )
+        tile = max(block_q, block_k)  # one padded length for queries and keys (the smaller tile divides it)
+        pad = lambda a, axis: _pad_to(a, tile, axis)
+        sel = pad(pad(selection.astype(jnp.int8), 1), 2)
+        out = _selected(
+            pad(q.transpose(0, 2, 1, 3), 2), pad(k.transpose(0, 2, 1, 3), 2), pad(v.transpose(0, 2, 1, 3), 2),
+            pad(key_mask.astype(jnp.float32), 1).reshape(B, 1, -1), sel,
+            sm_scale, block_q, block_k, interpret,
+        )
+        return out[:, :, :T, :].transpose(0, 2, 1, 3)
 
     # [B, T, H, D] → [B, H, T, D]
     qt = _pad_to(q.transpose(0, 2, 1, 3), block_q, 2)
@@ -796,7 +1039,7 @@ def flash_attention(
 def attention_reference(
     q, k, v, key_mask, *, causal=True, sm_scale=None,
     q_offset=0, k_offset=0, q_positions=None, k_positions=None,
-    alibi_slopes=None, window=None,
+    alibi_slopes=None, window=None, selection=None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Naive XLA attention with identical masking semantics (test oracle).
 
@@ -816,6 +1059,8 @@ def attention_reference(
         visible = visible & (k_slots <= q_slots)[None, None, :, :]
     if window:
         visible = visible & (q_slots - k_slots < window)[None, None, :, :]
+    if selection is not None:  # (B, T, S): the keys each query keeps, one set for all heads
+        visible = visible & (selection != 0)[:, None, :, :]
     if alibi_slopes is not None:
         dist = (
             k_positions[:, None, :] - q_positions[:, :, None]

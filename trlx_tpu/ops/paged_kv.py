@@ -67,6 +67,7 @@ __all__ = [
     "refuse_recurrent_state",
     "refuse_ring_cache",
     "latent_cache_bytes",
+    "index_cache_bytes",
     "refuse_latent_cache",
 ]
 
@@ -319,10 +320,23 @@ def refuse_recurrent_state(cache: Any, path: str) -> None:
 LATENT_LEAVES = ("ckv", "k_rope")
 
 
+# what rides with the latent under a learned selection: the ONE index key a
+# slot of each layer that selects for itself (`indexer_types` "full")
+INDEX_LEAVES = ("k_index",)
+
+
 def latent_cache_bytes(cache: Any) -> int:
     """Bytes of a cache pytree's latent leaves, by leaf name (0 for a model
-    whose layers hold K and V)."""
+    whose layers hold K and V). The index keys that ride with a latent under
+    a learned selection are counted apart (``index_cache_bytes``)."""
     return _named_leaf_bytes(cache, LATENT_LEAVES)
+
+
+def index_cache_bytes(cache: Any) -> int:
+    """Bytes of a cache pytree's index-key leaves, by leaf name (0 for a
+    model without a learned selection of keys). They exist only beside a
+    latent, so whatever refuses a latent cache refuses them with it."""
+    return _named_leaf_bytes(cache, INDEX_LEAVES)
 
 
 # what each KV-only path would do with per-head K and V that a latent layer
@@ -338,12 +352,21 @@ _PER_HEAD_KV_PATHS = {
 def refuse_latent_cache(cache: Any, path: str) -> None:
     """Called where each KV-only rollout path builds its state, beside
     ``refuse_recurrent_state``: a model whose layers cache a latent (no ``k``,
-    no ``v``) stops there by name."""
+    no ``v``) stops there by name, and with it the index keys of a learned
+    selection, which ride on the same slots and which none of these paths
+    scores, selects from or moves."""
     if latent_cache_bytes(cache):
+        riding = ""
+        if index_cache_bytes(cache):
+            riding = (
+                f", and index keys with it (leaves {INDEX_LEAVES}: a learned selection of keys, "
+                "`index_topk` > 0, the glm_moe_dsa family; ROADMAP.md queue 2, B8)"
+            )
         raise NotImplementedError(
             f"{path} does not support a model whose cache holds a latent in place of K and V "
             f"(leaves {LATENT_LEAVES}: latent attention, `kv_lora_rank` > 0, the pangu_ultra_moe "
-            f"family): {_PER_HEAD_KV_PATHS[path]}; use the plain sampler (ROADMAP.md queue 2, B4)"
+            f"and glm_moe_dsa families){riding}: {_PER_HEAD_KV_PATHS[path]}; use the plain sampler "
+            "(ROADMAP.md queue 2, B4)"
         )
 
 
@@ -393,8 +416,9 @@ def dense_kv_bytes(cfg: Any, batch_size: int, slots: int) -> int:
     allocates its cache inside the jitted program, so the gauge is computed
     rather than measured (exact: shapes are static)."""
     itemsize = np.dtype(cfg.dtype).itemsize
-    if getattr(cfg, "latent_attention", False):  # the latent and the one roped key
-        return int(cfg.num_layers * batch_size * slots * (cfg.kv_lora_rank + cfg.qk_rope_head_dim) * itemsize)
+    if getattr(cfg, "latent_attention", False):  # the latent and the one roped key, and a selecting layer's index key
+        index = sum(cfg.index_head_dim for layout in cfg.layer_layouts if layout.indexer == "full")
+        return int(batch_size * slots * (cfg.num_layers * (cfg.kv_lora_rank + cfg.qk_rope_head_dim) + index) * itemsize)
     return int(
         2 * cfg.num_layers * batch_size * slots * cfg.kv_heads
         * cfg.dims_per_head * itemsize

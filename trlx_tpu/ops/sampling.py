@@ -234,12 +234,17 @@ def layer_extents(extents: Tuple[int, ...], cache_slots: int) -> Tuple[int, ...]
     return (*(e for e in extents if e < cache_slots), cache_slots)
 
 
-def kv_slots_read(extents: Tuple[int, ...], prompt_width: int, steps: int) -> int:
+def kv_slots_read(extents: Tuple[int, ...], prompt_width: int, steps: int, selected: int = 0) -> int:
     """Cache slots a row's attention reads in one layer over the first
     ``steps`` decode steps under ``extents`` (step ``i`` writes slot ``P +
     i``; a step past the last extent, in a ring, reads the last): host
-    arithmetic for ``rollout/kv_read_frac``, against ``steps * S``."""
+    arithmetic for ``rollout/kv_read_frac``, against ``steps * S``. Under a
+    learned selection of ``selected`` keys a step on a cache of more slots
+    reads that many of them, whatever the extent (the indexer's own pass over
+    the index keys still follows it)."""
     last = len(extents) - 1
+    if selected and extents[last] > selected:
+        return steps * selected
     return sum(extents[min(bisect_left(extents, prompt_width + i + 1), last)] for i in range(steps))
 
 

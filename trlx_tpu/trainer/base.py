@@ -39,7 +39,7 @@ from trlx_tpu.models.builder import (
     is_frozen,
     trainable_mask,
 )
-from trlx_tpu.models.transformer import cache_slots, make_kv_cache
+from trlx_tpu.models.transformer import cache_slots, make_kv_cache, selected_frac
 from trlx_tpu.ops.sampling import (
     GenerationConfig,
     GenerationOutput,
@@ -1474,7 +1474,10 @@ class TPUBaseTrainer(BaseRLTrainer):
         ``rollout/kv_cache_bytes`` (``k``, ``v``) and
         ``rollout/ssm_state_bytes`` (``ssm``, ``conv``; 0 for a KV-only
         model), and where the layers cache a latent in place of K and V
-        ``rollout/latent_cache_bytes`` (``ckv``, ``k_rope``; K and V then 0); where the stack mixes attention layouts, K and V are also
+        ``rollout/latent_cache_bytes`` (``ckv``, ``k_rope``; K and V then 0)
+        with ``rollout/index_cache_bytes`` beside it (``k_index``, the index
+        keys of the layers that select for themselves; 0 for a model without
+        a learned selection); where the stack mixes attention layouts, K and V are also
         split into ``rollout/kv_cache_window_bytes`` (the window layers'
         rings) and ``rollout/kv_cache_global_bytes``. The
         continuous-batching engines report their own measured gauge
@@ -1482,7 +1485,7 @@ class TPUBaseTrainer(BaseRLTrainer):
         self.last_kv_extents = self.last_kv_layers = None
         if self.is_seq2seq:
             return  # T5 cross/self caches have their own layout; not gauged
-        from trlx_tpu.ops.paged_kv import kv_bytes, latent_cache_bytes, recurrent_state_bytes
+        from trlx_tpu.ops.paged_kv import index_cache_bytes, kv_bytes, latent_cache_bytes, recurrent_state_bytes
 
         B, P = prompt_shape
         S = P + gen_config.max_new_tokens
@@ -1504,13 +1507,15 @@ class TPUBaseTrainer(BaseRLTrainer):
         policy_cache = cache(self.tcfg, S)
         state = recurrent_state_bytes(policy_cache)
         latent = latent_cache_bytes(policy_cache)
+        index = index_cache_bytes(policy_cache)
         total = kv_bytes(policy_cache) - state
         self.last_cache_stats = {
-            "rollout/kv_cache_bytes": float(total - latent),
+            "rollout/kv_cache_bytes": float(total - latent - index),
             "rollout/ssm_state_bytes": float(state),
         }
-        if latent:  # the layers cache a latent in place of K and V
+        if latent:  # the layers cache a latent in place of K and V, and index keys with it
             self.last_cache_stats["rollout/latent_cache_bytes"] = float(latent)
+            self.last_cache_stats["rollout/index_cache_bytes"] = float(index)
         if not self.tcfg.scan_layers:
             layouts = self.tcfg.layer_layouts
             self.last_kv_layers = tuple(
@@ -2167,6 +2172,8 @@ class TPUBaseTrainer(BaseRLTrainer):
                         stats["learn/attn_tile"],
                         stats["learn/attn_interior_frac"],
                     ) = self._attn_tile_walk(width)
+                    if getattr(self.tcfg, "index_topk", 0):  # every layer attends under the selection
+                        stats["learn/attn_selected_frac"] = selected_frac(width, self.tcfg.index_topk)
                     batch_size = next(
                         v.shape[0] for v in batch.values() if hasattr(v, "shape")
                     ) if isinstance(batch, dict) else self.config.train.batch_size

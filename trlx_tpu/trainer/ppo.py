@@ -705,7 +705,7 @@ class PPOTrainer(TPUBaseTrainer):
             P, N = chunk["prompt_ids"].shape[1], response_mask.shape[1]
             extents = chunk.get("kv_extents") or (P + N,)
             for slots, windowed in chunk.get("kv_layers") or ((P + N, False),):
-                read = kv_slots_read(layer_extents(extents, slots), P, decode_steps)
+                read = kv_slots_read(layer_extents(extents, slots), P, decode_steps, getattr(self.tcfg, "index_topk", 0))
                 acc["kv_slots_read"] += read
                 acc["kv_slots"] += decode_steps * (P + N)
                 if windowed:
