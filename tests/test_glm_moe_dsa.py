@@ -37,9 +37,10 @@ from trlx_tpu.models.transformer import (
     largest_k,
     make_kv_cache,
     selected_frac,
+    sparse_gather_rows,
 )
 from trlx_tpu.ops import sampling
-from trlx_tpu.ops.paged_kv import index_cache_bytes, latent_cache_bytes, refuse_latent_cache
+from trlx_tpu.ops.paged_kv import dense_kv_bytes, index_cache_bytes, latent_cache_bytes, refuse_latent_cache
 from trlx_tpu.ops.sampling import GenerationConfig, generate, kv_slots_read
 
 # Relative L2 of the logits. Both sides compute in float32 on the CPU; what is
@@ -228,7 +229,8 @@ def test_selected_frac_at_the_cells_width():
 def test_cache_tree_holds_index_keys_on_full_layers_only():
     cache = jax.eval_shape(lambda: make_kv_cache(CFG, B, T))
     assert [sorted(layer) for layer in cache] == [
-        ["ckv", "k_index", "k_rope"] if kind == "full" else ["ckv", "k_rope"] for kind in TYPES]
+        ["k_index", "latent"] if kind == "full" else ["latent"] for kind in TYPES]
+    assert cache[1]["latent"].shape == (B, T, CFG.kv_lora_rank + CFG.qk_rope_head_dim) == (B, T, 16 + 8)
     assert cache[0]["k_index"].shape == (B, T, CFG.index_head_dim)
     assert latent_cache_bytes(cache) == 5 * B * T * (16 + 8) * 4
     assert index_cache_bytes(cache) == 2 * B * T * 12 * 4
@@ -279,22 +281,195 @@ def test_a_decode_step_past_index_topk_reads_the_borrowed_set_on_a_shared_layer(
         chosen.append(select(*a, **kw))
         return chosen[-1]
 
-    def noting_absorbed(q_c, q_r, ckv, *a, **kw):
-        attended.append(ckv)
-        return absorbed(q_c, q_r, ckv, *a, **kw)
+    def noting_absorbed(q_c, q_r, ckv, k_rope, *a, **kw):
+        attended.append(jnp.concatenate([ckv, k_rope], axis=-1))
+        return absorbed(q_c, q_r, ckv, k_rope, *a, **kw)
 
     monkeypatch.setattr(transformer, "select_slots", noting_select)
     monkeypatch.setattr(transformer, "absorbed_latent_attention", noting_absorbed)
     _, cache = decode_through_the_caches(params, ids, mask, T - 1)
     assert len(chosen) == 2 and len(attended) == 5
     assert all(c.shape == (B, CFG.index_topk) for c in chosen)
-    assert all(a.shape == (B, CFG.index_topk, CFG.kv_lora_rank) for a in attended)
+    assert all(a.shape == (B, CFG.index_topk, CFG.kv_lora_rank + CFG.qk_rope_head_dim) for a in attended)
+    assert all(int(c.min()) >= 0 for c in chosen)  # every row has index_topk visible slots here
     assert not np.array_equal(np.sort(chosen[0]), np.sort(chosen[1]))
     for layer in (1, 2, 3):
-        borrowed = jnp.take_along_axis(cache[layer]["ckv"], chosen[0][:, :, None], axis=1)
+        borrowed = jnp.take_along_axis(cache[layer]["latent"], chosen[0][:, :, None], axis=1)
         assert np.array_equal(np.asarray(attended[layer]), np.asarray(borrowed))
-    own = jnp.take_along_axis(cache[4]["ckv"], chosen[1][:, :, None], axis=1)
+    own = jnp.take_along_axis(cache[4]["latent"], chosen[1][:, :, None], axis=1)
     assert np.array_equal(np.asarray(attended[4]), np.asarray(own))
+
+
+PANGU = TransformerConfig.pangu("test", param_dtype=jnp.float32, dtype=jnp.float32, attention_impl="xla")
+
+
+@pytest.mark.parametrize("prompt", [5, 21])
+@pytest.mark.parametrize("cfg", [CFG, PANGU], ids=["glm", "pangu"])
+def test_cached_steps_give_the_logits_of_the_cacheless_pass(cfg, prompt):
+    """Both families, one leaf ``latent`` a layer under glm's selection and
+    ``ckv`` beside ``k_rope`` without one: a prefill (two column ranges
+    written) and then a slot a step, against the program's own expanded
+    pass without a cache. Rows of 0, 8 and 16 pads on 40 slots: the glm steps
+    select on every layer, and from a prompt of 5 a row's first steps see
+    fewer than ``index_topk`` slots (picks of -1)."""
+    params, (ids, mask) = seeded_params(11, cfg), batch(11)
+    got, cache = decode_through_the_caches(params, ids, mask, prompt, cfg)
+    assert rel_l2(got, system_logits(params, ids, mask, cfg), mask) < TOL
+    r = cfg.kv_lora_rank
+    assert all(sorted(layer) in (["latent"], ["k_index", "latent"]) if cfg.index_topk else sorted(layer) == ["ckv", "k_rope"] for layer in cache)
+    for layer in cache:  # the latent and the roped key of every slot were written
+        halves = (layer["latent"][0, :, :r], layer["latent"][0, :, r:]) if cfg.index_topk else (layer["ckv"][0], layer["k_rope"][0])
+        assert all(float(jnp.abs(columns).sum(axis=-1).min()) > 0 for columns in halves)
+
+
+def test_a_pick_of_minus_one_is_the_masked_slot_the_gathered_bias_kept_out(monkeypatch):
+    """Row 2 is left-padded to slot 35: at the step that writes slot 38 it has
+    three visible slots of a cache of 40, fewer than ``index_topk`` = 8. The
+    absorbed attention of every layer equals the step's plain formula as it
+    stood with THREE gathers a slot: ``top_k``'s own places (masked ones
+    among them), the latent, the roped key and the bias each gathered by
+    them, two score products and their sum."""
+    params, (ids, mask) = seeded_params(12), batch(12)
+    mask = mask.at[2, :36].set(0)
+    selects, steps = [], []
+    select, absorbed = transformer.select_slots, transformer.absorbed_latent_attention
+
+    def noting_select(q_i, k_i, w, bias, ci, extents, topk):
+        selects.append((q_i, k_i, w, bias))
+        return select(q_i, k_i, w, bias, ci, extents, topk)
+
+    def noting_absorbed(q_c, q_r, ckv, k_rope, bias, ci, extents, scale, dtype):
+        steps.append((q_c, q_r, scale, absorbed(q_c, q_r, ckv, k_rope, bias, ci, extents, scale, dtype)))
+        return steps[-1][-1]
+
+    monkeypatch.setattr(transformer, "select_slots", noting_select)
+    monkeypatch.setattr(transformer, "absorbed_latent_attention", noting_absorbed)
+
+    model, prompt, r = CausalTransformer(CFG), 38, CFG.kv_lora_rank
+    slots = jnp.concatenate([mask[:, :prompt], jnp.zeros((B, T - prompt), jnp.int32)], axis=1)
+    out = model.apply({"params": params}, ids[:, :prompt], attention_mask=slots, cache=make_kv_cache(CFG, B, T), cache_index=0)
+    slots = slots.at[:, prompt].set(1)
+    out = model.apply({"params": params}, ids[:, prompt : prompt + 1], attention_mask=slots, cache=out["cache"], cache_index=prompt)
+    assert len(selects) == 2 and len(steps) == 5
+
+    def old_places(q_i, k_i, w, bias):  # select_slots as it was: top_k's places, whatever their values
+        scores = transformer.index_scores(q_i[:, None], k_i, w[:, None])[:, 0]
+        return jax.lax.top_k(jnp.where(bias[:, 0, 0] > -1.0, scores, -jnp.inf), CFG.index_topk)[1]
+
+    picked = [select(*args, prompt, None, CFG.index_topk) for args in selects]
+    assert [int((p[2] < 0).sum()) for p in picked] == [5, 5] and all(int(p[:2].min()) >= 0 for p in picked)
+    for layer, (q_c, q_r, scale, got) in enumerate(steps):
+        args = selects[layer == 4]  # layers 1 to 3 borrow layer 0's set
+        places, bias = old_places(*args), args[3]
+        assert np.array_equal(np.asarray(places)[:2], np.asarray(picked[layer == 4])[:2])
+        latent = out["cache"][layer]["latent"]
+        c, k_r = (jnp.take_along_axis(a, places[:, :, None], axis=1) for a in (latent[..., :r], latent[..., r:]))
+        b = jnp.take_along_axis(bias, places[:, None, None, :], axis=3)[:, :, 0]
+        assert sorted(np.unique(np.asarray(b)).tolist()) == [-1e9, 0.0]
+        scores = jnp.einsum("bhr,bsr->bhs", q_c, c) + jnp.einsum("bhd,bsd->bhs", q_r, k_r)
+        want = jnp.einsum("bhs,bsr->bhr", jax.nn.softmax(scores * scale + b, axis=-1), c)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-6)
+
+
+def test_a_cache_no_longer_than_index_topk_reads_the_one_leaf_as_the_split_form():
+    """On a cache of ``index_topk`` slots the selection never binds and every
+    step attends densely over the ``latent`` leaf's two column ranges. The
+    same steps over a cache that holds those ranges as ``ckv`` and ``k_rope``
+    (the layout of a layer without an indexer) give the same logits and
+    leave the same numbers in the slots."""
+    slots, prompt, r = CFG.index_topk, 5, CFG.kv_lora_rank
+    params, (ids, _) = seeded_params(13), batch(13, width=slots)
+    mask = jnp.asarray(np.arange(slots)[None, :] >= 2 * np.arange(B)[:, None], jnp.int32)  # 0, 2 and 4 pads
+    model = CausalTransformer(CFG)
+
+    def split(cache):
+        return [{"ckv": layer["latent"][..., :r], "k_rope": layer["latent"][..., r:],
+                 **{name: leaf for name, leaf in layer.items() if name != "latent"}} for layer in cache]
+
+    def run(cache):
+        seen = jnp.concatenate([mask[:, :prompt], jnp.zeros((B, slots - prompt), jnp.int32)], axis=1)
+        out = model.apply({"params": params}, ids[:, :prompt], attention_mask=seen, cache=cache, cache_index=0)
+        logits = [out["logits"]]
+        for t in range(prompt, slots):
+            seen = seen.at[:, t].set(mask[:, t])
+            out = model.apply({"params": params}, ids[:, t : t + 1], attention_mask=seen, cache=out["cache"], cache_index=t)
+            logits.append(out["logits"])
+        return jnp.concatenate(logits, axis=1), out["cache"]
+
+    fused, fused_cache = run(make_kv_cache(CFG, B, slots))
+    apart, apart_cache = run(split(make_kv_cache(CFG, B, slots)))
+    assert all("latent" in layer for layer in fused_cache) and all("ckv" in layer and "latent" not in layer for layer in apart_cache)
+    np.testing.assert_allclose(np.asarray(fused), np.asarray(apart), rtol=1e-6, atol=1e-6)
+    for one, two in zip(split(fused_cache), apart_cache):
+        assert sorted(one) == sorted(two)
+        for name in one:
+            np.testing.assert_array_equal(np.asarray(one[name]), np.asarray(two[name]))
+    assert rel_l2(fused, system_logits(params, ids, mask), mask) < TOL
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations carry
+    (``cond`` branches, ``pjit`` and ``custom_jvp`` bodies)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _equations(sub)
+
+
+@pytest.mark.parametrize("extents", [None, (24, 32, 40)], ids=["one_extent", "three_extents"])
+def test_a_decode_step_gathers_one_row_a_chosen_slot_and_nothing_of_the_bias(extents):
+    """The traced single-token step of the selecting model: one gather a
+    latent layer, ``[B, S, r + dr] -> [B, index_topk, r + dr]``, none whose
+    operand is the bias ``[B, 1, 1, S]`` or any other view of the cache, and
+    the rows those gathers fetch are what ``rollout/sparse_gather_rows`` says."""
+    model, width = CausalTransformer(CFG), CFG.kv_lora_rank + CFG.qk_rope_head_dim
+    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    cache = jax.eval_shape(lambda: make_kv_cache(CFG, B, T))
+    kw = {"kv_extents": extents} if extents else {}
+    step = lambda p, i, m, c, at: model.apply({"params": p}, i, attention_mask=m, cache=c, cache_index=at, **kw)["logits"]
+    jaxpr = jax.make_jaxpr(step)(params, jax.ShapeDtypeStruct((B, 1), jnp.int32), jax.ShapeDtypeStruct((B, T), jnp.int32),
+                                 cache, jax.ShapeDtypeStruct((), jnp.int32))
+    gathers = [e for e in _equations(jaxpr.jaxpr) if e.primitive.name == "gather"]
+    operands = [tuple(e.invars[0].aval.shape) for e in gathers]
+    assert not [s for s in operands if len(s) == 4]  # the bias is [B, 1, 1, T]
+    of_the_cache = [e for e in gathers if T in e.invars[0].aval.shape and len(e.invars[0].aval.shape) == 3]
+    assert [tuple(e.invars[0].aval.shape) for e in of_the_cache] == [(B, T, width)] * CFG.num_layers
+    rows = sum(int(np.prod(e.outvars[0].aval.shape[:-1])) // B for e in of_the_cache)
+    assert all(e.outvars[0].aval.shape[-1] == width for e in of_the_cache)
+    assert rows == sparse_gather_rows(CFG, T) == 5 * 8
+    # a cache no longer than index_topk selects nothing, and a model without an indexer never does
+    assert sparse_gather_rows(CFG, 8) == 0 and sparse_gather_rows(PANGU, T) == 0
+    big = config_from_spec("builtin:glm-5.2", num_layers=5, first_k_dense=1, indexer_types=TYPES)
+    assert sparse_gather_rows(big, 8192) == 10240  # the cell: five layers of 2048
+
+
+@pytest.mark.parametrize("path", ["slot_refill", "engine", "prefix_cache", "speculative"])
+@pytest.mark.parametrize("spec,rows,slots,latent,index", [
+    ("builtin:glm-5.2", 8, 8192, 377487360, 33554432),  # latent_cache_gib 0.3516, index_cache_gib 0.03125
+    ("builtin:pangu-ultra-moe-718b", 64, 640, 235929600, 0),  # latent_cache_gib 0.21973
+], ids=["glm52_ppo_ctx8k", "pangu718b_ppo_decode"])
+def test_the_cells_caches_hold_the_bytes_they_held_and_are_refused_as_they_were(spec, rows, slots, latent, index, path):
+    """One leaf of 576 columns in place of 512 and 64 on a layer under a
+    selection, the two leaves without one: the same 1152 bytes a slot a layer
+    at both cells' shapes, found by leaf name, and every KV-only path still
+    stops at either."""
+    kw = dict(indexer_types=TYPES) if index else {}
+    cfg = config_from_spec(spec, num_layers=5, first_k_dense=1, dtype=jnp.bfloat16, **kw)
+    cache = jax.eval_shape(lambda: make_kv_cache(cfg, rows, slots))
+    shapes = {"latent": (rows, slots, 576)} if index else {"ckv": (rows, slots, 512), "k_rope": (rows, slots, 64)}
+    assert all({k: v.shape for k, v in layer.items() if k != "k_index"} == shapes for layer in cache)
+    assert all(leaf.dtype == jnp.bfloat16 for leaf in jax.tree_util.tree_leaves(cache))
+    assert (latent_cache_bytes(cache), index_cache_bytes(cache)) == (latent, index)
+    assert latent == 5 * rows * slots * 1152 and dense_kv_bytes(cfg, rows, slots) == latent + index
+    with pytest.raises(NotImplementedError, match=rf"^{path} does not support .*leaves \('ckv', 'k_rope', 'latent'\)"):
+        refuse_latent_cache(cache, path)
+    stacked = jax.eval_shape(lambda: make_kv_cache(dataclasses.replace(cfg, scan_layers=True), rows, slots))
+    assert {k: v.shape for k, v in stacked.items() if k != "k_index"} == {k: (5,) + v for k, v in shapes.items()}
+    with pytest.raises(NotImplementedError, match=rf"^{path} does not support"):
+        refuse_latent_cache(stacked, path)
 
 
 def test_generate_records_the_references_logprobs(monkeypatch):
@@ -420,7 +595,7 @@ def test_kv_only_path_refuses_the_latent_and_the_index_cache_by_name(build, path
     with pytest.raises(NotImplementedError, match="^" + LATENT_REFUSAL.format(path=path)):
         build()
     # a latent cache without index keys is refused in the words it always was
-    with pytest.raises(NotImplementedError, match=r"K and V \(leaves \('ckv', 'k_rope'\).*families\): "):
+    with pytest.raises(NotImplementedError, match=r"K and V \(leaves \('ckv', 'k_rope', 'latent'\).*families\): "):
         refuse_latent_cache(jax.eval_shape(lambda: make_kv_cache(TransformerConfig.pangu("test"), 2, 8)), path)
 
 
@@ -635,7 +810,8 @@ def test_collection_counters_name_the_index_cache():
     assert trainer.last_cache_stats == {
         "rollout/kv_cache_bytes": 0.0, "rollout/ssm_state_bytes": 0.0,
         "rollout/latent_cache_bytes": float(5 * 3 * 40 * (16 + 8) * 4),
-        "rollout/index_cache_bytes": float(2 * 3 * 40 * 12 * 4)}, trainer.last_cache_stats
+        "rollout/index_cache_bytes": float(2 * 3 * 40 * 12 * 4),
+        "rollout/sparse_gather_rows": 5.0 * 8}, trainer.last_cache_stats
     assert trainer.last_kv_layers == ((40, False),) * 5
 
 

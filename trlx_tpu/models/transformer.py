@@ -1394,6 +1394,17 @@ def selected_frac(width: int, topk: int) -> float:
     return (kept * (kept + 1) // 2 + (width - kept) * topk) / max(width * (width + 1) // 2, 1)
 
 
+def sparse_gather_rows(cfg: "TransformerConfig", slots: int) -> int:
+    """Rows of the cache a single-token step gathers for one row of the batch
+    on a cache of ``slots`` slots, summed over the layers: ``index_topk`` on
+    every layer that attends under the selection (``LatentAttention``: each
+    chosen slot is one row of the ``latent`` leaf, fetched once), 0 where the
+    selection does not bind. Host arithmetic for ``rollout/sparse_gather_rows``."""
+    if not cfg.index_topk or slots <= cfg.index_topk:
+        return 0
+    return cfg.index_topk * sum(layout.indexer is not None for layout in cfg.layer_layouts)
+
+
 def largest_k(x: jax.Array, k: int) -> jax.Array:
     """Boolean mask of the ``k`` largest entries along the last axis of a
     float32 ``x`` (every entry where there are fewer than ``k``); of entries
@@ -1471,7 +1482,10 @@ def select_slots(q_i, k_i, w, attention_bias, cache_index, kv_extents, topk: int
     """One query token's selection ``[B, topk]`` (slot numbers) over the index
     keys of the cache ``k_i [B, S, DI]``, ``S > topk``: the ``topk`` visible
     slots of the largest index score. Where fewer are visible the rest are
-    masked slots, which the caller's gathered bias keeps out of the softmax.
+    ``-1``: ``top_k`` hands back the values beside the places, and a pick is
+    a masked slot exactly when its value is the ``-inf`` the mask put there,
+    so whoever attends over the selection (this layer and those that borrow
+    it) needs no second look at the bias.
     Over the shortest of ``kv_extents`` (never under ``topk`` slots) that
     holds the slot just written, as ``extent_attention``."""
 
@@ -1480,7 +1494,8 @@ def select_slots(q_i, k_i, w, attention_bias, cache_index, kv_extents, topk: int
             n = k_i.shape[1] if extent is None else max(extent, topk)
             scores = index_scores(q_i[:, None], k_i[:, :n], w[:, None])[:, 0]
             with jax.named_scope("trlx/attn_index_select"):
-                return jax.lax.top_k(jnp.where(bias[:, 0, 0, :n] > -1.0, scores, -jnp.inf), topk)[1]
+                values, slots = jax.lax.top_k(jnp.where(bias[:, 0, 0, :n] > -1.0, scores, -jnp.inf), topk)
+                return jnp.where(values > -jnp.inf, slots, -1)
 
         return pick
 
@@ -1551,13 +1566,18 @@ class LatentAttention(nn.Module):
     (``flash_attention(..., selection=)``; by ``selected_attention``'s masked
     einsums where no kernel runs), a single-token step on more than
     ``index_topk`` slots as slot numbers ``[B, index_topk]``
-    (``select_slots``), the chosen latents gathered and attended over in
-    absorbed form. A row no longer than
+    (``select_slots``; ``-1`` where fewer slots are visible), the chosen
+    slots' rows of the cache gathered once and attended over in absorbed
+    form. A row no longer than
     ``index_topk`` selects every causal key and runs as without an indexer
     (``selection`` None). The selection has no gradient.
 
-    The cache is ``{"ckv": [B, S, r], "k_rope": [B, S, dr]}``, with
-    ``"k_index": [B, S, DI]`` on a ``full`` layer, written at ``cache_index``
+    The cache is ``{"ckv": [B, S, r], "k_rope": [B, S, dr]}``; on a layer
+    under a selection it is ONE leaf ``{"latent": [B, S, r + dr]}``, a slot's
+    normed latent ``c`` in columns ``[0, r)`` beside its roped key ``k_r`` in
+    ``[r, r + dr)`` (a step that gathers chosen slots fetches one row a slot:
+    XLA's gather costs by the row, not the byte), with ``"k_index": [B, S,
+    DI]`` on a ``full`` layer. All written at ``cache_index``
     (one scalar for all rows: the plain sampler). A span (prefill) must
     start at slot 0: it attends over its own keys, expanded, and leaves its
     latents in the cache. ``kv_b_proj`` takes no LoRA adapter: the absorbed
@@ -1606,32 +1626,41 @@ class LatentAttention(nn.Module):
             ci = jnp.asarray(cache_index)
             if ci.ndim:
                 raise NotImplementedError("a latent cache is written at one scalar cache_index for all rows (the plain sampler)")
-            new_cache = {
-                "ckv": jax.lax.dynamic_update_slice(cache["ckv"], c.astype(cache["ckv"].dtype), (0, ci, 0)),
-                "k_rope": jax.lax.dynamic_update_slice(cache["k_rope"], k_r.astype(cache["k_rope"].dtype), (0, ci, 0)),
-            }
+            if "latent" in cache:  # a layer under a selection: one row a slot, written as its two column ranges
+                rows = jax.lax.dynamic_update_slice(cache["latent"], c.astype(cache["latent"].dtype), (0, ci, 0))
+                new_cache = {"latent": jax.lax.dynamic_update_slice(rows, k_r.astype(rows.dtype), (0, ci, r))}
+            else:
+                new_cache = {
+                    "ckv": jax.lax.dynamic_update_slice(cache["ckv"], c.astype(cache["ckv"].dtype), (0, ci, 0)),
+                    "k_rope": jax.lax.dynamic_update_slice(cache["k_rope"], k_r.astype(cache["k_rope"].dtype), (0, ci, 0)),
+                }
             if index is not None:
                 new_cache["k_index"] = jax.lax.dynamic_update_slice(cache["k_index"], index[1].astype(cache["k_index"].dtype), (0, ci, 0))
         step = cache is not None and T == 1
         # the selection binds where the keys in reach outnumber it: a step's cache slots, a pass's tokens
-        selects = bool(topk) and (cache["ckv"].shape[1] if step else T) > topk
+        selects = bool(topk) and (cache_slots(cache) if step else T) > topk
         if selects and index is None and selection is None:
             raise ValueError("a layer whose indexer type is `shared` was handed no selection")
         if step:
             q_n, q_r = queries(cq, sin, cos)
             q_c = jnp.einsum("bhn,rhn->bhr", q_n[:, 0], w_kvb[..., :dn])
-            ckv, k_rope, bias = new_cache["ckv"], new_cache["k_rope"], attention_bias
+            bias = attention_bias
             extents = kv_extents.slots if kv_extents is not None else None
             if selects:
                 if index is not None:
                     selection = select_slots(index[0][:, 0], new_cache["k_index"], index[2][:, 0], bias, ci, extents, topk)
                 with jax.named_scope("trlx/attn_sparse"):
-                    # the chosen slots' latents, and their own places in the bias
-                    ckv, k_rope = (jnp.take_along_axis(a, selection[:, :, None], axis=1) for a in (ckv, k_rope))
-                    bias = jnp.take_along_axis(bias, selection[:, None, None, :], axis=3)
+                    # each chosen slot's one row. A pick of -1 is a masked slot (select_slots):
+                    # the bias over a step's slots is 0 or -1e9 and nothing else here
+                    # (_attention_bias: rotary positions, no window under a selection), so over
+                    # the chosen ones it is this, term for term, without a gather of its own.
+                    # top_k's places clamped at 0 are slots of the cache: no fill pass over the rows
+                    rows = jnp.take_along_axis(new_cache["latent"], jnp.maximum(selection, 0)[:, :, None], axis=1, mode="promise_in_bounds")
+                    bias = jnp.where(selection >= 0, 0.0, -1e9)[:, None, None, :]
                 extents = None
-            else:
-                selection = None
+            else:  # every slot, as without an indexer
+                selection, rows = None, new_cache.get("latent")
+            ckv, k_rope = (rows[..., :r], rows[..., r:]) if rows is not None else (new_cache["ckv"], new_cache["k_rope"])
             o_c = absorbed_latent_attention(q_c, q_r[:, 0], ckv, k_rope, bias, ci, extents, 1.0 / np.sqrt(dn + dr), cfg.dtype)
             out = jnp.einsum("bhr,rhv->bhv", o_c, w_kvb[..., dn:]).reshape(B, 1, H * dv)
             return project(o, out, cfg), new_cache, (selection if self.lends else None)
@@ -2261,8 +2290,8 @@ def _cache_is_paged(cache) -> bool:
 
 def cache_slots(layer_cache: Dict[str, jax.Array], stacked: bool = False) -> int:
     """Slots a layer's dense cache holds a row: the length of ``k``, or of a
-    latent layer's ``ckv`` (behind a leading layer dim where ``stacked``)."""
-    leaf = layer_cache["k"] if "k" in layer_cache else layer_cache["ckv"]
+    latent layer's ``ckv`` or ``latent`` (behind a leading layer dim where ``stacked``)."""
+    leaf = next(layer_cache[name] for name in ("k", "ckv", "latent") if name in layer_cache)
     return leaf.shape[1 + stacked]
 
 
@@ -2876,7 +2905,11 @@ def make_kv_cache(
     kv_lora_rank]`` and ``k_rope`` ``[B, slots, qk_rope_head_dim]`` IN PLACE
     of ``k`` and ``v``: 576 numbers a slot at the published widths where
     per-head K and V would be 40,960. Under a learned selection
-    (``index_topk`` > 0) a layer whose indexer type is ``full`` also holds
+    (``index_topk`` > 0) a layer holds the same numbers as ONE leaf ``latent``
+    ``[B, slots, kv_lora_rank + qk_rope_head_dim]``, the normed latent in
+    columns ``[0, kv_lora_rank)`` and the roped key after it: its decode steps
+    gather chosen slots, and a gathered row costs the same whatever it holds
+    (``LatentAttention``). A layer whose indexer type is ``full`` also holds
     ``k_index`` ``[B, slots, index_head_dim]``, the index keys its decode
     steps score; a ``shared`` layer holds none.
     """
@@ -2887,11 +2920,21 @@ def make_kv_cache(
         slots = min(max_length, layout.window) if layout.window else max_length
         if cfg.latent_attention:
             # the normed latent and the one roped key (ops/paged_kv.py::LATENT_LEAVES), the
-            # same slot axis and cache_index as K and V have, and no K or V
-            latent = {
-                "ckv": jnp.zeros(stacked + (batch_size, slots, cfg.kv_lora_rank), dtype),
-                "k_rope": jnp.zeros(stacked + (batch_size, slots, cfg.qk_rope_head_dim), dtype),
-            }
+            # same slot axis and cache_index as K and V have, and no K or V; side by side
+            # in one row a slot on a layer whose steps gather chosen slots. Two layouts
+            # for a measured reason, not for anything a layer without an indexer needs:
+            # with the one leaf there too, the TPU compiler's memory-space assignment
+            # kept the caches on chip in place of q_b_proj's prefetched weights and
+            # pangu718b_ppo_decode ran 2.7% slower (PERF.md section 6, PR 43). To merge
+            # them, measure that cell (ROADMAP queue 2, B4c)
+            r, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+            if layout.indexer:
+                latent = {"latent": jnp.zeros(stacked + (batch_size, slots, r + dr), dtype)}
+            else:
+                latent = {
+                    "ckv": jnp.zeros(stacked + (batch_size, slots, r), dtype),
+                    "k_rope": jnp.zeros(stacked + (batch_size, slots, dr), dtype),
+                }
             if layout.indexer == "full":
                 # the indexer's ONE normed, roped key a slot (ops/paged_kv.py::INDEX_LEAVES),
                 # on the layers that select for themselves only

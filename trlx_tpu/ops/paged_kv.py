@@ -316,8 +316,11 @@ def refuse_recurrent_state(cache: Any, path: str) -> None:
 
 # what a latent-attention layer keeps a slot IN PLACE of K and V
 # (models/transformer.py::make_kv_cache): the normed latent that keys and
-# values are made from, and the one roped key all heads share
-LATENT_LEAVES = ("ckv", "k_rope")
+# values are made from, and the one roped key all heads share; on a layer
+# under a learned selection both in ONE leaf `latent [B, S, kv_lora_rank +
+# qk_rope_head_dim]`, the latent's columns first (its steps gather chosen
+# slots, a row a slot)
+LATENT_LEAVES = ("ckv", "k_rope", "latent")
 
 
 # what rides with the latent under a learned selection: the ONE index key a

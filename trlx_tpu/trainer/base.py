@@ -39,7 +39,7 @@ from trlx_tpu.models.builder import (
     is_frozen,
     trainable_mask,
 )
-from trlx_tpu.models.transformer import cache_slots, make_kv_cache, selected_frac
+from trlx_tpu.models.transformer import cache_slots, make_kv_cache, selected_frac, sparse_gather_rows
 from trlx_tpu.ops.sampling import (
     GenerationConfig,
     GenerationOutput,
@@ -1474,10 +1474,13 @@ class TPUBaseTrainer(BaseRLTrainer):
         ``rollout/kv_cache_bytes`` (``k``, ``v``) and
         ``rollout/ssm_state_bytes`` (``ssm``, ``conv``; 0 for a KV-only
         model), and where the layers cache a latent in place of K and V
-        ``rollout/latent_cache_bytes`` (``ckv``, ``k_rope``; K and V then 0)
+        ``rollout/latent_cache_bytes`` (``ckv``, ``k_rope``, or the two in
+        one leaf ``latent`` on a layer under a selection; K and V then 0)
         with ``rollout/index_cache_bytes`` beside it (``k_index``, the index
         keys of the layers that select for themselves; 0 for a model without
-        a learned selection); where the stack mixes attention layouts, K and V are also
+        a learned selection) and ``rollout/sparse_gather_rows`` (rows of the
+        cache a decode step gathers under that selection, a row of the batch,
+        summed over the layers); where the stack mixes attention layouts, K and V are also
         split into ``rollout/kv_cache_window_bytes`` (the window layers'
         rings) and ``rollout/kv_cache_global_bytes``. The
         continuous-batching engines report their own measured gauge
@@ -1516,6 +1519,9 @@ class TPUBaseTrainer(BaseRLTrainer):
         if latent:  # the layers cache a latent in place of K and V, and index keys with it
             self.last_cache_stats["rollout/latent_cache_bytes"] = float(latent)
             self.last_cache_stats["rollout/index_cache_bytes"] = float(index)
+        if getattr(self.tcfg, "index_topk", 0) and self.draft_module is None:
+            # rows of the cache a decode step gathers a row of the batch, all layers: static, as the extents are
+            self.last_cache_stats["rollout/sparse_gather_rows"] = float(sparse_gather_rows(self.tcfg, S))
         if not self.tcfg.scan_layers:
             layouts = self.tcfg.layer_layouts
             self.last_kv_layers = tuple(
