@@ -413,8 +413,12 @@ def test_kv_only_presets_trace_to_the_programs_recorded_before_the_family(family
     rows through a select that lets the gradient of the rows in a group
     alone through (``ragged_dot``'s backward left the others' unwritten on the
     chip), one ``select_n`` and one ``stop_gradient`` a layer, which the
-    compiler folds out of the forward; trees and every other family's
-    programs are the first recording's."""
+    compiler folds out of the forward, and once more in PR 44: the layer's two
+    row permutations go through ``permute_rows``, so each gather stands inside
+    a ``custom_vjp_call`` and ``unsort`` is traced before the first of them
+    (compiled for the CPU both toy programs are the same instructions as on
+    PR 44's parent, names aside); trees and every other family's programs are
+    the first recording's."""
     # other test files of the same worker set jax_default_matmul_precision at
     # import, and a precision is printed on every dot of a jaxpr
     with jax.default_matmul_precision(None), open(RECORDED) as f:

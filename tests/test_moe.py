@@ -453,6 +453,128 @@ def test_dropless_equals_ample_capacity(what):
                                    err_msg=jax.tree_util.keystr(path))
 
 
+def _plain_permute_rows(rows, perm, inv):
+    """The two permutations of ``MoEMLP._dropless_rows`` as they were written
+    before they had a backward of their own: a gather that JAX transposes
+    into a scatter-add. The independent statement of what ``permute_rows``
+    computes, forward and backward."""
+    return rows.at[perm].get(unique_indices=True)
+
+
+_DROPLESS_LAYERS = {
+    # all eight experts held: every assignment is computed
+    "all_held": dict(),
+    # a chip's share: experts 4 to 6 of 8, so most sorted rows lie past the
+    # last group and the select's stop_gradient is on their path
+    "held_share": dict(moe_experts_held=3, moe_first_expert=4),
+    # padding tokens sort past the last expert with the other chips' rows
+    "padding": dict(),
+    "padding_held_share": dict(moe_experts_held=2, moe_first_expert=1),
+    # the permutations' residuals and backward under rematerialisation
+    "checkpoint": dict(moe_experts_held=3, moe_first_expert=4),
+    # a layer past MOE_MAX_TOKENS: three pieces of twelve tokens under lax.map
+    "pieces": dict(moe_experts_held=3, moe_first_expert=4),
+}
+
+
+_DROPLESS_K = 3
+
+
+def _dropless_layer_loss(case, dtype, permute, monkeypatch):
+    """``(loss(params, x), params, x)`` of a dropless ``MoEMLP`` that moves
+    its rows with ``permute`` (None: the layer's own ``permute_rows``)."""
+    from trlx_tpu.models import transformer
+
+    cfg = _cfg(
+        num_experts=8, num_experts_per_tok=_DROPLESS_K, moe_capacity_factor=0.0,
+        dtype=dtype, param_dtype=dtype, **_DROPLESS_LAYERS[case],
+    )
+    rs = np.random.RandomState(3)
+    x = jnp.asarray(rs.randn(3, 12, cfg.hidden_size), dtype)
+    mask = jnp.ones((3, 12), jnp.int32)
+    if case.startswith("padding"):
+        mask = mask.at[0, :5].set(0).at[2, :9].set(0)
+    target = jnp.asarray(rs.randn(3, 12, cfg.hidden_size), jnp.float32)
+    layer = MoEMLP(cfg)
+    params = layer.init(jax.random.PRNGKey(1), x)["params"]
+    if permute is not None:
+        monkeypatch.setattr(transformer, "permute_rows", permute)
+    if case == "pieces":
+        monkeypatch.setattr(transformer, "MOE_MAX_TOKENS", 8)
+        monkeypatch.setattr(transformer, "MOE_PIECE_TOKENS", 12)
+        assert transformer.moe_token_pieces(36) == 3
+
+    def loss(p, x):
+        def apply(p, x):
+            return layer.apply({"params": p}, x, token_mask=mask)
+
+        if case == "checkpoint":
+            apply = jax.checkpoint(apply)
+        y, aux = apply(p, x)
+        return jnp.sum(y.astype(jnp.float32) * target) + 0.01 * jnp.sum(aux)
+
+    return loss, params, x
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("case", list(_DROPLESS_LAYERS))
+def test_dropless_backward_by_gather_is_the_plain_gathers_transpose(case, dtype, monkeypatch):
+    """``permute_rows`` gives its backward as a gather by the inverse
+    permutation; the transpose JAX derives from the plain gather is a
+    scatter-add into zeros at unique rows. Every output row takes one input
+    row and nothing is added, so the gradients are EQUAL, not close."""
+    loss, params, x = _dropless_layer_loss(case, dtype, None, monkeypatch)
+    value, grads = jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(params, x)
+    loss, params, x = _dropless_layer_loss(case, dtype, _plain_permute_rows, monkeypatch)
+    plain_value, plain = jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(params, x)
+    np.testing.assert_array_equal(np.asarray(value), np.asarray(plain_value))
+    flat = jax.tree_util.tree_flatten_with_path(grads)[0]
+    assert len(flat) == len(jax.tree_util.tree_leaves(params)) + 1
+    moved = 0
+    for (path, g), q in zip(flat, jax.tree_util.tree_leaves(plain)):
+        g, q = np.asarray(g.astype(jnp.float32)), np.asarray(q.astype(jnp.float32))
+        assert np.isfinite(g).all(), jax.tree_util.keystr(path)
+        np.testing.assert_array_equal(g, q, err_msg=jax.tree_util.keystr(path))
+        moved += bool(np.any(g != 0))
+    # the comparison is of gradients that are there: x, the router and the
+    # three expert kernels all receive one
+    assert moved == len(flat)
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations carry."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(inner)
+
+
+@pytest.mark.parametrize("case", ["all_held", "held_share", "checkpoint"])
+def test_dropless_backward_holds_no_scatter_of_the_row_buffer(case, monkeypatch):
+    """The lowered backward moves the sorted ``[tokens x K, d]`` row buffer by
+    gathers alone: the scatters the layer keeps are over ``[E + 1]`` counts
+    and the ``[tokens x K]`` inverse permutation, never over rows. The plain
+    form, traced the same way, holds the two scatter-adds this test is there
+    to keep out, so the walk sees what it looks for."""
+
+    def row_scatters(permute):
+        loss, params, x = _dropless_layer_loss(case, jnp.bfloat16, permute, monkeypatch)
+        rows = x.shape[0] * x.shape[1] * _DROPLESS_K
+        eqns = list(_equations(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(params, x).jaxpr))
+        found = [
+            e.primitive.name for e in eqns
+            if e.primitive.name.startswith("scatter")
+            and e.invars[0].aval.ndim == 2 and e.invars[0].aval.shape[0] == rows
+        ]
+        return found, [e.primitive.name for e in eqns]
+
+    found, names = row_scatters(None)
+    assert found == [], found
+    assert sum(n == "gather" for n in names) >= 4  # two forward, two backward
+    plain, _ = row_scatters(_plain_permute_rows)
+    assert plain == ["scatter-add", "scatter-add"], plain
+
+
 @pytest.mark.parametrize("method", ["grpo", "ppo"])
 def test_olmoe_rl_step_through_train(method, tmp_path):
     """One collection and two optimizer steps on ``builtin:olmoe-test``
