@@ -5,7 +5,7 @@ through the entry points a user calls — ``trlx_tpu.train()``, the paged
 continuous-batching Engine, the HTTP serving frontend — at the full
 published width of GPT-2-small (``builtin:gpt2-small``: 12 layers x 768,
 12 heads x 64, vocab 50257; random init from the config seed,
-``builtin:bytes`` tokenizer; the 64+40-token task shape of ``bench.py``),
+``builtin:bytes`` tokenizer; 64-token prompts and 40 new tokens),
 and checks what comes out by the repo's own means.
 
     python chip_smoke.py            # one chip: device, train, engine, kernels
@@ -49,14 +49,7 @@ CHIPS4_PHASES = ("sharded",)
 # Pallas kernel flavors (trlx_tpu.analysis.kernels.KERNEL_PARITY) that Mosaic
 # refuses today, with the compiler's words; tests/test_aot_tpu.py turns this
 # table into strict xfails. Everything else is in KERNEL_CHECKS below.
-KERNELS_REFUSED = {
-    # the reversed GAE lax.scan inside PPOConfig.get_advantages_and_returns;
-    # past `rev`, Pallas TPU lowers only fori_loop-shaped scans (no per-step
-    # inputs or outputs), so the repair is a rewrite of the kernel body
-    "fused-loss": (
-        "Unimplemented primitive in Pallas TPU lowering for KernelType.TC: rev"
-    ),
-}
+KERNELS_REFUSED = {}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -104,7 +97,7 @@ def make_prompts(size: Size, n: int, seed: int = 0):
 
 
 def ppo_config(size: Size, ckpt_dir: str, total_steps: int, **parallel):
-    """The bench task shape (bench.py): 64-token prompts, 40 new tokens,
+    """The smoke task shape: 64-token prompts, 40 new tokens,
     chunk 128, ppo_epochs 4, two unfrozen layers, default attention_impl —
     which on a TPU is the Pallas flash kernel. The rehearsal forces that
     kernel (interpreted) so the CPU walk takes the same path."""

@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """CI lint entry point: run EVERY graftlint pass (metric-names included)
-over the real ``trlx_tpu/`` tree AND ``scripts/`` (bench/evidence scripts
-spawn processes and write spool files — unlinted tooling is where the
+over the real ``trlx_tpu/`` tree AND ``scripts/`` (tooling spawns
+processes and writes files — unlinted tooling is where the
 "works on my launcher" hangs hide) against the committed baseline
 (``GRAFTLINT_BASELINE.txt``). Non-zero exit on any non-baselined finding
 or stale baseline entry.

@@ -1665,7 +1665,7 @@ def test_kernel_registry_lost_reference_and_test(tmp_path):
             def _kernel(x_ref, o_ref):
                 o_ref[...] = x_ref[...]
 
-            def fused_ppo_loss(x):
+            def fused_sample(x):
                 if not has_pallas_tpu():
                     return x
                 return pl.pallas_call(_kernel, out_shape=x)(x)
@@ -1675,8 +1675,8 @@ def test_kernel_registry_lost_reference_and_test(tmp_path):
     )
     assert codes(findings) == ["GL1004", "GL1004"]
     assert sorted(f.detail for f in findings) == [
-        "fused-loss:reference:fused_ppo_loss_reference",
-        "fused-loss:test:tests/test_fused_loss.py",
+        "fused-sample:reference:sample_token_from_logits",
+        "fused-sample:test:tests/test_paged_attention.py",
     ]
 
 
@@ -1693,21 +1693,20 @@ def test_kernel_parity_registry_on_real_tree():
     sites = kp._collect_sites(g)
     # the current kernel surface: flash fwd + fused bwd and the same pair
     # under a selection (`flash_attention(..., selection=)`, its own two
-    # call sites behind the same entry), fused-loss fwd + bwd, paged decode,
-    # fused sampling, paged prefill
-    assert len(sites) == 9, sorted(
+    # call sites behind the same entry), paged decode, fused sampling,
+    # paged prefill
+    assert len(sites) == 7, sorted(
         (s.mod.relpath, s.fn.qualname if s.fn else "<module>") for s in sites
     )
     assert {s.mod.relpath for s in sites} == {
         "trlx_tpu/ops/flash_attention.py",
-        "trlx_tpu/ops/fused_loss.py",
         "trlx_tpu/ops/paged_attention.py",
         "trlx_tpu/ops/paged_prefill.py",
     }
     flavors = {flavor for flavor, _, _, _ in KERNEL_PARITY}
     assert flavors == {
         "paged-decode", "paged-prefill", "paged-verify", "fused-sample",
-        "fused-loss", "flash-fwd", "flash-bwd",
+        "flash-fwd", "flash-bwd",
     }
     for flavor, entry, reference, test_path in KERNEL_PARITY:
         assert g.resolve_root_names([entry]), f"{flavor}: entry `{entry}`"

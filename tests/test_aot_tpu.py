@@ -29,7 +29,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 from chip_smoke import KERNELS_REFUSED as REFUSED
 from trlx_tpu.analysis.kernels import KERNEL_PARITY
 
-# GPT-2-small attention widths and the bench task shape (bench.py)
+# GPT-2-small attention widths and chip_smoke.py's task shape
 H, D, VOCAB = 12, 64, 50257
 PROMPT, NEW = 64, 40
 T = PROMPT + NEW
@@ -147,22 +147,6 @@ def _fused_sample():
     )
 
 
-def _fused_loss():
-    from trlx_tpu.models.ppo import PPOConfig
-    from trlx_tpu.ops.fused_loss import fused_ppo_loss
-
-    method = PPOConfig()
-    ops = tuple(_s((128, NEW), jnp.float32) for _ in range(6))
-
-    def fwd_bwd(*o):
-        def loss(lp, v):
-            return fused_ppo_loss(method, lp, v, *o[2:], interpret=False)[0]
-
-        return jax.value_and_grad(loss, argnums=(0, 1))(o[0], o[1])
-
-    return fwd_bwd, ops
-
-
 _BUILDERS = {
     "flash-fwd": _flash_fwd,
     "flash-bwd": _flash_bwd,
@@ -170,7 +154,6 @@ _BUILDERS = {
     "paged-prefill": _paged_prefill,
     "paged-verify": _paged_verify,
     "fused-sample": _fused_sample,
-    "fused-loss": _fused_loss,
 }
 
 def test_table_covers_the_registry():

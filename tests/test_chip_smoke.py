@@ -1,7 +1,7 @@
 """``chip_smoke.py``'s control flow, walked without a chip and without a
 compile: which phases each option runs, the exact last line, that a failing
 phase fails the run, and that nothing continues on a device other than the
-TPU by itself (same gate for ``bench.py``). Plus the two runtime rules the
+TPU by itself. Plus the two runtime rules the
 script leans on: an unknown TPU ``device_kind`` is an error, and the compile
 cache is placed from outside or at ``<checkout>/.jax_cache``.
 
@@ -144,14 +144,6 @@ def test_chip_smoke_refuses_without_a_chip(smoke, monkeypatch, capsys):
     with pytest.raises(RuntimeError, match="not a TPU"):
         smoke.main([])
     assert capsys.readouterr().out == ""  # no result of any kind
-
-
-def test_bench_refuses_without_a_chip(monkeypatch, capsys):
-    _no_chip(monkeypatch)
-    bench = _load("bench", "bench.py")
-    with pytest.raises(RuntimeError, match="not a TPU"):
-        bench.main()
-    assert capsys.readouterr().out == ""
 
 
 def test_kernel_tables_agree_with_the_registry(smoke):

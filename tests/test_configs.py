@@ -8,6 +8,7 @@ import yaml
 
 from trlx_tpu.data.configs import TRLConfig
 from trlx_tpu.data.default_configs import (
+    default_grpo_config,
     default_ilql_config,
     default_ppo_config,
     default_sft_config,
@@ -69,6 +70,17 @@ def test_strict_from_dict_rejects_unknown():
     config = default_ppo_config().to_dict()
     config["model"]["bogus_key"] = 1
     with pytest.raises(ValueError):
+        TRLConfig.from_dict(config)
+
+
+@pytest.mark.parametrize("make", [default_ppo_config, default_grpo_config])
+def test_method_loss_kernel_is_an_unknown_key(make):
+    """The option went with the fused learner kernel (PR 45): a config that
+    still sets it fails as any unknown key does, and no default carries it."""
+    assert not hasattr(make().method, "loss_kernel")
+    config = make().to_dict()
+    config["method"]["loss_kernel"] = "xla"
+    with pytest.raises(ValueError, match=r"Unknown keys \['loss_kernel'\]"):
         TRLConfig.from_dict(config)
 
 

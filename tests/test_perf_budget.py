@@ -103,7 +103,7 @@ def test_budget_gpt2_test_spec():
     verify really is a single target forward over gamma+1 positions — a
     change that re-serializes verification (gamma+1 forwards) shows up as
     a flop jump, and speculation adds exactly these two programs per
-    bucket (zero-extra-programs claim, benchmarks/ENGINE_SPEC_cpu.json).
+    bucket (the zero-extra-programs claim).
     The serial `generate` budget here is the solo speculative sampler —
     the bit-parity reference program (tests/test_spec_engine.py)."""
     _assert_within_budget("gpt2_test_spec")

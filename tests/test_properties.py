@@ -157,14 +157,24 @@ def test_group_advantages_invariants(groups, group_size, seed):
 
     rng = np.random.RandomState(seed)
     scores = rng.randn(groups * group_size).astype(np.float32) * 3.0
+
+    def atol(rewards):
+        # float32 centring leaves about eps * |rewards| in each element, and
+        # the division by the group's std multiplies that by 1 / std: a group
+        # whose rewards nearly coincide has advantages known that much less
+        # well. So the tolerance follows each group's |rewards|.max() / std;
+        # a constant is one that such a draw exceeds.
+        r = rewards.reshape(groups, group_size)
+        ratio = np.abs(r).max(axis=1, keepdims=True) / (r.std(axis=1, keepdims=True) + 1e-6)
+        return 1e-5 * np.maximum(1.0, ratio)
+
     adv = group_advantages_np(scores, group_size)
     g = adv.reshape(groups, group_size)
-    np.testing.assert_allclose(g.mean(axis=1), 0.0, atol=1e-5)
+    assert (np.abs(g.mean(axis=1, keepdims=True)) <= atol(scores)).all()
     # shifting any group's rewards by a constant leaves advantages unchanged
     shifted = scores + np.repeat(rng.randn(groups).astype(np.float32) * 10, group_size)
-    np.testing.assert_allclose(
-        group_advantages_np(shifted, group_size), adv, atol=1e-4
-    )
+    moved = group_advantages_np(shifted, group_size).reshape(groups, group_size) - g
+    assert (np.abs(moved) <= atol(scores) + atol(shifted)).all()
     # unscaled variant is exactly the centered rewards
     centered = group_advantages_np(scores, group_size, scale=False)
     np.testing.assert_allclose(

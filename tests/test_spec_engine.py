@@ -370,9 +370,6 @@ class TestValidation:
             ),
         )
         assert t is not None
-        # method.loss_kernel is validated at construction the same way
-        with pytest.raises(ValueError, match="loss_kernel"):
-            build(method=dict(loss_kernel="mosaic"))
 
 
 @pytest.mark.slow

@@ -110,8 +110,7 @@ ENGINE_KEYS = frozenset({
     "engine/prefill_kernel_pallas",
     # analytic bytes the refill prefills move through transient dense
     # views (pool→view gather on entry, span→pool scatter on exit):
-    # exactly 0 under the in-place prefill kernel — the acceptance number
-    # of benchmarks/ENGINE_PREFILL_cpu.json
+    # exactly 0 under the in-place prefill kernel
     "engine/refill_gather_bytes",
     "engine/refill_scatter_bytes",
     # chunked-prefill scheduling (engine.prefill_chunk,
@@ -135,12 +134,6 @@ ENGINE_KEYS = frozenset({
     # when engine.decode_kernel: pallas composes with engine.speculative)
     # vs the gather → shared round → scatter reference
     "engine/spec_verify_kernel_pallas",
-    # fused learner-step kernel gauge (0/1): method.loss_kernel: pallas
-    # ran with the Mosaic (pallas TPU) backend importable
-    # (ops/fused_loss.py) — a Mosaic-less build's staged fallback reports
-    # 0, so an artifact can't claim kernel=1 it never ran
-    # (docs/PERFORMANCE.md "Fused learner kernels")
-    "train/loss_kernel_pallas",
     # serving extensions on the engine (docs/SERVING.md): per-request
     # queue-wait percentiles from the enqueue→prefill spans, priority-
     # preemption count, and the host-tier re-land accounting (blocks

@@ -102,9 +102,6 @@ KERNEL_PARITY: Tuple[Tuple[str, str, str, str], ...] = (
     # fused temperature/top-k/top-p sampling (PR 16)
     ("fused-sample", "fused_sample",
      "sample_token_from_logits", "tests/test_paged_attention.py"),
-    # fused GAE + whiten + PPO loss, fwd + bwd custom_vjp pair (PR 18)
-    ("fused-loss", "fused_ppo_loss",
-     "fused_ppo_loss_reference", "tests/test_fused_loss.py"),
     # flash attention forward (PR 16)
     ("flash-fwd", "flash_attention",
      "attention_reference", "tests/test_flash_attention.py"),

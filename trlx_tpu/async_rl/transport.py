@@ -483,12 +483,6 @@ class FleetCoordinator:
         with self._cond:
             return len(self._members)
 
-    def pending_acks(self) -> int:
-        """Publishes not yet acked by every live member (bench/test hook:
-        drain this to 0 before reading the latency window)."""
-        with self._cond:
-            return len(self._await_acks)
-
     def members_snapshot(self) -> List[Dict[str, Any]]:
         """Diagnostic view: (id, slot, mesh descriptor) per live member."""
         with self._cond:

@@ -318,7 +318,8 @@ class EngineConfig:
         in-place Pallas paged-prefill kernel (``ops/paged_prefill.py``):
         prompt K/V commits through the block table and attention reads
         pool blocks straight into VMEM — refill gather/scatter bytes drop
-        to exactly 0 (``benchmarks/ENGINE_PREFILL_cpu.json``).
+        to exactly 0 (``engine/refill_gather_bytes`` /
+        ``engine/refill_scatter_bytes``).
         Bit-identical to the gather path by contract; the parity reference
         is the dense einsum attention (models whose
         ``resolved_attention_impl()`` is pallas-flash prefill through the

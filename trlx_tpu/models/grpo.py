@@ -15,7 +15,7 @@ is inherited wholesale by :class:`~trlx_tpu.trainer.grpo.GRPOTrainer`.
 """
 
 from dataclasses import dataclass
-from typing import Any, ClassVar, Dict, Tuple
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -86,11 +86,6 @@ class GRPOConfig(PPOConfig):
         (leave-one-out mean — REINFORCE-Leave-One-Out; unbiased baseline,
         no std scaling).
     """
-
-    #: GRPO's group-baseline loss has no GAE recurrence or value head, so the
-    #: fused Pallas learner kernel (``ops/fused_loss.py``) has nothing to fuse
-    #: here — narrow the hostable loss_kernel values back to the XLA path.
-    LOSS_KERNELS: ClassVar[Tuple[str, ...]] = ("xla",)
 
     name: str = "GRPOConfig"
     group_size: int = 8

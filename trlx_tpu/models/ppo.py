@@ -11,7 +11,7 @@ Behavioral parity targets in the reference:
 """
 
 from dataclasses import dataclass, field
-from typing import Any, ClassVar, Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -94,20 +94,9 @@ class PPOConfig(MethodConfig):
         proximal/behavior mismatch, truncation bounds its variance
         (V-trace/TIS-style; PipelineRL arxiv 2509.19128).
     :param iw_clip: truncation bound of the behavior ratio.
-    :param loss_kernel: learner-step compute program. ``"xla"`` (default)
-        runs the staged chain — :meth:`get_advantages_and_returns` then
-        :meth:`loss` as separate XLA programs; ``"pallas"`` fuses GAE,
-        whitening, the clipped losses, and the stats/sketches into one
-        Pallas program per step (``ops/fused_loss.py``), bit-identical in
-        loss/grads/stats to the staged path. Validated at trainer
-        construction (``trainer/base.py``) like ``engine.decode_kernel``.
     :param gen_kwargs: sampling kwargs for rollouts/eval
     :param gen_experience_kwargs: optional distinct sampling kwargs for rollouts
     """
-
-    #: loss_kernel values this method can host ("pallas" needs the
-    #: GAE/value-head loss shape — GRPO narrows this to ("xla",))
-    LOSS_KERNELS: ClassVar[Tuple[str, ...]] = ("xla", "pallas")
 
     name: str = "PPOConfig"
     ppo_epochs: int = 4
@@ -127,7 +116,6 @@ class PPOConfig(MethodConfig):
     cliprange_reward: float = 10.0
     iw_correction: str = "off"
     iw_clip: float = 2.0
-    loss_kernel: str = "xla"
     gen_kwargs: Dict[str, Any] = field(default_factory=dict)
     gen_experience_kwargs: Optional[Dict[str, Any]] = None
 
@@ -178,9 +166,7 @@ class PPOConfig(MethodConfig):
         # built from batch constants (rollout values + rewards) so no
         # parameter gradient reaches it there either way — the stop makes
         # the no-leak property local to this function instead of an
-        # accident of the call site, and makes the fused kernel's
-        # targets-are-constants treatment (ops/fused_loss.py) exact by
-        # definition (grad-equality pinned in tests/test_fused_loss.py).
+        # accident of the call site (pinned in tests/test_ppo_loss.py).
         return (
             jax.lax.stop_gradient(advantages),
             jax.lax.stop_gradient(returns),
@@ -264,35 +250,6 @@ class PPOConfig(MethodConfig):
             padding_percentage=1.0 - n / mask.size,
         )
         return loss, flatten_dict(stats)
-
-    def loss_fused(
-        self,
-        logprobs: jax.Array,  # [B, R] new per-token logprobs
-        values: jax.Array,  # [B, R] new value predictions
-        old_logprobs: jax.Array,  # [B, R] proximal-anchor logprobs
-        old_values: jax.Array,  # [B, R] rollout values (GAE input)
-        rewards: jax.Array,  # [B, R] per-token KL-penalty rewards
-        mask: jax.Array,  # [B, R] response mask
-        behavior_logprobs: Optional[jax.Array] = None,
-    ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-        """The ``loss_kernel: pallas`` program: GAE + whitening +
-        :meth:`loss` as one fused Pallas kernel (``ops/fused_loss.py``)
-        instead of staged XLA programs — bit-identical loss/grads/stats.
-        Note the different seam: the fused program takes ``rewards`` and
-        computes advantages/returns *inside* the kernel, so callers skip
-        :meth:`get_advantages_and_returns` entirely."""
-        from trlx_tpu.ops.fused_loss import fused_ppo_loss  # late: ops import us
-
-        return fused_ppo_loss(
-            self,
-            logprobs,
-            values,
-            old_logprobs,
-            old_values,
-            rewards,
-            mask,
-            behavior_logprobs,
-        )
 
 
 def iw_weights(

@@ -31,8 +31,8 @@ collection (:meth:`HealthMonitor.observe_rollout`):
     above ``REPEAT_FRAC_CEIL`` — degenerate looping generations.
 
 Each detector publishes a ``health/<name>`` 0/1 gauge; ``health/verdict``
-summarizes (0 = ok). The string verdict (``"ok"`` or the first tripped
-detector) feeds the bench headline. A detector's first trip of the run logs,
+summarizes (0 = ok); :attr:`verdict` is the string (``"ok"`` or the first
+tripped detector). A detector's first trip of the run logs,
 records a structured ``health`` flight-recorder event, and sets
 :attr:`just_tripped` for exactly one step so the trainer can dump the flight
 record and the offending batch (``triage/step<N>.npz`` — trainer/base.py).

@@ -24,7 +24,7 @@ import threading
 from typing import Any, Dict, List, Optional
 
 # bf16 peak per chip, keyed by a substring of ``device_kind`` — single source
-# of truth (bench.py reads it through device_peak_flops). Sources: Google
+# of truth (read through device_peak_flops). Sources: Google
 # Cloud TPU documentation, system architecture pages per generation. A v5e
 # reports ``device_kind == "TPU v5 lite"``.
 TPU_PEAK_FLOPS = {

@@ -3,10 +3,8 @@
 The paged Engine (``ops/paged_kv.py``, ``trlx_tpu/engine/``) fixed the
 block-pool KV layout while leaving compute dense: every decode segment
 gathers the pool into a transient dense ``[B, S, kvH, D]`` view, runs the
-unchanged dense attention, and scatters the written span back. The
-committed A/B (``benchmarks/ENGINE_PAGED_cpu.json``) shows that per-segment
-gather dominating wall-clock. The kernels here delete that tax for the
-decode inner loop:
+unchanged dense attention, and scatters the written span back. The kernels
+here delete that per-segment gather for the decode inner loop:
 
 - :func:`paged_attention_decode` reads K/V **through the block table in
   place**: the grid walks ``(slot, head, table entry)`` and a

@@ -678,21 +678,6 @@ def budget_configs() -> Dict[str, Tuple[TRLConfig, Dict[str, int]]]:
             ),
             dict(batch_size=8, prompt_len=32, gen_len=16),
         ),
-        "gpt2_test_loss_kernel": (
-            # the fused learner-step kernel (method.loss_kernel: pallas):
-            # train_step compiles with GAE + whitening + the clipped
-            # losses as ONE fused program (ops/fused_loss.py) instead of
-            # the staged chain. Paired with gpt2_test, this budget is the
-            # standing record of the fused program's compiled cost — a
-            # regression that splits the fusion back into staged [B, R]
-            # HBM round-trips shows up as a bytes/temp jump here.
-            base.evolve(
-                model=dict(model_path="builtin:gpt2-test", num_layers_unfrozen=1),
-                tokenizer=dict(tokenizer_path="builtin:bytes"),
-                method=dict(loss_kernel="pallas"),
-            ),
-            dict(batch_size=8, prompt_len=32, gen_len=16),
-        ),
         "ilql_gpt2_test": (
             default_ilql_config().evolve(
                 model=dict(model_path="builtin:gpt2-test", num_layers_unfrozen=-1),

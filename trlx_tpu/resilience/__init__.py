@@ -13,8 +13,8 @@ them *self-healing*. Four pieces, bundled per trainer as
 - :mod:`retry` — retry/timeout/exponential-backoff-with-jitter around
   ``reward_fn`` and tracker publishes, with configurable fallbacks;
 - :mod:`faults` — a deterministic :class:`FaultPlan`
-  (``"sigterm@step:5; nan_loss@step:7"``) that tests and ``bench.py`` use to
-  prove recovery end-to-end on CPU.
+  (``"sigterm@step:5; nan_loss@step:7"``) that tests use to prove recovery
+  end-to-end on CPU.
 
 Atomic checkpoint commits (stage → rename → marker) live in
 ``trlx_tpu/utils/checkpoint.py``; the guard's rollback and ``maybe_resume``

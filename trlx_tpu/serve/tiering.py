@@ -24,8 +24,8 @@ Sharp edges (docs/SERVING.md):
   under the params that computed it, exactly like device-side entries.
 - Spill/re-land move ``block_bytes`` per block over PCIe/host memory; the
   win is elastic: it pays off when re-prefill compute > transfer, which is
-  the regime long shared prompts live in (measured by
-  ``scripts/bench_serve_ab.py``).
+  the regime long shared prompts live in (no benchmark cell prices it
+  yet).
 - The write-back runs un-donated (CPU backends do not implement buffer
   donation and would warn); on a real accelerator a donated variant would
   avoid the transient pool copy.
@@ -135,8 +135,7 @@ class HostTier:
     def reland_many(self, digests: Any, pool: Any, blocks: Any) -> Any:
         """Re-land a consecutive run of spilled chunks in one pool update:
         each pool leaf is copy-on-written ONCE for the whole run instead of
-        once per block (``scripts/bench_serve_ab.py`` measures the
-        difference on multi-block prefixes)."""
+        once per block."""
         vals = [self._pool[d] for d in digests]
         for d in digests:
             self._pool.move_to_end(d)

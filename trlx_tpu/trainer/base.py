@@ -311,19 +311,6 @@ class TPUBaseTrainer(BaseRLTrainer):
             # pass runs the multi-position paged kernel in place
             # (ops/paged_attention.py::paged_verify_attention), so
             # engine.speculative composes with decode_kernel: pallas
-        lk = str(getattr(config.method, "loss_kernel", "xla"))
-        if lk not in ("xla", "pallas"):
-            raise ValueError(
-                f"unknown method.loss_kernel '{lk}' (xla | pallas)"
-            )
-        hostable = getattr(type(config.method), "LOSS_KERNELS", ("xla",))
-        if lk == "pallas" and "pallas" not in hostable:
-            raise ValueError(
-                f"method.loss_kernel: pallas is the fused GAE + whitening + "
-                f"clipped-loss learner kernel (ops/fused_loss.py) — "
-                f"{type(config.method).__name__} has no GAE/value-head loss "
-                f"to fuse (hostable kernels: {list(hostable)})"
-            )
         if config.serve.enabled:
             # each precondition its own error (docs/SERVING.md): the
             # serving frontend is built on block-table operations

@@ -1527,32 +1527,11 @@ class PPOTrainer(TPUBaseTrainer):
         old_values = batch["values"]
         rewards = batch["rewards"]
 
-        # method.loss_kernel: pallas routes through the fused learner kernel
-        # (ops/fused_loss.py): GAE + whitening + clipped loss in ONE program,
-        # so get_advantages_and_returns moves inside the kernel and the
-        # trainer hands it raw rewards instead of precomputed targets. The
-        # XLA path below stays the bit-parity reference.
-        use_fused = getattr(method, "loss_kernel", "xla") == "pallas"
-        if not use_fused:
-            advantages, returns = method.get_advantages_and_returns(
-                old_values, rewards, response_mask
-            )
+        advantages, returns = method.get_advantages_and_returns(
+            old_values, rewards, response_mask
+        )
 
         def method_loss(logprobs, values_pred):
-            if use_fused:
-                loss, stats = method.loss_fused(
-                    logprobs=logprobs,
-                    values=values_pred,
-                    old_logprobs=old_logprobs,
-                    old_values=old_values,
-                    rewards=rewards,
-                    mask=response_mask,
-                    behavior_logprobs=batch.get("behavior_logprobs"),
-                )
-                stats["train/loss_kernel_pallas"] = jnp.asarray(
-                    float(use_fused), jnp.float32
-                )
-                return loss, stats
             return method.loss(
                 logprobs=logprobs,
                 values=values_pred,

@@ -55,8 +55,8 @@ def compile_cache_dir() -> Optional[str]:
 
 def measurement_devices() -> Tuple[list, bool]:
     """``(jax.devices(), rehearsal)`` for an entry point whose output is
-    read as a statement about the accelerator (``bench.py``,
-    ``chip_smoke.py``). Such a run never continues on the CPU by itself: any
+    read as a statement about the accelerator (``chip_smoke.py``,
+    ``chipbench/run.py``). Such a run never continues on the CPU by itself: any
     platform but ``tpu`` raises — unless the caller pinned
     ``JAX_PLATFORMS=cpu``, which asks for a CPU walk of the control flow
     (``rehearsal`` is then True and the caller labels its output so)."""
@@ -79,7 +79,7 @@ def initialize_runtime() -> None:
     """Process-level JAX runtime setup, driven by environment variables.
 
     Called once at the top of :func:`train` (idempotent), and by every entry
-    point that builds a trainer directly (``bench.py``, ``chip_smoke.py``),
+    point that builds a trainer directly (``chip_smoke.py``, ``chipbench/run.py``),
     before the first JAX operation. Three concerns:
 
     - **Compile cache** — see :func:`compile_cache_dir`.
