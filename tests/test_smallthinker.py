@@ -14,6 +14,10 @@ layout does under ``scan_layers`` and on the whole-row rollout paths.
 """
 
 import dataclasses
+import hashlib
+import json
+import os
+import re
 import types
 
 import numpy as np
@@ -276,25 +280,71 @@ def build_prefix_cache():
     ContinuousEngine(fns, None, 0, prewarm=False, prefix_cache=True)
 
 
-def sample_speculatively():
-    from trlx_tpu.ops.speculative import generate_speculative
-
-    ids = jnp.ones((2, 8), jnp.int32)
-    generate_speculative(
-        None, None, None, None, cache_of(CFG), cache_of(TransformerConfig.gpt2("test")),
-        ids, ids, jax.random.PRNGKey(0), GenerationConfig(max_new_tokens=2))
-
-
 @pytest.mark.parametrize("build,path,slots", [
     (lambda: build_slot_refill(paged=False), "slot_refill", 12),
     (lambda: build_slot_refill(paged=True), "engine", 12),
     (build_prefix_cache, "prefix_cache", 16),
-    (sample_speculatively, "speculative", 14),
-], ids=["slot_refill", "engine", "prefix_cache", "speculative"])
+], ids=["slot_refill", "engine", "prefix_cache"])
 def test_whole_row_path_refuses_a_ring_by_name(build, path, slots):
     with pytest.raises(NotImplementedError, match="^" + RING_REFUSAL.format(path=path, slots=slots)):
         build()
     refuse_ring_cache(jax.eval_shape(lambda: make_kv_cache(CFG, 2, 8)), 8, path)  # inside the window: passes
+
+
+def test_speculation_with_a_separate_draft_is_refused_by_the_ring_it_would_overrun():
+    """Speculation's verify writes a span of gamma + 1 tokens at each row's own
+    index, which a ring takes where it holds window + gamma slots: a model
+    that drafts with its own module gets those (tests/test_exaone_moe.py),
+    this one's rings are the window's 8, and the model says so by name."""
+    from trlx_tpu.ops.speculative import generate_speculative
+
+    draft_cfg = TransformerConfig.gpt2("test", param_dtype=jnp.float32, dtype=jnp.float32)
+    draft_cfg = dataclasses.replace(draft_cfg, vocab_size=CFG.vocab_size)
+    target, draft = CausalTransformer(CFG), CausalTransformer(draft_cfg)
+    ids = jnp.ones((2, 8), jnp.int32)
+    d_params = draft.init(jax.random.PRNGKey(0), ids)["params"]
+    with pytest.raises(NotImplementedError, match="a span of 5 tokens at each row's own index into a ring cache of 8 slots"):
+        generate_speculative(
+            lambda p, i, **kw: target.apply({"params": p}, i, **kw), seeded_params(0),
+            lambda p, i, **kw: draft.apply({"params": p}, i, **kw), d_params,
+            cache_of(CFG), cache_of(draft_cfg), ids, ids, jax.random.PRNGKey(0), GenerationConfig(max_new_tokens=4))
+
+
+RING_PROGRAMS = os.path.join(os.path.dirname(__file__), "fixtures", "smallthinker_ring_programs_before_exaone.json")
+
+
+def ring_program_fingerprints():
+    """sha256 of the toy's cache tree for rows of 16 slots (rings of 8) and of
+    the jaxpr text of the sampler's two programs on it: the prefill of 12
+    tokens from slot 0 and the single-token step at slot 12."""
+    cfg = config_from_spec("builtin:smallthinker-test", attention_impl="xla")
+    model = CausalTransformer(cfg)
+    ids = jnp.zeros((2, 12), jnp.int32)
+    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), ids)["params"])
+    cache = jax.eval_shape(lambda: make_kv_cache(cfg, 2, 16))
+    slots = jnp.ones((2, 16), jnp.int32)
+    texts = {
+        "cache": str(jax.tree_util.tree_map(lambda x: (x.shape, str(x.dtype)), cache)),
+        "prefill": str(jax.make_jaxpr(
+            lambda p, c: model.apply({"params": p}, ids, attention_mask=slots, cache=c,
+                                     cache_index=jnp.asarray(0, jnp.int32)))(params, cache)),
+        "decode": str(jax.make_jaxpr(
+            lambda p, c: model.apply({"params": p}, ids[:, :1], attention_mask=slots, cache=c,
+                                     cache_index=jnp.asarray(12, jnp.int32), kv_extents=(8, 16)))(params, cache)),
+    }
+    clean = lambda text: re.sub(r"0x[0-9a-f]+", "0x", text)
+    return {k: hashlib.sha256(clean(v).encode()).hexdigest() for k, v in texts.items()}
+
+
+@pytest.mark.parametrize("program", ["cache", "prefill", "decode"])
+def test_ring_programs_are_the_ones_recorded_before_the_per_row_ring(program):
+    """Recorded on PR 46's parent by this function: ``make_kv_cache`` and
+    ``_ring_plan`` took a ``[B]`` vector of cache indices, a span past slot 0
+    and ``gamma`` more slots for a model that drafts, under this model's feet,
+    and its cache tree and both programs through the ring are byte for byte
+    what they were (the benchmark's cell 6)."""
+    with jax.default_matmul_precision(None), open(RING_PROGRAMS) as f:
+        assert ring_program_fingerprints()[program] == json.load(f)[program]
 
 
 @pytest.mark.parametrize("how", ["vector_cache_index", "span_past_slot_zero"])

@@ -871,3 +871,121 @@ class TestPerRowRngComposition:
         out = _spec(t, d, ids[:1], mask[:1], cfg, 2)
         assert np.asarray(out.response_tokens).shape == (1, 6)
         assert int(np.asarray(out.response_mask).sum()) == 6
+
+
+# ---------------------------------------------------------------------------
+# the model's own next-token-prediction module as the drafter
+# (``module_drafter``; the toy of K-EXAONE: a window of 8 on four layers, so
+# every ring is shorter than the row, and a global layer)
+# ---------------------------------------------------------------------------
+
+
+def _self_drafting_model():
+    kw = dict(model_extra_kwargs=dict(dtype=jnp.float32, param_dtype=jnp.float32, attention_impl="xla"))
+    mod, params, cfg = build_causal_lm(ModelConfig("builtin:k-exaone-test", **kw), head="value")
+    apply = lambda p, i, **k: mod.apply({"params": p}, i, **k)
+    draft = lambda p, h, n, **k: mod.apply({"params": p}, h, n, method="draft", **k)
+    return apply, draft, params, cfg
+
+
+def _self_spec(model, ids, mask, cfg, rng=0, **kw):
+    from trlx_tpu.ops.speculative import module_drafter
+
+    apply, draft, params, tcfg = model
+    L = tcfg.num_layers
+    return generate_speculative(
+        apply, params, None, params,
+        lambda b, s: make_kv_cache(tcfg, b, s, jnp.float32)[:L],
+        lambda b, s: make_kv_cache(tcfg, b, s, jnp.float32)[L:],
+        ids, mask, jax.random.PRNGKey(rng), cfg, gamma=1, drafter=module_drafter(draft), **kw,
+    )
+
+
+def _long_prompts(B=4, P=12):
+    """Prompts longer than the window, rows at unlike depths of padding."""
+    rs = np.random.RandomState(1)
+    ids = rs.randint(0, 250, (B, P)).astype(np.int32)
+    mask = (np.arange(P)[None, :] >= (2 * (np.arange(B) % 4))[:, None]).astype(np.int32)
+    ids[mask == 0] = 258
+    return jnp.asarray(ids), jnp.asarray(mask)
+
+
+@pytest.mark.parametrize("eos", [None, 7], ids=["to_the_budget", "eos_stops_rows"])
+def test_self_draft_greedy_exactly_matches_plain_sampler(eos):
+    """Greedy output of the self-drafting sampler (tokens, mask, logprobs,
+    values) is the plain sampler's, through rings shorter than the row: the
+    two-token verify at each row's own index reads what the single-token step
+    reads."""
+    model = _self_drafting_model()
+    apply, _, params, tcfg = model
+    ids, mask = _long_prompts()
+    cfg = GenerationConfig(max_new_tokens=20, do_sample=False, eos_token_id=eos, pad_token_id=258)
+    ref = generate(apply, params, lambda b, s: make_kv_cache(tcfg, b, s, jnp.float32),
+                   ids, mask, jax.random.PRNGKey(0), cfg)
+    out, stats = jax.jit(partial(_self_spec, model, cfg=cfg, return_stats=True))(ids, mask)
+    assert (np.asarray(out.response_tokens) == np.asarray(ref.response_tokens)).all()
+    assert (np.asarray(out.response_mask) == np.asarray(ref.response_mask)).all()
+    np.testing.assert_allclose(out.response_logprobs, ref.response_logprobs, atol=1e-5)
+    np.testing.assert_allclose(out.response_values, ref.response_values, atol=1e-5)
+    assert int(stats["proposed_draft_tokens"]) == int(stats["live_row_rounds"]) <= 4 * int(stats["rounds"])
+
+
+def test_identical_draft_accepts_everything_through_rings():
+    """With ``q`` equal to ``p`` (the model's own stack as a separate drafter,
+    one proposal a round) every proposal is accepted and a round commits two
+    tokens: the drafter's single-token writes and the verify's two-token span
+    land in the same ring positions, each row at its own index."""
+    from trlx_tpu.models.transformer import CausalTransformer
+
+    apply, _, params, tcfg = _self_drafting_model()
+    bare = CausalTransformer(tcfg)
+    d = (lambda p, i, **k: bare.apply({"params": p}, i, **k), params["backbone"], tcfg)
+    ids, mask = _long_prompts()
+    cfg = GenerationConfig(max_new_tokens=20, do_sample=True, temperature=1.0, eos_token_id=None, pad_token_id=258)
+    out, stats = _spec((apply, params, tcfg), d, ids, mask, cfg, gamma=1, return_stats=True)
+    assert np.asarray(out.response_mask).all()
+    assert float(stats["acceptance_rate"]) > 0.97 and int(stats["rounds"]) <= 11, stats
+    assert abs(float(stats["tokens_per_round"]) - (1 + float(stats["acceptance_rate"]))) < 0.05
+
+
+def test_self_draft_records_the_targets_logprobs_and_values():
+    """Sampled rollouts: the logprob and the value recorded at every position
+    are those of ONE scoring forward of the target over the finished rows
+    (PPO's ``make_experience`` cannot tell which sampler made them)."""
+    model = _self_drafting_model()
+    apply, _, params, _ = model
+    ids, mask = _long_prompts()
+    N = 20
+    cfg = GenerationConfig(max_new_tokens=N, do_sample=True, temperature=1.0, eos_token_id=None, pad_token_id=258)
+    out, stats = jax.jit(partial(_self_spec, model, cfg=cfg, rng=5, return_stats=True))(ids, mask)
+    P = ids.shape[1]
+    full = apply(params, out.sequences, attention_mask=jnp.concatenate([mask, out.response_mask], axis=1))
+    lp = jax.nn.log_softmax(full["logits"][:, P - 1 : -1].astype(jnp.float32), axis=-1)
+    want = jnp.take_along_axis(lp, out.response_tokens[..., None], axis=-1)[..., 0]
+    np.testing.assert_allclose(out.response_logprobs, want, atol=2e-5)
+    np.testing.assert_allclose(out.response_values, full["value"][:, P - 1 : -1], atol=2e-5)
+    assert 0 < int(stats["accepted_draft_tokens"]) < int(stats["proposed_draft_tokens"])  # rows at unlike depths
+
+
+def test_self_draft_sampling_is_distribution_exact_under_rings_at_unlike_depths():
+    """A chi-square of the self-drafting sampler's tokens against the plain
+    sampler's, 2048 rows of one prompt each: the marginal of the token at
+    response positions 1, 3 and 5 (behind one to three rounds of unlike
+    acceptance histories, so rows at unlike depths; the prompt is longer than
+    the window, so every ring has wrapped), binned by token id mod 8. Seven
+    degrees of freedom: 24.3 is the 0.1% point."""
+    model = _self_drafting_model()
+    apply, _, params, tcfg = model
+    B = 2048
+    ids = jnp.tile(jnp.asarray([[5, 9, 17, 23, 40, 41, 77, 3, 200, 150, 99, 12]], jnp.int32), (B, 1))
+    mask = jnp.ones_like(ids)
+    cfg = GenerationConfig(max_new_tokens=6, do_sample=True, temperature=0.7, eos_token_id=None, pad_token_id=258)
+    ref = jax.jit(lambda r: generate(apply, params, lambda b, s: make_kv_cache(tcfg, b, s, jnp.float32),
+                                     ids, mask, r, cfg))(jax.random.PRNGKey(3))
+    out, stats = jax.jit(partial(_self_spec, model, cfg=cfg, rng=11, return_stats=True))(ids, mask)
+    assert 0.02 < float(stats["acceptance_rate"]) < 0.98
+    for position in (1, 3, 5):
+        a = np.bincount(np.asarray(ref.response_tokens)[:, position] % 8, minlength=8).astype(np.float64)
+        b = np.bincount(np.asarray(out.response_tokens)[:, position] % 8, minlength=8).astype(np.float64)
+        chi2 = float(((a - b) ** 2 / np.maximum(a + b, 1.0)).sum())
+        assert chi2 < 24.3, (position, chi2, a, b)

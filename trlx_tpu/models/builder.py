@@ -320,7 +320,8 @@ def trainable_mask(
     blocks, the final norm, the lm head; and so does every top-level tree
     beside the backbone (value head, Q and V heads) except ILQL's target-Q
     heads, which never train (reference ``freeze_bottom_causal_layers``,
-    ``trlx/utils/modeling.py:34-44``). A policy without a ``backbone`` key (GRPO's
+    ``trlx/utils/modeling.py:34-44``), and a next-token-prediction module
+    ``mtp_<k>``, which no loss reads. A policy without a ``backbone`` key (GRPO's
     bare transformer) has nothing this function freezes: every leaf trains,
     whatever ``num_layers_unfrozen`` says. Under ``scan_layers`` the stacked
     ``h_scan`` leaves get a per-layer 0/1 vector where only some layers train.
@@ -343,6 +344,11 @@ def trainable_mask(
                     sub[name] = _mask_scan_blocks(
                         layer_tree, tcfg, num_layers_unfrozen, lora
                     )
+                    continue
+                if name.startswith("mtp_"):
+                    # a next-token-prediction module: the rollout sampler's drafter,
+                    # which no loss reads (TransformerConfig.mtp_layers)
+                    sub[name] = _mark(layer_tree, False)
                     continue
                 if name.startswith("h_"):
                     in_range = (

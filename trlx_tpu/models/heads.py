@@ -90,6 +90,10 @@ class CausalLMWithValueHead(nn.Module):
     def init_cache(self, batch_size, max_length, dtype=None):
         return self.backbone.init_cache(batch_size, max_length, dtype)
 
+    def draft(self, hidden, next_ids, **kw):
+        """The backbone's next-token-prediction module (``CausalTransformer.draft``)."""
+        return self.backbone.draft(hidden, next_ids, **kw)
+
 
 class ILQLHeadsModule(nn.Module):
     """V head + n Q heads + n frozen target-Q heads over hidden states."""

@@ -41,6 +41,12 @@ _GLM_MOE_DSA_NO_INTEROP = (
     "(random weights) only and no converter pair was ever checked against one "
     "(ROADMAP.md queue 2, B8)"
 )
+_EXAONE_MOE_NO_INTEROP = (
+    "model_type 'exaone_moe' has no HF checkpoint conversion yet: no checkpoint can "
+    "be fetched where this was built, so the family runs from 'builtin:k-exaone-<size>' "
+    "(random weights) only and no converter pair was ever checked against one "
+    "(ROADMAP.md queue 2, B6)"
+)
 
 
 class UnsupportedHFExport(ValueError):
@@ -526,6 +532,8 @@ def config_from_hf(hf_config) -> TransformerConfig:
         raise ValueError(_PANGU_ULTRA_MOE_NO_INTEROP)
     if mt == "glm_moe_dsa":
         raise ValueError(_GLM_MOE_DSA_NO_INTEROP)
+    if mt == "exaone_moe":
+        raise ValueError(_EXAONE_MOE_NO_INTEROP)
     raise ValueError(f"Unsupported HF model type for causal import: {mt}")
 
 
@@ -1165,6 +1173,8 @@ def hf_config_from_transformer(cfg):
         raise UnsupportedHFExport(_PANGU_ULTRA_MOE_NO_INTEROP)
     if mt == "glm_moe_dsa":
         raise UnsupportedHFExport(_GLM_MOE_DSA_NO_INTEROP)
+    if mt == "exaone_moe":
+        raise UnsupportedHFExport(_EXAONE_MOE_NO_INTEROP)
     raise UnsupportedHFExport(
         f"No HF export mapping for model_type={mt!r} "
         "(set TransformerConfig.model_type to an HF family)"

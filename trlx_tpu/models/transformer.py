@@ -187,6 +187,10 @@ def _flash_attention(q, k, v, flash_args: Dict[str, Any]) -> jax.Array:
 # (chipbench/configs/smallthinker-21b-a3b-l4e16.json, `assumed`, has the
 # measured numbers). A constant with its measurement, not a setting.
 QK_INIT_STD_SMALLTHINKER = 0.04
+# the like for K-EXAONE, whose per-head QK-norm takes it as the norms' scale
+# (`_qk_norm`): 2 on q and on k, a score of standard deviation 4
+# (chipbench/configs/k-exaone-236b-a23b-l5e8.json, `assumed`)
+QK_INIT_STD_EXAONE = 0.04
 
 
 class LayerLayout(NamedTuple):
@@ -245,9 +249,11 @@ class TransformerConfig:
     activation: str = "gelu_new"  # gelu_new | gelu | silu | relu
     parallel_residual: bool = False  # gptj/neox style
     shared_ln: bool = False  # gptj: one LN feeds both attn and mlp
-    # olmoe: RMSNorm with a learned scale over the whole projected width of
-    # q and of k, before the split into heads and before rotary
-    qk_norm: bool = False
+    # RMSNorm with a learned scale on q and on k, before rotary. True (olmoe):
+    # over the whole projected width, before the split into heads. "head"
+    # (exaone_moe): over each head's own dims, one scale of the head's size
+    # that every head of q (of k) shares
+    qk_norm: Any = False  # False | True | "head"
     attn_bias: bool = True
     mlp_bias: bool = True
     qkv_bias: Optional[bool] = None  # overrides attn_bias for q/k/v if set
@@ -384,6 +390,19 @@ class TransformerConfig:
     moe_topk_method: str = "greedy"
     moe_bias_init_std: float = 0.0  # std of that bias's random init (a trained one balances load)
 
+    # next-token-prediction modules behind the stack (exaone_moe's published
+    # `num_nextn_predict_layers`; the DeepSeek-V3 report's module): module k is
+    # a subtree `mtp_<k>` beside the `h_<i>` blocks, ONE more block fed
+    # `eh_proj [RMSNorm(h_t) ; RMSNorm(Emb(x_{t+1}))]` (`h_t` the last layer's
+    # output before the final norm), with a final norm of its own and the main
+    # embedding and head: a distribution over `x_{t+2}`. Its layer kind is
+    # `layer_layout(num_layers + k)` and its cache layer `make_kv_cache`'s entry
+    # `num_layers + k`. Only `CausalTransformer.draft` runs it (the rollout
+    # sampler's drafter, `ops/speculative.py`): the scoring forward, the hydra
+    # branch and the train step never do, it takes no adapter and
+    # `trainable_mask` freezes it. One module is built
+    mtp_layers: int = 0
+
     # a second sequence mixer beside attention in every block (falcon_h1):
     # "mamba2" runs Mamba-2 heads and the attention heads on the SAME normed
     # input and adds both to the residual. Its per-sequence state (the
@@ -425,6 +444,14 @@ class TransformerConfig:
             raise ValueError("sandwich_norm is built for the sequential residual path only")
         if self.kv_lora_rank and (self.qk_norm or self.position_scheme != "rotary" or self.mixer != "none"):
             raise ValueError("latent attention (kv_lora_rank > 0) takes rotary positions, no qk_norm, no second mixer")
+        if self.qk_norm not in (False, True, "head"):
+            raise ValueError(f"qk_norm {self.qk_norm!r} is not False, True (the whole projected width) or 'head'")
+        if self.mtp_layers not in (0, 1) or (self.mtp_layers and (self.latent_attention or self.mixer != "none" or self.scan_layers)):
+            raise NotImplementedError(
+                "mtp_layers: ONE next-token-prediction module is built, behind a stack of K/V attention layers run "
+                "unscanned; several modules chained, a module under a latent cache or beside a recurrent state are "
+                "not (ROADMAP.md queue 2, B6)"
+            )
         if self.moe_topk_method not in ("greedy", "noaux_tc"):
             raise ValueError(f"moe_topk_method {self.moe_topk_method!r} is not greedy or noaux_tc")
         if self.index_topk:
@@ -448,6 +475,14 @@ class TransformerConfig:
         return self.num_kv_heads or self.num_heads
 
     def layer_layout(self, layer: int) -> LayerLayout:
+        if layer >= self.num_layers:
+            # a next-token-prediction module's block (`mtp_layers`): one of the stack's
+            # global layers, so without rotary wherever the stack's global layers have none
+            return LayerLayout(
+                window=None,
+                rotary=self.position_scheme == "rotary" and self.rope_layout is None,
+                ffn="moe" if self.num_experts > 0 else "dense",
+            )
         windowed = self.sliding_window_layout is None or bool(self.sliding_window_layout[layer])
         roped = self.rope_layout is None or bool(self.rope_layout[layer])
         return LayerLayout(
@@ -743,6 +778,61 @@ class TransformerConfig:
         )
 
     @staticmethod
+    def exaone(size: str = "236b-a23b", **overrides) -> "TransformerConfig":
+        """K-EXAONE-236B-A23B (``model_type`` ``exaone_moe``): GQA with an
+        RMSNorm on each head's q and k (``qk_norm: "head"``); three layers in
+        four attend through a window of 128 with rotary embedding, the fourth
+        globally with none; one leading dense SwiGLU layer, then layers of 128
+        routed SwiGLU experts (sigmoid scores, top 8 renormalised, times 2.5)
+        beside one shared expert; ONE next-token-prediction module
+        (``mtp_layers``), with which the model drafts its own rollouts
+        (``ops/speculative.py``). Limits: the rollout sampler (plain or
+        self-drafting: a window layer keeps a ring of ``window + 1`` slots),
+        the scoring forward, the hydra branch and the train step; no slot
+        refill, paged Engine or prefix cache while a layer's cache is shorter
+        than the row (``ops/paged_kv.py::refuse_ring_cache``); no
+        ``scan_layers``, no HF checkpoint import. ``qk_init_std`` is the
+        stand-in scale of the scores, which under a per-head norm lives in the
+        norms' scales (``_qk_norm``; chipbench/configs/k-exaone-236b-a23b-l5e8.json,
+        `assumed`). ``builtin:k-exaone-236b-a23b`` | ``builtin:k-exaone-test``."""
+        period = (1, 1, 1, 0)
+        dims = {
+            # the benchmark's cut in small: a dense layer, a whole period, the module behind a global layer;
+            # a window of 8 that binds on any row past 8 tokens
+            "test": dict(vocab_size=259, hidden_size=64, num_layers=5, num_heads=4, num_kv_heads=2, head_dim=16, intermediate_size=128, max_position_embeddings=128,
+                         moe_intermediate_size=32, num_experts=8, num_experts_per_tok=2, sliding_window=8,
+                         sliding_window_layout=(1,) + period, rope_layout=(1,) + period),
+            "236b-a23b": dict(vocab_size=153600, hidden_size=6144, num_layers=48, num_heads=64, num_kv_heads=8, head_dim=128, intermediate_size=18432, max_position_embeddings=262144,
+                              moe_intermediate_size=2048, num_experts=128, num_experts_per_tok=8, sliding_window=128,
+                              sliding_window_layout=period * 12, rope_layout=period * 12),
+        }[size]
+        return _make_preset(
+            dims,
+            overrides,
+            model_type="exaone_moe",
+            position_scheme="rotary",
+            rope_theta=1e6,
+            norm="rmsnorm",
+            layer_norm_epsilon=1e-5,
+            activation="silu",
+            attn_bias=False,
+            mlp_bias=False,
+            tie_word_embeddings=False,
+            qk_norm="head",
+            first_k_dense=1,
+            num_shared_experts=1,
+            moe_gated=True,
+            moe_scoring="sigmoid",
+            routed_scaling_factor=2.5,
+            moe_capacity_factor=0.0,  # dropless
+            moe_renormalize=True,  # norm_topk_prob: true
+            router_aux_coef=0.0,  # the config publishes no balance loss
+            mtp_layers=1,
+            embed_init_std=1.0,
+            qk_init_std=QK_INIT_STD_EXAONE,
+        )
+
+    @staticmethod
     def falconh1(size: str = "34b", **overrides) -> "TransformerConfig":
         """Falcon-H1: Mamba-2 heads beside attention heads in every block.
         Limits: the plain sampler, the scoring forward and the train step
@@ -945,11 +1035,15 @@ def Norm(config: TransformerConfig, name: str):
 
 
 def _qk_norm(config: TransformerConfig, name: str):
+    # a per-head norm divides q_proj's and k_proj's scale out again, so under it the
+    # stand-in weights' `qk_init_std` goes into the norms' learned scales instead
+    # (1 at the default 0.02): the scores' spread is the product of the two scales
+    scale = config.qk_init_std / 0.02 if config.qk_norm == "head" else 1.0
     return nn.RMSNorm(
         epsilon=config.layer_norm_epsilon,
         dtype=config.dtype,
         param_dtype=config.param_dtype,
-        scale_init=param_with_axes(nn.initializers.ones, ("joined_kv",)),
+        scale_init=param_with_axes(nn.initializers.constant(scale), ("joined_kv",)),
         name=name,
     )
 
@@ -1137,11 +1231,13 @@ class Attention(nn.Module):
         v = _dense(cfg, KV * D, qkv_bias, ("embed", "joined_kv"), "v_proj")(x).reshape(B, T, KV, D)
         if cfg.key_multiplier != 1.0:
             k = k * cfg.key_multiplier
-        if cfg.qk_norm:
+        if cfg.qk_norm is True:
             # over the whole projected width (all heads together), float32
             # statistics; every cache and kernel path below sees normed q, k
             q, k = _qk_norm(cfg, "q_norm")(q), _qk_norm(cfg, "k_norm")(k)
         q, k = q.reshape(B, T, H, D), k.reshape(B, T, KV, D)
+        if cfg.qk_norm == "head":  # over each head's D dims, one scale [D] for all heads
+            q, k = _qk_norm(cfg, "q_norm")(q), _qk_norm(cfg, "k_norm")(k)
 
         if (cfg.position_scheme == "rotary") if self.rotary is None else self.rotary:
             rdim = cfg.rotary_dim or D
@@ -1239,14 +1335,19 @@ class Attention(nn.Module):
             # (CausalTransformer._ring_plan built the bias / flash_args to match)
             ci = jnp.asarray(cache_index)
             C = cache["k"].shape[1]
-            if T == 1:
+            if ci.ndim:
+                # each row's span of T tokens at its own slot (speculation's verify): slot
+                # ci + i of row b goes to ring position (ci[b] + i) mod C
+                rows, at = jnp.arange(B)[:, None], (ci[:, None] + jnp.arange(T)[None, :]) % C
+                write = lambda c, x: c.at[rows, at].set(x.astype(c.dtype), unique_indices=True)
+            elif T == 1:
                 write = lambda c, x: jax.lax.dynamic_update_slice(c, x.astype(c.dtype), (0, ci % C, 0, 0))
             elif T <= C:  # a prefill from slot 0 that does not wrap
                 write = lambda c, x: jax.lax.dynamic_update_slice(c, x.astype(c.dtype), (0, 0, 0, 0))
             else:  # a prefill from slot 0: its last C positions stay
                 write = lambda c, x: jnp.roll(x[:, T - C :].astype(c.dtype), (T - C) % C, axis=1)
             new_cache = {"k": write(cache["k"], k), "v": write(cache["v"], v)}
-            if T == 1:
+            if T == 1 or ci.ndim:
                 k, v = new_cache["k"], new_cache["v"]
             # a prefill attends over its own k, v: nothing older is in the ring
         elif cache is not None:
@@ -2398,6 +2499,27 @@ class Block(nn.Module):
         return x, new_cache, aux, selection
 
 
+class NextTokenModule(nn.Module):
+    """One next-token-prediction module (``TransformerConfig.mtp_layers``):
+    ``x' = eh_proj [h_norm(h) ; e_norm(emb)]``, one ``Block`` of the kind
+    ``layer_layout(layer)`` over its own keys and values, and a final norm of
+    its own. Returns the block's output (what a second module would be fed),
+    that output normed (what the main head reads) and the cache layer."""
+
+    config: TransformerConfig
+    layer: int
+
+    @nn.compact
+    def __call__(self, hidden, emb, attention_bias, positions, cache, cache_index, flash_args, token_mask):
+        cfg = self.config
+        joined = jnp.concatenate([Norm(cfg, "h_norm")(hidden), Norm(cfg, "e_norm")(emb)], axis=-1)
+        x = _dense(cfg, cfg.hidden_size, False, ("mlp", "embed"), "eh_proj")(joined)
+        # PPO neither reads nor trains the module: it takes no adapter
+        block = Block(dataclasses.replace(cfg, lora_r=0), self.layer, name="block")
+        x, new_cache, _, _ = block(x, attention_bias, positions, cache, cache_index, flash_args, token_mask)
+        return x, Norm(cfg, "ln_f")(x), new_cache
+
+
 def _remat_policy(cfg: TransformerConfig):
     """Rematerialisation policy per ``cfg.remat``:
 
@@ -2507,6 +2629,7 @@ class CausalTransformer(nn.Module):
             self.ln_f = Norm(cfg, name="ln_f")
         if not cfg.tie_word_embeddings:
             self.lm_head = _dense(cfg, cfg.vocab_size, cfg.lm_head_bias, ("embed", "vocab"), "lm_head")
+        self.mtp = [NextTokenModule(cfg, cfg.num_layers + k, name=f"mtp_{k}") for k in range(cfg.mtp_layers)]
 
     def _logits(self, h):
         cfg = self.config
@@ -2618,24 +2741,43 @@ class CausalTransformer(nn.Module):
         cfg = self.config
         B, T = positions.shape
         ci = jnp.asarray(cache_index)
-        if ci.ndim or cfg.position_scheme == "alibi":
+        if cfg.position_scheme == "alibi":
             raise NotImplementedError(
                 "a window layer's ring cache (fewer slots than the row: make_kv_cache) "
-                "is written by the plain sampler only: one scalar cache_index for all "
-                "rows, no ALiBi (ROADMAP.md queue 2, B3)"
+                "takes no ALiBi (ROADMAP.md queue 2, B3)"
             )
+        if ci.ndim:
+            # each row's span of T tokens at its own slot (speculation's verify): the ring
+            # is read after all T are written, so position j holds the newest slot <= the
+            # span's last congruent to j, and a query sees those at or before its own slot
+            # and inside its window. The span's first query still needs slot ci - window + 1,
+            # which the write of the span's last must not have landed on
+            if slots < window + T - 1:
+                raise NotImplementedError(
+                    f"a span of {T} tokens at each row's own index into a ring cache of {slots} slots under a window "
+                    f"of {window}: the ring must hold window + span - 1 slots (make_kv_cache gives a model that drafts "
+                    "with its own module, mtp_layers, window + gamma; a separate draft model beside a ring is not built)"
+                )
+            top = (ci + T - 1)[:, None]  # [B, 1]
+            slot = top - jnp.mod(top - jnp.arange(slots)[None, :], slots)  # [B, C]
+            valid = (slot >= 0) & (jnp.take_along_axis(key_mask, jnp.maximum(slot, 0), axis=1) > 0)
+            behind = _query_slots(ci, B, T)[:, :, None] - slot[:, None, :]  # [B, T, C] slots behind the query
+            visible = valid[:, None, :] & (behind >= 0) & (behind < window)
+            return jnp.where(visible, 0.0, -1e9)[:, None], None, StaticExtents((slots,), ring=True)
         if T > 1:
             # (a traced index cannot be looked at: the caller's word for it)
             if not isinstance(ci, jax.core.Tracer) and int(ci) != 0:
                 raise NotImplementedError(
-                    "a span of tokens into a ring cache must start at slot 0 (the "
-                    "sampler's prefill): chunked prefill over a ring is not built"
+                    "a span of tokens into a ring cache at ONE cache_index for all rows must start at "
+                    "slot 0 (the sampler's prefill): chunked prefill over a ring is not built"
                 )
             view = StaticExtents((slots,), ring=True)
             return self._attn_inputs(key_mask[:, :T], positions, 0, use_flash, window) + (view,)
         j = jnp.arange(slots)
         slot = ci - jnp.mod(ci - j, slots)  # the newest slot <= ci at ring position j
         ring_mask = jnp.where(slot >= 0, jnp.take(key_mask, jnp.maximum(slot, 0), axis=1), 0)
+        if slots > window:  # a drafting model's ring holds `gamma` slots more than the window
+            ring_mask = jnp.where(ci - slot < window, ring_mask, 0)
         bias = jnp.where(ring_mask > 0, 0.0, -1e9)[:, None, None, :]
         from trlx_tpu.ops.sampling import layer_extents
 
@@ -2701,6 +2843,8 @@ class CausalTransformer(nn.Module):
                 token_mask = _token_validity(attention_mask, offset, T)
 
         x = self._embed(input_ids, positions)
+        if self.mtp and self.is_initializing():  # no forward but `draft` runs the module: make its leaves
+            self.draft(x, input_ids)
         # flash kernels take a scalar slot offset; per-row cache depths
         # (speculative decoding) go through the bias path (T is tiny there).
         # Paged (block-table-carrying) caches always take the bias path too:
@@ -2761,6 +2905,8 @@ class CausalTransformer(nn.Module):
                 aux = aux + aux_i
                 if cache is not None:
                     new_cache.append(updated)
+            if cache is not None:  # a next-token-prediction module's layers: `draft` writes them
+                new_cache.extend(cache[cfg.num_layers :])
 
         return self._epilogue(x, branch_input, new_cache, logits_span, aux)
 
@@ -2901,6 +3047,40 @@ class CausalTransformer(nn.Module):
         logits = self._logits(h if logits_span is None else h[:, logits_span[0] : logits_span[1]])
         return {"logits": logits, "hidden_states": h}
 
+    def draft(
+        self,
+        hidden: jax.Array,  # [B, T, E]: `pre_norm_hidden` of the tokens at slots [cache_index, cache_index + T)
+        next_ids: jax.Array,  # [B, T]: the token AFTER each of them
+        attention_mask: Optional[jax.Array] = None,  # [B, T], or the [B, S] slot mask with a cache
+        cache: Optional[List[Dict[str, jax.Array]]] = None,  # the modules' layers: make_kv_cache(...)[num_layers:]
+        cache_index: Optional[jax.Array] = None,
+        logits_span: Optional[Tuple[int, int]] = None,
+    ) -> Dict[str, Any]:
+        """The next-token-prediction module (``mtp_layers``): from the stack's
+        hidden state at token ``t`` and the embedding of token ``t + 1``, logits
+        over token ``t + 2``, through the main embedding and head. The module's
+        entry for token ``t`` lives at ``t``'s slot of its own cache layer: the
+        rollout sampler's drafter (``ops/speculative.py::module_drafter``)."""
+        cfg = self.config
+        B, T = next_ids.shape
+        if attention_mask is None:
+            attention_mask = jnp.ones((B, T), jnp.int32)
+        q_offset = cache_index if cache is not None and cache_index is not None else 0
+        key_pos = jnp.maximum(jnp.cumsum(attention_mask, axis=1) - 1, 0)
+        positions = jax.vmap(lambda kp, qs: kp[qs])(key_pos, _query_slots(q_offset, B, T))
+        token_mask = _token_validity(attention_mask, q_offset, T) if cache is not None else attention_mask
+        use_flash = cfg.resolved_attention_impl() == "pallas" and T > 1 and jnp.asarray(q_offset).ndim == 0
+        bias, flash_args = self._attn_inputs(attention_mask, positions, q_offset, use_flash, None)
+        with jax.named_scope("trlx/mtp_draft"):
+            x, new_cache = hidden, []
+            for k, module in enumerate(self.mtp):
+                x, h, updated = module(
+                    x, self._embed(next_ids, positions), bias, positions, None if cache is None else cache[k], cache_index, flash_args, token_mask
+                )
+                new_cache.append(updated)
+            logits = self._logits(h if logits_span is None else h[:, logits_span[0] : logits_span[1]])
+        return {"logits": logits, "cache": new_cache if cache is not None else None}
+
     def project_logits(self, hidden: jax.Array) -> jax.Array:
         """Vocab projection of (already final-normed) hidden states — lets
         loss code stream chunks through the lm head instead of
@@ -2923,9 +3103,11 @@ def make_kv_cache(
     or one stacked dict with a leading layer dim when ``cfg.scan_layers``.
     A layer's ``k`` and ``v`` are as long as its layout needs
     (``cfg.layer_layout``): ``max_length`` slots for a full-causal layer,
-    ``min(max_length, window)`` for a window layer, which the plain sampler
-    then writes as a ring (slot ``t`` at ``t mod window``: ``CausalTransformer.
-    _ring_plan``). A ``mixer: mamba2`` layer also holds ``ssm`` (the recurrent
+    ``min(max_length, window)`` for a window layer, which the sampler then
+    writes as a ring (slot ``t`` at ``t mod window``: ``CausalTransformer.
+    _ring_plan``); a model that drafts its own rollouts (``mtp_layers``) gets
+    ``window + mtp_layers`` slots a ring and, behind the blocks' layers, one
+    more cache layer for each module. A ``mixer: mamba2`` layer also holds ``ssm`` (the recurrent
     state, float32 whatever ``dtype``: hundreds of steps of ``S = aS + ...``
     drift in bf16) and ``conv`` (the conv's last ``K - 1`` input rows). A
     latent-attention layer (``kv_lora_rank`` > 0) holds ``ckv`` ``[B, slots,
@@ -2944,7 +3126,10 @@ def make_kv_cache(
     stacked = (cfg.num_layers,) if cfg.scan_layers else ()
 
     def layer(layout: LayerLayout):
-        slots = min(max_length, layout.window) if layout.window else max_length
+        # a model that drafts (`mtp_layers`) verifies a span of gamma + 1 = mtp_layers + 1
+        # tokens a round: the write of the span's last must not land on the slot the
+        # span's first still reads (`_ring_plan`)
+        slots = min(max_length, layout.window + cfg.mtp_layers) if layout.window else max_length
         if cfg.latent_attention:
             # the normed latent and the one roped key (ops/paged_kv.py::LATENT_LEAVES), the
             # same slot axis and cache_index as K and V have, and no K or V; side by side
@@ -2978,7 +3163,8 @@ def make_kv_cache(
 
     if cfg.scan_layers:  # one layout for the stack: CausalTransformer refuses a mixed one
         return layer(cfg.layer_layout(0))
-    return [layer(layout) for layout in cfg.layer_layouts]
+    # the blocks' layers, then one for each next-token-prediction module (`CausalTransformer.draft`)
+    return [layer(cfg.layer_layout(i)) for i in range(cfg.num_layers + cfg.mtp_layers)]
 
 
 def stack_layer_params(backbone: Dict[str, Any], num_layers: int, prefix: str = "h_") -> Dict[str, Any]:
@@ -3018,6 +3204,7 @@ BUILTIN_SPECS = {
     "falconh1": TransformerConfig.falconh1,
     "pangu": TransformerConfig.pangu,
     "glm": TransformerConfig.glm,
+    "k-exaone": TransformerConfig.exaone,
     "gptj": TransformerConfig.gptj,
     "gptneox": TransformerConfig.gptneox,
     "pythia": TransformerConfig.gptneox,
@@ -3030,7 +3217,9 @@ def config_from_spec(spec: str, **overrides) -> TransformerConfig:
     """Parse a ``builtin:<family>-<size>`` model spec into a config."""
     if spec.startswith("builtin:"):
         spec = spec.split(":", 1)[1]
-    family, _, size = spec.partition("-")
+    # the longest family name the spec starts with (one has a hyphen of its own: k-exaone)
+    family = max((f for f in BUILTIN_SPECS if spec == f or spec.startswith(f + "-")), key=len, default=spec.partition("-")[0])
+    size = spec[len(family) + 1 :]
     if family not in BUILTIN_SPECS:
         raise ValueError(f"Unknown model family '{family}'. Known: {sorted(BUILTIN_SPECS)}")
     return BUILTIN_SPECS[family](size or "test", **overrides)

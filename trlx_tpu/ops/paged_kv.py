@@ -379,7 +379,6 @@ _WHOLE_ROW_PATHS = {
     "slot_refill": "ops/slot_refill.py refills one slot's row at its own depth, a [B] vector of cache indices",
     "engine": "the engine/ block tables map every slot of a row to a block and the allocator frees none before the row ends",
     "prefix_cache": "the engine's prefix cache shares a prompt's blocks from slot 0, which a ring has overwritten",
-    "speculative": "ops/speculative.py verifies and rewinds rows to their own accepted lengths, a [B] vector of cache indices",
 }
 
 
@@ -388,7 +387,8 @@ def refuse_ring_cache(cache: Any, slots: int, path: str) -> None:
     (arrays or shapes) its ``init_cache_fn`` gives for a row of ``slots``: a
     window layer keeps ``min(slots, window)`` slots
     (``models/transformer.py::make_kv_cache``), and where that is fewer than
-    the row's, the cache is a ring only the plain sampler writes. A model of
+    the row's, the cache is a ring only the plain sampler and speculation's
+    verify write (``CausalTransformer._ring_plan``). A model of
     mixed layouts runs through these paths while no layer's cache is shorter
     than the row (each layer's bias carries its own window); past that they
     stop here by name rather than write a ring as if it were the row."""

@@ -1,13 +1,29 @@
 """Speculative decoding for rollout generation (draft-and-verify).
 
 Beyond the reference (whose generation hot loop is plain HF ``generate``,
-SURVEY.md §3.2): a small draft model proposes ``gamma`` tokens
-autoregressively, the target model scores all of them in ONE forward, and a
-rejection-sampling acceptance rule keeps a prefix — provably sampling from
-the target distribution (Leviathan et al. 2023; Chen et al. 2023). Per
-round the target runs one length-``gamma+1`` forward instead of up to
-``gamma+1`` single-token decodes, so rollout wall-clock approaches the
-draft's cost when the draft approximates the target well.
+SURVEY.md §3.2): a drafter proposes ``gamma`` tokens, the target model
+scores all of them in ONE forward, and a rejection-sampling acceptance rule
+keeps a prefix — provably sampling from the target distribution (Leviathan
+et al. 2023; Chen et al. 2023). Per round the target runs one
+length-``gamma+1`` forward instead of up to ``gamma+1`` single-token
+decodes, so rollout wall-clock approaches the draft's cost when the draft
+approximates the target well.
+
+Two drafters, one round (``Drafter``; ``spec_round_step`` is the verify, the
+acceptance rule, the residual and bonus draws and the per-row bookkeeping
+for both):
+
+- ``model_drafter``: a SEPARATE small model (``model.draft_model_path``)
+  with a full cache of its own, ``gamma`` single-token forwards a round;
+- ``module_drafter``: the target's OWN next-token-prediction module
+  (``TransformerConfig.mtp_layers``, ``CausalTransformer.draft``; K-EXAONE
+  publishes one), one proposal a round from the target's last hidden states,
+  the target's embedding and head, and one cache layer of its own. The
+  model's published key decides (``trainer/base.py``), no option does. The
+  target's verify is then ONE forward over two tokens a row at the row's own
+  cache index; a window layer's cache is a ring of ``window + gamma`` slots
+  that takes that span at a per-row index (``CausalTransformer._ring_plan``),
+  so the rows may be longer than the window.
 
 TPU-first structure: the whole sampler is one jitted program — a
 ``lax.while_loop`` over rounds with static shapes throughout. Rows accept
@@ -49,12 +65,12 @@ outputs — sampling is exact w.r.t. the ADJUSTED target distribution, and
 the (unadjusted) draft's mismatch only costs acceptance rate.
 """
 
-from typing import Any, Callable, Optional
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
-from trlx_tpu.ops.paged_kv import refuse_latent_cache, refuse_recurrent_state, refuse_ring_cache
+from trlx_tpu.ops.paged_kv import refuse_latent_cache, refuse_recurrent_state
 from trlx_tpu.ops.sampling import (
     _NON_CARRY_KEYS,
     GenerationConfig,
@@ -155,18 +171,134 @@ def accept_and_extra(
     return k, extra_tok, rng
 
 
+class Drafter(NamedTuple):
+    """What differs between the drafters of a speculative round: everything
+    else (the verify, ``accept_and_extra``, the residual and bonus draws, the
+    per-row bookkeeping, ``min_new_tokens``, the transition mask,
+    ``adjust_logits``) is ``spec_round_step``'s. ``state`` is the drafter's
+    part of the carry (``carry["d_cache"]``).
+
+    - ``prefill(params, target_prefill_out, input_ids, slot_mask, cache)``:
+      the state before the first round;
+    - ``propose(params, state, draw, rng, t_last, c, mask_round)``: ``G``
+      proposals ``[B, G]``, the distributions they were drawn from ``[B, G,
+      V]`` float32, the advanced rng and the state; ``draw(logits, prev, j,
+      rng)`` is the round's own ``(token, probs, rng)``;
+    - ``settle(state, t_out, block_toks, commit_len)``: the state once the
+      round has committed ``commit_len`` of ``block_toks``."""
+
+    prefill: Callable[..., Any]
+    propose: Callable[..., Any]
+    settle: Callable[..., Any]
+
+
+def model_drafter(draft_apply: Callable[..., Any], G: int) -> Drafter:
+    """A SEPARATE small model with a cache of its own: ``G`` single-token
+    forwards a round (unrolled: ``G`` is small and static), rewound by index
+    arithmetic alone."""
+
+    def prefill(params, t_pre, input_ids, slot0, cache):
+        P = input_ids.shape[1]
+        return draft_apply(
+            params, input_ids, attention_mask=slot0, positions=None,
+            cache=cache, cache_index=jnp.asarray(0, jnp.int32), logits_span=(P - 1, P),
+        )["cache"]
+
+    def propose(params, d_cache_r, draw, rng, t_last, c, mask_round):
+        tok_r = t_last
+        d_toks, q_probs = [], []
+        for j in range(G):
+            prev = tok_r  # the token being fed — q_{j+1} conditions on it
+            out_j = draft_apply(
+                params, tok_r[:, None], attention_mask=mask_round,
+                positions=None, cache=d_cache_r, cache_index=c - 1 + j,
+            )
+            tok_r, probs_j, rng = draw(out_j["logits"][:, -1, :], prev, j, rng)
+            d_toks.append(tok_r)
+            q_probs.append(probs_j)
+            d_cache_r = out_j["cache"]
+        # one more draft forward to write d_G's K/V (logits discarded):
+        # after a fully-accepted round the NEXT round marks d_G's slot
+        # committed, and a zero-K/V hole there would quietly degrade every
+        # subsequent proposal — exactly in the high-acceptance regime
+        d_cache_new = draft_apply(
+            params, tok_r[:, None], attention_mask=mask_round,
+            positions=None, cache=d_cache_r, cache_index=c - 1 + G,
+            logits_span=(0, 0),
+        )["cache"]
+        return jnp.stack(d_toks, axis=1), jnp.stack(q_probs, axis=1), rng, d_cache_new
+
+    return Drafter(prefill, propose, lambda state, t_out, block_toks, commit_len: state)
+
+
+def module_drafter(draft_apply: Callable[..., Any]) -> Drafter:
+    """The TARGET's own next-token-prediction module
+    (``CausalTransformer.draft``: ``draft_apply(params, hidden, next_ids,
+    attention_mask=, cache=, cache_index=, logits_span=)``), one proposal a
+    round: no second model and no second full cache, the target's embedding
+    and head, and the target's last hidden states in place of a forward of
+    its own stack.
+
+    The module's entry for slot ``s`` is made from ``(h_s, x_{s+1})``, the
+    target's pre-norm hidden state at ``s`` and the token after it, and gives
+    a distribution over ``x_{s+2}``. With ``t_last = x_{c-1}`` at slot ``c -
+    1`` still to be forwarded, the proposal for slot ``c`` comes from entry
+    ``c - 2``. A round committed one or two tokens, so entry ``c - 3`` may be
+    new as well: the state carries the last TWO pairs ``(h, next token)``, at
+    slots ``c - 3`` and ``c - 2``, the module runs ONE forward over both at
+    the row's own index (writing an entry a second time writes the same
+    numbers), and ``settle`` slides the pairs by the tokens committed, over
+    the two hidden states the verify just made (slots ``c - 1`` and ``c``)."""
+
+    def prefill(params, t_pre, input_ids, slot0, cache):
+        P = input_ids.shape[1]
+        if P < 3:
+            raise ValueError(f"a self-drafting model needs prompts of at least 3 slots (padded), got {P}")
+        hidden = t_pre["pre_norm_hidden"]  # [B, P, E]
+        # entry s from (h_s, x_{s+1}); the last has no next token yet and is written again, with
+        # the first committed token, before any query reads it (slot-causality hides it until then)
+        next_ids = jnp.concatenate([input_ids[:, 1:], input_ids[:, -1:]], axis=1)
+        d_cache = draft_apply(
+            params, hidden, next_ids, attention_mask=slot0, cache=cache,
+            cache_index=jnp.asarray(0, jnp.int32), logits_span=(0, 0),
+        )["cache"]
+        return {"cache": d_cache, "hidden": hidden[:, P - 3 : P - 1], "next": input_ids[:, P - 2 :]}
+
+    def propose(params, state, draw, rng, t_last, c, mask_round):
+        out = draft_apply(
+            params, state["hidden"], state["next"], attention_mask=mask_round,
+            cache=state["cache"], cache_index=c - 3, logits_span=(1, 2),
+        )
+        tok, probs, rng = draw(out["logits"][:, -1, :], t_last, 0, rng)
+        return tok[:, None], probs[:, None, :], rng, {**state, "cache": out["cache"]}
+
+    def settle(state, t_out, block_toks, commit_len):
+        def slide(old, new):  # rows [B, 2 + 2, ...] of slots c - 3 .. c, from the row's commit_len on
+            both = jnp.concatenate([old, new.astype(old.dtype)], axis=1)
+            return jax.vmap(lambda row, at: jax.lax.dynamic_slice_in_dim(row, at, 2, axis=0))(both, commit_len)
+
+        return {
+            "cache": state["cache"],
+            "hidden": slide(state["hidden"], t_out["pre_norm_hidden"]),
+            "next": slide(state["next"], block_toks),
+        }
+
+    return Drafter(prefill, propose, settle)
+
+
 def spec_round_step(
     carry: dict,
     *,
     prompt_mask: jax.Array,  # [B, P] int32
     target_apply: Callable[..., Any],
     target_params: Any,
-    draft_apply: Callable[..., Any],
+    draft_apply: Optional[Callable[..., Any]] = None,
     draft_params: Any,
     config: GenerationConfig,
     G: int,
     transition_mask: Optional[jax.Array] = None,
     adjust_logits: Optional[Callable[[Any, jax.Array], jax.Array]] = None,
+    drafter: Optional[Drafter] = None,  # None: a separate model behind `draft_apply`
 ) -> dict:
     """One draft-propose → verify → accept round over the shared carry.
 
@@ -218,22 +350,14 @@ def spec_round_step(
         [jnp.zeros((B, P), jnp.int32), probe.astype(jnp.int32)], axis=1
     )
 
-    # ---- draft proposes G tokens (G single-token forwards, unrolled:
-    # G is small and static) ----
-    d_cache_r, tok_r = carry["d_cache"], t_last
-    d_toks = jnp.zeros((B, G), jnp.int32)
-    # [B, G, V] full draft dists for the residual resample — f32: the
-    # rejection-sampling identity needs the SAME q as the accept test
-    # (a rounded copy would sample the extra token from rounding noise
-    # when p ≈ q, precisely the good-draft case)
-    q_probs = None
-    for j in range(G):
-        prev = tok_r  # the token being fed — q_{j+1} conditions on it
-        out_j = draft_apply(
-            draft_params, tok_r[:, None], attention_mask=mask_round,
-            positions=None, cache=d_cache_r, cache_index=c - 1 + j,
-        )
-        logits_j = out_j["logits"][:, -1, :].astype(jnp.float32)
+    # ---- the drafter proposes G tokens ----
+    def draw(logits_j, prev, j, rng):
+        """Proposal ``j`` of the round from the drafter's logits: ``(token,
+        the float32 distribution it was drawn from, rng)``. The rejection-
+        sampling identity needs the SAME q as the accept test (a rounded copy
+        would sample the extra token from rounding noise when p ≈ q,
+        precisely the good-draft case)."""
+        logits_j = logits_j.astype(jnp.float32)
         if transition_mask is not None:
             logits_j = apply_transition_mask(transition_mask, prev, logits_j)
         if config.eos_token_id is not None and config.min_new_tokens > 0:
@@ -255,36 +379,31 @@ def spec_round_step(
         if config.do_sample:
             log_probs_j = jnp.log(jnp.maximum(probs_j, 1e-30))
             if per_row:
-                tok_r = jax.vmap(
+                tok = jax.vmap(
                     lambda kk, row: jax.random.categorical(kk, row)
                 )(rj, log_probs_j).astype(jnp.int32)
             else:
-                tok_r = jax.random.categorical(
+                tok = jax.random.categorical(
                     rj, log_probs_j, axis=-1
                 ).astype(jnp.int32)
         else:
-            tok_r = jnp.argmax(probs_j, axis=-1).astype(jnp.int32)
-        if q_probs is None:
-            q_probs = jnp.zeros((B, G) + probs_j.shape[-1:], jnp.float32)
-        d_toks = d_toks.at[:, j].set(tok_r)
-        q_probs = q_probs.at[:, j].set(probs_j)
-        d_cache_r = out_j["cache"]
-    # one more draft forward to write d_G's K/V (logits discarded):
-    # after a fully-accepted round the NEXT round marks d_G's slot
-    # committed, and a zero-K/V hole there would quietly degrade every
-    # subsequent proposal — exactly in the high-acceptance regime
-    d_cache_new = draft_apply(
-        draft_params, tok_r[:, None], attention_mask=mask_round,
-        positions=None, cache=d_cache_r, cache_index=c - 1 + G,
-        logits_span=(0, 0),
-    )["cache"]
+            tok = jnp.argmax(probs_j, axis=-1).astype(jnp.int32)
+        return tok, probs_j, rng
+
+    if drafter is None:
+        drafter = model_drafter(draft_apply, G)
+    with jax.named_scope("trlx/spec_draft"):
+        d_toks, q_probs, rng, d_state = drafter.propose(
+            draft_params, carry["d_cache"], draw, rng, t_last, c, mask_round
+        )
 
     # ---- one target forward verifies everything ----
     verify_in = jnp.concatenate([t_last[:, None], d_toks], axis=1)  # [B, G+1]
-    t_out = target_apply(
-        target_params, verify_in, attention_mask=mask_round,
-        positions=None, cache=carry["t_cache"], cache_index=c - 1,
-    )
+    with jax.named_scope("trlx/spec_verify"):
+        t_out = target_apply(
+            target_params, verify_in, attention_mask=mask_round,
+            positions=None, cache=carry["t_cache"], cache_index=c - 1,
+        )
     t_cache_new = t_out["cache"]
     t_logits = t_out["logits"].astype(jnp.float32)  # [B, G+1, V]
     if adjust_logits is not None:
@@ -388,7 +507,7 @@ def spec_round_step(
         "done": done_new,
         "t_last": t_last_new,
         "t_cache": t_cache_new,
-        "d_cache": d_cache_new,
+        "d_cache": drafter.settle(d_state, t_out, block_toks_w, commit_len),
         "tokens": tokens,
         "logprobs": logprobs,
         "values": values,
@@ -421,6 +540,9 @@ def generate_speculative(
     transition_mask: Optional[jax.Array] = None,  # [Vm, Vm'] bool: the
     # trainer's prev→next logit mask; applied identically to draft AND
     # target so constrained sampling (e.g. randomwalks) stays lossless
+    drafter: Optional[Drafter] = None,  # None: a separate model behind
+    # ``draft_apply`` (``model_drafter``); ``module_drafter(...)``: the
+    # target's own next-token-prediction module, ``gamma`` its one proposal
     adjust_logits: Optional[Callable[[Any, jax.Array], jax.Array]] = None,
     # algorithm logit reshaping (ILQL: log π + β(minQ − V)) applied to the
     # TARGET's verify distributions — step_out carries the target forward's
@@ -463,11 +585,13 @@ def generate_speculative(
     input_ids = input_ids.astype(jnp.int32)
     prompt_mask = attention_mask.astype(jnp.int32)
 
+    if drafter is None:
+        drafter = model_drafter(draft_apply, G)
     t_cache = init_target_cache(B, S)
     d_cache = init_draft_cache(B, S)
     refuse_recurrent_state((t_cache, d_cache), "speculative")
     refuse_latent_cache((t_cache, d_cache), "speculative")
-    refuse_ring_cache((t_cache, d_cache), S, "speculative")
+    # (a window layer's ring takes each row's span at its own index: _ring_plan)
 
     # ---- prefill both caches over the prompt block ----
     slot0 = jnp.concatenate([prompt_mask, jnp.zeros((B, NB - 1), jnp.int32)], axis=1)
@@ -475,10 +599,7 @@ def generate_speculative(
         target_params, input_ids, attention_mask=slot0, positions=None,
         cache=t_cache, cache_index=jnp.asarray(0, jnp.int32), logits_span=(P - 1, P),
     )
-    d_pre = draft_apply(
-        draft_params, input_ids, attention_mask=slot0, positions=None,
-        cache=d_cache, cache_index=jnp.asarray(0, jnp.int32), logits_span=(P - 1, P),
-    )
+    d_state = drafter.prefill(draft_params, t_pre, input_ids, slot0, d_cache)
 
     def round_step(carry):
         # the shared round (also the CB spec segment's body) — one function,
@@ -488,12 +609,12 @@ def generate_speculative(
             prompt_mask=prompt_mask,
             target_apply=target_apply,
             target_params=target_params,
-            draft_apply=draft_apply,
             draft_params=draft_params,
             config=config,
             G=G,
             transition_mask=transition_mask,
             adjust_logits=adjust_logits,
+            drafter=drafter,
         )
 
     def cond(carry):
@@ -505,7 +626,7 @@ def generate_speculative(
         "done": jnp.zeros((B,), bool),
         "t_last": input_ids[:, -1],
         "t_cache": t_pre["cache"],
-        "d_cache": d_pre["cache"],
+        "d_cache": d_state,
         "tokens": jnp.full((B, NB), V_pad, jnp.int32),
         "logprobs": jnp.zeros((B, NB), jnp.float32),
         "values": jnp.zeros((B, NB), jnp.float32),
@@ -531,6 +652,9 @@ def generate_speculative(
         stats = {
             "rounds": final["rounds"],
             "accepted_draft_tokens": final["accepted"],
+            # on live rows only: a row that has ended still runs, and counts nowhere
+            "proposed_draft_tokens": final["live_rounds"] * G,
+            "live_row_rounds": final["live_rounds"],
             # fraction of proposed draft tokens accepted (per live row-round)
             "acceptance_rate": final["accepted"]
             / jnp.maximum(final["live_rounds"] * G, 1),

@@ -390,6 +390,16 @@ class TPUBaseTrainer(BaseRLTrainer):
             )
             self.param_mask = mask_fn(params, self.tcfg, config.model.num_layers_unfrozen)
             self.draft_module = self.draft_params = self.draft_tcfg = None
+            # a model that publishes a next-token-prediction module (tcfg.mtp_layers)
+            # drafts its rollouts with it: the model's own key decides, no option does
+            self.self_drafts = bool(getattr(self.tcfg, "mtp_layers", 0))
+            if self.self_drafts and config.model.draft_model_path:
+                raise ValueError(
+                    f"model.draft_model_path {config.model.draft_model_path!r} beside a model that drafts with its "
+                    f"own next-token-prediction module (mtp_layers {self.tcfg.mtp_layers}, model_type "
+                    f"{self.tcfg.model_type!r}): one drafter a sampler, and the module is the model's own; unset "
+                    "model.draft_model_path"
+                )
             self.last_spec_stats: Dict[str, float] = {}
             self.last_cache_stats: Dict[str, float] = {}
             # the serial dense sampler's static cache extents for the newest
@@ -1021,6 +1031,12 @@ class TPUBaseTrainer(BaseRLTrainer):
     # generation
     # ------------------------------------------------------------------
 
+    @property
+    def draft_gamma(self) -> int:
+        """Tokens proposed a round: one a next-token-prediction module where the
+        model drafts with its own, else ``model.draft_gamma``."""
+        return int(self.tcfg.mtp_layers if self.self_drafts else self.config.model.draft_gamma)
+
     def _apply_fn(self):
         module = self.module
 
@@ -1094,7 +1110,7 @@ class TPUBaseTrainer(BaseRLTrainer):
                         adjust_logits=adjust,
                     )
 
-            elif self.draft_module is not None:
+            elif self.draft_module is not None or self.self_drafts:
                 # speculative decoding: draft proposes, the policy verifies
                 # γ tokens per forward — lossless, so the rollout semantics
                 # (tokens/logprobs/values under the policy) are unchanged.
@@ -1103,17 +1119,30 @@ class TPUBaseTrainer(BaseRLTrainer):
                 # positional eos blocking), and the algo adjust hook (ILQL
                 # reshaping — applied to the target's verify distributions;
                 # a mismatched plain draft only costs acceptance rate).
-                from trlx_tpu.ops.speculative import generate_speculative
+                from trlx_tpu.ops.speculative import generate_speculative, module_drafter
 
                 apply_fn = self._apply_fn()
                 draft_module = self.draft_module
                 draft_params = self.draft_params
                 tcfg, dcfg = self.tcfg, self.draft_tcfg
-                gamma = self.config.model.draft_gamma
+                gamma = self.draft_gamma
                 trans_mask = self._logit_mask_array()
+                drafter = None  # a separate model behind draft_apply
+                target_cache = lambda B, S: make_kv_cache(tcfg, B, S)
+                draft_cache = lambda B, S: make_kv_cache(dcfg, B, S)
 
                 def draft_apply(p, ids, **kw):
                     return draft_module.apply({"params": p}, ids, **kw)
+
+                if self.self_drafts:
+                    # the policy's own module, on the policy's own parameters: one
+                    # cache list, the blocks' layers first and the module's behind them
+                    module, L = self.module, tcfg.num_layers
+                    drafter = module_drafter(
+                        lambda p, hidden, next_ids, **kw: module.apply({"params": p}, hidden, next_ids, method="draft", **kw)
+                    )
+                    target_cache = lambda B, S: make_kv_cache(tcfg, B, S)[:L]
+                    draft_cache = lambda B, S: make_kv_cache(tcfg, B, S)[L:]
 
                 def rollout_generate(params, input_ids, attention_mask, rng):
                     # first arg is the target params, or the engine's
@@ -1122,6 +1151,8 @@ class TPUBaseTrainer(BaseRLTrainer):
                     # abstract-weight lowering (trlx_tpu/perf.py) requires
                     if type(params) is tuple:
                         t_params, d_params = params
+                    elif drafter is not None:
+                        t_params = d_params = params
                     else:
                         t_params, d_params = params, draft_params
                     return generate_speculative(
@@ -1129,8 +1160,8 @@ class TPUBaseTrainer(BaseRLTrainer):
                         t_params,
                         draft_apply,
                         d_params,
-                        lambda B, S: make_kv_cache(tcfg, B, S),
-                        lambda B, S: make_kv_cache(dcfg, B, S),
+                        target_cache,
+                        draft_cache,
                         input_ids,
                         attention_mask,
                         rng,
@@ -1139,6 +1170,7 @@ class TPUBaseTrainer(BaseRLTrainer):
                         return_stats=True,
                         transition_mask=trans_mask,
                         adjust_logits=algo_adjust,
+                        drafter=drafter,
                     )
 
             else:
@@ -1421,13 +1453,15 @@ class TPUBaseTrainer(BaseRLTrainer):
                 # recorded for make_experience's stats (rollout observability:
                 # the knob this informs is model.draft_gamma)
                 # device_get already lands host scalars; no asarray needed
+                spec_stats = jax.device_get(spec_stats)
                 self.last_spec_stats = {
-                    "rollout/spec_acceptance_rate": float(
-                        jax.device_get(spec_stats["acceptance_rate"])
-                    ),
-                    "rollout/spec_rounds": int(
-                        jax.device_get(spec_stats["rounds"])
-                    ),
+                    "rollout/spec_acceptance_rate": float(spec_stats["acceptance_rate"]),
+                    "rollout/spec_rounds": int(spec_stats["rounds"]),
+                    # on live rows: a row that has ended still runs its rounds, and counts in neither
+                    "rollout/draft_proposed": int(spec_stats["proposed_draft_tokens"]),
+                    "rollout/draft_accepted": int(spec_stats["accepted_draft_tokens"]),
+                    "rollout/spec_live_row_rounds": int(spec_stats["live_row_rounds"]),
+                    "rollout/tokens_per_round": float(spec_stats["tokens_per_round"]),
                 }
             sp.fence((out.sequences, out.response_tokens))
         self.last_generate_span = sp
@@ -1479,7 +1513,8 @@ class TPUBaseTrainer(BaseRLTrainer):
 
         B, P = prompt_shape
         S = P + gen_config.max_new_tokens
-        if self.draft_module is None:
+        drafts = self.draft_module is not None or self.self_drafts
+        if not drafts:
             self.last_kv_extents = kv_extents(P, gen_config.max_new_tokens)
 
         def cache(tcfg, slots):
@@ -1506,7 +1541,7 @@ class TPUBaseTrainer(BaseRLTrainer):
         if latent:  # the layers cache a latent in place of K and V, and index keys with it
             self.last_cache_stats["rollout/latent_cache_bytes"] = float(latent)
             self.last_cache_stats["rollout/index_cache_bytes"] = float(index)
-        if getattr(self.tcfg, "index_topk", 0) and self.draft_module is None:
+        if getattr(self.tcfg, "index_topk", 0) and not drafts:
             # rows of the cache a decode step gathers a row of the batch, all layers: static, as the extents are
             self.last_cache_stats["rollout/sparse_gather_rows"] = float(sparse_gather_rows(self.tcfg, S))
         if not self.tcfg.scan_layers:
@@ -1522,13 +1557,17 @@ class TPUBaseTrainer(BaseRLTrainer):
                 )
                 self.last_cache_stats["rollout/kv_cache_window_bytes"] = float(window)
                 self.last_cache_stats["rollout/kv_cache_global_bytes"] = float(total - window)
-        if self.draft_module is not None:
+        if drafts:
             # speculative decoding: target + draft caches, both S + gamma
-            # slots (ops/speculative.py sizes them P + N + G)
-            S_spec = S + int(self.config.model.draft_gamma)
-            total = kv_bytes(cache(self.tcfg, S_spec)) + kv_bytes(
-                cache(self.draft_tcfg, S_spec)
-            )
+            # slots (ops/speculative.py sizes them P + N + G); a model's own
+            # module has its layer in the model's own cache list
+            S_spec = S + self.draft_gamma
+            spec_cache = cache(self.tcfg, S_spec)
+            total = kv_bytes(spec_cache)
+            if self.self_drafts:
+                self.last_cache_stats["rollout/mtp_cache_bytes"] = float(kv_bytes(spec_cache[self.tcfg.num_layers :]))
+            else:
+                total += kv_bytes(cache(self.draft_tcfg, S_spec))
         self.obs.metrics.set_gauge("memory/kv_cache_bytes", float(total))
 
     def generate_eval(self, input_ids, attention_mask=None, **kwargs) -> GenerationOutput:

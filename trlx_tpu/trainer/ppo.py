@@ -534,7 +534,7 @@ class PPOTrainer(TPUBaseTrainer):
                 "time/generate_wait": self.last_generate_span.wait,
             },
         )
-        stats.update(self.last_spec_stats)
+        spec_stats = dict(self.last_spec_stats)
         stats.update(self.last_cache_stats)
 
         # dispatch the scoring forward immediately on the generation's
@@ -557,6 +557,7 @@ class PPOTrainer(TPUBaseTrainer):
             "score_out": score_out,
             "kv_extents": self.last_kv_extents,
             "kv_layers": self.last_kv_layers,
+            "spec_stats": spec_stats,
         }
 
     def _rollout_chunk_host(self, dev: Dict[str, Any]) -> Dict[str, Any]:
@@ -606,6 +607,7 @@ class PPOTrainer(TPUBaseTrainer):
             "host": host,
             "kv_extents": dev.get("kv_extents"),
             "kv_layers": dev.get("kv_layers"),
+            "spec_stats": dev.get("spec_stats"),
             "stats": stats,
             "host_s": perf_counter() - host_t0,
             # what this stage spent inside reward_fn and waiting for the
@@ -694,8 +696,19 @@ class PPOTrainer(TPUBaseTrainer):
             n_per_row = response_mask.sum(axis=1)
             decode_steps = int(n_per_row.max()) if n_per_row.size else 0
             acc["decode_steps"] += decode_steps
-            acc["slot_steps"] += int(response_mask.shape[0]) * decode_steps
-            acc["live_slot_steps"] += int(n_per_row.sum())
+            spec = chunk.get("spec_stats")
+            if spec:
+                # under a drafter a pass of the loop is a ROUND, which commits one to
+                # gamma + 1 tokens a row: row-rounds take the place of slot-steps (the
+                # same numbers where a round is a token), summed over the chunks
+                for key in ("rollout/spec_rounds", "rollout/draft_proposed", "rollout/draft_accepted", "rollout/spec_live_row_rounds"):
+                    acc[key] = acc.get(key, 0) + spec[key]
+                acc["slot_steps"] += int(response_mask.shape[0]) * spec["rollout/spec_rounds"]
+                acc["live_slot_steps"] += spec["rollout/spec_live_row_rounds"]
+                acc["committed_live"] = acc.get("committed_live", 0.0) + spec["rollout/tokens_per_round"] * spec["rollout/spec_live_row_rounds"]
+            else:
+                acc["slot_steps"] += int(response_mask.shape[0]) * decode_steps
+                acc["live_slot_steps"] += int(n_per_row.sum())
             # cache slots a row's attention read over those steps, a layer:
             # the dense sampler's steps stop at a static extent of the cache
             # (ops/sampling.py::kv_extents), and a window layer's cache is a
@@ -705,12 +718,19 @@ class PPOTrainer(TPUBaseTrainer):
             P, N = chunk["prompt_ids"].shape[1], response_mask.shape[1]
             extents = chunk.get("kv_extents") or (P + N,)
             for slots, windowed in chunk.get("kv_layers") or ((P + N, False),):
-                read = kv_slots_read(layer_extents(extents, slots), P, decode_steps, getattr(self.tcfg, "index_topk", 0))
+                if spec:
+                    # a round's verify reads a layer's cache whole, at each row's own depth
+                    # (no static extent holds for all rows): its ring, or every slot of the row
+                    passes = spec["rollout/spec_rounds"]
+                    read = passes * slots
+                else:
+                    passes = decode_steps
+                    read = kv_slots_read(layer_extents(extents, slots), P, decode_steps, getattr(self.tcfg, "index_topk", 0))
                 acc["kv_slots_read"] += read
-                acc["kv_slots"] += decode_steps * (P + N)
+                acc["kv_slots"] += passes * (P + N)
                 if windowed:
                     acc["kv_window_slots_read"] += read
-                    acc["kv_window_slots"] += decode_steps * (P + N)
+                    acc["kv_window_slots"] += passes * (P + N)
 
             prompt_ids, prompt_mask = chunk["prompt_ids"], chunk["prompt_mask"]
             # async chunks ship the sampler's exact behavior logprobs; they ride
@@ -1454,7 +1474,14 @@ class PPOTrainer(TPUBaseTrainer):
                         "rollout/padded_decode_frac",
                         1.0 - acc["live_slot_steps"] / acc["slot_steps"],
                     )
-                # the share of the cache's slots the decode steps' attention read
+                if "rollout/spec_rounds" in acc:  # a drafter's rounds, over the collection's chunks
+                    for key in ("rollout/spec_rounds", "rollout/draft_proposed", "rollout/draft_accepted"):
+                        stats[key] = float(acc[key])
+                    stats["rollout/spec_acceptance_rate"] = acc["rollout/draft_accepted"] / max(acc["rollout/draft_proposed"], 1)
+                    stats["rollout/tokens_per_round"] = acc["committed_live"] / max(acc["rollout/spec_live_row_rounds"], 1)
+                    # the fenced generate time a round, prefill included (time/decode_step beside it: a TOKEN of the longest row)
+                    stats["time/spec_round"] = stats.get("time/generate", 0.0) / max(acc["rollout/spec_rounds"], 1)
+                # the share of the cache's slots the decode steps' (or rounds') attention read
                 stats["rollout/kv_read_frac"] = (
                     acc["kv_slots_read"] / acc["kv_slots"] if acc["kv_slots"] else 1.0
                 )
