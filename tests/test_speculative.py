@@ -989,3 +989,139 @@ def test_self_draft_sampling_is_distribution_exact_under_rings_at_unlike_depths(
         b = np.bincount(np.asarray(out.response_tokens)[:, position] % 8, minlength=8).astype(np.float64)
         chi2 = float(((a - b) ** 2 / np.maximum(a + b, 1.0)).sum())
         assert chi2 < 24.3, (position, chi2, a, b)
+
+
+# ---------------------------------------------------------------------------
+# A round's writes at per-row offsets (no loop over the rows)
+
+
+def _buffers(B, NB, seed=0):
+    rs = np.random.RandomState(seed)
+    return {
+        "tokens": jnp.asarray(rs.randint(0, 250, (B, NB)), jnp.int32),
+        "logprobs": jnp.asarray(-rs.rand(B, NB), jnp.float32),
+        "values": jnp.asarray(rs.randn(B, NB), jnp.float32),
+        "mask": jnp.asarray(rs.randint(0, 2, (B, NB)), jnp.int32),
+    }
+
+
+@pytest.mark.parametrize("name", ["tokens", "logprobs", "values", "mask"])
+@pytest.mark.parametrize("G", [1, 4])
+def test_block_write_is_the_per_row_dynamic_update_slice(G, name):
+    """``write_row_blocks`` (a blend over the buffer) leaves bit for bit what a
+    ``dynamic_update_slice`` a row leaves, on each of a round's four buffers:
+    a row at offset 0, rows at unlike depths, a row whose offset the clamp
+    moved to ``NB - (G + 1)`` and a done row that writes pads over pads. The
+    blocks hold what a round writes, invalid entries included (the pad token,
+    0.0, 0.0, 0 past a row's committed prefix)."""
+    from trlx_tpu.ops.speculative import write_row_blocks
+
+    N, pad = 12, 258
+    NB = N + G + 1
+    n_out = jnp.asarray([0, 3, N, N - 1, 7, N], jnp.int32)  # rows 2 and 5 ended (row 5 by the budget, earlier)
+    B = n_out.shape[0]
+    off = jnp.minimum(n_out, NB - (G + 1))  # spec_round_step's clamp
+    assert int(off[0]) == 0 and int(off[2]) == NB - (G + 1) and len(set(np.asarray(off).tolist())) >= 4
+    rs = np.random.RandomState(G)
+    valid = jnp.asarray(np.arange(G + 1)[None, :] < rs.randint(1, G + 2, (B, 1))) & (n_out < N)[:, None]
+    block = {
+        "tokens": jnp.where(valid, jnp.asarray(rs.randint(0, 250, (B, G + 1)), jnp.int32), pad),
+        "logprobs": jnp.where(valid, jnp.asarray(-rs.rand(B, G + 1), jnp.float32), 0.0),
+        "values": jnp.where(valid, jnp.asarray(rs.randn(B, G + 1), jnp.float32), 0.0),
+        "mask": valid.astype(jnp.int32),
+    }[name]
+    assert not np.asarray(valid)[2].any() and not np.asarray(valid)[5].any()  # the done rows write pads alone
+    buf = _buffers(B, NB)[name]
+    want = jnp.stack([jax.lax.dynamic_update_slice(buf[b], block[b], (off[b],)) for b in range(B)])
+    got = jax.jit(write_row_blocks)(buf, block, off)
+    assert got.dtype == buf.dtype
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert (np.asarray(got) != np.asarray(buf)).any()
+
+
+@pytest.mark.parametrize("leaf", ["k", "v"])
+@pytest.mark.parametrize("T", [1, 2, 5])
+def test_dense_cache_write_is_the_per_row_dynamic_update_slice(T, leaf):
+    """``write_row_spans`` (one scatter of ``(row, slot)`` pairs) leaves bit
+    for bit, in bf16, what a Python loop of ``dynamic_update_slice`` a row
+    leaves in a dense ``[B, S, KV, D]`` cache: rows at unlike indices, one at 0
+    and one at the last legal index ``S - T`` (where a round's last probe
+    lands), from float32 projections cast on the way in as ``Attention``
+    hands them over."""
+    from trlx_tpu.models.transformer import write_row_spans
+
+    B, S, KV, D = 5, 19, 2, 8
+    rs = np.random.RandomState({"k": 3, "v": 4}[leaf] + T)
+    cache = jnp.asarray(rs.randn(B, S, KV, D), jnp.bfloat16)
+    x = jnp.asarray(rs.randn(B, T, KV, D), jnp.float32)
+    ci = jnp.asarray([0, S - T, 7, 3, 11], jnp.int32)
+    want = jnp.stack([
+        jax.lax.dynamic_update_slice(cache[b], x[b].astype(jnp.bfloat16), (ci[b], 0, 0)) for b in range(B)
+    ])
+    got = jax.jit(write_row_spans)(cache, x, ci)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+    assert (np.asarray(got[1, S - T :], np.float32) == np.asarray(x[1].astype(jnp.bfloat16), np.float32)).all()
+
+
+def test_dense_cache_write_drops_a_parked_rows_token():
+    """The slot engine's dense segment parks a row that ended by length at
+    ``cache_index = S`` and still forwards a pad token for it: that write
+    leaves the cache, and is dropped (a clamp would put the pad token's K and V
+    over the row's last real slot). The other rows' writes land."""
+    from trlx_tpu.models.transformer import write_row_spans
+
+    B, S, KV, D = 3, 9, 2, 4
+    cache = jnp.zeros((B, S, KV, D), jnp.bfloat16)
+    got = write_row_spans(cache, jnp.ones((B, 1, KV, D), jnp.float32), jnp.asarray([2, S, S - 1], jnp.int32))
+    assert float(jnp.sum(got[1].astype(jnp.float32))) == 0.0
+    assert float(jnp.sum(got[0].astype(jnp.float32))) == float(jnp.sum(got[2].astype(jnp.float32))) == KV * D
+    assert (np.asarray(got[2, S - 1], np.float32) == 1.0).all()
+
+
+def _scatters(jaxpr):
+    """Every ``scatter*`` equation of a jaxpr, sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name.startswith("scatter"):
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _scatters(sub)
+
+
+def _row_serial(eqn, buffers=()):
+    """Whether the chip would run this scatter one row at a time: batched over
+    the rows (``operand_batching_dims``), or over an output buffer at all."""
+    return bool(eqn.params["dimension_numbers"].operand_batching_dims) or eqn.invars[0].aval.shape in buffers
+
+
+def test_a_round_has_no_scatter_batched_over_the_rows():
+    """No write of a speculative round may come back as a scatter with
+    ``operand_batching_dims``, which is what ``jax.vmap`` makes of a per-row
+    ``dynamic_update_slice``, and none may take a ``[B, NB]`` output buffer as
+    its operand: the chip's compiler runs that form one row at a time, a
+    ``while`` of 64 dynamic-update-slices a write a round, which was 1.40 s of
+    cell 9's 17.5 s cycle in eight such loops (``PERF.md`` section 5, cell 9;
+    section 6, PR 47). The round's body is walked with the model's own
+    sub-jaxprs: the stack's verify, the module's draft, four rings and two
+    dense caches under a ``[B]`` cache index."""
+    model = _self_drafting_model()
+    ids, mask = _long_prompts()
+    N, G = 20, 1
+    cfg = GenerationConfig(max_new_tokens=N, do_sample=True, temperature=1.0, eos_token_id=7, pad_token_id=258)
+    whole = jax.make_jaxpr(partial(_self_spec, model, cfg=cfg))(ids, mask).jaxpr
+    rounds = [e for e in whole.eqns if e.primitive.name == "while"]
+    assert len(rounds) == 1
+    body = rounds[0].params["body_jaxpr"].jaxpr
+    found = list(_scatters(body))
+    # K and V of four rings and two dense caches (the stack's layers and the module's): twelve
+    # scatters over a cache, beside the sparse layers' own over their routing tables
+    shapes = [e.invars[0].aval.shape for e in found]
+    assert sum(len(s) == 4 for s in shapes) == 2 * (model[3].num_layers + 1), shapes
+    B, NB = ids.shape[0], N + G + 1
+    bad = [e for e in found if _row_serial(e, buffers={(B, NB)})]
+    assert not bad, [(str(e.invars[0].aval), e.params["dimension_numbers"]) for e in bad]
+    # and the walk does see the form it guards against
+    old = jax.make_jaxpr(jax.vmap(lambda b, x, o: jax.lax.dynamic_update_slice(b, x, (o,))))(
+        jnp.zeros((B, NB)), jnp.ones((B, G + 1)), jnp.zeros((B,), jnp.int32)
+    ).jaxpr
+    assert [_row_serial(e) for e in _scatters(old)] == [True]

@@ -1359,11 +1359,11 @@ class Attention(nn.Module):
                 k_cache = jax.lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype), (0, ci, 0, 0))
                 v_cache = jax.lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype), (0, ci, 0, 0))
             else:
-                row_write = jax.vmap(
-                    lambda c, x, i: jax.lax.dynamic_update_slice(c, x, (i, 0, 0))
-                )
-                k_cache = row_write(cache["k"], k.astype(cache["k"].dtype), ci)
-                v_cache = row_write(cache["v"], v.astype(cache["v"].dtype), ci)
+                # each row's span of T tokens at its own slot (a speculative round's verify
+                # and drafts, the slot engine's dense segment): one scatter of (row, slot)
+                # pairs in place in the loop's carry, as the ring branch above writes
+                k_cache = write_row_spans(cache["k"], k, ci)
+                v_cache = write_row_spans(cache["v"], v, ci)
             k, v = k_cache, v_cache
             new_cache = {"k": k_cache, "v": v_cache}
 
@@ -3223,3 +3223,25 @@ def config_from_spec(spec: str, **overrides) -> TransformerConfig:
     if family not in BUILTIN_SPECS:
         raise ValueError(f"Unknown model family '{family}'. Known: {sorted(BUILTIN_SPECS)}")
     return BUILTIN_SPECS[family](size or "test", **overrides)
+
+
+def write_row_spans(cache: jax.Array, x: jax.Array, ci: jax.Array) -> jax.Array:
+    """``cache[b, ci[b] : ci[b] + T] = x[b]`` for every row ``b`` of a dense
+    ``cache [B, S, KV, D]``, ``x [B, T, KV, D]`` and ``ci [B]``: ONE scatter of
+    the index pairs ``(row, slot)`` with a window of ``[KV, D]``, as the ring
+    branch of ``Attention`` writes. (A vmapped ``dynamic_update_slice`` is a
+    scatter batched over the rows with a window of a whole row, which the chip
+    runs one row at a time; a blend would read and write the whole cache.) The
+    caller keeps ``0 <= ci`` and ``ci + T <= S``, as
+    ``ops/speculative.py::spec_round_step`` does (its last probe lands on slot
+    ``S - 1``): then nothing is dropped, and nothing was clipped. The slot
+    engine's dense segment parks a row that ended by length at ``ci = S`` with
+    ``T = 1``: that write, of a pad token's K and V, is dropped.
+
+    (At the end of the file so that the lines above keep their numbers: a
+    Pallas kernel's compile-cache key holds its callers' lines.)"""
+    B, T = x.shape[:2]
+    rows, at = jnp.arange(B)[:, None], ci[:, None] + jnp.arange(T)[None, :]
+    return cache.at[rows, at].set(
+        x.astype(cache.dtype), mode="drop", unique_indices=True, indices_are_sorted=True
+    )

@@ -29,7 +29,12 @@ TPU-first structure: the whole sampler is one jitted program — a
 ``lax.while_loop`` over rounds with static shapes throughout. Rows accept
 different prefix lengths, so both KV caches use per-row write indices (the
 ``[B]``-vector ``cache_index`` path of ``models/transformer.py::Attention``)
-and committed-token bookkeeping is per row. Rounds are stateless: each
+and committed-token bookkeeping is per row. A round writes at those per-row
+offsets without a loop over the rows: its block of ``gamma + 1`` entries goes
+into each ``[B, N + gamma + 1]`` output buffer as a blend over the buffer, and
+its K/V go into a dense cache as one scatter of ``(row, slot)`` index pairs,
+because a vmapped ``dynamic_update_slice`` becomes a scatter batched over the
+rows, which the chip runs one row at a time. Rounds are stateless: each
 starts by re-feeding the last committed token (whose K/V the caches lack —
 it was sampled from a residual/bonus distribution, never forwarded), which
 also re-derives both models' next-token distributions, so no logits are
@@ -286,6 +291,19 @@ def module_drafter(draft_apply: Callable[..., Any]) -> Drafter:
     return Drafter(prefill, propose, settle)
 
 
+def write_row_blocks(buf: jax.Array, blk: jax.Array, off: jax.Array) -> jax.Array:
+    """``buf[b, off[b] : off[b] + W] = blk[b]`` for every row ``b`` of ``buf
+    [B, NB]``, ``blk [B, W]`` and ``off [B]`` (``0 <= off <= NB - W``), as a
+    blend over the buffer: column ``n`` of row ``b`` takes entry ``n -
+    off[b]`` of the row's block where there is one. ``W`` selects that fuse
+    into one element-wise pass over the buffer; no gather (a ``take_along_axis``
+    here is one, of single elements) and nothing serial in the rows."""
+    rel = jnp.arange(buf.shape[1])[None, :] - off[:, None]  # [B, NB]
+    for j in range(blk.shape[1]):
+        buf = jnp.where(rel == j, blk[:, j : j + 1].astype(buf.dtype), buf)
+    return buf
+
+
 def spec_round_step(
     carry: dict,
     *,
@@ -476,17 +494,12 @@ def spec_round_step(
     block_mask_w = valid.astype(jnp.int32)
 
     # ---- per-row block write into the output buffers ----
-    def row_write(buf, blk, i):
-        return jax.vmap(
-            lambda b, x, o: jax.lax.dynamic_update_slice(b, x.astype(b.dtype), (o,))
-        )(buf, blk, i)
-
     # never write past the buffer; done rows re-write pads over pads
     off = jnp.minimum(n_out, NB - (G + 1))
-    tokens = row_write(carry["tokens"], block_toks_w, off)
-    logprobs = row_write(carry["logprobs"], block_lp_w, off)
-    values = row_write(carry["values"], block_val_w, off)
-    out_mask = row_write(carry["mask"], block_mask_w, off)
+    tokens = write_row_blocks(carry["tokens"], block_toks_w, off)
+    logprobs = write_row_blocks(carry["logprobs"], block_lp_w, off)
+    values = write_row_blocks(carry["values"], block_val_w, off)
+    out_mask = write_row_blocks(carry["mask"], block_mask_w, off)
 
     n_new = n_out + commit_len
     done_new = done | (n_new >= N)
