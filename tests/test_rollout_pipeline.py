@@ -132,7 +132,12 @@ class TestRolloutPipeline:
 PROMPTS = ["hello world", "the quick brown fox", "lorem ipsum", "foo bar"] * 4
 
 
-def _trainer(method, tmp_path, depth, reward_fn, tag):
+# uneven prompts: sorted into four groups of two their longest rows are 5, 12, 21 and 38 tokens
+MIXED_PROMPTS = ["abc", "hello", "lorem ip", "hello world!", "the quick brown", "the quick brown fox j",
+                 "pack my box with five dozen", "sphinx of black quartz, judge my vow!!"] * 2
+
+
+def _trainer(method, tmp_path, depth, reward_fn, tag, prompts=PROMPTS, batch_size=8, chunk_size=4):
     import trlx_tpu.pipeline.offline_pipeline  # noqa: F401 (registration)
     import trlx_tpu.trainer.grpo  # noqa: F401 (registration)
     import trlx_tpu.trainer.ppo  # noqa: F401 (registration)
@@ -145,7 +150,7 @@ def _trainer(method, tmp_path, depth, reward_fn, tag):
     cfg = default().evolve(
         train=dict(
             seq_length=48,
-            batch_size=8,
+            batch_size=batch_size,
             total_steps=4,
             checkpoint_interval=1000,
             checkpoint_dir=str(tmp_path / f"ckpts_{tag}"),
@@ -156,7 +161,7 @@ def _trainer(method, tmp_path, depth, reward_fn, tag):
         tokenizer=dict(tokenizer_path="builtin:bytes"),
         method=dict(
             num_rollouts=16,
-            chunk_size=4,
+            chunk_size=chunk_size,
             ppo_epochs=1,
             gen_kwargs=dict(max_new_tokens=8, top_k=0, top_p=1.0, do_sample=True),
             **extra,
@@ -166,7 +171,7 @@ def _trainer(method, tmp_path, depth, reward_fn, tag):
         config=cfg, reward_fn=reward_fn, metric_fn=None, stop_sequences=[]
     )
     trainer.add_prompt_pipeline(
-        get_pipeline(cfg.train.pipeline)(PROMPTS, 40, trainer.tokenizer)
+        get_pipeline(cfg.train.pipeline)(prompts, 40, trainer.tokenizer)
     )
     return trainer
 
@@ -240,6 +245,34 @@ class TestPipelinedExperience:
             if e.get("ph") == "M" and e["args"]["name"] == "rollout pipeline worker"
         ]
         assert len(names) == 1 and names[0]["tid"] in overlap_tids
+
+    @pytest.mark.parametrize("method", ["ppo", "grpo"])
+    def test_length_grouped_scoring_is_bit_identical_to_serial(self, tmp_path, monkeypatch, method):
+        """Chunks of uneven prompts are scored in length groups, one dispatch
+        a group (trainer/ppo.py::score_groups): the worker lands them all and
+        the store is the serial path's to the bit on that path too."""
+        from trlx_tpu.pipeline import ppo_pipeline
+
+        monkeypatch.setattr(ppo_pipeline, "LADDER_BASE", 8)  # a query budget of 40: scoring's rungs 8 and 40
+        kw = dict(prompts=MIXED_PROMPTS, batch_size=2, chunk_size=8)
+        serial = _trainer(method, tmp_path, 0, _slow_letter_reward, "serial", **kw)
+        piped = _trainer(method, tmp_path, 2, _slow_letter_reward, "piped", **kw)
+        for _ in range(2):  # the second pass is warm
+            serial.store.clear_history()
+            piped.store.clear_history()
+            serial.make_experience(16)
+            piped.make_experience(16)
+            _assert_stores_identical(serial.store, piped.store)
+        for trainer in (serial, piped):
+            shapes = sorted(trainer._score_fns)
+            # groups of two rows at rung 8 and at their chunk's width (16 to 40: a chunk narrower
+            # than the top rung keeps its own width there); a chunk with no prompt under 9 tokens whole
+            assert {b for b, _, _ in shapes} <= {2, 8} and (2, 8, 8) in shapes, shapes
+            assert {p for _, p, _ in shapes} <= {8, 16, 24, 32, 40}
+            assert trainer.make_experience_stats["collect/score_shapes"] == float(len(shapes))
+            assert 0.0 < trainer.make_experience_stats["collect/score_pad_frac"] < 0.5
+        assert piped.make_experience_stats["throughput/rollout_overlap_frac"] > 0.0
+        assert _pipeline_threads() == []
 
     def test_reward_error_propagates_no_leaked_worker(self, tmp_path):
         calls = {"n": 0}

@@ -44,7 +44,7 @@ from trlx_tpu.models.transformer import CausalTransformer
 from trlx_tpu.ops.sampling import GenerationOutput, kv_slots_read, layer_extents
 from trlx_tpu.parallel import shard_batch
 from trlx_tpu.pipeline import BasePipeline
-from trlx_tpu.pipeline.ppo_pipeline import PPORolloutStorage, length_ladder
+from trlx_tpu.pipeline.ppo_pipeline import PPORolloutStorage, length_ladder, pad_length
 from trlx_tpu.trainer import register_trainer
 from trlx_tpu.observability import tracing
 from trlx_tpu.trainer.base import TPUBaseTrainer, attributed_between
@@ -313,7 +313,7 @@ class PPOTrainer(TPUBaseTrainer):
             start_id = self.tcfg.decoder_start_token_id
 
             def score_fn(params, ref_params, sequences, prompt_mask, response_tokens,
-                         response_mask):
+                         response_mask, take=None):  # never grouped: take stays None
                 # encoder side: the prompt; decoder side: teacher-forced
                 # responses shifted right behind the start token (reference
                 # seq2seq scoring, ``accelerate_ppo_trainer.py:369-398``)
@@ -411,11 +411,11 @@ class PPOTrainer(TPUBaseTrainer):
 
         groups = score_row_groups(B, P + N)
 
-        def score_fn(params, ref_params, *rows):
+        def score_fn(params, ref_params, *rows, take=None):
+            rows = rows if take is None else group_rows(*rows, take)  # one length group of a chunk
             if groups == 1:
                 return score_rows(params, ref_params, *rows)
-            # a chunk too long to score at once: groups of its rows, one after another
-            split = lambda a: a.reshape(groups, B // groups, *a.shape[1:])
+            split = lambda a: a.reshape(groups, B // groups, *a.shape[1:])  # too long to score at once
             out = jax.lax.map(lambda g: score_rows(params, ref_params, *g), tuple(map(split, rows)))
             return jax.tree_util.tree_map(lambda a: a.reshape(B, *a.shape[2:]), out)
 
@@ -456,39 +456,39 @@ class PPOTrainer(TPUBaseTrainer):
         response_tokens,
         response_mask,
         params=None,  # async actors score under their adopted param copy
+        prompt_ids=None,  # the chunk's prompts on the host: with them its rows are scored by length
     ):
         """Dispatch the scoring forward and start its async device→host
         copies — the single home of the dispatch tail (recompile watchdog,
         async copies) shared by the chunked device stage, the continuous-
         batching group flush, and GRPO. ``shard_batch`` is a no-copy
         ``device_put`` for already-placed device arrays, so feeding the
-        generation's outputs straight through costs nothing."""
-        score_fn = self._get_score_fn(shape)
-        batch = shard_batch(
-            {
-                "sequences": sequences,
-                "prompt_mask": prompt_mask,
-                "response_tokens": response_tokens,
-                "response_mask": response_mask,
-            },
-            self.mesh,
-        )
-        score_out = score_fn(
-            self.state.params if params is None else params,
-            self.ref_params,
-            batch["sequences"],
-            batch["prompt_mask"],
-            batch["response_tokens"],
-            batch["response_mask"],
-        )
-        self.obs.recompile.observe("score", score_fn)
-        # start the device→host copies of the scoring outputs without
-        # blocking: by the time the host stage asks for these arrays they
-        # have usually landed
-        for leaf in jax.tree_util.tree_leaves(score_out):
-            if hasattr(leaf, "copy_to_host_async"):
-                leaf.copy_to_host_async()
-        return score_out
+        generation's outputs straight through costs nothing. One dispatch,
+        or one a length group where the chunk's rows need different rungs of
+        the learner's query ladder (:func:`score_groups`, at the end of this file)."""
+        B, P, N = shape
+        groups = None if prompt_ids is None else self._score_groups(prompt_mask)
+        rows = shard_batch({"response_tokens": response_tokens, "response_mask": response_mask}, self.mesh)
+        outs = []
+        for take, width in groups or [(None, P)]:
+            key = (B if take is None else len(take), width, N)
+            whole = {"sequences": sequences, "prompt_mask": prompt_mask}
+            batch = shard_batch(whole if take is None else group_prompts(prompt_ids, prompt_mask, take, width), self.mesh)
+            score_fn = self._get_score_fn(key)
+            score_out = score_fn(
+                self.state.params if params is None else params, self.ref_params,
+                batch["sequences"], batch["prompt_mask"], rows["response_tokens"], rows["response_mask"],
+                take=take,
+            )
+            self.obs.recompile.observe("score", score_fn, planned=key)  # a planned shape's first compile passes
+            # start the device→host copies of the scoring outputs without
+            # blocking: by the time the host stage asks for these arrays they
+            # have usually landed
+            for leaf in jax.tree_util.tree_leaves(score_out):
+                if hasattr(leaf, "copy_to_host_async"):
+                    leaf.copy_to_host_async()
+            outs.append(score_out)
+        return outs[0] if groups is None else {"groups": outs, "takes": [take for take, _ in groups]}
 
     def _fan_out(self, prompt_ids, prompt_mask) -> Tuple[np.ndarray, np.ndarray]:
         """Every prompt row repeated ``_rollout_fanout`` times, group-
@@ -548,7 +548,7 @@ class PPOTrainer(TPUBaseTrainer):
             prompt_mask,
             gen_out.response_tokens,
             gen_out.response_mask,
-            params=params,
+            params=params, prompt_ids=prompt_ids,
         )
         return {
             "prompt_ids": prompt_ids,
@@ -595,7 +595,7 @@ class PPOTrainer(TPUBaseTrainer):
             stats["time/reward"] = reward_sp.duration
             stats["time/exp_score"] = reward_sp.duration
             wait_t0 = perf_counter()
-            host = to_host(dev["score_out"])  # usually landed already (async copy)
+            host = scores_in_chunk_order(to_host(dev["score_out"]))  # usually landed already (async copy)
             score_wait = perf_counter() - wait_t0
         stats["time/score"] = score_sp.duration
         return {
@@ -642,9 +642,9 @@ class PPOTrainer(TPUBaseTrainer):
         with self.obs.span("collect/finalize"):
             _add_times(stats, chunk["stats"])
             acc["host_s"] += chunk["host_s"]
-            response_mask = chunk["response_mask"]
-            response_tokens = chunk["response_tokens"]
+            response_mask, response_tokens = chunk["response_mask"], chunk["response_tokens"]
             host = chunk["host"]
+            self._note_score_slots(chunk, acc)
 
             # Non-finite scores (a flaky reward endpoint, an overflowed RM)
             # are zeroed BEFORE the running moments fold them in —
@@ -911,7 +911,7 @@ class PPOTrainer(TPUBaseTrainer):
             prompt_mask,
             response_tokens,
             response_mask,
-            params=params,
+            params=params, prompt_ids=prompt_ids,
         )
         return {
             "prompt_ids": prompt_ids,
@@ -1435,11 +1435,11 @@ class PPOTrainer(TPUBaseTrainer):
                 # what the host and the runtime did meanwhile, on any thread
                 attributed = attributed_between(mark, self._step_mark)
                 stats.update(attributed)
-                # the fenced generate spans' two halves, summed like every
-                # time/* key (the chunked paths have them; actors and the
-                # slot-refill engine generate off this thread's clock)
+                # the fenced generate spans' two halves, summed like every time/* key (the chunked
+                # paths have them; actors and the slot-refill engine generate off this thread's clock)
                 stats.setdefault("time/generate_dispatch", 0.0)
                 stats.setdefault("time/generate_wait", 0.0)
+                self._score_summary(stats, acc)
                 # the collection's self time: wall time the main thread spent in
                 # none of generate, reward_fn, or the wait for the scoring outputs
                 # (defined on the chunked paths; actors and the slot-refill engine
@@ -1628,11 +1628,7 @@ class PPOTrainer(TPUBaseTrainer):
         the job's own length budget (``trlx.py::train`` truncates the prompts
         to the same one) and is fixed for the run. Both ladders are passed by
         name, so a caller's default for either length cannot replace them."""
-        new = int(self._resolve_gen_config()[0].max_new_tokens)
-        self._step_ladders = (
-            length_ladder(int(self.config.train.seq_length) - new),
-            length_ladder(new),
-        )
+        self._step_ladders = self._length_ladders()
         return self.store.create_loader(
             self.config.train.batch_size,
             shuffle=True,
@@ -1640,6 +1636,14 @@ class PPOTrainer(TPUBaseTrainer):
             query_length=self._step_ladders[0],
             response_length=self._step_ladders[1],
         )
+
+    def _length_ladders(self) -> Tuple[Tuple[int, ...], Tuple[int, ...]]:
+        """The ladders of query and response widths the job's length budget
+        implies: the learner's loader pads a minibatch to their rungs, and
+        the scoring forward cuts a length group of a chunk to a query rung.
+        Known from the configuration alone, so before the first collection."""
+        new = int(self._resolve_gen_config()[0].max_new_tokens)
+        return length_ladder(int(self.config.train.seq_length) - new), length_ladder(new)
 
     def _planned_step_shape(self, batch: Any) -> bool:
         items = batch._asdict() if hasattr(batch, "_asdict") else batch
@@ -1726,3 +1730,118 @@ class PPOTrainer(TPUBaseTrainer):
         self.make_experience(self.config.method.num_rollouts, self.iter_count)
         with self.obs.span("learn/loader", stage="create"):
             self.train_dataloader = self._learner_loader()
+
+    # ------------------------------------------------------------------
+    # which rows share a scoring forward (docs/PERFORMANCE.md)
+    # ------------------------------------------------------------------
+    #
+    # This section stands behind loss_fn, and what it needed above keeps the
+    # line counts there: a Mosaic kernel's compile-cache key holds its callers'
+    # file paths and LINES (score_rows, score_fn and loss_fn are such callers;
+    # PERF.md section 6, PR 47), so moving them would make every cell compile
+    # its scoring program and train step again behind a parent that has them.
+
+    def _score_groups(self, prompt_mask) -> Optional[list]:
+        """:func:`score_groups` of a chunk under this job's minibatch and
+        query ladder; ``None``: the chunk is scored whole (a seq2seq chunk
+        always: its prompts are the encoder's side)."""
+        if self.is_seq2seq:
+            return None
+        mask = np.asarray(prompt_mask)
+        return score_groups(
+            mask.sum(axis=1), mask.shape[1], int(self.config.train.batch_size),
+            score_rungs(self._length_ladders()[0]),
+        )
+
+    def _note_score_slots(self, chunk: Dict[str, Any], acc: Dict[str, float]) -> None:
+        """One chunk's real tokens (its two masks) and the slots its scoring
+        programs were fed, into the collection's sums."""
+        prompt_mask, response_mask = chunk["prompt_mask"], chunk["response_mask"]
+        (B, P), N = prompt_mask.shape, response_mask.shape[1]
+        groups = self._score_groups(prompt_mask)
+        slots = B * (P + N) if groups is None else sum(len(take) * (width + N) for take, width in groups)
+        acc["score_slots"] = acc.get("score_slots", 0) + slots
+        acc["score_tokens"] = acc.get("score_tokens", 0) + int(prompt_mask.sum()) + int(response_mask.sum())
+
+    def _score_summary(self, stats: Dict[str, float], acc: Dict[str, float]) -> None:
+        """The collection record's two keys on the scoring forward's shapes:
+        the share of padding in what it was fed, and how many distinct
+        ``(rows, prompt width, new tokens)`` it has a program for (each built
+        at its first dispatch; the twin of ``learn/step_shapes``)."""
+        if acc.get("score_slots"):
+            stats["collect/score_pad_frac"] = 1.0 - acc["score_tokens"] / acc["score_slots"]
+        stats["collect/score_shapes"] = float(len(self._score_fns))
+
+
+def score_rungs(ladder: Tuple[int, ...]) -> Tuple[int, ...]:
+    """The rungs of the learner's query ladder the scoring forward cuts its
+    length groups to: the first and the last. A scoring program a rung is
+    set-up (trace, lower, cache load: 1.0 + 0.8 + 0.3 s each on the chip's
+    host), and ``setup_s`` is an end-to-end metric. Measured on a v5e, warm
+    starts, parent and change alternating, six pairs (PERF.md section 6, PR
+    48): with all three rungs of the hh cells (256, 512, 896; 19,456 slots a
+    chunk where the whole chunk is 32,768) the median ``setup_s`` of
+    ``gptj6b_ppo_hh`` went 47.17 -> 52.54 s, the first cycle's part of it
+    26.0 -> 29.2 s: over the 5% ISSUE 48 allowed. The first and last rungs
+    (22,528 slots) keep three quarters of the slots saved for one more
+    program in place of two. A constant with its measurement, not a setting."""
+    return tuple(ladder) if len(ladder) < 3 else (ladder[0], ladder[-1])
+
+
+def score_groups(lengths, width: int, rows: int, ladder) -> Optional[list]:
+    """Which rows of a chunk share a scoring forward, and at which prompt width.
+
+    ``lengths`` are the prompts' real tokens a row, ``width`` the slots the
+    chunk's prompts are left-padded to. The rows are stable-sorted by length
+    and cut into groups of ``rows`` (the learner's minibatch, so a group runs
+    at a ``(rows, width)`` the train step runs at too); a group's width is the
+    smallest rung of ``ladder`` (:func:`score_rungs` of the learner's query
+    ladder, ``pipeline/ppo_pipeline.py::length_ladder``) that holds its longest prompt,
+    and never more than ``width``: left padding is cut off, none is added.
+    Returns ``[(row indices, width), ...]``, or ``None`` where nothing is to be
+    gained: every group needs the chunk's own width, or the chunk is no whole
+    number of groups. The chunk is then scored whole, in the one program it
+    always had. Chunks of one width (a rung) have at most one program a rung.
+
+    Rows do not interact inside the scoring forward (positions come from the
+    mask's cumulative sum, so left padding cut off moves nothing), with one
+    exception this adds no new case of: an expert layer with a capacity limit
+    drops by what else is in its batch, so a row's score there depends on its
+    group, as it already depends on its chunk and on ``score_row_groups``'
+    pieces. Every configured cell is dropless."""
+    lengths = np.asarray(lengths)
+    if rows < 1 or len(lengths) == 0 or len(lengths) % rows:
+        return None
+    order = np.argsort(lengths, kind="stable").astype(np.int32).reshape(-1, rows)
+    # a group's last row is its longest
+    rungs = [pad_length([range(int(lengths[take[-1]]))], tuple(ladder)) for take in order]
+    widths = [min(rung or width, width) for rung in rungs]
+    if all(w == width for w in widths):
+        return None
+    return list(zip(order, widths))
+
+
+def group_prompts(prompt_ids, prompt_mask, take, width: int) -> Dict[str, np.ndarray]:
+    """On the host: the prompts of the rows ``take``, the left padding beyond
+    ``width`` slots cut off, under the names of the scoring program's first
+    two arguments (:func:`group_rows` appends the responses to the first)."""
+    cut = np.asarray(prompt_mask).shape[1] - width
+    return {"sequences": np.asarray(prompt_ids)[take, cut:], "prompt_mask": np.asarray(prompt_mask)[take, cut:]}
+
+
+def group_rows(prompt_ids, prompt_mask, response_tokens, response_mask, take):
+    """Inside the scoring program of one length group: the group's prompts
+    (cut to the group's width on the host) beside the chunk's responses give
+    ``score_rows``' four arrays for the rows ``take``."""
+    response_tokens, response_mask = response_tokens[take], response_mask[take]
+    sequences = jnp.concatenate([prompt_ids, response_tokens], axis=1)
+    return sequences, prompt_mask, response_tokens, response_mask
+
+
+def scores_in_chunk_order(host: Dict[str, Any]) -> Dict[str, np.ndarray]:
+    """A chunk's landed scoring outputs, ``[B, N]`` each in the chunk's own
+    row order, whether one dispatch made them or one a length group."""
+    if "groups" not in host:
+        return host
+    back = np.argsort(np.concatenate(host["takes"]))
+    return {k: np.concatenate([g[k] for g in host["groups"]])[back] for k in host["groups"][0]}
