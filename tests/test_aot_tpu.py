@@ -272,6 +272,26 @@ def test_selected_flash_kernels_compile_and_keep_the_names(topo):
     assert "s8[1,8192,8192]" in text.replace(" ", "")  # the selection reaches the kernels a byte a pair
 
 
+def test_block_selected_flash_kernels_compile_and_keep_the_names(topo):
+    """Under a selection by blocks of keys (``selection_block=64``, one set a
+    KV head under GQA 32 : 2) at the MiniCPM-SALA cell's shape, one row of
+    16384 slots and heads of 128: the selection reaches the kernels as one
+    bf16 a (query, block), 256 lanes a query, and a tile's mask is widened
+    from it on the MXU; Mosaic takes both kernels under the usual names."""
+    from trlx_tpu.ops import flash_attention as fa
+
+    def loss(q, k, v, m, sel):
+        with jax.named_scope("attn"):
+            return fa.flash_attention(q, k, v, m, selection=sel, selection_block=64, interpret=False).astype(jnp.float32).sum()
+
+    q, kv = _s((1, 16384, 32, 128)), _s((1, 16384, 2, 128))
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)),
+                    (q, kv, kv, _s((1, 16384), jnp.float32), _s((1, 2, 16384, 256), jnp.bool_)),
+                    SingleDeviceSharding(topo.devices[0]))
+    _assert_the_benchmark_finds_both_kernels(text)
+    assert "bf16[1,2,16384,256]" in text.replace(" ", "")  # 1/64 of a byte a pair... two bytes a block
+
+
 def _assert_the_benchmark_finds_both_kernels(text):
     """Two Mosaic calls, and each of ``flash_fwd_device_ms`` /
     ``flash_bwd_device_ms``'s patterns matches exactly its own."""

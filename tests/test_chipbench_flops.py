@@ -14,3 +14,22 @@ def _a_cells_mesh_is_its_one_chip(monkeypatch):
     from trlx_tpu.parallel import make_mesh
 
     monkeypatch.setattr(base, "make_mesh", lambda parallel: make_mesh(parallel, devices=jax.devices()[:1]))
+
+
+# ``python3 -m pytest chipbench/tests`` FAILS one case on this tree, and tier 1 shows it as an
+# expected failure, not as a case left out: the benchmark's own check of the forward count adds
+# ``flops.attention_mix`` (the score square of ``num_attention_heads`` heads) for EVERY layer of the
+# reference, and six of minicpm-sala-9b-l8's eight layers hold a recurrence and no scores. The file
+# is a ``benchmark`` PR's to edit (PERF.md section 7 has the edit and the failing numbers); the
+# configuration is held to its reference by ``tests/test_minicpm_sala.py`` meanwhile. ``strict``:
+# the day the benchmark's test asks the family's costs for a layer's mix, this case passes and
+# tier 1 says so.
+_benchmarks_own = test_forward_count_is_the_references_matmuls  # noqa: F405
+EVERY_LAYER_CHARGED_A_SQUARE = {"minicpm-sala-9b-l8": "chipbench/tests/test_flops.py charges each layer an attention square"}
+
+
+@pytest.mark.parametrize("config_name", [
+    pytest.param(c, marks=pytest.mark.xfail(strict=True, reason=EVERY_LAYER_CHARGED_A_SQUARE[c]))
+    if c in EVERY_LAYER_CHARGED_A_SQUARE else c for c in CONFIGS])  # noqa: F405
+def test_forward_count_is_the_references_matmuls(config_name):  # noqa: F811
+    _benchmarks_own(config_name)

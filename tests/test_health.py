@@ -444,6 +444,44 @@ def test_health_trip_fault_dumps_flightrec_and_triage(tmp_path):
     assert "health/triage_dumps" in keys
 
 
+def test_a_trip_after_the_first_step_compiles_nothing(tmp_path):
+    """A PPO run builds its triage programs at the first optimizer step
+    (``PPOTrainer._warm_triage``): the dump of a later trip, on a batch of
+    the same shape, finds them and compiles nothing, however few steps a
+    collection feeds (the health window is the default's, not the cycle's)."""
+    import jax.monitoring
+
+    import trlx_tpu.trlx as trlx
+    from trlx_tpu.observability.health import DEFAULT_WINDOW
+
+    compiles, during = [], []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, duration, **kw: compiles.append(event)
+        if event == "/jax/core/compile/backend_compile_duration" else None
+    )
+
+    def hook(trainer):
+        dump = trainer._dump_triage
+
+        def counted(reason, stats):
+            before = len(compiles)
+            path = dump(reason, stats)
+            during.append((path, len(compiles) - before))
+            return path
+
+        trainer._dump_triage = counted
+
+    config = _health_ppo_config(tmp_path).evolve(resilience=dict(fault_plan="health_trip@step:1"))
+    trainer = trlx.train(reward_fn=lambda samples, prompts, outputs, **kw: [float(len(o)) for o in outputs],
+                         prompts=["ab", "cd", "ef", "gh", "ij", "kl", "mn", "op"], config=config,
+                         init_trainer_hook=hook)
+    assert trainer.obs.health.window == DEFAULT_WINDOW
+    (path, compiled), = during
+    arrays, _ = _load_triage(path)
+    assert {"advantages", "returns", "logprob_deltas"} <= set(arrays)
+    assert compiled == 0
+
+
 def test_update_guard_rejection_triages_batch(tmp_path):
     """A guard-rejected (injected NaN) update triages the offending batch
     through the same path — the RESILIENCE.md update-guard seam feeds the

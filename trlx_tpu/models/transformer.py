@@ -20,6 +20,7 @@ TPU-first design decisions:
 
 import dataclasses
 import functools
+import math
 from functools import partial
 from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
@@ -149,8 +150,10 @@ def _flash_attention(q, k, v, flash_args: Dict[str, Any]) -> jax.Array:
     }
     operands["q_offset"] = jnp.asarray(flash_args.get("q_offset", 0), jnp.int32)
 
+    selection_block = flash_args.get("selection_block", 1)  # static, as the window
+
     def call(q, k, v, kw):
-        return flash_attention(q, k, v, causal=True, window=window, **kw)
+        return flash_attention(q, k, v, causal=True, window=window, selection_block=selection_block, **kw)
 
     mesh = _traced_global_mesh()
     if mesh is None or mesh.devices.size == 1:
@@ -169,7 +172,7 @@ def _flash_attention(q, k, v, flash_args: Dict[str, Any]) -> jax.Array:
         "k_positions": rows,
         "alibi_slopes": P(head_axis),
         "q_offset": P(),
-        "selection": P(batch_axes, None, None),
+        "selection": P(batch_axes, None, None) if selection_block == 1 else P(batch_axes, head_axis, None, None),
     }
     return jax.shard_map(
         call,
@@ -191,6 +194,14 @@ QK_INIT_STD_SMALLTHINKER = 0.04
 # (`_qk_norm`): 2 on q and on k, a score of standard deviation 4
 # (chipbench/configs/k-exaone-236b-a23b-l5e8.json, `assumed`)
 QK_INIT_STD_EXAONE = 0.04
+# the like for MiniCPM-SALA, per-head norms again: 2 on q and on k of both kinds of
+# layer (chipbench/configs/minicpm-sala-9b-l8.json, `assumed`)
+QK_INIT_STD_MINICPM_SALA = 0.04
+# ... and of its token embedding: 1 / scale_emb, so that the residual stream starts at unit size as a
+# muP-trained embedding under `scale_emb` 12 does. At the 1.0 of the other stand-ins the stream is 12
+# and a layer's two branches (x 0.2475) are a sixtieth of it: the first chip run read a float32
+# reference 0.0068 away and no fault of a mixer could have read more than twice that
+EMBED_INIT_STD_MINICPM_SALA = 1.0 / 12.0
 
 
 class LayerLayout(NamedTuple):
@@ -205,6 +216,14 @@ class LayerLayout(NamedTuple):
     # indexer of its own and selects; "shared" = it attends over the set the
     # last "full" layer before it chose; None = no selection
     indexer: Optional[str] = None
+    # the layer's sequence mixer (`mixer_layout`): "attention" (`Attention`, or
+    # `LatentAttention` under `kv_lora_rank`; where `mixer` is "mamba2", with
+    # Mamba-2 heads BESIDE it in the block) | "lightning" (`LightningMixer`: a
+    # linear recurrence whose state is the layer's whole cache, no K or V)
+    mixer: str = "attention"
+
+
+SPARSE_INIT_BLOCKS = 1  # leading blocks every query of a block selection keeps (MiniCPM4's init_blocks)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -426,6 +445,34 @@ class TransformerConfig:
     ssm_in_multiplier: float = 1.0
     ssm_out_multiplier: float = 1.0
     ssm_multipliers: Tuple[float, ...] = (1.0,) * 5  # in_proj's z, x, B, C, dt segments
+    # both sublayers' outputs times this before the residual add (minicpm_sala:
+    # `scale_depth / sqrt(num_hidden_layers)` of the PUBLISHED depth)
+    residual_multiplier: float = 1.0
+
+    # each layer's sequence mixer (minicpm_sala's `mixer_types`): one entry a
+    # layer, "attention" | "lightning" (`LayerLayout.mixer`); None = attention
+    # on every layer. Entries past `num_layers` are not read. A lightning
+    # layer runs `lightning_heads` heads of `lightning_head_dim` (q, k and v
+    # alike) through `LightningMixer`
+    mixer_layout: Optional[Tuple[str, ...]] = None
+    lightning_heads: int = 0
+    lightning_head_dim: int = 0
+    lightning_chunk: int = 128  # chunk of the scan (ops/ssd.py)
+    # a sigmoid gate of a full `hidden x heads*head_dim` projection (`z_proj`)
+    # on the attention layers' output, before `o_proj`
+    attn_output_gate: bool = False
+    # block-sparse attention over a plain GQA cache (minicpm_sala's `minicpm4`
+    # layers, InfLLM-V2; `sparse_topk` > 0, on every `attention` layer): a
+    # query attends over `sparse_topk` blocks of `sparse_block` keys, chosen
+    # for all the query heads of a KV group from the keys' running mean-pool
+    # (`select_blocks`); a row of under `sparse_dense_len` slots attends
+    # densely. Positions count from a row's first real token
+    sparse_topk: int = 0
+    sparse_block: int = 64
+    sparse_kernel: int = 32  # keys a compressed key averages
+    sparse_stride: int = 16  # ... and the step between two of them
+    sparse_window: int = 2048  # a query keeps every block with a key this close behind it, and block 0
+    sparse_dense_len: int = 8192
 
     def resolved_attention_impl(self) -> str:
         if self.attention_impl == "auto":
@@ -440,6 +487,25 @@ class TransformerConfig:
                 if len(value) < self.num_layers:
                     raise ValueError(f"{name} has {len(value)} entries for {self.num_layers} layers")
                 object.__setattr__(self, name, value)
+        if self.mixer_layout is not None:
+            kinds = tuple(str(m) for m in self.mixer_layout)
+            if len(kinds) < self.num_layers or set(kinds[: self.num_layers]) - {"attention", "lightning"}:
+                raise ValueError(f"mixer_layout needs one of attention | lightning for each of {self.num_layers} layers: {kinds}")
+            if self.mixer != "none" or self.latent_attention or self.mtp_layers or self.lightning_heads < 1 or self.lightning_head_dim < 2:
+                raise ValueError(
+                    "mixer_layout (lightning layers among attention layers) takes K/V attention layers, no second mixer "
+                    "beside them, no next-token-prediction module, and lightning_heads heads of lightning_head_dim"
+                )
+            object.__setattr__(self, "mixer_layout", kinds)
+        if self.sparse_topk:
+            b, k, s = self.sparse_block, self.sparse_kernel, self.sparse_stride
+            if (self.latent_attention or self.sliding_window or self.position_scheme == "alibi" or min(b, k, s) < 1 or b % s or k % s
+                    or self.sparse_topk <= SPARSE_INIT_BLOCKS + -(-self.sparse_window // b) + 1):
+                raise ValueError(
+                    "a block selection (sparse_topk > 0) runs over a plain K/V cache, no sliding window, no ALiBi, "
+                    "blocks and kernels of whole strides, and more blocks than the forced ones "
+                    f"(init {SPARSE_INIT_BLOCKS} + those of the window {self.sparse_window} over {b})"
+                )
         if self.sandwich_norm and (self.parallel_residual or self.mixer != "none"):
             raise ValueError("sandwich_norm is built for the sequential residual path only")
         if self.kv_lora_rank and (self.qk_norm or self.position_scheme != "rotary" or self.mixer != "none"):
@@ -490,6 +556,7 @@ class TransformerConfig:
             rotary=self.position_scheme == "rotary" and roped,
             ffn="moe" if self.num_experts > 0 and layer >= self.first_k_dense else "dense",
             indexer=(self.indexer_types[layer] if self.indexer_types else "full") if self.index_topk else None,
+            **({"mixer": self.mixer_layout[layer]} if self.mixer_layout else {}),
         )
 
     @property
@@ -830,6 +897,63 @@ class TransformerConfig:
             mtp_layers=1,
             embed_init_std=1.0,
             qk_init_std=QK_INIT_STD_EXAONE,
+        )
+
+    @staticmethod
+    def minicpm_sala(size: str = "9b", **overrides) -> "TransformerConfig":
+        """MiniCPM-SALA 9B (``model_type`` ``minicpm_sala``): 24 of 32 layers
+        are lightning linear attention (``LightningMixer``: 32 heads of 128,
+        per-head QK-norm, rotary, a fixed decay a head, an RMSNorm over the
+        joined heads and a sigmoid gate; the layer's whole cache is its
+        float32 state), 8 (``mixer_types`` ``minicpm4``) are GQA 32/2
+        attention without rotary, with a per-head QK-norm and an output gate,
+        under MiniCPM4's block selection (InfLLM-V2: 64 blocks of 64 keys a
+        query, chosen a KV group from mean-pooled keys; ``select_blocks``);
+        SwiGLU; muP scalings (embedding x ``scale_emb``, both residual
+        branches x ``scale_depth / sqrt(32)``, logits over ``hidden /
+        dim_model_base``). ``mixer_layout`` says each layer's kind and the
+        rotary follows it (lightning: yes, attention: no), so a cut of the
+        depth overrides ``mixer_layout`` alone. Limits: the plain sampler,
+        the scoring forward, the hydra branch and the train step
+        (``ops/paged_kv.py::refuse_recurrent_state``); no ``scan_layers``, no
+        ring attention over ``sequence``, no HF checkpoint import.
+        ``qk_init_std`` is the stand-in scale of the scores, in the per-head
+        norms' scales, ``embed_init_std`` that of the token embedding, ``1 /
+        scale_emb`` (chipbench/configs/minicpm-sala-9b-l8.json, `assumed`).
+        ``builtin:minicpm-sala-9b`` | ``builtin:minicpm-sala-test``."""
+        a, l = "attention", "lightning"
+        dims = {
+            # the benchmark's cut in small: a sparse layer, lightning layers, a sparse layer last; blocks of 8 keys,
+            # kernels of 4 every 2, 5 blocks a query of which up to 4 are forced, dense under 32 slots; every
+            # scaling other than 1
+            "test": dict(vocab_size=259, hidden_size=64, num_layers=4, num_heads=4, num_kv_heads=2, head_dim=16, intermediate_size=128, max_position_embeddings=256,
+                         lightning_heads=4, lightning_head_dim=16, lightning_chunk=8, mixer_layout=(a, l, l, a),
+                         sparse_topk=5, sparse_block=8, sparse_kernel=4, sparse_stride=2, sparse_window=12, sparse_dense_len=32,
+                         embedding_multiplier=2.0, lm_head_multiplier=0.5, residual_multiplier=0.7),
+            "9b": dict(vocab_size=73448, hidden_size=4096, num_layers=32, num_heads=32, num_kv_heads=2, head_dim=128, intermediate_size=16384, max_position_embeddings=524288,
+                       lightning_heads=32, lightning_head_dim=128, lightning_chunk=128,
+                       mixer_layout=(a,) + (l,) * 8 + (a,) + (l,) * 6 + (a, a) + (l,) * 4 + (a,) + (l,) * 6 + (a, a, a),
+                       sparse_topk=64, sparse_block=64, sparse_kernel=32, sparse_stride=16, sparse_window=2048, sparse_dense_len=8192,
+                       embedding_multiplier=12.0, lm_head_multiplier=256.0 / 4096.0, residual_multiplier=1.4 / math.sqrt(32)),
+        }[size]
+        kinds = overrides.get("mixer_layout", dims["mixer_layout"])
+        overrides.setdefault("rope_layout", tuple(int(kind == l) for kind in kinds))
+        return _make_preset(
+            dims,
+            overrides,
+            model_type="minicpm_sala",
+            position_scheme="rotary",
+            rope_theta=10000.0,
+            norm="rmsnorm",
+            layer_norm_epsilon=1e-6,
+            activation="silu",
+            attn_bias=False,
+            mlp_bias=False,
+            tie_word_embeddings=False,
+            qk_norm="head",
+            attn_output_gate=True,
+            embed_init_std=EMBED_INIT_STD_MINICPM_SALA,
+            qk_init_std=QK_INIT_STD_MINICPM_SALA,
         )
 
     @staticmethod
@@ -1205,7 +1329,22 @@ def absorbed_latent_attention(q_c, q_r, ckv, k_rope, attention_bias, cache_index
 
 class Attention(nn.Module):
     """Multi-head / grouped-query attention with RoPE/ALiBi and an explicit
-    KV cache ({"k","v"} arrays [B, S, kvH, D] written at ``cache_index``)."""
+    KV cache ({"k","v"} arrays [B, S, kvH, D] written at ``cache_index``).
+
+    **Under a block selection** (``cfg.sparse_topk`` > 0, on a row of
+    ``sparse_dense_len`` slots or more: the cache's slots, or a pass's
+    tokens) a query's softmax runs over the keys of ``sparse_topk`` blocks of
+    ``sparse_block`` keys, one choice for the query heads of each KV head
+    (``select_blocks``), made from the keys' mean-pooled ``kbar``, a third
+    cache leaf ``[B, KV, S / stride, D]`` that fills as kernels complete.
+    Kernels and blocks are laid from each row's FIRST REAL TOKEN: a long pass
+    (and the sampler's prefill, which must start at slot 0 and attends over
+    its own keys) rolls its rows there and back (``roll_rows``) and runs the
+    flash kernels under one more mask a tile, 64 keys to a bit
+    (``block_sparse_attention``); a single-token step scores the kernels of
+    the cache, completes at most one more from the last ``sparse_kernel``
+    keys, and masks the dense read of the cache to the chosen blocks'
+    slots. What is computed is exactly the chosen set; nothing is skipped."""
 
     config: TransformerConfig
     rotary: Optional[bool] = None  # the layer's layout says; None = position_scheme does
@@ -1229,6 +1368,10 @@ class Attention(nn.Module):
         q = _dense(cfg, H * D, qkv_bias, ("embed", "joined_kv"), "q_proj", cfg.qk_init_std)(x)
         k = _dense(cfg, KV * D, qkv_bias, ("embed", "joined_kv"), "k_proj", cfg.qk_init_std)(x)
         v = _dense(cfg, KV * D, qkv_bias, ("embed", "joined_kv"), "v_proj")(x).reshape(B, T, KV, D)
+        gated = lambda out: out  # noqa: E731
+        if cfg.attn_output_gate:
+            gate = jax.nn.sigmoid(_dense(cfg, H * D, False, ("embed", "joined_kv"), "z_proj")(x))
+            gated = lambda out: out * gate  # noqa: E731
         if cfg.key_multiplier != 1.0:
             k = k * cfg.key_multiplier
         if cfg.qk_norm is True:
@@ -1247,6 +1390,8 @@ class Attention(nn.Module):
             k = apply_rotary(k, sin, cos, rdim, neox)
 
         paged = cache is not None and isinstance(cache, dict) and "block_table" in cache
+        if paged and cfg.sparse_topk:
+            raise NotImplementedError("the paged Engine holds K and V blocks and no compressed keys (ops/paged_kv.py::refuse_recurrent_state)")
         if paged:
             # in-place paged attention (ops/paged_attention.py single-token
             # decode; ops/paged_prefill.py chunked prefill): K/V live in
@@ -1325,7 +1470,7 @@ class Attention(nn.Module):
                     out = paged_prefill_attention(
                         q, k_pool, v_pool, table, attention_bias
                     ).reshape(B, T, H * D)
-            out = _dense(cfg, cfg.hidden_size, cfg.attn_bias, ("joined_kv", "embed"), "o_proj")(out)
+            out = _dense(cfg, cfg.hidden_size, cfg.attn_bias, ("joined_kv", "embed"), "o_proj")(gated(out))
             return out, new_cache
 
         new_cache = None
@@ -1364,13 +1509,31 @@ class Attention(nn.Module):
                 # pairs in place in the loop's carry, as the ring branch above writes
                 k_cache = write_row_spans(cache["k"], k, ci)
                 v_cache = write_row_spans(cache["v"], v, ci)
-            k, v = k_cache, v_cache
-            new_cache = {"k": k_cache, "v": v_cache}
+            new_cache = {"k": k_cache, "v": v_cache}  # (and `kbar` under a block selection: below)
+            if not (cfg.sparse_topk and T > 1):  # a span under the block selection attends over its own keys
+                k, v = k_cache, v_cache
 
         ring_mesh = None
         if flash_args is not None and cache is None:
             ring_mesh = _maybe_ring_mesh(T)
-        if ring_mesh is not None:
+        sparse_pass = False
+        if cfg.sparse_topk:
+            if ring_mesh is not None or (cache is not None and ci.ndim):
+                raise NotImplementedError(
+                    "a block selection (sparse_topk) runs whole rows or the plain sampler's steps at one scalar cache_index: "
+                    "no ring attention over `sequence`, no per-row cache_index (ROADMAP.md queue 2, B8)"
+                )
+            # the selection binds on a row of `sparse_dense_len` slots or more: the cache's, or a pass's tokens
+            selects = (T if cache is None else cache["k"].shape[1]) >= cfg.sparse_dense_len
+            sparse_pass = T > 1 and (selects or cache is not None)
+            if T == 1 and cache is not None:
+                attention_bias, new_cache["kbar"] = self._sparse_step_bias(q, k, cache["kbar"], attention_bias, positions, ci, selects)
+        if sparse_pass:
+            out, kbar = self._sparse_pass(q, k, v, attention_bias, flash_args, selects)
+            if cache is not None:
+                new_cache["kbar"] = jax.lax.dynamic_update_slice(cache["kbar"], kbar[:, :, : cache["kbar"].shape[2]], (0, 0, 0, 0))
+            out = out.reshape(B, T, H * D)
+        elif ring_mesh is not None:
             # sequence-parallel exact attention: K/V chunks rotate around the
             # mesh's ``sequence`` ring with zigzag causal placement (context
             # parallelism; beyond the reference, which caps seq_length
@@ -1392,8 +1555,75 @@ class Attention(nn.Module):
             out = extent_attention(q, k, v, attention_bias, ci, kv_extents.slots, cfg.dtype).reshape(B, T, H * D)
         else:
             out = grouped_einsum_attention(q, k, v, attention_bias, cfg.dtype).reshape(B, T, H * D)
-        out = _dense(cfg, cfg.hidden_size, cfg.attn_bias, ("joined_kv", "embed"), "o_proj")(out)
+        out = _dense(cfg, cfg.hidden_size, cfg.attn_bias, ("joined_kv", "embed"), "o_proj")(gated(out))
         return out, new_cache
+
+    def _sparse_pass(self, q, k, v, attention_bias, flash_args, selects):
+        """Whole rows (or the sampler's prefill from slot 0) of a layer under
+        the block selection: ``(out [B, T, H, D], kbar [B, KV, T / stride,
+        D])``. Rows are rolled to their first real token, where kernels and
+        blocks start, and the result back; a row of under ``sparse_dense_len``
+        slots (``selects`` False) attends densely and still leaves its ``kbar``."""
+        cfg = self.config
+        B, T = q.shape[:2]
+        if flash_args is not None:
+            real = flash_args["key_mask"][:, :T] > 0
+        else:  # the last query's row of the bias: every valid key of the span
+            real = attention_bias[:, 0, -1, :T] > -1.0
+        lead = jnp.argmax(real, axis=1)  # pads in front of each row
+        q, k, v, real = (roll_rows(a, lead) for a in (q, k, v, real))
+        kbar = pooled_keys(k * real[:, :, None, None].astype(k.dtype), cfg)
+
+        def seen(start, tq, tk):  # [B, tq, tk]: causal by position, and no padded key
+            causal = jnp.arange(tk)[None, :] <= start + jnp.arange(tq)[:, None]
+            return causal[None] & real[:, None, :tk]
+
+        def chosen_of(start, tq, tk):
+            t = (start + jnp.arange(tq))[None, :]
+            kernels = max((tk - cfg.sparse_kernel) // cfg.sparse_stride + 1, 1)
+            rows = jax.lax.dynamic_slice_in_dim(q, start, tq, axis=1)
+            return select_blocks(rows, kbar[:, :, :kernels], t, -(-tk // cfg.sparse_block), cfg)
+
+        if selects:
+            args = None if flash_args is None else {"key_mask": real.astype(jnp.int32), "q_offset": 0}
+            out = block_sparse_attention(cfg, q, k, v, seen, chosen_of, cfg.dtype, args)
+        elif flash_args is not None:
+            out = _flash_attention(q, k, v, {"key_mask": real.astype(jnp.int32), "q_offset": 0})
+        else:
+            bias = jnp.where(seen(0, T, T), 0.0, -1e9)[:, None]
+            out = grouped_einsum_attention(q, k, v, bias, cfg.dtype)
+        return roll_rows(out, -lead), kbar
+
+    def _sparse_step_bias(self, q, k_cache, kbar, attention_bias, positions, ci, selects):
+        """One token a row on a layer under the block selection: ``(the
+        bias [B, H, 1, S] of the dense read, masked to the chosen blocks'
+        slots; kbar with the kernel this token completes)``. The token at
+        position ``t`` completes kernel ``(t - kernel + 1) / stride`` where
+        that is a whole number: the mean of the cache's last ``kernel`` keys."""
+        cfg = self.config
+        B, S = k_cache.shape[:2]
+        H, KV = cfg.num_heads, cfg.kv_heads
+        kernel, stride, block = cfg.sparse_kernel, cfg.sparse_stride, cfg.sparse_block
+        t = positions[:, 0]
+        with jax.named_scope("trlx/block_select"):
+            last = jax.lax.dynamic_slice_in_dim(k_cache, jnp.maximum(ci - kernel + 1, 0), kernel, axis=1)
+            mean = (jnp.sum(last.astype(jnp.float32), axis=1) / kernel).astype(kbar.dtype)  # [B, KV, D]
+            completes = (t >= kernel - 1) & ((t - kernel + 1) % stride == 0)
+            at = jnp.where(completes, (t - kernel + 1) // stride, kbar.shape[2])  # past the end: dropped
+            kbar = kbar.at[jnp.arange(B), :, at].set(mean, mode="drop", unique_indices=True)
+        if not selects:
+            return attention_bias, kbar
+        n_blocks = -(-S // block)
+        chosen = select_blocks(q, kbar, t[:, None], n_blocks, cfg)[:, :, 0]  # [B, KV, NB]
+        with jax.named_scope("trlx/block_select"):
+            # a slot's block, from its position in its row, as a 0/1 matrix [B, NB, S]: the chosen blocks
+            # reach their slots by one small product (a gather of 131,072 single elements took 1.6 ms a
+            # layer a step on a v5e, a quarter of the step: PERF.md section 6, PR 49)
+            at_position = jnp.arange(S)[None, :] - (ci - t)[:, None]
+            of_block = (at_position[:, None, :] // block == jnp.arange(n_blocks)[None, :, None]).astype(jnp.bfloat16)
+            kept = jnp.einsum("bkn,bns->bks", chosen.astype(jnp.bfloat16), of_block, preferred_element_type=jnp.float32) > 0.5
+            bias = attention_bias + jnp.where(jnp.repeat(kept, H // KV, axis=1), 0.0, -1e9)[:, :, None, :]
+        return bias, kbar
 
 
 class _Projection(nn.Module):
@@ -1559,12 +1789,28 @@ def select_keys(q_i, k_i, w, visible, topk: int) -> jax.Array:
     return jnp.concatenate(_query_blocks(T, block), axis=1)
 
 
-def selected_attention(q, k, v, visible, selection, dtype) -> jax.Array:
+def selected_attention(q, k, v, visible, selection, dtype, selection_block: int = 1) -> jax.Array:
     """``grouped_einsum_attention``'s function of MHA ``q``, ``k [b, T, H,
     D]`` and ``v [b, T, H, Dv]`` with each query's softmax over its selected
     visible keys only (``selection [b, T, T]``), a block of queries at a time;
-    a block's scores are recomputed in the backward pass, never kept."""
+    a block's scores are recomputed in the backward pass, never kept. A
+    selection by blocks of keys, ``[b, KV, T, T / selection_block]``, holds one
+    set for the query heads of each of ``k``'s and ``v``'s ``KV`` heads (GQA)."""
     b, T, H, D = q.shape
+    if selection.ndim == 4:
+        KV = k.shape[2]
+
+        @functools.partial(jax.checkpoint, static_argnums=(1, 2))
+        def grouped(start, tq, tk):
+            sel = jax.lax.dynamic_slice_in_dim(selection, start, tq, axis=2)[..., : -(-tk // selection_block)]
+            keep = visible(start, tq, tk)[:, None] & jnp.repeat(sel, selection_block, axis=-1)[..., :tk]
+            rows = jax.lax.dynamic_slice_in_dim(q, start, tq, axis=1).reshape(b, tq, KV, H // KV, D)
+            scores = jnp.einsum("bqkgd,bskd->bkgqs", rows, k[:, :tk], preferred_element_type=jnp.float32)
+            scores = jnp.where(keep[:, :, None], scores / np.sqrt(D), -1e9)
+            probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
+            return jnp.einsum("bkgqs,bskd->bqkgd", probs, v[:, :tk]).reshape(b, tq, H, v.shape[-1])
+
+        return jnp.concatenate(_query_blocks(T, grouped), axis=1)
 
     @functools.partial(jax.checkpoint, static_argnums=(1, 2))
     def block(start, tq, tk):
@@ -1603,6 +1849,116 @@ def select_slots(q_i, k_i, w, attention_bias, cache_index, kv_extents, topk: int
     if kv_extents is None or len(kv_extents) < 2:
         return over(None)(q_i, k_i, w, attention_bias)
     return _switch_on_extent(cache_index, kv_extents, over, q_i, k_i, w, attention_bias)
+
+
+# ---------------------------------------------------------------------------
+# a selection by BLOCKS of keys over a plain K/V cache (`sparse_topk`)
+# ---------------------------------------------------------------------------
+
+
+def roll_rows(a: jax.Array, shift: jax.Array) -> jax.Array:
+    """``out[b, i] = a[b, (i + shift[b]) mod T]`` along axis 1: each row of a
+    left-padded batch moved to its own first real token (``shift`` the pads in
+    front of it) and, by ``-shift``, back."""
+    T = a.shape[1]
+    at = (jnp.arange(T)[None, :] + shift[:, None]) % T
+    return jnp.take_along_axis(a, at.reshape(at.shape + (1,) * (a.ndim - 2)), axis=1, mode="promise_in_bounds")
+
+
+def pooled_keys(k: jax.Array, cfg: "TransformerConfig") -> jax.Array:
+    """The compressed keys ``[B, KV, NK, D]`` of ``k [B, T, KV, D]``, a row's
+    keys from its first real token on with zeros behind its last: ``kbar_j``
+    the mean of keys ``[stride j, stride j + kernel)``, float32 sums, in
+    ``k``'s dtype as the cache holds them. ``NK = T // stride``: the last
+    ``kernel / stride - 1`` of them reach past the row and are never valid."""
+    B, T, KV, D = k.shape
+    stride, per = cfg.sparse_stride, cfg.sparse_kernel // cfg.sparse_stride
+    n = T // stride
+    strides = k[:, : n * stride].astype(jnp.float32).reshape(B, n, stride, KV, D).sum(axis=2)
+    strides = jnp.pad(strides, ((0, 0), (0, per - 1), (0, 0), (0, 0)))
+    kbar = sum(strides[:, i : i + n] for i in range(per)) / cfg.sparse_kernel
+    return kbar.transpose(0, 2, 1, 3).astype(k.dtype)
+
+
+def select_blocks(q: jax.Array, kbar: jax.Array, t: jax.Array, n_blocks: int, cfg: "TransformerConfig") -> jax.Array:
+    """The blocks ``[B, KV, tq, n_blocks]`` (bool) the queries ``q [B, tq, H,
+    D]`` at positions ``t [B | 1, tq]`` attend over, one set for the ``H /
+    KV`` query heads of a KV group: each head's softmax over the compressed
+    keys ``kbar [B, KV, NK, D]`` that are complete at ``t`` (``stride j +
+    kernel - 1 <= t``), summed over the group; a block scores the largest of
+    the kernels that overlap it; the first ``SPARSE_INIT_BLOCKS`` blocks and
+    every block with a key in ``(t - sparse_window, t]`` are chosen whatever
+    they score, the best others until ``sparse_topk`` are (of equal scores the
+    lower block, as ``largest_k``); never a block past the query's own.
+    Parameter-free, float32, and without a gradient."""
+    B, tq, H, D = q.shape
+    KV, NK = kbar.shape[1], kbar.shape[2]
+    block, stride, reach = cfg.sparse_block, cfg.sparse_stride, cfg.sparse_kernel // cfg.sparse_stride - 1
+    per = block // stride
+    with jax.named_scope("trlx/block_select"):
+        q, kbar = jax.lax.stop_gradient(q), jax.lax.stop_gradient(kbar)
+        s = jnp.einsum("bqkgd,bkjd->bkgqj", q.reshape(B, tq, KV, H // KV, D), kbar, preferred_element_type=jnp.float32)
+        done = (stride * jnp.arange(NK) + cfg.sparse_kernel - 1 <= t[..., None])[:, None, None]  # [B | 1, 1, 1, tq, NK]
+        s = jnp.where(done, s / np.sqrt(D), -jnp.inf)
+        e = jnp.where(done, jnp.exp(s - jnp.maximum(jnp.max(s, axis=-1, keepdims=True), -1e30)), 0.0)
+        r = jnp.sum(e / jnp.maximum(jnp.sum(e, axis=-1, keepdims=True), 1e-30), axis=2)  # [B, KV, tq, NK]
+        # block b overlaps kernels per * b - reach .. per * b + per - 1
+        r = jnp.pad(jnp.where(done[:, :, 0], r, -jnp.inf), ((0, 0), (0, 0), (0, 0), (reach, max(per * n_blocks - NK, 0))),
+                    constant_values=-jnp.inf)
+        score = functools.reduce(jnp.maximum, [r[..., i : i + per * n_blocks : per] for i in range(per + reach)])
+        blocks = jnp.arange(n_blocks)
+        own = (t // block)[..., None]  # the query's own block
+        causal = (blocks <= own)[:, None]
+        forced = ((blocks < SPARSE_INIT_BLOCKS) | (blocks >= (jnp.maximum(t - cfg.sparse_window + 1, 0) // block)[..., None]))[:, None]
+        score = jnp.where(causal, jnp.where(forced, jnp.inf, score), -jnp.inf)
+        return largest_k(score, cfg.sparse_topk) & causal
+
+
+def block_selected_pairs(width: int, cfg: "TransformerConfig") -> Tuple[float, float]:
+    """``(chosen, causal)`` (query, key) pairs of a row of ``width`` tokens, no
+    padding, on a layer under the block selection: a query at position ``t``
+    keeps every key while its causal blocks number ``sparse_topk`` or fewer,
+    else ``sparse_topk - 1`` whole blocks and its own up to itself; all of
+    them on a row under ``sparse_dense_len``. Host arithmetic for
+    ``learn/attn_block_selected_frac``."""
+    t = np.arange(width, dtype=np.int64)
+    kept = t + 1
+    if cfg.sparse_topk and width >= cfg.sparse_dense_len:
+        bound = (cfg.sparse_topk - 1) * cfg.sparse_block + t % cfg.sparse_block + 1
+        kept = np.where(t // cfg.sparse_block + 1 > cfg.sparse_topk, bound, kept)
+    return float(kept.sum()), float(width * (width + 1) // 2)
+
+
+def block_selected_steps(prompt: int, new: int, cfg: "TransformerConfig") -> float:
+    """Chosen blocks over causal blocks of the decode steps that write slots
+    ``[prompt, prompt + new)`` of an unpadded row, mean over the steps. Host
+    arithmetic for ``rollout/attn_block_selected_frac``."""
+    causal = np.arange(prompt, prompt + new, dtype=np.int64) // cfg.sparse_block + 1
+    if not cfg.sparse_topk or prompt + new < cfg.sparse_dense_len or not new:
+        return 1.0
+    return float(np.mean(np.minimum(causal, cfg.sparse_topk) / causal))
+
+
+def block_sparse_attention(cfg, q, k, v, visible, chosen_of, dtype, flash_args=None) -> jax.Array:
+    """``[B, T, H, D]``: causal GQA attention of ``q [B, T, H, D]`` over the
+    row's own ``k``, ``v [B, T, KV, D]``, rows in position space (``roll_rows``),
+    each query's softmax over the keys of its chosen blocks: ``chosen_of(start,
+    n_queries, n_keys)`` gives a block of queries' sets ``[B, KV, n_queries,
+    NB]``, ``visible(start, n_queries, n_keys)`` their causal and padding mask.
+    Through the flash kernels under ``selection=`` where ``flash_args`` (their
+    key mask) is given, else through ``selected_attention``'s masked einsums."""
+    T = q.shape[1]
+    n_blocks = -(-T // cfg.sparse_block)
+
+    def padded(start, tq, tk):  # queries on axis 1, as `_query_blocks` joins them
+        sel = chosen_of(start, tq, tk)
+        return jnp.pad(sel, ((0, 0), (0, 0), (0, 0), (0, n_blocks - sel.shape[-1]))).transpose(0, 2, 1, 3)
+
+    selection = jnp.concatenate(_query_blocks(T, padded), axis=1).transpose(0, 2, 1, 3)  # [B, KV, T, NB]
+    with jax.named_scope("trlx/block_attn"):
+        if flash_args is not None:
+            return _flash_attention(q, k, v, {**flash_args, "selection": selection, "selection_block": cfg.sparse_block})
+        return selected_attention(q, k, v, visible, selection, dtype, cfg.sparse_block)
 
 
 class Indexer(nn.Module):
@@ -1825,6 +2181,28 @@ class LatentAttention(nn.Module):
         return out, new_cache, (made if index is not None else selection)
 
 
+# A gated MLP builds three [tokens, width] intermediates (gate, up, their
+# product): 2.0 GB each for the 61,440 tokens of a 4-row prefill at 15360 under
+# a width of 16384, which one v5e cannot hold beside 7 GB of weights. Tokens do
+# not interact, so a forward whose intermediate would pass MLP_MAX_BYTES runs
+# equal pieces of at most MLP_PIECE_BYTES one after another. The largest of
+# the cells that came before (falcon-h1: 40,960 x 21,504 x 2 = 1.76 GB) is
+# under the first number and keeps its program; a train step's one row of
+# 16384 (0.54 GB) runs whole. Constants with their arithmetic, not settings.
+MLP_MAX_BYTES = int(1.8 * 2**30)
+MLP_PIECE_BYTES = 2**29
+
+
+def mlp_token_pieces(tokens: int, token_bytes: int) -> int:
+    """How many equal pieces a gated MLP cuts ``tokens`` into (``token_bytes``
+    a token of one intermediate): 1 up to ``MLP_MAX_BYTES``, else the fewest
+    that divide ``tokens`` into pieces of at most ``MLP_PIECE_BYTES``."""
+    if tokens * token_bytes <= MLP_MAX_BYTES:
+        return 1
+    fewest = -(-tokens * token_bytes // MLP_PIECE_BYTES)
+    return next((n for n in range(fewest, 64 * fewest) if tokens % n == 0), 1)
+
+
 class MLP(nn.Module):
     config: TransformerConfig
     width: Optional[int] = None  # None = `intermediate_size`
@@ -1834,6 +2212,21 @@ class MLP(nn.Module):
         cfg = self.config
         width = self.width or cfg.intermediate_size
         act = get_activation(cfg.activation)
+        pieces = mlp_token_pieces(int(np.prod(x.shape[:-1])), width * jnp.dtype(cfg.dtype).itemsize)
+        if cfg.activation == "silu" and pieces > 1:
+            if cfg.mlp_bias:
+                raise NotImplementedError("a gated MLP in pieces (mlp_token_pieces) has no biases")
+            gate_mult, down_mult = cfg.mlp_multipliers
+            kernels = [_Projection(cfg, shape, axes, name=name)() for name, shape, axes in (
+                ("gate_proj", (cfg.hidden_size, width), ("embed", "ffn")), ("up_proj", (cfg.hidden_size, width), ("embed", "ffn")),
+                ("down_proj", (width, cfg.hidden_size), ("ffn", "embed")))]
+
+            def piece(rows):
+                gate, up = project(kernels[0], rows, cfg), project(kernels[1], rows, cfg)
+                y = project(kernels[2], act(gate * gate_mult if gate_mult != 1.0 else gate) * up, cfg)
+                return y * down_mult if down_mult != 1.0 else y
+
+            return jax.lax.map(piece, x.reshape(pieces, -1, x.shape[-1])).reshape(x.shape)
         if cfg.activation == "silu":  # gated (llama-style) MLP
             gate = _dense(cfg, width, cfg.mlp_bias, ("embed", "ffn"), "gate_proj")(x)
             up = _dense(cfg, width, cfg.mlp_bias, ("embed", "ffn"), "up_proj")(x)
@@ -1966,6 +2359,62 @@ class Mamba2Mixer(nn.Module):
         )(y)
         new_cache = None if cache is None else {"ssm": state, "conv": conv_state.astype(cache["conv"].dtype)}
         return out, new_cache
+
+
+class LightningMixer(nn.Module):
+    """Lightning linear attention (Qin et al., arXiv:2401.04658) as
+    ``minicpm_sala`` runs it: ``q, k, v`` of ``lightning_heads`` heads of
+    ``lightning_head_dim``, an RMSNorm with a learned scale over each head's q
+    and k, rotary embedding on all of a head's dims, then per head the linear
+    recurrence ``S_t = lambda_h S_{t-1} + k_t v_t^T``, ``o_t = S_t^T q_t /
+    sqrt(d)`` with the fixed decay ``lambda_h = exp(-2^(-8 h / H))`` (ALiBi's
+    slopes) and no normaliser; ``o_proj(RMSNorm(concat_h o) * sigmoid(z_proj
+    u))``. The recurrence is ``ops/ssd.py``'s, ``x = v``, ``B = k``, ``C = q /
+    sqrt(d)``, ``A = log lambda``, every step 1, no groups and no skip.
+
+    The layer's whole cache is ``{"state": [B, H, d, d]}`` float32 (``S``
+    transposed: value channels by key channels), no K and no V: one token
+    takes ``ssd_step``, a span (prefill) the chunked scan from the stored
+    state, a pass without a cache the chunked scan from zero, run again in
+    the backward pass rather than kept (``Mamba2Mixer``). ``token_mask``
+    marks real tokens: a padded position feeds nothing into the state, so a
+    left-padded row reaches its first real token with a zero state."""
+
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, u, positions, cache=None, token_mask=None):
+        from trlx_tpu.ops.ssd import ssd_chunked, ssd_step
+
+        cfg = self.config
+        B, T, _ = u.shape
+        H, D = cfg.lightning_heads, cfg.lightning_head_dim
+        q = _dense(cfg, H * D, False, ("embed", "joined_kv"), "q_proj", cfg.qk_init_std)(u).reshape(B, T, H, D)
+        k = _dense(cfg, H * D, False, ("embed", "joined_kv"), "k_proj", cfg.qk_init_std)(u).reshape(B, T, H, D)
+        v = _dense(cfg, H * D, False, ("embed", "joined_kv"), "v_proj")(u).reshape(B, T, H, D)
+        gate = jax.nn.sigmoid(_dense(cfg, H * D, False, ("embed", "joined_kv"), "z_proj")(u))
+        if cfg.qk_norm:
+            q, k = _qk_norm(cfg, "q_norm")(q), _qk_norm(cfg, "k_norm")(k)
+        sin, cos = rotary_sin_cos(positions, D, cfg.rope_theta)
+        q, k = apply_rotary(q, sin, cos, D, True), apply_rotary(k, sin, cos, D, True)
+        q = q * jnp.asarray(1.0 / np.sqrt(D), q.dtype)
+        if token_mask is not None:
+            v = v * token_mask.reshape(B, T, 1, 1).astype(v.dtype)
+        log_decay = -jnp.asarray(alibi_slopes(H), jnp.float32)
+
+        if cache is not None and T == 1:
+            with jax.named_scope("trlx/lightning_step"):
+                y, state = ssd_step(cache["state"], v[:, 0], jnp.ones((1, H), jnp.float32), log_decay, k[:, 0], q[:, 0], None)
+            y = y[:, None]
+        else:
+            scan = partial(ssd_chunked, chunk=cfg.lightning_chunk)
+            if cache is None:
+                scan = jax.checkpoint(scan)
+            with jax.named_scope("trlx/lightning_scan"):
+                y, state = scan(v, jnp.ones((1, T, H), jnp.float32), log_decay, k, q, None, None, None if cache is None else cache["state"])
+        y = Norm(cfg, name="o_norm")(y.reshape(B, T, H * D)) * gate
+        out = _dense(cfg, cfg.hidden_size, False, ("joined_kv", "embed"), "o_proj")(y)
+        return out, (None if cache is None else {"state": state})
 
 
 @functools.lru_cache(maxsize=None)
@@ -2416,15 +2865,16 @@ def _cache_is_paged(cache) -> bool:
     return False
 
 
-def cache_slots(layer_cache: Dict[str, jax.Array], stacked: bool = False) -> int:
+def cache_slots(layer_cache: Dict[str, jax.Array], stacked: bool = False) -> Optional[int]:
     """Slots a layer's dense cache holds a row: the length of ``k``, or of a
-    latent layer's ``ckv`` or ``latent`` (behind a leading layer dim where ``stacked``)."""
-    leaf = next(layer_cache[name] for name in ("k", "ckv", "latent") if name in layer_cache)
-    return leaf.shape[1 + stacked]
+    latent layer's ``ckv`` or ``latent`` (behind a leading layer dim where
+    ``stacked``); None for a layer whose whole cache is a recurrent state."""
+    leaf = next((layer_cache[name] for name in ("k", "ckv", "latent") if name in layer_cache), None)
+    return None if leaf is None else leaf.shape[1 + stacked]
 
 
 def _needs_token_mask(cfg: TransformerConfig) -> bool:
-    return cfg.num_experts > 0 or cfg.mixer != "none"
+    return cfg.num_experts > 0 or cfg.mixer != "none" or cfg.mixer_layout is not None
 
 
 def _query_slots(q_offset, B: int, T: int) -> jax.Array:
@@ -2476,7 +2926,9 @@ class Block(nn.Module):
             x = x + mix_out * cfg.ssm_out_multiplier + attn_out * cfg.attention_out_multiplier
             mlp_out, aux = run_mlp(Norm(cfg, name="ln_mlp")(x))
             return x + mlp_out, new_cache, aux, None
-        if cfg.latent_attention:
+        if layout.mixer == "lightning":
+            attn_out, new_cache = LightningMixer(cfg, name="attn")(h, positions, cache, token_mask)
+        elif cfg.latent_attention:
             lends = self.layer + 1 < cfg.num_layers and cfg.layer_layout(self.layer + 1).indexer == "shared"
             attn_out, new_cache, selection = LatentAttention(cfg, layout.indexer, lends, name="attn")(
                 h, attention_bias, positions, cache, cache_index, flash_args, kv_extents, selection
@@ -2490,12 +2942,13 @@ class Block(nn.Module):
             mlp_out, aux = run_mlp(mlp_in)
             x = x + attn_out + mlp_out
         else:
-            x = x + attn_out
+            scaled = cfg.residual_multiplier != 1.0
+            x = x + (attn_out * cfg.residual_multiplier if scaled else attn_out)
             h = Norm(cfg, name="ln_mlp")(x)
             mlp_out, aux = run_mlp(h)
             if cfg.sandwich_norm:
                 mlp_out = Norm(cfg, name="ln_mlp_post")(mlp_out)
-            x = x + mlp_out
+            x = x + (mlp_out * cfg.residual_multiplier if scaled else mlp_out)
         return x, new_cache, aux, selection
 
 
@@ -2714,8 +3167,8 @@ class CausalTransformer(nn.Module):
         for i in layers:
             window = cfg.layer_layout(i).window
             slots = S
-            if dense:
-                slots = cache_slots(cache if isinstance(cache, dict) else cache[i], isinstance(cache, dict))
+            if dense:  # (a layer with no slots at all, a recurrent state alone, reads the row's plan and none of it)
+                slots = cache_slots(cache if isinstance(cache, dict) else cache[i], isinstance(cache, dict)) or S
             if (window, slots) not in plans:
                 if slots == S:
                     plans[window, slots] = self._attn_inputs(key_mask, positions, q_offset, use_flash, window) + (extents,)
@@ -3110,6 +3563,10 @@ def make_kv_cache(
     more cache layer for each module. A ``mixer: mamba2`` layer also holds ``ssm`` (the recurrent
     state, float32 whatever ``dtype``: hundreds of steps of ``S = aS + ...``
     drift in bf16) and ``conv`` (the conv's last ``K - 1`` input rows). A
+    ``lightning`` layer (``mixer_layout``) holds ``state`` ``[B, heads, d, d]``
+    float32 and nothing else; an attention layer under a block selection
+    (``sparse_topk`` > 0) holds ``kbar`` ``[B, KV, max_length / sparse_stride,
+    D]`` beside ``k`` and ``v``, the keys' mean-pool its decode steps score. A
     latent-attention layer (``kv_lora_rank`` > 0) holds ``ckv`` ``[B, slots,
     kv_lora_rank]`` and ``k_rope`` ``[B, slots, qk_rope_head_dim]`` IN PLACE
     of ``k`` and ``v``: 576 numbers a slot at the published widths where
@@ -3152,10 +3609,19 @@ def make_kv_cache(
                 # on the layers that select for themselves only
                 latent["k_index"] = jnp.zeros(stacked + (batch_size, slots, cfg.index_head_dim), dtype)
             return latent
+        if layout.mixer == "lightning":
+            # the layer's whole cache: the recurrence's state, float32 as `ssm` is, and no K or V
+            # (ops/paged_kv.py::RECURRENT_LEAVES)
+            heads, d = cfg.lightning_heads, cfg.lightning_head_dim
+            return {"state": jnp.zeros(stacked + (batch_size, heads, d, d), jnp.float32)}
         shapes = {
             "k": ((batch_size, slots, cfg.kv_heads, cfg.dims_per_head), dtype),
             "v": ((batch_size, slots, cfg.kv_heads, cfg.dims_per_head), dtype),
         }
+        if cfg.sparse_topk:
+            # the keys' running mean-pool (`pooled_keys`), by KV head: kernel j is complete once the
+            # row's token `sparse_stride * j + sparse_kernel - 1` is in (ops/paged_kv.py::POOLED_LEAVES)
+            shapes["kbar"] = ((batch_size, cfg.kv_heads, max_length // cfg.sparse_stride, cfg.dims_per_head), dtype)
         if cfg.mixer == "mamba2":
             shapes["ssm"] = ((batch_size, cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_state), jnp.float32)
             shapes["conv"] = ((batch_size, cfg.mamba_conv - 1, cfg.mamba_conv_channels), dtype)
@@ -3205,6 +3671,7 @@ BUILTIN_SPECS = {
     "pangu": TransformerConfig.pangu,
     "glm": TransformerConfig.glm,
     "k-exaone": TransformerConfig.exaone,
+    "minicpm-sala": TransformerConfig.minicpm_sala,
     "gptj": TransformerConfig.gptj,
     "gptneox": TransformerConfig.gptneox,
     "pythia": TransformerConfig.gptneox,

@@ -557,12 +557,33 @@ _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 # ---------------------------------------------------------------------------
 
 
+# a selection by blocks reaches the kernels in stretches of this many blocks: a vector register's lanes
+SEL_LANES = 128
+
+
+def _tile_of_blocks(blocks, first, block_k: int, sel_block: int):
+    """``(bQ, bK)`` bool: a key tile's mask from a selection by blocks of
+    ``sel_block`` keys. ``blocks (bQ, SEL_LANES)`` bf16 holds, for each query,
+    one 0/1 a block of a 128-block stretch of the row, the tile's own
+    starting at lane ``first``; column ``c`` of the tile takes lane ``first +
+    c // sel_block``: one ``(bQ, 128) x (128, bK)`` product with a 0/1
+    matrix built from two iotas, which the MXU has to spare (a lane gather
+    or a dynamic lane slice narrower than 128 Mosaic does not lower)."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (SEL_LANES, block_k), 0)
+    column = jax.lax.broadcasted_iota(jnp.int32, (SEL_LANES, block_k), 1)
+    spread = (lane == first + column // sel_block).astype(blocks.dtype)
+    # 0/1 operands: one bf16 pass is exact, and Mosaic takes no float32 pass over bf16 operands,
+    # which a process-wide `jax_default_matmul_precision: highest` would otherwise ask for
+    return jax.lax.dot_general(blocks, spread, (((1,), (0,)), ((), ())), precision=jax.lax.Precision.DEFAULT,
+                               preferred_element_type=jnp.float32) > 0.5
+
+
 def _selected_fwd_kernel(
     q_ref,  # (1, 1, bQ, D)
     k_ref,  # (1, 1, Sp, D)
     v_ref,  # (1, 1, Sp, Dv)
     kmask_ref,  # (1, 1, Sp)
-    sel_ref,  # (1, bQ, Sp) int8: nonzero = this query keeps this key; one for all heads
+    sel_ref,  # (1, 1, bQ, Sp) int8: nonzero = this query keeps this key; (1, 1, bQ, NBp) bf16 by blocks of keys
     o_ref,  # (1, 1, bQ, Dv)
     l_ref,  # (1, 1, bQ, LANES)
     *,
@@ -570,11 +591,14 @@ def _selected_fwd_kernel(
     block_k: int,
     seq_k: int,
     block_q: int,
+    sel_block: int,
 ):
     """``_fwd_kernel``'s causal walk (slot offsets 0, no window, no ALiBi)
     with one more mask a tile: the query block's rows of the selection. Every
     tile up to the diagonal is visited and masked: a learned selection keeps
-    some key of nearly every tile, so there is none to skip."""
+    some key of nearly every tile, so there is none to skip. A selection by
+    blocks of ``sel_block`` keys is widened to the tile on the MXU
+    (``_tile_of_blocks``)."""
     iq = pl.program_id(2)
     q = q_ref[0, 0].astype(jnp.float32) * sm_scale
     q0 = iq * block_q
@@ -586,7 +610,12 @@ def _selected_fwd_kernel(
         k = k_ref[0, 0, pl.ds(ik * block_k, block_k), :].astype(jnp.float32)
         v = v_ref[0, 0, pl.ds(ik * block_k, block_k), :].astype(jnp.float32)
         kmask = kmask_ref[0, 0, pl.ds(ik * block_k, block_k)].reshape(1, block_k)
-        chosen = sel_ref[0, :, pl.ds(ik * block_k, block_k)].astype(jnp.int32) != 0
+        if sel_block == 1:
+            chosen = sel_ref[0, 0, :, pl.ds(ik * block_k, block_k)].astype(jnp.int32) != 0
+        else:
+            first = ik * (block_k // sel_block)  # the tile's first block of keys
+            lanes = pl.multiple_of((first // SEL_LANES) * SEL_LANES, SEL_LANES)
+            chosen = _tile_of_blocks(sel_ref[0, 0, :, pl.ds(lanes, SEL_LANES)], first - lanes, block_k, sel_block)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # (bQ, bK)
@@ -618,7 +647,7 @@ def _selected_bwd_kernel(
     k_ref,  # (1, 1, bK, D)
     v_ref,  # (1, 1, bK, Dv)
     kmask_ref,  # (1, 1, bK)
-    sel_ref,  # (1, Tp, bK) int8: the key block's columns of the selection
+    sel_ref,  # (1, 1, Tp, bK) int8: the key block's columns of the selection; (1, 1, Tp, 128) bf16 by blocks
     lse_ref,  # (1, 1, Tp, LANES)
     delta_ref,  # (1, 1, Tp, LANES)
     do_ref,  # (1, 1, Tp, Dv)
@@ -630,6 +659,7 @@ def _selected_bwd_kernel(
     block_q: int,
     seq_q: int,
     block_k: int,
+    sel_block: int,
 ):
     """``_bwd_fused_kernel`` under the same selection: dq, dk and dv in one
     pass over the query blocks at or below the key block's diagonal."""
@@ -653,7 +683,11 @@ def _selected_bwd_kernel(
         do = do_ref[0, 0, pl.ds(iq * block_q, block_q), :].astype(jnp.float32)
         lse = lse_ref[0, 0, pl.ds(iq * block_q, block_q), 0:1]
         delta = delta_ref[0, 0, pl.ds(iq * block_q, block_q), 0:1]
-        chosen = sel_ref[0, pl.ds(iq * block_q, block_q), :].astype(jnp.int32) != 0
+        if sel_block == 1:
+            chosen = sel_ref[0, 0, pl.ds(iq * block_q, block_q), :].astype(jnp.int32) != 0
+        else:  # the 128 blocks of keys among which this key tile's lie (the BlockSpec's window)
+            first = ik * (block_k // sel_block)
+            chosen = _tile_of_blocks(sel_ref[0, 0, pl.ds(iq * block_q, block_q), :], first % SEL_LANES, block_k, sel_block)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
@@ -684,31 +718,36 @@ def _selected_bwd_kernel(
     dv_ref[0, 0] = dv.astype(dv_ref.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
-def _selected(q, k, v, kmask, sel, sm_scale: float, block_q: int, block_k: int, interpret: bool):
-    """MHA ``q, k (B, H, T, D)``, ``v (B, H, T, Dv)``, ``kmask (B, 1, T)``
-    float, ``sel (B, T, T)`` int8, all padded to the tiles: causal attention
-    with each query's softmax over the keys it keeps."""
-    return _selected_fwd_impl(q, k, v, kmask, sel, sm_scale, block_q, block_k, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _selected(q, k, v, kmask, sel, sm_scale: float, block_q: int, block_k: int, interpret: bool, sel_block: int = 1):
+    """``q (B, H, T, D)`` over ``k (B, KV, T, D)``, ``v (B, KV, T, Dv)``
+    (query head ``h`` reads KV head ``h // (H / KV)``), ``kmask (B, 1, T)``
+    float, all padded to the tiles: causal attention with each query's
+    softmax over the keys it keeps. ``sel (B, KS, T, T)`` int8 keeps keys one
+    by one (``sel_block`` 1); ``sel (B, KS, T, NBp)`` bf16 keeps them by blocks
+    of ``sel_block``, ``NBp`` the row's blocks padded to whole 128-lane
+    stretches. One set for the ``H / KS`` query heads of each of its ``KS``."""
+    return _selected_fwd_impl(q, k, v, kmask, sel, sm_scale, block_q, block_k, interpret, sel_block)[0]
 
 
-def _selected_fwd_impl(q, k, v, kmask, sel, sm_scale, block_q, block_k, interpret):
+def _selected_fwd_impl(q, k, v, kmask, sel, sm_scale, block_q, block_k, interpret, sel_block=1):
     B, H, T, D = q.shape
-    S, Dv = k.shape[2], v.shape[3]
+    KV, S, Dv = k.shape[1], k.shape[2], v.shape[3]
+    group, per_set = H // KV, H // sel.shape[1]
     kernel = functools.partial(
-        _selected_fwd_kernel, sm_scale=sm_scale, block_k=block_k, seq_k=S, block_q=block_q
+        _selected_fwd_kernel, sm_scale=sm_scale, block_k=block_k, seq_k=S, block_q=block_q, sel_block=sel_block
     )
     itemsize = q.dtype.itemsize
-    resident = 2 * S * ((D + Dv) * itemsize + 8 * 4) + 2 * block_q * S
+    resident = 2 * S * ((D + Dv) * itemsize + 8 * 4) + 2 * block_q * sel.shape[3] * sel.dtype.itemsize
     return pl.pallas_call(
         kernel,
         grid=(B, H, T // block_q),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, D), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, S, D), lambda b, h, i: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, S, Dv), lambda b, h, i: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, S, D), lambda b, h, i: (b, h // group, 0, 0)),
+            pl.BlockSpec((1, 1, S, Dv), lambda b, h, i: (b, h // group, 0, 0)),
             pl.BlockSpec((1, 1, S), lambda b, h, i: (b, 0, 0)),
-            pl.BlockSpec((1, block_q, S), lambda b, h, i: (b, i, 0)),
+            pl.BlockSpec((1, 1, block_q, sel.shape[3]), lambda b, h, i: (b, h // per_set, i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_q, Dv), lambda b, h, i: (b, h, i, 0)),
@@ -724,31 +763,37 @@ def _selected_fwd_impl(q, k, v, kmask, sel, sm_scale, block_q, block_k, interpre
     )(q, k, v, kmask, sel)
 
 
-def _selected_fwd_rule(q, k, v, kmask, sel, sm_scale, block_q, block_k, interpret):
-    out, lse = _selected_fwd_impl(q, k, v, kmask, sel, sm_scale, block_q, block_k, interpret)
+def _selected_fwd_rule(q, k, v, kmask, sel, sm_scale, block_q, block_k, interpret, sel_block):
+    out, lse = _selected_fwd_impl(q, k, v, kmask, sel, sm_scale, block_q, block_k, interpret, sel_block)
     return out, (q, k, v, kmask, sel, out, lse)
 
 
-def _selected_bwd_rule(sm_scale, block_q, block_k, interpret, res, do):
+def _selected_bwd_rule(sm_scale, block_q, block_k, interpret, sel_block, res, do):
     q, k, v, kmask, sel, out, lse = res
     B, H, T, D = q.shape
-    S, Dv = k.shape[2], v.shape[3]
+    KV, S, Dv = k.shape[1], k.shape[2], v.shape[3]
+    group, per_set = H // KV, H // sel.shape[1]
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     delta = jnp.broadcast_to(delta[..., None], (B, H, T, LANES))
     kernel = functools.partial(
-        _selected_bwd_kernel, sm_scale=sm_scale, block_q=block_q, seq_q=T, block_k=block_k
+        _selected_bwd_kernel, sm_scale=sm_scale, block_q=block_q, seq_q=T, block_k=block_k, sel_block=sel_block
     )
     itemsize = q.dtype.itemsize
-    resident = 2 * T * ((D + Dv) * itemsize + D * 4 + 2 * 128 * 4) + 2 * T * block_k
+    if sel_block == 1:  # the key block's own columns
+        sel_spec = pl.BlockSpec((1, 1, T, block_k), lambda b, h, i: (b, h // per_set, 0, i))
+    else:  # the 128-lane stretch of blocks that holds the key block's
+        tile_blocks = block_k // sel_block
+        sel_spec = pl.BlockSpec((1, 1, T, SEL_LANES), lambda b, h, i: (b, h // per_set, 0, i * tile_blocks // SEL_LANES))
+    resident = 2 * T * ((D + Dv) * itemsize + D * 4 + 2 * 128 * 4) + 2 * T * sel_spec.block_shape[3] * sel.dtype.itemsize
     dq, dk, dv = pl.pallas_call(
         kernel,
         grid=(B, H, S // block_k),
         in_specs=[
             pl.BlockSpec((1, 1, T, D), lambda b, h, i: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_k, Dv), lambda b, h, i: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, block_k, D), lambda b, h, i: (b, h // group, i, 0)),
+            pl.BlockSpec((1, 1, block_k, Dv), lambda b, h, i: (b, h // group, i, 0)),
             pl.BlockSpec((1, 1, block_k), lambda b, h, i: (b, 0, i)),
-            pl.BlockSpec((1, T, block_k), lambda b, h, i: (b, 0, i)),
+            sel_spec,
             pl.BlockSpec((1, 1, T, LANES), lambda b, h, i: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, T, LANES), lambda b, h, i: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, T, Dv), lambda b, h, i: (b, h, 0, 0)),
@@ -767,7 +812,11 @@ def _selected_bwd_rule(sm_scale, block_q, block_k, interpret, res, do):
         name=BWD_KERNEL_NAME,
         **_vmem_params(resident, _tile_working_bytes(block_q, block_k, max(D, Dv), itemsize), interpret),
     )(q, k, v, kmask, sel, lse, delta, do)
-    no_gradient = np.zeros(sel.shape, jax.dtypes.float0)  # an integer operand's cotangent
+    if group > 1:  # each query head's partials, summed over its KV head's group
+        dk = dk.reshape(B, KV, group, S, D).sum(axis=2)
+        dv = dv.reshape(B, KV, group, S, Dv).sum(axis=2)
+    # an integer operand's cotangent; the 0/1 blocks are no function of anything differentiated
+    no_gradient = np.zeros(sel.shape, jax.dtypes.float0) if sel_block == 1 else jnp.zeros_like(sel)
     return dq.astype(q.dtype), dk, dv, jnp.zeros_like(kmask), no_gradient
 
 
@@ -955,13 +1004,18 @@ def flash_attention(
     return_lse: bool = False,
     window: Optional[int] = None,  # sliding-window width (None = unbounded)
     selection: Optional[jax.Array] = None,  # (B, T, S) nonzero = this query keeps this key
+    selection_block: int = 1,  # > 1: selection (B, KS, T, ceil(S / selection_block)), by blocks of keys
 ):
     """Flash attention over ``[B, T, H, D]`` tensors (model layout).
 
-    With ``selection`` each query's softmax runs over the keys it keeps (a
-    learned sparse selection, one for all heads), causal and under the key
-    mask as always: MHA over the row's own keys (``S = T``, slot offsets 0),
-    no window, no ALiBi; kernels of their own, under the same names.
+    With ``selection`` each query's softmax runs over the keys it keeps,
+    causal and under the key mask as always, over the row's own keys (``S =
+    T``, slot offsets 0), no window, no ALiBi; kernels of their own, under the
+    same names. Two forms: ``(B, T, T)``, key by key and one set for all heads
+    (a learned sparse selection over MHA); with ``selection_block`` > 1 ``(B,
+    KS, T, ceil(T / selection_block))``, by blocks of that many keys and one
+    set for the ``H / KS`` query heads of each of ``KS`` (a block selection
+    under GQA: ``KS`` the KV heads), 1 / ``selection_block`` of the bytes.
 
     Pads T/S up to block multiples internally; padded key slots are invisible
     (mask 0), padded query rows produce zeros and are sliced off. With
@@ -981,18 +1035,32 @@ def flash_attention(
     alibi = alibi_slopes is not None
     block_q, block_k = _resolve_blocks(block_q, block_k, T, S, interpret)
     if selection is not None:
-        if not causal or alibi or window or return_lse or H != KV or S != T or selection.shape != (B, T, S):
+        by_blocks = selection_block > 1
+        if by_blocks and interpret and (block_k % selection_block or SEL_LANES % (block_k // selection_block)):
+            # the interpreter's tile is the row (`_resolve_blocks`): cut it to a power of two of whole blocks
+            block_q = block_k = selection_block * 2 ** int(np.log2(max(block_k // selection_block, 1)))
+        shape_ok = (
+            selection.ndim == 4 and selection.shape[::2] == (B, T) and H % selection.shape[1] == 0
+            and selection.shape[3] == -(-S // selection_block) and block_k % selection_block == 0
+            and SEL_LANES % (block_k // selection_block) == 0
+        ) if by_blocks else (H == KV and selection.shape == (B, T, S))
+        if not causal or alibi or window or return_lse or S != T or not shape_ok:
             raise ValueError(
-                "a selection runs causal MHA over the row's own keys: no window, no ALiBi, "
-                f"no lse, a (B, T, T) selection (got {selection.shape} for q {q.shape}, k {k.shape})"
+                "a selection runs causal MHA over the row's own keys: no window, no ALiBi, no lse, a (B, T, T) "
+                "selection; or, under selection_block, GQA under a (B, KS, T, T / selection_block) one whose blocks "
+                f"divide the key tile (got {selection.shape}, block {selection_block}, for q {q.shape}, k {k.shape}, tile {block_k})"
             )
         tile = max(block_q, block_k)  # one padded length for queries and keys (the smaller tile divides it)
         pad = lambda a, axis: _pad_to(a, tile, axis)
-        sel = pad(pad(selection.astype(jnp.int8), 1), 2)
+        if by_blocks:  # the padded row's blocks, in whole 128-lane stretches so that a tile's lie inside one
+            sel = pad(selection.astype(jnp.bfloat16), 2)
+            sel = _pad_to(_pad_to(sel, sel.shape[2] // selection_block, 3), SEL_LANES, 3)
+        else:
+            sel = pad(pad(selection.astype(jnp.int8), 1), 2)[:, None]
         out = _selected(
             pad(q.transpose(0, 2, 1, 3), 2), pad(k.transpose(0, 2, 1, 3), 2), pad(v.transpose(0, 2, 1, 3), 2),
             pad(key_mask.astype(jnp.float32), 1).reshape(B, 1, -1), sel,
-            sm_scale, block_q, block_k, interpret,
+            sm_scale, block_q, block_k, interpret, selection_block,
         )
         return out[:, :, :T, :].transpose(0, 2, 1, 3)
 
@@ -1039,7 +1107,7 @@ def flash_attention(
 def attention_reference(
     q, k, v, key_mask, *, causal=True, sm_scale=None,
     q_offset=0, k_offset=0, q_positions=None, k_positions=None,
-    alibi_slopes=None, window=None, selection=None,
+    alibi_slopes=None, window=None, selection=None, selection_block=1,
 ) -> Tuple[jax.Array, jax.Array]:
     """Naive XLA attention with identical masking semantics (test oracle).
 
@@ -1059,7 +1127,10 @@ def attention_reference(
         visible = visible & (k_slots <= q_slots)[None, None, :, :]
     if window:
         visible = visible & (q_slots - k_slots < window)[None, None, :, :]
-    if selection is not None:  # (B, T, S): the keys each query keeps, one set for all heads
+    if selection is not None and selection_block > 1:  # (B, KS, T, S / block): by blocks, a set a group of heads
+        keys = jnp.repeat(selection != 0, selection_block, axis=3)[..., :S]
+        visible = visible & jnp.repeat(keys, H // keys.shape[1], axis=1)
+    elif selection is not None:  # (B, T, S): the keys each query keeps, one set for all heads
         visible = visible & (selection != 0)[:, None, :, :]
     if alibi_slopes is not None:
         dist = (

@@ -65,6 +65,8 @@ __all__ = [
     "dense_kv_bytes",
     "recurrent_state_bytes",
     "refuse_recurrent_state",
+    "pooled_key_bytes",
+    "linear_state_bytes",
     "refuse_ring_cache",
     "latent_cache_bytes",
     "index_cache_bytes",
@@ -273,8 +275,23 @@ def kv_bytes(cache: Any) -> int:
 
 
 # what a `mixer: mamba2` layer keeps a sequence beside K and V
-# (models/transformer.py::make_kv_cache): the state and the conv's last rows
-RECURRENT_LEAVES = ("ssm", "conv")
+# (models/transformer.py::make_kv_cache): the state and the conv's last rows;
+# and what a `lightning` layer (`mixer_layout`) keeps IN PLACE of K and V: its state
+RECURRENT_LEAVES = ("ssm", "conv", "state")
+
+# what an attention layer under a block selection (`sparse_topk`) keeps beside
+# K and V: the keys' running mean-pool its decode steps score
+POOLED_LEAVES = ("kbar",)
+
+
+def linear_state_bytes(cache: Any) -> int:
+    """Bytes of the ``state`` leaves alone: the lightning layers' whole cache."""
+    return _named_leaf_bytes(cache, ("state",))
+
+
+def pooled_key_bytes(cache: Any) -> int:
+    """Bytes of a cache pytree's compressed keys (``kbar``), by leaf name."""
+    return _named_leaf_bytes(cache, POOLED_LEAVES)
 
 
 def recurrent_state_bytes(cache: Any) -> int:
@@ -309,8 +326,15 @@ def refuse_recurrent_state(cache: Any, path: str) -> None:
     if recurrent_state_bytes(cache):
         raise NotImplementedError(
             f"{path} does not support a model whose cache holds recurrent state "
-            f"(leaves {RECURRENT_LEAVES}: `mixer: mamba2`, the falcon_h1 family): "
+            f"(leaves {RECURRENT_LEAVES}: `mixer: mamba2`, the falcon_h1 family; a `lightning` "
+            f"layer of `mixer_layout`, the minicpm_sala family): "
             f"{_KV_ONLY_PATHS[path]}; use the plain sampler (ROADMAP.md queue 2, B7)"
+        )
+    if pooled_key_bytes(cache):
+        raise NotImplementedError(
+            f"{path} does not support a model whose attention runs under a block selection "
+            f"(leaf {POOLED_LEAVES[0]}: `sparse_topk`, the minicpm_sala family): it holds K and V a slot and "
+            "no compressed keys, which fill by each row's own position; use the plain sampler (ROADMAP.md queue 2, B8)"
         )
 
 

@@ -1,7 +1,7 @@
-"""The Mamba-2 recurrence (state-space duality, Dao & Gu 2024,
-arXiv:2405.21060) in plain ``jax.numpy``: the chunked scan the prefill, the
-scoring forward and the train step run, the one-token step the decode loop
-runs, and the causal depthwise conv in front of both.
+"""The linear recurrence of a state-space or linear-attention head in plain
+``jax.numpy`` (state-space duality, Dao & Gu 2024, arXiv:2405.21060): the
+chunked scan the prefill, the scoring forward and the train step run, the
+one-token step the decode loop runs, and Mamba-2's causal depthwise conv.
 
 Per head ``h`` with state ``S [P, N]`` (``P`` channels of the head, ``N`` the
 state size), decay rate ``A_h < 0`` and the step size ``dt_t > 0``::
@@ -9,10 +9,18 @@ state size), decay rate ``A_h < 0`` and the step size ``dt_t > 0``::
     S_t = exp(dt_t A_h) S_{t-1} + dt_t * x_t (outer) B_t
     y_t = S_t C_t + D_h x_t
 
-``B`` and ``C`` are shared by the ``H / G`` heads of a group. Everything is
-XLA: no Pallas kernel. The decay arithmetic and every accumulation are
-float32; the matmul operands keep the dtype of ``x`` (bf16 where the model
-computes in bf16); the state is float32 always.
+Two mixers run it (``models/transformer.py``). **Mamba-2** (``Mamba2Mixer``):
+``dt`` a softplus of the token, ``B`` and ``C`` shared by the ``H / G`` heads
+of a group, a skip ``D``. **Lightning attention** (``LightningMixer``; Qin et
+al., arXiv:2401.04658: ``S_t = lambda_h S_{t-1} + k_t v_t^T``, ``o_t = S_t^T
+q_t``): ``x = v``, ``B = k``, ``C = q``, ``A_h = log lambda_h``, a step of 1
+on every token (``dt`` of ``[1, T, H]`` ones: whatever depends on the steps
+alone, the decays inside a chunk, is then built once and not once a row),
+``G = H`` and no skip (``D`` None).
+
+Everything is XLA: no Pallas kernel. The decay arithmetic and every
+accumulation are float32; the matmul operands keep the dtype of ``x`` (bf16
+where the model computes in bf16); the state is float32 always.
 """
 
 from typing import Optional, Tuple
@@ -30,11 +38,11 @@ def _per_head(t: jax.Array, heads: int) -> jax.Array:
 
 def ssd_chunked(
     x: jax.Array,  # [B, T, H, P]
-    dt: jax.Array,  # [B, T, H] step sizes, after softplus
+    dt: jax.Array,  # [B | 1, T, H] step sizes (after softplus; 1: every row's alike)
     A: jax.Array,  # [H] negative decay rates
     B: jax.Array,  # [B, T, G, N]
     C: jax.Array,  # [B, T, G, N]
-    D: jax.Array,  # [H] skip
+    D: Optional[jax.Array],  # [H] skip; None = none
     mask: Optional[jax.Array] = None,  # [B, T] 1 on real tokens
     initial_state: Optional[jax.Array] = None,  # [B, H, P, N] float32
     chunk: int = 128,
@@ -63,7 +71,7 @@ def ssd_chunked(
         xc = x.reshape(Bsz, nc, Q, H, P)
         Bc = B.reshape(Bsz, nc, Q, G, N)
         Cc = C.reshape(Bsz, nc, Q, G, N)
-        dtc = dt.astype(F32).reshape(Bsz, nc, Q, H)
+        dtc = dt.astype(F32).reshape(dt.shape[0], nc, Q, H)
         # log-decay accumulated inside each chunk: cs[t] = sum_{s<=t} dt_s A,
         # heads before positions so that the chunk is the minor dimension
         cs = jnp.cumsum(dtc * A.astype(F32), axis=2).transpose(0, 1, 3, 2)  # [B, nc, H, Q]
@@ -103,18 +111,19 @@ def ssd_chunked(
                            entering.astype(dtype).reshape(Bsz, nc, G, H // G, P, N),
                            preferred_element_type=F32).reshape(Bsz, nc, Q, H, P)
         y = y + y_off * jnp.exp(cs).transpose(0, 1, 3, 2)[..., None]
-        y = y + xc.astype(F32) * D.astype(F32)[:, None]
+        if D is not None:
+            y = y + xc.astype(F32) * D.astype(F32)[:, None]
         return y.reshape(Bsz, nc * Q, H, P)[:, :T].astype(dtype), final
 
 
 def ssd_step(
     state: jax.Array,  # [B, H, P, N] float32
     x: jax.Array,  # [B, H, P]
-    dt: jax.Array,  # [B, H]
+    dt: jax.Array,  # [B | 1, H]
     A: jax.Array,  # [H]
     B: jax.Array,  # [B, G, N]
     C: jax.Array,  # [B, G, N]
-    D: jax.Array,  # [H]
+    D: Optional[jax.Array],  # [H]; None = no skip
 ) -> Tuple[jax.Array, jax.Array]:
     """One token of the recurrence: ``(y [B, H, P], new state)``, float32
     throughout (the step is bound by reading and writing the state)."""
@@ -126,7 +135,9 @@ def ssd_step(
         state = state * decay[:, :, None, None] + (
             (dt[..., None] * xf)[..., None] * Bh[:, :, None, :]
         )
-        y = jnp.einsum("bhpn,bhn->bhp", state, Ch) + D.astype(F32)[:, None] * xf
+        y = jnp.einsum("bhpn,bhn->bhp", state, Ch)
+        if D is not None:
+            y = y + D.astype(F32)[:, None] * xf
         return y.astype(x.dtype), state
 
 

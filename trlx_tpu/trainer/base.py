@@ -39,7 +39,9 @@ from trlx_tpu.models.builder import (
     is_frozen,
     trainable_mask,
 )
-from trlx_tpu.models.transformer import cache_slots, make_kv_cache, selected_frac, sparse_gather_rows
+from trlx_tpu.models.transformer import (
+    block_selected_pairs, block_selected_steps, cache_slots, make_kv_cache, selected_frac, sparse_gather_rows,
+)
 from trlx_tpu.ops.sampling import (
     GenerationConfig,
     GenerationOutput,
@@ -533,6 +535,7 @@ class TPUBaseTrainer(BaseRLTrainer):
         self._emergency_resume = False
         self._prompt_chunks_drawn = 0
         self._triage_dumps = 0
+        self._triage_fns: Optional[Tuple[Callable, Callable]] = None  # ppo.py::_triage_programs
         # the sink's totals where the host gap began (tracing.mark): a step
         # record carries the difference to its own fence
         self._step_mark: Optional[Dict[str, float]] = None
@@ -867,7 +870,7 @@ class TPUBaseTrainer(BaseRLTrainer):
         if not layouts or not width:
             return 1.0, 0.0, 0.0
         block_q, block_k = choose_blocks(width, width)
-        pairs = [block_pairs_visited(width, layout.window, block_q, block_k) for layout in layouts]
+        pairs = [block_pairs_visited(width, layout.window, block_q, block_k) for layout in layouts if layout.mixer == "attention"]
         visited, causal, interior = (sum(p[i] for p in pairs) for i in range(3))
         return visited / max(causal, 1), float(block_k), interior / max(visited, 1)
 
@@ -934,7 +937,8 @@ class TPUBaseTrainer(BaseRLTrainer):
                 f"{self.iter_count} (injected straggler)"
             )
             _sleep(SLEEP_FAULT_S)
-        if self._train_step_fn is None:
+        first_step = self._train_step_fn is None
+        if first_step:
             self._train_step_fn = self._build_train_step()
         if batch is self._last_batch_host:
             arrays = self._last_batch_sharded
@@ -947,6 +951,8 @@ class TPUBaseTrainer(BaseRLTrainer):
             self._last_batch_host = batch
             self._last_batch_sharded = arrays
         self.state, stats = self._train_step_fn(self.state, arrays, self._loss_scale())
+        if first_step:  # set-up: the device is on its first step meanwhile
+            self._warm_triage(batch)
         # recompile watchdog: a warm train step retracing (shape/dtype
         # drift) is invisible otherwise — it just gets slow. The first
         # compile of a shape the trainer's pad policy planned is expected
@@ -1494,7 +1500,11 @@ class TPUBaseTrainer(BaseRLTrainer):
         per-sequence state side by side by leaf name:
         ``rollout/kv_cache_bytes`` (``k``, ``v``) and
         ``rollout/ssm_state_bytes`` (``ssm``, ``conv``; 0 for a KV-only
-        model), and where the layers cache a latent in place of K and V
+        model), ``rollout/linear_state_bytes`` where layers hold a linear
+        recurrence's ``state`` as their whole cache, ``rollout/kbar_cache_bytes``
+        and ``rollout/attn_block_selected_frac`` where attention runs under a
+        block selection (the compressed keys; chosen blocks over causal
+        blocks, mean over the decode steps of an unpadded row), and where the layers cache a latent in place of K and V
         ``rollout/latent_cache_bytes`` (``ckv``, ``k_rope``, or the two in
         one leaf ``latent`` on a layer under a selection; K and V then 0)
         with ``rollout/index_cache_bytes`` beside it (``k_index``, the index
@@ -1509,7 +1519,9 @@ class TPUBaseTrainer(BaseRLTrainer):
         self.last_kv_extents = self.last_kv_layers = None
         if self.is_seq2seq:
             return  # T5 cross/self caches have their own layout; not gauged
-        from trlx_tpu.ops.paged_kv import index_cache_bytes, kv_bytes, latent_cache_bytes, recurrent_state_bytes
+        from trlx_tpu.ops.paged_kv import (
+            index_cache_bytes, kv_bytes, latent_cache_bytes, linear_state_bytes, pooled_key_bytes, recurrent_state_bytes,
+        )
 
         B, P = prompt_shape
         S = P + gen_config.max_new_tokens
@@ -1533,11 +1545,18 @@ class TPUBaseTrainer(BaseRLTrainer):
         state = recurrent_state_bytes(policy_cache)
         latent = latent_cache_bytes(policy_cache)
         index = index_cache_bytes(policy_cache)
+        linear = linear_state_bytes(policy_cache)
+        pooled = pooled_key_bytes(policy_cache)
         total = kv_bytes(policy_cache) - state
         self.last_cache_stats = {
-            "rollout/kv_cache_bytes": float(total - latent - index),
-            "rollout/ssm_state_bytes": float(state),
+            "rollout/kv_cache_bytes": float(total - latent - index - pooled),
+            "rollout/ssm_state_bytes": float(state - linear),
         }
+        if linear:
+            self.last_cache_stats["rollout/linear_state_bytes"] = float(linear)
+        if pooled:  # attention layers under a block selection: the compressed keys, and the blocks a step keeps
+            self.last_cache_stats["rollout/kbar_cache_bytes"] = float(pooled)
+            self.last_cache_stats["rollout/attn_block_selected_frac"] = block_selected_steps(P, gen_config.max_new_tokens, self.tcfg)
         if latent:  # the layers cache a latent in place of K and V, and index keys with it
             self.last_cache_stats["rollout/latent_cache_bytes"] = float(latent)
             self.last_cache_stats["rollout/index_cache_bytes"] = float(index)
@@ -1546,14 +1565,14 @@ class TPUBaseTrainer(BaseRLTrainer):
             self.last_cache_stats["rollout/sparse_gather_rows"] = float(sparse_gather_rows(self.tcfg, S))
         if not self.tcfg.scan_layers:
             layouts = self.tcfg.layer_layouts
-            self.last_kv_layers = tuple(
+            self.last_kv_layers = tuple(  # (a lightning layer has no slots to read)
                 (int(cache_slots(layer)), layout.window is not None)
-                for layer, layout in zip(policy_cache, layouts)
+                for layer, layout in zip(policy_cache, layouts) if cache_slots(layer)
             )
             if len({layout.window for layout in layouts}) > 1:  # window layers beside global ones
                 window = sum(
                     kv_bytes({"k": layer["k"], "v": layer["v"]})
-                    for layer, layout in zip(policy_cache, layouts) if layout.window is not None
+                    for layer, layout in zip(policy_cache, layouts) if layout.window is not None and "k" in layer
                 )
                 self.last_cache_stats["rollout/kv_cache_window_bytes"] = float(window)
                 self.last_cache_stats["rollout/kv_cache_global_bytes"] = float(total - window)
@@ -1891,6 +1910,23 @@ class TPUBaseTrainer(BaseRLTrainer):
         per-token logprob deltas). Must not raise past its own best effort."""
         return {}
 
+    def _warm_triage(self, batch: Any) -> None:
+        """Subclass hook, called once at the first optimizer step: a trainer
+        whose every run reaches :meth:`_dump_triage` builds the programs of
+        its :meth:`_triage_extra` here, in set-up, not at the trip."""
+
+    def _triage_rows(self, batch: Any) -> Dict[str, np.ndarray]:
+        """The first ``TRIAGE_MAX_ROWS`` rows of every array of a batch."""
+        if hasattr(batch, "_asdict"):
+            batch = batch._asdict()
+        if not isinstance(batch, dict):
+            return {}
+        return {
+            key: np.asarray(value[:TRIAGE_MAX_ROWS])
+            for key, value in batch.items()
+            if hasattr(value, "shape") and getattr(value, "ndim", 0) > 0
+        }
+
     def _dump_triage(self, reason: str, stats: Dict[str, Any]) -> Optional[str]:
         """Write the current (memoized) batch as ``triage/step<N>.npz`` so a
         bad update is reproducible offline — tokens, masks, and whatever the
@@ -1902,18 +1938,10 @@ class TPUBaseTrainer(BaseRLTrainer):
         if jax.process_index() != 0:
             return None
         directory = self.obs._trace_dir
-        batch = self._last_batch_host
-        if hasattr(batch, "_asdict"):
-            batch = batch._asdict()
-        if not directory or not isinstance(batch, dict):
-            return None
-        if self._triage_dumps >= TRIAGE_MAX_DUMPS:
+        if not directory or self._triage_dumps >= TRIAGE_MAX_DUMPS:
             return None
         try:
-            arrays: Dict[str, np.ndarray] = {}
-            for key, value in batch.items():
-                if hasattr(value, "shape") and getattr(value, "ndim", 0) > 0:
-                    arrays[key] = np.asarray(value[:TRIAGE_MAX_ROWS])
+            arrays = self._triage_rows(self._last_batch_host)
             if not arrays:
                 return None
             try:
@@ -2206,6 +2234,9 @@ class TPUBaseTrainer(BaseRLTrainer):
                     ) = self._attn_tile_walk(width)
                     if getattr(self.tcfg, "index_topk", 0):  # every layer attends under the selection
                         stats["learn/attn_selected_frac"] = selected_frac(width, self.tcfg.index_topk)
+                    if getattr(self.tcfg, "sparse_topk", 0):  # the attention layers' pairs under the block selection
+                        chosen, causal = block_selected_pairs(width, self.tcfg)
+                        stats["learn/attn_block_selected_frac"] = chosen / max(causal, 1.0)
                     batch_size = next(
                         v.shape[0] for v in batch.values() if hasattr(v, "shape")
                     ) if isinstance(batch, dict) else self.config.train.batch_size

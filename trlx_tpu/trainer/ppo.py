@@ -24,7 +24,7 @@ import os
 from collections import deque
 from contextlib import ExitStack
 from time import perf_counter
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -1663,6 +1663,47 @@ class PPOTrainer(TPUBaseTrainer):
             * len(self.train_dataloader),
         )
 
+    def _triage_programs(self) -> Tuple[Callable, Callable]:
+        """The two programs of :meth:`_triage_extra`, built once and kept, so
+        that a dump finds at a shape it has run at what it compiled there."""
+        if self._triage_fns is None:
+            module = self.module
+
+            @jax.jit
+            def response_logprobs(params, batch):
+                queries, responses = batch["query_tensors"], batch["response_tensors"]
+                Q, R = queries.shape[1], responses.shape[1]
+                out = module.apply(
+                    {"params": params},
+                    jnp.concatenate([queries, responses], axis=1),
+                    attention_mask=jnp.concatenate(
+                        [batch["query_mask"], batch["response_mask"]], axis=1
+                    ),
+                    logits_span=(Q - 1, Q + R - 1),
+                )
+                return logprobs_of_labels(out["logits"], responses)
+
+            self._triage_fns = (
+                jax.jit(self.config.method.get_advantages_and_returns),
+                response_logprobs,
+            )
+        return self._triage_fns
+
+    def _warm_triage(self, batch: Any) -> None:
+        """A fresh value head explains none of the returns, so
+        ``value_ev_collapse`` trips at the end of the health window of every
+        run with one, and its dump needs two programs: they are built here, at
+        the first optimizer step, on the rows a dump of this batch would hold.
+        A cycle of fewer steps than the window would otherwise compile them in
+        the middle of its second (PERF.md section 6, PR 49). A trainer with no
+        value head compiles them when something trips."""
+        if self.model_head != "value" or not self.obs._trace_dir or jax.process_index() != 0:
+            return
+        try:
+            self._triage_extra(self._triage_rows(batch))
+        except Exception:  # pragma: no cover - defensive: triage never stops a run
+            logger.warning("triage warm-up failed", exc_info=True)
+
     def _triage_extra(self, arrays: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
         """Derived per-token quantities for a triaged batch: GAE advantages/
         returns, plus the new-policy per-token logprob deltas from one
@@ -1674,9 +1715,10 @@ class PPOTrainer(TPUBaseTrainer):
         values = arrays.get("values")
         rewards = arrays.get("rewards")
         mask = arrays.get("response_mask")
+        advantages_and_returns, response_logprobs = self._triage_programs()
         try:
             if values is not None and rewards is not None and mask is not None:
-                adv, ret = jax.jit(self.config.method.get_advantages_and_returns)(
+                adv, ret = advantages_and_returns(
                     jnp.asarray(values),
                     jnp.asarray(rewards),
                     jnp.asarray(mask, jnp.float32),
@@ -1686,22 +1728,6 @@ class PPOTrainer(TPUBaseTrainer):
         except Exception:  # pragma: no cover - defensive, crash-path code
             pass
         tokens = ("query_tensors", "response_tensors", "query_mask", "response_mask")
-        module = self.module
-
-        @jax.jit
-        def response_logprobs(params, batch):
-            queries, responses = batch["query_tensors"], batch["response_tensors"]
-            Q, R = queries.shape[1], responses.shape[1]
-            out = module.apply(
-                {"params": params},
-                jnp.concatenate([queries, responses], axis=1),
-                attention_mask=jnp.concatenate(
-                    [batch["query_mask"], batch["response_mask"]], axis=1
-                ),
-                logits_span=(Q - 1, Q + R - 1),
-            )
-            return logprobs_of_labels(out["logits"], responses)
-
         try:
             if not self.is_seq2seq and all(k in arrays for k in tokens + ("logprobs",)):
                 new_logprobs = response_logprobs(
