@@ -44,6 +44,31 @@ def trlx_log_records():
         logger.removeHandler(handler)
 
 
+@pytest.fixture(scope="session", autouse=True)
+def _session_program_store(tmp_path_factory):
+    """What a module- or session-scoped fixture builds is built before any
+    test's own store exists: it goes to a store of this session (of this
+    worker), never to ``programs/`` under the shared compile cache."""
+    from trlx_tpu.utils import programs
+
+    root = str(tmp_path_factory.mktemp("session_programs"))
+    keep, programs.store_dir = programs.store_dir, lambda: root
+    yield
+    programs.store_dir = keep
+
+
+@pytest.fixture(autouse=True)
+def _own_program_store(tmp_path, monkeypatch):
+    """Every test keeps its compiled programs (``trlx_tpu/utils/programs.py``)
+    in a store of its own. JAX's compile cache above is ONE directory for every
+    session, worker and tree, and tests patch functions in place that no key
+    can see: a store under it would hand one test the program another
+    compiled. The compile cache itself stays shared, so the suite's time holds."""
+    from trlx_tpu.utils import programs
+
+    monkeypatch.setattr(programs, "store_dir", lambda: str(tmp_path / "programs"))
+
+
 # ---------------------------------------------------------------------------
 # leaked-thread / leaked-process sentinel
 # ---------------------------------------------------------------------------

@@ -251,6 +251,45 @@ def test_flash_kernels_keep_their_names_in_the_compiled_program(topo):
     _assert_the_benchmark_finds_both_kernels(text)
 
 
+def test_a_stored_programs_lowering_does_not_depend_on_who_called_it(topo):
+    """The program store's guard compares the StableHLO text a program was
+    compiled from with a fresh lowering's (``utils/programs.py::verify``). A
+    Mosaic kernel's serialized body carries its locations, and by default
+    frames of the jitted function's CALLERS among them, so the same program
+    lowered under a collection and under the guard differs in text (cell 10 on
+    the chip, PR 50) and holds the checkout's path. The store lowers without
+    the call stack: the text is then the program's alone."""
+    import base64
+    import re
+
+    from trlx_tpu.ops import flash_attention as fa
+    from trlx_tpu.utils import programs
+
+    def loss(q, k, v, m):
+        return fa.flash_attention(q, k, v, m, interpret=False).astype(jnp.float32).sum()
+
+    x, one = _s((B, T, H, D)), SingleDeviceSharding(topo.devices[0])
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+        (x, x, x, _s((B, T), jnp.float32)))
+
+    def lower(depth):
+        if depth:
+            return lower(depth - 1)
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*args).as_text()
+
+    def bodies(text):
+        return [base64.b64decode(b) for b in re.findall(r'body\\22: \\22([A-Za-z0-9+/=]*)', text)]
+
+    assert lower(0) != lower(3)  # the control: the callers are in the kernels' locations
+    assert any(b".py" in body for body in bodies(lower(0)))
+    with programs._no_call_stack():
+        shallow, deep = lower(0), lower(3)
+    assert shallow == deep and len(bodies(shallow)) >= 2
+    assert not any(b".py" in body or b"/" + b"root" in body for body in bodies(shallow))
+    assert jax.config.jax_traceback_in_locations_limit != 0  # and the limit is back
+
+
 def test_selected_flash_kernels_compile_and_keep_the_names(topo):
     """Under a selection (``flash_attention(..., selection=)``) the forward
     and the fused backward are kernels of their own with an int8 tile of the

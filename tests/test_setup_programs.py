@@ -100,7 +100,7 @@ def test_one_init_program_serves_every_seed(monkeypatch):
 
     texts = []
 
-    def lowered_only(make_params, seed, mesh, abstract, load_backbone=None):
+    def lowered_only(make_params, seed, mesh, abstract, load_backbone=None, stored=None):
         texts.append(jax.jit(make_params).lower(np.int64(seed)).as_text())
         return jax.eval_shape(make_params, np.int64(seed))
 

@@ -55,6 +55,12 @@ JIT_WRAPPERS = {
     "jax.experimental.shard_map.shard_map",
 }
 PARTIAL_NAMES = {"functools.partial", "partial"}
+# the program store's constructors (``trlx_tpu/utils/programs.py``): a
+# ``jax.jit`` kept from one start to the next, called as
+# ``stored_program(name, fn, key_parts, ...)`` or, through a job's store,
+# ``<...>.programs.program(name, fn, *parts, ...)``: the function is the
+# SECOND argument, the jit keywords ride the call as they do ``jax.jit``'s
+STORED_JIT = "trlx_tpu.utils.programs.stored_program"
 
 # canonical dotted names whose `target=` keyword starts a new thread of
 # control (the thread-root constructors the escape analysis keys on)
@@ -624,6 +630,19 @@ class CallGraph:
     def is_jit_name(self, dotted: Optional[str]) -> bool:
         return dotted in JIT_WRAPPERS
 
+    def jit_call(self, call: ast.Call, scope, mod) -> Optional[Tuple[str, Optional[ast.AST]]]:
+        """``(wrapper's name, the expression of the function it traces)`` for a
+        call that opens a trace (a ``JIT_WRAPPERS`` name, or the program
+        store's constructors, whose function is the second argument), else
+        ``None``; the expression is ``None`` where the call names no function."""
+        chain = attr_chain(call.func) or []
+        if chain[-1:] == ["stored_program"] or chain[-2:] == ["programs", "program"]:
+            return STORED_JIT, call.args[1] if len(call.args) > 1 else None
+        name = self.external_name(call.func, scope, mod)
+        if not self.is_jit_name(name):
+            return None
+        return name, call.args[0] if call.args else None
+
     def _jit_kwargs(self, call: ast.Call) -> Tuple[Tuple[int, ...], Tuple[int, ...]]:
         static = donate = ()
         for kw in call.keywords:
@@ -669,12 +688,9 @@ class CallGraph:
                 if not isinstance(node, ast.Call):
                     continue
                 scope = self.enclosing_function(mod, node)
-                name = self.external_name(node.func, scope, mod)
-                if not self.is_jit_name(name):
+                name, target = self.jit_call(node, scope, mod) or (None, None)
+                if target is None:
                     continue
-                if not node.args:
-                    continue
-                target = node.args[0]
                 static, donate = self._jit_kwargs(node)
                 if isinstance(target, ast.Lambda):
                     for fn in self.functions:

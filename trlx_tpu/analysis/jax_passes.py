@@ -453,10 +453,11 @@ class DonationSafetyPass(LintPass):
     # -- which callables donate -----------------------------------------
 
     def _jit_donate(self, graph: CallGraph, node: ast.AST, scope, mod) -> Tuple[int, ...]:
-        """donate_argnums of a ``jax.jit(...)`` expression (else ())."""
+        """donate_argnums of a ``jax.jit(...)`` expression, or of a stored
+        program's constructor (else ())."""
         if not isinstance(node, ast.Call):
             return ()
-        if not graph.is_jit_name(graph.external_name(node.func, scope, mod)):
+        if graph.jit_call(node, scope, mod) is None:
             return ()
         _, donate = graph._jit_kwargs(node)
         return donate

@@ -131,7 +131,7 @@ def _import_hf_backbone(params, head, backbone_numpy, param_dtype):
     return params
 
 
-def _build_params(make_params, seed: int, mesh, abstract: bool, load_backbone=None):
+def _build_params(make_params, seed: int, mesh, abstract: bool, load_backbone=None, stored=None):
     """``make_params(seed)`` (initializers, target-Q sync; the dummy forward
     that ``module.init`` traces is dead code, pruned before lowering) as ONE
     jitted program whose outputs are born under ``param_shardings`` when a
@@ -140,19 +140,39 @@ def _build_params(make_params, seed: int, mesh, abstract: bool, load_backbone=No
     program serves every seed (a constant would be a new program, and a new
     compile, a seed). ``abstract`` stops at the shapes (the trace they cost
     is the one ``jit`` then finds in its cache). ``load_backbone(params)``
-    overlays pretrained host arrays, which are then placed leaf by leaf."""
+    overlays pretrained host arrays, which are then placed leaf by leaf.
+    ``stored(name, fn, jit_kwargs)`` makes the program one of a job's stored
+    ones (``utils/programs.py``): the shapes, which ``out_shardings`` needs, are
+    then traced on a miss only."""
     # an int64 scalar becomes the int32 that ``PRNGKey(<Python int>)`` makes
     seed = np.int64(seed)
-    shapes = jax.eval_shape(make_params, seed)
     if abstract:
-        return shapes
-    out_shardings = param_shardings(shapes, mesh) if mesh is not None else None
-    params = jax.jit(make_params, out_shardings=out_shardings)(seed)
+        return jax.eval_shape(make_params, seed)
+
+    def jit_kwargs():
+        if mesh is None:
+            return {}
+        return {"out_shardings": param_shardings(jax.eval_shape(make_params, seed), mesh)}
+
+    if stored is None:
+        params = jax.jit(make_params, **jit_kwargs())(seed)
+    else:
+        params = stored("make_params", make_params, jit_kwargs)(seed)
     if load_backbone is not None:
         params = load_backbone(params)
         if mesh is not None:
             params = shard_params(params, mesh)
     return params
+
+
+def _stored_by(programs, module, cfg, head, two_qs):
+    """``_build_params``'s ``stored`` for a job's ``ProgramStore``: the program
+    is kept by the module's classes and the model's resolved config."""
+    if programs is None:
+        return None
+    programs.extend(classes=type(module).__mro__)
+    return lambda name, fn, jit_kwargs: programs.program(
+        name, fn, repr(cfg), head, two_qs, jit_kwargs=jit_kwargs, once=True)
 
 
 def resolve_transformer_config(
@@ -184,6 +204,7 @@ def build_causal_lm(
     seed: int = 0,
     abstract: bool = False,
     mesh: Optional[Any] = None,
+    programs: Optional[Any] = None,
 ) -> Tuple[Any, Dict[str, Any], TransformerConfig]:
     """Build module + params. Pretrained weights (HF torch) replace the
     backbone subtree; heads stay freshly initialized. With a ``mesh`` the
@@ -192,7 +213,8 @@ def build_causal_lm(
     ``abstract=True`` returns a ``ShapeDtypeStruct`` pytree instead of real
     arrays (and skips any pretrained-weight load): enough to lower/compile
     the training programs for cost/memory analysis without materializing a
-    multi-GB model (``trlx_tpu/perf.py``)."""
+    multi-GB model (``trlx_tpu/perf.py``). ``programs`` is the job's
+    ``ProgramStore``: ``make_params`` is then kept by the model's config."""
     tcfg, hf_path = resolve_transformer_config(model_config, parallel)
 
     if head == "value":
@@ -225,7 +247,8 @@ def build_causal_lm(
         return _import_hf_backbone(params, head, backbone, tcfg.param_dtype)
 
     params = _build_params(
-        make_params, seed, mesh, abstract, load_backbone if hf_path is not None else None
+        make_params, seed, mesh, abstract, load_backbone if hf_path is not None else None,
+        _stored_by(programs, module, tcfg, head, two_qs),
     )
     return module, params, tcfg
 
@@ -436,6 +459,7 @@ def build_seq2seq_lm(
     seed: int = 0,
     abstract: bool = False,
     mesh: Optional[Any] = None,
+    programs: Optional[Any] = None,
 ):
     """Build seq2seq module + params (pretrained backbone import, fresh heads).
 
@@ -472,7 +496,8 @@ def build_seq2seq_lm(
         return _import_hf_backbone(params, head, hf_params["backbone"], scfg.param_dtype)
 
     params = _build_params(
-        make_params, seed, mesh, abstract, load_backbone if hf_path is not None else None
+        make_params, seed, mesh, abstract, load_backbone if hf_path is not None else None,
+        _stored_by(programs, module, scfg, head, two_qs),
     )
     return module, params, scfg
 

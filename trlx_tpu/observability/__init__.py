@@ -208,16 +208,24 @@ class Observability:
             "setup/programs": d.get("runtime/programs", 0.0),
             "setup/cache_hits": d.get("runtime/cache_hits", 0.0),
             "setup/cache_misses": d.get("runtime/cache_misses", 0.0),
+            # the job's own programs (utils/programs.py): executables loaded
+            # without a trace, programs traced and written for the next start
+            "setup/store_hits": d.get("runtime/store_hits", 0.0),
+            "setup/store_misses": d.get("runtime/store_misses", 0.0),
+            "setup/store_load_s": d.get("runtime/store_load", 0.0),
+            "setup/store_write_s": d.get("runtime/store_write", 0.0),
             "setup/total_s": s.import_s + in_train,
         }
         gauges["setup/compile_load_s"] = gauges["setup/compile_s"] + gauges["setup/cache_load_s"]
+        asked = gauges["setup/store_hits"] + gauges["setup/store_misses"]
+        gauges["setup/store_hit_pct"] = 100.0 * gauges["setup/store_hits"] / asked if asked else 0.0
         for name, value in gauges.items():
             self.metrics.set_gauge(name, value)
         logger.info(
             "set-up %.1f s: import %.1f, build %.1f (init_model %.1f), first eval %.1f, "
             "first cycle %.1f; of these the runtime took trace+lower %.1f, compile %.1f, "
             "cache load %.1f (%d programs: %d from the cache, %d written to it, %d "
-            "compiled in all) and the collector %.1f\n%s",
+            "compiled in all; %d loaded without a trace in %.1f) and the collector %.1f\n%s",
             gauges["setup/total_s"], gauges["setup/import_s"], gauges["setup/build_s"],
             gauges["setup/init_model_s"], gauges["setup/first_eval_s"],
             gauges["setup/first_cycle_s"], gauges["setup/trace_lower_s"],
@@ -226,6 +234,7 @@ class Observability:
             # what a start that found its programs in the cache still compiled:
             # 0 when set-up runs nothing the cache does not keep
             gauges["setup/programs"] - gauges["setup/cache_hits"],
+            gauges["setup/store_hits"], gauges["setup/store_load_s"],
             gauges["setup/gc_pause_s"], tracing.programs_table(s.programs),
         )
 

@@ -44,6 +44,11 @@ process (:func:`install_sources`):
   events), ``runtime/cache_hits`` (executables loaded from the persistent
   cache) and ``runtime/cache_misses`` (compiled and written to it): set-up's
   account reads all three (``Observability.freeze_setup``);
+- the program store (``utils/programs.py``), which calls the sink itself:
+  ``runtime/store_load`` (seconds reading and loading a kept executable, with
+  its program's name) and ``runtime/store_write`` (digesting, serializing and
+  writing one after a miss), and the counts ``runtime/store_hits`` and
+  ``runtime/store_misses``. A hit raises none of the runtime's own events;
 - the interpreter, through ``gc.callbacks``: ``host/gc`` with the generation;
 - the fence: a fenced span knows ``dispatch`` (open to fence) from ``wait``;
 - the operating system, in :func:`mark` (the trainer takes one where a
@@ -218,7 +223,8 @@ def programs_table(before: Dict[str, Dict[str, float]], rows: int = 12) -> str:
     """The runtime's seconds by program since ``before`` (an earlier
     :func:`programs`): the ``rows`` costliest by name, the rest (the eager
     operations of ``init``, mostly) in one row."""
-    kinds = ("runtime/trace", "runtime/lower", "runtime/compile", "runtime/cache_load")
+    kinds = ("runtime/trace", "runtime/lower", "runtime/compile", "runtime/cache_load",
+             "runtime/store_load")
     table = []
     for fun, row in programs().items():
         old = before.get(fun, {})
@@ -229,8 +235,9 @@ def programs_table(before: Dict[str, Dict[str, float]], rows: int = 12) -> str:
     rest = table[rows:]
     if rest:
         table = table[:rows] + [
-            (f"{len(rest)} others", [sum(r[1][i] for r in rest) for i in range(5)])]
-    lines = [f"{'program':<32}{'compiled':>9}{'trace':>9}{'lower':>9}{'compile':>9}{'load':>9}"]
+            (f"{len(rest)} others", [sum(r[1][i] for r in rest) for i in range(6)])]
+    lines = [f"{'program':<32}{'compiled':>9}{'trace':>9}{'lower':>9}{'compile':>9}{'load':>9}"
+             f"{'store':>9}"]
     for fun, (n, *seconds) in table:
         lines.append(f"{fun[:31]:<32}{int(n):>9}" + "".join(f"{x:>9.3f}" for x in seconds))
     return "\n".join(lines)
@@ -288,8 +295,17 @@ def _on_runtime_event(event: str, **kw: Any) -> None:
     # fired inside the compile event the executable belongs to; like
     # runtime/programs, only an outermost compile's counts
     kind = _CACHE_COUNTS.get(event)
+    if kind == "runtime/cache_hits":
+        _RUNTIME.cache_hits = getattr(_RUNTIME, "cache_hits", 0) + 1
     if kind is not None and getattr(_RUNTIME, "depth", 0) <= 1:
         count(kind)
+
+
+def thread_cache_hits() -> int:
+    """Executables the persistent compile cache has given THIS thread so far:
+    the program store asks around a compile whether its executable was compiled
+    here or loaded (``utils/programs.py::_compile_and_write``)."""
+    return getattr(_RUNTIME, "cache_hits", 0)
 
 
 def _on_gc(phase: str, info: Dict[str, int]) -> None:

@@ -73,6 +73,7 @@ from trlx_tpu.utils.checkpoint import (
 from trlx_tpu.observability import Observability, Span, tracing, train_step_flops
 from trlx_tpu.observability import mfu as obs_mfu
 from trlx_tpu.resilience import UPDATE_OK_KEY, Resilience, TrainingPreempted
+from trlx_tpu.utils.programs import ProgramStore, array_bytes
 from trlx_tpu.utils.trackers import make_tracker
 
 logger = logging.get_logger(__name__)
@@ -365,6 +366,11 @@ class TPUBaseTrainer(BaseRLTrainer):
         self.obs = Observability(config)
         self.mesh = make_mesh(config.parallel)
         set_global_mesh(self.mesh)  # model code reads this for sequence-parallel ops
+        # the job's own programs, kept compiled from one start to the next by
+        # what the code can observe of the job (utils/programs.py)
+        self.programs = ProgramStore(
+            config, classes=(*type(self).__mro__, type(config.method)), mesh=self.mesh
+        )
         # NOTE: the global mesh is process-wide; entry points re-assert it so
         # two trainers in one process don't trace against each other's mesh
         with self.obs.span("setup/tokenizer"):
@@ -389,7 +395,9 @@ class TPUBaseTrainer(BaseRLTrainer):
                 seed=config.train.seed,
                 abstract=abstract_init,
                 mesh=self.mesh,
+                programs=self.programs,
             )
+            self.programs.extend(repr(self.tcfg), self.model_head, two_qs)
             self.param_mask = mask_fn(params, self.tcfg, config.model.num_layers_unfrozen)
             self.draft_module = self.draft_params = self.draft_tcfg = None
             # a model that publishes a next-token-prediction module (tcfg.mtp_layers)
@@ -443,7 +451,9 @@ class TPUBaseTrainer(BaseRLTrainer):
                     seed=config.train.seed + 1,
                     abstract=abstract_init,
                     mesh=self.mesh,
+                    programs=self.programs,
                 )
+                self.programs.extend(repr(self.draft_tcfg))
                 if self.draft_tcfg.vocab_size != self.tcfg.vocab_size:
                     raise ValueError(
                         f"draft vocab {self.draft_tcfg.vocab_size} != policy vocab "
@@ -475,23 +485,29 @@ class TPUBaseTrainer(BaseRLTrainer):
                 rollout_rng, state_rng = jax.random.split(jax.random.PRNGKey(seed))
                 return jnp.zeros((), jnp.int32), state_rng, rollout_rng
 
+            optimizer = self.optimizer
+
             def init_state(p, seed):
-                return (self.optimizer.init(p), *init_counters(seed))
+                return (optimizer.init(p), *init_counters(seed))
 
             # one program for everything of the state but the params: the
             # moments, the step counter and both rng streams. The seed is an
             # argument (models/builder.py::_build_params says why)
             seed = np.int64(config.train.seed)
-            opt_state = jax.eval_shape(self.optimizer.init, params)
             if abstract_init:
-                counters = jax.jit(init_counters, out_shardings=replicated)(seed)
+                opt_state = jax.eval_shape(optimizer.init, params)
+                counters = self.programs.program(
+                    "init_counters", init_counters, once=True, out_shardings=replicated)(seed)
             else:
-                opt_state, *counters = jax.jit(
-                    init_state,
-                    out_shardings=(
-                        _optimizer_state_shardings(self.mesh, params, opt_state),
+                # the moments' shapes, which their shardings follow, are
+                # traced where the program is (on a miss of the store)
+                opt_state, *counters = self.programs.program(
+                    "init_state", init_state, once=True,
+                    jit_kwargs=lambda: {"out_shardings": (
+                        _optimizer_state_shardings(
+                            self.mesh, params, jax.eval_shape(optimizer.init, params)),
                         replicated, replicated, replicated,
-                    ),
+                    )},
                 )(params, seed)
             step, state_rng, self._rollout_rng = counters
             self.state = TrainState(
@@ -811,10 +827,10 @@ class TPUBaseTrainer(BaseRLTrainer):
 
         if state_shardings is not None:
             # stats stay unspecified (None): XLA picks, as before
-            return jax.jit(
-                train_step, donate_argnums=(0,), out_shardings=(state_shardings, None)
+            return self.programs.program(
+                "train_step", train_step, donate_argnums=(0,), out_shardings=(state_shardings, None)
             )
-        return jax.jit(train_step, donate_argnums=(0,))
+        return self.programs.program("train_step", train_step, donate_argnums=(0,))
 
     def _drop_batch_memo(self) -> None:
         """Release the memoized sharded batch (one batch of HBM) once its
@@ -1197,8 +1213,16 @@ class TPUBaseTrainer(BaseRLTrainer):
                     )
 
             # the def's name is the program's (module `jit_rollout_generate`
-            # in a device trace): trace reductions match on it
-            self._generate_fns[key] = jax.jit(rollout_generate)
+            # in a device trace): trace reductions match on it. Kept by the memo
+            # key and the bytes of the mask the closure bakes in; a separate
+            # draft model's parameters are constants of the closure, which no
+            # key sees, and such a program is not kept (utils/programs.py)
+            if self.draft_module is not None and not self.is_seq2seq:
+                self._generate_fns[key] = jax.jit(rollout_generate)
+            else:
+                self._generate_fns[key] = self.programs.program(
+                    "rollout_generate", rollout_generate, repr(key), array_bytes(self.logit_mask)
+                )
         return self._generate_fns[key]
 
     def _resolve_gen_config(

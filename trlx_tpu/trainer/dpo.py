@@ -99,7 +99,7 @@ class DPOTrainer(TPUBaseTrainer):
             def ref_logps(p, ids, attn, out):
                 return _completion_logps(module, p, ids, attn, out, chunk)[0]
 
-            self._ref_logp_fn = jax.jit(ref_logps)
+            self._ref_logp_fn = self.programs.program("ref_logps", ref_logps, chunk)
         return self._ref_logp_fn
 
     def make_experience(self, samples: Sequence[Sequence[str]], seq_length: int) -> None:

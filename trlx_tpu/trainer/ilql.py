@@ -243,8 +243,8 @@ class ILQLTrainer(TPUBaseTrainer):
             raise ValueError("config.method must be ILQLConfig")
         self.ilql: ILQLConfig = config.method
         self.store: Optional[ILQLRolloutStorage] = None
-        self._sync_fn = jax.jit(
-            partial(sync_target_q_params, alpha=self.ilql.alpha)
+        self._sync_fn = self.programs.program(
+            "sync_target_q_params", partial(sync_target_q_params, alpha=self.ilql.alpha)
         )
 
     def make_experience(

@@ -175,7 +175,7 @@ class PPOTrainer(TPUBaseTrainer):
         def ref_snapshot(params):
             return jax.tree_util.tree_map(jnp.copy, extract(params))
 
-        return jax.jit(ref_snapshot)(self.state.params)
+        return self.programs.program("ref_snapshot", ref_snapshot, once=True)(self.state.params)
 
     # ------------------------------------------------------------------
     # rollout collection
@@ -362,7 +362,7 @@ class PPOTrainer(TPUBaseTrainer):
                     "ref_logprobs": ref_logprobs,
                 }
 
-            fn = jax.jit(score_fn)
+            fn = self.programs.program("score_fn", score_fn, batch_shape)
             self._score_fns[batch_shape] = fn
             return fn
 
@@ -419,7 +419,7 @@ class PPOTrainer(TPUBaseTrainer):
             out = jax.lax.map(lambda g: score_rows(params, ref_params, *g), tuple(map(split, rows)))
             return jax.tree_util.tree_map(lambda a: a.reshape(B, *a.shape[2:]), out)
 
-        fn = jax.jit(score_fn)
+        fn = self.programs.program("score_fn", score_fn, batch_shape)
         self._score_fns[batch_shape] = fn
         return fn
 
@@ -1669,7 +1669,6 @@ class PPOTrainer(TPUBaseTrainer):
         if self._triage_fns is None:
             module = self.module
 
-            @jax.jit
             def response_logprobs(params, batch):
                 queries, responses = batch["query_tensors"], batch["response_tensors"]
                 Q, R = queries.shape[1], responses.shape[1]
@@ -1684,8 +1683,9 @@ class PPOTrainer(TPUBaseTrainer):
                 return logprobs_of_labels(out["logits"], responses)
 
             self._triage_fns = (
-                jax.jit(self.config.method.get_advantages_and_returns),
-                response_logprobs,
+                self.programs.program(
+                    "get_advantages_and_returns", self.config.method.get_advantages_and_returns),
+                self.programs.program("response_logprobs", response_logprobs),
             )
         return self._triage_fns
 

@@ -46,7 +46,10 @@ def compile_cache_dir() -> Optional[str]:
     ``JAX_COMPILATION_CACHE_DIR`` places it from outside (JAX reads that
     variable itself, so nothing is set in code). The path is a function of
     the checkout alone — it is part of the cache key's directory, so a name
-    that moved (temp dir, pid, time) would never hit."""
+    that moved (temp dir, pid, time) would never hit. The program store
+    (``utils/programs.py``: the job's own programs, compiled, by a key that
+    needs no trace) shares the directory as ``programs/`` inside it, so
+    removing it still makes a start cold."""
     if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         return None
     checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
