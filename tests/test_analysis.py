@@ -1834,7 +1834,8 @@ def test_span_names_pass(tmp_path):
                     "also_bad", live=3                # multi-line call: caught
                 ):
                     pass
-                tracer.instant("bad_instant")
+                tracer.instant("not_a_span")          # no such method since PR 35: not scanned
+                tracer.add_complete_event("bad_event", 0.0, 1.0)
                 tracer.add_complete_event("engine/prefill", 0.0, 1.0)
                 with tracer.span(name):               # dynamic: out of scope
                     pass
@@ -1847,7 +1848,7 @@ def test_span_names_pass(tmp_path):
     assert [(f.code, f.detail) for f in findings] == [
         ("GL502", "bad span name"),
         ("GL502", "also_bad"),
-        ("GL502", "bad_instant"),
+        ("GL502", "bad_event"),
     ]
 
 

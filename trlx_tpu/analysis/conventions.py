@@ -9,7 +9,7 @@ call site must use a ``namespace/name`` key. ``LEGACY_KEYS`` is frozen;
 ``RESILIENCE_KEYS`` registers the canonical resilience counters the
 static scan can't see (parameterized helper emissions).
 
-``span-names`` (GL502) holds span/instant/complete-event names to the SAME
+``span-names`` (GL502) holds span and complete-event names to the SAME
 ``namespace/name`` rule: spans land in the same dashboards and merged
 multi-rank traces as metrics, so one naming convention covers both.
 ``LEGACY_SPAN_NAMES`` freezes the five pre-convention trainer spans
@@ -435,9 +435,9 @@ class MetricNamesPass(LintPass):
 # ---------------------------------------------------------------------------
 
 # call names whose first literal-string argument is a span/track name:
-# Tracer.span / Observability.span / module-level span(), Tracer.instant,
+# Tracer.span / Observability.span / module-level span(),
 # Tracer.add_complete_event, and the engine's injected `self._span` seam
-_SPAN_FUNCS = frozenset({"span", "_span", "instant", "add_complete_event"})
+_SPAN_FUNCS = frozenset({"span", "_span", "add_complete_event"})
 
 # Pre-convention trainer span names, kept for trace/dashboard continuity
 # (they predate the namespace rule and appear in every committed trace).

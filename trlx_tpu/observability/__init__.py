@@ -173,10 +173,12 @@ class Observability:
     def span(self, name: str, fence: Any = None, **args: Any):
         return self.tracer.span(name, fence=fence, **args)
 
-    def freeze_setup(self) -> None:
+    def freeze_setup(self, programs: Optional[Dict[str, Any]] = None) -> None:
         """The second collection begins: set-up is over. Freeze what it was
         made of as ``setup/*`` gauges, which every later step record
-        snapshots, and log the table of programs once. The phases tile the
+        snapshots, and log the table of programs once (``programs``: what the
+        job's own executables hold on the device, ``ProgramStore.account()``,
+        for the table's last four columns). The phases tile the
         time from ``trlx.train()`` to now: build (``train()`` to the first
         collection, and the eval pipeline and ``prepare_learning`` after
         it), the first evaluation, the first cycle (its collection, and its
@@ -235,7 +237,8 @@ class Observability:
             # 0 when set-up runs nothing the cache does not keep
             gauges["setup/programs"] - gauges["setup/cache_hits"],
             gauges["setup/store_hits"], gauges["setup/store_load_s"],
-            gauges["setup/gc_pause_s"], tracing.programs_table(s.programs),
+            gauges["setup/gc_pause_s"],
+            tracing.programs_table(s.programs, held=(programs or {}).get("by_program")),
         )
 
     def note_dropped_spans(self) -> None:

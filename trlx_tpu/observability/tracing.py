@@ -68,7 +68,7 @@ import time
 import weakref
 from collections import deque
 from contextlib import contextmanager
-from typing import Any, Callable, Deque, Dict, Iterator, List, Optional, Tuple, Union
+from typing import Any, Callable, Deque, Dict, Iterator, List, Optional, Sequence, Tuple, Union
 
 try:  # POSIX only; the thread's counters need Linux's RUSAGE_THREAD
     import resource
@@ -219,27 +219,42 @@ def programs() -> Dict[str, Dict[str, float]]:
         return {fun: dict(row) for fun, row in _PROGRAMS.items()}
 
 
-def programs_table(before: Dict[str, Dict[str, float]], rows: int = 12) -> str:
+def programs_table(before: Dict[str, Dict[str, float]], rows: int = 12,
+                   held: Optional[Dict[str, Sequence[float]]] = None) -> str:
     """The runtime's seconds by program since ``before`` (an earlier
     :func:`programs`): the ``rows`` costliest by name, the rest (the eager
-    operations of ``init``, mostly) in one row."""
+    operations of ``init``, mostly) in one row. Behind the seconds, for the
+    programs ``held`` names (``utils/programs.py::ProgramStore.account``'s
+    ``by_program``: the job's own store, walked now; blank for any other), what
+    their executables hold on the device: how many are resident, their code in
+    MiB, the largest temporaries and the largest arguments + outputs - aliased
+    in GiB. The row with the large temporaries sizes what the runtime reserves
+    on top of the allocator's peak."""
     kinds = ("runtime/trace", "runtime/lower", "runtime/compile", "runtime/cache_load",
              "runtime/store_load")
+    held = held or {}
     table = []
-    for fun, row in programs().items():
+    # (a held program is in the table even where the sink has no second of it)
+    for fun, row in {**dict.fromkeys(held, {}), **programs()}.items():
         old = before.get(fun, {})
         new = [row.get(k, 0.0) - old.get(k, 0.0) for k in ("programs",) + kinds]
-        if any(new):
-            table.append((fun, new))
+        if any(new) or fun in held:
+            table.append((fun, new, held.get(fun)))
     table.sort(key=lambda r: -sum(r[1][1:]))
     rest = table[rows:]
     if rest:
-        table = table[:rows] + [
-            (f"{len(rest)} others", [sum(r[1][i] for r in rest) for i in range(6)])]
+        kept = [r[2] for r in rest if r[2]]
+        table = table[:rows] + [(
+            f"{len(rest)} others", [sum(r[1][i] for r in rest) for i in range(6)],
+            [sum(h[0] for h in kept), sum(h[1] for h in kept), max(h[2] for h in kept),
+             max(h[3] for h in kept)] if kept else None)]
     lines = [f"{'program':<32}{'compiled':>9}{'trace':>9}{'lower':>9}{'compile':>9}{'load':>9}"
-             f"{'store':>9}"]
-    for fun, (n, *seconds) in table:
-        lines.append(f"{fun[:31]:<32}{int(n):>9}" + "".join(f"{x:>9.3f}" for x in seconds))
+             f"{'store':>9}{'resident':>9}{'code MiB':>9}{'temp GiB':>9}{'live GiB':>9}"]
+    for fun, (n, *seconds), row in table:
+        line = f"{fun[:31]:<32}{int(n):>9}" + "".join(f"{x:>9.3f}" for x in seconds)
+        if row:
+            line += f"{int(row[0]):>9}{row[1] / 2**20:>9.1f}{row[2] / 2**30:>9.3f}{row[3] / 2**30:>9.3f}"
+        lines.append(line)
     return "\n".join(lines)
 
 

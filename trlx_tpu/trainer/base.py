@@ -927,6 +927,13 @@ class TPUBaseTrainer(BaseRLTrainer):
         except Exception as e:  # pragma: no cover - defensive
             logger.warning(f"span trace export failed: {e}")
 
+    def _note_state_bytes(self) -> None:
+        """The memory account's state terms (docs/OBSERVABILITY.md "The memory
+        account"): host arithmetic over the trees' shapes, once when learning
+        is prepared and again when a restore replaces the state."""
+        self.obs.memory.note_state(
+            self.state.params, self.state.opt_state, getattr(self, "ref_params", None))
+
     def train_step(self, batch: Dict[str, np.ndarray]) -> Dict[str, float]:
         """One optimization step on a host batch; returns host scalar stats.
 
@@ -1477,23 +1484,28 @@ class TPUBaseTrainer(BaseRLTrainer):
             self.last_spec_stats = {}
             self._note_dense_kv_gauge(input_ids.shape, gen_config)
             out = engine.generate(batch["input_ids"], batch["attention_mask"], rng)
+            spec_stats = None
             if type(out) is tuple:  # speculative sampler: (output, stats) —
                 # GenerationOutput itself is a NamedTuple, hence the exact check
                 out, spec_stats = out
-                # recorded for make_experience's stats (rollout observability:
-                # the knob this informs is model.draft_gamma)
-                # device_get already lands host scalars; no asarray needed
-                spec_stats = jax.device_get(spec_stats)
-                self.last_spec_stats = {
-                    "rollout/spec_acceptance_rate": float(spec_stats["acceptance_rate"]),
-                    "rollout/spec_rounds": int(spec_stats["rounds"]),
-                    # on live rows: a row that has ended still runs its rounds, and counts in neither
-                    "rollout/draft_proposed": int(spec_stats["proposed_draft_tokens"]),
-                    "rollout/draft_accepted": int(spec_stats["accepted_draft_tokens"]),
-                    "rollout/spec_live_row_rounds": int(spec_stats["live_row_rounds"]),
-                    "rollout/tokens_per_round": float(spec_stats["tokens_per_round"]),
-                }
             sp.fence((out.sequences, out.response_tokens))
+        if spec_stats is not None:
+            # recorded for make_experience's stats (rollout observability:
+            # the knob this informs is model.draft_gamma). Read AFTER the
+            # fence has ended: landing the six scalars waits for the whole
+            # program, and inside the span that wait read as dispatch
+            # (generate_dispatch_ms 9578 in cell 9; PERF.md section 6, PR 51).
+            # device_get already lands host scalars; no asarray needed
+            spec_stats = jax.device_get(spec_stats)
+            self.last_spec_stats = {
+                "rollout/spec_acceptance_rate": float(spec_stats["acceptance_rate"]),
+                "rollout/spec_rounds": int(spec_stats["rounds"]),
+                # on live rows: a row that has ended still runs its rounds, and counts in neither
+                "rollout/draft_proposed": int(spec_stats["proposed_draft_tokens"]),
+                "rollout/draft_accepted": int(spec_stats["accepted_draft_tokens"]),
+                "rollout/spec_live_row_rounds": int(spec_stats["live_row_rounds"]),
+                "rollout/tokens_per_round": float(spec_stats["tokens_per_round"]),
+            }
         self.last_generate_span = sp
         self.obs.recompile.observe("generate", engine._fn)
         return out
@@ -1769,6 +1781,7 @@ class TPUBaseTrainer(BaseRLTrainer):
         set_global_mesh(self.mesh)
         logger.info("Starting training")
         self.prepare_learning()
+        self._note_state_bytes()
         self.maybe_resume()
         self._maybe_start_serving()
         try:
@@ -2274,7 +2287,7 @@ class TPUBaseTrainer(BaseRLTrainer):
                             ),
                         )
                     )
-                    stats.update(self.obs.memory.collect())
+                    stats.update(self.obs.memory.collect(self.programs.account()))
                     # feed the NEXT boundary's cluster beat (distributed
                     # telemetry) with this step's scalars, and surface the
                     # tracer's drop counter before the snapshot below
@@ -2467,6 +2480,7 @@ class TPUBaseTrainer(BaseRLTrainer):
         }
 
     def _close_cycle(self) -> None:
+        self.obs.memory.log_account()
         cycle, self._cycle = self._cycle, None
         if cycle is not None and "step gap" in cycle["parts"]:
             self._note_interval("cycle", None, f"cycle {cycle['n']}", cycle["seconds"],
@@ -2601,6 +2615,7 @@ class TPUBaseTrainer(BaseRLTrainer):
             elastic=self.resilience.config.elastic,
             metrics=self.obs.metrics,
         )
+        self._note_state_bytes()  # another mesh gives the leaves other shards
         extra = read_extra(directory)
         self.iter_count = int(extra.get("iter_count", 0))
         if "best_reward" in extra:

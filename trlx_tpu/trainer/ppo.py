@@ -1404,7 +1404,7 @@ class PPOTrainer(TPUBaseTrainer):
         }
         self._close_cycle()
         if self.obs.tracer.cycle == 1:  # the second collection: set-up is over
-            self.obs.freeze_setup()
+            self.obs.freeze_setup(self.programs.account())
         self.obs.tracer.next_cycle()
         with self.obs.span("collect/experience"):
             mark = tracing.mark()

@@ -44,7 +44,7 @@ import tempfile
 import threading
 import time
 from functools import lru_cache
-from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, Iterable, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
@@ -269,14 +269,48 @@ def _no_call_stack():
 # ---------------------------------------------------------------------------
 
 
-class _Held:
-    """One signature's executable, and what :func:`verify` needs of it."""
+class ProgramBytes(NamedTuple):
+    """What the compiler says one executable holds on a device
+    (``Compiled.memory_analysis()``, read once when the executable is made or
+    loaded: no trace and no compile). ``peak`` is the compiler's own
+    ``peak_memory_in_bytes`` where the runtime gives one above 0, else 0."""
 
-    __slots__ = ("call", "loaded", "digest", "path", "abstract")
+    code: int
+    arguments: int
+    outputs: int
+    aliased: int
+    temp: int
+    peak: int
+
+    @property
+    def live(self) -> int:
+        """Bytes of buffers a run touches: arguments, and the outputs that are
+        no donated argument's buffer."""
+        return self.arguments + self.outputs - self.aliased
+
+    @classmethod
+    def of(cls, compiled: Any) -> Optional["ProgramBytes"]:
+        try:
+            m = compiled.memory_analysis()
+            return cls(int(m.generated_code_size_in_bytes), int(m.argument_size_in_bytes),
+                       int(m.output_size_in_bytes), int(m.alias_size_in_bytes),
+                       int(m.temp_size_in_bytes),
+                       max(int(getattr(m, "peak_memory_in_bytes", 0) or 0), 0))
+        except Exception as e:  # a runtime without the analysis: the account has no row
+            logger.debug("program store: no memory analysis (%s: %s)", type(e).__name__, e)
+            return None
+
+
+class _Held:
+    """One signature's executable, what :func:`verify` needs of it, and its
+    row of bytes (``None`` where the runtime has no memory analysis)."""
+
+    __slots__ = ("call", "loaded", "digest", "path", "abstract", "bytes")
 
     def __init__(self, call, loaded, digest, path, abstract):
         self.call, self.loaded, self.digest, self.path, self.abstract = (
             call, loaded, digest, path, abstract)
+        self.bytes = ProgramBytes.of(call)
 
 
 class StoredProgram:
@@ -480,6 +514,12 @@ class StoredProgram:
     def loaded(self) -> int:
         return sum(h.loaded for h in self._held.values())
 
+    def rows(self) -> Tuple[List[ProgramBytes], bool]:
+        """The rows of the executables this program holds now (a ``once``
+        program's has gone with its call), and whether it ever had one."""
+        held = [h for h in list(self._held.values()) if h.bytes is not None]
+        return [h.bytes for h in held if h.call is not None], bool(held)
+
 
 def _abstract(x: Any) -> Any:
     """A leaf's twin for a later ``lower``: no buffer is kept alive."""
@@ -550,6 +590,33 @@ class ProgramStore:
 
     def loaded(self) -> int:
         return sum(p.loaded() for p in self._programs)
+
+    def account(self) -> Optional[Dict[str, Any]]:
+        """What the job's executables hold on a device now. ``by_program``:
+        name -> executables resident, their code bytes summed, the largest
+        temporaries and the largest arguments + outputs - aliased among them
+        (one name can hold several signatures and several sites); ``code`` and
+        ``resident`` are its sums, ``temp`` its largest temporaries, of
+        ``temp_program``, with the compiler's own peak of that executable.
+        ``None`` where no program of the job ever had a row (the plain
+        ``jax.jit`` path: no store directory, several processes, a class
+        without a source file)."""
+        by_program: Dict[str, List[int]] = {}
+        out: Dict[str, Any] = {"temp": 0, "temp_program": "", "temp_program_peak": 0}
+        for p in self._programs:
+            rows, ever = p.rows()
+            if not ever:
+                continue
+            mine = by_program.setdefault(p.name, [0, 0, 0, 0])
+            for row in rows:
+                mine[:] = mine[0] + 1, mine[1] + row.code, max(mine[2], row.temp), max(mine[3], row.live)
+                if row.temp >= out["temp"]:
+                    out.update(temp=row.temp, temp_program=p.name, temp_program_peak=row.peak)
+        if not by_program:
+            return None
+        out.update(by_program=by_program, resident=sum(r[0] for r in by_program.values()),
+                   code=sum(r[1] for r in by_program.values()))
+        return out
 
 
 def verify(trainer: Any) -> int:
