@@ -37,7 +37,7 @@ COLLECTION_KEYS = {
 }
 NEW_SPANS = {
     "collect/experience", "collect/prompts", "collect/finalize",
-    "learn/loader", "learn/step_host",
+    "learn/loader", "learn/step_host", "learn/land",
 }
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NEW_LAYER_METRICS = (
@@ -193,10 +193,15 @@ def test_step_records_carry_gap_and_padding(run):
     assert [r["learn/step_width"] for r in steps] == [24.0, 24.0]
     assert steps[-1]["learn/step_shapes"] == 1.0 and "recompile/train_step" not in steps[-1]
     # with time/train_step the gap tiles the learn phase: the second step's
-    # gap is the host time from the first step's fence to its own span
+    # gap is the host time from the first step's fence to its own launch. The
+    # fence is the landing's (`learn/land` opens with it), and the job's
+    # first step lands before anything else is launched
     first, second = sorted(_spans(run, "train_step"), key=lambda e: e["ts"])
+    landed, _ = sorted(_spans(run, "learn/land"), key=lambda e: e["ts"])
+    assert first["ts"] + first["dur"] <= landed["ts"] <= second["ts"]
     assert steps[1]["time/step_gap"] == pytest.approx(
-        (second["ts"] - first["ts"] - first["dur"]) * 1e-6, abs=1e-6)
+        (second["ts"] - landed["ts"]) * 1e-6 - landed["args"]["wait_s"], abs=2e-4)
+    assert [r["learn/ahead"] for r in steps] == [0.0, 0.0]
 
 
 def test_known_padding_gives_the_known_fraction(run):

@@ -410,7 +410,7 @@ def attributed_run(request, tmp_path_factory):
         trainer.tracker = recorder
         train_step, calls = trainer.train_step, []
 
-        def slowed(batch):
+        def slowed(batch, **kw):
             calls.append(time.perf_counter())
             if len(calls) == SLOW_STEP:
                 # thirty of the cycles this machine has run since the compiles
@@ -419,7 +419,7 @@ def attributed_run(request, tmp_path_factory):
                 # deaf to a burst of load
                 windows = sorted(calls[i] - calls[i - 2] for i in range(4, SLOW_STEP, 2))
                 time.sleep(min(max(3.0, 30 * windows[len(windows) // 2]), 40.0))
-            return train_step(batch)
+            return train_step(batch, **kw)
 
         trainer.train_step = slowed
 
@@ -463,8 +463,10 @@ def test_every_record_carries_its_attribution(attributed_run):
             r["time/generate"], rel=1e-9)
     for r in steps:
         assert STEP_RECORD_KEYS <= set(r), sorted(STEP_RECORD_KEYS - set(r))
-        assert r["time/train_step_dispatch"] + r["time/train_step_wait"] == pytest.approx(
-            r["time/train_step"], rel=1e-9)
+        # the launch and the landing's fence; between them the host lands the
+        # step before (a step launched ahead) or launches the one after
+        assert r["time/train_step_dispatch"] > 0.0
+        assert 0.0 <= r["time/train_step_wait"] <= r["time/train_step"]
     # one width, one program: only the first step of the shape traces
     assert steps[0]["runtime/retrace_s"] > 0 and steps[0]["runtime/compile_s"] > 0
     assert [r["runtime/retrace_s"] for r in steps[1:]] == [0.0] * (2 * CYCLES - 1)
@@ -494,7 +496,8 @@ def test_setup_is_under_spans_and_frozen_at_the_second_collection(attributed_run
         assert {k: r[k] for k in SETUP_GAUGES} == frozen
     total = frozen["setup/total_s"]
     phases = ("setup/import_s", "setup/build_s", "setup/first_eval_s", "setup/first_cycle_s")
-    assert all(0 <= frozen[k] <= total for k in SETUP_GAUGES - {"setup/programs"})
+    counts = {"setup/programs", "setup/cache_hits", "setup/cache_misses"}  # not seconds
+    assert all(0 <= frozen[k] <= total for k in SETUP_GAUGES - counts)
     assert sum(frozen[k] for k in phases) == pytest.approx(total, rel=1e-9)  # they tile it
     assert frozen["setup/init_model_s"] <= frozen["setup/build_s"]
     assert frozen["setup/compile_load_s"] == frozen["setup/compile_s"] + frozen["setup/cache_load_s"]

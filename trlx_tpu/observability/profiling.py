@@ -91,10 +91,18 @@ class ProfileWindow:
     def enabled(self) -> bool:
         return self.start is not None
 
+    def starts_at(self, step: int) -> bool:
+        """Whether ``on_step_start(step)`` would open the trace."""
+        return (self.enabled and not self.active and not self._done
+                and self.start <= step <= self.stop_step)
+
+    def stops_at(self, step: int) -> bool:
+        """Whether ``on_step_end(step)`` would close it. The learn loop has
+        no other step in flight at either edge: the trace holds whole steps."""
+        return self.active and step >= self.stop_step
+
     def on_step_start(self, step: int) -> None:
-        if not self.enabled or self.active or self._done:
-            return
-        if self.start <= step <= self.stop_step:
+        if self.starts_at(step):
             import jax
 
             logger.info(
@@ -105,7 +113,7 @@ class ProfileWindow:
             self.active = True
 
     def on_step_end(self, step: int) -> None:
-        if self.active and step >= self.stop_step:
+        if self.stops_at(step):
             self.stop()
             self._done = True
 

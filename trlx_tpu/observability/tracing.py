@@ -393,7 +393,7 @@ def uninstall_sources() -> None:
 class Span:
     """One timed region. ``duration`` is valid after the span closes."""
 
-    __slots__ = ("name", "depth", "args", "t0", "t1", "t_fence", "_fence", "attributed")
+    __slots__ = ("name", "depth", "args", "t0", "t1", "t_fence", "t_ready", "_fence", "attributed")
 
     def __init__(self, name: str, depth: int, args: Optional[Dict[str, Any]] = None):
         self.name = name
@@ -401,6 +401,7 @@ class Span:
         self.args = args or {}
         self.t1: Optional[float] = None
         self.t_fence: Optional[float] = None  # when the fence began
+        self.t_ready: Optional[float] = None  # when it returned
         self._fence: FenceLike = None
         # kind -> seconds attributed to this span while it was the innermost
         # one open on its thread (allocated on first use)
@@ -412,6 +413,14 @@ class Span:
         self._fence = tree
         return self
 
+    def block(self, tree: FenceLike) -> float:
+        """Fence on ``tree`` now, inside the span, which goes on (the learn
+        loop's landing: its record follows its fence); when it returned."""
+        self.t_fence = time.perf_counter()
+        _block(tree() if callable(tree) else tree)
+        self.t_ready = time.perf_counter()
+        return self.t_ready
+
     @property
     def duration(self) -> float:
         """Seconds, device-fenced if a fence was set. 0.0 while open."""
@@ -421,13 +430,13 @@ class Span:
     def wait(self) -> float:
         """Seconds the host spent in the fence with nothing left to do; 0.0
         without a fence."""
-        return (self.t1 - self.t_fence) if self.t_fence is not None and self.t1 is not None else 0.0
+        return (self.t_ready - self.t_fence) if self.t_ready is not None else 0.0
 
     @property
     def dispatch(self) -> float:
-        """Seconds from the span's opening to its fence: the host was still
-        setting up, placing arguments, enqueueing, or in anything
-        ``attributed`` names. ``dispatch + wait == duration``."""
+        """Seconds of the span outside its fence: the host was still setting
+        up, placing arguments, enqueueing, or in anything ``attributed``
+        names. ``dispatch + wait == duration``."""
         return self.duration - self.wait
 
     def add(self, kind: str, seconds: float) -> None:
@@ -436,10 +445,7 @@ class Span:
         self.attributed[kind] = self.attributed.get(kind, 0.0) + seconds
 
     def close(self) -> float:
-        if self._fence is not None:
-            self.t_fence = time.perf_counter()
-            _block(self._fence() if callable(self._fence) else self._fence)
-        self.t1 = time.perf_counter()
+        self.t1 = self.block(self._fence) if self._fence is not None else time.perf_counter()
         return self.duration
 
 

@@ -177,6 +177,12 @@ class FaultPlan:
     def __bool__(self) -> bool:
         return bool(self.specs)
 
+    def due(self, step: int) -> bool:
+        """Whether any entry triggers on update ``step``, of whatever kind
+        (nothing advances, nothing fires): the learn loop launches such a
+        step with nothing else in flight."""
+        return any(s.trigger == "step" and s.matches(step) for s in self.specs)
+
     def poll(
         self,
         kind: str,

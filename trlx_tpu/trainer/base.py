@@ -16,12 +16,13 @@ at the host boundary (reward/metric fns), and per-rank scatter is
 ``shard_batch`` placement.
 """
 
+import dataclasses
 import json
 import os
 from abc import abstractmethod
 import statistics
 from collections import deque
-from contextlib import ExitStack
+from contextlib import ExitStack, closing
 from time import perf_counter, time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -108,6 +109,66 @@ SLOW_MIN_HISTORY = 3
 
 # parts of an interval in which the host waits for the device
 _WAIT_PARTS = ("generate wait", "score wait", "train_step wait")
+
+
+@dataclasses.dataclass
+class _Flight:
+    """A train step between its launch and its landing. The learn loop holds
+    at most two: the one it lands and the one launched ahead of that."""
+
+    step: int  # the update's index: iter_count when it lands
+    batch: Any  # its host batch, held until it lands (the record, a triage dump)
+    stats: Any  # the program's stat outputs, futures
+    t_open: float  # when the launch's span opened
+    dispatch: float  # seconds inside the launch
+    ahead: bool  # launched before the previous step had landed
+    last_replay: bool  # its batch's last: post_backward_callback follows the landing
+    first_of_job: bool  # the launch built (or loaded) the train step
+    hidden: float = 0.0  # time/step_host_hidden, set by the landing before it
+    discarded: bool = False  # a rollback landed on the step before it
+
+
+@dataclasses.dataclass
+class _LearnLoop:
+    """What one ``learn()`` call's launches and landings share."""
+
+    step_host: ExitStack  # holds the open `learn/step_host` span
+    clock: Clock
+    tbar: Any
+    results: Dict[str, Any]  # the newest evaluation's
+    finished: bool = False  # a landing reached total_steps and closed the run
+
+
+class _Lookahead:
+    """An iterator one can look one item ahead on."""
+
+    def __init__(self, iterable):
+        self._it = iter(iterable)
+        self._held: List[Any] = []
+
+    def __iter__(self):
+        return self
+
+    def __next__(self):
+        return self._held.pop() if self._held else next(self._it)
+
+    def peek(self, default=None):
+        """The item ``next()`` will return, drawn now; ``default`` at the end."""
+        if not self._held:
+            try:
+                self._held.append(next(self._it))
+            except StopIteration:
+                return default
+        return self._held[0]
+
+    def close(self) -> None:
+        self._it.close()
+
+
+def _is_ready(tree: Any) -> bool:
+    """Whether a program's outputs are there (they become ready together)."""
+    leaves = jax.tree_util.tree_leaves(tree)
+    return not leaves or getattr(leaves[0], "is_ready", lambda: True)()
 
 
 def attributed_between(m0: Dict[str, float], m1: Dict[str, float]) -> Dict[str, float]:
@@ -530,6 +591,9 @@ class TPUBaseTrainer(BaseRLTrainer):
         self._step_shapes: set = set()  # batch shapes the train step has run at
         self._last_batch_host: Any = None
         self._last_batch_sharded: Any = None
+        # the host batch of the step being landed: what a triage dump holds
+        # (a step launched ahead may have placed the next one meanwhile)
+        self._landing_batch: Any = None
 
         # resilience: preemption handler, update guard, host-call hardening,
         # fault plan (docs/RESILIENCE.md). Shares the metrics registry so
@@ -837,6 +901,7 @@ class TPUBaseTrainer(BaseRLTrainer):
         replay window is over — before rollout collection / final eval."""
         self._last_batch_host = None
         self._last_batch_sharded = None
+        self._landing_batch = None
 
     def _maybe_prefetch(self, loader, depth: Optional[int] = None):
         """Wrap a loader in background-thread prefetch (``depth`` batches
@@ -934,19 +999,25 @@ class TPUBaseTrainer(BaseRLTrainer):
         self.obs.memory.note_state(
             self.state.params, self.state.opt_state, getattr(self, "ref_params", None))
 
-    def train_step(self, batch: Dict[str, np.ndarray]) -> Dict[str, float]:
-        """One optimization step on a host batch; returns host scalar stats.
+    def train_step(self, batch: Dict[str, np.ndarray], step: Optional[int] = None) -> Dict[str, Any]:
+        """Launch one optimization step on a host batch: place the batch (or
+        reuse its placed copy), enqueue the program, return its stat outputs
+        as futures. ``self.state`` is the step's new state from here on, a
+        future too. ``step`` is the update's index, ``iter_count`` unless the
+        learn loop launches it ahead of the previous step's landing.
 
         The sharded device copy is memoized on the batch object: the PPO
         inner loop replays the same batch ``ppo_epochs`` times
         (``n_updates_per_batch``), and one host→device transfer serves all
         replays."""
         set_global_mesh(self.mesh)
+        if step is None:
+            step = self.iter_count
         plan = self.resilience.plan
         if (
             plan
             and jax.process_index() == jax.process_count() - 1
-            and plan.poll("sleep_one_proc", step=self.iter_count)
+            and plan.poll("sleep_one_proc", step=step)
         ):
             # deterministic straggler: stall the LAST rank's step so the
             # cluster-telemetry watchdog has something real to flag
@@ -957,7 +1028,7 @@ class TPUBaseTrainer(BaseRLTrainer):
 
             logger.warning(
                 f"fault plan: sleeping {SLEEP_FAULT_S}s inside update "
-                f"{self.iter_count} (injected straggler)"
+                f"{step} (injected straggler)"
             )
             _sleep(SLEEP_FAULT_S)
         first_step = self._train_step_fn is None
@@ -973,7 +1044,7 @@ class TPUBaseTrainer(BaseRLTrainer):
                 )
             self._last_batch_host = batch
             self._last_batch_sharded = arrays
-        self.state, stats = self._train_step_fn(self.state, arrays, self._loss_scale())
+        self.state, stats = self._train_step_fn(self.state, arrays, self._loss_scale(step))
         if first_step:  # set-up: the device is on its first step meanwhile
             self._warm_triage(batch)
         # recompile watchdog: a warm train step retracing (shape/dtype
@@ -995,15 +1066,17 @@ class TPUBaseTrainer(BaseRLTrainer):
         that the train step's first compile for it is no shape drift."""
         return False
 
-    def _loss_scale(self) -> np.float32:
-        """1.0, or NaN when the fault plan poisons this step's loss
+    def _loss_scale(self, step: Optional[int] = None) -> np.float32:
+        """1.0, or NaN when the fault plan poisons update ``step``'s loss
         (``nan_loss@step:N`` — deterministic update-guard exercise). Traced
         as a scalar array argument, so both values share one compiled
         program and the clean-path multiply is an exact identity."""
         plan = self.resilience.plan
-        if plan and plan.poll("nan_loss", step=self.iter_count):
+        if step is None:
+            step = self.iter_count
+        if plan and plan.poll("nan_loss", step=step):
             logger.warning(
-                f"fault plan: poisoning the loss of update {self.iter_count} to NaN"
+                f"fault plan: poisoning the loss of update {step} to NaN"
             )
             return np.float32(np.nan)
         return np.float32(1.0)
@@ -1965,7 +2038,7 @@ class TPUBaseTrainer(BaseRLTrainer):
         }
 
     def _dump_triage(self, reason: str, stats: Dict[str, Any]) -> Optional[str]:
-        """Write the current (memoized) batch as ``triage/step<N>.npz`` so a
+        """Write the batch of the step being landed as ``triage/step<N>.npz`` so a
         bad update is reproducible offline — tokens, masks, and whatever the
         trainer derives (docs/OBSERVABILITY.md "Training dynamics").
 
@@ -1978,7 +2051,7 @@ class TPUBaseTrainer(BaseRLTrainer):
         if not directory or self._triage_dumps >= TRIAGE_MAX_DUMPS:
             return None
         try:
-            arrays = self._triage_rows(self._last_batch_host)
+            arrays = self._triage_rows(self._landing_batch)
             if not arrays:
                 return None
             try:
@@ -2024,54 +2097,62 @@ class TPUBaseTrainer(BaseRLTrainer):
             logger.warning("triage dump failed", exc_info=True)
             return None
 
-    def _check_faults_and_preemption(self) -> None:
-        """Step-boundary seam, called before every update: deliver any
-        fault-plan signals for this step, coordinate the preemption flag
-        across processes, then honor an agreed request with one committed
-        emergency checkpoint."""
+    def _check_faults_and_preemption(
+        self, step: Optional[int] = None, land: Callable[[], None] = lambda: None
+    ) -> None:
+        """Step-boundary seam, called before every update is launched: deliver
+        any fault-plan signals for update ``step``, coordinate the preemption
+        flag across processes, then honor an agreed request with one committed
+        emergency checkpoint. ``step`` is ``iter_count``, or one more while the
+        previous update is still in flight; ``land`` lands that one, and is
+        called before a request is honored: the checkpoint is of a landed
+        state with nothing in flight (a step the fault plan names is launched
+        with nothing in flight to begin with)."""
         import signal as _signal
 
+        if step is None:
+            step = step
         plan = self.resilience.plan
         if plan:
             # raise_signal runs the installed handler synchronously, so the
             # request is honored at THIS boundary — fully deterministic
-            if plan.poll("sigterm", step=self.iter_count):
+            if plan.poll("sigterm", step=step):
                 _signal.raise_signal(_signal.SIGTERM)
-            if plan.poll("sigint", step=self.iter_count):
+            if plan.poll("sigint", step=step):
                 _signal.raise_signal(_signal.SIGINT)
             # the multihost fault: every process polls (lockstep counters),
             # only process 0 is actually signaled — the coordination
             # allgather below must carry the request to the peers
             if (
-                plan.poll("sigterm_one_proc", step=self.iter_count)
+                plan.poll("sigterm_one_proc", step=step)
                 and jax.process_index() == 0
             ):
                 _signal.raise_signal(_signal.SIGTERM)
-            if plan.poll("flightrec_dump", step=self.iter_count):
+            if plan.poll("flightrec_dump", step=step):
                 # deterministic flight-recorder exercise: same dump path as
                 # the crash/NaN-halt/preemption shutdown, no crash needed
                 self.obs.dump_flight_record(
-                    reason=f"fault plan: flightrec_dump@step:{self.iter_count}"
+                    reason=f"fault plan: flightrec_dump@step:{step}"
                 )
-            if plan.poll("health_trip", step=self.iter_count):
+            if plan.poll("health_trip", step=step):
                 # arm an injected detector trip; this step's health update
                 # consumes it and runs the organic flightrec+triage path
-                self.obs.health.force_trip("fault_plan", step=self.iter_count)
+                self.obs.health.force_trip("fault_plan", step=step)
             if self._serve is not None and plan.poll(
-                "request_flood", step=self.iter_count
+                "request_flood", step=step
             ):
                 # admission-control drill (docs/RESILIENCE.md): a synthetic
                 # burst through the real gate must shed load with 429s
                 rejected = self._serve.flood_drill()
                 logger.warning(
-                    f"request_flood drill at step {self.iter_count}: "
+                    f"request_flood drill at step {step}: "
                     f"{rejected} synthetic requests shed by admission"
                 )
         if self._serve is not None:
             # serve-while-training: every step boundary publishes the fresh
             # params; the pump adopts them at its next serve-idle point, so
             # every response is generated under ONE params version
-            self._serve.publish(self._serve_params_copy(), version=self.iter_count)
+            self._serve.publish(self._serve_params_copy(), version=step)
         preemption = self.resilience.preemption
         requested = preemption.requested
         coordinate = self.resilience.config.coordinate_preemption
@@ -2090,12 +2171,13 @@ class TPUBaseTrainer(BaseRLTrainer):
             # rank still rides the same allgather, skipping only the
             # analysis).
             requested_any = self.obs.cluster.beat(
-                requested, step=self.iter_count, collective=coordinate
+                requested, step=step, collective=coordinate
             )
             if coordinate:
                 requested = requested_any
         if not requested:
             return
+        land()
         if not preemption.requested:
             # this process was not signaled itself; a peer was
             preemption.request("peer preemption (coordinated)")
@@ -2134,45 +2216,26 @@ class TPUBaseTrainer(BaseRLTrainer):
                     return
             yield item
 
-    def _learn_loop(self, step_host: ExitStack) -> Dict[str, Any]:  # noqa: C901
-        # Emergency resume: the checkpoint froze the run between two
-        # updates. Fast-forward the loop to that exact boundary — skipped
-        # slots run no device work, no eval, no callbacks (all of that
-        # happened before the checkpoint; the rollout RNG and controller
-        # state were restored with it), so the resumed run's stream of
-        # device calls is identical to an uninterrupted run's.
-        emergency_resume = self._emergency_resume
-        self._emergency_resume = False
-        skip_target = self.iter_count if emergency_resume else 0
+    def post_backward_touches_state(self, updates: int) -> bool:
+        """Whether :meth:`post_backward_callback`, run once ``updates``
+        updates have landed, reads or replaces ``self.state`` (ILQL's target
+        sync). The learn loop then launches nothing ahead of it; a callback
+        that stays on the host (PPO's KL controller) runs while the next
+        step is on the chip."""
+        return False
+
+    def _step_plan(self, skip_target: int):
+        """The learn loop's steps in the order they run, ``(batch,
+        last_replay)`` each, and ``None`` after the last step of an epoch
+        that ran one. Nothing here touches the device: the loop looks one
+        item ahead to know whether a step is the last before a boundary.
+
+        Emergency resume: the checkpoint froze the run between two updates.
+        The first ``skip_target`` slots are passed over — no device work, no
+        eval, no callbacks (all of that happened before the checkpoint; the
+        rollout RNG and controller state were restored with it), so the
+        resumed run's stream of device calls is an uninterrupted run's."""
         done = 0
-
-        if emergency_resume:
-            results: Dict[str, Any] = {}
-            logger.info(
-                f"emergency resume: fast-forwarding to update {skip_target}"
-            )
-        else:
-            with self.obs.span("setup/first_eval") as eval_sp:
-                results = self.evaluate()
-            self.obs.setup.first_eval_s = eval_sp.duration
-            self.obs.setup.first_eval_end = eval_sp.t1
-            self.tracker.log(results, step=self.iter_count)
-            self._report_sweep(results)
-        clock = Clock()
-        if self._host_gap_t0 is None:  # no collection came before the loop
-            self._host_gap_t0 = perf_counter()
-        if self._step_mark is None:
-            self._step_mark = tracing.mark()
-
-        tbar = logging.tqdm(
-            initial=self.iter_count,
-            total=self.total_steps,
-            disable=jax.process_index() != 0,
-            position=0,
-            leave=True,
-        )
-
-        profile = self.obs.profile
         for _ in range(self.config.train.epochs):
             if done < skip_target:
                 # fully-skipped epochs cost nothing (not even collation)
@@ -2198,227 +2261,392 @@ class TPUBaseTrainer(BaseRLTrainer):
             for batch in self._spanned(
                 self._maybe_prefetch(self.train_dataloader), "learn/loader", stage="collate"
             ):
-                batch_ran = False
-                for _ in range(self.n_updates_per_batch):
+                for replay in range(self.n_updates_per_batch):
                     if done < skip_target:
                         done += 1
                         continue
-                    batch_ran = epoch_ran = True
-                    self._check_faults_and_preemption()
-                    profile.on_step_start(self.iter_count)
-                    step_host.close()  # the host gap ends where the step begins
-                    with profile.step_annotation("train", self.iter_count):
-                        with self.obs.span("train_step") as sp:
-                            step_gap = sp.t0 - self._host_gap_t0
-                            device_stats = self.train_step(batch)
-                            # fence on the new state AND the stat outputs:
-                            # the donated-state update can still be in
-                            # flight after the stats land, and without any
-                            # fence the timer reads async dispatch latency
-                            sp.fence((self.state, device_stats))
-                    self._host_gap_t0 = sp.t1
-                    # what the runtime, the collector and the scheduler did
-                    # from the previous fence (or the end of the collection)
-                    # to this one: the interval time/step_gap and
-                    # time/train_step tile
-                    step_mark = tracing.mark()
-                    attributed = attributed_between(self._step_mark, step_mark)
-                    self._step_mark = step_mark
-                    # everything the host does until the next step's span
-                    # opens, or until the post-epoch collection
-                    step_host.enter_context(self.obs.span("learn/step_host"))
-                    host_stats = to_host(device_stats)
-                    stats = filter_non_scalars(host_stats)
-                    # collapse the on-device distribution sketches into
-                    # dist/* percentile gauges BEFORE the filter's output is
-                    # used — the raw histogram arrays live only in host_stats
-                    stats.update(self.obs.dynamics.summarize(host_stats))
-                    # a guard-rejected update is the one moment the offending
-                    # batch is still in hand — triage it before any rollback
-                    # (docs/RESILIENCE.md "Update guard", OBSERVABILITY.md
-                    # "Training dynamics")
-                    if stats.get(UPDATE_OK_KEY) == 0.0:
-                        if self._dump_triage("update_guard", stats):
-                            self.obs.dump_flight_record(
-                                reason=f"update guard rejected step {self.iter_count}"
-                            )
-                    # update guard: the on-device finiteness flag landed
-                    # with the stats; skip was already applied on device,
-                    # rollback/halt are host decisions (docs/RESILIENCE.md)
-                    if self.resilience.guard.after_step(stats) == "rollback":
-                        self._rollback_to_committed()
-                    step_time = sp.duration
-                    stats["time/step"] = step_time
-                    stats["time/train_step"] = step_time
-                    # host time between the previous fence (or the end of
-                    # the collection) and this step's span: with
-                    # time/train_step it tiles the learn phase
-                    stats["time/step_gap"] = step_gap
-                    stats["time/train_step_dispatch"] = sp.dispatch
-                    stats["time/train_step_wait"] = sp.wait
-                    stats.update(attributed)
-                    real_tokens, fed_tokens, width = self._batch_token_counts(batch)
-                    stats["learn/pad_frac"] = (
-                        1.0 - real_tokens / fed_tokens if fed_tokens else 0.0
-                    )
-                    stats["learn/step_width"] = float(width)
-                    stats["learn/grad_param_frac"] = self._grad_param_frac
-                    self._note_step(stats, attributed, width, sp.t1)
-                    (
-                        stats["learn/attn_visited_frac"],
-                        stats["learn/attn_tile"],
-                        stats["learn/attn_interior_frac"],
-                    ) = self._attn_tile_walk(width)
-                    if getattr(self.tcfg, "index_topk", 0):  # every layer attends under the selection
-                        stats["learn/attn_selected_frac"] = selected_frac(width, self.tcfg.index_topk)
-                    if getattr(self.tcfg, "sparse_topk", 0):  # the attention layers' pairs under the block selection
-                        chosen, causal = block_selected_pairs(width, self.tcfg)
-                        stats["learn/attn_block_selected_frac"] = chosen / max(causal, 1.0)
-                    batch_size = next(
-                        v.shape[0] for v in batch.values() if hasattr(v, "shape")
-                    ) if isinstance(batch, dict) else self.config.train.batch_size
-                    stats.update(
-                        self.obs.throughput.step_stats(
-                            step_time,
-                            tokens=real_tokens,
-                            samples=batch_size,
-                            flops_per_device=self._ensure_train_step_flops(
-                                self._last_batch_sharded
-                            ),
-                        )
-                    )
-                    stats.update(self.obs.memory.collect(self.programs.account()))
-                    # feed the NEXT boundary's cluster beat (distributed
-                    # telemetry) with this step's scalars, and surface the
-                    # tracer's drop counter before the snapshot below
-                    self.obs.cluster.note_step(
-                        step_time,
-                        tokens_per_sec=stats.get(
-                            "throughput/tokens_per_sec", 0.0
-                        ),
-                        device_bytes=stats.get(
-                            "memory/device_bytes_in_use",
-                            stats.get("memory/host_rss_bytes", 0.0),
-                        ),
-                    )
-                    # elastic fleet membership rides the same beat vector
-                    # (async_rl.transport: collective; None off-fleet)
-                    collector = getattr(self, "_async", None)
-                    if collector is not None and hasattr(
-                        collector, "fleet_size"
-                    ):
-                        self.obs.cluster.note_fleet(collector.fleet_size())
-                    self.obs.note_dropped_spans()
-                    stats.update(self.obs.metrics.snapshot())
-                    if self._serve is not None:
-                        # per-tenant/per-class SLO percentiles live on the
-                        # HTTP /metrics endpoint; the flat SERVE_KEYS
-                        # gauges ride the training metric stream
-                        stats.update(self._serve.flat_metrics())
-                    # windowed health detectors over this step's metric
-                    # stream; a trip transition dumps the flight record and
-                    # triages the batch that produced it
-                    stats.update(
-                        self.obs.health.update(stats, step=self.iter_count)
-                    )
-                    tripped = self.obs.health.just_tripped
-                    if tripped is not None:
-                        if self._dump_triage(f"health:{tripped}", stats):
-                            # this step's registry snapshot is already taken;
-                            # surface the counter on the step that dumped
-                            stats["health/triage_dumps"] = float(
-                                self._triage_dumps
-                            )
-                        self.obs.dump_flight_record(
-                            reason=f"health_trip: {tripped} @ step {self.iter_count}"
-                        )
-                    # the flight recorder keeps the last N steps' stats for
-                    # the crash dump (docs/OBSERVABILITY.md)
-                    self.obs.flightrec.record(
-                        "step", {"iter": self.iter_count, "stats": stats}
-                    )
-                    clock.tick(batch_size)
-                    stats["time/per_1k_samples"] = clock.get_stat(1000)
-                    profile.on_step_end(self.iter_count)
-                    self.iter_count += 1
-
-                    if self.iter_count % self.config.train.checkpoint_interval == 0:
-                        # retention ring: prune BEFORE saving so the join
-                        # inside prune waits on the long-finished previous
-                        # save, not the one about to dispatch
-                        keep = self.resilience.config.keep_last_n
-                        if keep > 0:
-                            prune_checkpoints(self.config.train.checkpoint_dir, keep)
-                        subfolder = f"checkpoint_{self.iter_count:0{len(str(self.total_steps))}d}"
-                        self.save(os.path.join(self.config.train.checkpoint_dir, subfolder))
-
-                    if self.iter_count % self.config.train.eval_interval == 0:
-                        results = self.evaluate()
-                        stats.update(results)
-                        self._report_sweep(stats)
-                        if self.config.train.save_best:
-                            reward = stats.get(
-                                "reward/mean", stats.get("metrics/reward", -float("inf"))
-                            )
-                            if reward > self.best_reward:
-                                self.best_reward = reward
-                                best_path = os.path.join(
-                                    self.config.train.checkpoint_dir, "best_checkpoint"
-                                )
-                                logger.info(f"Saving best state so far into {best_path}")
-                                self.save(best_path)
-
-                    desc = " | ".join(
-                        f"{k}: {significant(v)}"
-                        for k, v in stats.items()
-                        if k.startswith("losses/")
-                    )
-                    tbar.set_description(f"[{desc}]")
-                    tbar.update()
-
-                    if self.iter_count >= self.total_steps:
-                        profile.stop()
-                        # the flops analysis runs on a daemon thread; join it
-                        # here so even a run too short for it to land mid-loop
-                        # still reports a final measured MFU
-                        flops = self._ensure_train_step_flops(
-                            self._last_batch_sharded, wait=True
-                        )
-                        if flops and "throughput/mfu" not in stats:
-                            stats["throughput/mfu"] = obs_mfu(
-                                flops, step_time, self.obs.throughput.peak
-                            )
-                        self._drop_batch_memo()
-                        results = self.evaluate()
-                        stats.update(results)
-                        stats.update(self.obs.throughput.summary())
-                        self.tracker.log(stats, step=self.iter_count)
-                        self._report_sweep(stats)
-                        subfolder = f"checkpoint_{self.iter_count:0{len(str(self.total_steps))}d}"
-                        self.save(os.path.join(self.config.train.checkpoint_dir, subfolder))
-                        tbar.close()
-                        wait_for_saves()  # async saves must land before exit
-                        self._export_observability()
-                        # flush/close the tracker (W&B runs must finalize;
-                        # JSONL transparently reopens if logged again)
-                        self.tracker.finish()
-                        return results
-
-                    self.tracker.log(stats, step=self.iter_count)
-
-                if batch_ran:  # fully fast-forwarded batches already had
-                    self.post_backward_callback()  # their callback pre-checkpoint
+                    epoch_ran = True
+                    yield batch, replay == self.n_updates_per_batch - 1
             if epoch_ran:
+                yield None
+
+    def _learn_loop(self, step_host: ExitStack) -> Dict[str, Any]:
+        """The learner runs one step ahead of its records: a step is a
+        *launch* (:meth:`_launch`: place or reuse the batch, enqueue the
+        program) and a *landing* (:meth:`_land`: fence, record, checkpoint,
+        eval, tracker), and step N+1 is launched BEFORE step N lands whenever
+        it is known and no boundary lies between the two
+        (:meth:`_boundary_after`), so the chip is inside N+1 while the host
+        writes N's record. Depth one: at most one step in flight beside the
+        one being landed. The device calls, their order and their arguments
+        are those of a loop that lands every step before the next launch."""
+        emergency_resume = self._emergency_resume
+        self._emergency_resume = False
+        skip_target = self.iter_count if emergency_resume else 0
+
+        if emergency_resume:
+            results: Dict[str, Any] = {}
+            logger.info(
+                f"emergency resume: fast-forwarding to update {skip_target}"
+            )
+        else:
+            with self.obs.span("setup/first_eval") as eval_sp:
+                results = self.evaluate()
+            self.obs.setup.first_eval_s = eval_sp.duration
+            self.obs.setup.first_eval_end = eval_sp.t1
+            self.tracker.log(results, step=self.iter_count)
+            self._report_sweep(results)
+        if self._host_gap_t0 is None:  # no collection came before the loop
+            self._host_gap_t0 = perf_counter()
+        if self._step_mark is None:
+            self._step_mark = tracing.mark()
+
+        loop = _LearnLoop(
+            step_host=step_host,
+            clock=Clock(),
+            tbar=logging.tqdm(
+                initial=self.iter_count,
+                total=self.total_steps,
+                disable=jax.process_index() != 0,
+                position=0,
+                leave=True,
+            ),
+            results=results,
+        )
+        # closed on every way out: a loop left mid-epoch leaves no prefetch
+        # worker behind
+        with closing(_Lookahead(self._step_plan(skip_target))) as plan:
+            self._run_steps(loop, plan)
+        if not loop.finished:  # the epochs ran out before total_steps
+            self.obs.profile.stop()
+            loop.tbar.close()
+            wait_for_saves()  # async saves must land before exit
+            self._export_observability()
+            self.tracker.finish()
+        return loop.results
+
+    def _run_steps(self, loop: "_LearnLoop", plan: "_Lookahead") -> None:
+        """Launch and land the plan's steps, until it ends or a landing
+        reaches ``total_steps`` (``loop.finished``)."""
+        flying: Optional[_Flight] = None  # launched and not landed
+
+        def land_flying() -> None:
+            nonlocal flying
+            if flying is not None:
+                landing, flying = flying, None
+                self._land(loop, landing)
+
+        for planned in plan:
+            if planned is None:  # an epoch's end: its last step has landed
                 self._drop_batch_memo()  # free the batch's HBM before rollouts
-                step_host.close()
+                loop.step_host.close()
                 with self.obs.span("learn/post_epoch"):
                     self.post_epoch_callback()
-        profile.stop()
-        tbar.close()
-        wait_for_saves()  # async saves must land before exit
-        self._export_observability()
-        self.tracker.finish()
-        return results
+                continue
+            batch, last_replay = planned
+            step = self.iter_count + (flying is not None)
+            self._check_faults_and_preemption(step, land=land_flying)
+            launched = self._launch(loop, batch, last_replay, step, ahead=flying is not None)
+            if flying is not None:  # it lands while the chip runs the step after it
+                landing, flying = flying, None
+                self._land(loop, landing, ahead_of=launched)
+                if launched.discarded:
+                    # a rollback took away the state it was computed from:
+                    # the same update again, from the restored one
+                    launched = self._launch(loop, batch, last_replay, step, ahead=False)
+            if self._boundary_after(launched, plan.peek()):
+                self._land(loop, launched)
+                if loop.finished:
+                    return
+            else:
+                flying = launched
+
+    def _boundary_after(self, flight: "_Flight", nxt: Any) -> bool:
+        """Whether ``flight``, just launched, lands before anything else is
+        launched: the next step is not known (``nxt`` is None: an epoch's
+        end, with its collection, or the plan's), or something between the
+        two acts on the landed state — a checkpoint, an evaluation or the
+        run's end falls due, the profiler's window opens or closes, the
+        fault plan names the next step, the batch's callback touches the
+        state — or this is the job's first step (its compile, the triage
+        programs, the flops thread). Step counts and configuration alone
+        decide, so every process decides alike and collective beats stay
+        aligned."""
+        if nxt is None or flight.first_of_job:
+            return True
+        landed = flight.step + 1  # iter_count once it lands: the next step's index
+        train = self.config.train
+        if (
+            landed % train.checkpoint_interval == 0
+            or landed % train.eval_interval == 0
+            or landed >= self.total_steps
+        ):
+            return True
+        profile = self.obs.profile
+        if profile.stops_at(flight.step) or profile.starts_at(landed):
+            return True
+        if self.resilience.plan.due(landed):
+            return True
+        return flight.last_replay and self.post_backward_touches_state(landed)
+
+    def _launch(self, loop: "_LearnLoop", batch: Any, last_replay: bool, step: int,
+                ahead: bool) -> "_Flight":
+        """Update ``step`` goes to the chip; its landing follows, after the
+        next step's launch where that one may run ahead."""
+        self.obs.profile.on_step_start(step)
+        loop.step_host.close()  # the host gap ends where the step begins
+        first_of_job = self._train_step_fn is None
+        with self.obs.profile.step_annotation("train", step):
+            with self.obs.span("train_step") as sp:
+                device_stats = self.train_step(batch, step=step)
+        # what the host does between a launch and a landing, and from a
+        # landing to the next launch or to the post-epoch collection
+        loop.step_host.enter_context(self.obs.span("learn/step_host"))
+        return _Flight(
+            step=step, batch=batch, stats=device_stats, t_open=sp.t0, dispatch=sp.duration,
+            ahead=ahead, last_replay=last_replay, first_of_job=first_of_job,
+        )
+
+    def _discard(self, flight: "_Flight") -> None:
+        """A rollback lands on the step before ``flight``: what ``flight``
+        computed came from the rejected state and goes with it. It is no
+        update: ``iter_count`` does not advance and the loop launches the
+        same update again, from the restored state."""
+        stats = filter_non_scalars(to_host(flight.stats))
+        stats["learn/ahead"] = 1.0
+        stats["learn/discarded"] = 1.0
+        flight.discarded = True
+        self.obs.flightrec.record(
+            "resilience", {"event": "discarded_in_flight", "step": flight.step}
+        )
+        self.tracker.log(stats, step=self.iter_count)
+
+    def _land(self, loop: "_LearnLoop", flight: "_Flight",  # noqa: C901
+              ahead_of: Optional["_Flight"] = None) -> None:
+        """Fence update ``flight.step`` and write its record: everything
+        from the stats' way to the host to ``tracker.log``, with the
+        checkpoint and the evaluation that fall due. ``ahead_of`` is the step
+        launched after it, in flight meanwhile: the fence is then on the stat
+        outputs alone (the state was donated to ``ahead_of``; one program's
+        outputs become ready together), and a verdict that acts on the state
+        (a rejected update, a health trip's dump) first waits for it."""
+        loop.step_host.close()
+        self._landing_batch = batch = flight.batch
+        # the step launched ahead, to wait for before a verdict acts on the
+        # state (None, an empty tree, where nothing is in flight)
+        in_flight = (self.state, ahead_of.stats) if ahead_of is not None else None
+        with self.obs.span("learn/land", step=flight.step) as sp:
+            # a boundary fences the new state AND the stat outputs: the
+            # donated-state update can still be in flight after the stats
+            # land, and without any fence the timer reads async dispatch
+            # latency
+            t_ready = sp.block(
+                flight.stats if ahead_of is not None else (self.state, flight.stats)
+            )
+            # the interval this record tiles the learn phase with: from the
+            # previous fence (or the end of the collection) to this one
+            t_prev, self._host_gap_t0 = self._host_gap_t0, t_ready
+            # what the runtime, the collector and the scheduler did in it
+            step_mark = tracing.mark()
+            attributed = attributed_between(self._step_mark, step_mark)
+            self._step_mark = step_mark
+            host_stats = to_host(flight.stats)
+            stats = filter_non_scalars(host_stats)
+            # collapse the on-device distribution sketches into
+            # dist/* percentile gauges BEFORE the filter's output is
+            # used — the raw histogram arrays live only in host_stats
+            stats.update(self.obs.dynamics.summarize(host_stats))
+            # a guard-rejected update is the one moment the offending
+            # batch is still in hand — triage it before any rollback
+            # (docs/RESILIENCE.md "Update guard", OBSERVABILITY.md
+            # "Training dynamics")
+            if stats.get(UPDATE_OK_KEY) == 0.0:
+                jax.block_until_ready(in_flight)
+                if self._dump_triage("update_guard", stats):
+                    self.obs.dump_flight_record(
+                        reason=f"update guard rejected step {self.iter_count}"
+                    )
+            # update guard: the on-device finiteness flag landed
+            # with the stats; skip was already applied on device,
+            # rollback/halt are host decisions (docs/RESILIENCE.md)
+            if self.resilience.guard.after_step(stats) == "rollback":
+                if ahead_of is not None:
+                    self._discard(ahead_of)
+                self._rollback_to_committed()
+            # never shorter than the chip's own time on the step: it opens
+            # at the launch, or where the previous step's fence returned if
+            # the launch came before that
+            step_time = t_ready - max(flight.t_open, t_prev)
+            stats["time/step"] = step_time
+            stats["time/train_step"] = step_time
+            # host time between the previous fence (or the end of the
+            # collection) and this step's launch, 0 for a step launched
+            # ahead: with time/train_step it tiles the learn phase
+            stats["time/step_gap"] = max(flight.t_open - t_prev, 0.0)
+            stats["time/train_step_dispatch"] = flight.dispatch
+            stats["time/train_step_wait"] = sp.wait
+            stats["learn/ahead"] = float(flight.ahead)
+            # of the previous landing, the seconds after its fence that
+            # passed while this step was on the chip
+            stats["time/step_host_hidden"] = flight.hidden
+            stats.update(attributed)
+            real_tokens, fed_tokens, width = self._batch_token_counts(batch)
+            stats["learn/pad_frac"] = (
+                1.0 - real_tokens / fed_tokens if fed_tokens else 0.0
+            )
+            stats["learn/step_width"] = float(width)
+            stats["learn/grad_param_frac"] = self._grad_param_frac
+            self._note_step(stats, attributed, (width, flight.ahead), t_ready,
+                            next_launch=ahead_of.dispatch if ahead_of is not None else 0.0)
+            (
+                stats["learn/attn_visited_frac"],
+                stats["learn/attn_tile"],
+                stats["learn/attn_interior_frac"],
+            ) = self._attn_tile_walk(width)
+            if getattr(self.tcfg, "index_topk", 0):  # every layer attends under the selection
+                stats["learn/attn_selected_frac"] = selected_frac(width, self.tcfg.index_topk)
+            if getattr(self.tcfg, "sparse_topk", 0):  # the attention layers' pairs under the block selection
+                chosen, causal = block_selected_pairs(width, self.tcfg)
+                stats["learn/attn_block_selected_frac"] = chosen / max(causal, 1.0)
+            batch_size = next(
+                v.shape[0] for v in batch.values() if hasattr(v, "shape")
+            ) if isinstance(batch, dict) else self.config.train.batch_size
+            stats.update(
+                self.obs.throughput.step_stats(
+                    step_time,
+                    tokens=real_tokens,
+                    samples=batch_size,
+                    flops_per_device=self._ensure_train_step_flops(
+                        self._last_batch_sharded
+                    ),
+                )
+            )
+            stats.update(self.obs.memory.collect(self.programs.account()))
+            # feed the NEXT boundary's cluster beat (distributed
+            # telemetry) with this step's scalars, and surface the
+            # tracer's drop counter before the snapshot below
+            self.obs.cluster.note_step(
+                step_time,
+                tokens_per_sec=stats.get(
+                    "throughput/tokens_per_sec", 0.0
+                ),
+                device_bytes=stats.get(
+                    "memory/device_bytes_in_use",
+                    stats.get("memory/host_rss_bytes", 0.0),
+                ),
+            )
+            # elastic fleet membership rides the same beat vector
+            # (async_rl.transport: collective; None off-fleet)
+            collector = getattr(self, "_async", None)
+            if collector is not None and hasattr(
+                collector, "fleet_size"
+            ):
+                self.obs.cluster.note_fleet(collector.fleet_size())
+            self.obs.note_dropped_spans()
+            stats.update(self.obs.metrics.snapshot())
+            if self._serve is not None:
+                # per-tenant/per-class SLO percentiles live on the
+                # HTTP /metrics endpoint; the flat SERVE_KEYS
+                # gauges ride the training metric stream
+                stats.update(self._serve.flat_metrics())
+            # windowed health detectors over this step's metric
+            # stream; a trip transition dumps the flight record and
+            # triages the batch that produced it
+            stats.update(
+                self.obs.health.update(stats, step=self.iter_count)
+            )
+            tripped = self.obs.health.just_tripped
+            if tripped is not None:
+                jax.block_until_ready(in_flight)
+                if self._dump_triage(f"health:{tripped}", stats):
+                    # this step's registry snapshot is already taken;
+                    # surface the counter on the step that dumped
+                    stats["health/triage_dumps"] = float(
+                        self._triage_dumps
+                    )
+                self.obs.dump_flight_record(
+                    reason=f"health_trip: {tripped} @ step {self.iter_count}"
+                )
+            # the flight recorder keeps the last N steps' stats for
+            # the crash dump (docs/OBSERVABILITY.md)
+            self.obs.flightrec.record(
+                "step", {"iter": self.iter_count, "stats": stats}
+            )
+            loop.clock.tick(batch_size)
+            stats["time/per_1k_samples"] = loop.clock.get_stat(1000)
+            self.obs.profile.on_step_end(self.iter_count)
+            self.iter_count += 1
+        # checkpoint, evaluation and the tracker's write: between spans again
+        loop.step_host.enter_context(self.obs.span("learn/step_host"))
+
+        if self.iter_count % self.config.train.checkpoint_interval == 0:
+            # retention ring: prune BEFORE saving so the join
+            # inside prune waits on the long-finished previous
+            # save, not the one about to dispatch
+            keep = self.resilience.config.keep_last_n
+            if keep > 0:
+                prune_checkpoints(self.config.train.checkpoint_dir, keep)
+            subfolder = f"checkpoint_{self.iter_count:0{len(str(self.total_steps))}d}"
+            self.save(os.path.join(self.config.train.checkpoint_dir, subfolder))
+
+        if self.iter_count % self.config.train.eval_interval == 0:
+            loop.results = self.evaluate()
+            stats.update(loop.results)
+            self._report_sweep(stats)
+            if self.config.train.save_best:
+                reward = stats.get(
+                    "reward/mean", stats.get("metrics/reward", -float("inf"))
+                )
+                if reward > self.best_reward:
+                    self.best_reward = reward
+                    best_path = os.path.join(
+                        self.config.train.checkpoint_dir, "best_checkpoint"
+                    )
+                    logger.info(f"Saving best state so far into {best_path}")
+                    self.save(best_path)
+
+        desc = " | ".join(
+            f"{k}: {significant(v)}"
+            for k, v in stats.items()
+            if k.startswith("losses/")
+        )
+        loop.tbar.set_description(f"[{desc}]")
+        loop.tbar.update()
+
+        if self.iter_count >= self.total_steps:
+            self.obs.profile.stop()
+            # the flops analysis runs on a daemon thread; join it
+            # here so even a run too short for it to land mid-loop
+            # still reports a final measured MFU
+            flops = self._ensure_train_step_flops(
+                self._last_batch_sharded, wait=True
+            )
+            if flops and "throughput/mfu" not in stats:
+                stats["throughput/mfu"] = obs_mfu(
+                    flops, step_time, self.obs.throughput.peak
+                )
+            self._drop_batch_memo()
+            loop.results = self.evaluate()
+            stats.update(loop.results)
+            stats.update(self.obs.throughput.summary())
+            self.tracker.log(stats, step=self.iter_count)
+            self._report_sweep(stats)
+            subfolder = f"checkpoint_{self.iter_count:0{len(str(self.total_steps))}d}"
+            self.save(os.path.join(self.config.train.checkpoint_dir, subfolder))
+            loop.tbar.close()
+            wait_for_saves()  # async saves must land before exit
+            self._export_observability()
+            # flush/close the tracker (W&B runs must finalize;
+            # JSONL transparently reopens if logged again)
+            self.tracker.finish()
+            loop.finished = True
+            return
+
+        self.tracker.log(stats, step=self.iter_count)
+        if flight.last_replay:
+            self.post_backward_callback()
+        if ahead_of is not None and not _is_ready(ahead_of.stats):
+            ahead_of.hidden = perf_counter() - t_ready
 
     # ------------------------------------------------------------------
     # slow intervals (docs/OBSERVABILITY.md "A slow interval names its cause")
@@ -2446,17 +2674,23 @@ class TPUBaseTrainer(BaseRLTrainer):
                 "slow_interval", {"kind": kind, "verdict": verdict, "line": line, **parts})
         history.append((seconds, parts))
 
-    def _note_step(self, stats: Dict[str, float], attributed: Dict[str, float], width: int,
-                   t_fence: float) -> None:
-        """A step record's interval: previous fence to this one."""
+    def _note_step(self, stats: Dict[str, float], attributed: Dict[str, float], key: Any,
+                   t_fence: float, next_launch: float = 0.0) -> None:
+        """A step record's interval: previous fence to this one. ``key`` is
+        what makes two steps alike: the width, and whether the step was
+        launched ahead (its launch then lies before the interval, and the
+        landing of the step before it inside). ``next_launch``: seconds in
+        the launch of the step after it, where that fell inside the interval
+        (a shape's first step loads or compiles its program there)."""
         seconds = stats["time/step_gap"] + stats["time/train_step"]
         parts = {
             "train_step wait": stats["time/train_step_wait"],
             "train_step dispatch": stats["time/train_step_dispatch"],
+            "next launch": next_launch,
             "step gap": stats["time/step_gap"],
             **attributed,
         }
-        self._note_interval("step", width, f"step {self.iter_count}", seconds, parts,
+        self._note_interval("step", key, f"step {self.iter_count}", seconds, parts,
                             t_fence - seconds, t_fence)
         if self._cycle is not None:
             self._cycle["seconds"] += seconds

@@ -354,8 +354,11 @@ class ILQLTrainer(TPUBaseTrainer):
             self.config.train.epochs * len(self.train_dataloader),
         )
 
+    def post_backward_touches_state(self, updates: int) -> bool:
+        return updates % self.ilql.steps_for_target_q_sync == 0
+
     def post_backward_callback(self) -> None:
-        if self.iter_count % self.ilql.steps_for_target_q_sync == 0:
+        if self.post_backward_touches_state(self.iter_count):
             self.state = self.state.replace(
                 params=self._sync_fn(self.state.params)
             )

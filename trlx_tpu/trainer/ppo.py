@@ -1348,8 +1348,8 @@ class PPOTrainer(TPUBaseTrainer):
         collector.end_collection()
         stats.update(collector.collection_stats())
 
-    def train_step(self, batch):
-        stats = super().train_step(batch)
+    def train_step(self, batch, step=None):
+        stats = super().train_step(batch, step=step)
         if self._async is not None:
             # the learner's update clock IS the weight-channel version:
             # publish after every optimizer update (in-flight sync; thinned
