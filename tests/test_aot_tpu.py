@@ -331,6 +331,62 @@ def test_block_selected_flash_kernels_compile_and_keep_the_names(topo):
     assert "bf16[1,2,16384,256]" in text.replace(" ", "")  # 1/64 of a byte a pair... two bytes a block
 
 
+def _metric_pattern(name):
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "layer_metrics", f"{name}.json")) as f:
+        return json.load(f)["pattern"]
+
+
+def _executed(text):
+    """The instructions of a compiled module that run as events of their own:
+    those of the computations that hold fusions (a fused computation holds
+    none), without the ones that only name a value."""
+    import re
+
+    out = []
+    for block in re.split(r"\n(?=(?:ENTRY )?%[^\n]*\{\n)", text):
+        if " fusion(" in block:
+            lines = [l.strip().removeprefix("ROOT ") for l in block.split("\n")[1:]]
+            out += [l for l in lines if l.startswith("%") and not re.search(
+                r" (?:get-tuple-element|bitcast|constant|parameter|tuple|while|iota)\(", l)]
+    return out
+
+
+def test_the_chunked_delta_rule_compiles_at_the_kimi_cells_piece_and_the_benchmark_finds_it(topo):
+    """``ops/delta_rule.py`` at the Kimi-Linear cell's shapes: the chunked form
+    on a piece of 2 rows of 4096 tokens, 32 heads of 128, forward and (run
+    again under ``jax.checkpoint``) backward, inside the chip's memory with
+    room for what the program holds; and the one-token step on 32 rows.
+    ``kda_scan_device_ms`` reads its events by result shape and
+    ``kda_step_device_ms`` by the state's shape in a fusion's text: both
+    patterns find the bulk of their own program's instructions and none of
+    the other's."""
+    import re
+
+    from trlx_tpu.ops.delta_rule import kda_chunked, kda_step
+
+    one = SingleDeviceSharding(topo.devices[0])
+    place = lambda args: jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), args)
+
+    def loss(q, k, v, g, beta):
+        o, state = jax.checkpoint(kda_chunked)(q, k, v, g, beta)
+        return jnp.sum(o.astype(jnp.float32) ** 2) + jnp.sum(state)
+
+    f32 = lambda *shape: _s(shape, jnp.float32)
+    scan_args = (f32(2, 4096, 32, 128), f32(2, 4096, 32, 128), _s((2, 4096, 32, 128)), f32(2, 4096, 32, 128), f32(2, 4096, 32))
+    scan = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(*place(scan_args)).compile()
+    assert scan.memory_analysis().temp_size_in_bytes < 5 * 2**30
+    step_args = (f32(32, 32, 128, 128), f32(32, 32, 128), f32(32, 32, 128), _s((32, 32, 128)), f32(32, 32, 128), f32(32, 32))
+    step = jax.jit(kda_step).lower(*place(step_args)).compile()
+    scan_ops, step_ops = _executed(scan.as_text()), _executed(step.as_text())
+    scan_rx, step_rx = re.compile(_metric_pattern("kda_scan_device_ms")), re.compile(_metric_pattern("kda_step_device_ms"))
+    assert sum(bool(scan_rx.search(l)) for l in scan_ops) > 0.75 * len(scan_ops) > 100
+    assert sum(bool(step_rx.search(l)) for l in step_ops) >= 1
+    assert not any(step_rx.search(l) for l in scan_ops) and not any(scan_rx.search(l) for l in step_ops)
+
+
 def _assert_the_benchmark_finds_both_kernels(text):
     """Two Mosaic calls, and each of ``flash_fwd_device_ms`` /
     ``flash_bwd_device_ms``'s patterns matches exactly its own."""

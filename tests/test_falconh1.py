@@ -404,7 +404,7 @@ def program_fingerprints(family):
 
 
 @pytest.mark.parametrize("family", RECORDED_FAMILIES)
-def test_kv_only_presets_trace_to_the_programs_recorded_before_the_family(family):
+def test_kv_only_presets_trace_to_the_programs_recorded_before_the_family(family, clean_trace_state):
     """Recorded on the commit before the per-layer attention layouts (PR 33's
     parent) by this function (``python tests/test_falconh1.py`` there writes
     the file): parameter tree, cache tree and both jaxprs byte for byte, of

@@ -471,7 +471,7 @@ def program_fingerprints(family):
 
 
 @pytest.mark.parametrize("family", RECORDED_FAMILIES)
-def test_presets_trace_to_the_programs_recorded_before_the_family(family):
+def test_presets_trace_to_the_programs_recorded_before_the_family(family, clean_trace_state):
     """Recorded on PR 49's parent by this function (``python
     tests/test_minicpm_sala.py`` there writes the file), before ``LayerLayout``
     gained the layer's mixer, ``make_kv_cache`` its two new kinds of layer,

@@ -337,7 +337,7 @@ def ring_program_fingerprints():
 
 
 @pytest.mark.parametrize("program", ["cache", "prefill", "decode"])
-def test_ring_programs_are_the_ones_recorded_before_the_per_row_ring(program):
+def test_ring_programs_are_the_ones_recorded_before_the_per_row_ring(program, clean_trace_state):
     """Recorded on PR 46's parent by this function: ``make_kv_cache`` and
     ``_ring_plan`` took a ``[B]`` vector of cache indices, a span past slot 0
     and ``gamma`` more slots for a model that drafts, under this model's feet,

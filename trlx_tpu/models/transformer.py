@@ -202,6 +202,9 @@ QK_INIT_STD_MINICPM_SALA = 0.04
 # and a layer's two branches (x 0.2475) are a sixtieth of it: the first chip run read a float32
 # reference 0.0068 away and no fault of a mixer could have read more than twice that
 EMBED_INIT_STD_MINICPM_SALA = 1.0 / 12.0
+# the like for Kimi-Linear's latent layers, whose `q_proj` makes the queries from the normed stream with
+# no latent and no norm between (chipbench/configs/kimi-linear-48b-a3b-l8e32.json, `assumed`)
+QK_INIT_STD_KIMI_LINEAR = 0.08
 
 
 class LayerLayout(NamedTuple):
@@ -219,7 +222,9 @@ class LayerLayout(NamedTuple):
     # the layer's sequence mixer (`mixer_layout`): "attention" (`Attention`, or
     # `LatentAttention` under `kv_lora_rank`; where `mixer` is "mamba2", with
     # Mamba-2 heads BESIDE it in the block) | "lightning" (`LightningMixer`: a
-    # linear recurrence whose state is the layer's whole cache, no K or V)
+    # linear recurrence whose state is the layer's whole cache, no K or V) |
+    # "kda" (`KDAMixer`: a gated delta rule behind short convs; the state and
+    # the convs' last rows are the layer's whole cache)
     mixer: str = "attention"
 
 
@@ -473,6 +478,14 @@ class TransformerConfig:
     sparse_stride: int = 16  # ... and the step between two of them
     sparse_window: int = 2048  # a query keeps every block with a key this close behind it, and block 0
     sparse_dense_len: int = 8192
+    # Kimi Delta Attention layers (kimi_linear's `linear_attn_config`; the
+    # "kda" entries of `mixer_layout`, beside latent or K/V attention layers):
+    # `kda_heads` heads of `kda_head_dim` (q, k and v alike; the two gates'
+    # low rank too) behind causal depthwise convs of width `kda_conv`, through
+    # `KDAMixer` and `ops/delta_rule.py`
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv: int = 4
 
     def resolved_attention_impl(self) -> str:
         if self.attention_impl == "auto":
@@ -489,12 +502,19 @@ class TransformerConfig:
                 object.__setattr__(self, name, value)
         if self.mixer_layout is not None:
             kinds = tuple(str(m) for m in self.mixer_layout)
-            if len(kinds) < self.num_layers or set(kinds[: self.num_layers]) - {"attention", "lightning"}:
-                raise ValueError(f"mixer_layout needs one of attention | lightning for each of {self.num_layers} layers: {kinds}")
-            if self.mixer != "none" or self.latent_attention or self.mtp_layers or self.lightning_heads < 1 or self.lightning_head_dim < 2:
+            used = set(kinds[: self.num_layers])
+            if len(kinds) < self.num_layers or used - {"attention", "lightning", "kda"}:
+                raise ValueError(f"mixer_layout needs one of attention | lightning | kda for each of {self.num_layers} layers: {kinds}")
+            if self.mixer != "none" or self.mtp_layers or (
+                    "lightning" in used and (self.latent_attention or self.lightning_heads < 1 or self.lightning_head_dim < 2)):
                 raise ValueError(
                     "mixer_layout (lightning layers among attention layers) takes K/V attention layers, no second mixer "
                     "beside them, no next-token-prediction module, and lightning_heads heads of lightning_head_dim"
+                )
+            if "kda" in used and (self.index_topk or self.sparse_topk or self.kda_heads < 1 or self.kda_head_dim < 2 or self.kda_conv < 2):
+                raise ValueError(
+                    "mixer_layout (kda layers among attention layers) takes latent or K/V attention layers under no "
+                    "selection, kda_heads heads of kda_head_dim and a conv of kda_conv >= 2 taps"
                 )
             object.__setattr__(self, "mixer_layout", kinds)
         if self.sparse_topk:
@@ -521,10 +541,11 @@ class TransformerConfig:
         if self.moe_topk_method not in ("greedy", "noaux_tc"):
             raise ValueError(f"moe_topk_method {self.moe_topk_method!r} is not greedy or noaux_tc")
         if self.index_topk:
-            if not self.kv_lora_rank or self.sliding_window or self.index_head_dim < self.qk_rope_head_dim or self.index_heads < 1:
+            if (not self.kv_lora_rank or not self.q_lora_rank or self.sliding_window or self.index_head_dim < self.qk_rope_head_dim
+                    or self.index_heads < 1):
                 raise ValueError(
-                    "a learned selection (index_topk > 0) runs over a latent cache (kv_lora_rank > 0), no sliding "
-                    "window, with index_heads >= 1 heads of index_head_dim >= qk_rope_head_dim"
+                    "a learned selection (index_topk > 0) runs over a latent cache (kv_lora_rank > 0) from a query "
+                    "latent (q_lora_rank > 0), no sliding window, with index_heads >= 1 heads of index_head_dim >= qk_rope_head_dim"
                 )
             types = self.indexer_types
             if types is not None:  # a list from a JSON override
@@ -954,6 +975,66 @@ class TransformerConfig:
             attn_output_gate=True,
             embed_init_std=EMBED_INIT_STD_MINICPM_SALA,
             qk_init_std=QK_INIT_STD_MINICPM_SALA,
+        )
+
+    @staticmethod
+    def kimi_linear(size: str = "48b-a3b", **overrides) -> "TransformerConfig":
+        """Kimi-Linear-48B-A3B (``model_type`` ``kimi_linear``): 20 of 27
+        layers are Kimi Delta Attention (``KDAMixer``: 32 heads of 128 behind
+        causal depthwise convs of width 4, L2-normed q and k, a gated delta
+        rule whose decay is a vector a head, a per-head output norm under a
+        sigmoid gate; the layer's whole cache is its float32 state and the
+        convs' last rows), 7 (every fourth, and the last) latent attention
+        with NO query latent and NO rotary embedding (``LatentAttention``
+        under ``q_lora_rank`` 0 and ``LayerLayout.rotary`` False: the 64
+        "rope" dims are one un-rotated key the heads share); one leading dense
+        SwiGLU layer, then layers of 256 routed SwiGLU experts (sigmoid
+        scores, the eight largest of ``score + bias``, renormalised, times
+        2.446) beside one shared expert. ``mixer_layout`` says each layer's
+        kind, so a cut of the depth overrides ``num_layers`` alone. Limits:
+        the plain sampler, the scoring forward, the hydra branch and the train
+        step (``ops/paged_kv.py::refuse_recurrent_state``,
+        ``refuse_latent_cache``); no ``scan_layers``, no ring attention over
+        ``sequence``, no HF checkpoint import. ``qk_init_std`` is the stand-in
+        scale of the latent layers' ``q_proj``
+        (chipbench/configs/kimi-linear-48b-a3b-l8e32.json, `assumed`).
+        ``builtin:kimi-linear-48b-a3b`` | ``builtin:kimi-linear-test``."""
+        a, k = "attention", "kda"
+        dims = {
+            # the benchmark's cut in small: a dense KDA layer, two KDA expert layers, a latent expert layer; unlike
+            # sizes everywhere a test can tell them apart (q/k 20 = 12 + 8, v 16, KDA heads of 24), 8 experts
+            "test": dict(vocab_size=259, hidden_size=64, num_layers=4, num_heads=4, intermediate_size=128, max_position_embeddings=256,
+                         kv_lora_rank=16, qk_nope_head_dim=12, qk_rope_head_dim=8, v_head_dim=16,
+                         kda_heads=2, kda_head_dim=24, kda_conv=4, mixer_layout=(k, k, k, a),
+                         moe_intermediate_size=32, num_experts=8, num_experts_per_tok=2, first_k_dense=1, moe_bias_init_std=0.05),
+            "48b-a3b": dict(vocab_size=163840, hidden_size=2304, num_layers=27, num_heads=32, num_kv_heads=32, intermediate_size=9216, max_position_embeddings=1048576,
+                            kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+                            kda_heads=32, kda_head_dim=128, kda_conv=4, mixer_layout=(k, k, k, a) * 6 + (k, k, a),
+                            moe_intermediate_size=1024, num_experts=256, num_experts_per_tok=8, first_k_dense=1),
+        }[size]
+        overrides.setdefault("rope_layout", (0,) * len(overrides.get("mixer_layout", dims["mixer_layout"])))  # mla_use_nope
+        return _make_preset(
+            dims,
+            overrides,
+            model_type="kimi_linear",
+            position_scheme="rotary",  # (what `LatentAttention` is built under; no layer applies one: rope_layout)
+            rope_theta=10000.0,
+            norm="rmsnorm",
+            layer_norm_epsilon=1e-5,
+            activation="silu",
+            attn_bias=False,
+            mlp_bias=False,
+            tie_word_embeddings=False,
+            num_shared_experts=1,
+            moe_gated=True,
+            moe_scoring="sigmoid",
+            moe_topk_method="noaux_tc",
+            routed_scaling_factor=2.446,
+            moe_capacity_factor=0.0,  # dropless
+            moe_renormalize=True,
+            router_aux_coef=0.0,  # balance is the selection bias's work, not a loss's
+            embed_init_std=1.0,
+            qk_init_std=QK_INIT_STD_KIMI_LINEAR,
         )
 
     @staticmethod
@@ -1670,13 +1751,14 @@ def project(p: Dict[str, jax.Array], x: jax.Array, cfg: TransformerConfig) -> ja
 LATENT_MAX_TOKENS = 8192
 
 
-def latent_row_pieces(rows: int, width: int) -> int:
+def latent_row_pieces(rows: int, width: int, most: int = LATENT_MAX_TOKENS) -> int:
     """How many equal pieces of whole rows an expanded latent-attention pass
-    runs in: 1 up to ``LATENT_MAX_TOKENS`` tokens, else the fewest that divide
-    ``rows`` into pieces of at most that many (one row a piece at worst)."""
-    if rows * width <= LATENT_MAX_TOKENS:
+    (a KDA layer's, under its own ``most``) runs in: 1 up to ``most`` tokens,
+    else the fewest that divide ``rows`` into pieces of at most that many (one
+    row a piece at worst)."""
+    if rows * width <= most:
         return 1
-    fewest = -(-rows * width // LATENT_MAX_TOKENS)
+    fewest = -(-rows * width // most)
     return next((n for n in range(fewest, rows) if rows % n == 0), rows)
 
 
@@ -1995,9 +2077,13 @@ class Indexer(nn.Module):
 class LatentAttention(nn.Module):
     """Multi-head latent attention with an explicit latent cache.
 
-    ``cq = RMS(x Wqa)``; ``q = cq Wqb`` gives each head ``[q_n | q_r]``;
+    ``cq = RMS(x Wqa)``; ``q = cq Wqb`` gives each head ``[q_n | q_r]``
+    (under ``q_lora_rank`` 0 there is no query latent: ``q = x Wq``, ONE
+    ``q_proj``, no ``q_a_proj`` and no ``q_a_norm``, and no learned selection);
     ``[ckv | k_r] = x Wkva``, ``c = RMS(ckv)``, ONE ``k_r`` for all heads;
-    rotary embedding on ``q_r`` and ``k_r`` only (split-half pairs);
+    rotary embedding on ``q_r`` and ``k_r`` only (split-half pairs; none where
+    the layer's ``rotary`` is False, NoPE: both stay as projected, in the
+    expanded form, the absorbed form and the cache alike);
     ``[k_n | v]`` a head ``= c Wkvb``; scores ``(q_n . k_n + q_r . k_r) /
     sqrt(dn + dr)``, causal softmax in float32, output ``concat(sum p v) Wo``.
 
@@ -2043,6 +2129,7 @@ class LatentAttention(nn.Module):
     config: TransformerConfig
     indexer: Optional[str] = None  # this layer's LayerLayout.indexer
     lends: bool = False  # the next layer borrows the selection in force here
+    rotary: bool = True  # this layer's LayerLayout.rotary: False leaves `q_r` and `k_r` as projected (NoPE)
 
     @nn.compact
     def __call__(self, x, attention_bias, positions, cache=None, cache_index=None, flash_args=None, kv_extents=None, selection=None):
@@ -2063,20 +2150,27 @@ class LatentAttention(nn.Module):
             return nn.RMSNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                               scale_init=param_with_axes(nn.initializers.ones, ("latent",)), name=name)
 
-        cq = latent_norm("q_a_norm")(_dense(cfg, cfg.q_lora_rank, False, ("embed", "latent"), "q_a_proj")(x))
+        if cfg.q_lora_rank:
+            cq = latent_norm("q_a_norm")(_dense(cfg, cfg.q_lora_rank, False, ("embed", "latent"), "q_a_proj")(x))
+        else:  # no query latent: ONE projection `q_proj` from the layer's input, which stands where `cq` does below
+            cq = x
         kv_a = _dense(cfg, r + dr, False, ("embed", "latent"), "kv_a_proj")(x)
         c = latent_norm("kv_a_norm")(kv_a[..., :r])
-        q_b = _Projection(cfg, (cfg.q_lora_rank, H * (dn + dr)), ("latent", "joined_kv"), cfg.qk_init_std, name="q_b_proj")()
+        if cfg.q_lora_rank:
+            q_b = _Projection(cfg, (cfg.q_lora_rank, H * (dn + dr)), ("latent", "joined_kv"), cfg.qk_init_std, name="q_b_proj")()
+        else:
+            q_b = _Projection(cfg, (cfg.hidden_size, H * (dn + dr)), ("embed", "joined_kv"), cfg.qk_init_std, name="q_proj")()
         w_kvb = _Projection(cfg, (r, H * (dn + dv)), ("latent", "joined_kv"), name="kv_b_proj")()["kernel"].reshape(r, H, dn + dv)
         o = _Projection(cfg, (H * dv, cfg.hidden_size), ("joined_kv", "embed"), name="o_proj")()
 
         sin, cos = rotary_sin_cos(positions, dr, cfg.rope_theta)
-        k_r = apply_rotary(kv_a[..., None, r:], sin, cos, dr, True)[:, :, 0]  # [B, T, dr]: one head
+        roped = (lambda a, sin, cos: apply_rotary(a, sin, cos, dr, True)) if self.rotary else (lambda a, sin, cos: a)
+        k_r = roped(kv_a[..., None, r:], sin, cos)[:, :, 0]  # [B, T, dr]: one head
         index = Indexer(cfg, name="indexer")(cq, x, sin, cos) if self.indexer == "full" else None
 
         def queries(cq, sin, cos):
             q = project(q_b, cq, cfg).reshape(*cq.shape[:2], H, dn + dr)
-            return q[..., :dn], apply_rotary(q[..., dn:], sin, cos, dr, True)
+            return q[..., :dn], roped(q[..., dn:], sin, cos)
 
         new_cache = None
         if cache is not None:
@@ -2240,6 +2334,23 @@ class MLP(nn.Module):
         return _dense(cfg, cfg.hidden_size, cfg.mlp_bias, ("ffn", "embed"), "down_proj")(h)
 
 
+def _conv_taps_init(key, shape, dtype):
+    """torch's Conv1d default for a depthwise conv of width ``shape[0]``."""
+    bound = 1.0 / np.sqrt(shape[0])
+    return jax.random.uniform(key, shape, jnp.float32, -bound, bound).astype(dtype)
+
+
+# the Mamba-2 paper's initialisation: A uniform in [1, 16], dt log-uniform in
+# [0.001, 0.1] through dt_bias (softplus's inverse)
+def _a_log_init(key, shape, dtype):
+    return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0)).astype(dtype)
+
+
+def _dt_bias_init(key, shape, dtype):
+    dt0 = jnp.exp(jax.random.uniform(key, shape, jnp.float32, np.log(1e-3), np.log(1e-1)))
+    return (dt0 + jnp.log(-jnp.expm1(-dt0))).astype(dtype)
+
+
 class Mamba2Mixer(nn.Module):
     """Mamba-2 heads (``ops/ssd.py``): ``in_proj`` to ``z | x | B | C | dt``,
     a causal depthwise conv and SiLU over ``x, B, C``, the selective
@@ -2296,16 +2407,9 @@ class Mamba2Mixer(nn.Module):
         def vector(name, init, n):
             return self.param(name, param_with_axes(init, ("ssm",)), (n,), cfg.param_dtype)
 
-        K = cfg.mamba_conv
-
-        def conv_init(key, shape, dtype):
-            # torch's Conv1d default for a depthwise conv of width K
-            bound = 1.0 / np.sqrt(K)
-            return jax.random.uniform(key, shape, jnp.float32, -bound, bound).astype(dtype)
-
         conv_w = self.param(
-            "conv_weight", param_with_axes(conv_init, ("conv", "ssm")),
-            (K, cfg.mamba_conv_channels), cfg.param_dtype,
+            "conv_weight", param_with_axes(_conv_taps_init, ("conv", "ssm")),
+            (cfg.mamba_conv, cfg.mamba_conv_channels), cfg.param_dtype,
         )
         conv_b = vector("conv_bias", nn.initializers.zeros, cfg.mamba_conv_channels)
         xbc, conv_state = causal_conv(
@@ -2316,18 +2420,10 @@ class Mamba2Mixer(nn.Module):
             xbc = xbc * keep  # the conv's bias is not zero at a pad
         x, Bm, Cm = jnp.split(xbc, [d_ssm, d_ssm + gn], axis=-1)
 
-        # the Mamba-2 paper's initialisation: A uniform in [1, 16], dt
-        # log-uniform in [0.001, 0.1] through dt_bias (softplus's inverse), D = 1
-        def a_log_init(key, shape, dtype):
-            return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0)).astype(dtype)
-
-        def dt_bias_init(key, shape, dtype):
-            dt0 = jnp.exp(jax.random.uniform(key, shape, jnp.float32, np.log(1e-3), np.log(1e-1)))
-            return (dt0 + jnp.log(-jnp.expm1(-dt0))).astype(dtype)
-
-        A = -jnp.exp(vector("A_log", a_log_init, H).astype(jnp.float32))
+        # (`_a_log_init`, `_dt_bias_init`: the paper's initialisation; D = 1)
+        A = -jnp.exp(vector("A_log", _a_log_init, H).astype(jnp.float32))
         D = vector("D", nn.initializers.ones, H)
-        dt = jax.nn.softplus(dt.astype(jnp.float32) + vector("dt_bias", dt_bias_init, H).astype(jnp.float32))
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + vector("dt_bias", _dt_bias_init, H).astype(jnp.float32))
 
         x, Bm, Cm = x.reshape(B, T, H, P), Bm.reshape(B, T, G, N), Cm.reshape(B, T, G, N)
         state = None if cache is None else cache["ssm"]
@@ -2415,6 +2511,133 @@ class LightningMixer(nn.Module):
         y = Norm(cfg, name="o_norm")(y.reshape(B, T, H * D)) * gate
         out = _dense(cfg, cfg.hidden_size, False, ("joined_kv", "embed"), "o_proj")(y)
         return out, (None if cache is None else {"state": state})
+
+
+# A KDA layer's pass builds q, k, v, the log decays (float32) and the output of
+# `kda_heads x kda_head_dim` a token, and the chunked delta rule some fifteen
+# float32 arrays of that size beside them (ops/delta_rule.py): at 32 heads of
+# 128 that is 16 KB a token an array, 2.1 GB an array for the 131,072 tokens of
+# a 32-row scoring forward at width 4096. Rows do not interact, so past
+# KDA_MAX_TOKENS the mixer runs equal pieces of whole rows, of at most that
+# many tokens, one after another (`latent_row_pieces`'s arithmetic). A constant
+# with its arithmetic, not a setting.
+KDA_MAX_TOKENS = 8192
+
+
+class KDAMixer(nn.Module):
+    """Kimi Delta Attention (Kimi Linear report, arXiv:2510.26692) as
+    ``kimi_linear`` runs it: ``q~, k~, v~`` of ``kda_heads`` heads of
+    ``kda_head_dim`` each through its own causal depthwise conv of width
+    ``kda_conv`` (no bias) and ``silu``; ``q = l2norm(q~) / sqrt(d)``, ``k =
+    l2norm(k~)`` a head; a log decay a CHANNEL ``g = -exp(A_log_h)
+    softplus(f_b(f_a u) + dt_bias)`` and a write strength a head ``beta =
+    sigmoid(b u)``; per head the gated delta rule of ``ops/delta_rule.py``
+    (float32); ``o_proj(concat_h(RMSNorm_d(o_h) * sigmoid(g_b(g_a u))_h))``, the
+    norm over each head's own channels with one learned scale of ``d``.
+
+    The layer's whole cache is ``{"state": [B, H, d, d]}`` float32 (key
+    channels by value channels) and ``{"conv": [B, kda_conv - 1, 3 H d]}``,
+    the last rows of ``[q~ | k~ | v~]`` before the convs; no K, no V: one
+    token takes ``kda_step``, a span (prefill) the chunked form from the
+    stored state, a pass without a cache the chunked form from zero, run
+    again in the backward pass rather than kept (``LightningMixer``), in
+    pieces of whole rows where the pass is long (``KDA_MAX_TOKENS``).
+    ``token_mask`` marks real tokens: a padded position feeds nothing into
+    the conv window or the state and does not decay it.
+
+    Returns ``(out, new cache, statistics)``; the statistics of a pass
+    without a cache are ``[sum of beta over real tokens (mean over heads),
+    real tokens, the most negative log decay accumulated inside a chunk]``
+    (``kda_summary``), else None."""
+
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, u, cache=None, token_mask=None):
+        from trlx_tpu.ops.delta_rule import CHUNK, kda_chunked, kda_step
+        from trlx_tpu.ops.ssd import causal_conv
+
+        cfg = self.config
+        B, T, E = u.shape
+        H, D, K = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_conv
+        W = H * D
+
+        def proj(name, shape, axes):
+            return _Projection(cfg, shape, axes, name=name)()
+
+        def vector(name, init, n):
+            return self.param(name, param_with_axes(init, ("ssm",)), (n,), cfg.param_dtype)
+
+        qkv = [proj(name, (E, W), ("embed", "joined_kv")) for name in ("q_proj", "k_proj", "v_proj")]
+        f_a, f_b = proj("f_a_proj", (E, D), ("embed", "latent")), proj("f_b_proj", (D, W), ("latent", "joined_kv"))
+        g_a, g_b = proj("g_a_proj", (E, D), ("embed", "latent")), proj("g_b_proj", (D, W), ("latent", "joined_kv"))
+        b_proj = proj("b_proj", (E, H), ("embed", "heads"))
+        o_proj = proj("o_proj", (W, E), ("joined_kv", "embed"))
+
+        # the Mamba-2 convention, as `Mamba2Mixer` draws its own: A a head, a step a channel through dt_bias
+        conv_w = self.param("conv_weight", param_with_axes(_conv_taps_init, ("conv", "ssm")), (K, 3 * W), cfg.param_dtype)
+        rate = jnp.exp(vector("A_log", _a_log_init, H).astype(jnp.float32))[:, None]  # [H, 1]
+        dt_bias = vector("dt_bias", _dt_bias_init, W).astype(jnp.float32)
+        o_scale = vector("o_norm_scale", nn.initializers.ones, D).astype(jnp.float32)
+        step = cache is not None and T == 1
+
+        def rows(u, keep, conv_state, state):
+            """Whole rows ``u [b, T, E]`` with their mask ``keep [b, T]`` (or
+            None) and, under a cache, their conv rows and state."""
+            b = u.shape[0]
+            real = None if keep is None else keep.reshape(b, T, 1).astype(u.dtype)
+            if real is not None:
+                u = u * real  # no bias anywhere below: a padded token's q~, k~, v~ are zero
+            with jax.named_scope("trlx/kda_conv"):
+                x = jnp.concatenate([project(p, u, cfg) for p in qkv], axis=-1)
+                x, conv_state = causal_conv(x, conv_w, None, conv_state)
+                q, k, v = jnp.split(nn.silu(x), 3, axis=-1)
+                q, k = q.astype(jnp.float32).reshape(b, T, H, D), k.astype(jnp.float32).reshape(b, T, H, D)
+                q = q * (jax.lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True) + 1e-6) / np.sqrt(D))
+                k = k * jax.lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + 1e-6)
+                v = v.reshape(b, T, H, D)
+            with jax.named_scope("trlx/kda_gate"):
+                f = project(f_b, project(f_a, u, cfg), cfg).astype(jnp.float32) + dt_bias
+                g = -rate * jax.nn.softplus(f).reshape(b, T, H, D)
+                beta = jax.nn.sigmoid(project(b_proj, u, cfg).astype(jnp.float32))
+                gate = jax.nn.sigmoid(project(g_b, project(g_a, u, cfg), cfg))
+                if real is not None:  # ... and neither decays the state nor writes to it
+                    g, beta = g * real[..., None].astype(jnp.float32), beta * real.astype(jnp.float32)
+            stats = None
+            if step:
+                o, state = kda_step(state, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
+                o = o[:, None]
+            else:
+                o, state = kda_chunked(q, k, v, g, beta, state)
+                if cache is None:
+                    in_chunk = jnp.pad(g, ((0, 0), (0, -T % CHUNK), (0, 0), (0, 0))).reshape(b, -1, CHUNK, H, D)
+                    stats = jax.lax.stop_gradient(jnp.stack([
+                        jnp.sum(jnp.mean(beta, axis=-1)), float(b * T) if keep is None else jnp.sum(keep.astype(jnp.float32)),
+                        jnp.min(jnp.sum(in_chunk, axis=2)),
+                    ]))
+            # the norm over each head's own channels, float32 statistics, then the gate
+            o = o.astype(jnp.float32)
+            o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + cfg.layer_norm_epsilon) * o_scale
+            out = project(o_proj, o.reshape(b, T, W).astype(cfg.dtype) * gate, cfg)
+            return out, conv_state, state, stats
+
+        conv_state, state = (None, None) if cache is None else (cache["conv"], cache["state"])
+        pieces = 1 if step else latent_row_pieces(B, T, KDA_MAX_TOKENS)
+        if cache is None:
+            # a backward pass runs a piece again rather than keep its float32 gates, chunk matrices and states
+            rows = jax.checkpoint(rows)
+        if pieces == 1:
+            out, conv_state, state, stats = rows(u, token_mask, conv_state, state)
+        else:
+            split = lambda a: a.reshape(pieces, B // pieces, *a.shape[1:])
+            join = lambda a: a.reshape(B, *a.shape[2:])
+            operands = jax.tree_util.tree_map(split, (u, token_mask, conv_state, state))
+            out, conv_state, state, stats = jax.lax.map(lambda piece: rows(*piece), operands)
+            out, conv_state, state = jax.tree_util.tree_map(join, (out, conv_state, state))
+            if stats is not None:
+                stats = jnp.stack([jnp.sum(stats[:, 0]), jnp.sum(stats[:, 1]), jnp.min(stats[:, 2])])
+        new_cache = None if cache is None else {"state": state, "conv": conv_state.astype(cache["conv"].dtype)}
+        return out, new_cache, stats
 
 
 @functools.lru_cache(maxsize=None)
@@ -2808,8 +3031,29 @@ def aux_size(cfg: TransformerConfig) -> int:
     expert / mean)·tokens] and, where the layer holds a share of its experts,
     [assignments that fell on a held expert, (busiest held expert / mean of
     the held)·tokens]; then, where the layers have shared experts, [rows
-    through the shared expert, Σ chosen raw router scores]."""
+    through the shared expert, Σ chosen raw router scores]; then, where
+    layers run ``KDAMixer``, [Σ beta, real tokens, one slot a KDA layer that
+    only it writes] (``kda_summary``)."""
+    kda = kda_layers(cfg)
+    return _moe_aux_size(cfg) + (2 + len(kda) if kda else 0)
+
+
+def _moe_aux_size(cfg: TransformerConfig) -> int:
     return 6 + 2 * _holds_share(cfg) + 2 * bool(cfg.num_shared_experts)
+
+
+def kda_layers(cfg: TransformerConfig) -> Tuple[int, ...]:
+    """The layers that run ``KDAMixer``."""
+    return tuple(i for i in range(cfg.num_layers) if cfg.mixer_layout and cfg.mixer_layout[i] == "kda")
+
+
+def kda_summary(aux: jax.Array, cfg: TransformerConfig) -> jax.Array:
+    """``[log_decay_min, beta_mean]`` of a pass's KDA layers: the most
+    negative log decay accumulated inside any chunk of any of them (what the
+    chunked delta rule's exponents must survive: ``ops/delta_rule.py``), and
+    the mean write strength over their real tokens and heads."""
+    m = _moe_aux_size(cfg)
+    return jnp.stack([jnp.min(aux[m + 2 :]), aux[m] / jnp.maximum(aux[m + 1], 1.0)])
 
 
 def _holds_share(cfg: TransformerConfig) -> bool:
@@ -2912,7 +3156,7 @@ class Block(nn.Module):
         def run_mlp(h):
             if sparse:
                 return MoEMLP(cfg, name="mlp")(h, token_mask, router_input)
-            return MLP(cfg, name="mlp")(h), jnp.zeros((aux_size(cfg),), jnp.float32)
+            return MLP(cfg, name="mlp")(h), jnp.zeros((_moe_aux_size(cfg),), jnp.float32)
 
         h = Norm(cfg, name="ln_attn")(x)
         if cfg.mixer == "mamba2":
@@ -2926,11 +3170,14 @@ class Block(nn.Module):
             x = x + mix_out * cfg.ssm_out_multiplier + attn_out * cfg.attention_out_multiplier
             mlp_out, aux = run_mlp(Norm(cfg, name="ln_mlp")(x))
             return x + mlp_out, new_cache, aux, None
+        kda_stats = None
         if layout.mixer == "lightning":
             attn_out, new_cache = LightningMixer(cfg, name="attn")(h, positions, cache, token_mask)
+        elif layout.mixer == "kda":
+            attn_out, new_cache, kda_stats = KDAMixer(cfg, name="attn")(h, cache, token_mask)
         elif cfg.latent_attention:
             lends = self.layer + 1 < cfg.num_layers and cfg.layer_layout(self.layer + 1).indexer == "shared"
-            attn_out, new_cache, selection = LatentAttention(cfg, layout.indexer, lends, name="attn")(
+            attn_out, new_cache, selection = LatentAttention(cfg, layout.indexer, lends, rotary, name="attn")(
                 h, attention_bias, positions, cache, cache_index, flash_args, kv_extents, selection
             )
         else:
@@ -2949,6 +3196,12 @@ class Block(nn.Module):
             if cfg.sandwich_norm:
                 mlp_out = Norm(cfg, name="ln_mlp_post")(mlp_out)
             x = x + (mlp_out * cfg.residual_multiplier if scaled else mlp_out)
+        kda = kda_layers(cfg)
+        if kda:  # [sum of beta, real tokens, one slot a KDA layer for its most negative chunk decay] behind the experts' statistics
+            own = jnp.zeros((2 + len(kda),), jnp.float32)
+            if kda_stats is not None:
+                own = own.at[:2].set(kda_stats[:2]).at[2 + kda.index(self.layer)].set(kda_stats[2])
+            aux = jnp.concatenate([aux, own])
         return x, new_cache, aux, selection
 
 
@@ -3382,6 +3635,8 @@ class CausalTransformer(nn.Module):
             out["router_load"] = router_load_summary(aux, cfg)
             if cfg.num_shared_experts:
                 out["router_shared"] = shared_expert_summary(aux, cfg)
+        if aux is not None and new_cache is None and kda_layers(cfg):  # a whole pass (the decode loop carries no statistics)
+            out["kda_stats"] = kda_summary(aux, cfg)
         return out
 
     def _pipelined_blocks(
@@ -3564,7 +3819,8 @@ def make_kv_cache(
     state, float32 whatever ``dtype``: hundreds of steps of ``S = aS + ...``
     drift in bf16) and ``conv`` (the conv's last ``K - 1`` input rows). A
     ``lightning`` layer (``mixer_layout``) holds ``state`` ``[B, heads, d, d]``
-    float32 and nothing else; an attention layer under a block selection
+    float32 and nothing else; a ``kda`` layer ``state`` ``[B, heads, d, d]``
+    float32 and ``conv`` ``[B, kda_conv - 1, 3 heads d]`` and nothing else; an attention layer under a block selection
     (``sparse_topk`` > 0) holds ``kbar`` ``[B, KV, max_length / sparse_stride,
     D]`` beside ``k`` and ``v``, the keys' mean-pool its decode steps score. A
     latent-attention layer (``kv_lora_rank`` > 0) holds ``ckv`` ``[B, slots,
@@ -3587,6 +3843,15 @@ def make_kv_cache(
         # tokens a round: the write of the span's last must not land on the slot the
         # span's first still reads (`_ring_plan`)
         slots = min(max_length, layout.window + cfg.mtp_layers) if layout.window else max_length
+        if layout.mixer == "kda":
+            # the layer's whole cache: the delta rule's state (key channels by value channels, float32 as `ssm`
+            # is) and the last `kda_conv - 1` rows of [q~ | k~ | v~] before the convs; no K, V or latent
+            # (ops/paged_kv.py::RECURRENT_LEAVES)
+            heads, d = cfg.kda_heads, cfg.kda_head_dim
+            return {
+                "state": jnp.zeros(stacked + (batch_size, heads, d, d), jnp.float32),
+                "conv": jnp.zeros(stacked + (batch_size, cfg.kda_conv - 1, 3 * heads * d), dtype),
+            }
         if cfg.latent_attention:
             # the normed latent and the one roped key (ops/paged_kv.py::LATENT_LEAVES), the
             # same slot axis and cache_index as K and V have, and no K or V; side by side
@@ -3672,6 +3937,7 @@ BUILTIN_SPECS = {
     "glm": TransformerConfig.glm,
     "k-exaone": TransformerConfig.exaone,
     "minicpm-sala": TransformerConfig.minicpm_sala,
+    "kimi-linear": TransformerConfig.kimi_linear,
     "gptj": TransformerConfig.gptj,
     "gptneox": TransformerConfig.gptneox,
     "pythia": TransformerConfig.gptneox,

@@ -277,6 +277,7 @@ def kv_bytes(cache: Any) -> int:
 # what a `mixer: mamba2` layer keeps a sequence beside K and V
 # (models/transformer.py::make_kv_cache): the state and the conv's last rows;
 # and what a `lightning` layer (`mixer_layout`) keeps IN PLACE of K and V: its state
+# (a `kda` layer: its state and its convs' last rows, `conv` again)
 RECURRENT_LEAVES = ("ssm", "conv", "state")
 
 # what an attention layer under a block selection (`sparse_topk`) keeps beside
@@ -285,8 +286,12 @@ POOLED_LEAVES = ("kbar",)
 
 
 def linear_state_bytes(cache: Any) -> int:
-    """Bytes of the ``state`` leaves alone: the lightning layers' whole cache."""
-    return _named_leaf_bytes(cache, ("state",))
+    """Bytes of the layers whose whole cache is a linear recurrence's: a
+    ``state`` leaf (a lightning layer's) and the ``conv`` rows beside one (a
+    KDA layer's; a ``mixer: mamba2`` layer's ``conv`` lies beside ``ssm``, K
+    and V and is not counted here)."""
+    layers = cache if isinstance(cache, (list, tuple)) else [cache]
+    return sum(_named_leaf_bytes(layer, ("state", "conv")) for layer in layers if isinstance(layer, dict) and "state" in layer)
 
 
 def pooled_key_bytes(cache: Any) -> int:
@@ -327,7 +332,7 @@ def refuse_recurrent_state(cache: Any, path: str) -> None:
         raise NotImplementedError(
             f"{path} does not support a model whose cache holds recurrent state "
             f"(leaves {RECURRENT_LEAVES}: `mixer: mamba2`, the falcon_h1 family; a `lightning` "
-            f"layer of `mixer_layout`, the minicpm_sala family): "
+            f"layer of `mixer_layout`, the minicpm_sala family; a `kda` layer, the kimi_linear family): "
             f"{_KV_ONLY_PATHS[path]}; use the plain sampler (ROADMAP.md queue 2, B7)"
         )
     if pooled_key_bytes(cache):
@@ -391,8 +396,8 @@ def refuse_latent_cache(cache: Any, path: str) -> None:
             )
         raise NotImplementedError(
             f"{path} does not support a model whose cache holds a latent in place of K and V "
-            f"(leaves {LATENT_LEAVES}: latent attention, `kv_lora_rank` > 0, the pangu_ultra_moe "
-            f"and glm_moe_dsa families){riding}: {_PER_HEAD_KV_PATHS[path]}; use the plain sampler "
+            f"(leaves {LATENT_LEAVES}: latent attention, `kv_lora_rank` > 0, the pangu_ultra_moe, "
+            f"glm_moe_dsa and kimi_linear families){riding}: {_PER_HEAD_KV_PATHS[path]}; use the plain sampler "
             "(ROADMAP.md queue 2, B4)"
         )
 

@@ -18,6 +18,12 @@ on every token (``dt`` of ``[1, T, H]`` ones: whatever depends on the steps
 alone, the decays inside a chunk, is then built once and not once a row),
 ``G = H`` and no skip (``D`` None).
 
+What it does NOT compute: a recurrence whose update is not a plain decay by
+a scalar a head a token. A delta rule (the state corrected by what it already
+answers for the key) under a decay a channel is ``ops/delta_rule.py``
+(``kda_chunked``, ``kda_step``: Kimi Delta Attention), which shares only
+``causal_conv`` with this file.
+
 Everything is XLA: no Pallas kernel. The decay arithmetic and every
 accumulation are float32; the matmul operands keep the dtype of ``x`` (bf16
 where the model computes in bf16); the state is float32 always.
@@ -144,7 +150,7 @@ def ssd_step(
 def causal_conv(
     x: jax.Array,  # [B, T, C]
     weight: jax.Array,  # [K, C] depthwise taps, oldest first
-    bias: jax.Array,  # [C]
+    bias: Optional[jax.Array],  # [C]; None = no bias
     conv_state: Optional[jax.Array] = None,  # [B, K - 1, C] rows before x
 ) -> Tuple[jax.Array, jax.Array]:
     """``y_t = bias + sum_k weight[k] * x_{t - (K-1) + k}`` over the channel's
@@ -157,7 +163,7 @@ def causal_conv(
         if conv_state is None:
             conv_state = jnp.zeros((Bsz, K - 1, Cn), x.dtype)
         seen = jnp.concatenate([conv_state.astype(x.dtype), x], axis=1)  # [B, K-1+T, C]
-        y = bias.astype(x.dtype)
-        for k in range(K):
+        y = seen[:, :T] * weight[0].astype(x.dtype) if bias is None else bias.astype(x.dtype)
+        for k in range(bias is None, K):
             y = y + seen[:, k : k + T] * weight[k].astype(x.dtype)
         return y, seen[:, T:]

@@ -664,6 +664,9 @@ class TPUBaseTrainer(BaseRLTrainer):
         every ``loss_fn`` routes its return through here so any trainer can
         drive a mixture-of-experts policy."""
         loss, stats = loss_stats
+        kda = out.get("kda_stats") if isinstance(out, dict) else None
+        if kda is not None:  # layers under a gated delta rule (KDAMixer): what its chunked form must survive, and how hard it writes
+            stats = dict(stats, **{"learn/kda_log_decay_min": kda[0], "learn/kda_beta_mean": kda[1]})
         aux = out.get("router_aux_loss") if isinstance(out, dict) else None
         if aux is None:
             return loss, stats
