@@ -587,6 +587,27 @@ def _k_fused_sample(size: Size):
     return np.asarray(lp), np.asarray(rlp)
 
 
+def _k_kda_scan(size: Size):
+    """The chunked delta rule at the published head size: 2 rows of 200
+    tokens (a last chunk that is not whole), 2 heads of 128, from a non-zero
+    state; float32 throughout, so the kernel's six-pass products must land
+    on the ``jax.numpy`` form's. ``kda_chunked`` reads the backend itself."""
+    import jax
+    import jax.numpy as jnp
+
+    from trlx_tpu.ops.delta_rule import kda_chunked, kda_chunked_reference
+
+    ks = jax.random.split(jax.random.PRNGKey(8), 6)
+    shape = (2, 200, 2, 128)
+    q, k, v = (jax.random.normal(key, shape, jnp.float32) for key in ks[:3])
+    q, k = q / jnp.linalg.norm(q, axis=-1, keepdims=True), k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    g = -jnp.exp(jax.random.uniform(ks[3], shape, minval=np.log(1e-3), maxval=np.log(1.6)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], shape[:3]))
+    args = (q, k, v, g, beta, jax.random.normal(ks[5], (2, 2, 128, 128)))
+    flat = lambda out: np.concatenate([np.asarray(a, np.float32).ravel() for a in out])
+    return flat(jax.jit(kda_chunked)(*args)), flat(jax.jit(kda_chunked_reference)(*args))
+
+
 # flavor -> (check, tolerance relative to max(1, max|reference|)). bf16
 # kernels against bf16/f32 references: a few output roundings (2^-8 each).
 KERNEL_CHECKS = {
@@ -596,6 +617,7 @@ KERNEL_CHECKS = {
     "paged-prefill": (_k_paged_prefill, 2e-2),
     "paged-verify": (_k_paged_verify, 2e-2),
     "fused-sample": (_k_fused_sample, 1e-4),
+    "kda-scan": (_k_kda_scan, 1e-4),
 }
 
 

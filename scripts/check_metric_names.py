@@ -25,6 +25,7 @@ from trlx_tpu.analysis.conventions import (  # noqa: E402,F401
     ENGINE_KEYS,
     FLIGHTREC_KEYS,
     HEALTH_KEYS,
+    LEARN_KERNEL_KEYS,
     LEGACY_KEYS,
     OBS_KEYS,
     RESILIENCE_KEYS,

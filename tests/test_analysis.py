@@ -1694,11 +1694,12 @@ def test_kernel_parity_registry_on_real_tree():
     # the current kernel surface: flash fwd + fused bwd and the same pair
     # under a selection (`flash_attention(..., selection=)`, its own two
     # call sites behind the same entry), paged decode, fused sampling,
-    # paged prefill
-    assert len(sites) == 7, sorted(
+    # paged prefill, the chunked delta rule's forward
+    assert len(sites) == 8, sorted(
         (s.mod.relpath, s.fn.qualname if s.fn else "<module>") for s in sites
     )
     assert {s.mod.relpath for s in sites} == {
+        "trlx_tpu/ops/delta_rule.py",
         "trlx_tpu/ops/flash_attention.py",
         "trlx_tpu/ops/paged_attention.py",
         "trlx_tpu/ops/paged_prefill.py",
@@ -1706,7 +1707,7 @@ def test_kernel_parity_registry_on_real_tree():
     flavors = {flavor for flavor, _, _, _ in KERNEL_PARITY}
     assert flavors == {
         "paged-decode", "paged-prefill", "paged-verify", "fused-sample",
-        "flash-fwd", "flash-bwd",
+        "flash-fwd", "flash-bwd", "kda-scan",
     }
     for flavor, entry, reference, test_path in KERNEL_PARITY:
         assert g.resolve_root_names([entry]), f"{flavor}: entry `{entry}`"

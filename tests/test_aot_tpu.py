@@ -147,6 +147,19 @@ def _fused_sample():
     )
 
 
+def _kda_scan():
+    """The toy cannot reach this flavor (its heads are 24 wide): two heads of
+    128 over 104 tokens, v in bf16 as the trainer runs it. The cell's own
+    piece is ``test_the_chunked_delta_rule_compiles_at_the_kimi_cells_piece``."""
+    from trlx_tpu.ops import delta_rule
+
+    x, f32 = _s((B, T, 2, 128)), _s((B, T, 2, 128), jnp.float32)
+    return (
+        lambda *a: delta_rule._scan_with_xla_backward(*a, delta_rule.CHUNK, False),
+        (f32, f32, x, f32, _s((B, T, 2), jnp.float32), _s((B, 2, 128, 128), jnp.float32)),
+    )
+
+
 _BUILDERS = {
     "flash-fwd": _flash_fwd,
     "flash-bwd": _flash_bwd,
@@ -154,6 +167,7 @@ _BUILDERS = {
     "paged-prefill": _paged_prefill,
     "paged-verify": _paged_verify,
     "fused-sample": _fused_sample,
+    "kda-scan": _kda_scan,
 }
 
 def test_table_covers_the_registry():
@@ -354,19 +368,23 @@ def _executed(text):
     return out
 
 
-def test_the_chunked_delta_rule_compiles_at_the_kimi_cells_piece_and_the_benchmark_finds_it(topo):
+def test_the_chunked_delta_rule_compiles_at_the_kimi_cells_piece_and_the_benchmark_finds_it(topo, monkeypatch):
     """``ops/delta_rule.py`` at the Kimi-Linear cell's shapes: the chunked form
-    on a piece of 2 rows of 4096 tokens, 32 heads of 128, forward and (run
-    again under ``jax.checkpoint``) backward, inside the chip's memory with
-    room for what the program holds; and the one-token step on 32 rows.
+    on a piece of 2 rows of 4096 tokens, 32 heads of 128, forward (the Pallas
+    kernel) and, run again under ``jax.checkpoint``, backward (the
+    ``jax.numpy`` form differentiated), inside the chip's memory with room for
+    what the program holds; the prefill's piece of 3072 tokens from a stored
+    state, forward alone; and the one-token step on 32 rows.
     ``kda_scan_device_ms`` reads its events by result shape and
-    ``kda_step_device_ms`` by the state's shape in a fusion's text: both
-    patterns find the bulk of their own program's instructions and none of
-    the other's."""
+    ``kda_step_device_ms`` by the state's shape in a fusion's text: the scan's
+    pattern finds the kernel's call (by the piece's final state among its
+    results) and the bulk of the backward's instructions, and neither pattern
+    any of the other's program."""
     import re
 
-    from trlx_tpu.ops.delta_rule import kda_chunked, kda_step
+    from trlx_tpu.ops.delta_rule import KERNEL_NAME, kda_chunked, kda_step
 
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the choice of Mosaic over the interpreter, as the chip answers it
     one = SingleDeviceSharding(topo.devices[0])
     place = lambda args: jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), args)
 
@@ -378,10 +396,16 @@ def test_the_chunked_delta_rule_compiles_at_the_kimi_cells_piece_and_the_benchma
     scan_args = (f32(2, 4096, 32, 128), f32(2, 4096, 32, 128), _s((2, 4096, 32, 128)), f32(2, 4096, 32, 128), f32(2, 4096, 32))
     scan = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(*place(scan_args)).compile()
     assert scan.memory_analysis().temp_size_in_bytes < 5 * 2**30
+    prefill_args = tuple(_s((2, 3072) + a.shape[2:], a.dtype) for a in scan_args) + (f32(2, 32, 128, 128),)
+    prefill = jax.jit(kda_chunked).lower(*place(prefill_args)).compile()
+    assert prefill.memory_analysis().temp_size_in_bytes < 2**30  # no float32 chunk arrays: the layout copies of q, k, v, g at most
     step_args = (f32(32, 32, 128, 128), f32(32, 32, 128), f32(32, 32, 128), _s((32, 32, 128)), f32(32, 32, 128), f32(32, 32))
     step = jax.jit(kda_step).lower(*place(step_args)).compile()
-    scan_ops, step_ops = _executed(scan.as_text()), _executed(step.as_text())
     scan_rx, step_rx = re.compile(_metric_pattern("kda_scan_device_ms")), re.compile(_metric_pattern("kda_step_device_ms"))
+    for program in (scan, prefill):  # one call a pass, named, and the metric's pattern finds it
+        calls = [l.strip().removeprefix("ROOT ") for l in program.as_text().splitlines() if 'custom_call_target="tpu_custom_call"' in l]
+        assert len(calls) == 1 and calls[0].startswith(f"%{KERNEL_NAME}") and scan_rx.search(calls[0]) and not step_rx.search(calls[0]), calls
+    scan_ops, step_ops = _executed(scan.as_text()), _executed(step.as_text())
     assert sum(bool(scan_rx.search(l)) for l in scan_ops) > 0.75 * len(scan_ops) > 100
     assert sum(bool(step_rx.search(l)) for l in step_ops) >= 1
     assert not any(step_rx.search(l) for l in scan_ops) and not any(scan_rx.search(l) for l in step_ops)

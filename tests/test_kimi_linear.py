@@ -559,6 +559,7 @@ def test_train_runs_ppo_with_adapters_through_both_kinds_of_layer(tmp_path):
     assert collection["rollout/latent_cache_bytes"] > 0 and collection["rollout/kv_cache_bytes"] == 0
     step = next(r for r in records if "time/train_step" in r)
     assert step["learn/kda_log_decay_min"] < 0.0 and 0.0 < step["learn/kda_beta_mean"] < 1.0
+    assert step["learn/kda_scan_pallas"] == 0.0  # heads of 24: the jax.numpy form (heads of whole lanes take the kernel: tests/test_delta_rule_kernel.py)
     assert 0.0 < step["moe/held_frac"] < 1.0
     assert np.isfinite([v for k, v in step.items() if k.startswith("losses/")]).all()
     changed = set()

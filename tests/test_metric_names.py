@@ -128,6 +128,17 @@ def test_scanner_sees_the_codebase():
     assert "learn/attn_interior_frac" in keys
 
 
+def test_learn_kernel_gauges_registered_and_visible():
+    """The learner's ``*_pallas`` gauges (which form a pass took) are
+    registered, namespaced, and literal ``stats[...]`` sites the scanner
+    sees."""
+    checker = _load_checker()
+    assert checker.LEARN_KERNEL_KEYS == {"learn/kda_scan_pallas"}
+    keys = checker.scanned_keys()
+    for key in checker.LEARN_KERNEL_KEYS:
+        assert checker._CONVENTION_RE.match(key) and key.endswith("_pallas") and key in keys, key
+
+
 def test_engine_keys_registered_and_namespaced():
     """Every canonical engine/* + memory gauge key (docs/PERFORMANCE.md) is
     registered in the checker, follows the namespace/name convention, and

@@ -146,6 +146,15 @@ ENGINE_KEYS = frozenset({
     "engine/host_tier_tokens_saved",
 })
 
+# Which form a learner's pass took (0/1), by the ``*_pallas`` convention
+# (analysis/kernels.py GL1002): the chunked delta rule of a model with
+# ``KDAMixer`` layers, stamped in trainer/base.py::with_router_aux from the
+# function that makes the choice (ops/delta_rule.py::scan_takes_kernel: the
+# Pallas kernel at a head size of whole lanes, else the jax.numpy form)
+LEARN_KERNEL_KEYS = frozenset({
+    "learn/kda_scan_pallas",
+})
+
 # Canonical serving-frontend keys (trlx_tpu/serve/, docs/SERVING.md): the
 # FLAT aggregate gauges ServeMetrics.metrics() emits into the training
 # metric stream — TTFT/TPOT/queue-wait percentiles over all serve traffic,

@@ -108,6 +108,10 @@ KERNEL_PARITY: Tuple[Tuple[str, str, str, str], ...] = (
     # flash attention fused backward (dq+dk+dv)
     ("flash-bwd", "flash_attention_bwd_chunk",
      "attention_reference", "tests/test_flash_attention.py"),
+    # the chunked gated delta rule's forward (Kimi Delta Attention, PR 55);
+    # its backward differentiates the reference
+    ("kda-scan", "kda_chunked",
+     "kda_chunked_reference", "tests/test_delta_rule_kernel.py"),
 )
 
 

@@ -2514,13 +2514,16 @@ class LightningMixer(nn.Module):
 
 
 # A KDA layer's pass builds q, k, v, the log decays (float32) and the output of
-# `kda_heads x kda_head_dim` a token, and the chunked delta rule some fifteen
-# float32 arrays of that size beside them (ops/delta_rule.py): at 32 heads of
-# 128 that is 16 KB a token an array, 2.1 GB an array for the 131,072 tokens of
-# a 32-row scoring forward at width 4096. Rows do not interact, so past
-# KDA_MAX_TOKENS the mixer runs equal pieces of whole rows, of at most that
-# many tokens, one after another (`latent_row_pieces`'s arithmetic). A constant
-# with its arithmetic, not a setting.
+# `kda_heads x kda_head_dim` a token: at 32 heads of 128 that is 16 KB a token
+# an array, 2.1 GB an array for the 131,072 tokens of a 32-row scoring forward
+# at width 4096. The forward of the chunked delta rule adds nothing to them at
+# the published head size (a kernel that keeps a chunk on the chip,
+# ops/delta_rule.py), but its backward pass, and the forward at any other head
+# size, is plain jax.numpy and holds some fifteen float32 arrays of that size
+# beside them. Rows do not interact, so past KDA_MAX_TOKENS the mixer runs
+# equal pieces of whole rows, of at most that many tokens, one after another
+# (`latent_row_pieces`'s arithmetic). A constant with its arithmetic, not a
+# setting.
 KDA_MAX_TOKENS = 8192
 
 

@@ -665,8 +665,12 @@ class TPUBaseTrainer(BaseRLTrainer):
         drive a mixture-of-experts policy."""
         loss, stats = loss_stats
         kda = out.get("kda_stats") if isinstance(out, dict) else None
-        if kda is not None:  # layers under a gated delta rule (KDAMixer): what its chunked form must survive, and how hard it writes
+        if kda is not None:  # layers under a gated delta rule (KDAMixer): what its chunked form must survive, how hard it writes, and whether the pass took the kernel
+            from trlx_tpu.ops.delta_rule import scan_takes_kernel
+
+            head = self.tcfg.kda_head_dim
             stats = dict(stats, **{"learn/kda_log_decay_min": kda[0], "learn/kda_beta_mean": kda[1]})
+            stats["learn/kda_scan_pallas"] = float(scan_takes_kernel(head, head))
         aux = out.get("router_aux_loss") if isinstance(out, dict) else None
         if aux is None:
             return loss, stats
