@@ -504,6 +504,39 @@ def test_flash_compiles_with_unlike_head_sizes_at_latent_attention_widths(topo, 
     assert f"bf16[{rows},{H},{width},{Dv}]" in text.replace(" ", "")
 
 
+@pytest.mark.parametrize("width,heads,D,window,counted", [(8192, 64, 256, 513, 2), (7168, 64, 256, 513, 2), (8192, 128, 192, None, 0)],
+                         ids=["train_row_window_layer", "prefill_row_window_layer", "train_row_full_layer"])
+def test_flash_compiles_with_unlike_head_sizes_under_a_window_and_the_benchmark_tells_the_layers(topo, width, heads, D, window, counted):
+    """``dots3note_ppo_ctx8k``'s two kinds of latent layer, one row a piece: a
+    window layer's 64 heads of q/k 256 and v 128 under ``window=513``, forward
+    and backward, and a full layer's 128 heads of 192 / 128. Both kernels keep
+    their names; ``window_latent_pass_device_ms``'s pattern finds the window
+    layer's two calls (by the first result's 64 heads) and none of a full
+    layer's."""
+    import json
+    import re
+
+    from trlx_tpu.ops import flash_attention as fa
+
+    Dv = 128
+
+    def loss(q, k, v, m):
+        with jax.named_scope("attn"):
+            out = fa.flash_attention(q, k, v, m, interpret=False, window=window)
+            assert out.shape == (1, width, heads, Dv)
+            return out.astype(jnp.float32).sum()
+
+    qk = _s((1, width, heads, D))
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), (qk, qk, _s((1, width, heads, Dv)), _s((1, width), jnp.float32)),
+                    SingleDeviceSharding(topo.devices[0]))
+    _assert_the_benchmark_finds_both_kernels(text)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "layer_metrics", "window_latent_pass_device_ms.json")) as f:
+        pattern = json.load(f)["pattern"]
+    calls = [l.strip().removeprefix("ROOT ") for l in text.splitlines() if 'custom_call_target="tpu_custom_call"' in l]
+    assert sum(bool(re.search(pattern, c)) for c in calls) == counted, calls
+
+
 def test_dense_decode_step_builds_no_repeated_kv(topo, monkeypatch):
     """Cached single-token decoding runs the dense einsum branch of
     ``Attention``. At the attention shapes of ``mistral7b_grpo_decode``

@@ -53,6 +53,12 @@ _KIMI_LINEAR_NO_INTEROP = (
     "(random weights) only and no converter pair was ever checked against one "
     "(ROADMAP.md queue 2, B7)"
 )
+_DOTS3_NOTE_NO_INTEROP = (
+    "model_type 'dots3_note' has no HF checkpoint conversion yet: no checkpoint can "
+    "be fetched where this was built, so the family runs from 'builtin:dots3-note' "
+    "(random weights) only and no converter pair was ever checked against one "
+    "(ROADMAP.md queue 2, B4)"
+)
 _EXAONE_MOE_NO_INTEROP = (
     "model_type 'exaone_moe' has no HF checkpoint conversion yet: no checkpoint can "
     "be fetched where this was built, so the family runs from 'builtin:k-exaone-<size>' "
@@ -550,6 +556,8 @@ def config_from_hf(hf_config) -> TransformerConfig:
         raise ValueError(_MINICPM_SALA_NO_INTEROP)
     if mt == "kimi_linear":
         raise ValueError(_KIMI_LINEAR_NO_INTEROP)
+    if mt == "dots3_note":
+        raise ValueError(_DOTS3_NOTE_NO_INTEROP)
     raise ValueError(f"Unsupported HF model type for causal import: {mt}")
 
 
@@ -1195,6 +1203,8 @@ def hf_config_from_transformer(cfg):
         raise UnsupportedHFExport(_MINICPM_SALA_NO_INTEROP)
     if mt == "kimi_linear":
         raise UnsupportedHFExport(_KIMI_LINEAR_NO_INTEROP)
+    if mt == "dots3_note":
+        raise UnsupportedHFExport(_DOTS3_NOTE_NO_INTEROP)
     raise UnsupportedHFExport(
         f"No HF export mapping for model_type={mt!r} "
         "(set TransformerConfig.model_type to an HF family)"

@@ -18,6 +18,7 @@ TPU-first design decisions:
 - optional ``remat`` and ``scan_layers`` for memory/compile scaling.
 """
 
+import contextlib
 import dataclasses
 import functools
 import math
@@ -207,6 +208,24 @@ EMBED_INIT_STD_MINICPM_SALA = 1.0 / 12.0
 QK_INIT_STD_KIMI_LINEAR = 0.08
 
 
+class AttentionSizes(NamedTuple):
+    """One layer's attention geometry (``TransformerConfig.attention_sizes``),
+    the ONE answer every reader takes: ``LatentAttention``, ``make_kv_cache``,
+    ``ops/paged_kv.py::dense_kv_bytes`` and the benchmark's counts. A window
+    layer of a stack with ``swa_*`` sizes has its own; every other layer the
+    model-wide ones (so an ``Indexer``, which only a full layer has, reads the
+    model-wide ``qk_rope_head_dim``)."""
+
+    heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    nope: int  # a latent head's no-rope q and k dims (a K/V head: all of `dims_per_head`)
+    rope: int  # ... and its roped ones, the ONE shared key's size (a K/V head: 0)
+    v: int
+    theta: float
+    window: Optional[int]
+
+
 class LayerLayout(NamedTuple):
     """One layer's kind (``TransformerConfig.layer_layout``): its attention
     layout, which the bias, the flash arguments, the sampler's cache and the
@@ -228,6 +247,9 @@ class LayerLayout(NamedTuple):
     mixer: str = "attention"
 
 
+# the window layers' own sizes (`TransformerConfig.attention_sizes`)
+SWA_FIELDS = ("swa_num_heads", "swa_q_lora_rank", "swa_kv_lora_rank", "swa_qk_nope_head_dim", "swa_qk_rope_head_dim",
+              "swa_v_head_dim", "swa_rope_theta")
 SPARSE_INIT_BLOCKS = 1  # leading blocks every query of a block selection keeps (MiniCPM4's init_blocks)
 
 
@@ -486,6 +508,27 @@ class TransformerConfig:
     kda_heads: int = 0
     kda_head_dim: int = 0
     kda_conv: int = 4
+    # a second attention geometry on the WINDOW layers of a latent stack
+    # (dots3_note's `swa_*` keys): each None = as the model-wide field of the
+    # like name, which the full layers keep (`attention_sizes`). With
+    # `index_topk` the full layers select for themselves and a window layer
+    # selects nothing and holds no index keys (`layer_layout`); its cache is a
+    # ring of `sliding_window` latents (`make_kv_cache`)
+    swa_num_heads: Optional[int] = None
+    swa_q_lora_rank: Optional[int] = None
+    swa_kv_lora_rank: Optional[int] = None
+    swa_qk_nope_head_dim: Optional[int] = None
+    swa_qk_rope_head_dim: Optional[int] = None
+    swa_v_head_dim: Optional[int] = None
+    swa_rope_theta: Optional[float] = None
+    # "headwise": a sigmoid gate a HEAD on a latent layer's output before
+    # `o_proj`, `g = sigmoid(x Wgate)`, `Wgate` `hidden x heads` from the
+    # layer's normed input (`head_gate`; `attn_output_gate` above is the
+    # full-width one of the K/V layers)
+    attention_gate_type: Optional[str] = None
+    # the two normed latents times `sqrt(hidden_size / rank)`, in both forms
+    # of `LatentAttention` and in its cache
+    mla_lora_rescale: bool = False
 
     def resolved_attention_impl(self) -> str:
         if self.attention_impl == "auto":
@@ -540,12 +583,26 @@ class TransformerConfig:
             )
         if self.moe_topk_method not in ("greedy", "noaux_tc"):
             raise ValueError(f"moe_topk_method {self.moe_topk_method!r} is not greedy or noaux_tc")
+        swa = [name for name in SWA_FIELDS if getattr(self, name) is not None]
+        if swa and not (self.kv_lora_rank and self.sliding_window):
+            raise ValueError(f"the window layers' own sizes ({swa}) take latent attention (kv_lora_rank > 0) and a sliding_window")
+        if self.attention_gate_type not in (None, "headwise") or (
+                self.attention_gate_type and (not self.kv_lora_rank or "kda" in (self.mixer_layout or ()))):
+            raise ValueError(f"attention_gate_type {self.attention_gate_type!r}: None or 'headwise', on latent attention "
+                             "and no kda layers beside it (the K/V layers' full-width gate is attn_output_gate)")
+        if self.mla_lora_rescale and not self.kv_lora_rank:
+            raise ValueError("mla_lora_rescale scales the normed latents of latent attention (kv_lora_rank > 0)")
         if self.index_topk:
-            if (not self.kv_lora_rank or not self.q_lora_rank or self.sliding_window or self.index_head_dim < self.qk_rope_head_dim
+            # beside a window the FULL layers select, each for itself, and a window layer selects nothing
+            # (`layer_layout`): a set borrowed across window layers is not built
+            windowed = bool(self.sliding_window) and (self.sliding_window_layout is None or self.indexer_types is not None)
+            if (not self.kv_lora_rank or not self.q_lora_rank or windowed or self.index_head_dim < self.qk_rope_head_dim
                     or self.index_heads < 1):
                 raise ValueError(
                     "a learned selection (index_topk > 0) runs over a latent cache (kv_lora_rank > 0) from a query "
-                    "latent (q_lora_rank > 0), no sliding window, with index_heads >= 1 heads of index_head_dim >= qk_rope_head_dim"
+                    "latent (q_lora_rank > 0), with index_heads >= 1 heads of index_head_dim >= qk_rope_head_dim; beside a "
+                    "sliding window only where sliding_window_layout leaves layers full, which then select for "
+                    "themselves (no indexer_types: a window layer neither selects nor borrows)"
                 )
             types = self.indexer_types
             if types is not None:  # a list from a JSON override
@@ -576,9 +633,35 @@ class TransformerConfig:
             window=self.sliding_window if windowed and self.sliding_window else None,
             rotary=self.position_scheme == "rotary" and roped,
             ffn="moe" if self.num_experts > 0 and layer >= self.first_k_dense else "dense",
-            indexer=(self.indexer_types[layer] if self.indexer_types else "full") if self.index_topk else None,
+            indexer=(self.indexer_types[layer] if self.indexer_types else "full")
+            if self.index_topk and not (windowed and self.sliding_window) else None,
             **({"mixer": self.mixer_layout[layer]} if self.mixer_layout else {}),
         )
+
+    def attention_sizes(self, layer: int) -> AttentionSizes:
+        """Layer ``layer``'s attention geometry: the model-wide sizes, and on a
+        window layer each ``swa_*`` size that is set in its place."""
+        window = self.layer_layout(layer).window
+        latent = self.latent_attention
+
+        def own(name, wide):
+            value = getattr(self, name) if window else None
+            return wide if value is None else value
+
+        return AttentionSizes(
+            heads=own("swa_num_heads", self.num_heads),
+            q_lora_rank=own("swa_q_lora_rank", self.q_lora_rank),
+            kv_lora_rank=own("swa_kv_lora_rank", self.kv_lora_rank),
+            nope=own("swa_qk_nope_head_dim", self.qk_nope_head_dim) if latent else self.dims_per_head,
+            rope=own("swa_qk_rope_head_dim", self.qk_rope_head_dim) if latent else 0,
+            v=own("swa_v_head_dim", self.v_dims_per_head),
+            theta=own("swa_rope_theta", self.rope_theta),
+            window=window,
+        )
+
+    @property
+    def layer_types(self) -> List[str]:
+        return ["sliding_attention" if layout.window else "full_attention" for layout in self.layer_layouts]
 
     @property
     def layer_layouts(self) -> Tuple[LayerLayout, ...]:
@@ -1035,6 +1118,69 @@ class TransformerConfig:
             router_aux_coef=0.0,  # balance is the selection bias's work, not a loss's
             embed_init_std=1.0,
             qk_init_std=QK_INIT_STD_KIMI_LINEAR,
+        )
+
+    @staticmethod
+    def dots3(size: str = "note", **overrides) -> "TransformerConfig":
+        """dots3-note-prev (``model_type`` ``dots3_note``): latent attention
+        of TWO geometries in one stack (``attention_sizes``). A full layer
+        (13 of 46) runs 128 heads over a 512-wide latent under a learned
+        selection of ``index_topk`` keys that its own indexer makes; a window
+        layer (three in four, ``sliding_window_layout``) runs the ``swa_*``
+        sizes, 64 heads over a 1024-wide latent inside a window of 513, selects
+        nothing, and caches a ring of 513 latents. Both under a sigmoid gate a
+        head (``attention_gate_type`` headwise) and with the normed latents at
+        ``sqrt(hidden / rank)`` (``mla_lora_rescale``); one leading dense SwiGLU
+        layer, then layers of 256 routed SwiGLU experts (sigmoid scores, the
+        eight largest of ``score + bias``, renormalised, times 1) beside one
+        shared expert. The vision and audio towers and the next-token module
+        are not built. Limits: the plain sampler, the scoring forward, the
+        hydra branch and the train step only (``ops/paged_kv.py::
+        refuse_latent_cache``); no ``scan_layers``, no ring attention over
+        ``sequence``, no HF checkpoint import. ``q_b_proj`` keeps the program's
+        0.02: under the rescale that is already a score of standard deviation 2
+        (chipbench/configs/dots3-note-prev-l6e8.json, `assumed`).
+        ``builtin:dots3-note`` | ``builtin:dots3-note-test``."""
+        f, w = 0, 1  # sliding_window_layout: a full layer, a window layer
+        dims = {
+            # the benchmark's cut in small (dense full, full, window x 3, full), the two kinds unlike in EVERY size so
+            # that a test can tell a swapped one: full 4 heads, latents 32 / 16, q/k 20 = 12 + 8, v 16; window 2 heads,
+            # latents 24 / 24, q/k 24 = 20 + 4, v 12; a window of 5 and a selection of 8 that bind on any row past 8
+            "note-test": dict(vocab_size=259, hidden_size=64, num_layers=6, num_heads=4, intermediate_size=128, max_position_embeddings=256,
+                              q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=12, qk_rope_head_dim=8, v_head_dim=16, rope_theta=8e7,
+                              swa_num_heads=2, swa_q_lora_rank=24, swa_kv_lora_rank=24, swa_qk_nope_head_dim=20, swa_qk_rope_head_dim=4,
+                              swa_v_head_dim=12, swa_rope_theta=5e4, sliding_window=5, sliding_window_layout=(f, f, w, w, w, f),
+                              index_topk=8, index_heads=2, index_head_dim=12,
+                              moe_intermediate_size=32, num_experts=8, num_experts_per_tok=2, first_k_dense=1, moe_bias_init_std=0.05),
+            "note": dict(vocab_size=152064, hidden_size=5120, num_layers=46, num_heads=128, num_kv_heads=128, intermediate_size=13824, max_position_embeddings=524288,
+                         q_lora_rank=1024, kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128, rope_theta=8e7,
+                         swa_num_heads=64, swa_q_lora_rank=1024, swa_kv_lora_rank=1024, swa_qk_nope_head_dim=192, swa_qk_rope_head_dim=64,
+                         swa_v_head_dim=128, swa_rope_theta=5e4, sliding_window=513, sliding_window_layout=(f, f) + (w, w, w, f) * 11,
+                         index_topk=2048, index_heads=64, index_head_dim=128,
+                         moe_intermediate_size=1536, num_experts=256, num_experts_per_tok=8, first_k_dense=1),
+        }[size]
+        return _make_preset(
+            dims,
+            overrides,
+            model_type="dots3_note",
+            position_scheme="rotary",
+            norm="rmsnorm",
+            layer_norm_epsilon=1e-5,
+            activation="silu",
+            attn_bias=False,
+            mlp_bias=False,
+            tie_word_embeddings=False,
+            attention_gate_type="headwise",
+            mla_lora_rescale=True,
+            num_shared_experts=1,
+            moe_gated=True,
+            moe_scoring="sigmoid",
+            moe_topk_method="noaux_tc",
+            routed_scaling_factor=1.0,
+            moe_capacity_factor=0.0,  # dropless
+            moe_renormalize=True,  # norm_topk_prob: true
+            router_aux_coef=0.0,  # balance is the selection bias's work, not a loss's
+            embed_init_std=1.0,
         )
 
     @staticmethod
@@ -2127,16 +2273,26 @@ class LatentAttention(nn.Module):
     form folds its matrix, not its output."""
 
     config: TransformerConfig
-    indexer: Optional[str] = None  # this layer's LayerLayout.indexer
+    layer: int = 0  # which layer's kind (`layer_layout`: indexer, rotary) and sizes (`attention_sizes`) this is
     lends: bool = False  # the next layer borrows the selection in force here
-    rotary: bool = True  # this layer's LayerLayout.rotary: False leaves `q_r` and `k_r` as projected (NoPE)
 
     @nn.compact
-    def __call__(self, x, attention_bias, positions, cache=None, cache_index=None, flash_args=None, kv_extents=None, selection=None):
+    def __call__(self, x, attention_bias, positions, cache=None, cache_index=None, flash_args=None, kv_extents=None, selection=None,
+                 token_mask=None):
+        """``(output, new cache, selection in force for a borrowing layer,
+        gate statistics)``: the last is ``[sum over real tokens of a token's
+        mean gate, real tokens]`` of a pass under a headwise gate, else None."""
         cfg = self.config
         B, T, _ = x.shape
-        H, dn, dr, dv, r = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_dims_per_head, cfg.kv_lora_rank
-        topk = cfg.index_topk if self.indexer else 0
+        layout = cfg.layer_layout(self.layer)
+        indexer, rotary = layout.indexer, layout.rotary
+        H, rq, r, dn, dr, dv, theta, window = cfg.attention_sizes(self.layer)
+        topk = cfg.index_topk if indexer else 0
+        if cfg.lora_r and "head_gate" in cfg.lora_targets:
+            raise ValueError(
+                "head_gate takes no LoRA adapter: the headwise gate's projection is one number a head "
+                "(LatentAttention); adapt q_a_proj, q_b_proj, kv_a_proj, o_proj"
+            )
         if "kv_b_proj" in cfg.lora_targets and cfg.lora_r:
             raise ValueError(
                 "kv_b_proj takes no LoRA adapter: a decode step folds its matrix into the "
@@ -2150,34 +2306,56 @@ class LatentAttention(nn.Module):
             return nn.RMSNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                               scale_init=param_with_axes(nn.initializers.ones, ("latent",)), name=name)
 
-        if cfg.q_lora_rank:
-            cq = latent_norm("q_a_norm")(_dense(cfg, cfg.q_lora_rank, False, ("embed", "latent"), "q_a_proj")(x))
+        if rq:
+            cq = latent_norm("q_a_norm")(_dense(cfg, rq, False, ("embed", "latent"), "q_a_proj")(x))
         else:  # no query latent: ONE projection `q_proj` from the layer's input, which stands where `cq` does below
             cq = x
         kv_a = _dense(cfg, r + dr, False, ("embed", "latent"), "kv_a_proj")(x)
         c = latent_norm("kv_a_norm")(kv_a[..., :r])
-        if cfg.q_lora_rank:
-            q_b = _Projection(cfg, (cfg.q_lora_rank, H * (dn + dr)), ("latent", "joined_kv"), cfg.qk_init_std, name="q_b_proj")()
+        if cfg.mla_lora_rescale:
+            # the normed latents at the residual stream's size, sqrt(hidden / rank) each: what both
+            # forms read and what the cache holds; `k_r` is neither normed nor scaled
+            c = c * np.sqrt(cfg.hidden_size / r).astype(c.dtype)
+            if rq:
+                cq = cq * np.sqrt(cfg.hidden_size / rq).astype(cq.dtype)
+        if rq:
+            q_b = _Projection(cfg, (rq, H * (dn + dr)), ("latent", "joined_kv"), cfg.qk_init_std, name="q_b_proj")()
         else:
             q_b = _Projection(cfg, (cfg.hidden_size, H * (dn + dr)), ("embed", "joined_kv"), cfg.qk_init_std, name="q_proj")()
         w_kvb = _Projection(cfg, (r, H * (dn + dv)), ("latent", "joined_kv"), name="kv_b_proj")()["kernel"].reshape(r, H, dn + dv)
         o = _Projection(cfg, (H * dv, cfg.hidden_size), ("joined_kv", "embed"), name="o_proj")()
+        gate = None
+        if cfg.attention_gate_type == "headwise":
+            with jax.named_scope("trlx/attn_head_gate"):
+                gate = jax.nn.sigmoid(_dense(cfg, H, False, ("embed", "heads"), "head_gate")(x))  # [B, T, H]
 
-        sin, cos = rotary_sin_cos(positions, dr, cfg.rope_theta)
-        roped = (lambda a, sin, cos: apply_rotary(a, sin, cos, dr, True)) if self.rotary else (lambda a, sin, cos: a)
+        sin, cos = rotary_sin_cos(positions, dr, theta)
+        roped = (lambda a, sin, cos: apply_rotary(a, sin, cos, dr, True)) if rotary else (lambda a, sin, cos: a)
         k_r = roped(kv_a[..., None, r:], sin, cos)[:, :, 0]  # [B, T, dr]: one head
-        index = Indexer(cfg, name="indexer")(cq, x, sin, cos) if self.indexer == "full" else None
+        index = Indexer(cfg, name="indexer")(cq, x, sin, cos) if indexer == "full" else None
 
         def queries(cq, sin, cos):
             q = project(q_b, cq, cfg).reshape(*cq.shape[:2], H, dn + dr)
             return q[..., :dn], roped(q[..., dn:], sin, cos)
 
         new_cache = None
+        ring = kv_extents is not None and kv_extents.ring
         if cache is not None:
             ci = jnp.asarray(cache_index)
             if ci.ndim:
                 raise NotImplementedError("a latent cache is written at one scalar cache_index for all rows (the plain sampler)")
-            if "latent" in cache:  # a layer under a selection: one row a slot, written as its two column ranges
+            if ring:
+                # a window layer's ring of C latents, slot t at t mod C (CausalTransformer._ring_plan built
+                # the bias / flash_args to match), written as `Attention`'s ring of K and V is
+                C = cache["ckv"].shape[1]
+                if T == 1:
+                    write = lambda leaf, a: jax.lax.dynamic_update_slice(leaf, a.astype(leaf.dtype), (0, ci % C, 0))
+                elif T <= C:  # a prefill from slot 0 that does not wrap
+                    write = lambda leaf, a: jax.lax.dynamic_update_slice(leaf, a.astype(leaf.dtype), (0, 0, 0))
+                else:  # a prefill from slot 0: its last C positions stay
+                    write = lambda leaf, a: jnp.roll(a[:, T - C :].astype(leaf.dtype), (T - C) % C, axis=1)
+                new_cache = {"ckv": write(cache["ckv"], c), "k_rope": write(cache["k_rope"], k_r)}
+            elif "latent" in cache:  # a layer under a selection: one row a slot, written as its two column ranges
                 rows = jax.lax.dynamic_update_slice(cache["latent"], c.astype(cache["latent"].dtype), (0, ci, 0))
                 new_cache = {"latent": jax.lax.dynamic_update_slice(rows, k_r.astype(rows.dtype), (0, ci, r))}
             else:
@@ -2212,9 +2390,12 @@ class LatentAttention(nn.Module):
             else:  # every slot, as without an indexer
                 selection, rows = None, new_cache.get("latent")
             ckv, k_rope = (rows[..., :r], rows[..., r:]) if rows is not None else (new_cache["ckv"], new_cache["k_rope"])
-            o_c = absorbed_latent_attention(q_c, q_r[:, 0], ckv, k_rope, bias, ci, extents, 1.0 / np.sqrt(dn + dr), cfg.dtype)
-            out = jnp.einsum("bhr,rhv->bhv", o_c, w_kvb[..., dn:]).reshape(B, 1, H * dv)
-            return project(o, out, cfg), new_cache, (selection if self.lends else None)
+            with jax.named_scope("trlx/attn_latent_ring") if ring else contextlib.nullcontext():
+                o_c = absorbed_latent_attention(q_c, q_r[:, 0], ckv, k_rope, bias, ci, extents, 1.0 / np.sqrt(dn + dr), cfg.dtype)
+            out = jnp.einsum("bhr,rhv->bhv", o_c, w_kvb[..., dn:])
+            if gate is not None:
+                out = out * gate[:, 0, :, None]
+            return project(o, out.reshape(B, 1, H * dv), cfg), new_cache, (selection if self.lends else None), None
 
         use_flash = flash_args is not None
         if use_flash and _maybe_ring_mesh(T) is not None:
@@ -2223,11 +2404,11 @@ class LatentAttention(nn.Module):
                 "(parallel/ring_attention.py); latent attention is not built for it: use sequence=1"
             )
 
-        def expanded(cq, c, k_r, sin, cos, visible, chosen):
+        def expanded(cq, c, k_r, sin, cos, visible, chosen, gate):
             """Whole rows ``[b, T, ...]``; ``visible`` is their key mask
             (flash) or their additive bias (einsum path); ``chosen`` what
             decides their selection: the indexer's ``(q_i, k_i, w)``, the
-            selection handed in, or nothing."""
+            selection handed in, or nothing; ``gate`` their headwise gate."""
             b = cq.shape[0]
             with jax.named_scope("trlx/attn_latent_expand"):
                 q_n, q_r = queries(cq, sin, cos)
@@ -2251,6 +2432,8 @@ class LatentAttention(nn.Module):
                 out = _flash_attention(q, k, v, {**flash_args, "key_mask": visible})
             else:
                 out = grouped_einsum_attention(q, k, v, visible, cfg.dtype)
+            if gate is not None:
+                out = out * gate[..., None].astype(out.dtype)
             out = out.reshape(b, T, H * dv)
             if selects:
                 # a group of rows at a time: on a whole [7168, H dv] piece the TPU compiler
@@ -2262,17 +2445,22 @@ class LatentAttention(nn.Module):
             return out, (chosen if selects and index is not None and self.lends else None)
 
         chosen = (index if index is not None else selection) if selects else None
-        operands = (cq, c, k_r, sin, cos, flash_args["key_mask"] if use_flash else attention_bias, chosen)
+        operands = (cq, c, k_r, sin, cos, flash_args["key_mask"] if use_flash else attention_bias, chosen, gate)
         pieces = latent_row_pieces(B, T)
-        if pieces == 1:
-            out, made = expanded(*operands)
-        else:
-            split = lambda a: a.reshape(pieces, B // pieces, *a.shape[1:])
-            join = lambda a: a.reshape(B, *a.shape[2:])
-            out, made = jax.tree_util.tree_map(join, jax.lax.map(lambda piece: expanded(*piece), jax.tree_util.tree_map(split, operands)))
+        with jax.named_scope("trlx/attn_latent_window") if window else contextlib.nullcontext():
+            if pieces == 1:
+                out, made = expanded(*operands)
+            else:
+                split = lambda a: a.reshape(pieces, B // pieces, *a.shape[1:])
+                join = lambda a: a.reshape(B, *a.shape[2:])
+                out, made = jax.tree_util.tree_map(join, jax.lax.map(lambda piece: expanded(*piece), jax.tree_util.tree_map(split, operands)))
+        gate_stats = None
+        if gate is not None:  # [a token's mean gate summed over the real tokens, real tokens]
+            real = jnp.ones((B, T), jnp.float32) if token_mask is None else token_mask.astype(jnp.float32)
+            gate_stats = jnp.stack([jnp.sum(jnp.mean(gate.astype(jnp.float32), axis=-1) * real), jnp.sum(real)])
         if not (selects and self.lends):
-            return out, new_cache, None
-        return out, new_cache, (made if index is not None else selection)
+            return out, new_cache, None, gate_stats
+        return out, new_cache, (made if index is not None else selection), gate_stats
 
 
 # A gated MLP builds three [tokens, width] intermediates (gate, up, their
@@ -3036,9 +3224,17 @@ def aux_size(cfg: TransformerConfig) -> int:
     the held)·tokens]; then, where the layers have shared experts, [rows
     through the shared expert, Σ chosen raw router scores]; then, where
     layers run ``KDAMixer``, [Σ beta, real tokens, one slot a KDA layer that
-    only it writes] (``kda_summary``)."""
+    only it writes] (``kda_summary``); then, under a headwise gate, [Σ of the
+    real tokens' mean gate, real tokens] (``gate_summary``)."""
     kda = kda_layers(cfg)
-    return _moe_aux_size(cfg) + (2 + len(kda) if kda else 0)
+    return _moe_aux_size(cfg) + (2 + len(kda) if kda else 0) + 2 * bool(cfg.attention_gate_type)
+
+
+def gate_summary(aux: jax.Array) -> jax.Array:
+    """The mean headwise gate of a pass's gated layers over their real tokens
+    and heads (``attention_gate_type``; the last two slots of ``aux``): near 0
+    a stand-in gate shuts attention out of the stream, near 1 it is no gate."""
+    return aux[-2] / jnp.maximum(aux[-1], 1.0)
 
 
 def _moe_aux_size(cfg: TransformerConfig) -> int:
@@ -3173,15 +3369,15 @@ class Block(nn.Module):
             x = x + mix_out * cfg.ssm_out_multiplier + attn_out * cfg.attention_out_multiplier
             mlp_out, aux = run_mlp(Norm(cfg, name="ln_mlp")(x))
             return x + mlp_out, new_cache, aux, None
-        kda_stats = None
+        kda_stats = gate_stats = None
         if layout.mixer == "lightning":
             attn_out, new_cache = LightningMixer(cfg, name="attn")(h, positions, cache, token_mask)
         elif layout.mixer == "kda":
             attn_out, new_cache, kda_stats = KDAMixer(cfg, name="attn")(h, cache, token_mask)
         elif cfg.latent_attention:
             lends = self.layer + 1 < cfg.num_layers and cfg.layer_layout(self.layer + 1).indexer == "shared"
-            attn_out, new_cache, selection = LatentAttention(cfg, layout.indexer, lends, rotary, name="attn")(
-                h, attention_bias, positions, cache, cache_index, flash_args, kv_extents, selection
+            attn_out, new_cache, selection, gate_stats = LatentAttention(cfg, self.layer, lends, name="attn")(
+                h, attention_bias, positions, cache, cache_index, flash_args, kv_extents, selection, token_mask
             )
         else:
             attn_out, new_cache = Attention(cfg, rotary, name="attn")(h, attention_bias, positions, cache, cache_index, flash_args, kv_extents)
@@ -3205,6 +3401,8 @@ class Block(nn.Module):
             if kda_stats is not None:
                 own = own.at[:2].set(kda_stats[:2]).at[2 + kda.index(self.layer)].set(kda_stats[2])
             aux = jnp.concatenate([aux, own])
+        if cfg.attention_gate_type:  # [sum of the real tokens' mean gate, real tokens], last (`gate_summary`)
+            aux = jnp.concatenate([aux, jnp.zeros((2,), jnp.float32) if gate_stats is None else gate_stats])
         return x, new_cache, aux, selection
 
 
@@ -3418,25 +3616,29 @@ class CausalTransformer(nn.Module):
         q_offset = cache_index if cache is not None and cache_index is not None else 0
         plans: Dict[Any, Any] = {}
         out = []
-        if dense and cfg.latent_attention and positions.shape[1] > 1:
-            return [self._latent_prefill_plan(key_mask, positions, cache_index, use_flash)] * len(layers)
+        latent_prefill = dense and cfg.latent_attention and positions.shape[1] > 1
         for i in layers:
             window = cfg.layer_layout(i).window
             slots = S
             if dense:  # (a layer with no slots at all, a recurrent state alone, reads the row's plan and none of it)
                 slots = cache_slots(cache if isinstance(cache, dict) else cache[i], isinstance(cache, dict)) or S
             if (window, slots) not in plans:
-                if slots == S:
+                if latent_prefill:  # a plan a KIND of latent layer: its window, and whether its cache is a ring
+                    plans[window, slots] = self._latent_prefill_plan(key_mask, positions, cache_index, use_flash, window, slots)
+                elif slots == S:
                     plans[window, slots] = self._attn_inputs(key_mask, positions, q_offset, use_flash, window) + (extents,)
                 else:
                     plans[window, slots] = self._ring_plan(key_mask, positions, cache_index, use_flash, window, slots, extents)
             out.append(plans[window, slots])
         return out
 
-    def _latent_prefill_plan(self, key_mask, positions, cache_index, use_flash):
+    def _latent_prefill_plan(self, key_mask, positions, cache_index, use_flash, window, slots):
         """A span of tokens into a latent cache (the sampler's prefill)
-        attends over its own keys, expanded: per-head K and V of the whole
-        cache are never built, so it must start at slot 0, as a ring's."""
+        attends over its own keys, expanded, inside the layer's ``window``:
+        per-head K and V of the whole cache are never built, so it must start
+        at slot 0, as a ring's; a window layer whose cache has fewer ``slots``
+        than the row leaves its last ``slots`` latents there (the view says
+        ``ring``: ``LatentAttention``)."""
         ci = jnp.asarray(cache_index)
         if ci.ndim or (not isinstance(ci, jax.core.Tracer) and int(ci) != 0):
             raise NotImplementedError(
@@ -3444,7 +3646,8 @@ class CausalTransformer(nn.Module):
                 "for all rows (the sampler's prefill): chunked prefill over a latent cache is not built"
             )
         T = positions.shape[1]
-        return self._attn_inputs(key_mask[:, :T], positions, 0, use_flash, None) + (None,)
+        view = StaticExtents((slots,), ring=True) if slots < key_mask.shape[1] else None
+        return self._attn_inputs(key_mask[:, :T], positions, 0, use_flash, window) + (view,)
 
     def _ring_plan(self, key_mask, positions, cache_index, use_flash, window, slots, extents):
         cfg = self.config
@@ -3640,6 +3843,8 @@ class CausalTransformer(nn.Module):
                 out["router_shared"] = shared_expert_summary(aux, cfg)
         if aux is not None and new_cache is None and kda_layers(cfg):  # a whole pass (the decode loop carries no statistics)
             out["kda_stats"] = kda_summary(aux, cfg)
+        if aux is not None and new_cache is None and cfg.attention_gate_type:
+            out["attn_gate_mean"] = gate_summary(aux)
         return out
 
     def _pipelined_blocks(
@@ -3836,12 +4041,16 @@ def make_kv_cache(
     gather chosen slots, and a gathered row costs the same whatever it holds
     (``LatentAttention``). A layer whose indexer type is ``full`` also holds
     ``k_index`` ``[B, slots, index_head_dim]``, the index keys its decode
-    steps score; a ``shared`` layer holds none.
+    steps score; a ``shared`` layer holds none. A WINDOW layer of a latent
+    stack holds ``ckv`` and ``k_rope`` at its own sizes
+    (``cfg.attention_sizes``) over ``min(max_length, window)`` slots, a ring
+    of latents where that is fewer than the row's, and no index keys.
     """
     dtype = dtype or cfg.dtype
     stacked = (cfg.num_layers,) if cfg.scan_layers else ()
 
-    def layer(layout: LayerLayout):
+    def layer(i: int):
+        layout, sizes = cfg.layer_layout(i), cfg.attention_sizes(i)
         # a model that drafts (`mtp_layers`) verifies a span of gamma + 1 = mtp_layers + 1
         # tokens a round: the write of the span's last must not land on the slot the
         # span's first still reads (`_ring_plan`)
@@ -3864,7 +4073,7 @@ def make_kv_cache(
             # kept the caches on chip in place of q_b_proj's prefetched weights and
             # pangu718b_ppo_decode ran 2.7% slower (PERF.md section 6, PR 43). To merge
             # them, measure that cell (ROADMAP queue 2, B4c)
-            r, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+            r, dr = sizes.kv_lora_rank, sizes.rope
             if layout.indexer:
                 latent = {"latent": jnp.zeros(stacked + (batch_size, slots, r + dr), dtype)}
             else:
@@ -3896,9 +4105,9 @@ def make_kv_cache(
         return {name: jnp.zeros(stacked + shape, dt) for name, (shape, dt) in shapes.items()}
 
     if cfg.scan_layers:  # one layout for the stack: CausalTransformer refuses a mixed one
-        return layer(cfg.layer_layout(0))
+        return layer(0)
     # the blocks' layers, then one for each next-token-prediction module (`CausalTransformer.draft`)
-    return [layer(cfg.layer_layout(i)) for i in range(cfg.num_layers + cfg.mtp_layers)]
+    return [layer(i) for i in range(cfg.num_layers + cfg.mtp_layers)]
 
 
 def stack_layer_params(backbone: Dict[str, Any], num_layers: int, prefix: str = "h_") -> Dict[str, Any]:
@@ -3941,6 +4150,7 @@ BUILTIN_SPECS = {
     "k-exaone": TransformerConfig.exaone,
     "minicpm-sala": TransformerConfig.minicpm_sala,
     "kimi-linear": TransformerConfig.kimi_linear,
+    "dots3": TransformerConfig.dots3,
     "gptj": TransformerConfig.gptj,
     "gptneox": TransformerConfig.gptneox,
     "pythia": TransformerConfig.gptneox,

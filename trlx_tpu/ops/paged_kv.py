@@ -69,6 +69,7 @@ __all__ = [
     "linear_state_bytes",
     "refuse_ring_cache",
     "latent_cache_bytes",
+    "latent_ring_bytes",
     "index_cache_bytes",
     "refuse_latent_cache",
 ]
@@ -305,12 +306,12 @@ def recurrent_state_bytes(cache: Any) -> int:
     return _named_leaf_bytes(cache, RECURRENT_LEAVES)
 
 
-def _named_leaf_bytes(cache: Any, names: Tuple[str, ...]) -> int:
+def _named_leaf_bytes(cache: Any, names: Tuple[str, ...], keep=lambda leaf: True) -> int:
     return int(
         sum(
             int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
             for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]
-            if getattr(path[-1], "key", None) in names
+            if getattr(path[-1], "key", None) in names and keep(leaf)
         )
     )
 
@@ -364,6 +365,14 @@ def latent_cache_bytes(cache: Any) -> int:
     return _named_leaf_bytes(cache, LATENT_LEAVES)
 
 
+def latent_ring_bytes(cache: Any, slots: int) -> int:
+    """Bytes of the latent leaves that hold fewer than the row's ``slots``: a
+    window layer's ring of latents (``models/transformer.py::make_kv_cache``,
+    a latent stack with a sliding window), which ``latent_cache_bytes`` counts
+    too; 0 where no latent layer has a window below the row's length."""
+    return _named_leaf_bytes(cache, LATENT_LEAVES, lambda leaf: leaf.shape[-2] < slots)
+
+
 def index_cache_bytes(cache: Any) -> int:
     """Bytes of a cache pytree's index-key leaves, by leaf name (0 for a
     model without a learned selection of keys). They exist only beside a
@@ -396,8 +405,9 @@ def refuse_latent_cache(cache: Any, path: str) -> None:
             )
         raise NotImplementedError(
             f"{path} does not support a model whose cache holds a latent in place of K and V "
-            f"(leaves {LATENT_LEAVES}: latent attention, `kv_lora_rank` > 0, the pangu_ultra_moe, "
-            f"glm_moe_dsa and kimi_linear families){riding}: {_PER_HEAD_KV_PATHS[path]}; use the plain sampler "
+            f"(leaves {LATENT_LEAVES}: latent attention, `kv_lora_rank` > 0, a window layer's ring of latents too; "
+            f"the pangu_ultra_moe, glm_moe_dsa, kimi_linear and dots3_note families){riding}: "
+            f"{_PER_HEAD_KV_PATHS[path]}; use the plain sampler "
             "(ROADMAP.md queue 2, B4)"
         )
 
@@ -448,9 +458,15 @@ def dense_kv_bytes(cfg: Any, batch_size: int, slots: int) -> int:
     allocates its cache inside the jitted program, so the gauge is computed
     rather than measured (exact: shapes are static)."""
     itemsize = np.dtype(cfg.dtype).itemsize
-    if getattr(cfg, "latent_attention", False):  # the latent and the one roped key, and a selecting layer's index key
-        index = sum(cfg.index_head_dim for layout in cfg.layer_layouts if layout.indexer == "full")
-        return int(batch_size * slots * (cfg.num_layers * (cfg.kv_lora_rank + cfg.qk_rope_head_dim) + index) * itemsize)
+    if getattr(cfg, "latent_attention", False):
+        # each layer's latent and one roped key at its own sizes over its own slots (a window layer's ring), and a
+        # selecting layer's index key
+        numbers = 0
+        for i, layout in enumerate(cfg.layer_layouts):
+            sizes = cfg.attention_sizes(i)
+            own = min(slots, layout.window) if layout.window else slots
+            numbers += own * (sizes.kv_lora_rank + sizes.rope) + (slots * cfg.index_head_dim if layout.indexer == "full" else 0)
+        return int(batch_size * numbers * itemsize)
     return int(
         2 * cfg.num_layers * batch_size * slots * cfg.kv_heads
         * cfg.dims_per_head * itemsize

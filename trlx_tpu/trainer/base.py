@@ -671,6 +671,9 @@ class TPUBaseTrainer(BaseRLTrainer):
             head = self.tcfg.kda_head_dim
             stats = dict(stats, **{"learn/kda_log_decay_min": kda[0], "learn/kda_beta_mean": kda[1]})
             stats["learn/kda_scan_pallas"] = float(scan_takes_kernel(head, head))
+        gate = out.get("attn_gate_mean") if isinstance(out, dict) else None
+        if gate is not None:  # a headwise gate on the latent layers' output: neither shut nor open
+            stats = dict(stats, **{"learn/attn_gate_mean": gate})
         aux = out.get("router_aux_loss") if isinstance(out, dict) else None
         if aux is None:
             return loss, stats
@@ -1622,7 +1625,8 @@ class TPUBaseTrainer(BaseRLTrainer):
         block selection (the compressed keys; chosen blocks over causal
         blocks, mean over the decode steps of an unpadded row), and where the layers cache a latent in place of K and V
         ``rollout/latent_cache_bytes`` (``ckv``, ``k_rope``, or the two in
-        one leaf ``latent`` on a layer under a selection; K and V then 0)
+        one leaf ``latent`` on a layer under a selection; K and V then 0;
+        a window layer's ring of latents apart, ``rollout/latent_ring_bytes``)
         with ``rollout/index_cache_bytes`` beside it (``k_index``, the index
         keys of the layers that select for themselves; 0 for a model without
         a learned selection) and ``rollout/sparse_gather_rows`` (rows of the
@@ -1636,7 +1640,8 @@ class TPUBaseTrainer(BaseRLTrainer):
         if self.is_seq2seq:
             return  # T5 cross/self caches have their own layout; not gauged
         from trlx_tpu.ops.paged_kv import (
-            index_cache_bytes, kv_bytes, latent_cache_bytes, linear_state_bytes, pooled_key_bytes, recurrent_state_bytes,
+            index_cache_bytes, kv_bytes, latent_cache_bytes, latent_ring_bytes, linear_state_bytes, pooled_key_bytes,
+            recurrent_state_bytes,
         )
 
         B, P = prompt_shape
@@ -1674,8 +1679,11 @@ class TPUBaseTrainer(BaseRLTrainer):
             self.last_cache_stats["rollout/kbar_cache_bytes"] = float(pooled)
             self.last_cache_stats["rollout/attn_block_selected_frac"] = block_selected_steps(P, gen_config.max_new_tokens, self.tcfg)
         if latent:  # the layers cache a latent in place of K and V, and index keys with it
-            self.last_cache_stats["rollout/latent_cache_bytes"] = float(latent)
+            ring = latent_ring_bytes(policy_cache, S)
+            self.last_cache_stats["rollout/latent_cache_bytes"] = float(latent - ring)
             self.last_cache_stats["rollout/index_cache_bytes"] = float(index)
+            if ring:  # window layers' rings of latents, counted apart from the full layers' caches
+                self.last_cache_stats["rollout/latent_ring_bytes"] = float(ring)
         if getattr(self.tcfg, "index_topk", 0) and not drafts:
             # rows of the cache a decode step gathers a row of the batch, all layers: static, as the extents are
             self.last_cache_stats["rollout/sparse_gather_rows"] = float(sparse_gather_rows(self.tcfg, S))
@@ -1685,7 +1693,7 @@ class TPUBaseTrainer(BaseRLTrainer):
                 (int(cache_slots(layer)), layout.window is not None)
                 for layer, layout in zip(policy_cache, layouts) if cache_slots(layer)
             )
-            if len({layout.window for layout in layouts}) > 1:  # window layers beside global ones
+            if len({layout.window for layout in layouts}) > 1 and not latent:  # window layers beside global ones, K and V
                 window = sum(
                     kv_bytes({"k": layer["k"], "v": layer["v"]})
                     for layer, layout in zip(policy_cache, layouts) if layout.window is not None and "k" in layer
