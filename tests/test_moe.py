@@ -474,6 +474,8 @@ _DROPLESS_LAYERS = {
     "checkpoint": dict(moe_experts_held=3, moe_first_expert=4),
     # a layer past MOE_MAX_TOKENS: three pieces of twelve tokens under lax.map
     "pieces": dict(moe_experts_held=3, moe_first_expert=4),
+    # ... and with every expert held
+    "pieces_all_held": dict(),
 }
 
 
@@ -499,7 +501,7 @@ def _dropless_layer_loss(case, dtype, permute, monkeypatch):
     params = layer.init(jax.random.PRNGKey(1), x)["params"]
     if permute is not None:
         monkeypatch.setattr(transformer, "permute_rows", permute)
-    if case == "pieces":
+    if case.startswith("pieces"):
         monkeypatch.setattr(transformer, "MOE_MAX_TOKENS", 8)
         monkeypatch.setattr(transformer, "MOE_PIECE_TOKENS", 12)
         assert transformer.moe_token_pieces(36) == 3
@@ -573,6 +575,197 @@ def test_dropless_backward_holds_no_scatter_of_the_row_buffer(case, monkeypatch)
     assert sum(n == "gather" for n in names) >= 4  # two forward, two backward
     plain, _ = row_scatters(_plain_permute_rows)
     assert plain == ["scatter-add", "scatter-add"], plain
+
+
+# --- a chip's share of the experts: the row buffers cut to a bound -----------
+#
+# ``MoEMLP._dropless_rows`` of a layer that holds ``held < E`` experts builds
+# its sorted row buffers at ``held_row_bound`` rows where the held rows fit
+# (``_held_rows``) and at ``tokens x K`` where they do not (``_all_rows``),
+# one ``lax.cond`` (``transformer._either``) forward and one backward. The
+# toy layers' 108 assignments are under one 128-row tile and under the
+# shortest call that has a bound, and 3 experts of 8 are more than a
+# sixteenth, so the tests cut the tile to 8 rows and lift the two floors: 88
+# of 108 rows at 3 experts of 8, 56 at 2 of 8, 32 of a 36-assignment piece.
+
+_TAKE = {
+    "compact": lambda take_first, first, second, *operands: first(*operands),
+    "all_rows": lambda take_first, first, second, *operands: second(*operands),
+}
+_HELD_SHARE_LAYERS = ["held_share", "padding_held_share", "checkpoint", "pieces"]
+
+
+def _small_row_tile(monkeypatch, tile=8):
+    """The bound's arithmetic at the toy layers' size: a row tile of
+    ``tile``, no shortest call, any cut of the rows."""
+    from trlx_tpu.models import transformer
+    from trlx_tpu.ops import grouped_matmul
+
+    monkeypatch.setattr(grouped_matmul, "ROW_TILE", tile)
+    monkeypatch.setattr(transformer, "MOE_HELD_MIN_ROWS", 0)
+    monkeypatch.setattr(transformer, "MOE_HELD_MIN_CUT", 1)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("case", _HELD_SHARE_LAYERS)
+def test_compact_rows_equal_all_rows_to_the_bit(case, dtype, monkeypatch):
+    """The ``cond``'s two bodies, each called directly: the same rows go
+    through the same grouped matmuls, the rows past the bound are the zeros
+    ``_all_rows``' select writes, and a token's ``K`` results are summed by
+    the same einsum, so the value and the gradients with respect to ``x``,
+    the router and the three expert kernels are EQUAL, not close."""
+    from trlx_tpu.models import transformer
+
+    _small_row_tile(monkeypatch)
+    got = {}
+    for name, take in _TAKE.items():
+        monkeypatch.setattr(transformer, "_either", take)
+        loss, params, x = _dropless_layer_loss(case, dtype, None, monkeypatch)
+        got[name] = jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(params, x)
+    (value, grads), (want_value, want) = got["compact"], got["all_rows"]
+    np.testing.assert_array_equal(np.asarray(value), np.asarray(want_value))
+    flat = jax.tree_util.tree_flatten_with_path(grads)[0]
+    assert len(flat) == 5  # the router, the three expert kernels, x
+    for (path, g), q in zip(flat, jax.tree_util.tree_leaves(want)):
+        g, q = np.asarray(g.astype(jnp.float32)), np.asarray(q.astype(jnp.float32))
+        assert np.isfinite(g).all() and np.any(g != 0), jax.tree_util.keystr(path)
+        np.testing.assert_array_equal(g, q, err_msg=jax.tree_util.keystr(path))
+
+
+def _biased_to_the_held(real_tokens, monkeypatch, tile):
+    """A layer whose router bias sends all three choices of every token to
+    its three held experts: ``3 x real_tokens`` held rows against a bound of
+    ``round_up(2 * ceil(108 * 3 / 8), tile)``."""
+    _small_row_tile(monkeypatch, tile)
+    cfg = _cfg(num_experts=8, num_experts_per_tok=3, moe_capacity_factor=0.0, moe_experts_held=3,
+               moe_first_expert=4, moe_topk_method="noaux_tc", moe_scoring="sigmoid")
+    rs = np.random.RandomState(5)
+    x = jnp.asarray(rs.randn(3, 12, cfg.hidden_size), jnp.float32)
+    mask = (jnp.arange(36) < real_tokens).astype(jnp.int32).reshape(3, 12)
+    layer = MoEMLP(cfg)
+    params = dict(layer.init(jax.random.PRNGKey(1), x)["params"])
+    params["router_bias"] = jnp.where((jnp.arange(8) >= 4) & (jnp.arange(8) < 7), 100.0, 0.0)
+    return layer, params, x, mask
+
+
+@pytest.mark.parametrize("real_tokens,compact", [(28, 1.0), (29, 0.0), (36, 0.0)],
+                         ids=["held_rows_equal_the_bound", "one_token_over", "every_row_held"])
+def test_a_call_over_the_bound_runs_every_row_and_says_so(real_tokens, compact, monkeypatch):
+    """``L <= C`` takes the compact body, ``L > C`` the all-rows one, on the
+    traced count; either way the result is the one a layer without a bound
+    gives (``held_row_bound`` at ``tokens x K``: the program before the
+    bound), and ``moe/compact_frac`` (slots 8 and 9 of ``aux``) says which
+    ran."""
+    from trlx_tpu.models import transformer
+    from trlx_tpu.models.transformer import held_row_bound, router_load_summary
+
+    layer, params, x, mask = _biased_to_the_held(real_tokens, monkeypatch, tile=6)
+    assert held_row_bound(108, 3, 8) == 84
+    run = jax.jit(lambda p, x: layer.apply({"params": p}, x, token_mask=mask))
+    y, aux = run(params, x)
+    assert float(aux[6]) == 3 * real_tokens  # every real assignment fell on a held expert
+    assert (float(aux[8]), float(aux[9])) == (compact, 1.0)
+    assert float(router_load_summary(aux, layer.config)[4]) == compact
+    monkeypatch.setattr(transformer, "held_row_bound", lambda rows, held, experts: rows)
+    want, want_aux = jax.jit(lambda p, x: layer.apply({"params": p}, x, token_mask=mask))(params, x)
+    assert want_aux.shape == aux.shape and float(want_aux[9]) == 0.0  # no bound, no choice, no call counted
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(want))
+    assert np.any(np.asarray(y) != 0)
+
+
+def _row_buffers(eqns, rows, tokens, K, widths=(64, 96)):
+    """Equations that WRITE a ``tokens x K``-row buffer of feature rows:
+    ``[rows, width]`` or ``[tokens, K, width]`` at the toy layers' hidden
+    size or expert width; reshapes and casts of one are the same buffer and
+    do not count."""
+    def is_buffer(aval):
+        shape = getattr(aval, "shape", ())
+        rows_first = (len(shape) == 2 and shape[0] == rows) or (len(shape) == 3 and shape[:2] == (tokens, K))
+        return rows_first and shape[-1] in widths and jnp.issubdtype(aval.dtype, jnp.floating)
+
+    return [
+        e.primitive.name for e in eqns
+        if e.primitive.name not in ("reshape", "convert_element_type", "custom_vjp_call", "jit")  # the same buffer, or a call that holds the writer
+        and any(is_buffer(v.aval) for v in e.outvars)
+    ]
+
+
+@pytest.mark.parametrize("case", ["held_share", "checkpoint"])
+def test_compact_rows_hold_no_scatter_and_four_buffers_of_every_row(case, monkeypatch):
+    """The compact body and its gradient move rows by gathers alone (no
+    ``scatter*`` whose operand has ``tokens x K`` or ``bound`` rows of
+    features), and the traced gradient writes a buffer of ``tokens x K``
+    rows four times: the gathered results the weighted sum reads, in the
+    forward and again where the backward differentiates the body from its
+    inputs; their gradient ``dy x gates`` (the einsum's own transpose); and
+    the gathered row gradients each token sums (the transpose of
+    ``rows_of_tokens``). The all-rows body, walked the same way, writes such
+    a buffer some forty times."""
+    from trlx_tpu.models import transformer
+
+    _small_row_tile(monkeypatch)
+    tokens, K = 36, _DROPLESS_K
+    bound = transformer.held_row_bound(tokens * K, 3, 8)
+    assert bound == 88
+
+    def walk(take):
+        monkeypatch.setattr(transformer, "_either", _TAKE[take])
+        loss, params, x = _dropless_layer_loss(case, jnp.bfloat16, None, monkeypatch)
+        eqns = list(_equations(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(params, x).jaxpr))
+        scatters = [
+            e.primitive.name for e in eqns
+            if e.primitive.name.startswith("scatter") and e.invars[0].aval.ndim == 2
+            and e.invars[0].aval.shape[0] in (tokens * K, bound, bound + 1)
+        ]
+        return scatters, _row_buffers(eqns, tokens * K, tokens, K)
+
+    scatters, buffers = walk("compact")
+    assert scatters == [], scatters
+    assert sorted(buffers) == ["gather", "gather", "gather", "transpose"], buffers
+    scatters, buffers = walk("all_rows")
+    assert scatters == [] and len(buffers) > 40, buffers
+
+
+_ALL_HELD_PROGRAMS = {
+    "bf16": "4449df6e17478673350851b0b03c630fb61dd02de5222d1d2b75974e942c5363",
+    "f32": "3caacfd088979e0cd90659f71eaeed2322b2f345ecb438dc701c323cd61eea0a",
+    "f32_pieces": "302aedbb690ede31bc0d050353bbc4dca7c5366fcecc8ac8390ef1cfd964e86e",
+}
+
+
+@pytest.mark.parametrize("which", list(_ALL_HELD_PROGRAMS))
+def test_a_layer_that_holds_every_expert_traces_the_program_it_had(which, monkeypatch, clean_trace_state):
+    """Where no bound applies (every expert held here; more than a sixteenth
+    of them, or a short call) the layer has one body and no ``cond``: the jaxpr of its
+    value and gradients is, letter for letter, the one recorded from the
+    commit before the bound (``dc79a02``; sha256 of ``str(jaxpr)``), whole
+    and in three pieces under ``lax.map``."""
+    import hashlib
+
+    case = "pieces_all_held" if which.endswith("pieces") else "all_held"
+    dtype = jnp.bfloat16 if which == "bf16" else jnp.float32
+    loss, params, x = _dropless_layer_loss(case, dtype, None, monkeypatch)
+    text = str(jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1)))(params, x))
+    assert "cond[" not in text
+    assert hashlib.sha256(text.encode()).hexdigest() == _ALL_HELD_PROGRAMS[which]
+
+
+def test_held_row_bound_is_twice_the_even_share_up_to_a_tile():
+    """The arithmetic of the cells: 8 of 256 experts at a 4096-token piece,
+    at train steps of 8192 and 5120 tokens, 8 of 128; no bound where it would
+    not cut the rows by eight (32 of 256, 16 of 64, every expert), nor for a
+    decode step's rows or the toy layers of this file."""
+    from trlx_tpu.models.transformer import held_row_bound
+
+    assert held_row_bound(4096 * 8, 8, 256) == 2048
+    assert held_row_bound(8192 * 8, 8, 256) == 4096
+    assert held_row_bound(5120 * 8, 8, 256) == 2560
+    assert held_row_bound(4096 * 8, 8, 128) == 4096
+    assert held_row_bound(512 * 8, 8, 256) == 256  # the shortest call with a bound
+    assert held_row_bound(4096 * 8, 32, 256) == 4096 * 8 and held_row_bound(8192 * 6, 16, 64) == 8192 * 6
+    assert held_row_bound(64 * 8, 8, 256) == 512 and held_row_bound(128 * 8, 8, 128) == 1024
+    assert held_row_bound(8192 * 8, 64, 64) == 8192 * 8
+    assert held_row_bound(36 * 3, 3, 8) == 108
 
 
 @pytest.mark.parametrize("method", ["grpo", "ppo"])

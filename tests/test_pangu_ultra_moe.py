@@ -128,7 +128,7 @@ def test_forward_matches_reference(seed, cfg):
     assert rel_l2(out["logits"], want, mask) < TOL
     load, shared = np.asarray(out["router_load"]), np.asarray(out["router_shared"])
     assert load[0] == 0.0  # nothing dropped: the layers have no capacity
-    assert load.shape == ((4,) if cfg is HELD else (2,))
+    assert load.shape == ((5,) if cfg is HELD else (2,))  # [.., held_frac, held_load_max_over_mean, compact_frac]
     # the shared expert's rows over its rows and the routed rows computed here
     # (all of them: 1 / (1 + top 2); a quarter of them held: about 1 / 1.5)
     assert shared.shape == (2,) and 0.0 < shared[1] < 1.0
@@ -402,8 +402,8 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
         assert rel_l2(y, routed_part + shared_part, mask) < TOL
         routed_total = routed_total + (y - shared_part)  # every chip computes the shared expert alike
         held_assignments += float(aux[6])
-        assert float(aux[3]) == 0.0 and aux.shape == (10,)
-        assert float(aux[8]) == float(jnp.sum(mask))  # rows through the shared expert
+        assert float(aux[3]) == 0.0 and aux.shape == (12,)
+        assert float(aux[10]) == float(jnp.sum(mask))  # rows through the shared expert, behind the two compact-call counts
     assert rel_l2(routed_total + shared_want, routed_want + shared_want, mask) < TOL
     assert held_assignments == float(jnp.sum(mask)) * K
     y_all, aux_all = MoEMLP(CFG).apply({"params": whole}, n, mask)

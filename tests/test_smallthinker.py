@@ -133,8 +133,9 @@ def test_forward_matches_reference(seed, cfg):
     load = np.asarray(out["router_load"])
     assert load[0] == 0.0  # nothing dropped: the layer has no capacity
     if cfg is HELD:
-        # [.., held_frac, held_load_max_over_mean]: two of eight experts held
-        assert load.shape == (4,) and 0.05 < load[2] < 0.6 and 1.0 <= load[3] <= 2.0
+        # [.., held_frac, held_load_max_over_mean, compact_frac]: two of eight experts held
+        # (a quarter of the experts held: no bound on the row buffers, no call counted)
+        assert load.shape == (5,) and 0.05 < load[2] < 0.6 and 1.0 <= load[3] <= 2.0 and load[4] == 0.0
     else:
         assert load.shape == (2,)
 
@@ -545,14 +546,21 @@ def test_hf_interop_says_there_is_no_converter():
 # ---------------------------------------------------------------------------
 
 
-def test_train_runs_grpo_on_the_preset_and_logs_its_counters(tmp_path):
+def test_train_runs_grpo_on_the_preset_and_logs_its_counters(tmp_path, monkeypatch):
     """``trlx_tpu.train()`` on ``builtin:smallthinker-test`` holding experts 2
     and 3: the same trainer, collector, sampler, scoring forward, hydra branch
     and train step as every other preset. Prompts of 20 letters and 12 new
     tokens: 32 slots, four times the window, so the sampler's window layers
     run rings; the records carry the layer kinds' cache bytes, the window
-    layers' read share, the held experts' share and the blocks flash visits."""
+    layers' read share, the held experts' share and the blocks flash visits.
+    A quarter of the experts held and calls of a few hundred rows have no
+    bound on their row buffers (``held_row_bound``'s two floors): lifted
+    here, so that the job runs the compact dispatch and logs its counter."""
     import trlx_tpu.trlx as trlx
+    from trlx_tpu.models import transformer
+
+    monkeypatch.setattr(transformer, "MOE_HELD_MIN_ROWS", 0)
+    monkeypatch.setattr(transformer, "MOE_HELD_MIN_CUT", 1)
     from trlx_tpu.data.default_configs import default_grpo_config
 
     config = default_grpo_config().evolve(
@@ -589,6 +597,9 @@ def test_train_runs_grpo_on_the_preset_and_logs_its_counters(tmp_path):
     step = next(r for r in records if "time/train_step" in r)
     assert 0.0 < float(step["moe/held_frac"]) < 0.7 and float(step["moe/dropped_frac"]) == 0.0
     assert 1.0 <= float(step["moe/held_load_max_over_mean"]) <= 2.0
+    # two of eight experts held, and the bound's two floors lifted above: the sorted row buffers have half of tokens x K
+    # rows, up to a 128-row tile, and every call of the train step's forward fitted them
+    assert float(step["moe/compact_frac"]) == 1.0
     assert step["learn/step_width"] <= 128 and step["learn/attn_visited_frac"] == 1.0  # one 128-slot block
     assert step["learn/attn_tile"] == 128.0 and step["learn/attn_interior_frac"] == 0.0  # and it holds the diagonal
     assert np.isfinite([v for k, v in step.items() if k.startswith("losses/")]).all()

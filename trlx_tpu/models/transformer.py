@@ -2875,6 +2875,88 @@ permute_rows.defvjp(
 )
 
 
+@jax.custom_vjp
+def rows_or_zero(rows: jax.Array, slot: jax.Array, live: jax.Array) -> jax.Array:
+    """``rows[slot]`` for ``rows [C, d]`` and ``slot [M]`` in ``[0, C]``,
+    where slot ``C`` reads a row of zeros; ``live [C]`` says which entry of
+    ``slot`` reads each row (``slot[live[r]] == r``, and no other entry
+    does). So the transpose is a gather too, ``g[live]``: what the
+    scatter-add JAX would derive gives, bit for bit, without the scatter
+    (``permute_rows``)."""
+    padded = jnp.concatenate([rows, jnp.zeros((1,) + rows.shape[1:], rows.dtype)])
+    return padded.at[slot].get(mode="promise_in_bounds")
+
+
+rows_or_zero.defvjp(
+    lambda rows, slot, live: (rows_or_zero(rows, slot, live), live),
+    lambda live, g: (g.at[live].get(mode="promise_in_bounds", unique_indices=True), None, None),
+)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def rows_of_tokens(x: jax.Array, live: jax.Array, slot: jax.Array, K: int) -> jax.Array:
+    """``x[live // K]``: the token of each of the first ``C`` sorted
+    assignments (``live [C]``: sorted row -> assignment ``token * K +
+    choice``; ``slot [N * K]``: assignment -> sorted row, ``C`` for one past
+    them). A token is read by up to ``K`` rows, so the transpose adds: each
+    token sums the gradients of its ``K`` assignments' rows, gathered by
+    ``slot`` with zeros for the assignments past ``C``: the sum
+    ``jnp.repeat``'s own transpose makes of a ``[N * K, d]`` row buffer, by a
+    gather and no scatter."""
+    return x.at[live // K].get(mode="promise_in_bounds")
+
+
+def _rows_of_tokens_bwd(K, res, g):
+    live, slot = res
+    like = jax.ShapeDtypeStruct((slot.shape[0] // K,) + g.shape[1:], g.dtype)
+    (dx,) = jax.linear_transpose(lambda x: jnp.repeat(x, K, axis=0), like)(rows_or_zero(g, slot, live))
+    return dx, None, None
+
+
+rows_of_tokens.defvjp(
+    lambda x, live, slot, K: (rows_of_tokens(x, live, slot, K), (live, slot)), _rows_of_tokens_bwd
+)
+
+
+def _either(take_first, first, second, *operands):
+    """``first(*operands)`` or ``second(*operands)`` on the chip's own
+    reading of ``take_first`` (the one ``lax.cond`` of the dropless
+    dispatch, forward and backward)."""
+    return jax.lax.cond(take_first, first, second, *operands)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0,))
+def one_of_two_rows(bodies, take_first, x, gate_vals, kernels, route):
+    """``bodies[0]`` or ``bodies[1]`` of ``(x, gate_vals, kernels, route)``,
+    two statements of one function, by ``take_first``. Differentiated as
+    one: the backward reads ``take_first`` again and differentiates the body
+    taken from the INPUTS, inside its own branch. Left to JAX, the forward
+    ``cond`` would return both bodies' residuals, and the body not taken has
+    to fill its own with zeros: ``tokens x K``-row buffers written for
+    nothing, the cost the compact body is there to take away."""
+    return _either(take_first, *bodies, x, gate_vals, kernels, route)
+
+
+def _one_of_two_rows_bwd(bodies, res, g):
+    take_first, *inputs = res
+
+    def back(body):
+        def run(x, gate_vals, kernels, route, g):
+            _, vjp = jax.vjp(lambda *diff: body(*diff, route), x, gate_vals, kernels)
+            return vjp(g)
+
+        return run
+
+    dx, dgates, dkernels = _either(take_first, back(bodies[0]), back(bodies[1]), *inputs, g)
+    return None, dx, dgates, dkernels, None
+
+
+one_of_two_rows.defvjp(
+    lambda bodies, take_first, *inputs: (one_of_two_rows(bodies, take_first, *inputs), (take_first, *inputs)),
+    _one_of_two_rows_bwd,
+)
+
+
 class MoEMLP(nn.Module):
     """Mixture-of-experts MLP: top-k router, then one of two dispatches.
 
@@ -2930,6 +3012,21 @@ class MoEMLP(nn.Module):
     does, is never computed and adds nothing: the layer returns the part of
     the result its own experts give, and that partial result goes on to the
     next layer. Nothing stands in for the absent chips or their traffic.
+    The held experts' assignments sort FIRST, so such a layer builds its row
+    buffers at ``held_row_bound`` rows, twice the share an even router sends
+    here, and not at ``B·T·k`` (``_held_rows``): the tokens of the first
+    ``bound`` sorted assignments are gathered (no ``repeat``), the three
+    grouped matmuls, the activation and the selects run on ``bound`` rows,
+    and the weighted sum reads each assignment's result through a gather
+    that gives a row of zeros to the assignments past the bound
+    (``rows_or_zero``, ``rows_of_tokens``: a gather in the backward too). A
+    call whose held rows pass the bound runs every row (``_all_rows``, the
+    body of a layer that holds them all) behind one ``lax.cond`` on the
+    traced count, forward and backward (``one_of_two_rows``): dropless and
+    exact either way, the same bits from both bodies. ``aux`` counts the
+    calls that fitted (``moe/compact_frac``). Where no bound applies (every
+    expert held, or more than a sixteenth of them; a short call, a decode
+    step's: ``held_row_bound``) there is one body and no ``cond``.
     """
 
     config: TransformerConfig
@@ -3002,8 +3099,11 @@ class MoEMLP(nn.Module):
         kernels["w_up"] = expert_kernel("w_up", (held, d, f), ("expert", "embed", "ffn"))
         kernels["w_down"] = expert_kernel("w_down", (held, f, d), ("expert", "ffn", "embed"))
 
-        dispatch = self._dropless if cfg.moe_capacity_factor == 0 else self._capacity
-        y, counts, dropped = dispatch(x, w, gate_vals, idx, kernels)
+        compact = None  # [calls that took the compact path, calls] of a layer that holds a share
+        if cfg.moe_capacity_factor == 0:
+            y, counts, dropped, compact = self._dropless(x, w, gate_vals, idx, kernels)
+        else:
+            y, counts, dropped = self._capacity(x, w, gate_vals, idx, kernels)
         if cfg.num_shared_experts:
             # every token, unweighted; a padding token's part is dropped with
             # the rest of its output
@@ -3030,6 +3130,8 @@ class MoEMLP(nn.Module):
             # busiest held expert over the mean of the held
             here = counts[cfg.moe_first_expert : cfg.moe_first_expert + held]
             stats += [jnp.sum(here), held * jnp.max(here) / jnp.maximum(jnp.sum(here), 1.0) * n_real]
+            # dropless calls that fitted ``held_row_bound``, and dropless calls
+            stats += [compact[0], compact[1]]
         if cfg.num_shared_experts:
             # rows through the shared expert, and the sum of the chosen raw scores
             stats += [n_real * cfg.num_shared_experts, jnp.sum(chosen_scores * w[..., None])]
@@ -3054,18 +3156,17 @@ class MoEMLP(nn.Module):
         if pieces == 1:
             return self._dropless_rows(x, w, gate_vals, idx, kernels)
         split = lambda a: a.reshape(pieces, 1, B * T // pieces, *a.shape[2:])
-        y, counts, dropped = jax.lax.map(
+        y, counts, dropped, compact = jax.lax.map(
             lambda piece: self._dropless_rows(*piece, kernels),
             tuple(split(a) for a in (x, w, gate_vals, idx)),
         )
-        return y.reshape(B, T, d), counts.sum(0), dropped.sum(0)
+        return y.reshape(B, T, d), counts.sum(0), dropped.sum(0), None if compact is None else compact.sum(0)
 
     def _dropless_rows(self, x, w, gate_vals, idx, kernels):
         """Every real token through all of its ``K`` experts that are held
         here. Returns ``(y [B, T, d], assignments asked for per router expert
-        [E], dropped = 0)``."""
-        from trlx_tpu.ops.grouped_matmul import grouped_matmul
-
+        [E], dropped = 0, [took the compact path, 1] or None where every
+        sorted row may be live)``."""
         cfg = self.config
         E, K = cfg.num_experts, cfg.num_experts_per_tok
         B, T, d = x.shape
@@ -3090,13 +3191,27 @@ class MoEMLP(nn.Module):
             group_sizes = counts[first : first + held]
         order = jnp.argsort(expert)  # stable: sorted row -> assignment
         unsort = jnp.zeros_like(order).at[order].set(jnp.arange(N * K), unique_indices=True)
-        # a permutation of the K-fold repeated rows, so that its backward is
-        # the gather by ``unsort`` (``permute_rows``), as the backward of the
-        # unsort below is the gather by ``order``: no scatter of the row
-        # buffer, which a v5e runs at 5.5 GB/s against a gather's 192.
-        # x[order // K] has no inverse to gather by: its transpose
-        # scatter-adds with duplicates
-        xin = permute_rows(jnp.repeat(x.reshape(N, d), K, axis=0), order, unsort)
+        route = (real, order, unsort, group_sizes)
+        bound = held_row_bound(N * K, held, E)
+        if bound == N * K:  # every sorted row may be live: one program, no choice to count
+            y = self._all_rows(x, gate_vals, kernels, route)
+            compact = None if held == E else jnp.zeros((2,), jnp.float32)
+        else:
+            # the held experts' assignments sort first, so the live rows are
+            # the first ``sum(group_sizes)`` of ``order``: where they fit the
+            # bound, the row buffers have ``bound`` rows; a call that
+            # overflows it runs every row, as a layer that holds them all does
+            fits = jnp.sum(group_sizes) <= bound
+            bodies = (partial(self._held_rows, bound=bound), self._all_rows)
+            y = one_of_two_rows(bodies, fits, x, gate_vals, kernels, route)
+            compact = jnp.stack([fits.astype(jnp.float32), jnp.ones((), jnp.float32)])
+        return y.reshape(B, T, d), counts.astype(jnp.float32), jnp.zeros((), jnp.float32), compact
+
+    def _sorted_rows(self, xin, kernels, group_sizes):
+        """The experts on the first rows of a sort: ``xin [rows, d]``, of
+        which the first ``sum(group_sizes)`` lie in a group. Zeros past them."""
+        from trlx_tpu.ops.grouped_matmul import grouped_matmul
+
         # rows past the last group (padding, an expert of another chip) hold
         # whatever the kernels left, and so does their GRADIENT: neither
         # kernel's backward writes it (on a v5e ``ragged_dot`` left NaN there
@@ -3104,20 +3219,60 @@ class MoEMLP(nn.Module):
         # it was given either way (the compiler folds it away) and passes the
         # gradient of the rows in a group alone, once, where it would reach
         # the tokens; the rows in between never mix with a group's
-        in_a_group = (jnp.arange(N * K) < jnp.sum(group_sizes))[:, None]
+        in_a_group = (jnp.arange(xin.shape[0]) < jnp.sum(group_sizes))[:, None]
         xin = jnp.where(in_a_group, xin, jax.lax.stop_gradient(xin))
 
-        out = self._experts(  # [N·K, d], sorted by expert
+        out = self._experts(  # sorted by expert
             kernels, lambda lhs, kernel: grouped_matmul(lhs, kernel, group_sizes), xin
         )
-        out = jnp.where(in_a_group, out, 0)
-        out = permute_rows(out, unsort, order).reshape(N, K, d)
+        return jnp.where(in_a_group, out, 0)
+
+    @staticmethod
+    def _sum_choices(out, gate_vals, real):
+        """``[N, K, d]`` results by assignment, weighed and summed a token:
+        ``[N, d]`` float32."""
+        N, K, _ = out.shape
         gates = gate_vals.reshape(N, K) * real[:, None]
-        y = jnp.einsum(
+        return jnp.einsum(
             "nkd,nk->nd", out, gates.astype(out.dtype),
             preferred_element_type=jnp.float32,
         )
-        return y.reshape(B, T, d), counts.astype(jnp.float32), jnp.zeros((), jnp.float32)
+
+    def _all_rows(self, x, gate_vals, kernels, route):
+        """All ``tokens x K`` sorted rows through the grouped matmuls,
+        whichever of them lie in a group. ``[N, d]`` float32."""
+        real, order, unsort, group_sizes = route
+        K, d = self.config.num_experts_per_tok, x.shape[-1]
+        N = real.shape[0]
+        # a permutation of the K-fold repeated rows, so that its backward is
+        # the gather by ``unsort`` (``permute_rows``), as the backward of the
+        # unsort below is the gather by ``order``: no scatter of the row
+        # buffer, which a v5e runs at 5.5 GB/s against a gather's 192.
+        # x[order // K] has no inverse to gather by: its transpose
+        # scatter-adds with duplicates
+        xin = permute_rows(jnp.repeat(x.reshape(N, d), K, axis=0), order, unsort)
+        out = self._sorted_rows(xin, kernels, group_sizes)  # [N·K, d]
+        out = permute_rows(out, unsort, order).reshape(N, K, d)
+        return self._sum_choices(out, gate_vals, real)
+
+    def _held_rows(self, x, gate_vals, kernels, route, bound):
+        """``_all_rows`` for a call whose live rows, the first
+        ``sum(group_sizes)`` of the sort, number at most ``bound``: the row
+        buffers hold the first ``bound`` sorted rows and nothing is built at
+        ``tokens x K`` rows but the gathered results the weighted sum reads
+        (forward) and the gathered row gradients the tokens sum (backward).
+        Equal to ``_all_rows`` to the bit, value and gradients: the same
+        rows through the same kernels, zeros where its select writes zeros,
+        the same sum over a token's ``K`` choices."""
+        real, order, unsort, group_sizes = route
+        K, d = self.config.num_experts_per_tok, x.shape[-1]
+        N = real.shape[0]
+        live = order[:bound]  # sorted row -> assignment, the held experts' first
+        slot = jnp.minimum(unsort, bound)  # assignment -> sorted row, or the row of zeros
+        xin = rows_of_tokens(x.reshape(N, d), live, slot, K)
+        out = self._sorted_rows(xin, kernels, group_sizes)  # [bound, d]
+        out = rows_or_zero(out, slot, live).reshape(N, K, d)
+        return self._sum_choices(out, gate_vals, real)
 
     def _capacity(self, x, w, gate_vals, idx, kernels):
         """GShard one-hot dispatch with a static capacity. Returns ``(y,
@@ -3198,6 +3353,40 @@ MOE_PIECE_TOKENS = 16384
 # and keeps its program.
 MOE_MAX_ROW_BYTES = 2 * 2**30
 MOE_PIECE_ROW_BYTES = 2**29
+# Where a layer holds `held` of its `E` experts, an even router sends
+# tokens·K·held/E of a call's tokens·K assignments to them, and they sort
+# first. The row buffers of such a call have MOE_HELD_ROWS_FACTOR times that
+# share of the rows, up to the grouped matmul's row tile (`held_row_bound`):
+# 6.25% of tokens·K where a chip holds 8 of 256, 12.5% at 8 of 128. A call
+# whose held rows pass the bound (a router twice as fond of this chip's
+# experts as of the others) runs all tokens·K rows, as before; the pieces
+# above stay sized for that. The second body is code the chip holds: 1.0 to
+# 1.7 MB a forward layer and 3.1 to 4.1 MB a trained one (compiled for a
+# described v5e, PR 57), 53 and 59 MB over the programs of the cells that
+# hold 32 of 256 and 16 of 64, which is 0.93 and 1.27% of their
+# `peak_hbm_gib` against a bound of 1%. So the bound has to cut the rows by
+# MOE_HELD_MIN_CUT or more (a layer that holds more than a sixteenth of its
+# experts traces the one program it always had), and a call under
+# MOE_HELD_MIN_ROWS rows keeps them all: a decode step's 256 to 1024 rows are
+# a few MB, and the `cond` around them cost more than the passes it spared
+# (cell 9's loop of rounds 9.57 -> 9.85 s, cell 7's 5.42 -> 5.44 s, PR 57).
+# The observable is the share held and the call's size; constants with their
+# arithmetic, not settings.
+MOE_HELD_ROWS_FACTOR = 2
+MOE_HELD_MIN_CUT = 8
+MOE_HELD_MIN_ROWS = 4096
+
+
+def held_row_bound(rows: int, held: int, experts: int) -> int:
+    """Rows of the sorted row buffers of a dropless call of ``rows = tokens x
+    K`` assignments in a layer that holds ``held`` of ``experts``; ``rows``
+    where no bound applies."""
+    from trlx_tpu.ops import grouped_matmul
+
+    tile = grouped_matmul.ROW_TILE
+    even = -(-rows * held // experts)
+    bound = -(-MOE_HELD_ROWS_FACTOR * even // tile) * tile
+    return bound if rows >= MOE_HELD_MIN_ROWS and bound * MOE_HELD_MIN_CUT <= rows else rows
 
 
 def moe_token_pieces(tokens: int, token_bytes: int = 0) -> int:
@@ -3221,7 +3410,8 @@ def aux_size(cfg: TransformerConfig) -> int:
     tokens·lse², tokens, assignments dropped, assignments asked for, (busiest
     expert / mean)·tokens] and, where the layer holds a share of its experts,
     [assignments that fell on a held expert, (busiest held expert / mean of
-    the held)·tokens]; then, where the layers have shared experts, [rows
+    the held)·tokens, dropless calls whose held rows fitted ``held_row_bound``,
+    dropless calls that had such a bound]; then, where the layers have shared experts, [rows
     through the shared expert, Σ chosen raw router scores]; then, where
     layers run ``KDAMixer``, [Σ beta, real tokens, one slot a KDA layer that
     only it writes] (``kda_summary``); then, under a headwise gate, [Σ of the
@@ -3238,7 +3428,7 @@ def gate_summary(aux: jax.Array) -> jax.Array:
 
 
 def _moe_aux_size(cfg: TransformerConfig) -> int:
-    return 6 + 2 * _holds_share(cfg) + 2 * bool(cfg.num_shared_experts)
+    return 6 + 4 * _holds_share(cfg) + 2 * bool(cfg.num_shared_experts)
 
 
 def kda_layers(cfg: TransformerConfig) -> Tuple[int, ...]:
@@ -3274,12 +3464,14 @@ def router_load_summary(aux: jax.Array, cfg: TransformerConfig) -> jax.Array:
     and not computed (0 under dropless routing, always), and the busiest
     expert's assignments over the mean, token-weighted over the layers.
     Where the layers hold a share of their experts, also ``[held_frac,
-    held_load_max_over_mean]``: the share of the assignments asked for that
-    fell on a held expert (every one of them computed), and the busiest held
-    expert over the mean of the held."""
+    held_load_max_over_mean, compact_frac]``: the share of the assignments asked for that
+    fell on a held expert (every one of them computed), the busiest held
+    expert over the mean of the held, and ``compact_frac``: the share of the
+    dropless calls with a bound on their row buffers (``held_row_bound``)
+    whose held rows fitted it (the others ran every row: as exact, slower)."""
     load = [aux[3] / jnp.maximum(aux[4], 1.0), aux[5] / jnp.maximum(aux[2], 1.0)]
-    if _holds_share(cfg):  # [held_frac, held_load_max_over_mean]
-        load += [aux[6] / jnp.maximum(aux[4], 1.0), aux[7] / jnp.maximum(aux[2], 1.0)]
+    if _holds_share(cfg):  # [held_frac, held_load_max_over_mean, compact_frac]
+        load += [aux[6] / jnp.maximum(aux[4], 1.0), aux[7] / jnp.maximum(aux[2], 1.0), aux[8] / jnp.maximum(aux[9], 1.0)]
     return jnp.stack(load)
 
 
@@ -3288,7 +3480,7 @@ def shared_expert_summary(aux: jax.Array, cfg: TransformerConfig) -> jax.Array:
     the share of the expert rows computed here that are the shared expert's
     (its rows over its rows plus the routed assignments that fell on an
     expert held here), and the mean raw router score of a chosen expert."""
-    i = 6 + 2 * _holds_share(cfg)
+    i = 6 + 4 * _holds_share(cfg)
     routed_here = aux[6] if _holds_share(cfg) else aux[4] - aux[3]
     return jnp.stack([aux[i] / jnp.maximum(aux[i] + routed_here, 1.0), aux[i + 1] / jnp.maximum(aux[4], 1.0)])
 

@@ -693,6 +693,7 @@ class TPUBaseTrainer(BaseRLTrainer):
             if load.shape[0] > 2:  # layers that hold a share of their experts
                 stats["moe/held_frac"] = load[2]
                 stats["moe/held_load_max_over_mean"] = load[3]
+                stats["moe/compact_frac"] = load[4]
         shared = out.get("router_shared")
         if shared is not None:  # layers with a shared expert beside the routed ones
             stats["moe/shared_row_frac"] = shared[0]
