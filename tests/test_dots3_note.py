@@ -30,7 +30,7 @@ from chipbench.costs import dots3_note as costs
 from chipbench.reference import dots3_note as reference
 from trlx_tpu.models import transformer
 from trlx_tpu.models.transformer import CausalTransformer, MoEMLP, TransformerConfig, config_from_spec, make_kv_cache
-from trlx_tpu.ops.paged_kv import dense_kv_bytes, index_cache_bytes, latent_cache_bytes, latent_ring_bytes
+from trlx_tpu.ops.cache_layout import INDEX, LATENT, cache_bytes, ring
 from trlx_tpu.ops.sampling import GenerationConfig, kv_slots_read, layer_extents
 
 TOL = 1e-4  # relative L2 of float32 logits: what is left is the order of summation
@@ -218,12 +218,10 @@ def test_cache_tree_holds_a_ring_on_window_layers_and_index_keys_on_full_ones():
     for i in WINDOW:
         assert {k: (v.shape, v.dtype) for k, v in cache[i].items()} == {
             "ckv": ((B, 5, 24), jnp.float32), "k_rope": ((B, 5, 4), jnp.float32)}
-    assert latent_ring_bytes(cache, T) == 3 * B * 5 * 28 * 4
-    assert latent_cache_bytes(cache) - latent_ring_bytes(cache, T) == 3 * B * T * 24 * 4
-    assert index_cache_bytes(cache) == 3 * B * T * 12 * 4
-    assert dense_kv_bytes(CFG, B, T) == latent_cache_bytes(cache) + index_cache_bytes(cache)
+    # the account beside the arithmetic: three window layers' rings, three full layers' latents and index keys, nothing else
+    assert cache_bytes(cache, T) == {ring(LATENT): 3 * B * 5 * 28 * 4, LATENT: 3 * B * T * 24 * 4, INDEX: 3 * B * T * 12 * 4}
     short = jax.eval_shape(lambda: make_kv_cache(CFG, B, 4))  # a row inside the window: no ring
-    assert latent_ring_bytes(short, 4) == 0 and short[2]["ckv"].shape == (B, 4, 24)
+    assert cache_bytes(short, 4)[ring(LATENT)] == 0 and short[2]["ckv"].shape == (B, 4, 24)
     bf16 = jax.eval_shape(lambda: make_kv_cache(dataclasses.replace(CFG, dtype=jnp.bfloat16), B, T))
     assert {leaf.dtype for leaf in jax.tree_util.tree_leaves(bf16)} == {jnp.dtype(jnp.bfloat16)}
 
@@ -339,8 +337,9 @@ def sample_speculatively():
     (sample_speculatively, "speculative"),
 ], ids=["slot_refill", "engine", "prefix_cache", "speculative"])
 def test_kv_only_path_refuses_the_latent_ring_and_the_index_cache_by_name(build, path):
-    words = (rf"^{path} does not support a model whose cache holds a latent in place of K and V.*a window layer's ring of "
-             r"latents too; .*dots3_note families\), and index keys with it \(leaves \('k_index',\).*B4")
+    words = (rf"^{path} does not support a model whose cache holds a latent in place of K and V \(leaves \['latent'\]\): .*B4[ab]\); and a latent in "
+             r"place of K and V in a ring of 5 slots for a row of \d+ \(leaves \['ckv', 'k_rope'\]\): .*B4[ab]\); and index keys beside a latent "
+             r"\(leaves \['k_index'\]\): .*B8c\); use the plain sampler")
     with pytest.raises(NotImplementedError, match=words):
         build()
 
@@ -493,7 +492,7 @@ def test_the_cut_is_the_configuration_files_and_its_widths_check():
     assert abs(count(shapes["h_1"]["attn"]) - (134.69 + 9.37)) < 0.05 and abs(count(shapes["h_2"]["attn"]) - 90.83) < 0.05
     assert sorted(k for k in shapes if k.startswith("h_") and "indexer" in shapes[k]["attn"]) == ["h_0", "h_1", "h_5"]
     cache = jax.eval_shape(lambda: make_kv_cache(dataclasses.replace(cut, dtype=jnp.bfloat16), 8, 8192))
-    assert latent_ring_bytes(cache, 8192) == 3 * 8 * 513 * 1088 * 2
+    assert cache_bytes(cache, 8192)[ring(LATENT)] == 3 * 8 * 513 * 1088 * 2
     assert cache[1]["latent"].shape == (8, 8192, 576) and cache[1]["k_index"].shape == (8, 8192, 128)
     traffic = job.load_json("traffic", "ppo_ctx8k_u2")
     assert traffic["job"]["model"]["num_layers_unfrozen"] == 2 and set(traffic["job"]) == {"method", "train", "model"}

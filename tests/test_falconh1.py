@@ -31,7 +31,7 @@ from trlx_tpu.models.transformer import (
     config_from_spec,
     make_kv_cache,
 )
-from trlx_tpu.ops.paged_kv import refuse_recurrent_state
+from trlx_tpu.ops.cache_layout import refuse
 from trlx_tpu.ops.ssd import ssd_chunked, ssd_step
 
 # Relative L2 of the logits. Both sides compute in float32 and the CPU's
@@ -255,7 +255,7 @@ def cache_of(cfg):
     return lambda B, S: make_kv_cache(cfg, B, S)
 
 
-REFUSAL = r"{path} does not support a model whose cache holds recurrent state .*mamba2.*falcon_h1.*B7"
+REFUSAL = r"{path} does not support a model whose cache holds recurrent state beside K and V \(leaves \['conv', 'ssm'\]\): .*recurrent state.*B7[bc]\); use the plain sampler"
 
 
 @pytest.mark.parametrize("path", ["slot_refill", "engine", "prefix_cache", "speculative"])
@@ -265,9 +265,9 @@ def test_kv_only_path_refuses_the_hybrid_cache_by_name(path, scan_layers):
 
     hybrid = dataclasses.replace(CFG, scan_layers=scan_layers)
     with pytest.raises(NotImplementedError, match="^" + REFUSAL.format(path=path)):
-        refuse_recurrent_state(jax.eval_shape(lambda: make_kv_cache(hybrid, 1, 1)), path)
+        refuse(jax.eval_shape(lambda: make_kv_cache(hybrid, 1, 1)), path, 1)
     kv_only = TransformerConfig.mistral("test", scan_layers=scan_layers)
-    refuse_recurrent_state(make_kv_cache(kv_only, 1, 1), path)  # passes
+    refuse(make_kv_cache(kv_only, 1, 1), path, 1)  # passes
 
 
 def build_slot_refill(paged):

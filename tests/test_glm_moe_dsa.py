@@ -40,7 +40,7 @@ from trlx_tpu.models.transformer import (
     sparse_gather_rows,
 )
 from trlx_tpu.ops import sampling
-from trlx_tpu.ops.paged_kv import dense_kv_bytes, index_cache_bytes, latent_cache_bytes, refuse_latent_cache
+from trlx_tpu.ops.cache_layout import INDEX, LATENT, cache_bytes, refuse
 from trlx_tpu.ops.sampling import GenerationConfig, generate, kv_slots_read
 
 # Relative L2 of the logits. Both sides compute in float32 on the CPU; what is
@@ -232,10 +232,9 @@ def test_cache_tree_holds_index_keys_on_full_layers_only():
         ["k_index", "latent"] if kind == "full" else ["latent"] for kind in TYPES]
     assert cache[1]["latent"].shape == (B, T, CFG.kv_lora_rank + CFG.qk_rope_head_dim) == (B, T, 16 + 8)
     assert cache[0]["k_index"].shape == (B, T, CFG.index_head_dim)
-    assert latent_cache_bytes(cache) == 5 * B * T * (16 + 8) * 4
-    assert index_cache_bytes(cache) == 2 * B * T * 12 * 4
+    assert cache_bytes(cache, T) == {LATENT: 5 * B * T * (16 + 8) * 4, INDEX: 2 * B * T * 12 * 4}
     pangu = jax.eval_shape(lambda: make_kv_cache(TransformerConfig.pangu("test"), B, T))
-    assert index_cache_bytes(pangu) == 0 and all(sorted(layer) == ["ckv", "k_rope"] for layer in pangu)
+    assert cache_bytes(pangu, T)[INDEX] == 0 and all(sorted(layer) == ["ckv", "k_rope"] for layer in pangu)
 
 
 def decode_through_the_caches(params, ids, mask, prompt, cfg=CFG, spy=None):
@@ -462,14 +461,16 @@ def test_the_cells_caches_hold_the_bytes_they_held_and_are_refused_as_they_were(
     shapes = {"latent": (rows, slots, 576)} if index else {"ckv": (rows, slots, 512), "k_rope": (rows, slots, 64)}
     assert all({k: v.shape for k, v in layer.items() if k != "k_index"} == shapes for layer in cache)
     assert all(leaf.dtype == jnp.bfloat16 for leaf in jax.tree_util.tree_leaves(cache))
-    assert (latent_cache_bytes(cache), index_cache_bytes(cache)) == (latent, index)
-    assert latent == 5 * rows * slots * 1152 and dense_kv_bytes(cfg, rows, slots) == latent + index
-    with pytest.raises(NotImplementedError, match=rf"^{path} does not support .*leaves \('ckv', 'k_rope', 'latent'\)"):
-        refuse_latent_cache(cache, path)
+    held = cache_bytes(cache, slots)  # the account beside the arithmetic: 1152 bytes a slot a layer, 128 bf16 index keys on two
+    assert (held[LATENT], held[INDEX], sum(held.values())) == (latent, index, latent + index)
+    assert latent == 5 * rows * slots * 1152 and index == (2 * rows * slots * 128 * 2 if index else 0)
+    leaves = r"\['latent'\]" if index else r"\['ckv', 'k_rope'\]"
+    with pytest.raises(NotImplementedError, match=rf"^{path} does not support .*a latent in place of K and V \(leaves {leaves}\): .*B4[ab]\)"):
+        refuse(cache, path, slots)
     stacked = jax.eval_shape(lambda: make_kv_cache(dataclasses.replace(cfg, scan_layers=True), rows, slots))
     assert {k: v.shape for k, v in stacked.items() if k != "k_index"} == {k: (5,) + v for k, v in shapes.items()}
     with pytest.raises(NotImplementedError, match=rf"^{path} does not support"):
-        refuse_latent_cache(stacked, path)
+        refuse(stacked, path, slots)
 
 
 def test_generate_records_the_references_logprobs(monkeypatch):
@@ -548,8 +549,8 @@ def test_ppo_loss_gradient_of_the_adapters_matches_the_references():
 # what a selection and its cache refuse, by name
 # ---------------------------------------------------------------------------
 
-LATENT_REFUSAL = (r"{path} does not support a model whose cache holds a latent in place of K and V.*"
-                  r"glm_moe_dsa.*index keys with it \(leaves \('k_index',\).*B8\).*B4")
+LATENT_REFUSAL = (r"{path} does not support a model whose cache holds a latent in place of K and V \(leaves \['latent'\]\): .*B4[ab]\); "
+                  r"and index keys beside a latent \(leaves \['k_index'\]\): .*B8c\); use the plain sampler")
 
 
 def cache_of(cfg):
@@ -594,9 +595,9 @@ def sample_speculatively():
 def test_kv_only_path_refuses_the_latent_and_the_index_cache_by_name(build, path):
     with pytest.raises(NotImplementedError, match="^" + LATENT_REFUSAL.format(path=path)):
         build()
-    # a latent cache without index keys is refused in the words it always was
-    with pytest.raises(NotImplementedError, match=r"K and V \(leaves \('ckv', 'k_rope', 'latent'\).*families\): "):
-        refuse_latent_cache(jax.eval_shape(lambda: make_kv_cache(TransformerConfig.pangu("test"), 2, 8)), path)
+    # a latent cache without index keys is refused for the latent alone
+    with pytest.raises(NotImplementedError, match=r"cache holds a latent in place of K and V \(leaves \['ckv', 'k_rope'\]\): [^;]*B4[ab]\); use the plain sampler"):
+        refuse(jax.eval_shape(lambda: make_kv_cache(TransformerConfig.pangu("test"), 2, 8)), path, 8)
 
 
 @pytest.mark.parametrize("how", ["vector_cache_index", "span_past_slot_zero"])

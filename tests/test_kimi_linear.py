@@ -393,13 +393,13 @@ def test_the_cut_is_the_configuration_files_and_its_widths_check():
 
 @pytest.mark.parametrize("path", ["slot_refill", "engine", "prefix_cache", "speculative"])
 def test_kv_only_paths_refuse_the_new_layers_by_name(path):
-    from trlx_tpu.ops.paged_kv import refuse_latent_cache, refuse_recurrent_state
+    from trlx_tpu.ops.cache_layout import refuse
 
     cache = jax.eval_shape(lambda: make_kv_cache(CFG, 1, 8))
-    with pytest.raises(NotImplementedError, match="kda.*kimi_linear.*B7"):
-        refuse_recurrent_state(cache, path)
-    with pytest.raises(NotImplementedError, match="kimi_linear.*B4"):
-        refuse_latent_cache(cache, path)
+    with pytest.raises(NotImplementedError, match=rf"^{path} .*a recurrence's state as the layer's whole cache \(leaves \['conv', 'state'\]\): .*B7[bc]\)"):
+        refuse(cache, path, 8)
+    with pytest.raises(NotImplementedError, match=rf"^{path} .*a latent in place of K and V \(leaves \['ckv', 'k_rope'\]\): .*B4[ab]\)"):
+        refuse(cache, path, 8)
 
 
 @pytest.mark.parametrize("way", ["import", "export"])

@@ -353,13 +353,13 @@ def test_the_cut_is_the_configuration_files_and_its_widths_check():
 
 @pytest.mark.parametrize("path", ["slot_refill", "engine", "prefix_cache", "speculative"])
 def test_kv_only_paths_refuse_the_new_layers_by_name(path):
-    from trlx_tpu.ops.paged_kv import refuse_recurrent_state
+    from trlx_tpu.ops.cache_layout import refuse
 
-    with pytest.raises(NotImplementedError, match="minicpm_sala.*B7"):
-        refuse_recurrent_state(jax.eval_shape(lambda: make_kv_cache(CFG, 1, 8)), path)
+    with pytest.raises(NotImplementedError, match=rf"^{path} .*compressed keys.*B8c\); and a recurrence's state as the layer's whole cache \(leaves \['state'\]\): .*B7[bc]\); use the plain sampler"):
+        refuse(jax.eval_shape(lambda: make_kv_cache(CFG, 1, 8)), path, 8)
     sparse_only = jax.eval_shape(lambda: make_kv_cache(config_from_spec("builtin:minicpm-sala-test", mixer_layout=("attention",) * 4), 1, 8))
-    with pytest.raises(NotImplementedError, match="kbar.*B8"):
-        refuse_recurrent_state(sparse_only, path)
+    with pytest.raises(NotImplementedError, match=rf"^{path} .*cache holds compressed keys beside K and V \(leaves \['kbar'\]\): .*B8c\); use the plain sampler$"):
+        refuse(sparse_only, path, 8)
 
 
 @pytest.mark.parametrize("way", ["import", "export"])

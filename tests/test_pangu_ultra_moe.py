@@ -32,7 +32,7 @@ from trlx_tpu.models.transformer import (
     make_kv_cache,
 )
 from trlx_tpu.ops import sampling
-from trlx_tpu.ops.paged_kv import latent_cache_bytes, refuse_latent_cache
+from trlx_tpu.ops.cache_layout import LATENT, cache_bytes, refuse
 from trlx_tpu.ops.sampling import GenerationConfig, generate
 
 # Relative L2 of the logits. Both sides compute in float32 on the CPU; what is
@@ -187,7 +187,7 @@ def test_cache_tree_holds_the_latent_and_no_k_or_v():
     cache = jax.eval_shape(lambda: make_kv_cache(big, 64, 640))
     per_slot = sum(leaf.shape[-1] for leaf in cache[0].values())
     assert per_slot == 576 and all("k" not in layer and "v" not in layer for layer in cache)
-    assert latent_cache_bytes(cache) == 5 * 64 * 640 * 1152
+    assert cache_bytes(cache, 640) == {LATENT: 5 * 64 * 640 * 1152}
     # per-head K and V of the same rows: 71 times as much
     assert 2 * 128 * (192 + 128) // 1152 == 71
     assert CFG.layer_layouts == (LayerLayout(None, True, "dense"),) + (LayerLayout(None, True, "moe"),) * 2
@@ -281,7 +281,8 @@ def test_generate_records_the_references_logprobs(monkeypatch):
 # what a latent cache refuses, by name
 # ---------------------------------------------------------------------------
 
-LATENT_REFUSAL = r"{path} does not support a model whose cache holds a latent in place of K and V.*B4"
+LATENT_REFUSAL = (r"{path} does not support a model whose cache holds a latent in place of K and V \(leaves \['ckv', 'k_rope'\]\): "
+                  r".*per-head K and V.*B4[ab]\); use the plain sampler")
 
 
 def cache_of(cfg):
@@ -326,7 +327,7 @@ def sample_speculatively():
 def test_kv_only_path_refuses_a_latent_cache_by_name(build, path):
     with pytest.raises(NotImplementedError, match="^" + LATENT_REFUSAL.format(path=path)):
         build()
-    refuse_latent_cache(jax.eval_shape(lambda: make_kv_cache(TransformerConfig.gpt2("test"), 2, 8)), path)
+    refuse(jax.eval_shape(lambda: make_kv_cache(TransformerConfig.gpt2("test"), 2, 8)), path, 8)
 
 
 @pytest.mark.parametrize("how", ["vector_cache_index", "span_past_slot_zero"])

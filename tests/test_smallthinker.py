@@ -36,7 +36,7 @@ from trlx_tpu.models.transformer import (
     make_kv_cache,
 )
 from trlx_tpu.ops import sampling
-from trlx_tpu.ops.paged_kv import refuse_ring_cache
+from trlx_tpu.ops.cache_layout import refuse
 from trlx_tpu.ops.sampling import GenerationConfig, generate, kv_slots_read, layer_extents
 
 # Relative L2 of the logits (or of a gradient leaf). Both sides compute in
@@ -253,8 +253,8 @@ def test_whole_row_paths_run_a_mixed_layout_over_full_length_caches():
     assert rel_l2(out["logits"], want[:, 30:33], mask[:, 30:33]) < TOL
 
 
-RING_REFUSAL = (r"{path} does not support a layer whose cache is shorter than the row "
-                r"\(a window layer's ring of 8 slots for a row of {slots}.*B3")
+RING_REFUSAL = (r"{path} does not support a model whose cache holds per-head K and V in a ring of 8 slots for a row of {slots} "
+                r"\(leaves \['k', 'v'\]\): .*B3c\); use the plain sampler")
 
 
 def cache_of(cfg):
@@ -289,7 +289,7 @@ def build_prefix_cache():
 def test_whole_row_path_refuses_a_ring_by_name(build, path, slots):
     with pytest.raises(NotImplementedError, match="^" + RING_REFUSAL.format(path=path, slots=slots)):
         build()
-    refuse_ring_cache(jax.eval_shape(lambda: make_kv_cache(CFG, 2, 8)), 8, path)  # inside the window: passes
+    refuse(jax.eval_shape(lambda: make_kv_cache(CFG, 2, 8)), path, 8)  # inside the window: passes
 
 
 def test_speculation_with_a_separate_draft_is_refused_by_the_ring_it_would_overrun():

@@ -70,14 +70,8 @@ from trlx_tpu.engine.allocator import (
     TenantQuotaExceeded,
 )
 from trlx_tpu.engine.prefix_cache import PrefixCache
-from trlx_tpu.ops.paged_kv import (
-    block_bytes,
-    kv_bytes,
-    num_table_blocks,
-    refuse_latent_cache,
-    refuse_recurrent_state,
-    refuse_ring_cache,
-)
+from trlx_tpu.ops import cache_layout
+from trlx_tpu.ops.paged_kv import block_bytes, kv_bytes, num_table_blocks
 
 __all__ = [
     "CompletedSequence",
@@ -397,7 +391,7 @@ class Engine:
     individually completed sequences. Trainers talk only to this surface
     (``_collect_continuous``; ``generate`` routes through
     :class:`SerialEngine`), so backends — dense, paged, and eventually the
-    disaggregated actor fleet (ROADMAP item 1) — swap under one interface.
+    disaggregated actor fleet — swap under one interface.
     """
 
     stats: EngineStats
@@ -617,9 +611,7 @@ class ContinuousEngine(Engine):
             self._TB = num_table_blocks(S, self._bs)
             self.allocator = BlockAllocator(self.spec.max_blocks)
             if prefix_cache:
-                refuse_recurrent_state(self.state.cache, "prefix_cache")
-                refuse_latent_cache(self.state.cache, "prefix_cache")
-                refuse_ring_cache(self.state.cache.pool, self._bs, "prefix_cache")
+                cache_layout.refuse(self.state.cache, "prefix_cache", self._bs)
                 self.prefix = PrefixCache(self._bs, prefix_capacity_blocks)
                 self.stats.prefix_enabled = True
             # host mirror of the device block table — authoritative between

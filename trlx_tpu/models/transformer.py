@@ -30,6 +30,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from trlx_tpu.ops.cache_layout import cache_slots
+
+
 def param_with_axes(init: Callable, axes: Tuple[str, ...]) -> Callable:
     """Logical axes of each parameter are derived from its *path* by the rule
     table in ``trlx_tpu/parallel/sharding.py`` (path-based, à la t5x), so the
@@ -211,7 +214,7 @@ QK_INIT_STD_KIMI_LINEAR = 0.08
 class AttentionSizes(NamedTuple):
     """One layer's attention geometry (``TransformerConfig.attention_sizes``),
     the ONE answer every reader takes: ``LatentAttention``, ``make_kv_cache``,
-    ``ops/paged_kv.py::dense_kv_bytes`` and the benchmark's counts. A window
+    ``ops/cache_layout.py`` and the benchmark's counts. A window
     layer of a stack with ``swa_*`` sizes has its own; every other layer the
     model-wide ones (so an ``Indexer``, which only a full layer has, reads the
     model-wide ``qk_rope_head_dim``)."""
@@ -821,7 +824,7 @@ class TransformerConfig:
         train step run a row longer than the window (window layers then keep
         a ring of ``sliding_window`` slots); slot refill, the paged Engine,
         the prefix cache and speculation only while no layer's cache is
-        shorter than the row (``ops/paged_kv.py::refuse_ring_cache``); no
+        shorter than the row (``ops/cache_layout.py::refuse``); no
         ``scan_layers``, no HF checkpoint import. ``qk_init_std`` is this
         preset's stand-in scale for q_proj and k_proj
         (chipbench/configs/smallthinker-21b-a3b-l4e16.json, `assumed`)."""
@@ -862,7 +865,7 @@ class TransformerConfig:
         norm after each sublayer as well as before (``sandwich_norm``). The
         next-token-prediction module is not built. Limits: the plain sampler,
         the scoring forward, the hydra branch and the train step only
-        (``ops/paged_kv.py::refuse_latent_cache``); no ``scan_layers`` (two
+        (``ops/cache_layout.py::refuse``); no ``scan_layers`` (two
         kinds of layer), no ring attention over ``sequence``, no HF checkpoint
         import. ``builtin:pangu-ultra-moe-718b`` | ``builtin:pangu-test``."""
         dims = {
@@ -908,7 +911,7 @@ class TransformerConfig:
         bias``, renormalised, times 2.5) beside one shared expert. The
         next-token-prediction module is not built. Limits: the plain sampler,
         the scoring forward, the hydra branch and the train step only
-        (``ops/paged_kv.py::refuse_latent_cache``); no ``scan_layers`` and no
+        (``ops/cache_layout.py::refuse``); no ``scan_layers`` and no
         pipeline schedule (neither carries a selection from layer to layer),
         no ring attention over ``sequence``, no HF checkpoint import.
         ``builtin:glm-5.2`` | ``builtin:glm-test``."""
@@ -961,7 +964,7 @@ class TransformerConfig:
         self-drafting: a window layer keeps a ring of ``window + 1`` slots),
         the scoring forward, the hydra branch and the train step; no slot
         refill, paged Engine or prefix cache while a layer's cache is shorter
-        than the row (``ops/paged_kv.py::refuse_ring_cache``); no
+        than the row (``ops/cache_layout.py::refuse``); no
         ``scan_layers``, no HF checkpoint import. ``qk_init_std`` is the
         stand-in scale of the scores, which under a per-head norm lives in the
         norms' scales (``_qk_norm``; chipbench/configs/k-exaone-236b-a23b-l5e8.json,
@@ -1019,7 +1022,7 @@ class TransformerConfig:
         rotary follows it (lightning: yes, attention: no), so a cut of the
         depth overrides ``mixer_layout`` alone. Limits: the plain sampler,
         the scoring forward, the hydra branch and the train step
-        (``ops/paged_kv.py::refuse_recurrent_state``); no ``scan_layers``, no
+        (``ops/cache_layout.py::refuse``); no ``scan_layers``, no
         ring attention over ``sequence``, no HF checkpoint import.
         ``qk_init_std`` is the stand-in scale of the scores, in the per-head
         norms' scales, ``embed_init_std`` that of the token embedding, ``1 /
@@ -1076,8 +1079,7 @@ class TransformerConfig:
         2.446) beside one shared expert. ``mixer_layout`` says each layer's
         kind, so a cut of the depth overrides ``num_layers`` alone. Limits:
         the plain sampler, the scoring forward, the hydra branch and the train
-        step (``ops/paged_kv.py::refuse_recurrent_state``,
-        ``refuse_latent_cache``); no ``scan_layers``, no ring attention over
+        step (``ops/cache_layout.py::refuse``); no ``scan_layers``, no ring attention over
         ``sequence``, no HF checkpoint import. ``qk_init_std`` is the stand-in
         scale of the latent layers' ``q_proj``
         (chipbench/configs/kimi-linear-48b-a3b-l8e32.json, `assumed`).
@@ -1135,8 +1137,7 @@ class TransformerConfig:
         eight largest of ``score + bias``, renormalised, times 1) beside one
         shared expert. The vision and audio towers and the next-token module
         are not built. Limits: the plain sampler, the scoring forward, the
-        hydra branch and the train step only (``ops/paged_kv.py::
-        refuse_latent_cache``); no ``scan_layers``, no ring attention over
+        hydra branch and the train step only (``ops/cache_layout.py::refuse``); no ``scan_layers``, no ring attention over
         ``sequence``, no HF checkpoint import. ``q_b_proj`` keeps the program's
         0.02: under the rescale that is already a score of standard deviation 2
         (chipbench/configs/dots3-note-prev-l6e8.json, `assumed`).
@@ -1187,7 +1188,7 @@ class TransformerConfig:
     def falconh1(size: str = "34b", **overrides) -> "TransformerConfig":
         """Falcon-H1: Mamba-2 heads beside attention heads in every block.
         Limits: the plain sampler, the scoring forward and the train step
-        only (``ops/paged_kv.py::refuse_recurrent_state``); no HF checkpoint import."""
+        only (``ops/cache_layout.py::refuse``); no HF checkpoint import."""
         dims = {
             # every multiplier differs from 1, so that a test sees each
             "test": dict(vocab_size=259, hidden_size=64, num_layers=2, num_heads=4, num_kv_heads=2, head_dim=16, intermediate_size=128, max_position_embeddings=128,
@@ -1618,7 +1619,7 @@ class Attention(nn.Module):
 
         paged = cache is not None and isinstance(cache, dict) and "block_table" in cache
         if paged and cfg.sparse_topk:
-            raise NotImplementedError("the paged Engine holds K and V blocks and no compressed keys (ops/paged_kv.py::refuse_recurrent_state)")
+            raise NotImplementedError("the paged Engine holds K and V blocks and no compressed keys (ops/cache_layout.py::refuse)")
         if paged:
             # in-place paged attention (ops/paged_attention.py single-token
             # decode; ops/paged_prefill.py chunked prefill): K/V live in
@@ -2300,7 +2301,7 @@ class LatentAttention(nn.Module):
                 "q_b_proj, kv_a_proj, o_proj"
             )
         if cache is not None and "block_table" in cache:
-            raise NotImplementedError("the paged Engine holds K and V blocks; a latent layer has none (ops/paged_kv.py::refuse_latent_cache)")
+            raise NotImplementedError("the paged Engine holds K and V blocks; a latent layer has none (ops/cache_layout.py::refuse)")
 
         def latent_norm(name):
             return nn.RMSNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
@@ -3500,14 +3501,6 @@ def _cache_is_paged(cache) -> bool:
     return False
 
 
-def cache_slots(layer_cache: Dict[str, jax.Array], stacked: bool = False) -> Optional[int]:
-    """Slots a layer's dense cache holds a row: the length of ``k``, or of a
-    latent layer's ``ckv`` or ``latent`` (behind a leading layer dim where
-    ``stacked``); None for a layer whose whole cache is a recurrent state."""
-    leaf = next((layer_cache[name] for name in ("k", "ckv", "latent") if name in layer_cache), None)
-    return None if leaf is None else leaf.shape[1 + stacked]
-
-
 def _needs_token_mask(cfg: TransformerConfig) -> bool:
     return cfg.num_experts > 0 or cfg.mixer != "none" or cfg.mixer_layout is not None
 
@@ -3813,7 +3806,7 @@ class CausalTransformer(nn.Module):
             window = cfg.layer_layout(i).window
             slots = S
             if dense:  # (a layer with no slots at all, a recurrent state alone, reads the row's plan and none of it)
-                slots = cache_slots(cache if isinstance(cache, dict) else cache[i], isinstance(cache, dict)) or S
+                slots = cache_slots(cache if isinstance(cache, dict) else cache[i]) or S
             if (window, slots) not in plans:
                 if latent_prefill:  # a plan a KIND of latent layer: its window, and whether its cache is a ring
                     plans[window, slots] = self._latent_prefill_plan(key_mask, positions, cache_index, use_flash, window, slots)
@@ -4250,14 +4243,14 @@ def make_kv_cache(
         if layout.mixer == "kda":
             # the layer's whole cache: the delta rule's state (key channels by value channels, float32 as `ssm`
             # is) and the last `kda_conv - 1` rows of [q~ | k~ | v~] before the convs; no K, V or latent
-            # (ops/paged_kv.py::RECURRENT_LEAVES)
+            # (ops/cache_layout.py::VOCABULARY: `conv` beside `state`)
             heads, d = cfg.kda_heads, cfg.kda_head_dim
             return {
                 "state": jnp.zeros(stacked + (batch_size, heads, d, d), jnp.float32),
                 "conv": jnp.zeros(stacked + (batch_size, cfg.kda_conv - 1, 3 * heads * d), dtype),
             }
         if cfg.latent_attention:
-            # the normed latent and the one roped key (ops/paged_kv.py::LATENT_LEAVES), the
+            # the normed latent and the one roped key (ops/cache_layout.py::VOCABULARY), the
             # same slot axis and cache_index as K and V have, and no K or V; side by side
             # in one row a slot on a layer whose steps gather chosen slots. Two layouts
             # for a measured reason, not for anything a layer without an indexer needs:
@@ -4274,13 +4267,13 @@ def make_kv_cache(
                     "k_rope": jnp.zeros(stacked + (batch_size, slots, dr), dtype),
                 }
             if layout.indexer == "full":
-                # the indexer's ONE normed, roped key a slot (ops/paged_kv.py::INDEX_LEAVES),
+                # the indexer's ONE normed, roped key a slot (ops/cache_layout.py::VOCABULARY),
                 # on the layers that select for themselves only
                 latent["k_index"] = jnp.zeros(stacked + (batch_size, slots, cfg.index_head_dim), dtype)
             return latent
         if layout.mixer == "lightning":
             # the layer's whole cache: the recurrence's state, float32 as `ssm` is, and no K or V
-            # (ops/paged_kv.py::RECURRENT_LEAVES)
+            # (ops/cache_layout.py::VOCABULARY)
             heads, d = cfg.lightning_heads, cfg.lightning_head_dim
             return {"state": jnp.zeros(stacked + (batch_size, heads, d, d), jnp.float32)}
         shapes = {
@@ -4289,7 +4282,7 @@ def make_kv_cache(
         }
         if cfg.sparse_topk:
             # the keys' running mean-pool (`pooled_keys`), by KV head: kernel j is complete once the
-            # row's token `sparse_stride * j + sparse_kernel - 1` is in (ops/paged_kv.py::POOLED_LEAVES)
+            # row's token `sparse_stride * j + sparse_kernel - 1` is in (ops/cache_layout.py::VOCABULARY)
             shapes["kbar"] = ((batch_size, cfg.kv_heads, max_length // cfg.sparse_stride, cfg.dims_per_head), dtype)
         if cfg.mixer == "mamba2":
             shapes["ssm"] = ((batch_size, cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_state), jnp.float32)

@@ -62,16 +62,6 @@ __all__ = [
     "detach_block_table",
     "kv_bytes",
     "block_bytes",
-    "dense_kv_bytes",
-    "recurrent_state_bytes",
-    "refuse_recurrent_state",
-    "pooled_key_bytes",
-    "linear_state_bytes",
-    "refuse_ring_cache",
-    "latent_cache_bytes",
-    "latent_ring_bytes",
-    "index_cache_bytes",
-    "refuse_latent_cache",
 ]
 
 # Physical block 0 is reserved as the permanent all-zeros block: fresh table
@@ -119,7 +109,7 @@ def init_paged_kv(
 
 def _scanned(leaf: jax.Array) -> bool:
     # pool/cache leaves: [NB, bs, kvH, D] per layer, or [L, NB, bs, kvH, D]
-    # when cfg.scan_layers stacked the layer axis in front
+    # when a scanned stack put the layer axis in front
     return leaf.ndim - 4 == 1
 
 
@@ -275,172 +265,6 @@ def kv_bytes(cache: Any) -> int:
     )
 
 
-# what a `mixer: mamba2` layer keeps a sequence beside K and V
-# (models/transformer.py::make_kv_cache): the state and the conv's last rows;
-# and what a `lightning` layer (`mixer_layout`) keeps IN PLACE of K and V: its state
-# (a `kda` layer: its state and its convs' last rows, `conv` again)
-RECURRENT_LEAVES = ("ssm", "conv", "state")
-
-# what an attention layer under a block selection (`sparse_topk`) keeps beside
-# K and V: the keys' running mean-pool its decode steps score
-POOLED_LEAVES = ("kbar",)
-
-
-def linear_state_bytes(cache: Any) -> int:
-    """Bytes of the layers whose whole cache is a linear recurrence's: a
-    ``state`` leaf (a lightning layer's) and the ``conv`` rows beside one (a
-    KDA layer's; a ``mixer: mamba2`` layer's ``conv`` lies beside ``ssm``, K
-    and V and is not counted here)."""
-    layers = cache if isinstance(cache, (list, tuple)) else [cache]
-    return sum(_named_leaf_bytes(layer, ("state", "conv")) for layer in layers if isinstance(layer, dict) and "state" in layer)
-
-
-def pooled_key_bytes(cache: Any) -> int:
-    """Bytes of a cache pytree's compressed keys (``kbar``), by leaf name."""
-    return _named_leaf_bytes(cache, POOLED_LEAVES)
-
-
-def recurrent_state_bytes(cache: Any) -> int:
-    """Bytes of a cache pytree's recurrent leaves, by leaf name (0 for a
-    KV-only model); ``kv_bytes`` less this is what K and V hold."""
-    return _named_leaf_bytes(cache, RECURRENT_LEAVES)
-
-
-def _named_leaf_bytes(cache: Any, names: Tuple[str, ...], keep=lambda leaf: True) -> int:
-    return int(
-        sum(
-            int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
-            for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]
-            if getattr(path[-1], "key", None) in names and keep(leaf)
-        )
-    )
-
-
-# rollout paths that keep K and V and nothing else (ROADMAP.md queue 2, B7)
-_KV_ONLY_PATHS = {
-    "slot_refill": "ops/slot_refill.py::SlotState (train.continuous_batching) holds K and V a slot and would refill a slot over another row's recurrent state",
-    "engine": "the engine/ slots (paged cache) hold K and V blocks and no recurrent state",
-    "prefix_cache": "the engine's prefix cache shares K and V blocks; a recurrent state has no snapshot at a block boundary to share",
-    "speculative": "ops/speculative.py rewinds K and V to the accepted length and cannot rewind a recurrent state",
-}
-
-
-def refuse_recurrent_state(cache: Any, path: str) -> None:
-    """Called where each KV-only rollout path builds its state, on the cache
-    pytree (arrays or shapes) it was given: a model whose layers hold
-    recurrent state stops there by name rather than drop it silently."""
-    if recurrent_state_bytes(cache):
-        raise NotImplementedError(
-            f"{path} does not support a model whose cache holds recurrent state "
-            f"(leaves {RECURRENT_LEAVES}: `mixer: mamba2`, the falcon_h1 family; a `lightning` "
-            f"layer of `mixer_layout`, the minicpm_sala family; a `kda` layer, the kimi_linear family): "
-            f"{_KV_ONLY_PATHS[path]}; use the plain sampler (ROADMAP.md queue 2, B7)"
-        )
-    if pooled_key_bytes(cache):
-        raise NotImplementedError(
-            f"{path} does not support a model whose attention runs under a block selection "
-            f"(leaf {POOLED_LEAVES[0]}: `sparse_topk`, the minicpm_sala family): it holds K and V a slot and "
-            "no compressed keys, which fill by each row's own position; use the plain sampler (ROADMAP.md queue 2, B8)"
-        )
-
-
-# what a latent-attention layer keeps a slot IN PLACE of K and V
-# (models/transformer.py::make_kv_cache): the normed latent that keys and
-# values are made from, and the one roped key all heads share; on a layer
-# under a learned selection both in ONE leaf `latent [B, S, kv_lora_rank +
-# qk_rope_head_dim]`, the latent's columns first (its steps gather chosen
-# slots, a row a slot)
-LATENT_LEAVES = ("ckv", "k_rope", "latent")
-
-
-# what rides with the latent under a learned selection: the ONE index key a
-# slot of each layer that selects for itself (`indexer_types` "full")
-INDEX_LEAVES = ("k_index",)
-
-
-def latent_cache_bytes(cache: Any) -> int:
-    """Bytes of a cache pytree's latent leaves, by leaf name (0 for a model
-    whose layers hold K and V). The index keys that ride with a latent under
-    a learned selection are counted apart (``index_cache_bytes``)."""
-    return _named_leaf_bytes(cache, LATENT_LEAVES)
-
-
-def latent_ring_bytes(cache: Any, slots: int) -> int:
-    """Bytes of the latent leaves that hold fewer than the row's ``slots``: a
-    window layer's ring of latents (``models/transformer.py::make_kv_cache``,
-    a latent stack with a sliding window), which ``latent_cache_bytes`` counts
-    too; 0 where no latent layer has a window below the row's length."""
-    return _named_leaf_bytes(cache, LATENT_LEAVES, lambda leaf: leaf.shape[-2] < slots)
-
-
-def index_cache_bytes(cache: Any) -> int:
-    """Bytes of a cache pytree's index-key leaves, by leaf name (0 for a
-    model without a learned selection of keys). They exist only beside a
-    latent, so whatever refuses a latent cache refuses them with it."""
-    return _named_leaf_bytes(cache, INDEX_LEAVES)
-
-
-# what each KV-only path would do with per-head K and V that a latent layer
-# does not have (ROADMAP.md queue 2, B4)
-_PER_HEAD_KV_PATHS = {
-    "slot_refill": "ops/slot_refill.py::SlotState refills a slot at its own depth, a [B] vector of cache indices, and its span prefill attends over the cache's per-head K and V",
-    "engine": "the engine/ block pool and its paged kernels (ops/paged_attention.py, ops/paged_prefill.py) hold and read per-head K and V blocks",
-    "prefix_cache": "the engine's prefix cache shares per-head K and V blocks",
-    "speculative": "ops/speculative.py verifies and rewinds rows at their own accepted lengths, a [B] vector of cache indices, over per-head K and V",
-}
-
-
-def refuse_latent_cache(cache: Any, path: str) -> None:
-    """Called where each KV-only rollout path builds its state, beside
-    ``refuse_recurrent_state``: a model whose layers cache a latent (no ``k``,
-    no ``v``) stops there by name, and with it the index keys of a learned
-    selection, which ride on the same slots and which none of these paths
-    scores, selects from or moves."""
-    if latent_cache_bytes(cache):
-        riding = ""
-        if index_cache_bytes(cache):
-            riding = (
-                f", and index keys with it (leaves {INDEX_LEAVES}: a learned selection of keys, "
-                "`index_topk` > 0, the glm_moe_dsa family; ROADMAP.md queue 2, B8)"
-            )
-        raise NotImplementedError(
-            f"{path} does not support a model whose cache holds a latent in place of K and V "
-            f"(leaves {LATENT_LEAVES}: latent attention, `kv_lora_rank` > 0, a window layer's ring of latents too; "
-            f"the pangu_ultra_moe, glm_moe_dsa, kimi_linear and dots3_note families){riding}: "
-            f"{_PER_HEAD_KV_PATHS[path]}; use the plain sampler "
-            "(ROADMAP.md queue 2, B4)"
-        )
-
-
-# rollout paths that write a layer's cache at any slot of the row, a row at a
-# time (ROADMAP.md queue 2, B3)
-_WHOLE_ROW_PATHS = {
-    "slot_refill": "ops/slot_refill.py refills one slot's row at its own depth, a [B] vector of cache indices",
-    "engine": "the engine/ block tables map every slot of a row to a block and the allocator frees none before the row ends",
-    "prefix_cache": "the engine's prefix cache shares a prompt's blocks from slot 0, which a ring has overwritten",
-}
-
-
-def refuse_ring_cache(cache: Any, slots: int, path: str) -> None:
-    """Called where each of those paths builds its state, on the cache pytree
-    (arrays or shapes) its ``init_cache_fn`` gives for a row of ``slots``: a
-    window layer keeps ``min(slots, window)`` slots
-    (``models/transformer.py::make_kv_cache``), and where that is fewer than
-    the row's, the cache is a ring only the plain sampler and speculation's
-    verify write (``CausalTransformer._ring_plan``). A model of
-    mixed layouts runs through these paths while no layer's cache is shorter
-    than the row (each layer's bias carries its own window); past that they
-    stop here by name rather than write a ring as if it were the row."""
-    for path_, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
-        if getattr(path_[-1], "key", None) == "k" and leaf.shape[-3] < slots:
-            raise NotImplementedError(
-                f"{path} does not support a layer whose cache is shorter than the row "
-                f"(a window layer's ring of {leaf.shape[-3]} slots for a row of {slots}: "
-                f"sliding_window below the row's length): {_WHOLE_ROW_PATHS[path]}; use the "
-                "plain sampler, or rows no longer than the window (ROADMAP.md queue 2, B3)"
-            )
-
-
 def block_bytes(cache: Any) -> int:
     """Bytes of ONE block across all layers/k/v — multiply by
     blocks-in-use for the live-token-scaled high-water number."""
@@ -451,23 +275,3 @@ def block_bytes(cache: Any) -> int:
         nb = leaf.shape[-4]
         total += int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize // nb
     return int(total)
-
-
-def dense_kv_bytes(cfg: Any, batch_size: int, slots: int) -> int:
-    """Analytic dense-cache bytes for a model config — the serial sampler
-    allocates its cache inside the jitted program, so the gauge is computed
-    rather than measured (exact: shapes are static)."""
-    itemsize = np.dtype(cfg.dtype).itemsize
-    if getattr(cfg, "latent_attention", False):
-        # each layer's latent and one roped key at its own sizes over its own slots (a window layer's ring), and a
-        # selecting layer's index key
-        numbers = 0
-        for i, layout in enumerate(cfg.layer_layouts):
-            sizes = cfg.attention_sizes(i)
-            own = min(slots, layout.window) if layout.window else slots
-            numbers += own * (sizes.kv_lora_rank + sizes.rope) + (slots * cfg.index_head_dim if layout.indexer == "full" else 0)
-        return int(batch_size * numbers * itemsize)
-    return int(
-        2 * cfg.num_layers * batch_size * slots * cfg.kv_heads
-        * cfg.dims_per_head * itemsize
-    )

@@ -63,6 +63,7 @@ from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from trlx_tpu.ops import cache_layout
 from trlx_tpu.ops.paged_kv import (
     PagedKV,
     PagedSpec,
@@ -70,9 +71,6 @@ from trlx_tpu.ops.paged_kv import (
     detach_block_table,
     gather_view,
     init_paged_kv,
-    refuse_latent_cache,
-    refuse_recurrent_state,
-    refuse_ring_cache,
     scatter_span,
     scatter_steps,
 )
@@ -290,11 +288,6 @@ def make_slot_refill_fns(
             "(ops/paged_prefill.py) — it requires the paged KV backend "
             "(engine.backend: paged)"
         )
-    for refuse in (refuse_recurrent_state, refuse_latent_cache):
-        refuse(
-            jax.eval_shape(lambda: init_cache_fn(1, 1)),
-            "slot_refill" if paged is None else "engine",
-        )
     G = int(speculative or 0)
     if G < 0:
         raise ValueError(f"speculative must be >= 0, got {G}")
@@ -328,10 +321,7 @@ def make_slot_refill_fns(
     # key-width lowering note) — G = 0 reduces to the plain S = P + N
     S = P + N + G
     NB = N + G + 1  # spec token buffers: block writes never clip
-    refuse_ring_cache(
-        jax.eval_shape(lambda: init_cache_fn(1, S)), S,
-        "slot_refill" if paged is None else "engine",
-    )
+    cache_layout.refuse(jax.eval_shape(lambda: init_cache_fn(1, S)), "slot_refill" if paged is None else "engine", S)
 
     def empty_state() -> SlotState:
         # step_out structure comes from an abstract prefill — shapes only

@@ -54,9 +54,9 @@ Exactness properties (tested in ``tests/test_speculative.py``):
   draw site (draft proposals, acceptance uniforms, residual/bonus), so a
   batched run is BIT-IDENTICAL per row to running each row alone with its
   chain — batch composition invariance, the property continuous batching
-  needs to host a speculative slot (ROADMAP item 2's named blocker,
-  removed). The per-row sampled streams differ from the batch-wide mode's
-  by construction; both are exact draws from the target distribution.
+  needs to host a speculative slot. The per-row sampled streams differ
+  from the batch-wide mode's by construction; both are exact draws from the
+  target distribution.
 
 Transition logit masks (the trainer's ``logit_mask``, e.g. randomwalks'
 allowed-moves table) compose natively: the mask is applied to the draft AND
@@ -75,7 +75,7 @@ from typing import Any, Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from trlx_tpu.ops.paged_kv import refuse_latent_cache, refuse_recurrent_state
+from trlx_tpu.ops import cache_layout
 from trlx_tpu.ops.sampling import (
     _NON_CARRY_KEYS,
     GenerationConfig,
@@ -576,9 +576,9 @@ def generate_speculative(
     B, P = input_ids.shape
     per_row = bool(config.per_row_rng)
     if per_row:
-        # Per-row key chains (the continuous-batching composition seam,
-        # ROADMAP item 2): every rng consumer below — each round's G draft
-        # proposals, the acceptance uniforms, the residual/bonus draw —
+        # Per-row key chains (the continuous-batching composition seam):
+        # every rng consumer below — each round's G draft proposals, the
+        # acceptance uniforms, the residual/bonus draw —
         # advances a [B, 2] per-row chain by a FIXED number of
         # split_row_keys steps per round, so a row's sample stream depends
         # only on (its chain start, its round index), never on batch
@@ -602,9 +602,7 @@ def generate_speculative(
         drafter = model_drafter(draft_apply, G)
     t_cache = init_target_cache(B, S)
     d_cache = init_draft_cache(B, S)
-    refuse_recurrent_state((t_cache, d_cache), "speculative")
-    refuse_latent_cache((t_cache, d_cache), "speculative")
-    # (a window layer's ring takes each row's span at its own index: _ring_plan)
+    cache_layout.refuse((t_cache, d_cache), "speculative", S)  # (a window layer's ring takes each row's span at its own index: _ring_plan)
 
     # ---- prefill both caches over the prompt block ----
     slot0 = jnp.concatenate([prompt_mask, jnp.zeros((B, NB - 1), jnp.int32)], axis=1)

@@ -41,8 +41,10 @@ from trlx_tpu.models.builder import (
     trainable_mask,
 )
 from trlx_tpu.models.transformer import (
-    block_selected_pairs, block_selected_steps, cache_slots, make_kv_cache, selected_frac, sparse_gather_rows,
+    block_selected_pairs, block_selected_steps, make_kv_cache, selected_frac, sparse_gather_rows,
 )
+from trlx_tpu.ops.cache_layout import INDEX, KV, LATENT, LINEAR, POOLED, RECURRENT, cache_bytes, cache_slots, ring
+from trlx_tpu.ops.paged_kv import kv_bytes
 from trlx_tpu.ops.sampling import (
     GenerationConfig,
     GenerationOutput,
@@ -1613,105 +1615,53 @@ class TPUBaseTrainer(BaseRLTrainer):
         return engine
 
     def _note_dense_kv_gauge(self, prompt_shape, gen_config) -> None:
-        """``memory/kv_cache_bytes`` for the serial dense path: the cache
-        is allocated inside the jitted program, so the gauge is computed
-        from the static shapes of that pytree (exact). The same walk gives
-        the collection record its two counters, the two kinds of
-        per-sequence state side by side by leaf name:
-        ``rollout/kv_cache_bytes`` (``k``, ``v``) and
-        ``rollout/ssm_state_bytes`` (``ssm``, ``conv``; 0 for a KV-only
-        model), ``rollout/linear_state_bytes`` where layers hold a linear
-        recurrence's ``state`` as their whole cache, ``rollout/kbar_cache_bytes``
-        and ``rollout/attn_block_selected_frac`` where attention runs under a
-        block selection (the compressed keys; chosen blocks over causal
-        blocks, mean over the decode steps of an unpadded row), and where the layers cache a latent in place of K and V
-        ``rollout/latent_cache_bytes`` (``ckv``, ``k_rope``, or the two in
-        one leaf ``latent`` on a layer under a selection; K and V then 0;
-        a window layer's ring of latents apart, ``rollout/latent_ring_bytes``)
-        with ``rollout/index_cache_bytes`` beside it (``k_index``, the index
-        keys of the layers that select for themselves; 0 for a model without
-        a learned selection) and ``rollout/sparse_gather_rows`` (rows of the
-        cache a decode step gathers under that selection, a row of the batch,
-        summed over the layers); where the stack mixes attention layouts, K and V are also
-        split into ``rollout/kv_cache_window_bytes`` (the window layers'
-        rings) and ``rollout/kv_cache_global_bytes``. The
-        continuous-batching engines report their own measured gauge
-        (EngineStats.metrics)."""
+        """``memory/kv_cache_bytes`` for the serial dense path and the collection record's ``rollout/*_bytes`` counters:
+        the sampler allocates its cache inside the jitted program, so both are computed from the static shapes of that
+        pytree (exact), by kind of leaf (``ops/cache_layout.py::cache_bytes``; this method maps its kinds to the record's
+        keys, docs/OBSERVABILITY.md). The continuous-batching engines report their own measured gauge (EngineStats.metrics)."""
         self.last_kv_extents = self.last_kv_layers = None
         if self.is_seq2seq:
             return  # T5 cross/self caches have their own layout; not gauged
-        from trlx_tpu.ops.paged_kv import (
-            index_cache_bytes, kv_bytes, latent_cache_bytes, latent_ring_bytes, linear_state_bytes, pooled_key_bytes,
-            recurrent_state_bytes,
-        )
-
         B, P = prompt_shape
         S = P + gen_config.max_new_tokens
         drafts = self.draft_module is not None or self.self_drafts
-        if not drafts:
-            self.last_kv_extents = kv_extents(P, gen_config.max_new_tokens)
+        self.last_kv_extents = None if drafts else kv_extents(P, gen_config.max_new_tokens)
 
-        def cache(tcfg, slots):
-            # one trace a shape: a walk at every call would be a retrace on
-            # every collection record (runtime/retrace_s), the device waiting
+        def cache(tcfg, slots):  # one trace a shape: a walk at every call would be a retrace on every collection record
             key = (tcfg, B, slots)
             if key not in self._kv_cache_shapes:
-
-                def kv_cache_shapes():  # named for the records
+                def kv_cache_shapes():  # named for the records (runtime/retrace_s)
                     return make_kv_cache(tcfg, B, slots)
-
                 self._kv_cache_shapes[key] = jax.eval_shape(kv_cache_shapes)
             return self._kv_cache_shapes[key]
 
         policy_cache = cache(self.tcfg, S)
-        state = recurrent_state_bytes(policy_cache)
-        latent = latent_cache_bytes(policy_cache)
-        index = index_cache_bytes(policy_cache)
-        linear = linear_state_bytes(policy_cache)
-        pooled = pooled_key_bytes(policy_cache)
-        total = kv_bytes(policy_cache) - state
-        self.last_cache_stats = {
-            "rollout/kv_cache_bytes": float(total - latent - index - pooled),
-            "rollout/ssm_state_bytes": float(state - linear),
-        }
-        if linear:
-            self.last_cache_stats["rollout/linear_state_bytes"] = float(linear)
-        if pooled:  # attention layers under a block selection: the compressed keys, and the blocks a step keeps
-            self.last_cache_stats["rollout/kbar_cache_bytes"] = float(pooled)
-            self.last_cache_stats["rollout/attn_block_selected_frac"] = block_selected_steps(P, gen_config.max_new_tokens, self.tcfg)
-        if latent:  # the layers cache a latent in place of K and V, and index keys with it
-            ring = latent_ring_bytes(policy_cache, S)
-            self.last_cache_stats["rollout/latent_cache_bytes"] = float(latent - ring)
-            self.last_cache_stats["rollout/index_cache_bytes"] = float(index)
-            if ring:  # window layers' rings of latents, counted apart from the full layers' caches
-                self.last_cache_stats["rollout/latent_ring_bytes"] = float(ring)
-        if getattr(self.tcfg, "index_topk", 0) and not drafts:
-            # rows of the cache a decode step gathers a row of the batch, all layers: static, as the extents are
-            self.last_cache_stats["rollout/sparse_gather_rows"] = float(sparse_gather_rows(self.tcfg, S))
+        held = cache_bytes(policy_cache, S)
+        kv, latent = held[KV] + held[ring(KV)], held[LATENT] + held[ring(LATENT)]
+        total = sum(held.values()) - held[RECURRENT] - held[LINEAR]
+        stats = self.last_cache_stats = {"rollout/kv_cache_bytes": float(kv), "rollout/ssm_state_bytes": float(held[RECURRENT])}
+        if latent:  # the layers cache a latent in place of K and V (a window layer's ring apart), and index keys with it
+            stats.update({"rollout/latent_cache_bytes": float(held[LATENT]), "rollout/index_cache_bytes": float(held[INDEX] + held[ring(INDEX)])})
+        for kind, key in ((LINEAR, "rollout/linear_state_bytes"), (POOLED, "rollout/kbar_cache_bytes"), (ring(LATENT), "rollout/latent_ring_bytes")):
+            if held[kind]:
+                stats[key] = float(held[kind])
+        if held[POOLED]:  # attention layers under a block selection: the blocks a step keeps
+            stats["rollout/attn_block_selected_frac"] = block_selected_steps(P, gen_config.max_new_tokens, self.tcfg)
+        if getattr(self.tcfg, "index_topk", 0) and not drafts:  # rows of the cache a decode step gathers a row of the batch, all layers
+            stats["rollout/sparse_gather_rows"] = float(sparse_gather_rows(self.tcfg, S))
         if not self.tcfg.scan_layers:
-            layouts = self.tcfg.layer_layouts
-            self.last_kv_layers = tuple(  # (a lightning layer has no slots to read)
-                (int(cache_slots(layer)), layout.window is not None)
-                for layer, layout in zip(policy_cache, layouts) if cache_slots(layer)
-            )
-            if len({layout.window for layout in layouts}) > 1 and not latent:  # window layers beside global ones, K and V
-                window = sum(
-                    kv_bytes({"k": layer["k"], "v": layer["v"]})
-                    for layer, layout in zip(policy_cache, layouts) if layout.window is not None and "k" in layer
-                )
-                self.last_cache_stats["rollout/kv_cache_window_bytes"] = float(window)
-                self.last_cache_stats["rollout/kv_cache_global_bytes"] = float(total - window)
+            layers = list(zip(policy_cache, self.tcfg.layer_layouts))  # (a layer whose whole cache is a state has no slots to read)
+            self.last_kv_layers = tuple((int(cache_slots(layer)), layout.window is not None) for layer, layout in layers if cache_slots(layer))
+            if len({layout.window for _, layout in layers}) > 1 and not latent:  # window layers beside global ones, K and V
+                window = cache_bytes([layer for layer, layout in layers if layout.window is not None], S)
+                stats["rollout/kv_cache_window_bytes"] = float(window[KV] + window[ring(KV)])
+                stats["rollout/kv_cache_global_bytes"] = float(total - window[KV] - window[ring(KV)])
         if drafts:
-            # speculative decoding: target + draft caches, both S + gamma
-            # slots (ops/speculative.py sizes them P + N + G); a model's own
-            # module has its layer in the model's own cache list
-            S_spec = S + self.draft_gamma
-            spec_cache = cache(self.tcfg, S_spec)
-            total = kv_bytes(spec_cache)
+            # target + draft caches, both S + gamma slots (ops/speculative.py); a model's own module has its layer in the model's own list
+            spec_cache = cache(self.tcfg, S + self.draft_gamma)
+            total = kv_bytes(spec_cache) + (0 if self.self_drafts else kv_bytes(cache(self.draft_tcfg, S + self.draft_gamma)))
             if self.self_drafts:
-                self.last_cache_stats["rollout/mtp_cache_bytes"] = float(kv_bytes(spec_cache[self.tcfg.num_layers :]))
-            else:
-                total += kv_bytes(cache(self.draft_tcfg, S_spec))
+                stats["rollout/mtp_cache_bytes"] = float(kv_bytes(spec_cache[self.tcfg.num_layers :]))
         self.obs.metrics.set_gauge("memory/kv_cache_bytes", float(total))
 
     def generate_eval(self, input_ids, attention_mask=None, **kwargs) -> GenerationOutput:
