@@ -669,6 +669,51 @@ def test_dropless_experts_are_grouped_kernels_at_olmoe_widths(topo, monkeypatch,
     assert 3 * matmul * (3 if grad else 1) <= flops < 1.5 * 3 * matmul * (3 if grad else 1)
 
 
+@pytest.mark.parametrize("frozen", [True, False], ids=["frozen_kernels", "trained_kernels"])
+def test_a_held_share_is_one_window_body_a_direction_and_a_frozen_kernel_has_no_gradient(topo, monkeypatch, frozen):
+    """One OLMoE-width expert layer that holds 8 of its 64 experts, 4 x 1024
+    tokens forward and backward: 32768 assignments in windows of 8192 sorted
+    rows (``held_row_bound``). The compiled value and gradient have two ``while`` loops
+    and no ``conditional`` (one body a direction: the code the chip holds),
+    every grouped matmul over rows has 8192 of them, and where the kernels
+    reach the layer through ``stop_gradient`` (the train step's frozen-leaf
+    rule) no grouped matmul has a ``[held, d, f]`` result: their gradient is
+    only ever added to itself in the backward loop's carry, and THIS compiler
+    takes the carry and the kernel that feeds it out. With the kernels
+    trained the same walk finds the three."""
+    import re
+
+    from trlx_tpu.models.transformer import MoEMLP, TransformerConfig, held_row_bound
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = TransformerConfig.olmoe("1b-7b", dtype=DT, param_dtype=DT, moe_experts_held=8, moe_first_expert=8)
+    layer = MoEMLP(cfg)
+    tokens, d, f = (4, 1024), cfg.hidden_size, cfg.intermediate_size
+    bound = held_row_bound(tokens[0] * tokens[1] * cfg.num_experts_per_tok, 8, cfg.num_experts)
+    assert bound == 8192
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    place = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    params = place(jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, d), DT)))["params"])
+    assert params["w_up"].shape == (8, d, f)
+
+    def loss(p, x):
+        if frozen:
+            p = {k: v if k == "router" else jax.lax.stop_gradient(v) for k, v in p.items()}
+        y, aux = layer.apply({"params": p}, x)
+        return jnp.sum(y.astype(jnp.float32)) + aux[0]
+
+    with jax.default_matmul_precision("highest"):
+        text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(params, place(_s(tokens + (d,)))).compile().as_text()
+    assert (len(re.findall(r" while\(", text)), len(re.findall(r" conditional\(", text))) == (2, 0)
+    results = re.findall(r"%ragged-dot-(?!metadata)[\w.-]* = bf16\[([0-9,]+)\]", text)
+    over_rows = [r for r in results if r in (f"{bound},{f}", f"{bound},{d}")]
+    of_kernels = [r for r in results if r in (f"8,{d},{f}", f"8,{f},{d}")]
+    # gate, up, down forward; again where the backward differentiates the window; their rows' gradients
+    assert len(over_rows) == 9 and len(over_rows) + len(of_kernels) == len(results), results
+    assert len(of_kernels) == (0 if frozen else 3), results
+
+
 # ---------------------------------------------------------------------------
 # the PPO learner's ladder of widths: one train-step program per rung
 # ---------------------------------------------------------------------------

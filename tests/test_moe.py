@@ -482,9 +482,11 @@ _DROPLESS_LAYERS = {
 _DROPLESS_K = 3
 
 
-def _dropless_layer_loss(case, dtype, permute, monkeypatch):
+def _dropless_layer_loss(case, dtype, permute, monkeypatch, count_calls=True):
     """``(loss(params, x), params, x)`` of a dropless ``MoEMLP`` that moves
-    its rows with ``permute`` (None: the layer's own ``permute_rows``)."""
+    its rows with ``permute`` (None: the layer's own ``permute_rows``).
+    ``count_calls=False`` leaves the two call counters at the end of a held
+    share's ``aux`` out of the loss: they say whether the layer had a bound."""
     from trlx_tpu.models import transformer
 
     cfg = _cfg(
@@ -513,6 +515,8 @@ def _dropless_layer_loss(case, dtype, permute, monkeypatch):
         if case == "checkpoint":
             apply = jax.checkpoint(apply)
         y, aux = apply(p, x)
+        if not count_calls:
+            aux = aux[:8]
         return jnp.sum(y.astype(jnp.float32) * target) + 0.01 * jnp.sum(aux)
 
     return loss, params, x
@@ -752,8 +756,9 @@ def test_a_layer_that_holds_every_expert_traces_the_program_it_had(which, monkey
 
 def test_held_row_bound_is_twice_the_even_share_up_to_a_tile():
     """The arithmetic of the cells: 8 of 256 experts at a 4096-token piece,
-    at train steps of 8192 and 5120 tokens, 8 of 128; no bound where it would
-    not cut the rows by eight (32 of 256, 16 of 64, every expert), nor for a
+    at train steps of 8192 and 5120 tokens, 8 of 128, 32 of 256 (a quarter
+    of the rows), 16 of 64 (half); no bound where twice the even share
+    reaches the rows (half the experts or more, every expert), nor for a
     decode step's rows or the toy layers of this file."""
     from trlx_tpu.models.transformer import held_row_bound
 
@@ -762,10 +767,249 @@ def test_held_row_bound_is_twice_the_even_share_up_to_a_tile():
     assert held_row_bound(5120 * 8, 8, 256) == 2560
     assert held_row_bound(4096 * 8, 8, 128) == 4096
     assert held_row_bound(512 * 8, 8, 256) == 256  # the shortest call with a bound
-    assert held_row_bound(4096 * 8, 32, 256) == 4096 * 8 and held_row_bound(8192 * 6, 16, 64) == 8192 * 6
+    assert held_row_bound(4096 * 8, 32, 256) == 8192 and held_row_bound(16384 * 8, 32, 256) == 32768
+    assert held_row_bound(8192 * 6, 16, 64) == 24576 and held_row_bound(16384 * 6, 16, 64) == 49152
     assert held_row_bound(64 * 8, 8, 256) == 512 and held_row_bound(128 * 8, 8, 128) == 1024
     assert held_row_bound(8192 * 8, 64, 64) == 8192 * 8
+    assert held_row_bound(8192 * 8, 32, 64) == 8192 * 8 and held_row_bound(8192 * 8, 48, 64) == 8192 * 8
     assert held_row_bound(36 * 3, 3, 8) == 108
+
+
+# --- a larger share of the experts: ONE body, window after window ------------
+#
+# Where the bound cuts the rows by less than ``MOE_HELD_MIN_CUT`` the layer has
+# one body, the experts on a window of ``bound`` sorted rows, in a ``while``
+# over the windows that hold a live row (``transformer.held_rows``), forward
+# and backward; each assignment reads its row after the loop, once. What it is
+# held to is the same layer with no bound (``held_row_bound`` patched to
+# ``rows``: every sorted row through ``_all_rows``). ``_windows`` gives the toy
+# layers this form whatever their share.
+
+
+def _windows(monkeypatch, tile=8, factor=2):
+    """The bound's arithmetic at the toy layers' size, and the one-body form
+    for every share: a row tile of ``tile``, no shortest call, ``factor``
+    times the even share, no cut large enough for two bodies."""
+    from trlx_tpu.models import transformer
+    from trlx_tpu.ops import grouped_matmul
+
+    monkeypatch.setattr(grouped_matmul, "ROW_TILE", tile)
+    monkeypatch.setattr(transformer, "MOE_HELD_MIN_ROWS", 0)
+    monkeypatch.setattr(transformer, "MOE_HELD_ROWS_FACTOR", factor)
+    monkeypatch.setattr(transformer, "MOE_HELD_MIN_CUT", 10**9)
+
+
+def _no_bound(monkeypatch):
+    """Every sorted row through ``_all_rows``: the layer before it had a bound."""
+    from trlx_tpu.models import transformer
+
+    monkeypatch.setattr(transformer, "held_row_bound", lambda rows, held, experts: rows)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("case", _HELD_SHARE_LAYERS)
+def test_windowed_rows_equal_all_rows_to_the_bit(case, dtype, monkeypatch):
+    """A call whose held rows fit one window against the same layer with no
+    bound: the same rows go through the same grouped matmuls, the rows past
+    the window are the zeros ``_all_rows``' select writes, a token's ``K``
+    results are summed by the same einsum and added to zeros once, so the
+    value and the gradients with respect to ``x``, the router and the three
+    expert kernels are EQUAL, not close."""
+    _windows(monkeypatch)
+    loss, params, x = _dropless_layer_loss(case, dtype, None, monkeypatch, count_calls=False)
+    counted = jax.jit(lambda p, x: MoEMLP(_cfg(
+        num_experts=8, num_experts_per_tok=_DROPLESS_K, moe_capacity_factor=0.0, dtype=dtype,
+        param_dtype=dtype, **_DROPLESS_LAYERS[case])).apply({"params": p}, x)[1])(params, x)
+    assert float(counted[8]) == float(counted[9]) > 0  # every call had a bound and fitted one window
+    value, grads = jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(params, x)
+    _no_bound(monkeypatch)
+    loss, params, x = _dropless_layer_loss(case, dtype, None, monkeypatch, count_calls=False)
+    want_value, want = jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(params, x)
+    np.testing.assert_array_equal(np.asarray(value), np.asarray(want_value))
+    flat = jax.tree_util.tree_flatten_with_path(grads)[0]
+    assert len(flat) == 5  # the router, the three expert kernels, x
+    for (path, g), q in zip(flat, jax.tree_util.tree_leaves(want)):
+        g, q = np.asarray(g.astype(jnp.float32)), np.asarray(q.astype(jnp.float32))
+        assert np.isfinite(g).all() and np.any(g != 0), jax.tree_util.keystr(path)
+        np.testing.assert_array_equal(g, q, err_msg=jax.tree_util.keystr(path))
+
+
+def _biased_to_the_held_windows(real_tokens, monkeypatch, tile, factor=2, dtype=jnp.float32, bias=100.0):
+    """A layer whose router bias sends all three choices of every token to
+    its three held experts (``bias`` -100: none of them): ``3 x real_tokens``
+    held rows against a bound of ``round_up(factor * ceil(108 * 3 / 8),
+    tile)``."""
+    _windows(monkeypatch, tile, factor)
+    cfg = _cfg(num_experts=8, num_experts_per_tok=3, moe_capacity_factor=0.0, moe_experts_held=3,
+               moe_first_expert=4, moe_topk_method="noaux_tc", moe_scoring="sigmoid",
+               dtype=dtype, param_dtype=dtype)
+    rs = np.random.RandomState(5)
+    x = jnp.asarray(rs.randn(3, 12, cfg.hidden_size), dtype)
+    mask = (jnp.arange(36) < real_tokens).astype(jnp.int32).reshape(3, 12)
+    layer = MoEMLP(cfg)
+    params = dict(layer.init(jax.random.PRNGKey(1), x)["params"])
+    params["router_bias"] = jnp.where((jnp.arange(8) >= 4) & (jnp.arange(8) < 7), bias, 0.0).astype(dtype)
+    return layer, params, x, mask
+
+
+@pytest.mark.parametrize("real_tokens,compact", [(28, 1.0), (29, 0.0), (36, 0.0)],
+                         ids=["held_rows_equal_the_bound", "one_token_over", "every_row_held"])
+def test_a_call_over_the_bound_runs_two_windows_and_says_so(real_tokens, compact, monkeypatch):
+    """``L <= C`` runs one window, ``L > C`` a second (84 rows, then the 3
+    or 24 left of a sort whose 108 rows the bound does not divide), on the
+    traced count; either way the result is the one a layer without a bound
+    gives (``held_row_bound`` at ``tokens x K``): a window's rows land where
+    they are computed and a token's ``K`` are summed once, after the loop,
+    so nothing is summed in another order (to the bit from one window; from
+    two, up to what a grouped matmul of another height rounds); and
+    ``moe/compact_frac`` (slots 8 and 9 of ``aux``) says whether one window
+    was enough."""
+    from trlx_tpu.models.transformer import held_row_bound, router_load_summary
+
+    layer, params, x, mask = _biased_to_the_held_windows(real_tokens, monkeypatch, tile=6)
+    assert held_row_bound(108, 3, 8) == 84
+    run = jax.jit(lambda p, x: layer.apply({"params": p}, x, token_mask=mask))
+    y, aux = run(params, x)
+    assert float(aux[6]) == 3 * real_tokens  # every real assignment fell on a held expert
+    assert (float(aux[8]), float(aux[9])) == (compact, 1.0)
+    assert float(router_load_summary(aux, layer.config)[4]) == compact
+    _no_bound(monkeypatch)
+    want, want_aux = jax.jit(lambda p, x: layer.apply({"params": p}, x, token_mask=mask))(params, x)
+    assert want_aux.shape == aux.shape and float(want_aux[9]) == 0.0  # no bound, no window, no call counted
+    if compact:
+        np.testing.assert_array_equal(np.asarray(y), np.asarray(want))
+    else:
+        np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=2e-6, atol=1e-7)
+    assert np.any(np.asarray(y) != 0)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("how", ["whole", "checkpoint", "pieces"])
+@pytest.mark.parametrize("factor,windows", [(2, 2), (1, 3)], ids=["two_windows", "three_windows"])
+def test_windows_of_a_call_over_the_bound_give_the_layer_without_one(factor, windows, how, dtype, monkeypatch):
+    """Every token's three choices on the held experts: 108 held rows in
+    windows of 84 (84 + 24) and, at once the even share, of 42 (42 + 42 +
+    24); in three pieces under ``lax.map`` a piece's 36 in windows of 30 and
+    18. Against the layer with no bound: every row goes through the kernels
+    it would and is read where it would be, and nothing but an expert
+    kernel's gradient is summed over windows (a kernel's rows in another
+    order), so value and all five gradients agree to float32 rounding in
+    float32 and bfloat16's in bfloat16."""
+    from trlx_tpu.models import transformer
+
+    layer, params, x, mask = _biased_to_the_held_windows(36, monkeypatch, tile=6, factor=factor, dtype=dtype)
+    rows = 108
+    if how == "pieces":
+        monkeypatch.setattr(transformer, "MOE_MAX_TOKENS", 8)
+        monkeypatch.setattr(transformer, "MOE_PIECE_TOKENS", 12)
+        rows, windows = 36, 2
+    bound = transformer.held_row_bound(rows, 3, 8)
+    assert bound == {(108, 2): 84, (108, 1): 42, (36, 2): 30, (36, 1): 18}[rows, factor]
+    assert -(-rows // bound) == windows
+    target = jnp.asarray(np.random.RandomState(6).randn(3, 12, x.shape[-1]), jnp.float32)
+
+    def loss(p, x):
+        apply = lambda p, x: layer.apply({"params": p}, x, token_mask=mask)
+        y, aux = (jax.checkpoint(apply) if how == "checkpoint" else apply)(p, x)
+        return jnp.sum(y.astype(jnp.float32) * target) + 0.01 * jnp.sum(aux[:8]), aux
+
+    (value, aux), grads = jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True))(params, x)
+    assert (float(aux[8]), float(aux[9])) == (0.0, 108 // rows)  # no call fitted one window
+    _no_bound(monkeypatch)
+    (want_value, _), want = jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True))(params, x)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(float(value), float(want_value), rtol=tol)
+    moved = 0
+    for (path, g), q in zip(jax.tree_util.tree_flatten_with_path(grads)[0], jax.tree_util.tree_leaves(want)):
+        g, q = np.asarray(g.astype(jnp.float32)), np.asarray(q.astype(jnp.float32))
+        assert np.isfinite(g).all(), jax.tree_util.keystr(path)
+        np.testing.assert_allclose(g, q, rtol=tol, atol=tol * np.abs(q).max(), err_msg=jax.tree_util.keystr(path))
+        moved += bool(np.any(g != 0))
+    assert moved == 5  # the router, the three expert kernels, x (the bias decides, and learns nothing)
+
+
+@pytest.mark.parametrize("why", ["no_real_token", "no_choice_falls_here"])
+def test_a_call_with_no_held_row_runs_no_window(why, monkeypatch):
+    """Zero held rows are zero trips of both loops: zeros out, zero
+    gradients, nothing unwritten let through."""
+    real_tokens, bias = (0, 100.0) if why == "no_real_token" else (36, -100.0)
+    layer, params, x, mask = _biased_to_the_held_windows(real_tokens, monkeypatch, tile=6, bias=bias)
+
+    def loss(p, x):
+        y, aux = layer.apply({"params": p}, x, token_mask=mask)
+        return jnp.sum(y * jnp.cos(jnp.arange(y.size).reshape(y.shape))), (y, aux)
+
+    (_, (y, aux)), grads = jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True))(params, x)
+    assert float(aux[4]) == 3 * real_tokens and float(aux[6]) == 0.0  # asked for, and none of them here
+    assert (float(aux[8]), float(aux[9])) == (1.0, 1.0)  # a call with a bound, and nothing overflowed it
+    assert not np.asarray(y).any()
+    for path, g in jax.tree_util.tree_flatten_with_path(grads)[0]:
+        assert not np.asarray(g).any(), jax.tree_util.keystr(path)  # zeros, so no NaN either
+
+
+@pytest.mark.parametrize("case", ["held_share", "checkpoint"])
+def test_windowed_rows_hold_no_scatter_and_four_buffers_of_every_row(case, monkeypatch):
+    """The bounded layer and its gradient move rows by gathers alone (no
+    ``scatter*`` whose operand has ``tokens x K`` or ``bound`` rows of
+    features), and the traced gradient writes a buffer of ``tokens x K``
+    rows four times, all of them OUTSIDE the two ``while`` bodies, which
+    work on ``bound`` rows: each assignment's result row, in the forward and
+    again in the backward, which runs the windows from the inputs; their
+    gradient ``dy x gates`` (the einsum's own transpose); and each
+    assignment's row gradient, which the tokens sum. Three of the four are a
+    gather under a select that drops what no window wrote (zeros broadcast
+    for the select count with it). The layer with no bound, walked the same
+    way, writes such a buffer some thirty times and has no ``while``."""
+    from trlx_tpu.models import transformer
+
+    _windows(monkeypatch)
+    tokens, K = 36, _DROPLESS_K
+    bound = transformer.held_row_bound(tokens * K, 3, 8)
+    assert bound == 88
+
+    def walk():
+        loss, params, x = _dropless_layer_loss(case, jnp.bfloat16, None, monkeypatch)
+        jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(params, x).jaxpr
+        eqns = list(_equations(jaxpr))
+        scatters = [
+            e.primitive.name for e in eqns
+            if e.primitive.name.startswith("scatter") and e.invars[0].aval.ndim == 2
+            and e.invars[0].aval.shape[0] in (tokens * K, bound, bound + 1, 2 * bound)
+        ]
+        names = [e.primitive.name for e in eqns]
+        in_loops = [inner for e in eqns if e.primitive.name == "while" for inner in _equations(e.params["body_jaxpr"].jaxpr)]
+        return scatters, _row_buffers(eqns, tokens * K, tokens, K), _row_buffers(in_loops, tokens * K, tokens, K), names.count("while"), names.count("cond")
+
+    scatters, buffers, in_loops, whiles, conds = walk()
+    assert scatters == [], scatters
+    assert sorted(buffers) == sorted(3 * ["gather", "select_n", "broadcast_in_dim"] + ["transpose"]), buffers
+    assert in_loops == [] and (whiles, conds) == (2, 0)  # one body a direction, on ``bound`` rows
+    _no_bound(monkeypatch)
+    scatters, buffers, in_loops, whiles, conds = walk()
+    assert scatters == [] and len(buffers) > 30, buffers
+    assert (whiles, conds) == (0, 0)
+
+
+def test_a_frozen_layers_kernel_gradients_are_not_computed(monkeypatch):
+    """A frozen expert kernel reaches the layer through ``stop_gradient``
+    (the train step's rule for a leaf the mask marks False), so its gradient
+    is only ever added to itself in the backward loop's carry. The compiled
+    gradient holds no product with a ``[held, d, f]`` or ``[held, f, d]``
+    result: the compiler takes the carry and its grouped matmul out. With
+    the kernels differentiated, the same walk finds the three."""
+    import re
+
+    _windows(monkeypatch)
+    loss, params, x = _dropless_layer_loss("held_share", jnp.float32, None, monkeypatch)
+    kernels = {k: v for k, v in params.items() if k != "router"}
+
+    def kernel_gradients(fn, *args):
+        text = jax.jit(jax.grad(fn, argnums=(0, 1))).lower(*args).compile().as_text()
+        return re.findall(r"= f32\[3,(?:64,96|96,64)\]\S* (?:dot|ragged-dot|convolution)\(", text)
+
+    frozen = lambda router, x: loss({"router": router, **jax.lax.stop_gradient(kernels)}, x)
+    assert len(kernel_gradients(loss, params, x)) >= 3
+    assert kernel_gradients(frozen, params["router"], x) == []
 
 
 @pytest.mark.parametrize("method", ["grpo", "ppo"])

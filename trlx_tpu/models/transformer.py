@@ -2958,6 +2958,111 @@ one_of_two_rows.defvjp(
 )
 
 
+def _window(x, route, bound, w):
+    """Window ``w`` of a sort: the assignments ``live [bound]`` of the sorted
+    rows ``[w x bound, (w + 1) x bound)``, their tokens' rows of ``x [N, d]``
+    (a token is read by up to ``K`` rows: a gather, no ``repeat``) and the
+    part of each held group that lies among them."""
+    _, order, unsort, group_sizes = route
+    lo = w * bound
+    if order.shape[0] % bound:
+        # whole windows: the last one reads assignment 0 where it passes the
+        # end of the sort, past every group like the rows before it
+        order = jnp.pad(order, (0, -order.shape[0] % bound))
+    live = jax.lax.dynamic_slice_in_dim(order, lo, bound)
+    xin = x.at[live // (unsort.shape[0] // x.shape[0])].get(mode="promise_in_bounds")
+    ends = jnp.cumsum(group_sizes)
+    in_window = lambda edge: jnp.clip(edge, lo, lo + bound)
+    return live, xin, in_window(ends) - in_window(ends - group_sizes)
+
+
+def _rows_by_window(rows: int, bound: int, d: int, dtype) -> jax.Array:
+    """Room for every window of a sort of ``rows`` rows, ``bound`` rows
+    each, and nothing written: a window's rows land where they are computed,
+    and only rows that were written are ever used (``_live_rows``)."""
+    return jax.lax.empty((-(-rows // bound) * bound, d), dtype)
+
+
+def _live_rows(by_window: jax.Array, unsort: jax.Array, held_rows: jax.Array) -> jax.Array:
+    """Each assignment's sorted row, zeros for an assignment that is not one
+    of the first ``held_rows`` of the sort (another chip's expert, padding):
+    a select over the gather, so that what no window wrote is read and
+    dropped, never used. (All of them reading ONE row instead was slower in
+    every cell it was tried in, 0.2 to 1.2% of the rate; PR 59.)"""
+    rows = by_window.at[unsort].get(mode="promise_in_bounds")
+    return jnp.where((unsort < held_rows)[:, None], rows, 0)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def held_rows(sorted_rows, sum_choices, bound, x, gate_vals, kernels, route):
+    """``MoEMLP._all_rows`` with its row buffers cut to windows of ``bound``
+    sorted rows: ONE ``while`` over the windows that hold a live row
+    (``ceil(held rows / bound)`` of them, a traced count; one wherever the
+    router is less than twice as fond of these experts as of the others)
+    gathers a window's tokens, runs ``sorted_rows`` on them and writes the
+    ``[bound, d]`` result where the window stands in an uninitialised
+    ``[tokens x K, d]`` buffer; every assignment then reads its row, or
+    zeros, and ``sum_choices`` weighs and sums a token's ``K`` as
+    ``_all_rows`` does. Nothing is summed over windows, so the value is
+    ``_all_rows``' to the bit however many windows ran.
+
+    The backward is one ``while`` over the same windows, from the INPUTS:
+    it runs a window's ``sorted_rows`` again, hands it the gradient of its
+    rows (gathered from ``g x gates`` by the window's assignments), writes
+    the gradient of the window's tokens' rows where the window stands, and
+    adds the kernels' gradients into its carry: the one sum over windows,
+    exact for one window. A kernel nothing is differentiated by (a frozen
+    leaf reaches the layer through ``stop_gradient``) has a gradient that is
+    only ever added to itself in that carry, and the compiler takes the
+    carry and the grouped matmul that feeds it out (read in the compiled
+    train steps, PR 59). A ``while`` of a traced count has no reverse mode
+    of JAX's own, and a bounded ``scan`` in its place would keep every
+    window's residuals."""
+    real, _, unsort, group_sizes = route
+    N, d = x.shape
+    K = unsort.shape[0] // N
+    held = jnp.sum(group_sizes)
+
+    def body(w, by_window):
+        _, xin, sizes = _window(x, route, bound, w)
+        return jax.lax.dynamic_update_slice_in_dim(by_window, sorted_rows(xin, kernels, sizes), w * bound, 0)
+
+    by_window = jax.lax.fori_loop(0, -(-held // bound), body, _rows_by_window(N * K, bound, d, x.dtype))
+    return sum_choices(_live_rows(by_window, unsort, held).reshape(N, K, d), gate_vals, real)
+
+
+def _held_rows_bwd(sorted_rows, sum_choices, bound, res, g):
+    x, gate_vals, kernels, route = res
+    real, _, unsort, group_sizes = route
+    N, d = x.shape
+    K = unsort.shape[0] // N
+    held = jnp.sum(group_sizes)
+    by_assignment = jax.ShapeDtypeStruct((N, K, d), x.dtype)
+    # bilinear in the rows and the gates: each transpose needs the other alone
+    (d_out,) = jax.linear_transpose(lambda out: sum_choices(out, gate_vals, real), by_assignment)(g)
+    d_out = d_out.reshape(N * K, d)
+
+    def body(w, carry):
+        outs, d_xins, d_kernels = carry
+        live, xin, sizes = _window(x, route, bound, w)
+        out, vjp = jax.vjp(lambda xin, kernels: sorted_rows(xin, kernels, sizes), xin, kernels)
+        d_xin, d_kernel = vjp(d_out.at[live].get(mode="promise_in_bounds"))
+        write = lambda by_window, rows: jax.lax.dynamic_update_slice_in_dim(by_window, rows, w * bound, 0)
+        return write(outs, out), write(d_xins, d_xin), jax.tree_util.tree_map(jnp.add, d_kernels, d_kernel)
+
+    empty = _rows_by_window(N * K, bound, d, x.dtype)
+    outs, d_xins, d_kernels = jax.lax.fori_loop(
+        0, -(-held // bound), body, (empty, empty, jax.tree_util.tree_map(jnp.zeros_like, kernels))
+    )
+    out = _live_rows(outs, unsort, held).reshape(N, K, d)
+    (d_gates,) = jax.linear_transpose(lambda gates: sum_choices(out, gates, real), gate_vals)(g)
+    (dx,) = jax.linear_transpose(lambda x: jnp.repeat(x, K, axis=0), x)(_live_rows(d_xins, unsort, held))
+    return dx, d_gates, d_kernels, None
+
+
+held_rows.defvjp(lambda *args: (held_rows(*args), args[3:]), _held_rows_bwd)
+
+
 class MoEMLP(nn.Module):
     """Mixture-of-experts MLP: top-k router, then one of two dispatches.
 
@@ -3024,10 +3129,22 @@ class MoEMLP(nn.Module):
     call whose held rows pass the bound runs every row (``_all_rows``, the
     body of a layer that holds them all) behind one ``lax.cond`` on the
     traced count, forward and backward (``one_of_two_rows``): dropless and
-    exact either way, the same bits from both bodies. ``aux`` counts the
-    calls that fitted (``moe/compact_frac``). Where no bound applies (every
-    expert held, or more than a sixteenth of them; a short call, a decode
-    step's: ``held_row_bound``) there is one body and no ``cond``.
+    exact either way, the same bits from both bodies. That second body is
+    code the chip holds, affordable where the bound cuts the rows by eight
+    or more (at most a sixteenth of the experts held). A layer that holds a
+    larger share (less than half) has ONE body instead (``held_rows``): the
+    experts on a WINDOW of ``bound`` sorted rows, in a ``while`` over the
+    windows that hold a live row (``ceil(held rows / bound)`` trips on the
+    traced count, one unless a call overflows the bound), each window's rows
+    written where the window stands in an uninitialised ``[B·T·k, d]``
+    buffer; after the loop each assignment reads its row, or zeros, and the
+    weighted sum over a token's ``k`` is ``_all_rows``' own, once: the bits
+    of the layer that holds every expert however many windows ran. Its
+    backward is one ``while`` over the same windows from the layer's inputs.
+    ``aux`` counts the calls that fitted the bound (``moe/compact_frac``).
+    Where no bound applies (every expert held, or half of them or more; a
+    short call, a decode step's: ``held_row_bound``) there is one body and
+    no ``cond`` or ``while``.
     """
 
     config: TransformerConfig
@@ -3200,11 +3317,16 @@ class MoEMLP(nn.Module):
         else:
             # the held experts' assignments sort first, so the live rows are
             # the first ``sum(group_sizes)`` of ``order``: where they fit the
-            # bound, the row buffers have ``bound`` rows; a call that
-            # overflows it runs every row, as a layer that holds them all does
+            # bound, the row buffers have ``bound`` rows
             fits = jnp.sum(group_sizes) <= bound
-            bodies = (partial(self._held_rows, bound=bound), self._all_rows)
-            y = one_of_two_rows(bodies, fits, x, gate_vals, kernels, route)
+            if bound * MOE_HELD_MIN_CUT <= N * K:
+                # a small share: a call that overflows the bound runs every
+                # row, as a layer that holds them all does, behind one cond
+                bodies = (partial(self._held_rows, bound=bound), self._all_rows)
+                y = one_of_two_rows(bodies, fits, x, gate_vals, kernels, route)
+            else:
+                # a large share: one body, window after window of ``bound`` rows
+                y = held_rows(self._sorted_rows, self._sum_choices, bound, x.reshape(N, d), gate_vals, kernels, route)
             compact = jnp.stack([fits.astype(jnp.float32), jnp.ones((), jnp.float32)])
         return y.reshape(B, T, d), counts.astype(jnp.float32), jnp.zeros((), jnp.float32), compact
 
@@ -3358,21 +3480,34 @@ MOE_PIECE_ROW_BYTES = 2**29
 # tokens·K·held/E of a call's tokens·K assignments to them, and they sort
 # first. The row buffers of such a call have MOE_HELD_ROWS_FACTOR times that
 # share of the rows, up to the grouped matmul's row tile (`held_row_bound`):
-# 6.25% of tokens·K where a chip holds 8 of 256, 12.5% at 8 of 128. A call
-# whose held rows pass the bound (a router twice as fond of this chip's
-# experts as of the others) runs all tokens·K rows, as before; the pieces
-# above stay sized for that. The second body is code the chip holds: 1.0 to
-# 1.7 MB a forward layer and 3.1 to 4.1 MB a trained one (compiled for a
-# described v5e, PR 57), 53 and 59 MB over the programs of the cells that
-# hold 32 of 256 and 16 of 64, which is 0.93 and 1.27% of their
-# `peak_hbm_gib` against a bound of 1%. So the bound has to cut the rows by
-# MOE_HELD_MIN_CUT or more (a layer that holds more than a sixteenth of its
-# experts traces the one program it always had), and a call under
-# MOE_HELD_MIN_ROWS rows keeps them all: a decode step's 256 to 1024 rows are
-# a few MB, and the `cond` around them cost more than the passes it spared
-# (cell 9's loop of rounds 9.57 -> 9.85 s, cell 7's 5.42 -> 5.44 s, PR 57).
-# The observable is the share held and the call's size; constants with their
-# arithmetic, not settings.
+# 6.25% of tokens·K where a chip holds 8 of 256, 12.5% at 8 of 128, 25% at 32
+# of 256, 50% at 16 of 64. A call whose held rows pass the bound (a router
+# twice as fond of this chip's experts as of the others) is still dropless
+# and exact, by one of two forms, chosen by the cut alone:
+# - a cut of MOE_HELD_MIN_CUT or more (at most a sixteenth of the experts
+#   held): the `bound`-row body or, for the call that overflows, all tokens·K
+#   rows, behind one `cond` (`one_of_two_rows`, PR 57). The second body is
+#   code the chip holds, 1.0 to 1.7 MB a forward layer and 3.1 to 4.1 MB a
+#   trained one (compiled for a described v5e, PR 57): +0.2 to +0.5% of
+#   `peak_hbm_gib` where the cut is 8 to 16, and 0.93 and 1.27% where a chip
+#   holds 32 of 256 and 16 of 64, against a bound of 1%;
+# - a smaller cut: ONE body, the experts on a window of `bound` sorted rows,
+#   in a `while` over the windows that hold a live row (`held_rows`, PR 59:
+#   ceil(held rows / bound) trips, one in every call seen), each window's
+#   rows written where the window stands in an uninitialised `[tokens·K, d]`
+#   buffer and read once after the loop. No second body, so no code growth
+#   (+9 and +7 MB over the parent's programs at 32 of 256 and 16 of 64), and
+#   +5.8 and +4.7% `samples_per_s` there (builder, PR 59). At a cut of 8 to 16
+#   the same form LOST 2.5 and 3.6% to the two bodies (cells 7, 8: the
+#   `while`'s fixed passes over `[tokens·K, d]` rooms beside a body of 6% of
+#   the rows), and a form that summed the windows' float32 `[tokens, d]`
+#   results in the loop's carry lost 0.5 to 1.3% there, which is why both
+#   forms stay.
+# A call under MOE_HELD_MIN_ROWS rows keeps them all: a decode step's 256 to
+# 1024 rows are a few MB, and the `cond` around them cost more than the
+# passes it spared (cell 9's loop of rounds 9.57 -> 9.85 s, cell 7's 5.42 ->
+# 5.44 s, PR 57; a `while` there is not priced). The observable is the share
+# held and the call's size; constants with their arithmetic, not settings.
 MOE_HELD_ROWS_FACTOR = 2
 MOE_HELD_MIN_CUT = 8
 MOE_HELD_MIN_ROWS = 4096
@@ -3381,13 +3516,14 @@ MOE_HELD_MIN_ROWS = 4096
 def held_row_bound(rows: int, held: int, experts: int) -> int:
     """Rows of the sorted row buffers of a dropless call of ``rows = tokens x
     K`` assignments in a layer that holds ``held`` of ``experts``; ``rows``
-    where no bound applies."""
+    where no bound applies: a short call, or a share whose bound would reach
+    the rows (half the experts or more, every expert)."""
     from trlx_tpu.ops import grouped_matmul
 
     tile = grouped_matmul.ROW_TILE
     even = -(-rows * held // experts)
     bound = -(-MOE_HELD_ROWS_FACTOR * even // tile) * tile
-    return bound if rows >= MOE_HELD_MIN_ROWS and bound * MOE_HELD_MIN_CUT <= rows else rows
+    return bound if rows >= MOE_HELD_MIN_ROWS and bound < rows else rows
 
 
 def moe_token_pieces(tokens: int, token_bytes: int = 0) -> int:
