@@ -434,8 +434,8 @@ def _assert_the_benchmark_finds_both_kernels(text):
 @pytest.mark.parametrize(
     "shape,window",
     [((1, 8192, 28, 4, 128), 4096), ((1, 8192, 28, 4, 128), None), ((8, 1024, 16, 16, 256), None), ((16, 640, 32, 8, 128), None),
-     ((4, 1152, 32, 8, 128), None)],
-    ids=["window_4096", "global", "gptj_1024x256", "mistral_640x128", "not_a_multiple_of_512"],
+     ((4, 1152, 32, 8, 128), None), ((8, 1152, 32, 8, 64), None)],
+    ids=["window_4096", "global", "gptj_1024x256", "mistral_640x128", "not_a_multiple_of_512", "lfm2_1152x64"],
 )
 def test_flash_forward_and_backward_compile_at_the_chosen_tile(topo, shape, window):
     """The learners' attention shapes at the tile the kernel chooses for
@@ -444,7 +444,9 @@ def test_flash_forward_and_backward_compile_at_the_chosen_tile(topo, shape, wind
     over 4 key/value heads of 128, under its window and without (512 x 512);
     GPT-J's eight rows of 1024 at head size 256 (512 x 512); sixteen rows of
     640 at 32 / 8 heads of 128 (one 640 x 640 tile); a long row that 512 does
-    not divide (1152 slots: 384 x 384, no slot of padding). Both kernels must lower
+    not divide (1152 slots: 384 x 384, no slot of padding), and
+    ``lfm2_8b_grpo_reason_r128``'s minibatch of eight such rows at 32 / 8 heads
+    of 64, half a vector register's lanes a head. Both kernels must lower
     for the chip, keep the names the benchmark's ``flash_fwd_device_ms`` /
     ``flash_bwd_device_ms`` match on, and fit the VMEM they ask for: the
     fused backward keeps whole-sequence q, do, dq, lse and delta in VMEM

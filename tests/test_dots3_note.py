@@ -509,8 +509,8 @@ APPENDED_TO = ("latent_cache_gib", "sparse_gather_rows", "attn_visited_pct", "kv
 @pytest.mark.parametrize("name", NEW_METRICS + APPENDED_TO)
 def test_the_cells_metrics_are_declared_and_their_files_name_what_the_harness_finds(name):
     """Each new metric lists the new cell alone and agrees with its file; each
-    accepted metric the cell joins kept its cells in their order with the new
-    one LAST, and its file is the accepted one (a reducer the harness has, a
+    accepted metric the cell joins lists it (last when PR 56 appended it; a
+    later cell is appended behind it), and its file is the accepted one (a reducer the harness has, a
     key the program logs or a pattern that compiles, a cost function the
     family's file or ``flops.py`` brings)."""
     from chipbench import job, layers
@@ -519,7 +519,7 @@ def test_the_cells_metrics_are_declared_and_their_files_name_what_the_harness_fi
     entry = next(m for m in job.load_benchmark()["per_layer"] if m["name"] == name)
     spec = layers.metric_files()[name]
     assert all(entry[k] == spec[k] for k in ("unit", "better", "source", "layer", "moves"))
-    assert entry["workloads"][-1] == cell and (entry["workloads"] == [cell]) == (name in NEW_METRICS)
+    assert cell in entry["workloads"] and (entry["workloads"] == [cell]) == (name in NEW_METRICS)
     if "pattern" in spec:
         re.compile(spec["pattern"])
         assert "PATTERN" not in spec["pattern"] + spec["reads"]

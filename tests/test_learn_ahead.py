@@ -52,6 +52,19 @@ def _quiet(monkeypatch):
     set_active_plan(None)
 
 
+@pytest.fixture(scope="module")
+def _no_mfu_thread():
+    """``_quiet``'s switch for what a module-scoped fixture runs, which is
+    built before the autouse fixture of its first test: set for the build and
+    put back after it. Left set in ``os.environ`` it stayed behind for every
+    later test of the worker, and ``tests/test_observability.py::
+    test_ppo_smoke_emits_throughput_and_trace`` then found no
+    ``throughput/mfu`` in its records (the driver's run of PR 59)."""
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setenv("TRLX_TPU_MFU", "0")
+        yield
+
+
 class Recorder:
     """Stand-in tracker: keeps every record the trainer logs."""
 
@@ -199,9 +212,8 @@ def losses(steps):
 
 
 @pytest.fixture(scope="module")
-def ppo_cycles(tmp_path_factory):
+def ppo_cycles(tmp_path_factory, _no_mfu_thread):
     tmp = tmp_path_factory.mktemp("ahead")
-    os.environ["TRLX_TPU_MFU"] = "0"  # module scope: before the autouse fixture
     learn = []  # (start, end) of each learn phase, on the loop's own clock
 
     def hook(trainer):
@@ -345,8 +357,7 @@ def test_bit_equal_to_a_run_of_boundaries(method, tmp_path, monkeypatch):
 
 
 @pytest.fixture(scope="module")
-def bounded(tmp_path_factory):
-    os.environ["TRLX_TPU_MFU"] = "0"
+def bounded(tmp_path_factory, _no_mfu_thread):
     tmp = tmp_path_factory.mktemp("bounded")
     return run_rl(rl_config(tmp, epochs=2, total_steps=22, checkpoint_interval=6, eval_interval=5))
 

@@ -1047,8 +1047,12 @@ class TestMergedTrace:
 # ---------------------------------------------------------------------------
 
 
-def test_ppo_smoke_emits_throughput_and_trace(tmp_path):
+def test_ppo_smoke_emits_throughput_and_trace(tmp_path, monkeypatch):
     import trlx_tpu.trlx as trlx
+
+    # the MFU gauge is what this test reads: a switch another test of the worker left in the environment
+    # (tests/test_learn_ahead.py's module fixtures did until PR 60) must not turn it off
+    monkeypatch.delenv("TRLX_TPU_MFU", raising=False)
     from trlx_tpu.data.default_configs import default_ppo_config
 
     config = default_ppo_config().evolve(

@@ -59,6 +59,12 @@ _DOTS3_NOTE_NO_INTEROP = (
     "(random weights) only and no converter pair was ever checked against one "
     "(ROADMAP.md queue 2, B4)"
 )
+_LFM2_MOE_NO_INTEROP = (
+    "model_type 'lfm2_moe' has no HF checkpoint conversion yet: no checkpoint can "
+    "be fetched where this was built, so the family runs from 'builtin:lfm2-<size>' "
+    "(random weights) only and no converter pair was ever checked against one "
+    "(ROADMAP.md queue 2, B7)"
+)
 _EXAONE_MOE_NO_INTEROP = (
     "model_type 'exaone_moe' has no HF checkpoint conversion yet: no checkpoint can "
     "be fetched where this was built, so the family runs from 'builtin:k-exaone-<size>' "
@@ -558,6 +564,8 @@ def config_from_hf(hf_config) -> TransformerConfig:
         raise ValueError(_KIMI_LINEAR_NO_INTEROP)
     if mt == "dots3_note":
         raise ValueError(_DOTS3_NOTE_NO_INTEROP)
+    if mt == "lfm2_moe":
+        raise ValueError(_LFM2_MOE_NO_INTEROP)
     raise ValueError(f"Unsupported HF model type for causal import: {mt}")
 
 
@@ -1205,6 +1213,8 @@ def hf_config_from_transformer(cfg):
         raise UnsupportedHFExport(_KIMI_LINEAR_NO_INTEROP)
     if mt == "dots3_note":
         raise UnsupportedHFExport(_DOTS3_NOTE_NO_INTEROP)
+    if mt == "lfm2_moe":
+        raise UnsupportedHFExport(_LFM2_MOE_NO_INTEROP)
     raise UnsupportedHFExport(
         f"No HF export mapping for model_type={mt!r} "
         "(set TransformerConfig.model_type to an HF family)"

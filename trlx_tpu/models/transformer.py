@@ -209,6 +209,9 @@ EMBED_INIT_STD_MINICPM_SALA = 1.0 / 12.0
 # the like for Kimi-Linear's latent layers, whose `q_proj` makes the queries from the normed stream with
 # no latent and no norm between (chipbench/configs/kimi-linear-48b-a3b-l8e32.json, `assumed`)
 QK_INIT_STD_KIMI_LINEAR = 0.08
+# the like for LFM2, per-head norms as K-EXAONE's: 2 on q and on k of the attention layers, a score of standard
+# deviation 4 at a head of 64 (chipbench/configs/lfm2-8b-a1b-l10e8.json, `assumed`)
+QK_INIT_STD_LFM2 = 0.04
 
 
 class AttentionSizes(NamedTuple):
@@ -246,7 +249,9 @@ class LayerLayout(NamedTuple):
     # Mamba-2 heads BESIDE it in the block) | "lightning" (`LightningMixer`: a
     # linear recurrence whose state is the layer's whole cache, no K or V) |
     # "kda" (`KDAMixer`: a gated delta rule behind short convs; the state and
-    # the convs' last rows are the layer's whole cache)
+    # the convs' last rows are the layer's whole cache) | "conv"
+    # (`ShortConvMixer`: a short causal conv between two gates; the conv's
+    # last input rows are the layer's whole cache)
     mixer: str = "attention"
 
 
@@ -383,6 +388,9 @@ class TransformerConfig:
     moe_group_size: int = 0  # dispatch group tokens (0 = whole sequence);
     # bounds the [.., E, C] slot tensors to O(T·G) instead of O(T²)
     moe_renormalize: bool = True  # mixtral renormalizes the top-k gate probs
+    # what the renormalisation divides by: 0 = `max(sum, 1e-9)`; above 0 the
+    # chosen scores' `sum + moe_renormalize_eps` (lfm2_moe publishes 1e-6)
+    moe_renormalize_eps: float = 0.0
     # the experts' own width where the stack also has dense layers of
     # `intermediate_size` (0 = `intermediate_size`: every family whose layers
     # are all sparse publishes one width)
@@ -480,8 +488,8 @@ class TransformerConfig:
     residual_multiplier: float = 1.0
 
     # each layer's sequence mixer (minicpm_sala's `mixer_types`): one entry a
-    # layer, "attention" | "lightning" (`LayerLayout.mixer`); None = attention
-    # on every layer. Entries past `num_layers` are not read. A lightning
+    # layer, "attention" | "lightning" | "kda" | "conv" (`LayerLayout.mixer`);
+    # None = attention on every layer. Entries past `num_layers` are not read. A lightning
     # layer runs `lightning_heads` heads of `lightning_head_dim` (q, k and v
     # alike) through `LightningMixer`
     mixer_layout: Optional[Tuple[str, ...]] = None
@@ -511,6 +519,13 @@ class TransformerConfig:
     kda_heads: int = 0
     kda_head_dim: int = 0
     kda_conv: int = 4
+    # gated short convolution layers (lfm2_moe; the "conv" entries of
+    # `mixer_layout`, beside K/V attention layers): `ShortConvMixer`, a causal
+    # depthwise conv of `conv_L_cache` taps over the hidden width between two
+    # elementwise gates; `conv_bias` is the published key (false: no bias on the
+    # two projections or the conv; true is refused, no model here has one)
+    conv_L_cache: int = 3
+    conv_bias: bool = False
     # a second attention geometry on the WINDOW layers of a latent stack
     # (dots3_note's `swa_*` keys): each None = as the model-wide field of the
     # like name, which the full layers keep (`attention_sizes`). With
@@ -549,8 +564,8 @@ class TransformerConfig:
         if self.mixer_layout is not None:
             kinds = tuple(str(m) for m in self.mixer_layout)
             used = set(kinds[: self.num_layers])
-            if len(kinds) < self.num_layers or used - {"attention", "lightning", "kda"}:
-                raise ValueError(f"mixer_layout needs one of attention | lightning | kda for each of {self.num_layers} layers: {kinds}")
+            if len(kinds) < self.num_layers or used - {"attention", "lightning", "kda", "conv"}:
+                raise ValueError(f"mixer_layout needs one of attention | lightning | kda | conv for each of {self.num_layers} layers: {kinds}")
             if self.mixer != "none" or self.mtp_layers or (
                     "lightning" in used and (self.latent_attention or self.lightning_heads < 1 or self.lightning_head_dim < 2)):
                 raise ValueError(
@@ -562,6 +577,8 @@ class TransformerConfig:
                     "mixer_layout (kda layers among attention layers) takes latent or K/V attention layers under no "
                     "selection, kda_heads heads of kda_head_dim and a conv of kda_conv >= 2 taps"
                 )
+            if "conv" in used and (self.conv_L_cache < 2 or self.conv_bias):
+                raise ValueError("mixer_layout (conv layers among attention layers) takes a conv of conv_L_cache >= 2 taps and no conv_bias")
             object.__setattr__(self, "mixer_layout", kinds)
         if self.sparse_topk:
             b, k, s = self.sparse_block, self.sparse_kernel, self.sparse_stride
@@ -1120,6 +1137,64 @@ class TransformerConfig:
             router_aux_coef=0.0,  # balance is the selection bias's work, not a loss's
             embed_init_std=1.0,
             qk_init_std=QK_INIT_STD_KIMI_LINEAR,
+        )
+
+    @staticmethod
+    def lfm2(size: str = "8b-a1b", **overrides) -> "TransformerConfig":
+        """LFM2-8B-A1B (``model_type`` ``lfm2_moe``): 18 of 24 layers mix the
+        sequence by a gated short convolution alone (``ShortConvMixer``:
+        ``in_proj`` to ``B | C | x``, ``C * conv3(B * x)``, ``out_proj``; no
+        activation, no bias; the conv's last two input rows are the layer's
+        whole cache), 6 (``layer_types`` ``full_attention``) by GQA 32/8
+        attention at a head of 64 with an RMSNorm on each head's q and k
+        (``qk_norm: "head"``) and rotary embedding at theta 1e6; two leading
+        dense SwiGLU layers of 7168, then layers of 32 routed SwiGLU experts
+        of 1792 (sigmoid scores, the four largest of ``score + bias``,
+        renormalised over ``sum + 1e-6``, times 1), no shared expert; the head
+        tied to the embedding. ``mixer_layout`` says each layer's kind, so a
+        cut of the depth overrides ``num_layers`` alone. Limits: the plain
+        sampler, the scoring forward, the hydra branch and the train step
+        (``ops/cache_layout.py::refuse``); no ``scan_layers``, no ring attention
+        over ``sequence``, no HF checkpoint import. ``qk_init_std`` is the
+        stand-in scale of the scores, in the per-head norms' scales
+        (chipbench/configs/lfm2-8b-a1b-l10e8.json, `assumed`).
+        ``builtin:lfm2-8b-a1b`` | ``builtin:lfm2-test``."""
+        a, c = "attention", "conv"
+        dims = {
+            # the benchmark's cut in small: two dense conv layers, an attention layer, conv layers, an attention
+            # layer, a conv layer last; GQA 4/2 of 16, 8 experts of 32 top-2 under a bias that binds
+            "test": dict(vocab_size=259, hidden_size=64, num_layers=6, num_heads=4, num_kv_heads=2, head_dim=16, intermediate_size=128, max_position_embeddings=256,
+                         mixer_layout=(c, c, a, c, a, c), moe_intermediate_size=32, num_experts=8, num_experts_per_tok=2, moe_bias_init_std=0.05),
+            "8b-a1b": dict(vocab_size=65536, hidden_size=2048, num_layers=24, num_heads=32, num_kv_heads=8, head_dim=64, intermediate_size=7168, max_position_embeddings=128000,
+                           mixer_layout=(c, c, a, c, c, c, a, c, c, c, a, c, c, c, a, c, c, c, a, c, c, a, c, c),
+                           moe_intermediate_size=1792, num_experts=32, num_experts_per_tok=4),
+        }[size]
+        return _make_preset(
+            dims,
+            overrides,
+            model_type="lfm2_moe",
+            position_scheme="rotary",
+            rope_theta=1e6,
+            norm="rmsnorm",
+            layer_norm_epsilon=1e-5,
+            activation="silu",
+            attn_bias=False,
+            mlp_bias=False,
+            tie_word_embeddings=True,
+            qk_norm="head",
+            first_k_dense=2,  # num_dense_layers
+            moe_gated=True,
+            moe_scoring="sigmoid",
+            moe_topk_method="noaux_tc",  # use_expert_bias
+            routed_scaling_factor=1.0,
+            moe_capacity_factor=0.0,  # dropless
+            moe_renormalize=True,  # norm_topk_prob: true
+            moe_renormalize_eps=1e-6,
+            router_aux_coef=0.0,  # balance is the selection bias's work, not a loss's
+            # (the embedding keeps the program's 0.02: tied, it is the head too, and at the 1.0 of the other
+            # expert stand-ins a token's own logit is its embedding's squared norm, 2048 against a spread of 45:
+            # every rollout repeats its prompt's last token)
+            qk_init_std=QK_INIT_STD_LFM2,
         )
 
     @staticmethod
@@ -2832,6 +2907,42 @@ class KDAMixer(nn.Module):
         return out, new_cache, stats
 
 
+class ShortConvMixer(nn.Module):
+    """LFM2's gated short convolution (``lfm2_moe``), a layer's whole
+    sequence mixer: ``[B | C | z] = in_proj(u)`` (hidden to three times
+    hidden, split in that order), ``g = B * z``, ``c_t = sum_k w_k g_{t - K +
+    1 + k}`` a channel (a causal depthwise conv of ``conv_L_cache`` taps,
+    ``ops/ssd.py::causal_conv``), ``out_proj(C * c)``. Two multiplicative
+    gates round the conv, no activation and no bias.
+
+    The layer's whole cache is ``{"conv": [B, conv_L_cache - 1, hidden]}``,
+    the last rows of ``g`` before the conv, whatever the row's length; no K,
+    no V, no state: a span (prefill) or one token starts from the stored
+    rows, a pass without a cache from zeros. ``token_mask`` marks real
+    tokens: a padded position feeds nothing into the window, so a left-padded
+    row reaches its first real token with zeros to its left."""
+
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, u, cache=None, token_mask=None):
+        from trlx_tpu.ops.ssd import causal_conv
+
+        cfg = self.config
+        B, T, E = u.shape
+        bcz = _dense(cfg, 3 * E, False, ("embed", "ssm"), "in_proj")(u)
+        conv_w = self.param("conv_weight", param_with_axes(_conv_taps_init, ("conv", "ssm")), (cfg.conv_L_cache, E), cfg.param_dtype)
+        with jax.named_scope("trlx/short_conv"):
+            b_gate, c_gate, z = jnp.split(bcz, 3, axis=-1)
+            g = b_gate * z
+            if token_mask is not None:
+                g = g * token_mask.reshape(B, T, 1).astype(g.dtype)
+            c, conv_state = causal_conv(g, conv_w, None, None if cache is None else cache["conv"])
+            y = c_gate * c
+        out = _dense(cfg, E, False, ("ssm", "embed"), "out_proj")(y)
+        return out, (None if cache is None else {"conv": conv_state.astype(cache["conv"].dtype)})
+
+
 @functools.lru_cache(maxsize=None)
 def _warn_indivisible_experts(num_experts: int, axis: int) -> None:
     """Warn ONCE per (experts, axis) pair: the divisibility fit silently
@@ -3189,7 +3300,9 @@ class MoEMLP(nn.Module):
         else:
             gate_vals, idx = jax.lax.top_k(scores, K)  # [B, T, K]
         chosen_scores = gate_vals
-        if cfg.moe_renormalize:
+        if cfg.moe_renormalize and cfg.moe_renormalize_eps:
+            gate_vals = gate_vals / (jnp.sum(gate_vals, axis=-1, keepdims=True) + cfg.moe_renormalize_eps)
+        elif cfg.moe_renormalize:
             gate_vals = gate_vals / jnp.maximum(
                 jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9
             )
@@ -3695,6 +3808,8 @@ class Block(nn.Module):
             attn_out, new_cache = LightningMixer(cfg, name="attn")(h, positions, cache, token_mask)
         elif layout.mixer == "kda":
             attn_out, new_cache, kda_stats = KDAMixer(cfg, name="attn")(h, cache, token_mask)
+        elif layout.mixer == "conv":
+            attn_out, new_cache = ShortConvMixer(cfg, name="attn")(h, cache, token_mask)
         elif cfg.latent_attention:
             lends = self.layer + 1 < cfg.num_layers and cfg.layer_layout(self.layer + 1).indexer == "shared"
             attn_out, new_cache, selection, gate_stats = LatentAttention(cfg, self.layer, lends, name="attn")(
@@ -4349,7 +4464,8 @@ def make_kv_cache(
     drift in bf16) and ``conv`` (the conv's last ``K - 1`` input rows). A
     ``lightning`` layer (``mixer_layout``) holds ``state`` ``[B, heads, d, d]``
     float32 and nothing else; a ``kda`` layer ``state`` ``[B, heads, d, d]``
-    float32 and ``conv`` ``[B, kda_conv - 1, 3 heads d]`` and nothing else; an attention layer under a block selection
+    float32 and ``conv`` ``[B, kda_conv - 1, 3 heads d]`` and nothing else; a ``conv`` layer ``conv`` ``[B, conv_L_cache - 1,
+    hidden]`` and nothing else; an attention layer under a block selection
     (``sparse_topk`` > 0) holds ``kbar`` ``[B, KV, max_length / sparse_stride,
     D]`` beside ``k`` and ``v``, the keys' mean-pool its decode steps score. A
     latent-attention layer (``kv_lora_rank`` > 0) holds ``ckv`` ``[B, slots,
@@ -4385,6 +4501,11 @@ def make_kv_cache(
                 "state": jnp.zeros(stacked + (batch_size, heads, d, d), jnp.float32),
                 "conv": jnp.zeros(stacked + (batch_size, cfg.kda_conv - 1, 3 * heads * d), dtype),
             }
+        if layout.mixer == "conv":
+            # the layer's whole cache: the last `conv_L_cache - 1` rows of the gated input `B * x` before
+            # the conv, whatever the row's length; no K, V, latent or state (ops/cache_layout.py::VOCABULARY:
+            # `conv` alone)
+            return {"conv": jnp.zeros(stacked + (batch_size, cfg.conv_L_cache - 1, cfg.hidden_size), dtype)}
         if cfg.latent_attention:
             # the normed latent and the one roped key (ops/cache_layout.py::VOCABULARY), the
             # same slot axis and cache_index as K and V have, and no K or V; side by side
@@ -4472,6 +4593,7 @@ BUILTIN_SPECS = {
     "minicpm-sala": TransformerConfig.minicpm_sala,
     "kimi-linear": TransformerConfig.kimi_linear,
     "dots3": TransformerConfig.dots3,
+    "lfm2": TransformerConfig.lfm2,
     "gptj": TransformerConfig.gptj,
     "gptneox": TransformerConfig.gptneox,
     "pythia": TransformerConfig.gptneox,

@@ -15,16 +15,17 @@ from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 import jax
 import numpy as np
 
-__all__ = ["KV", "POOLED", "RECURRENT", "LINEAR", "LATENT", "INDEX", "KINDS", "VOCABULARY", "PATHS", "REFUSED",
+__all__ = ["KV", "POOLED", "RECURRENT", "LINEAR", "CONV", "LATENT", "INDEX", "KINDS", "VOCABULARY", "PATHS", "REFUSED",
            "describe", "cache_slots", "cache_bytes", "ring", "refuse"]
 
 # the kinds of thing a layer keeps a sequence, in the words a refusal says them
-KV, POOLED, RECURRENT, LINEAR, LATENT, INDEX = "kv", "pooled", "recurrent", "linear", "latent", "index"
+KV, POOLED, RECURRENT, LINEAR, CONV, LATENT, INDEX = "kv", "pooled", "recurrent", "linear", "conv", "latent", "index"
 KINDS = {
     KV: "per-head K and V",
     POOLED: "compressed keys beside K and V",
     RECURRENT: "recurrent state beside K and V",
     LINEAR: "a recurrence's state as the layer's whole cache",
+    CONV: "a short convolution's last input rows as the layer's whole cache",
     LATENT: "a latent in place of K and V",
     INDEX: "index keys beside a latent",
 }
@@ -42,7 +43,9 @@ VOCABULARY = {
     "v": Leaf(KV, -3),
     "kbar": Leaf(POOLED, None),  # [B, KV heads, slots / stride, D]: the keys' mean-pool under a block selection
     "ssm": Leaf(RECURRENT, None),  # a state-space recurrence's state beside attention's K and V, float32
-    "conv": Leaf(RECURRENT, None, (("state", LINEAR),)),  # a conv's last input rows: beside `ssm`, or beside a delta rule's `state`
+    # a conv's last input rows: ALONE a gated short convolution layer's whole cache, [B, taps - 1, hidden] whatever the
+    # row's length; beside `ssm` part of a state-space mixer's state, beside a delta rule's `state` part of its layer's
+    "conv": Leaf(CONV, None, (("ssm", RECURRENT), ("state", LINEAR))),
     "state": Leaf(LINEAR, None),  # [B, heads, d, d] float32: a linear-attention or delta-rule layer's whole cache
     "ckv": Leaf(LATENT, -2),  # [B, slots, rank]: the normed latent keys and values are made from
     "k_rope": Leaf(LATENT, -2),  # [B, slots, rope]: the one roped key all heads share
@@ -115,6 +118,10 @@ PATHS = ("slot_refill", "engine", "prefix_cache", "speculative")
 
 _NO_POOLED = "it holds K and V a slot and no compressed keys, which fill by each row's own position"
 _NO_INDEX = "it scores, selects from and moves no index keys, which ride on a latent's slots"
+_REFILL_CONV = "ops/slot_refill.py::SlotState (train.continuous_batching) holds K and V a slot and would refill a slot over the conv window another row left, which nothing resets"
+_ENGINE_CONV = "the engine/ slots (paged cache) hold K and V blocks and no rows of a conv's window, which have no slot axis to page"
+_PREFIX_CONV = "the engine's prefix cache shares K and V blocks; a conv's window after a prefix has no snapshot at a block boundary to share"
+_REWIND_CONV = "ops/speculative.py rewinds K and V to the accepted length; the conv window has moved on past the rejected tokens and keeps no older rows to go back to"
 _REFILL_STATE = "ops/slot_refill.py::SlotState (train.continuous_batching) holds K and V a slot and would refill a slot over another row's recurrent state"
 _ENGINE_STATE = "the engine/ slots (paged cache) hold K and V blocks and no recurrent state"
 _PREFIX_STATE = "the engine's prefix cache shares K and V blocks; a recurrent state has no snapshot at a block boundary to share"
@@ -126,24 +133,28 @@ _REWIND_STATE = "ops/speculative.py rewinds K and V to the accepted length and c
 REFUSED = {
     ("slot_refill", RECURRENT): (_REFILL_STATE, "B7b"),
     ("slot_refill", LINEAR): (_REFILL_STATE, "B7b"),
+    ("slot_refill", CONV): (_REFILL_CONV, "B7b"),
     ("slot_refill", POOLED): (_NO_POOLED, "B8c"),
     ("slot_refill", LATENT): ("ops/slot_refill.py::SlotState refills a slot at its own depth, a [B] vector of cache indices, and its span prefill attends over the cache's per-head K and V", "B4b"),
     ("slot_refill", INDEX): (_NO_INDEX, "B8c"),
     ("slot_refill", ring(KV)): ("ops/slot_refill.py refills one slot's row at its own depth, a [B] vector of cache indices", "B3c"),
     ("engine", RECURRENT): (_ENGINE_STATE, "B7b"),
     ("engine", LINEAR): (_ENGINE_STATE, "B7b"),
+    ("engine", CONV): (_ENGINE_CONV, "B7b"),
     ("engine", POOLED): (_NO_POOLED, "B8c"),
     ("engine", LATENT): ("the engine/ block pool and its paged kernels (ops/paged_attention.py, ops/paged_prefill.py) hold and read per-head K and V blocks", "B4a"),
     ("engine", INDEX): (_NO_INDEX, "B8c"),
     ("engine", ring(KV)): ("the engine/ block tables map every slot of a row to a block and the allocator frees none before the row ends", "B3c"),
     ("prefix_cache", RECURRENT): (_PREFIX_STATE, "B7c"),
     ("prefix_cache", LINEAR): (_PREFIX_STATE, "B7c"),
+    ("prefix_cache", CONV): (_PREFIX_CONV, "B7c"),
     ("prefix_cache", POOLED): (_NO_POOLED, "B8c"),
     ("prefix_cache", LATENT): ("the engine's prefix cache shares per-head K and V blocks", "B4a"),
     ("prefix_cache", INDEX): (_NO_INDEX, "B8c"),
     ("prefix_cache", ring(KV)): ("the engine's prefix cache shares a prompt's blocks from slot 0, which a ring has overwritten", "B3c"),
     ("speculative", RECURRENT): (_REWIND_STATE, "B7c"),
     ("speculative", LINEAR): (_REWIND_STATE, "B7c"),
+    ("speculative", CONV): (_REWIND_CONV, "B7c"),
     ("speculative", POOLED): (_NO_POOLED, "B8c"),
     ("speculative", LATENT): ("ops/speculative.py verifies and rewinds rows at their own accepted lengths, a [B] vector of cache indices, over per-head K and V", "B4b"),
     ("speculative", INDEX): (_NO_INDEX, "B8c"),

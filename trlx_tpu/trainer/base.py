@@ -43,7 +43,7 @@ from trlx_tpu.models.builder import (
 from trlx_tpu.models.transformer import (
     block_selected_pairs, block_selected_steps, make_kv_cache, selected_frac, sparse_gather_rows,
 )
-from trlx_tpu.ops.cache_layout import INDEX, KV, LATENT, LINEAR, POOLED, RECURRENT, cache_bytes, cache_slots, ring
+from trlx_tpu.ops.cache_layout import CONV, INDEX, KV, LATENT, LINEAR, POOLED, RECURRENT, cache_bytes, cache_slots, ring
 from trlx_tpu.ops.paged_kv import kv_bytes
 from trlx_tpu.ops.sampling import (
     GenerationConfig,
@@ -1638,11 +1638,12 @@ class TPUBaseTrainer(BaseRLTrainer):
         policy_cache = cache(self.tcfg, S)
         held = cache_bytes(policy_cache, S)
         kv, latent = held[KV] + held[ring(KV)], held[LATENT] + held[ring(LATENT)]
-        total = sum(held.values()) - held[RECURRENT] - held[LINEAR]
+        total = sum(held.values()) - held[RECURRENT] - held[LINEAR] - held[CONV]
         stats = self.last_cache_stats = {"rollout/kv_cache_bytes": float(kv), "rollout/ssm_state_bytes": float(held[RECURRENT])}
         if latent:  # the layers cache a latent in place of K and V (a window layer's ring apart), and index keys with it
             stats.update({"rollout/latent_cache_bytes": float(held[LATENT]), "rollout/index_cache_bytes": float(held[INDEX] + held[ring(INDEX)])})
-        for kind, key in ((LINEAR, "rollout/linear_state_bytes"), (POOLED, "rollout/kbar_cache_bytes"), (ring(LATENT), "rollout/latent_ring_bytes")):
+        for kind, key in ((LINEAR, "rollout/linear_state_bytes"), (CONV, "rollout/conv_cache_bytes"), (POOLED, "rollout/kbar_cache_bytes"),
+                          (ring(LATENT), "rollout/latent_ring_bytes")):
             if held[kind]:
                 stats[key] = float(held[kind])
         if held[POOLED]:  # attention layers under a block selection: the blocks a step keeps
