@@ -539,7 +539,7 @@ def test_flash_compiles_with_unlike_head_sizes_under_a_window_and_the_benchmark_
     assert sum(bool(re.search(pattern, c)) for c in calls) == counted, calls
 
 
-def test_dense_decode_step_builds_no_repeated_kv(topo, monkeypatch):
+def test_dense_decode_step_builds_no_repeated_kv(topo, monkeypatch, clean_trace_state):
     """Cached single-token decoding runs the dense einsum branch of
     ``Attention``. At the attention shapes of ``mistral7b_grpo_decode``
     (64 rows, 640 cache slots, 32 query / 8 KV heads of 128, bf16; one layer
@@ -586,6 +586,68 @@ def test_dense_decode_step_builds_no_repeated_kv(topo, monkeypatch):
     assert max(sizes.values()) >= rows * slots * 8 * 128  # the cache itself is there
     repeated = rows * slots * cfg.num_heads * cfg.dims_per_head
     assert not {s: n for s, n in sizes.items() if n >= repeated}
+
+
+@pytest.mark.parametrize("head_dim", [64, 128])
+def test_the_decode_loop_keeps_the_cache_channels_minor(topo, monkeypatch, clean_trace_state, head_dim):
+    """The regression PR 61 removed, held where tier-1 sees it. At the
+    attention shapes of ``lfm2_8b_grpo_reason_r128`` (128 rows of 1152 slots,
+    32 query / 8 KV heads, bf16, the sampler's extents; one llama layer) the
+    decode loop's carried ``k`` and ``v`` must not have the SLOT axis as
+    their minor one: at a head of 64 the compiler turned ``[128, 1152, 8,
+    64]`` slot-minor (``{1,3,2,0...}``) to suit the score product, and a
+    step's write of one slot became 4096 strided read-modify-writes, 208 us
+    where the rows are 131 KB (PERF.md section 6, PR 61). Two heads side by
+    side in a 128-lane row (``ops/cache_layout.py::lane_heads``) keep the
+    channels minor, as a head of 128 always had them. Read off the compiled
+    program's text; nothing is timed. (``clean_trace_state``: a mesh of CPU
+    devices an earlier test of the worker left behind would pin the embedding.)"""
+    import re
+
+    from trlx_tpu.models.transformer import CausalTransformer, TransformerConfig, make_kv_cache
+    from trlx_tpu.ops.sampling import kv_extents
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rows, prompt, new = 128, 128, 1024
+    slots = prompt + new
+    cfg = TransformerConfig.llama("7b", num_layers=1, hidden_size=2048, num_heads=32, num_kv_heads=8, head_dim=head_dim,
+                                  intermediate_size=2048, vocab_size=4096, dtype=DT, param_dtype=DT)
+    model = CausalTransformer(cfg)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    place = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
+    )
+    params = place(jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    )["params"])
+    cache = place(jax.eval_shape(lambda: make_kv_cache(cfg, rows, slots)))
+    leaf = cache[0]["k"].shape
+    assert leaf == ((rows, slots, 4, 128) if head_dim == 64 else (rows, slots, 8, 128))
+    extents = kv_extents(prompt, new)
+    assert len(extents) > 1
+
+    def decode(params, token, slot_mask, cache, steps):
+        def step(i, carry):
+            token, cache = carry
+            out = model.apply(
+                {"params": params}, token, attention_mask=slot_mask, positions=jnp.full((rows, 1), prompt + i),
+                cache=cache, cache_index=prompt + i, kv_extents=extents,
+            )
+            return jnp.argmax(out["logits"][:, -1], axis=-1).astype(jnp.int32)[:, None], out["cache"]
+
+        return jax.lax.fori_loop(0, steps, step, (token, cache))
+
+    text = jax.jit(decode).lower(
+        params, place(_s((rows, 1), jnp.int32)), place(_s((rows, slots), jnp.int32)), cache, place(_s((), jnp.int32))
+    ).compile().as_text()
+    loops = [line for line in text.splitlines() if " while(" in line]
+    assert loops
+    shape = ",".join(str(n) for n in leaf)
+    carried = [layout for line in loops for layout in re.findall(rf"bf16\[{shape}\]\{{([^}}]*)\}}", line)]
+    assert len(carried) >= 2, loops  # k and v, in the loop's result (and its operand)
+    assert not [layout for layout in carried if layout.startswith("1,")], carried
+    writes = [line for line in text.splitlines() if " dynamic-update-slice(" in line and f"bf16[{shape}]" in line]
+    assert len(writes) >= 2 and not [w for w in writes if re.search(rf"= bf16\[{shape}\]\{{1,", w)], writes
 
 
 @pytest.mark.parametrize(

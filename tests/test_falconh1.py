@@ -357,7 +357,7 @@ def test_collection_counters_read_the_cache_by_leaf_name(family, state_bytes):
     slots = min(12, trainer.tcfg.sliding_window or 12)
     kv = 2 * 2 * 3 * slots * trainer.tcfg.kv_heads * trainer.tcfg.dims_per_head * 4
     assert trainer.last_cache_stats == {
-        "rollout/kv_cache_bytes": float(kv), "rollout/ssm_state_bytes": float(state_bytes)}
+        "rollout/kv_cache_bytes": float(kv), "rollout/ssm_state_bytes": float(state_bytes), "rollout/kv_lane_heads": 1.0}
 
 
 def test_hf_interop_says_there_is_no_converter():

@@ -809,7 +809,7 @@ def test_collection_counters_name_the_index_cache():
     trainer = PPOTrainer(cfg, reward_fn=lambda samples, **kw: [0.0] * len(samples))
     trainer._note_dense_kv_gauge((3, 21), GenerationConfig(max_new_tokens=19))
     assert trainer.last_cache_stats == {
-        "rollout/kv_cache_bytes": 0.0, "rollout/ssm_state_bytes": 0.0,
+        "rollout/kv_cache_bytes": 0.0, "rollout/ssm_state_bytes": 0.0, "rollout/kv_lane_heads": 1.0,
         "rollout/latent_cache_bytes": float(5 * 3 * 40 * (16 + 8) * 4),
         "rollout/index_cache_bytes": float(2 * 3 * 40 * 12 * 4),
         "rollout/sparse_gather_rows": 5.0 * 8}, trainer.last_cache_stats

@@ -330,7 +330,7 @@ def test_the_cut_is_the_configuration_files_and_its_arithmetic_holds():
     assert count(shapes["h_0"]["attn"]) == 16_783_360 and count(shapes["h_2"]["attn"]) == 10_485_888
     assert count(shapes["h_0"]["mlp"]) == 44_040_192 and count(shapes["h_2"]["mlp"]) == 88_080_384 + 65_536 + 32
     cache = jax.eval_shape(lambda: make_kv_cache(cut, 128, 1152))
-    assert cache[0]["conv"].shape == (128, 2, 2048) and cache[2]["k"].shape == (128, 1152, 8, 64)
+    assert cache[0]["conv"].shape == (128, 2, 2048) and cache[2]["k"].shape == (128, 1152, 4, 128)  # two KV heads of 64 a row (lane_heads)
     held = cache_bytes(cache, 1152)
     assert held[CONV] == 8 * 2**20 and held[KV] == 2 * 2 * 128 * 1152 * 8 * 64 * 2 and sum(held.values()) == held[CONV] + held[KV]
     traffic = job.load_json("traffic", "grpo_reason_r128")
@@ -540,7 +540,7 @@ def test_collection_counters_tell_the_conv_rows_from_k_and_v():
     trainer = GRPOTrainer(cfg, reward_fn=lambda samples, **kw: [0.0] * len(samples))
     trainer._note_dense_kv_gauge((3, 21), GenerationConfig(max_new_tokens=19))
     assert trainer.last_cache_stats == {
-        "rollout/kv_cache_bytes": float(2 * 2 * 3 * 40 * 2 * 16 * 4), "rollout/ssm_state_bytes": 0.0,
+        "rollout/kv_cache_bytes": float(2 * 2 * 3 * 40 * 2 * 16 * 4), "rollout/ssm_state_bytes": 0.0, "rollout/kv_lane_heads": 1.0,
         "rollout/conv_cache_bytes": float(4 * 3 * 2 * 64 * 4)}, trainer.last_cache_stats
     assert trainer.last_kv_layers == ((40, False), (40, False))  # a conv layer has no slots to read
 

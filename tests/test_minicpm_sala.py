@@ -498,7 +498,7 @@ def test_collection_counters_name_the_state_and_the_compressed_keys():
     trainer = PPOTrainer(cfg, reward_fn=lambda samples, **kw: [0.0] * len(samples))
     trainer._note_dense_kv_gauge((3, 40), GenerationConfig(max_new_tokens=16))
     assert trainer.last_cache_stats == {
-        "rollout/kv_cache_bytes": float(2 * 2 * 3 * 56 * 2 * 16 * 4), "rollout/ssm_state_bytes": 0.0,
+        "rollout/kv_cache_bytes": float(2 * 2 * 3 * 56 * 2 * 16 * 4), "rollout/ssm_state_bytes": 0.0, "rollout/kv_lane_heads": 1.0,
         "rollout/linear_state_bytes": float(2 * 3 * 4 * 16 * 16 * 4),
         "rollout/kbar_cache_bytes": float(2 * 3 * 2 * 28 * 16 * 4),
         "rollout/attn_block_selected_frac": block_selected_steps(40, 16, CFG)}, trainer.last_cache_stats

@@ -470,7 +470,7 @@ def test_collection_counters_name_the_latent_cache():
     trainer = PPOTrainer(cfg, reward_fn=lambda samples, **kw: [0.0] * len(samples))
     trainer._note_dense_kv_gauge((3, 21), GenerationConfig(max_new_tokens=19))
     assert trainer.last_cache_stats == {
-        "rollout/kv_cache_bytes": 0.0, "rollout/ssm_state_bytes": 0.0,
+        "rollout/kv_cache_bytes": 0.0, "rollout/ssm_state_bytes": 0.0, "rollout/kv_lane_heads": 1.0,
         "rollout/latent_cache_bytes": float(3 * 3 * 40 * (16 + 8) * 4),
         "rollout/index_cache_bytes": 0.0}, trainer.last_cache_stats  # no learned selection: no index keys
     assert trainer.last_kv_layers == ((40, False),) * 3

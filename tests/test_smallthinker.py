@@ -528,7 +528,7 @@ def test_collection_counters_split_the_cache_by_layer_kind():
     trainer._note_dense_kv_gauge((3, 21), GenerationConfig(max_new_tokens=19))
     row = 2 * 3 * trainer.tcfg.kv_heads * trainer.tcfg.dims_per_head * 4  # k and v, 3 rows, float32, a slot
     assert trainer.last_cache_stats == {
-        "rollout/kv_cache_bytes": float(row * (40 + 3 * 8)), "rollout/ssm_state_bytes": 0.0,
+        "rollout/kv_cache_bytes": float(row * (40 + 3 * 8)), "rollout/ssm_state_bytes": 0.0, "rollout/kv_lane_heads": 1.0,
         "rollout/kv_cache_window_bytes": float(row * 3 * 8),
         "rollout/kv_cache_global_bytes": float(row * 40)}
     assert trainer.last_kv_layers == ((40, False), (8, True), (8, True), (8, True))

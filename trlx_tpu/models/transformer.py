@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from trlx_tpu.ops.cache_layout import cache_slots
+from trlx_tpu.ops.cache_layout import cache_slots, lane_heads, lane_pack, lane_unpack
 
 
 def param_with_axes(init: Callable, axes: Tuple[str, ...]) -> Callable:
@@ -1541,16 +1541,36 @@ def grouped_einsum_attention(q, k, v, attention_bias, dtype) -> jax.Array:
     Query heads ``j*G .. j*G+G-1`` (``G = H // KV``) share KV head ``j``, so
     the head axis is viewed as ``[KV, G]`` and both contractions run against
     K and V as the cache holds them: nothing of ``B*S*H*D`` elements is ever
-    built. MHA is ``G = 1``."""
+    built. MHA is ``G = 1``.
+
+    **A lane-packed cache** (``k``, ``v [B, S, KV / P, P * D]``, ``P`` heads
+    side by side in a row: ``ops/cache_layout.py::lane_heads``) is read as it
+    lies too, ``P`` taken from the leaf: each query head's ``D`` channels
+    stand in lanes ``[w * D, (w + 1) * D)`` of a zero row of ``P * D`` (``w``
+    its KV head's place in the row), so a packed head has ``P * G`` query
+    rows and both contractions run over the packed axis; of a result row's
+    ``P * D`` lanes its own head's ``D`` are kept. A zero times a finite key
+    adds an exact zero to a float32 sum and the value product's lanes do not
+    mix, so the result is the unpacked one's; the scale stays ``1 / sqrt(D)``.
+    No ``[.., KV, D]`` view of the leaf is taken: inside a decode loop that
+    view is what turns the carried cache slot-minor at a head under 128."""
     B, T, H, D = q.shape
     S, KV = k.shape[1], k.shape[2]
-    G = H // KV
-    scores = jnp.einsum("btkgd,bskd->bkgts", q.reshape(B, T, KV, G, D), k).reshape(B, H, T, S)
+    P = k.shape[3] // D
+    G = H // (KV * P)
+    rows = q.reshape(B, T, KV, P * G, D)
+    if P > 1:
+        own = jnp.eye(P, dtype=bool)[:, None, :, None]  # [w, 1, lanes' w, 1]: a query row's own head's lanes
+        rows = jnp.where(own, q.reshape(B, T, KV, P, G, 1, D), 0).reshape(B, T, KV, P * G, P * D)
+    scores = jnp.einsum("btkgd,bskd->bkgts", rows, k).reshape(B, H, T, S)
     scores = scores / jnp.sqrt(jnp.asarray(D, dtype))
     scores = scores + attention_bias.astype(scores.dtype)
     probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(dtype)
-    out = jnp.einsum("bkgts,bskd->btkgd", probs.reshape(B, KV, G, T, S), v)
-    return out.reshape(B, T, H, v.shape[-1])
+    out = jnp.einsum("bkgts,bskd->btkgd", probs.reshape(B, KV, P * G, T, S), v)
+    if P > 1:
+        out = out.reshape(B, T, KV, P, G, P, D)
+        out = jnp.stack([out[:, :, :, w, :, w] for w in range(P)], axis=3)
+    return out.reshape(B, T, H, v.shape[-1] // P)
 
 
 @jax.tree_util.register_static
@@ -1705,6 +1725,11 @@ class Attention(nn.Module):
             # padding lanes — write nothing, mirroring scatter_steps'/
             # scatter_span's live-writes-only commit on the gather path.
             table = cache["block_table"]
+            if cache["k"].shape[-1] != D:
+                raise ValueError(
+                    f"a block pool's leaves are [NB, bs, KV, D] and this one's rows are {cache['k'].shape[-1]} wide at a head of {D}: "
+                    "make the pool and its rows with make_kv_cache(..., lane_packed=False) (ops/cache_layout.py)"
+                )
             ci = jnp.asarray(cache_index if cache_index is not None else 0)
             blk_size = cache["k"].shape[-3]
             if T == 1:
@@ -1777,6 +1802,11 @@ class Attention(nn.Module):
             return out, new_cache
 
         new_cache = None
+        # KV heads side by side in a row of this cache's `k` and `v` (ops/cache_layout.py::lane_heads), read off the
+        # leaf: the new rows are written in the leaf's form where they always were, the einsum reads the leaf as it
+        # lies (grouped_einsum_attention) and a flash call over the cache unpacks it, one copy of the leaf a call
+        side = 1 if cache is None else cache["k"].shape[-1] // D
+        k_rows, v_rows = lane_pack(k, side), lane_pack(v, side)
         ring = kv_extents is not None and kv_extents.ring
         if ring:
             # a window layer's cache of C < S slots, slot t at t mod C
@@ -1794,7 +1824,7 @@ class Attention(nn.Module):
                 write = lambda c, x: jax.lax.dynamic_update_slice(c, x.astype(c.dtype), (0, 0, 0, 0))
             else:  # a prefill from slot 0: its last C positions stay
                 write = lambda c, x: jnp.roll(x[:, T - C :].astype(c.dtype), (T - C) % C, axis=1)
-            new_cache = {"k": write(cache["k"], k), "v": write(cache["v"], v)}
+            new_cache = {"k": write(cache["k"], k_rows), "v": write(cache["v"], v_rows)}
             if T == 1 or ci.ndim:
                 k, v = new_cache["k"], new_cache["v"]
             # a prefill attends over its own k, v: nothing older is in the ring
@@ -1804,14 +1834,14 @@ class Attention(nn.Module):
             # decoding: rows rewind to different accepted lengths)
             ci = jnp.asarray(cache_index)
             if ci.ndim == 0:
-                k_cache = jax.lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype), (0, ci, 0, 0))
-                v_cache = jax.lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype), (0, ci, 0, 0))
+                k_cache = jax.lax.dynamic_update_slice(cache["k"], k_rows.astype(cache["k"].dtype), (0, ci, 0, 0))
+                v_cache = jax.lax.dynamic_update_slice(cache["v"], v_rows.astype(cache["v"].dtype), (0, ci, 0, 0))
             else:
                 # each row's span of T tokens at its own slot (a speculative round's verify
                 # and drafts, the slot engine's dense segment): one scatter of (row, slot)
                 # pairs in place in the loop's carry, as the ring branch above writes
-                k_cache = write_row_spans(cache["k"], k, ci)
-                v_cache = write_row_spans(cache["v"], v, ci)
+                k_cache = write_row_spans(cache["k"], k_rows, ci)
+                v_cache = write_row_spans(cache["v"], v_rows, ci)
             new_cache = {"k": k_cache, "v": v_cache}  # (and `kbar` under a block selection: below)
             if not (cfg.sparse_topk and T > 1):  # a span under the block selection attends over its own keys
                 k, v = k_cache, v_cache
@@ -1852,7 +1882,8 @@ class Attention(nn.Module):
                 window=flash_args.get("window"),
             ).reshape(B, T, H * D)
         elif flash_args is not None:
-            out = _flash_attention(q, k, v, flash_args).reshape(B, T, H * D)
+            # (the kernel reads [.., KV, D]: the prefill's call over a packed cache, outside any decode loop)
+            out = _flash_attention(q, lane_unpack(k, k.shape[-1] // D), lane_unpack(v, v.shape[-1] // D), flash_args).reshape(B, T, H * D)
         elif kv_extents is not None and len(kv_extents.slots) > 1 and T == 1 and cache is not None and ci.ndim == 0:
             # the sampler's single-token step, all rows at one slot
             out = extent_attention(q, k, v, attention_bias, ci, kv_extents.slots, cfg.dtype).reshape(B, T, H * D)
@@ -1910,6 +1941,7 @@ class Attention(nn.Module):
         t = positions[:, 0]
         with jax.named_scope("trlx/block_select"):
             last = jax.lax.dynamic_slice_in_dim(k_cache, jnp.maximum(ci - kernel + 1, 0), kernel, axis=1)
+            last = lane_unpack(last, k_cache.shape[-1] // kbar.shape[-1])  # (the cache's rows may hold heads side by side)
             mean = (jnp.sum(last.astype(jnp.float32), axis=1) / kernel).astype(kbar.dtype)  # [B, KV, D]
             completes = (t >= kernel - 1) & ((t - kernel + 1) % stride == 0)
             at = jnp.where(completes, (t - kernel + 1) // stride, kbar.shape[2])  # past the end: dropped
@@ -4447,13 +4479,22 @@ class CausalTransformer(nn.Module):
 
 
 def make_kv_cache(
-    cfg: TransformerConfig, batch_size: int, max_length: int, dtype=None
+    cfg: TransformerConfig, batch_size: int, max_length: int, dtype=None, lane_packed: bool = True
 ) -> Any:
     """All-zeros KV cache pytree for ``cfg`` (usable outside module ``apply``).
 
     Layout follows the block layout: a per-layer list of ``{"k", "v"}`` dicts,
     or one stacked dict with a leading layer dim when ``cfg.scan_layers``.
-    A layer's ``k`` and ``v`` are as long as its layout needs
+    A layer's ``k`` and ``v`` are ``[B, slots, KV / P, P * D]``: ``P =
+    lane_heads(D, KV)`` KV heads side by side in one 128-lane row where ``D <
+    128``, ``128 % D == 0`` and ``KV % P == 0`` (two at a head of 64, four at
+    32), the row-major reshape of ``[B, slots, KV, D]``'s last two axes, so
+    that a decode step writes one slot in place (``ops/cache_layout.py``);
+    ``P = 1``, the plain ``[B, slots, KV, D]``, at a head of 128 or more and
+    where ``lane_packed`` is False: a block pool's leaves ``[NB, bs, KV, D]``
+    and the rows bound for one, which the paged kernels read
+    (``ops/slot_refill.py``). ``Attention`` takes ``P`` from the leaf.
+    They are as long as the layer's layout needs
     (``cfg.layer_layout``): ``max_length`` slots for a full-causal layer,
     ``min(max_length, window)`` for a window layer, which the sampler then
     writes as a ring (slot ``t`` at ``t mod window``: ``CausalTransformer.
@@ -4533,9 +4574,10 @@ def make_kv_cache(
             # (ops/cache_layout.py::VOCABULARY)
             heads, d = cfg.lightning_heads, cfg.lightning_head_dim
             return {"state": jnp.zeros(stacked + (batch_size, heads, d, d), jnp.float32)}
+        side = lane_heads(cfg.dims_per_head, cfg.kv_heads) if lane_packed else 1
         shapes = {
-            "k": ((batch_size, slots, cfg.kv_heads, cfg.dims_per_head), dtype),
-            "v": ((batch_size, slots, cfg.kv_heads, cfg.dims_per_head), dtype),
+            "k": ((batch_size, slots, cfg.kv_heads // side, side * cfg.dims_per_head), dtype),
+            "v": ((batch_size, slots, cfg.kv_heads // side, side * cfg.dims_per_head), dtype),
         }
         if cfg.sparse_topk:
             # the keys' running mean-pool (`pooled_keys`), by KV head: kernel j is complete once the

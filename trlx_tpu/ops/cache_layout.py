@@ -7,6 +7,22 @@ ACCOUNT of a cache pytree's bytes by kind (``cache_bytes``) and the REFUSAL of
 the whole-row rollout paths (``refuse``: one table of what each path cannot
 hold and why). A new cache layout is written in ``models/`` and gets a row of
 ``VOCABULARY``; lifting a path for a kind is deleting a row of ``REFUSED``.
+
+**Lane-packed K and V.** A dense ``k`` / ``v`` leaf holds ``P = lane_heads(D,
+KV)`` KV heads side by side in its minor axis, ``[B, slots, KV / P, P * D]``:
+the row-major reshape of ``[B, slots, KV, D]``'s last two axes (``lane_pack``,
+``lane_unpack``), so the bytes, the slot axis and every writer's index tuple
+are what they were. At a head of 64 a ``[.., KV, 64]`` leaf would fill half of
+each 128-lane row, and the TPU compiler then keeps the loop-carried cache
+slot-minor, which makes a decode step's write of one slot a strided pass over
+the leaf (PERF.md section 6, PR 61); two heads a row keep the channels minor
+and the write in place. ``P`` is 1, and nothing is reshaped, at a head of 128
+or more. A reader takes ``P`` from the leaf it is handed (``leaf.shape[-1] //
+D``), never from the rule, so a cache made unpacked is read as it always was:
+the paged pool (``ops/paged_kv.py::PagedKV``, ``[NB, bs, KV, D]``), the rows
+bound for it and the dense view gathered from it are NOT packed
+(``make_kv_cache(..., lane_packed=False)``), since ``ops/paged_attention.py``
+and ``ops/paged_prefill.py`` read their own layout a block at a time.
 """
 
 from collections import Counter
@@ -16,7 +32,7 @@ import jax
 import numpy as np
 
 __all__ = ["KV", "POOLED", "RECURRENT", "LINEAR", "CONV", "LATENT", "INDEX", "KINDS", "VOCABULARY", "PATHS", "REFUSED",
-           "describe", "cache_slots", "cache_bytes", "ring", "refuse"]
+           "describe", "cache_slots", "cache_bytes", "ring", "refuse", "lane_heads", "lane_pack", "lane_unpack", "kv_lane_heads"]
 
 # the kinds of thing a layer keeps a sequence, in the words a refusal says them
 KV, POOLED, RECURRENT, LINEAR, CONV, LATENT, INDEX = "kv", "pooled", "recurrent", "linear", "conv", "latent", "index"
@@ -39,7 +55,9 @@ class Leaf(NamedTuple):
 
 # every leaf name `make_kv_cache` may give a layer's dict
 VOCABULARY = {
-    "k": Leaf(KV, -3),  # [B, slots, KV heads, D]; a window layer's is a ring where the window is shorter than the row
+    # [B, slots, KV heads / P, P * D], P = lane_heads(D, KV) heads side by side in a row (1 at a head of 128 or more: the
+    # plain [B, slots, KV heads, D]); a window layer's is a ring where the window is shorter than the row
+    "k": Leaf(KV, -3),
     "v": Leaf(KV, -3),
     "kbar": Leaf(POOLED, None),  # [B, KV heads, slots / stride, D]: the keys' mean-pool under a block selection
     "ssm": Leaf(RECURRENT, None),  # a state-space recurrence's state beside attention's K and V, float32
@@ -52,6 +70,36 @@ VOCABULARY = {
     "latent": Leaf(LATENT, -2),  # [ckv | k_rope] in one row a slot, on a layer whose steps gather chosen slots
     "k_index": Leaf(INDEX, -2),  # [B, slots, index dim]: the one index key a slot of a layer that selects for itself
 }
+
+
+LANES = 128  # the minor axis of a TPU's tile: a row of a leaf narrower than this is padded to it, or the leaf is turned
+
+
+def lane_heads(head_dim: int, kv_heads: int) -> int:
+    """KV heads a dense ``k`` / ``v`` leaf holds side by side in one row of
+    its minor axis: as many heads of ``head_dim`` as fill ``LANES``, where
+    they fill it exactly and divide the layer's ``kv_heads``; 1 otherwise.
+    THE rule: a function of the two shapes and of nothing else."""
+    heads = LANES // head_dim if head_dim < LANES and LANES % head_dim == 0 else 1
+    return heads if kv_heads % heads == 0 else 1
+
+
+def lane_pack(x: jax.Array, heads: int) -> jax.Array:
+    """``[..., KV, D]`` as the leaf holds it, ``[..., KV / heads, heads * D]``;
+    ``x`` itself at 1."""
+    return x if heads == 1 else x.reshape(x.shape[:-2] + (x.shape[-2] // heads, heads * x.shape[-1]))
+
+
+def lane_unpack(x: jax.Array, heads: int) -> jax.Array:
+    """``lane_pack``'s inverse."""
+    return x if heads == 1 else x.reshape(x.shape[:-2] + (x.shape[-2] * heads, x.shape[-1] // heads))
+
+
+def kv_lane_heads(cache: Any, head_dim: int) -> int:
+    """KV heads a row of the ``k`` leaves of a cache pytree (arrays or
+    shapes) holds, read off the leaves; 1 for a cache with no ``k``."""
+    leaves = jax.tree_util.tree_flatten_with_path(cache)[0]
+    return max((int(leaf.shape[-1]) // head_dim for path, leaf in leaves if path and getattr(path[-1], "key", None) == "k"), default=1)
 
 
 class Held(NamedTuple):

@@ -98,7 +98,10 @@ def num_table_blocks(slots: int, block_size: int) -> int:
 def init_paged_kv(
     init_cache_fn, spec: PagedSpec, batch_size: int, slots: int
 ) -> PagedKV:
-    """All-zeros pool + all-zero-block tables for ``batch_size`` slots."""
+    """All-zeros pool + all-zero-block tables for ``batch_size`` slots.
+    ``init_cache_fn`` gives ``[.., KV, D]`` leaves, never lane-packed ones
+    (``make_kv_cache(..., lane_packed=False)``: the paged kernels read a
+    block's own layout, and ``Attention`` refuses a packed pool)."""
     return PagedKV(
         pool=init_cache_fn(spec.max_blocks, spec.block_size),
         block_table=jnp.zeros(

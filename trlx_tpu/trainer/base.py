@@ -43,7 +43,7 @@ from trlx_tpu.models.builder import (
 from trlx_tpu.models.transformer import (
     block_selected_pairs, block_selected_steps, make_kv_cache, selected_frac, sparse_gather_rows,
 )
-from trlx_tpu.ops.cache_layout import CONV, INDEX, KV, LATENT, LINEAR, POOLED, RECURRENT, cache_bytes, cache_slots, ring
+from trlx_tpu.ops.cache_layout import CONV, INDEX, KV, LATENT, LINEAR, POOLED, RECURRENT, cache_bytes, cache_slots, kv_lane_heads, ring
 from trlx_tpu.ops.paged_kv import kv_bytes
 from trlx_tpu.ops.sampling import (
     GenerationConfig,
@@ -1429,7 +1429,8 @@ class TPUBaseTrainer(BaseRLTrainer):
                 adjust = self._compose_logit_mask(algo_adjust)
             self._generate_fns[key] = make_slot_refill_fns(
                 self._apply_fn(),
-                lambda B, S: make_kv_cache(tcfg, B, S),
+                # (a block pool, and the rows and views that go to and from it, are never lane-packed: make_kv_cache)
+                lambda B, S: make_kv_cache(tcfg, B, S, lane_packed=paged is None),
                 batch_size,
                 prompt_len,
                 gen_config,
@@ -1639,7 +1640,8 @@ class TPUBaseTrainer(BaseRLTrainer):
         held = cache_bytes(policy_cache, S)
         kv, latent = held[KV] + held[ring(KV)], held[LATENT] + held[ring(LATENT)]
         total = sum(held.values()) - held[RECURRENT] - held[LINEAR] - held[CONV]
-        stats = self.last_cache_stats = {"rollout/kv_cache_bytes": float(kv), "rollout/ssm_state_bytes": float(held[RECURRENT])}
+        stats = self.last_cache_stats = {"rollout/kv_cache_bytes": float(kv), "rollout/ssm_state_bytes": float(held[RECURRENT]),
+                                         "rollout/kv_lane_heads": float(kv_lane_heads(policy_cache, self.tcfg.dims_per_head))}
         if latent:  # the layers cache a latent in place of K and V (a window layer's ring apart), and index keys with it
             stats.update({"rollout/latent_cache_bytes": float(held[LATENT]), "rollout/index_cache_bytes": float(held[INDEX] + held[ring(INDEX)])})
         for kind, key in ((LINEAR, "rollout/linear_state_bytes"), (CONV, "rollout/conv_cache_bytes"), (POOLED, "rollout/kbar_cache_bytes"),
