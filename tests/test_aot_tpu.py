@@ -778,6 +778,57 @@ def test_a_held_share_is_one_window_body_a_direction_and_a_frozen_kernel_has_no_
     assert len(of_kernels) == (0 if frozen else 3), results
 
 
+@pytest.mark.parametrize("family,held,tokens,K,d", [("smallthinker", 16, (8, 1024), 6, 2560), ("lfm2", 8, (8, 1152), 4, 2048)],
+                         ids=["k6_d2560", "k4_d2048"])
+def test_a_held_share_never_views_its_row_buffers_by_choice(topo, monkeypatch, family, held, tokens, K, d):
+    """The windowed layer at a train step of cell 6 (8192 tokens, 6 of 16
+    held of 64, hidden 2560) and of cell 13 (9216 tokens, 4 of 8 held of 32,
+    hidden 2048), value and gradient. A ``[tokens x K, d]`` buffer viewed
+    ``[tokens, K, d]`` is free at ``K = 8`` alone; at 6 and 4 THIS compiler
+    makes the view a copy (``reshape bf16[8192,6,2560]{..T(8,128)(2,1)}``,
+    ``bf16[9216,4,2048]{..T(4,128)(2,1)}``: PR 61's tree holds five a
+    layer). ``held_rows`` gathers the rows choice-major, by the ``K`` index
+    columns laid end to end, and sums a token's ``K`` whole slices, so the
+    compiled program has no ``[tokens, K, d]`` array at all, and the only
+    ``[tokens x K, d]`` results outside fusions' bodies are the two rooms
+    (``empty``), the loops' carries, the windows' own writes into them and
+    the one gather a direction that reads them: no ``reshape``, ``copy`` or
+    ``transpose`` of one."""
+    import re
+
+    from trlx_tpu.models.transformer import MoEMLP, TransformerConfig, held_row_bound
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = getattr(TransformerConfig, family)(dtype=DT, param_dtype=DT, moe_experts_held=held, moe_first_expert=0)
+    N = tokens[0] * tokens[1]
+    assert (cfg.num_experts_per_tok, cfg.hidden_size) == (K, d)
+    assert N * K // 8 < held_row_bound(N * K, held, cfg.num_experts) < N * K  # the one-body form
+    layer = MoEMLP(cfg)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    place = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    params = place(jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, d), DT)))["params"])
+
+    def loss(p, x):
+        y, aux = layer.apply({"params": p}, x)
+        return jnp.sum(y.astype(jnp.float32) ** 2) + aux[0]
+
+    with jax.default_matmul_precision("highest"):
+        text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(params, place(_s(tokens + (d,)))).compile().as_text()
+    assert (len(re.findall(r" while\(", text)), len(re.findall(r" conditional\(", text))) == (2, 0)
+    assert f"[{N},{K},{d}]" not in text
+    by_row = re.compile(rf"^\s*(?:ROOT )?(%\S+) = (?:bf16|f32)\[{N * K},{d}\]\S* ([\w-]+)\(")
+    moved, fused = [], False
+    for line in text.splitlines():
+        if line and not line.startswith(" "):  # a computation's header
+            fused = "fused_computation" in line
+        m = by_row.match(line)
+        if m and not fused and (m.group(2) in ("reshape", "copy", "transpose") or re.search("copy|transpose|reshape", m.group(1))):
+            moved.append(line.strip()[:120])
+    assert moved == [], moved
+    assert len(re.findall(rf"= bf16\[{N * K},{d}\]\S* custom-call\(", text)) == 2  # the two rooms
+
+
 # ---------------------------------------------------------------------------
 # the PPO learner's ladder of widths: one train-step program per rung
 # ---------------------------------------------------------------------------

@@ -482,7 +482,7 @@ _DROPLESS_LAYERS = {
 _DROPLESS_K = 3
 
 
-def _dropless_layer_loss(case, dtype, permute, monkeypatch, count_calls=True):
+def _dropless_layer_loss(case, dtype, permute, monkeypatch, count_calls=True, K=_DROPLESS_K):
     """``(loss(params, x), params, x)`` of a dropless ``MoEMLP`` that moves
     its rows with ``permute`` (None: the layer's own ``permute_rows``).
     ``count_calls=False`` leaves the two call counters at the end of a held
@@ -490,7 +490,7 @@ def _dropless_layer_loss(case, dtype, permute, monkeypatch, count_calls=True):
     from trlx_tpu.models import transformer
 
     cfg = _cfg(
-        num_experts=8, num_experts_per_tok=_DROPLESS_K, moe_capacity_factor=0.0,
+        num_experts=8, num_experts_per_tok=K, moe_capacity_factor=0.0,
         dtype=dtype, param_dtype=dtype, **_DROPLESS_LAYERS[case],
     )
     rs = np.random.RandomState(3)
@@ -806,41 +806,68 @@ def _no_bound(monkeypatch):
     monkeypatch.setattr(transformer, "held_row_bound", lambda rows, held, experts: rows)
 
 
-@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
-@pytest.mark.parametrize("case", _HELD_SHARE_LAYERS)
-def test_windowed_rows_equal_all_rows_to_the_bit(case, dtype, monkeypatch):
-    """A call whose held rows fit one window against the same layer with no
-    bound: the same rows go through the same grouped matmuls, the rows past
-    the window are the zeros ``_all_rows``' select writes, a token's ``K``
-    results are summed by the same einsum and added to zeros once, so the
-    value and the gradients with respect to ``x``, the router and the three
-    expert kernels are EQUAL, not close."""
-    _windows(monkeypatch)
-    loss, params, x = _dropless_layer_loss(case, dtype, None, monkeypatch, count_calls=False)
-    counted = jax.jit(lambda p, x: MoEMLP(_cfg(
-        num_experts=8, num_experts_per_tok=_DROPLESS_K, moe_capacity_factor=0.0, dtype=dtype,
-        param_dtype=dtype, **_DROPLESS_LAYERS[case])).apply({"params": p}, x)[1])(params, x)
-    assert float(counted[8]) == float(counted[9]) > 0  # every call had a bound and fitted one window
-    value, grads = jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(params, x)
-    _no_bound(monkeypatch)
-    loss, params, x = _dropless_layer_loss(case, dtype, None, monkeypatch, count_calls=False)
-    want_value, want = jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(params, x)
+def _assert_the_layer_without_a_bound(got, want, dtype):
+    """The windowed form's ``(value, gradients)`` against the same layer's
+    with no bound. The value and the three expert kernels' gradients are
+    EQUAL: the same rows under the same gates, a token's ``K`` products taken
+    in float32 and added in the einsum's order ``k = 0..K-1``; the rows'
+    gradient ``g x gate`` is one product a number. Two sums are taken another
+    way and are held to their rounding. A gate's gradient is ``result . g``
+    over ``d``, reduced a window at a time where ``_sum_choices``' transpose
+    contracts ``[tokens, K, d]``: the same float32 terms in another order
+    (it reaches the router and, through it, ``x``). A token's gradient is the
+    sum of its ``K`` rows' gradients, taken in float32 and rounded once where
+    ``jnp.repeat``'s transpose adds them in the rows' dtype: equal in float32,
+    within a bfloat16 unit in the last place (and what ``K - 1`` roundings of
+    partial sums leave under cancellation) in bfloat16."""
+    (value, grads), (want_value, want) = got, want
     np.testing.assert_array_equal(np.asarray(value), np.asarray(want_value))
     flat = jax.tree_util.tree_flatten_with_path(grads)[0]
     assert len(flat) == 5  # the router, the three expert kernels, x
+    rtol, atol = (0.0, 1e-6) if dtype == jnp.float32 else (2.0**-7, 2.0**-8)
     for (path, g), q in zip(flat, jax.tree_util.tree_leaves(want)):
+        name = jax.tree_util.keystr(path)
         g, q = np.asarray(g.astype(jnp.float32)), np.asarray(q.astype(jnp.float32))
-        assert np.isfinite(g).all() and np.any(g != 0), jax.tree_util.keystr(path)
-        np.testing.assert_array_equal(g, q, err_msg=jax.tree_util.keystr(path))
+        assert np.isfinite(g).all() and np.any(g != 0), name
+        if "w_" in name:
+            np.testing.assert_array_equal(g, q, err_msg=name)
+        else:
+            np.testing.assert_allclose(g, q, rtol=rtol, atol=atol * np.abs(q).max(), err_msg=name)
 
 
-def _biased_to_the_held_windows(real_tokens, monkeypatch, tile, factor=2, dtype=jnp.float32, bias=100.0):
-    """A layer whose router bias sends all three choices of every token to
-    its three held experts (``bias`` -100: none of them): ``3 x real_tokens``
-    held rows against a bound of ``round_up(factor * ceil(108 * 3 / 8),
-    tile)``."""
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("case", _HELD_SHARE_LAYERS)
+@pytest.mark.parametrize("K", [_DROPLESS_K, 4, 6, 8], ids=["k3", "k4", "k6", "k8"])
+def test_windowed_rows_equal_all_rows_to_the_bit(K, case, dtype, monkeypatch):
+    """A call whose held rows fit one window against the same layer with no
+    bound, at 3, 4, 6 and 8 experts a token (a token's rows fill an (8, 128)
+    tile at 8 alone: ``_sum_live_rows``): the same rows go through the same
+    grouped matmuls, the rows past the window are the zeros ``_all_rows``'
+    select writes, and a token's ``K`` results are weighed and summed in the
+    einsum's order in float32, so the value and the expert kernels' gradients
+    are EQUAL, not close; the router's and ``x``'s are held to the rounding
+    of the two sums the backward takes another way
+    (``_assert_the_layer_without_a_bound``)."""
+    _windows(monkeypatch)
+    loss, params, x = _dropless_layer_loss(case, dtype, None, monkeypatch, count_calls=False, K=K)
+    counted = jax.jit(lambda p, x: MoEMLP(_cfg(
+        num_experts=8, num_experts_per_tok=K, moe_capacity_factor=0.0, dtype=dtype,
+        param_dtype=dtype, **_DROPLESS_LAYERS[case])).apply({"params": p}, x)[1])(params, x)
+    assert float(counted[8]) == float(counted[9]) > 0  # every call had a bound and fitted one window
+    got = jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(params, x)
+    _no_bound(monkeypatch)
+    loss, params, x = _dropless_layer_loss(case, dtype, None, monkeypatch, count_calls=False, K=K)
+    want = jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(params, x)
+    _assert_the_layer_without_a_bound(got, want, dtype)
+
+
+def _biased_to_the_held_windows(real_tokens, monkeypatch, tile, factor=2, dtype=jnp.float32, bias=100.0, K=3):
+    """A layer whose router bias sends three choices of every token (all of
+    them at ``K`` 3) to its three held experts (``bias`` -100: none of them):
+    ``3 x real_tokens`` held rows against a bound of ``round_up(factor *
+    ceil(36 * K * 3 / 8), tile)``."""
     _windows(monkeypatch, tile, factor)
-    cfg = _cfg(num_experts=8, num_experts_per_tok=3, moe_capacity_factor=0.0, moe_experts_held=3,
+    cfg = _cfg(num_experts=8, num_experts_per_tok=K, moe_capacity_factor=0.0, moe_experts_held=3,
                moe_first_expert=4, moe_topk_method="noaux_tc", moe_scoring="sigmoid",
                dtype=dtype, param_dtype=dtype)
     rs = np.random.RandomState(5)
@@ -860,13 +887,16 @@ def test_a_call_over_the_bound_runs_two_windows_and_says_so(real_tokens, compact
     traced count; either way the result is the one a layer without a bound
     gives (``held_row_bound`` at ``tokens x K``): a window's rows land where
     they are computed and a token's ``K`` are summed once, after the loop,
-    so nothing is summed in another order (to the bit from one window; from
-    two, up to what a grouped matmul of another height rounds); and
-    ``moe/compact_frac`` (slots 8 and 9 of ``aux``) says whether one window
-    was enough."""
+    in the einsum's order (from one window to the bit, in bfloat16, whose
+    products are exact in float32: in float32 XLA:CPU fuses a product and
+    an addition where it pleases, in the sum's loop and in the einsum's
+    contraction differently; from two windows, up to what a grouped matmul
+    of another height rounds); and ``moe/compact_frac`` (slots 8 and 9 of
+    ``aux``) says whether one window was enough."""
     from trlx_tpu.models.transformer import held_row_bound, router_load_summary
 
-    layer, params, x, mask = _biased_to_the_held_windows(real_tokens, monkeypatch, tile=6)
+    dtype = jnp.bfloat16 if compact else jnp.float32
+    layer, params, x, mask = _biased_to_the_held_windows(real_tokens, monkeypatch, tile=6, dtype=dtype)
     assert held_row_bound(108, 3, 8) == 84
     run = jax.jit(lambda p, x: layer.apply({"params": p}, x, token_mask=mask))
     y, aux = run(params, x)
@@ -881,6 +911,32 @@ def test_a_call_over_the_bound_runs_two_windows_and_says_so(real_tokens, compact
     else:
         np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=2e-6, atol=1e-7)
     assert np.any(np.asarray(y) != 0)
+
+
+def _held_to_the_layer_without_a_bound(layer, params, x, mask, how, dtype, calls, monkeypatch):
+    """Value and all five gradients of a call that took ``calls`` bounded
+    calls of several windows each, against the same layer with no bound:
+    float32 rounding in float32, bfloat16's in bfloat16."""
+    target = jnp.asarray(np.random.RandomState(6).randn(3, 12, x.shape[-1]), jnp.float32)
+
+    def loss(p, x):
+        apply = lambda p, x: layer.apply({"params": p}, x, token_mask=mask)
+        y, aux = (jax.checkpoint(apply) if how == "checkpoint" else apply)(p, x)
+        return jnp.sum(y.astype(jnp.float32) * target) + 0.01 * jnp.sum(aux[:8]), aux
+
+    (value, aux), grads = jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True))(params, x)
+    assert (float(aux[8]), float(aux[9])) == (0.0, calls)  # no call fitted one window
+    _no_bound(monkeypatch)
+    (want_value, _), want = jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True))(params, x)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(float(value), float(want_value), rtol=tol)
+    moved = 0
+    for (path, g), q in zip(jax.tree_util.tree_flatten_with_path(grads)[0], jax.tree_util.tree_leaves(want)):
+        g, q = np.asarray(g.astype(jnp.float32)), np.asarray(q.astype(jnp.float32))
+        assert np.isfinite(g).all(), jax.tree_util.keystr(path)
+        np.testing.assert_allclose(g, q, rtol=tol, atol=tol * np.abs(q).max(), err_msg=jax.tree_util.keystr(path))
+        moved += bool(np.any(g != 0))
+    assert moved == 5  # the router, the three expert kernels, x (the bias decides, and learns nothing)
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
@@ -906,26 +962,24 @@ def test_windows_of_a_call_over_the_bound_give_the_layer_without_one(factor, win
     bound = transformer.held_row_bound(rows, 3, 8)
     assert bound == {(108, 2): 84, (108, 1): 42, (36, 2): 30, (36, 1): 18}[rows, factor]
     assert -(-rows // bound) == windows
-    target = jnp.asarray(np.random.RandomState(6).randn(3, 12, x.shape[-1]), jnp.float32)
+    _held_to_the_layer_without_a_bound(layer, params, x, mask, how, dtype, 108 // rows, monkeypatch)
 
-    def loss(p, x):
-        apply = lambda p, x: layer.apply({"params": p}, x, token_mask=mask)
-        y, aux = (jax.checkpoint(apply) if how == "checkpoint" else apply)(p, x)
-        return jnp.sum(y.astype(jnp.float32) * target) + 0.01 * jnp.sum(aux[:8]), aux
 
-    (value, aux), grads = jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True))(params, x)
-    assert (float(aux[8]), float(aux[9])) == (0.0, 108 // rows)  # no call fitted one window
-    _no_bound(monkeypatch)
-    (want_value, _), want = jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True))(params, x)
-    tol = 2e-5 if dtype == jnp.float32 else 2e-2
-    np.testing.assert_allclose(float(value), float(want_value), rtol=tol)
-    moved = 0
-    for (path, g), q in zip(jax.tree_util.tree_flatten_with_path(grads)[0], jax.tree_util.tree_leaves(want)):
-        g, q = np.asarray(g.astype(jnp.float32)), np.asarray(q.astype(jnp.float32))
-        assert np.isfinite(g).all(), jax.tree_util.keystr(path)
-        np.testing.assert_allclose(g, q, rtol=tol, atol=tol * np.abs(q).max(), err_msg=jax.tree_util.keystr(path))
-        moved += bool(np.any(g != 0))
-    assert moved == 5  # the router, the three expert kernels, x (the bias decides, and learns nothing)
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("windows", [2, 3], ids=["two_windows", "three_windows"])
+@pytest.mark.parametrize("K", [4, 6, 8], ids=["k4", "k6", "k8"])
+def test_windows_at_four_six_and_eight_choices_give_the_layer_without_one(K, windows, dtype, monkeypatch):
+    """The same at 4, 6 and 8 experts a token, where a token's rows do not
+    (4, 6) and do (8) fill a tile of eight: three of every token's choices
+    on the three held experts, 108 held rows of ``36 x K``, in windows of 54
+    and of 36 (the bound set by hand: once the even share is two windows at
+    4 choices and one at 6 and 8)."""
+    from trlx_tpu.models import transformer
+
+    layer, params, x, mask = _biased_to_the_held_windows(36, monkeypatch, tile=6, dtype=dtype, K=K)
+    bound = 108 // windows
+    monkeypatch.setattr(transformer, "held_row_bound", lambda rows, held, experts: bound)
+    _held_to_the_layer_without_a_bound(layer, params, x, mask, "whole", dtype, 1, monkeypatch)
 
 
 @pytest.mark.parametrize("why", ["no_real_token", "no_choice_falls_here"])
@@ -951,15 +1005,19 @@ def test_a_call_with_no_held_row_runs_no_window(why, monkeypatch):
 def test_windowed_rows_hold_no_scatter_and_four_buffers_of_every_row(case, monkeypatch):
     """The bounded layer and its gradient move rows by gathers alone (no
     ``scatter*`` whose operand has ``tokens x K`` or ``bound`` rows of
-    features), and the traced gradient writes a buffer of ``tokens x K``
-    rows four times, all of them OUTSIDE the two ``while`` bodies, which
-    work on ``bound`` rows: each assignment's result row, in the forward and
-    again in the backward, which runs the windows from the inputs; their
-    gradient ``dy x gates`` (the einsum's own transpose); and each
-    assignment's row gradient, which the tokens sum. Three of the four are a
-    gather under a select that drops what no window wrote (zeros broadcast
-    for the select count with it). The layer with no bound, walked the same
-    way, writes such a buffer some thirty times and has no ``while``."""
+    features). Since PR 62 the four buffers of the name are two a direction:
+    one uninitialised ``[windows x bound, d]`` ROOM (the results by window
+    forward; the row gradients by window backward, beside a ``[windows x
+    bound]`` float32 vector of the gates' gradients: the backward's second
+    room, the results again, went with its gather), written by the ``while``
+    body a window of ``bound`` rows at a time, and ONE gather of it after the
+    loop, choice-major: a token's ``K`` rows are ``K`` whole slices of what
+    the gather wrote and are summed as they are read. Nothing else writes
+    ``tokens x K`` rows of features, inside the loops or outside, and no
+    equation has a ``[tokens, K, d]`` operand or result, so no ``d``-wide
+    array is reshaped to or from that shape. The layer with no bound, walked
+    the same way, writes such a buffer some thirty times, views it ``[tokens,
+    K, d]`` and has no ``while``."""
     from trlx_tpu.models import transformer
 
     _windows(monkeypatch)
@@ -978,15 +1036,24 @@ def test_windowed_rows_hold_no_scatter_and_four_buffers_of_every_row(case, monke
         ]
         names = [e.primitive.name for e in eqns]
         in_loops = [inner for e in eqns if e.primitive.name == "while" for inner in _equations(e.params["body_jaxpr"].jaxpr)]
-        return scatters, _row_buffers(eqns, tokens * K, tokens, K), _row_buffers(in_loops, tokens * K, tokens, K), names.count("while"), names.count("cond")
+        rooms = sorted(e.outvars[0].aval.shape for e in eqns if e.primitive.name == "empty")
+        by_choice = [
+            e.primitive.name for e in eqns for v in (*e.invars, *e.outvars)
+            if getattr(v.aval, "shape", ())[:2] == (tokens, K) and len(v.aval.shape) == 3
+        ]
+        return (scatters, _row_buffers(eqns, tokens * K, tokens, K), _row_buffers(in_loops, tokens * K, tokens, K),
+                rooms, by_choice, names.count("while"), names.count("cond"))
 
-    scatters, buffers, in_loops, whiles, conds = walk()
+    scatters, buffers, in_loops, rooms, by_choice, whiles, conds = walk()
     assert scatters == [], scatters
-    assert sorted(buffers) == sorted(3 * ["gather", "select_n", "broadcast_in_dim"] + ["transpose"]), buffers
-    assert in_loops == [] and (whiles, conds) == (2, 0)  # one body a direction, on ``bound`` rows
+    assert buffers == ["gather", "gather"] and in_loops == [], buffers
+    assert rooms == sorted([(2 * bound, 64), (2 * bound, 64), (2 * bound,)]), rooms  # the toy layers' hidden size
+    assert by_choice == [], by_choice
+    assert (whiles, conds) == (2, 0)  # one body a direction, on ``bound`` rows
     _no_bound(monkeypatch)
-    scatters, buffers, in_loops, whiles, conds = walk()
+    scatters, buffers, in_loops, rooms, by_choice, whiles, conds = walk()
     assert scatters == [] and len(buffers) > 30, buffers
+    assert "reshape" in by_choice and rooms == []
     assert (whiles, conds) == (0, 0)
 
 

@@ -3122,45 +3122,81 @@ def _window(x, route, bound, w):
 def _rows_by_window(rows: int, bound: int, d: int, dtype) -> jax.Array:
     """Room for every window of a sort of ``rows`` rows, ``bound`` rows
     each, and nothing written: a window's rows land where they are computed,
-    and only rows that were written are ever used (``_live_rows``)."""
+    and only rows that were written are ever used (``_sum_live_rows``)."""
     return jax.lax.empty((-(-rows // bound) * bound, d), dtype)
 
 
-def _live_rows(by_window: jax.Array, unsort: jax.Array, held_rows: jax.Array) -> jax.Array:
-    """Each assignment's sorted row, zeros for an assignment that is not one
-    of the first ``held_rows`` of the sort (another chip's expert, padding):
-    a select over the gather, so that what no window wrote is read and
-    dropped, never used. (All of them reading ONE row instead was slower in
-    every cell it was tried in, 0.2 to 1.2% of the rate; PR 59.)"""
-    rows = by_window.at[unsort].get(mode="promise_in_bounds")
-    return jnp.where((unsort < held_rows)[:, None], rows, 0)
+def _sum_live_rows(by_window: jax.Array, unsort: jax.Array, held_rows: jax.Array, K: int, gates=None) -> jax.Array:
+    """``[N, d]`` float32: the sum of each token's ``K`` live sorted rows
+    (``unsort [N x K]``: assignment ``token * K + choice`` -> sorted row),
+    each times its gate where ``gates [N, K]`` are given. ONE gather reads the
+    rows choice-major, by the ``K`` columns of ``unsort`` viewed ``[N, K]``
+    laid end to end (the index array is transposed, 4 bytes an assignment,
+    never the rows), so choice ``k`` is the whole-tile slice ``[k x N, (k +
+    1) x N)`` of what it wrote; each product is taken and added in float32
+    in the order ``k = 0..K-1``, as ``_sum_choices``' einsum does. Nothing
+    has the shape ``[N, K, d]``: a ``[N x K, d]`` buffer viewed that way is a
+    view at ``K = 8`` alone, where a token's rows fill an (8, 128) tile; at 6
+    and 4 the chip copies the buffer (2.91 ms for ``f32[16384,6,2560]``, 72 +
+    128 times a cycle of cell 6; PR 59's trace, PR 62). ``K`` gathers of
+    ``[N, d]``, one a column, were 31 MB more code in cell 6 (``hbm_code_gib``
+    0.262 -> 0.293, +0.67% of ``peak_hbm_gib``; builder, PR 62)."""
+    N = unsort.shape[0] // K
+    by_choice = unsort.reshape(N, K).T
+    rows = by_window.at[by_choice.reshape(K * N)].get(mode="promise_in_bounds")
+    # an assignment that is not among the first ``held_rows`` of the sort
+    # (another chip's expert, padding) reads what no window wrote: a select
+    # over the gather drops it. (All of them reading ONE row instead was
+    # slower in every cell it was tried in, 0.2 to 1.2% of the rate; PR 59.)
+    total = None
+    for k in range(K):
+        term = jnp.where((by_choice[k] < held_rows)[:, None], jax.lax.slice_in_dim(rows, k * N, (k + 1) * N), 0)
+        term = term.astype(jnp.float32)
+        if gates is not None:
+            term = term * gates[:, k, None].astype(jnp.float32)
+        total = term if total is None else total + term
+    return total
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def held_rows(sorted_rows, sum_choices, bound, x, gate_vals, kernels, route):
+def _choice_gates(gate_vals: jax.Array, real: jax.Array, K: int, dtype) -> jax.Array:
+    """``[N, K]`` gates as ``_sum_choices`` forms them: zero for a padding
+    token, rounded to the rows' dtype."""
+    return (gate_vals.reshape(-1, K) * real[:, None]).astype(dtype)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def held_rows(sorted_rows, bound, x, gate_vals, kernels, route):
     """``MoEMLP._all_rows`` with its row buffers cut to windows of ``bound``
     sorted rows: ONE ``while`` over the windows that hold a live row
     (``ceil(held rows / bound)`` of them, a traced count; one wherever the
     router is less than twice as fond of these experts as of the others)
     gathers a window's tokens, runs ``sorted_rows`` on them and writes the
     ``[bound, d]`` result where the window stands in an uninitialised
-    ``[tokens x K, d]`` buffer; every assignment then reads its row, or
-    zeros, and ``sum_choices`` weighs and sums a token's ``K`` as
-    ``_all_rows`` does. Nothing is summed over windows, so the value is
-    ``_all_rows``' to the bit however many windows ran.
+    ``[tokens x K, d]`` buffer; one gather then reads every assignment's
+    row choice-major and each token sums its ``K``, or zeros, under their
+    gates (``_sum_live_rows``: ``_sum_choices``' products in its order, with
+    no ``[tokens, K, d]`` view of the buffer). Nothing is summed over
+    windows, so the value is ``_all_rows``' to the bit however many windows
+    ran.
 
     The backward is one ``while`` over the same windows, from the INPUTS:
     it runs a window's ``sorted_rows`` again, hands it the gradient of its
-    rows (gathered from ``g x gates`` by the window's assignments), writes
-    the gradient of the window's tokens' rows where the window stands, and
-    adds the kernels' gradients into its carry: the one sum over windows,
-    exact for one window. A kernel nothing is differentiated by (a frozen
-    leaf reaches the layer through ``stop_gradient``) has a gradient that is
-    only ever added to itself in that carry, and the compiler takes the
-    carry and the grouped matmul that feeds it out (read in the compiled
-    train steps, PR 59). A ``while`` of a traced count has no reverse mode
-    of JAX's own, and a bounded ``scan`` in its place would keep every
-    window's residuals."""
+    rows (``g`` of each row's token times the row's gate: one gather of
+    ``bound`` rows of ``g [tokens, d]``, as the window gathers its tokens),
+    writes the gradient of the window's tokens' rows where the window stands
+    in the ONE ``[tokens x K, d]`` room the backward holds, reduces ``result x
+    g`` over ``d`` to the gradient of the window's gates (``[bound]`` float32,
+    written where the window stands in a ``[tokens x K]`` vector), and adds
+    the kernels' gradients into its carry: the one sum over windows, exact
+    for one window. After the loop each assignment reads its gate's gradient
+    and each token sums its ``K`` rows' gradients in float32
+    (``_sum_live_rows`` without gates). A kernel nothing is differentiated by
+    (a frozen leaf reaches the layer through ``stop_gradient``) has a
+    gradient that is only ever added to itself in that carry, and the
+    compiler takes the carry and the grouped matmul that feeds it out (read
+    in the compiled train steps, PR 59). A ``while`` of a traced count has no
+    reverse mode of JAX's own, and a bounded ``scan`` in its place would keep
+    every window's residuals."""
     real, _, unsort, group_sizes = route
     N, d = x.shape
     K = unsort.shape[0] // N
@@ -3171,39 +3207,43 @@ def held_rows(sorted_rows, sum_choices, bound, x, gate_vals, kernels, route):
         return jax.lax.dynamic_update_slice_in_dim(by_window, sorted_rows(xin, kernels, sizes), w * bound, 0)
 
     by_window = jax.lax.fori_loop(0, -(-held // bound), body, _rows_by_window(N * K, bound, d, x.dtype))
-    return sum_choices(_live_rows(by_window, unsort, held).reshape(N, K, d), gate_vals, real)
+    return _sum_live_rows(by_window, unsort, held, K, _choice_gates(gate_vals, real, K, x.dtype))
 
 
-def _held_rows_bwd(sorted_rows, sum_choices, bound, res, g):
+def _held_rows_bwd(sorted_rows, bound, res, g):
     x, gate_vals, kernels, route = res
     real, _, unsort, group_sizes = route
     N, d = x.shape
     K = unsort.shape[0] // N
     held = jnp.sum(group_sizes)
-    by_assignment = jax.ShapeDtypeStruct((N, K, d), x.dtype)
-    # bilinear in the rows and the gates: each transpose needs the other alone
-    (d_out,) = jax.linear_transpose(lambda out: sum_choices(out, gate_vals, real), by_assignment)(g)
-    d_out = d_out.reshape(N * K, d)
+    gates_of = partial(_choice_gates, real=real, K=K, dtype=x.dtype)
+    gates = gates_of(gate_vals).reshape(N * K)
 
     def body(w, carry):
-        outs, d_xins, d_kernels = carry
+        d_xins, d_gates, d_kernels = carry
         live, xin, sizes = _window(x, route, bound, w)
         out, vjp = jax.vjp(lambda xin, kernels: sorted_rows(xin, kernels, sizes), xin, kernels)
-        d_xin, d_kernel = vjp(d_out.at[live].get(mode="promise_in_bounds"))
+        # bilinear in the rows and the gates: each gradient needs the other alone
+        g_rows = g.at[live // K].get(mode="promise_in_bounds")
+        gate_rows = gates.at[live].get(mode="promise_in_bounds")
+        d_xin, d_kernel = vjp((g_rows * gate_rows[:, None].astype(g.dtype)).astype(out.dtype))
+        d_gate = jnp.sum(out.astype(g.dtype) * g_rows, axis=-1)
         write = lambda by_window, rows: jax.lax.dynamic_update_slice_in_dim(by_window, rows, w * bound, 0)
-        return write(outs, out), write(d_xins, d_xin), jax.tree_util.tree_map(jnp.add, d_kernels, d_kernel)
+        return write(d_xins, d_xin), write(d_gates, d_gate), jax.tree_util.tree_map(jnp.add, d_kernels, d_kernel)
 
-    empty = _rows_by_window(N * K, bound, d, x.dtype)
-    outs, d_xins, d_kernels = jax.lax.fori_loop(
-        0, -(-held // bound), body, (empty, empty, jax.tree_util.tree_map(jnp.zeros_like, kernels))
+    rows = _rows_by_window(N * K, bound, d, x.dtype)
+    d_xins, d_gates, d_kernels = jax.lax.fori_loop(
+        0, -(-held // bound), body,
+        (rows, jax.lax.empty(rows.shape[:1], g.dtype), jax.tree_util.tree_map(jnp.zeros_like, kernels)),
     )
-    out = _live_rows(outs, unsort, held).reshape(N, K, d)
-    (d_gates,) = jax.linear_transpose(lambda gates: sum_choices(out, gates, real), gate_vals)(g)
-    (dx,) = jax.linear_transpose(lambda x: jnp.repeat(x, K, axis=0), x)(_live_rows(d_xins, unsort, held))
+    d_gates = jnp.where(unsort < held, d_gates.at[unsort].get(mode="promise_in_bounds"), 0)
+    d_gates = d_gates.reshape(N, K).astype(x.dtype)
+    (d_gates,) = jax.linear_transpose(gates_of, gate_vals)(d_gates)
+    dx = _sum_live_rows(d_xins, unsort, held, K).astype(x.dtype)
     return dx, d_gates, d_kernels, None
 
 
-held_rows.defvjp(lambda *args: (held_rows(*args), args[3:]), _held_rows_bwd)
+held_rows.defvjp(lambda *args: (held_rows(*args), args[2:]), _held_rows_bwd)
 
 
 class MoEMLP(nn.Module):
@@ -3280,9 +3320,11 @@ class MoEMLP(nn.Module):
     windows that hold a live row (``ceil(held rows / bound)`` trips on the
     traced count, one unless a call overflows the bound), each window's rows
     written where the window stands in an uninitialised ``[B·T·k, d]``
-    buffer; after the loop each assignment reads its row, or zeros, and the
-    weighted sum over a token's ``k`` is ``_all_rows``' own, once: the bits
-    of the layer that holds every expert however many windows ran. Its
+    buffer; after the loop one gather reads each assignment's row, choice
+    by choice, and each token sums its ``k``, or zeros, under their gates in
+    ``_all_rows``' order, once (no ``[B·T, k, d]`` view of the buffer, a
+    copy on the chip unless ``k`` is 8): the bits of the layer that holds
+    every expert however many windows ran. Its
     backward is one ``while`` over the same windows from the layer's inputs.
     ``aux`` counts the calls that fitted the bound (``moe/compact_frac``).
     Where no bound applies (every expert held, or half of them or more; a
@@ -3471,7 +3513,7 @@ class MoEMLP(nn.Module):
                 y = one_of_two_rows(bodies, fits, x, gate_vals, kernels, route)
             else:
                 # a large share: one body, window after window of ``bound`` rows
-                y = held_rows(self._sorted_rows, self._sum_choices, bound, x.reshape(N, d), gate_vals, kernels, route)
+                y = held_rows(self._sorted_rows, bound, x.reshape(N, d), gate_vals, kernels, route)
             compact = jnp.stack([fits.astype(jnp.float32), jnp.ones((), jnp.float32)])
         return y.reshape(B, T, d), counts.astype(jnp.float32), jnp.zeros((), jnp.float32), compact
 
@@ -3640,7 +3682,13 @@ MOE_PIECE_ROW_BYTES = 2**29
 #   in a `while` over the windows that hold a live row (`held_rows`, PR 59:
 #   ceil(held rows / bound) trips, one in every call seen), each window's
 #   rows written where the window stands in an uninitialised `[tokens·K, d]`
-#   buffer and read once after the loop. No second body, so no code growth
+#   buffer and read once after the loop: one gather, choice-major, whose K
+#   whole slices of `[tokens, d]` are summed under their gates in float32
+#   (PR 62: a `[tokens, K, d]` view of the buffer is free at K = 8 alone and
+#   a copy at 6 and 4). The backward's loop carries ONE such room (each row's
+#   gradient), a `[tokens·K]` float32 vector (each gate's gradient, reduced
+#   over `d` in the window) and the kernels' gradients; after it every token
+#   sums its K rows' gradients the same way. No second body, so no code growth
 #   (+9 and +7 MB over the parent's programs at 32 of 256 and 16 of 64), and
 #   +5.8 and +4.7% `samples_per_s` there (builder, PR 59). At a cut of 8 to 16
 #   the same form LOST 2.5 and 3.6% to the two bodies (cells 7, 8: the
