@@ -5,6 +5,7 @@ import pytest
 from chipbench.tests.test_flops import *  # noqa: F401,F403
 from chipbench.tests.test_pangu_costs import *  # noqa: F401,F403
 from chipbench.tests.test_glm_costs import *  # noqa: F401,F403
+from chipbench.tests.test_nemotron_costs import *  # noqa: F401,F403
 
 
 @pytest.fixture(autouse=True)
@@ -31,6 +32,9 @@ EVERY_LAYER_CHARGED_A_SQUARE = {
     # ... and four of the toy lfm2's six layers hold a short convolution and no scores (14,745,600 expected of the
     # reference's 14,155,776: four squares of 147,456); held by tests/test_lfm2.py::test_forward_count_is_the_references_matmuls_in_both_kinds_of_layer
     "lfm2-8b-a1b-l10e8": "chipbench/tests/test_flops.py charges each layer an attention square",
+    # ... and eight of nemotron3-nano-30b-a3b-l9e8's nine layers are a Mamba-2 mixer or experts alone and hold no scores (PR 63);
+    # held by chipbench/tests/test_nemotron_costs.py::test_forward_count_is_the_references_matmuls_in_all_three_kinds_of_layer
+    "nemotron3-nano-30b-a3b-l9e8": "chipbench/tests/test_flops.py charges each layer an attention square",
 }
 
 

@@ -255,7 +255,7 @@ def cache_of(cfg):
     return lambda B, S: make_kv_cache(cfg, B, S)
 
 
-REFUSAL = r"{path} does not support a model whose cache holds recurrent state beside K and V \(leaves \['conv', 'ssm'\]\): .*recurrent state.*B7[bc]\); use the plain sampler"
+REFUSAL = r"{path} does not support a model whose cache holds recurrent state \(beside K and V, or with its conv's rows a layer's whole cache\) \(leaves \['conv', 'ssm'\]\): .*recurrent state.*B7[bc]\); use the plain sampler"
 
 
 @pytest.mark.parametrize("path", ["slot_refill", "engine", "prefix_cache", "speculative"])

@@ -65,6 +65,12 @@ _LFM2_MOE_NO_INTEROP = (
     "(random weights) only and no converter pair was ever checked against one "
     "(ROADMAP.md queue 2, B7)"
 )
+_NEMOTRON_H_NO_INTEROP = (
+    "model_type 'nemotron_h' has no HF checkpoint conversion yet: no checkpoint can "
+    "be fetched where this was built, so the family runs from 'builtin:nemotron3-<size>' "
+    "(random weights) only and no converter pair was ever checked against one "
+    "(ROADMAP.md queue 2, B7)"
+)
 _EXAONE_MOE_NO_INTEROP = (
     "model_type 'exaone_moe' has no HF checkpoint conversion yet: no checkpoint can "
     "be fetched where this was built, so the family runs from 'builtin:k-exaone-<size>' "
@@ -566,6 +572,8 @@ def config_from_hf(hf_config) -> TransformerConfig:
         raise ValueError(_DOTS3_NOTE_NO_INTEROP)
     if mt == "lfm2_moe":
         raise ValueError(_LFM2_MOE_NO_INTEROP)
+    if mt == "nemotron_h":
+        raise ValueError(_NEMOTRON_H_NO_INTEROP)
     raise ValueError(f"Unsupported HF model type for causal import: {mt}")
 
 
@@ -1215,6 +1223,8 @@ def hf_config_from_transformer(cfg):
         raise UnsupportedHFExport(_DOTS3_NOTE_NO_INTEROP)
     if mt == "lfm2_moe":
         raise UnsupportedHFExport(_LFM2_MOE_NO_INTEROP)
+    if mt == "nemotron_h":
+        raise UnsupportedHFExport(_NEMOTRON_H_NO_INTEROP)
     raise UnsupportedHFExport(
         f"No HF export mapping for model_type={mt!r} "
         "(set TransformerConfig.model_type to an HF family)"

@@ -212,6 +212,9 @@ QK_INIT_STD_KIMI_LINEAR = 0.08
 # the like for LFM2, per-head norms as K-EXAONE's: 2 on q and on k of the attention layers, a score of standard
 # deviation 4 at a head of 64 (chipbench/configs/lfm2-8b-a1b-l10e8.json, `assumed`)
 QK_INIT_STD_LFM2 = 0.04
+# the like for Nemotron-H's attention layers, which have no norm on q or k and no rotary: q.k/sqrt(128) of standard
+# deviation 4 at hidden 2688 (chipbench/configs/nemotron3-nano-30b-a3b-l9e8.json, `assumed`)
+QK_INIT_STD_NEMOTRON_H = 0.04
 
 
 class AttentionSizes(NamedTuple):
@@ -239,7 +242,9 @@ class LayerLayout(NamedTuple):
 
     window: Optional[int]  # a query sees its last `window` slots; None = full causal
     rotary: bool  # False: this layer applies no rotary embedding (NoPE)
-    ffn: str = "dense"  # dense (MLP of `intermediate_size`) | moe (MoEMLP of `expert_width`)
+    # dense (MLP of `intermediate_size`) | moe (MoEMLP of `expert_width`) | none (the layer is its
+    # sequence mixer alone: one norm, one sublayer, one residual add; `ffn_layout`)
+    ffn: str = "dense"
     # learned selection of keys (`index_topk` > 0): "full" = the layer has an
     # indexer of its own and selects; "shared" = it attends over the set the
     # last "full" layer before it chose; None = no selection
@@ -251,7 +256,10 @@ class LayerLayout(NamedTuple):
     # "kda" (`KDAMixer`: a gated delta rule behind short convs; the state and
     # the convs' last rows are the layer's whole cache) | "conv"
     # (`ShortConvMixer`: a short causal conv between two gates; the conv's
-    # last input rows are the layer's whole cache)
+    # last input rows are the layer's whole cache) | "mamba2" (`Mamba2Mixer`
+    # as the layer's WHOLE mixer: its state and its conv's last rows are the
+    # layer's whole cache, no K or V) | "none" (the layer is its feed-forward
+    # part alone and caches nothing)
     mixer: str = "attention"
 
 
@@ -278,7 +286,7 @@ class TransformerConfig:
     # selects the import/export converter pair in hf_interop
     model_type: Optional[str] = None
 
-    position_scheme: str = "learned"  # learned | rotary | alibi
+    position_scheme: str = "learned"  # learned | rotary | alibi | none (no positional term anywhere: nemotron_h)
     pos_offset: int = 0  # OPT stores positions with an offset of 2
     rotary_dim: Optional[int] = None  # partial rotary (gptj/neox); None = full
     rope_theta: float = 10000.0
@@ -300,7 +308,7 @@ class TransformerConfig:
 
     norm: str = "layernorm"  # layernorm | rmsnorm
     layer_norm_epsilon: float = 1e-5
-    activation: str = "gelu_new"  # gelu_new | gelu | silu | relu
+    activation: str = "gelu_new"  # gelu_new | gelu | silu | relu | relu2 (`relu(x)^2`)
     parallel_residual: bool = False  # gptj/neox style
     shared_ln: bool = False  # gptj: one LN feeds both attn and mlp
     # RMSNorm with a learned scale on q and on k, before rotary. True (olmoe):
@@ -404,6 +412,9 @@ class TransformerConfig:
     # of a deployment computes it alike, so it counts once when held shares
     # are added up
     num_shared_experts: int = 0
+    # that MLP's width where the family publishes one of its own (nemotron_h's
+    # `moe_shared_expert_intermediate_size`); 0 = `num_shared_experts * expert_width`
+    moe_shared_expert_intermediate_size: int = 0
     # how the router scores: "softmax" over the experts, or "sigmoid" of each
     # logit on its own; top-k runs on the scores either way
     moe_scoring: str = "softmax"
@@ -472,6 +483,9 @@ class TransformerConfig:
     mamba_groups: int = 1  # B and C are shared by heads / groups heads
     mamba_conv: int = 4  # causal depthwise conv width over x, B, C
     mamba_chunk: int = 128  # chunk of the scan (ops/ssd.py)
+    # std of the random init of `in_proj`'s z, x, B, C columns (`Mamba2Mixer`; the dt columns keep 0.02).
+    # Only matters for models run from random weights: 0.5 under Falcon-H1's multipliers (0.25 x 0.18 to 0.5)
+    mamba_in_proj_init_std: float = 0.5
     # the family's fixed scalar multipliers (muP forward scalings), all 1 for
     # every other family: applied only where they differ from 1
     embedding_multiplier: float = 1.0
@@ -493,6 +507,17 @@ class TransformerConfig:
     # layer runs `lightning_heads` heads of `lightning_head_dim` (q, k and v
     # alike) through `LightningMixer`
     mixer_layout: Optional[Tuple[str, ...]] = None
+    # each layer's feed-forward kind, one entry a layer: "dense" | "moe" | "none"
+    # (`LayerLayout.ffn`); None = `first_k_dense` dense layers, then experts
+    # where the model has any. A layer may lack its mixer ("none" in
+    # `mixer_layout`) or its feed-forward part, never both: it then is ONE
+    # norm, ONE sublayer, ONE residual add (`Block`)
+    ffn_layout: Optional[Tuple[str, ...]] = None
+    # nemotron_h's published `hybrid_override_pattern`, one letter a layer: `M`
+    # a Mamba-2 mixer alone, `*` attention alone, `E` the experts alone. Set,
+    # it IS the two layouts above (`__post_init__` derives both from it), and a
+    # cut of the depth reads its first `num_layers` letters
+    hybrid_override_pattern: Optional[str] = None
     lightning_heads: int = 0
     lightning_head_dim: int = 0
     lightning_chunk: int = 128  # chunk of the scan (ops/ssd.py)
@@ -561,11 +586,28 @@ class TransformerConfig:
                 if len(value) < self.num_layers:
                     raise ValueError(f"{name} has {len(value)} entries for {self.num_layers} layers")
                 object.__setattr__(self, name, value)
+        if self.hybrid_override_pattern is not None:
+            letters = {"M": ("mamba2", "none"), "*": ("attention", "none"), "E": ("none", "moe")}
+            pattern = str(self.hybrid_override_pattern)
+            if set(pattern) - set(letters):
+                raise ValueError(f"hybrid_override_pattern takes the letters M (Mamba-2), * (attention) and E (experts): {pattern!r}")
+            object.__setattr__(self, "mixer_layout", tuple(letters[c][0] for c in pattern))
+            object.__setattr__(self, "ffn_layout", tuple(letters[c][1] for c in pattern))
+        if self.ffn_layout is not None:
+            ffns = tuple(str(f) for f in self.ffn_layout)
+            mixers = self.mixer_layout or ("attention",) * len(ffns)
+            if len(ffns) < self.num_layers or set(ffns[: self.num_layers]) - {"dense", "moe", "none"} or (
+                    "moe" in ffns[: self.num_layers] and self.num_experts < 1):
+                raise ValueError(f"ffn_layout needs one of dense | moe (with num_experts) | none for each of {self.num_layers} layers: {ffns}")
+            empty = [i for i in range(min(self.num_layers, len(mixers))) if ffns[i] == "none" and mixers[i] == "none"]
+            if empty:
+                raise ValueError(f"layers {empty} have neither a sequence mixer (mixer_layout) nor a feed-forward part (ffn_layout): a layer is one or both")
+            object.__setattr__(self, "ffn_layout", ffns)
         if self.mixer_layout is not None:
             kinds = tuple(str(m) for m in self.mixer_layout)
             used = set(kinds[: self.num_layers])
-            if len(kinds) < self.num_layers or used - {"attention", "lightning", "kda", "conv"}:
-                raise ValueError(f"mixer_layout needs one of attention | lightning | kda | conv for each of {self.num_layers} layers: {kinds}")
+            if len(kinds) < self.num_layers or used - {"attention", "lightning", "kda", "conv", "mamba2", "none"}:
+                raise ValueError(f"mixer_layout needs one of attention | lightning | kda | conv | mamba2 | none for each of {self.num_layers} layers: {kinds}")
             if self.mixer != "none" or self.mtp_layers or (
                     "lightning" in used and (self.latent_attention or self.lightning_heads < 1 or self.lightning_head_dim < 2)):
                 raise ValueError(
@@ -579,7 +621,21 @@ class TransformerConfig:
                 )
             if "conv" in used and (self.conv_L_cache < 2 or self.conv_bias):
                 raise ValueError("mixer_layout (conv layers among attention layers) takes a conv of conv_L_cache >= 2 taps and no conv_bias")
+            if "mamba2" in used and (self.latent_attention or min(self.mamba_heads, self.mamba_head_dim, self.mamba_state, self.mamba_groups) < 1
+                                     or self.mamba_heads % self.mamba_groups or self.mamba_conv < 2):
+                raise ValueError(
+                    "mixer_layout (mamba2 layers among attention layers) takes K/V attention layers, mamba_heads heads of "
+                    "mamba_head_dim in mamba_groups groups of whole heads, a state of mamba_state and a conv of mamba_conv >= 2 taps"
+                )
+            if "none" in used and self.ffn_layout is None:
+                raise ValueError("mixer_layout `none` (a layer without a sequence mixer) needs ffn_layout to say the layer's feed-forward part")
             object.__setattr__(self, "mixer_layout", kinds)
+        lone = any("none" in (layout or ())[: self.num_layers] for layout in (self.mixer_layout, self.ffn_layout))
+        if lone and (self.parallel_residual or self.sandwich_norm or self.moe_router_input != "mlp_input"):
+            raise ValueError(
+                "a layer of ONE sublayer (a `none` in mixer_layout or ffn_layout) takes the sequential residual path: no "
+                "parallel_residual, no sandwich_norm, and a router on its own normed input"
+            )
         if self.sparse_topk:
             b, k, s = self.sparse_block, self.sparse_kernel, self.sparse_stride
             if (self.latent_attention or self.sliding_window or self.position_scheme == "alibi" or min(b, k, s) < 1 or b % s or k % s
@@ -649,10 +705,11 @@ class TransformerConfig:
             )
         windowed = self.sliding_window_layout is None or bool(self.sliding_window_layout[layer])
         roped = self.rope_layout is None or bool(self.rope_layout[layer])
+        ffn = "moe" if self.num_experts > 0 and layer >= self.first_k_dense else "dense"
         return LayerLayout(
             window=self.sliding_window if windowed and self.sliding_window else None,
             rotary=self.position_scheme == "rotary" and roped,
-            ffn="moe" if self.num_experts > 0 and layer >= self.first_k_dense else "dense",
+            ffn=self.ffn_layout[layer] if self.ffn_layout else ffn,
             indexer=(self.indexer_types[layer] if self.indexer_types else "full")
             if self.index_topk and not (windowed and self.sliding_window) else None,
             **({"mixer": self.mixer_layout[layer]} if self.mixer_layout else {}),
@@ -1198,6 +1255,71 @@ class TransformerConfig:
         )
 
     @staticmethod
+    def nemotron_h(size: str = "nano-30b-a3b", **overrides) -> "TransformerConfig":
+        """NVIDIA-Nemotron-3-Nano-30B-A3B (``model_type`` ``nemotron_h``): 52
+        layers of ONE sublayer each, ``h <- h + f_i(norm_i(h))``, the kind said
+        by ``hybrid_override_pattern[i]``: ``M`` a Mamba-2 mixer alone (64
+        heads of 64 in 8 groups, state 128, conv 4; its float32 state and its
+        conv's last rows are the layer's whole cache), ``*`` GQA 32/2 attention
+        at a head of 128 with NO rotary embedding and no other positional term,
+        ``E`` 128 routed experts of two matrices and ``relu(x)^2`` (sigmoid
+        scores, the six largest of ``score + bias``, renormalised, times 2.5)
+        beside one shared expert of its own published width; RMSNorm, no bias
+        on a projection, no multiplier, the head untied. The published string
+        is kept as a field and both layouts are derived from it
+        (``__post_init__``), so a cut of the depth overrides ``num_layers``
+        alone. Limits: the plain sampler, the scoring forward, the hydra branch
+        and the train step (``ops/cache_layout.py::refuse``); no
+        ``scan_layers``, no HF checkpoint import. ``mamba_in_proj_init_std``,
+        ``qk_init_std``, ``embed_init_std`` are stand-in scales
+        (chipbench/configs/nemotron3-nano-30b-a3b-l9e8.json, `assumed`).
+        ``builtin:nemotron3-nano-30b-a3b`` | ``builtin:nemotron-h-test``."""
+        dims = {
+            # the benchmark's cut in small: its nine letters, all three kinds; Mamba heads 4 x 16 in 2 groups, state
+            # 32, chunk 8; GQA 4/2 of 16; 8 experts of 32 top-2 under a bias that binds, a shared one of twice that
+            "test": dict(vocab_size=259, hidden_size=64, num_layers=9, num_heads=4, num_kv_heads=2, head_dim=16, intermediate_size=32, max_position_embeddings=256,
+                         hybrid_override_pattern="MEMEM*EME", mamba_heads=4, mamba_head_dim=16, mamba_groups=2, mamba_state=32, mamba_chunk=8,
+                         moe_intermediate_size=32, moe_shared_expert_intermediate_size=64, num_experts=8, num_experts_per_tok=2, moe_bias_init_std=0.05),
+            "nano-30b-a3b": dict(vocab_size=131072, hidden_size=2688, num_layers=52, num_heads=32, num_kv_heads=2, head_dim=128, intermediate_size=1856,
+                                 max_position_embeddings=262144, hybrid_override_pattern="MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME",
+                                 mamba_heads=64, mamba_head_dim=64, mamba_groups=8, mamba_state=128, mamba_chunk=128,
+                                 moe_intermediate_size=1856, moe_shared_expert_intermediate_size=3712, num_experts=128, num_experts_per_tok=6),
+        }[size]
+        return _make_preset(
+            dims,
+            overrides,
+            model_type="nemotron_h",
+            position_scheme="none",  # the attention layers apply no positional term: the state-space layers carry order
+            rope_theta=10000.0,  # published, and read by nothing
+            mamba_conv=4,
+            norm="rmsnorm",
+            layer_norm_epsilon=1e-5,
+            activation="relu2",  # mlp_hidden_act: the routed and the shared experts alike
+            attn_bias=False,
+            mlp_bias=False,
+            tie_word_embeddings=False,
+            num_shared_experts=1,
+            moe_gated=False,  # two matrices an expert
+            moe_scoring="sigmoid",
+            moe_topk_method="noaux_tc",
+            routed_scaling_factor=2.5,
+            moe_capacity_factor=0.0,  # dropless
+            moe_renormalize=True,  # norm_topk_prob
+            router_aux_coef=0.0,  # balance is the selection bias's work, not a loss's
+            # two stand-in scales that keep the held share of the assignments steady over seeds, which a trained
+            # selection bias does by balancing the load (PERF.md section 6, PR 63): the stream a router reads is the
+            # token's own embedding before it is anything else (at 1.0 the sublayers' constant parts, a Mamba-2
+            # output's and `relu^2`'s, decide which experts are popular and 8 held of 128 see 2.7 to 8.1% of the
+            # assignments by the seed) ...
+            embed_init_std=32.0,
+            # ... and in_proj's z, x, B, C pre-activations at an RMS of 1 on a normed input (Falcon-H1's 0.5 would put
+            # them at 26 and saturate every unit; at 3 `silu` is a `relu`, x, B and C are all positive and a head's
+            # output is one constant vector under a token's gate)
+            mamba_in_proj_init_std=1.0 / float(np.sqrt(overrides.get("hidden_size", dims["hidden_size"]))),
+            qk_init_std=QK_INIT_STD_NEMOTRON_H,
+        )
+
+    @staticmethod
     def dots3(size: str = "note", **overrides) -> "TransformerConfig":
         """dots3-note-prev (``model_type`` ``dots3_note``): latent attention
         of TWO geometries in one stack (``attention_sizes``). A full layer
@@ -1389,6 +1511,7 @@ def get_activation(name: str) -> Callable:
         "gelu": partial(nn.gelu, approximate=False),
         "silu": nn.silu,
         "relu": nn.relu,
+        "relu2": lambda x: jnp.square(nn.relu(x)),
     }[name]
 
 
@@ -2680,12 +2803,12 @@ class Mamba2Mixer(nn.Module):
         segments = (d_ssm, d_ssm, gn, gn, H)  # z, x, B, C, dt
 
         def in_proj_init(key, shape, dtype):
-            # z, x, B, C columns at 0.5: at the 0.02 of every other matrix the
-            # family's multipliers leave B and C so small that the state's part
-            # of a head's output is 1e-4 of the skip D x, and a model run from
-            # random weights has a dead state. The dt columns keep 0.02, so
-            # that the step sizes stay where dt_bias puts them
-            std = jnp.full((shape[1],), 0.5).at[-H:].set(0.02)
+            # z, x, B, C columns at `mamba_in_proj_init_std` (Falcon-H1: 0.5): at
+            # the 0.02 of every other matrix the family's multipliers leave B and
+            # C so small that the state's part of a head's output is 1e-4 of the
+            # skip D x, and a model run from random weights has a dead state. The
+            # dt columns keep 0.02, so that the step sizes stay where dt_bias puts them
+            std = jnp.full((shape[1],), cfg.mamba_in_proj_init_std).at[-H:].set(0.02)
             return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
 
         p = nn.Dense(
@@ -3412,7 +3535,7 @@ class MoEMLP(nn.Module):
         if cfg.num_shared_experts:
             # every token, unweighted; a padding token's part is dropped with
             # the rest of its output
-            shared = MLP(cfg, cfg.num_shared_experts * f, name="shared_expert")(x)
+            shared = MLP(cfg, cfg.moe_shared_expert_intermediate_size or cfg.num_shared_experts * f, name="shared_expert")(x)
             y = y + shared.astype(y.dtype) * w[..., None].astype(y.dtype)
 
         # Switch load-balance loss over the assignments asked for: E·Σ f_e·p_e
@@ -3446,10 +3569,13 @@ class MoEMLP(nn.Module):
     def _experts(self, kernels, matmul, xin):
         """gate/up/down on ``xin`` with ``matmul(rows, kernel)``."""
         act = get_activation(self.config.activation)
-        h = act(matmul(xin, kernels["w_gate"])) if "w_gate" in kernels else None
+        if "w_gate" not in kernels:  # two matrices an expert; its own scope, so that a trace tells it from the gated form's
+            with jax.named_scope(f"trlx/{self.config.activation}_experts"):
+                h = act(matmul(xin, kernels["w_up"]))
+            return matmul(h, kernels["w_down"])
+        h = act(matmul(xin, kernels["w_gate"]))
         up = matmul(xin, kernels["w_up"])
-        h = act(up) if h is None else h * up
-        return matmul(h, kernels["w_down"])
+        return matmul(h * up, kernels["w_down"])
 
     def _dropless(self, x, w, gate_vals, idx, kernels):
         """``_dropless_rows`` on all the tokens at once or, past
@@ -3871,7 +3997,8 @@ class Block(nn.Module):
                 return MoEMLP(cfg, name="mlp")(h, token_mask, router_input)
             return MLP(cfg, name="mlp")(h), jnp.zeros((_moe_aux_size(cfg),), jnp.float32)
 
-        h = Norm(cfg, name="ln_attn")(x)
+        # (a layer without a mixer has no norm in front of one: nemotron_h's `E`)
+        h = Norm(cfg, name="ln_attn")(x) if layout.mixer != "none" else None
         if cfg.mixer == "mamba2":
             # both mixers read the same normed input; their outputs are
             # summed before the one residual add, then the MLP as usual
@@ -3884,7 +4011,11 @@ class Block(nn.Module):
             mlp_out, aux = run_mlp(Norm(cfg, name="ln_mlp")(x))
             return x + mlp_out, new_cache, aux, None
         kda_stats = gate_stats = None
-        if layout.mixer == "lightning":
+        if layout.mixer == "none":
+            attn_out, new_cache = None, cache  # nothing cached: the layer's empty dict goes back as it came
+        elif layout.mixer == "mamba2":  # the layer's WHOLE mixer, under the name Falcon-H1's blocks give theirs
+            attn_out, new_cache = Mamba2Mixer(cfg, name="mixer")(h, cache, token_mask)
+        elif layout.mixer == "lightning":
             attn_out, new_cache = LightningMixer(cfg, name="attn")(h, positions, cache, token_mask)
         elif layout.mixer == "kda":
             attn_out, new_cache, kda_stats = KDAMixer(cfg, name="attn")(h, cache, token_mask)
@@ -3899,7 +4030,15 @@ class Block(nn.Module):
             attn_out, new_cache = Attention(cfg, rotary, name="attn")(h, attention_bias, positions, cache, cache_index, flash_args, kv_extents)
         if cfg.sandwich_norm:
             attn_out = Norm(cfg, name="ln_attn_post")(attn_out)
-        if cfg.parallel_residual:
+        if "none" in (layout.mixer, layout.ffn):
+            # a layer of ONE sublayer: one norm, one sublayer, one residual add, under the names the
+            # two-sublayer block gives that half
+            if layout.mixer == "none":
+                out, aux = run_mlp(Norm(cfg, name="ln_mlp")(x))
+            else:
+                out, aux = attn_out, jnp.zeros((_moe_aux_size(cfg),), jnp.float32)
+            x = x + (out * cfg.residual_multiplier if cfg.residual_multiplier != 1.0 else out)
+        elif cfg.parallel_residual:
             mlp_in = h if cfg.shared_ln else Norm(cfg, name="ln_mlp")(x)
             mlp_out, aux = run_mlp(mlp_in)
             x = x + attn_out + mlp_out
@@ -4554,7 +4693,8 @@ def make_kv_cache(
     ``lightning`` layer (``mixer_layout``) holds ``state`` ``[B, heads, d, d]``
     float32 and nothing else; a ``kda`` layer ``state`` ``[B, heads, d, d]``
     float32 and ``conv`` ``[B, kda_conv - 1, 3 heads d]`` and nothing else; a ``conv`` layer ``conv`` ``[B, conv_L_cache - 1,
-    hidden]`` and nothing else; an attention layer under a block selection
+    hidden]`` and nothing else; a ``mamba2`` layer (``mixer_layout``: Mamba-2 as the layer's whole mixer) ``ssm`` and
+    ``conv`` as above and nothing else; a layer without a mixer (``none``) an EMPTY dict; an attention layer under a block selection
     (``sparse_topk`` > 0) holds ``kbar`` ``[B, KV, max_length / sparse_stride,
     D]`` beside ``k`` and ``v``, the keys' mean-pool its decode steps score. A
     latent-attention layer (``kv_lora_rank`` > 0) holds ``ckv`` ``[B, slots,
@@ -4590,6 +4730,18 @@ def make_kv_cache(
                 "state": jnp.zeros(stacked + (batch_size, heads, d, d), jnp.float32),
                 "conv": jnp.zeros(stacked + (batch_size, cfg.kda_conv - 1, 3 * heads * d), dtype),
             }
+        def zeros(shapes):
+            return {name: jnp.zeros(stacked + shape, dt) for name, (shape, dt) in shapes.items()}
+
+        # a Mamba-2 mixer's state: the recurrence's, float32, and the conv's last `mamba_conv - 1` input rows
+        mamba = {
+            "ssm": ((batch_size, cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_state), jnp.float32),
+            "conv": ((batch_size, cfg.mamba_conv - 1, cfg.mamba_conv_channels), dtype),
+        }
+        if layout.mixer == "none":
+            return {}  # a layer without a sequence mixer keeps nothing a sequence
+        if layout.mixer == "mamba2":
+            return zeros(mamba)  # the layer's whole cache: no K or V beside them (ops/cache_layout.py::VOCABULARY)
         if layout.mixer == "conv":
             # the layer's whole cache: the last `conv_L_cache - 1` rows of the gated input `B * x` before
             # the conv, whatever the row's length; no K, V, latent or state (ops/cache_layout.py::VOCABULARY:
@@ -4632,9 +4784,8 @@ def make_kv_cache(
             # row's token `sparse_stride * j + sparse_kernel - 1` is in (ops/cache_layout.py::VOCABULARY)
             shapes["kbar"] = ((batch_size, cfg.kv_heads, max_length // cfg.sparse_stride, cfg.dims_per_head), dtype)
         if cfg.mixer == "mamba2":
-            shapes["ssm"] = ((batch_size, cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_state), jnp.float32)
-            shapes["conv"] = ((batch_size, cfg.mamba_conv - 1, cfg.mamba_conv_channels), dtype)
-        return {name: jnp.zeros(stacked + shape, dt) for name, (shape, dt) in shapes.items()}
+            shapes.update(mamba)
+        return zeros(shapes)
 
     if cfg.scan_layers:  # one layout for the stack: CausalTransformer refuses a mixed one
         return layer(0)
@@ -4684,6 +4835,8 @@ BUILTIN_SPECS = {
     "kimi-linear": TransformerConfig.kimi_linear,
     "dots3": TransformerConfig.dots3,
     "lfm2": TransformerConfig.lfm2,
+    "nemotron3": TransformerConfig.nemotron_h,
+    "nemotron-h": TransformerConfig.nemotron_h,
     "gptj": TransformerConfig.gptj,
     "gptneox": TransformerConfig.gptneox,
     "pythia": TransformerConfig.gptneox,

@@ -32,14 +32,14 @@ import jax
 import numpy as np
 
 __all__ = ["KV", "POOLED", "RECURRENT", "LINEAR", "CONV", "LATENT", "INDEX", "KINDS", "VOCABULARY", "PATHS", "REFUSED",
-           "describe", "cache_slots", "cache_bytes", "ring", "refuse", "lane_heads", "lane_pack", "lane_unpack", "kv_lane_heads"]
+           "describe", "cache_slots", "cache_bytes", "cacheless", "ring", "refuse", "lane_heads", "lane_pack", "lane_unpack", "kv_lane_heads"]
 
 # the kinds of thing a layer keeps a sequence, in the words a refusal says them
 KV, POOLED, RECURRENT, LINEAR, CONV, LATENT, INDEX = "kv", "pooled", "recurrent", "linear", "conv", "latent", "index"
 KINDS = {
     KV: "per-head K and V",
     POOLED: "compressed keys beside K and V",
-    RECURRENT: "recurrent state beside K and V",
+    RECURRENT: "recurrent state (beside K and V, or with its conv's rows a layer's whole cache)",
     LINEAR: "a recurrence's state as the layer's whole cache",
     CONV: "a short convolution's last input rows as the layer's whole cache",
     LATENT: "a latent in place of K and V",
@@ -60,7 +60,9 @@ VOCABULARY = {
     "k": Leaf(KV, -3),
     "v": Leaf(KV, -3),
     "kbar": Leaf(POOLED, None),  # [B, KV heads, slots / stride, D]: the keys' mean-pool under a block selection
-    "ssm": Leaf(RECURRENT, None),  # a state-space recurrence's state beside attention's K and V, float32
+    # a state-space recurrence's state, float32: beside attention's K and V (falcon_h1), or with `conv` the whole cache of
+    # a layer whose whole mixer it is (nemotron_h); the same kind and the same refusals either way
+    "ssm": Leaf(RECURRENT, None),
     # a conv's last input rows: ALONE a gated short convolution layer's whole cache, [B, taps - 1, hidden] whatever the
     # row's length; beside `ssm` part of a state-space mixer's state, beside a delta rule's `state` part of its layer's
     "conv": Leaf(CONV, None, (("ssm", RECURRENT), ("state", LINEAR))),
@@ -114,8 +116,9 @@ class Held(NamedTuple):
 def describe(cache: Any) -> List[Held]:
     """Every leaf of every layer's dict in a cache pytree (arrays or shapes: a
     list of layers, one stacked dict, a ``PagedKV``, a tuple of caches). The
-    paged pool's ``block_table`` is no layer's leaf and is passed by; any
-    other name ``VOCABULARY`` does not hold raises."""
+    paged pool's ``block_table`` is no layer's leaf and is passed by, as is a
+    layer that caches nothing (an empty dict has no leaf: ``cacheless``
+    counts those); any other name ``VOCABULARY`` does not hold raises."""
     layers: Dict[str, Dict[str, Any]] = {}
     for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
         name = getattr(path[-1], "key", None) if path else None
@@ -136,8 +139,15 @@ def describe(cache: Any) -> List[Held]:
 
 def cache_slots(layer_cache: Dict[str, jax.Array]) -> Optional[int]:
     """Slots a layer's dense cache holds a row (stacked or not); None for a
-    layer whose whole cache is a recurrent state."""
+    layer whose whole cache is a recurrent state, and for a layer that caches
+    nothing (an empty dict: no sequence mixer)."""
     return next((leaf.slots for leaf in describe(layer_cache) if leaf.slots is not None), None)
+
+
+def cacheless(cache: Any) -> int:
+    """Layers of a per-layer cache list that cache nothing: an empty dict, a
+    layer without a sequence mixer (``make_kv_cache``). 0 for a stacked cache."""
+    return sum(1 for layer in cache if isinstance(layer, dict) and not layer) if isinstance(cache, (list, tuple)) else 0
 
 
 def ring(kind: str) -> str:

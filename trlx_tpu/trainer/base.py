@@ -43,7 +43,7 @@ from trlx_tpu.models.builder import (
 from trlx_tpu.models.transformer import (
     block_selected_pairs, block_selected_steps, make_kv_cache, selected_frac, sparse_gather_rows,
 )
-from trlx_tpu.ops.cache_layout import CONV, INDEX, KV, LATENT, LINEAR, POOLED, RECURRENT, cache_bytes, cache_slots, kv_lane_heads, ring
+from trlx_tpu.ops.cache_layout import CONV, INDEX, KV, LATENT, LINEAR, POOLED, RECURRENT, cache_bytes, cache_slots, cacheless, kv_lane_heads, ring
 from trlx_tpu.ops.paged_kv import kv_bytes
 from trlx_tpu.ops.sampling import (
     GenerationConfig,
@@ -1648,6 +1648,9 @@ class TPUBaseTrainer(BaseRLTrainer):
                           (ring(LATENT), "rollout/latent_ring_bytes")):
             if held[kind]:
                 stats[key] = float(held[kind])
+        empty = cacheless(policy_cache)  # layers without a sequence mixer: an empty dict each
+        if empty:
+            stats["rollout/cacheless_layers"] = float(empty)
         if held[POOLED]:  # attention layers under a block selection: the blocks a step keeps
             stats["rollout/attn_block_selected_frac"] = block_selected_steps(P, gen_config.max_new_tokens, self.tcfg)
         if getattr(self.tcfg, "index_topk", 0) and not drafts:  # rows of the cache a decode step gathers a row of the batch, all layers
